@@ -97,7 +97,7 @@ def _record_meta() -> dict:
     """Schema + provenance stamp for every bench JSON row (ISSUE 7
     satellite): records are versioned and name the code revision they were
     measured at, so `perf_compare` can refuse cross-schema diffs and a row
-    pasted into BASELINE.md stays attributable. `analysis_clean` rides
+    pasted into a record stays attributable. `analysis_clean` rides
     along (ISSUE 11) so perf artifacts also certify the invariant lint."""
     from ditl_tpu.telemetry.perf import SWEEP_SCHEMA, git_rev
 
@@ -183,9 +183,9 @@ def _model_cfg(name: str, platform: str):
             # and measured fastest on v5e; "none" exceeds compile memory.
             remat="dots",
             attention_impl="flash",
-            # Measured on v5e (BASELINE.md r2 sweep): 1024-token tiles beat
-            # the 512 default by ~4% end-to-end at seq 1024 (whole-sequence
-            # tiles; fewer grid steps, no online-softmax rescale passes).
+            # Whole-sequence 1024-token tiles at seq 1024: fewer grid steps,
+            # no online-softmax rescale passes (builders' sweep from before
+            # this round preferred them to the 512 default; not re-measured).
             flash_block_q=1024, flash_block_kv=1024,
             # Fused blockwise CE: was a memory-only lever in r1, now matches
             # or beats naive at 32k vocab after the r2 sweep.
@@ -203,20 +203,19 @@ def _model_cfg(name: str, platform: str):
             head_dim=128, max_seq_len=2048, dtype="bfloat16",
             param_dtype="bfloat16",
             # r5: fused gate|up layout + the dots_inputs remat policy
-            # (save the norm outputs feeding the projections) measured
-            # -19 ms/step TOGETHER on v5e (582 -> 563; each alone is
-            # noise) — the first bite out of the r4 roofline's backward-
-            # scheduling residual (experiments/bwd_levers.py receipts in
-            # BASELINE.md). Same math: fused layout is bit-exact.
+            # (save the norm outputs feeding the projections), adopted
+            # TOGETHER (builders' figure from before this round, not
+            # re-measured; experiments/bwd_levers.py is the instrument).
+            # Same math: fused layout is bit-exact.
             remat="dots_inputs", fused_gate_up=True,
             attention_impl="flash",
             flash_block_q=1024, flash_block_kv=1024,
             # r3 sweep: CE block 4096 is +0.5% over 2048 (8192 matches
             # 4096); 2048-token flash tiles exceed v5e's 16M scoped VMEM,
-            # remat=attn loses 6%, batch 6/8 at s2048 exceed HBM. The
-            # b8 x s1024 SHAPE reaches 60.2% MFU (BASELINE.md) but changes
-            # the workload, so the pinned config keeps s2048 for an honest
-            # round-over-round vs_baseline.
+            # remat=attn loses 6%, batch 6/8 at s2048 exceed HBM (builders'
+            # figures from before this round, not re-measured). A b8 x
+            # s1024 SHAPE changes the workload, so the pinned config keeps
+            # s2048 for an honest round-over-round vs_baseline.
             loss_impl="fused", loss_block_tokens=4096,
         )
         batch, seq, optimizer = 4, 2048, "adafactor"
@@ -328,9 +327,8 @@ def bench_infer(engine: str = "lockstep", cache: str = "contiguous",
                 temperature: float = 0.0, guided: str = "",
                 spec_draft: bool = False, pipeline: bool = False,
                 admission: str = "reserve", pages: int = 0,
-                compile_cache_dir: str = "") -> int:
-    """Decode/serving benchmark — one JSON line. Every serving claim in
-    BASELINE.md is reproducible from here: ``--engine continuous`` ticks the
+                compile_cache: bool = False) -> int:
+    """Decode/serving benchmark — one JSON line: ``--engine continuous`` ticks the
     production slot engine (``--cache paged`` for the page pool + Pallas
     paged-attention kernel, ``--kv-quant int8`` for int8 pools,
     ``--speculative`` for speculative ticks), ``--infer-workload repetitive``
@@ -346,7 +344,8 @@ def bench_infer(engine: str = "lockstep", cache: str = "contiguous",
     from ditl_tpu.models import llama
     from ditl_tpu.runtime.distributed import enable_compile_cache
 
-    enable_compile_cache(compile_cache_dir)
+    if compile_cache:
+        enable_compile_cache()
     _inc0 = _incidents_now()
     platform = jax.devices()[0].platform
     cfg = ModelConfig(
@@ -418,7 +417,7 @@ def bench_infer(engine: str = "lockstep", cache: str = "contiguous",
                 "greedy argmax path of a deterministic chain self-cycles "
                 "(period <= lambda(m)), turning the workload into prompt-"
                 "lookup's best case and invalidating the draft-vs-lookup "
-                "split it exists to measure (BASELINE.md r4)"
+                "split it exists to measure"
             )
         novel = np.random.default_rng(1234)  # disjoint from training rng(1)
         prompts = _bigram_tokens(novel, batch, plen, chain_vocab).tolist()
@@ -630,7 +629,7 @@ def bench_infer(engine: str = "lockstep", cache: str = "contiguous",
 def run_gateway_bench(n_replicas: int, slots: int = 4, decode_chunk: int = 8,
                       prompt_len: int = 0, max_new: int = 0,
                       router: str = "affinity",
-                      compile_cache_dir: str = "",
+                      compile_cache: bool = False,
                       trace_out: str = "",
                       prefill_chunk: int = -1,
                       token_budget: int = -1,
@@ -691,7 +690,8 @@ def run_gateway_bench(n_replicas: int, slots: int = 4, decode_chunk: int = 8,
         serving_bench_summary, snapshot_serving,
     )
 
-    enable_compile_cache(compile_cache_dir)
+    if compile_cache:
+        enable_compile_cache()
     _inc0 = _incidents_now()
     platform = jax.devices()[0].platform
     cfg = ModelConfig(
@@ -1054,7 +1054,7 @@ def run_trace_replay_bench(trace_path: str, n_replicas: int = 3,
                            autoscale: bool = False, speed: float = 1.0,
                            min_replicas: int = 1,
                            slo_ttft_s: float = 2.5,
-                           compile_cache_dir: str = "",
+                           compile_cache: bool = False,
                            bulk_backlog: int = 0,
                            _model_overrides: dict | None = None,
                            _autoscale_overrides: dict | None = None) -> dict:
@@ -1097,7 +1097,8 @@ def run_trace_replay_bench(trace_path: str, n_replicas: int = 3,
     from ditl_tpu.models import llama
     from ditl_tpu.runtime.distributed import enable_compile_cache
 
-    enable_compile_cache(compile_cache_dir)
+    if compile_cache:
+        enable_compile_cache()
     _inc0 = _incidents_now()
     rows = load_trace(trace_path)
     if not rows:
@@ -2347,7 +2348,7 @@ def bench_gateway_overhead(*args, **kwargs) -> int:
 def run_multi_lora_bench(n_adapters: int = 4, slots: int = 4,
                          decode_chunk: int = 8, prompt_len: int = 0,
                          max_new: int = 0, swaps: int = 6,
-                         compile_cache_dir: str = "",
+                         compile_cache: bool = False,
                          _model_overrides: dict | None = None) -> dict:
     """Multi-LoRA serving overhead A/B (ISSUE 16 satellite): the SAME
     model, workload, and engine knobs run twice — once as a plain base
@@ -2388,7 +2389,8 @@ def run_multi_lora_bench(n_adapters: int = 4, slots: int = 4,
         # The swap drill re-publishes into a SPARE row while the old one
         # drains — a 1-row pool has no spare (and is not "multi" anyway).
         raise ValueError(f"n_adapters ({n_adapters}) must be >= 2")
-    enable_compile_cache(compile_cache_dir)
+    if compile_cache:
+        enable_compile_cache()
     _inc0 = _incidents_now()
     platform = jax.devices()[0].platform
     cfg = ModelConfig(
@@ -2545,7 +2547,7 @@ def _effective_bwd_impls(cfg, batch: int, seq: int, mesh=None) -> dict[str, str]
 def run_train_bench(model_name: str = "350m",
                     overrides: list[str] | None = None,
                     batch_override: int = 0, seq_override: int = 0,
-                    compile_cache_dir: str = "") -> dict:
+                    compile_cache: bool = False) -> dict:
     """One fine-tune bench measurement; returns the result record (the
     JSON row ``main`` prints). Extracted so ``--sweep`` can run it once per
     grid cell and record each row into the versioned sweep JSON."""
@@ -2572,8 +2574,9 @@ def run_train_bench(model_name: str = "350m",
     # bench's wall clock went (compile vs data staging vs timed steps).
     tracker = GoodputTracker()
     tracker.start()
-    if enable_compile_cache(compile_cache_dir):
-        print(f"bench: persistent compile cache at {compile_cache_dir}",
+    cache_dir = enable_compile_cache() if compile_cache else None
+    if cache_dir:
+        print(f"bench: persistent compile cache at {cache_dir}",
               file=sys.stderr)
     n_chips = len(jax.devices())
     platform = jax.devices()[0].platform
@@ -2638,8 +2641,7 @@ def run_train_bench(model_name: str = "350m",
     cost = compiled_cost(multi_exe, n_steps=chunk)
     state, metrics = multi_exe(state, gb0)
     loss_start = float(metrics["loss"][0])
-    float(metrics["loss"][-1])  # full host sync (block_until_ready alone does
-    # not guarantee completion through remote-device transports)
+    float(metrics["loss"][-1])  # full host sync: the value is on the host
     tracker.add("compile", time.perf_counter() - t0)
     print(f"bench: compile+first window {time.perf_counter() - t0:.1f}s "
           f"({params_m:.1f}M params)", file=sys.stderr)
@@ -2762,10 +2764,10 @@ def run_train_bench(model_name: str = "350m",
 
 def main(model_name: str = "350m", overrides: list[str] | None = None,
          batch_override: int = 0, seq_override: int = 0,
-         compile_cache_dir: str = "") -> int:
+         compile_cache: bool = False) -> int:
     result = run_train_bench(
         model_name, overrides=overrides, batch_override=batch_override,
-        seq_override=seq_override, compile_cache_dir=compile_cache_dir,
+        seq_override=seq_override, compile_cache=compile_cache,
     )
     print(json.dumps(result))
     return 0
@@ -2803,7 +2805,7 @@ def _parse_sweep_spec(spec: str) -> list[dict[str, str]]:
 def run_sweep(model_name: str, spec: str, out_path: str,
               overrides: list[str] | None = None,
               batch_override: int = 0, seq_override: int = 0,
-              compile_cache_dir: str = "") -> int:
+              compile_cache: bool = False) -> int:
     """``bench.py --sweep`` (ISSUE 7 tentpole leg 3): run a dotted-override
     grid, one resumable record per cell, into the versioned sweep JSON at
     ``out_path``. Cells already present in an existing record (same schema)
@@ -2870,7 +2872,7 @@ def run_sweep(model_name: str, spec: str, out_path: str,
             result = run_train_bench(
                 model_name, overrides=cell_overrides,
                 batch_override=cell_batch, seq_override=cell_seq,
-                compile_cache_dir=compile_cache_dir,
+                compile_cache=compile_cache,
             )
         except Exception as e:  # noqa: BLE001 - an OOM cell must not kill
             # the rest of the grid; the failure IS the cell's result.
@@ -3006,12 +3008,12 @@ if __name__ == "__main__":
                         help="train-bench batch override (0 = config default)")
     parser.add_argument("--seq", type=int, default=0,
                         help="train-bench seq-len override (0 = config default)")
-    parser.add_argument("--compile-cache-dir",
-                        default="~/.cache/ditl_tpu/xla-cache",
-                        help="persistent XLA compilation cache directory "
-                        "(on by default — a warm second run skips the "
-                        "~85 s compile+first-window; pass '' to disable; "
-                        "see docs/troubleshooting.md §20 for staleness)")
+    parser.add_argument("--no-compile-cache", action="store_true",
+                        help="disable the persistent XLA compilation cache "
+                        "(on by default: JAX_COMPILATION_CACHE_DIR when "
+                        "set, else one fixed directory inside the checkout "
+                        "— a warm second run skips the compile; see "
+                        "docs/troubleshooting.md §20 for staleness)")
     parser.add_argument("--chaos", default="", metavar="SPEC",
                         help="arm the fault plane (ditl_tpu/chaos/) with a "
                         "rule spec, e.g. 'engine.tick:delay@p=0.05,"
@@ -3188,7 +3190,7 @@ if __name__ == "__main__":
             n_adapters=args.serve_multi_lora, slots=args.slots,
             decode_chunk=args.decode_chunk, prompt_len=args.prompt_len,
             max_new=args.max_new,
-            compile_cache_dir=args.compile_cache_dir,
+            compile_cache=not args.no_compile_cache,
         ))
     if args.infer and args.serve_trace_replay:
         sys.exit(bench_trace_replay(
@@ -3196,7 +3198,7 @@ if __name__ == "__main__":
             slots=args.slots, decode_chunk=args.decode_chunk,
             autoscale=args.serve_autoscale, speed=args.trace_speed,
             min_replicas=args.serve_min_replicas,
-            compile_cache_dir=args.compile_cache_dir,
+            compile_cache=not args.no_compile_cache,
             bulk_backlog=args.serve_bulk_backlog,
         ))
     if args.infer and args.serve_replicas:
@@ -3204,7 +3206,7 @@ if __name__ == "__main__":
             args.serve_replicas, slots=args.slots,
             decode_chunk=args.decode_chunk, prompt_len=args.prompt_len,
             max_new=args.max_new, router=args.serve_router,
-            compile_cache_dir=args.compile_cache_dir,
+            compile_cache=not args.no_compile_cache,
             trace_out=args.trace_out,
             prefill_chunk=args.serve_prefill_chunk,
             token_budget=args.serve_token_budget,
@@ -3225,15 +3227,15 @@ if __name__ == "__main__":
             temperature=args.temperature, guided=args.guided,
             spec_draft=args.spec_draft, pipeline=args.pipeline,
             admission=args.admission, pages=args.pages,
-            compile_cache_dir=args.compile_cache_dir,
+            compile_cache=not args.no_compile_cache,
         ))
     if args.sweep:
         sys.exit(run_sweep(
             args.model, args.sweep, args.sweep_out,
             overrides=args.override, batch_override=args.batch,
             seq_override=args.seq,
-            compile_cache_dir=args.compile_cache_dir,
+            compile_cache=not args.no_compile_cache,
         ))
     sys.exit(main(args.model, overrides=args.override,
                   batch_override=args.batch, seq_override=args.seq,
-                  compile_cache_dir=args.compile_cache_dir))
+                  compile_cache=not args.no_compile_cache))

@@ -62,16 +62,17 @@ class RuntimeConfig:
     distributed: bool = False  # True => call jax.distributed.initialize
     log_level: str = "INFO"
     profiler_port: int = 0  # >0 => start jax.profiler server on this port
-    # Persistent XLA compilation cache (VERDICT r5 item 9: compile+first
-    # window is 85.6 s per session and pays on every restart, drill, and
-    # bench run). On by default; "" disables. The pinned directory is shared
-    # across sessions so a relaunch/elastic restart reuses compiled
-    # programs. On CPU the cache is only honored for single-device,
-    # single-process runs — this jaxlib's XLA:CPU intermittently crashes
-    # (SIGABRT/SIGSEGV) deserializing cached executables under the
-    # multi-device host platform and in multi-process gloo pods (see
-    # tests/conftest.py and docs/troubleshooting.md §20).
-    compile_cache_dir: str = "~/.cache/ditl_tpu/xla-cache"
+    # Persistent XLA compilation cache, on by default: restarts, elastic
+    # relaunches and repeat runs reuse compiled programs. WHERE it lives is
+    # not a config value: JAX_COMPILATION_CACHE_DIR when the environment
+    # sets it, else one fixed git-ignored directory inside the checkout
+    # (runtime/distributed.enable_compile_cache). On CPU the cache is only
+    # honored for single-device, single-process runs — XLA:CPU
+    # intermittently crashes (SIGABRT/SIGSEGV) deserializing cached
+    # executables under the multi-device host platform and in
+    # multi-process gloo pods (see tests/conftest.py and
+    # docs/troubleshooting.md §20).
+    compile_cache: bool = True
 
 
 @dataclass(frozen=True)
@@ -213,16 +214,16 @@ class ModelConfig:
     # fused_gate_up): the whole block's backward — activation grads and
     # BOTH weight grads — is emitted as one function with explicit
     # einsum contractions instead of autodiff transposes. An instrument
-    # against the backward-scheduling residual (BASELINE.md r5);
-    # measured-neutral configs should leave it off.
+    # against the backward-scheduling residual (a builders' null result
+    # from before this round); leave it off.
     mlp_custom_vjp: bool = False
     # MLP backward implementation behind the custom-VJP seam: "xla"
     # (explicit einsums, scheduled by XLA — the r5 null) | "pallas"
     # (hand-tiled Mosaic kernels, ops/mlp_bwd.py — the schedule is pinned
     # by the grid). "pallas" requires fused_gate_up and routes through the
     # custom VJP even when mlp_custom_vjp is off. Shapes the kernels
-    # cannot tile fall back to the einsum spelling; bench.py records which
-    # implementation actually ran.
+    # cannot tile raise on the TPU backend (size mlp_bwd_block_* to the
+    # model); in interpret mode they give way to the einsum spelling.
     mlp_bwd_impl: str = "xla"
     # Pallas MLP-backward tile sizes (0 = kernel defaults, sized for the
     # 1b3 shapes on v5e): token tile, intermediate-dim tile (pass 1),
@@ -337,8 +338,7 @@ class TrainConfig:
     # the moment's HBM footprint; variance always stays float32.
     adam_mu_dtype: str = "float32"
     # Optimizer steps per compiled call (lax.scan window; train/step.py
-    # make_multi_step). >1 removes host dispatch overhead between steps —
-    # significant over remote device transports.
+    # make_multi_step). >1 removes host dispatch overhead between steps.
     steps_per_call: int = 1
     log_every: int = 10
     metrics_file: str = ""  # "" => no JSONL scalar stream (metrics.py)

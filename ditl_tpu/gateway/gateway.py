@@ -2562,9 +2562,29 @@ def main(argv: list[str] | None = None) -> int:
             source="gateway",
             max_bytes=telemetry_cfg.journal_max_bytes(),
         ))
+    def replica_env(i: int) -> dict | None:
+        """One replica per chip. A process that initialises JAX claims
+        every chip it can see, so N replicas sharing one environment would
+        all want the same chips and all but the first would die at
+        start-up. Replica ``i`` is shown chip ``i`` alone, by the variables
+        libtpu honours (probed on a four-chip v5e host, PR 21); on a host
+        without TPUs they are inert. A lone replica keeps every chip (and
+        may shard over them with --replica-arg=--mesh)."""
+        if config.replicas <= 1:
+            return None
+        import os as _os
+
+        return {
+            **_os.environ,
+            "TPU_VISIBLE_CHIPS": str(i),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1",
+            "ALLOW_MULTIPLE_LIBTPU_LOAD": "1",
+        }
+
     handles = [
         SubprocessReplica(f"r{i}", make_build_argv(f"r{i}", roles[i]),
-                          role=roles[i])
+                          role=roles[i], env=replica_env(i))
         for i in range(config.replicas)
     ]
     fleet = Fleet(handles)

@@ -352,6 +352,9 @@ class _Handler(KeepAliveHandlerMixin, BaseHTTPRequestHandler):
     # adapter_names dict this replaces could not say "gone"), and the
     # owner-billing flush on /usage. None => legacy static routing.
     adapter_registry = None
+    # /v1/stats' "device" (runtime/distributed.device_summary) and
+    # "param_placement" (parallel/sharding.placement) blocks, read once.
+    placement_info: dict = {}
 
     def log_message(self, *args):  # route through our logger, not stderr
         logger.debug("http: " + args[0], *args[1:])
@@ -638,7 +641,12 @@ class _Handler(KeepAliveHandlerMixin, BaseHTTPRequestHandler):
         elif self.path in ("/v1/stats", "/stats"):
             stats = {"model": self.model_name, "engine": "lockstep",
                      "draining": bool(getattr(self.server, "draining", False)),
-                     "inflight": int(getattr(self.server, "inflight", 0))}
+                     "inflight": int(getattr(self.server, "inflight", 0)),
+                     # The device as jax reports it in THIS process, and
+                     # where the params live by their own shardings (both
+                     # read once, at make_server) — a caller checks what
+                     # the server runs on from the server's own answer.
+                     **self.placement_info}
             stats.update(self._load_snapshot())
             eng = self._engine_for_stats()
             if eng is not None:
@@ -2146,6 +2154,12 @@ def make_server(
         )
         for name, row in (adapter_names or {}).items():
             adapter_registry.seed(name, row)
+    from ditl_tpu.parallel.sharding import placement
+    from ditl_tpu.runtime.distributed import device_summary
+
+    placement_info = {"device": device_summary()}
+    if getattr(generator, "params", None) is not None:
+        placement_info["param_placement"] = placement(generator.params)
     handler = type(
         "BoundHandler",
         (_Handler,),
@@ -2170,6 +2184,7 @@ def make_server(
             "usage": usage,
             "usage_ledger": usage_ledger,
             "adapter_registry": adapter_registry,
+            "placement_info": placement_info,
         },
     )
     server = DrainableHTTPServer((host, port), handler)
@@ -2178,6 +2193,29 @@ def make_server(
         # the gateway's scale-to-zero wake budget uses a measured number.
         server.cold_start_s = float(cold_start_s)
     return server
+
+
+def _place_params(params, cfg: ModelConfig, mesh):
+    """Put the serving params on the mesh by the model's own rule table.
+    Eager init leaves the whole tree on the first device; un-placed, every
+    program call would copy it out from there and the first chip would
+    carry the full model. A tree the rule table does not describe (int8
+    weights, stacked adapters) is left to GSPMD, loudly."""
+    import jax
+
+    from ditl_tpu.models import llama
+    from ditl_tpu.parallel.sharding import is_axes_leaf, named_sharding_tree
+
+    axes = llama.param_logical_axes(cfg)
+    if (jax.tree.structure(axes, is_leaf=is_axes_leaf)
+            != jax.tree.structure(params)):
+        logger.warning(
+            "--mesh: the params tree (quantized weights / stacked adapters) "
+            "does not match the model's logical axes; it stays on the first "
+            "device and is copied out per program call"
+        )
+        return params
+    return jax.device_put(params, named_sharding_tree(mesh, axes))
 
 
 def serve(argv: list[str] | None = None) -> int:
@@ -2418,6 +2456,12 @@ def serve(argv: list[str] | None = None) -> int:
         "max_tenant_families=64 or conviction_share=0.5",
     )
     args = parser.parse_args(argv)
+
+    # Persistent compile cache, before the first program compiles: a
+    # restarted server, and a gateway's next replica, skip the compile.
+    from ditl_tpu.runtime.distributed import enable_compile_cache
+
+    enable_compile_cache()
 
     from ditl_tpu.config import Config, parse_overrides
 
@@ -2691,6 +2735,8 @@ def serve(argv: list[str] | None = None) -> int:
 
         params = quantize_weights(params)
         logger.info("quantized weights to int8 (weight-only)")
+    if mesh is not None and not args.pod:
+        params = _place_params(params, cfg, mesh)
     generator = Generator(params, cfg, tokenizer, mesh=mesh)
     draft_params = draft_cfg = None
     if args.draft_preset:

@@ -14,10 +14,12 @@ CPU simulation of an N-device pod (SURVEY.md §4's repaired test strategy):
     python -m ditl_tpu.launch --simulate 8 data.synthetic=true
 
 The persistent XLA compilation cache is on by default
-(``runtime.compile_cache_dir``, wired through ``init_runtime``): restarts,
-elastic relaunches, and repeat runs of an unchanged config skip the
-multi-minute first compile. ``runtime.compile_cache_dir=`` disables it;
-docs/troubleshooting.md §20 covers staleness.
+(``runtime.compile_cache``, wired through ``init_runtime``): restarts,
+elastic relaunches, and repeat runs of an unchanged config skip the first
+compile. It lives where ``JAX_COMPILATION_CACHE_DIR`` says, else in one
+fixed git-ignored directory inside the checkout;
+``runtime.compile_cache=false`` disables it; docs/troubleshooting.md §20
+covers staleness.
 """
 
 from __future__ import annotations

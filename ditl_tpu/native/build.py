@@ -79,3 +79,10 @@ class NativeLib:
 
     def available(self) -> bool:
         return self.get() is not None
+
+    @property
+    def loaded(self) -> bool:
+        """True once the library is in this process — unlike ``available``
+        it never triggers a build, so a summary can report whether the
+        native path actually ran without starting a compiler."""
+        return self._lib is not None

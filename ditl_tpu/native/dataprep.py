@@ -18,7 +18,7 @@ import numpy as np
 
 from ditl_tpu.native.build import NativeLib
 
-__all__ = ["available", "pack_stream", "segments_positions", "tokenize_padded"]
+__all__ = ["available", "loaded", "pack_stream", "segments_positions", "tokenize_padded"]
 
 _i64p = np.ctypeslib.ndpointer(np.int64, flags="C_CONTIGUOUS")
 _i32p = np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS")
@@ -54,6 +54,11 @@ def _get() -> ctypes.CDLL | None:
 
 def available() -> bool:
     return _LIB.available()
+
+
+def loaded() -> bool:
+    """Whether the library is loaded in this process (never builds)."""
+    return _LIB.loaded
 
 
 def _concat_docs(texts: list[str]) -> tuple[np.ndarray, np.ndarray]:

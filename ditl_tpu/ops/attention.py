@@ -21,7 +21,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from ditl_tpu.utils.compat import shard_map
+
+from ditl_tpu.ops.backend import refuse_on_tpu
 
 __all__ = ["dot_product_attention"]
 
@@ -156,12 +157,12 @@ def _seq_sharded_decode(
         def local4(q_, k_, v_, mask_):
             return local(q_, k_, v_, mask_, None, None)
 
-        return shard_map(
+        return jax.shard_map(
             local4, mesh=mesh,
             in_specs=(q_spec, kv_spec, kv_spec, mask_spec),
             out_specs=q_spec, check_vma=False,
         )(q, k, v, mask)
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(q_spec, kv_spec, kv_spec, mask_spec, scale_spec, scale_spec),
         out_specs=q_spec, check_vma=False,
@@ -260,7 +261,14 @@ def dot_product_attention(
         if not (fa.supports(q.shape[1], k.shape[1], q.shape[3], bq, bkv)
                 and fa.supports(q.shape[1], k.shape[1], q.shape[3],
                                 bqb or bq, bkvb or bkv)):
-            # Shapes the kernel can't tile (tiny tests, odd seq lens): XLA.
+            # Shapes the kernel can't tile: an error on the TPU; in
+            # interpret mode (tiny tests, odd seq lens) XLA.
+            refuse_on_tpu(
+                "attention_impl='flash'",
+                f"cannot tile Sq={q.shape[1]} Skv={k.shape[1]} "
+                f"D={q.shape[3]} (block_q={bq}, block_kv={bkv}, "
+                f"bwd {bqb or bq}/{bkvb or bkv})",
+            )
             return _xla_attention(q, k, v, causal=causal, segment_ids=segment_ids)
         if mesh is None:
             return fa.flash_attention(
@@ -275,7 +283,14 @@ def dot_product_attention(
         tp = _mesh_axes_size(mesh, rules.get("act_heads"))
         if q.shape[0] % dp or q.shape[2] % tp or k.shape[2] % tp:
             # Mesh doesn't divide batch/heads: the shard_map would fail at
-            # trace time — use the GSPMD-partitionable XLA path instead.
+            # trace time. An error on the TPU; in interpret mode the
+            # GSPMD-partitionable XLA path.
+            refuse_on_tpu(
+                "attention_impl='flash'",
+                f"mesh batch axes ({dp}) / heads axis ({tp}) do not divide "
+                f"batch={q.shape[0]} heads={q.shape[2]} "
+                f"kv_heads={k.shape[2]}",
+            )
             return _xla_attention(q, k, v, causal=causal, segment_ids=segment_ids)
         qkv_spec = logical_to_spec(("batch", None, "act_heads", None), rules)
         args = [q, k, v]
@@ -290,7 +305,7 @@ def dot_product_attention(
                 block_q=bq, block_kv=bkv, block_q_bwd=bqb, block_kv_bwd=bkvb,
             )
 
-        return shard_map(
+        return jax.shard_map(
             local,
             mesh=mesh,
             in_specs=tuple(in_specs),

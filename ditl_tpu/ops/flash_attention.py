@@ -43,7 +43,7 @@ from jax.experimental.pallas import tpu as pltpu
 __all__ = ["flash_attention"]
 
 from ditl_tpu.ops.attention import NEG_INF  # single source of the mask value
-from ditl_tpu.utils.compat import tpu_compiler_params
+from ditl_tpu.ops.backend import interpret_default
 
 NUM_LANES = 128
 NUM_SUBLANES = 8
@@ -54,17 +54,14 @@ class BlockSizes(NamedTuple):
     block_kv: int
 
 
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 def _pick_blocks(s_q: int, s_kv: int, block_q: int, block_kv: int) -> BlockSizes:
     return BlockSizes(min(block_q, s_q), min(block_kv, s_kv))
 
 
 def supports(s_q: int, s_kv: int, head_dim: int, block_q: int = 512,
              block_kv: int = 512) -> bool:
-    """True if the kernel can handle these shapes (callers fall back to XLA)."""
+    """True if the kernel can handle these shapes (off the TPU,
+    ``ops.attention`` gives way to XLA otherwise; on it, it raises)."""
     bq, bkv = _pick_blocks(s_q, s_kv, block_q, block_kv)
     return (
         s_q % bq == 0
@@ -284,7 +281,7 @@ def _fwd(
             pltpu.VMEM((bq, NUM_LANES), jnp.float32),  # l
             pltpu.VMEM((bq, d), jnp.float32),  # acc
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
@@ -515,7 +512,7 @@ def _bwd_impl(
         out_specs=pl.BlockSpec((1, 1, bq, d), q_map),
         out_shape=jax.ShapeDtypeStruct((b, h, s_q, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
@@ -635,9 +632,8 @@ def flash_attention(
     """FlashAttention with GQA + sequence-packing segment masks.
 
     Takes/returns the model's (B, S, H, D) layout. Raises ``ValueError`` on
-    shapes the kernel cannot tile — callers (``ops.attention``) fall back to
-    the XLA implementation. ``block_*_bwd`` size the backward kernels' tiles
-    independently (0 = same as forward).
+    shapes the kernel cannot tile. ``block_*_bwd`` size the backward kernels'
+    tiles independently (0 = same as forward).
     """
     b, s_q, h, d = q.shape
     _, s_kv, kv_heads, _ = k.shape
@@ -655,7 +651,7 @@ def flash_attention(
     blocks = _pick_blocks(s_q, s_kv, block_q, block_kv)
     blocks_bwd = _pick_blocks(s_q, s_kv, block_q_bwd, block_kv_bwd)
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
 
     qt = jnp.transpose(q, (0, 2, 1, 3))
     kt = jnp.transpose(k, (0, 2, 1, 3))

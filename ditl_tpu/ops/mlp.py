@@ -1,9 +1,9 @@
 """Fused-gate|up MLP block with a hand-written VJP and, per config, a
 Pallas fused-backward implementation.
 
-The r5 stop-gradient ablation (BASELINE.md, experiments/bwd_ablation.py)
-showed the MLP family's in-step weight-gradient GEMMs running at ~2x
-their isolated-peak rates — a property of XLA's backward SCHEDULE, not of
+The builders' r5 stop-gradient ablation (experiments/bwd_ablation.py;
+figures from before this round, not re-measured) showed the MLP family's
+in-step weight-gradient GEMMs running at ~2x their isolated-peak rates — a property of XLA's backward SCHEDULE, not of
 the GEMM shapes. The first instrument against that was this module's
 custom VJP: the whole block's backward (activation grads and BOTH weight
 grads) emitted as ONE function with explicit einsum contractions. The r5
@@ -19,8 +19,8 @@ tests/test_bwd_kernels.py). Enabled per config via
 ``ModelConfig.mlp_custom_vjp`` (einsum spelling) /
 ``ModelConfig.mlp_bwd_impl="pallas"`` (Pallas kernels; requires
 ``fused_gate_up``; plain float weights only — quantized serving never
-differentiates). Shapes ops/mlp_bwd.supports rejects fall back to the
-einsum spelling; bench.py records the implementation that actually ran.
+differentiates). Shapes ops/mlp_bwd.supports rejects raise on the TPU and
+give way to the einsum spelling in interpret mode only (ops/backend.py).
 """
 
 from __future__ import annotations
@@ -59,9 +59,15 @@ def _bwd(constrain, bwd_impl, bwd_blocks, interpret, res, g):
                 h, w_gu, w_down, gate, up, g,
                 blocks=bwd_blocks, interpret=interpret,
             )
-        # Shapes the kernel can't tile (tiny tests, odd dims): the einsum
-        # spelling below. bench.py re-derives this decision and records the
-        # implementation that actually ran, so an A/B stays attributable.
+        # Shapes the kernel can't tile: an error on the TPU; in interpret
+        # mode (tiny tests, odd dims) the einsum spelling below.
+        from ditl_tpu.ops.backend import refuse_on_tpu
+
+        refuse_on_tpu(
+            "mlp_bwd_impl='pallas'",
+            f"cannot tile N={b * s} D={d} F={w_down.shape[0]} "
+            f"(blocks={bwd_blocks})",
+        )
     # Recompute the cheap elementwise pieces (the "dots"-policy choice).
     sg = jax.nn.sigmoid(gate)
     silu_gate = gate * sg
@@ -132,7 +138,6 @@ def mlp_block(constrain, h: jax.Array, w_gu: jax.Array, w_down: jax.Array,
     if eff != "pallas" or mesh is None:
         return mlp_gu(constrain, h, w_gu, w_down, eff, bwd_blocks)
     from ditl_tpu.parallel.sharding import DEFAULT_RULES, logical_to_spec
-    from ditl_tpu.utils.compat import shard_map
 
     rules = rules if rules is not None else DEFAULT_RULES
     h_spec = logical_to_spec(("batch", None, None), rules)
@@ -141,7 +146,7 @@ def mlp_block(constrain, h: jax.Array, w_gu: jax.Array, w_down: jax.Array,
     def local(h_, wgu_, wdn_):
         return mlp_gu(_identity, h_, wgu_, wdn_, "pallas", bwd_blocks)
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh, in_specs=(h_spec, w_spec, w_spec),
         out_specs=h_spec, check_vma=False,
     )(h, w_gu, w_down)

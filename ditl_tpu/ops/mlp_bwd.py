@@ -1,8 +1,8 @@
 """Pallas fused-backward kernels for the fused-gate|up SwiGLU MLP block.
 
-The r5 custom-VJP null (BASELINE.md, experiments/bwd_levers.py) proved the
-~40 ms MLP backward residual is XLA's in-step *schedule*, not the einsum
-spelling: re-emitting the same contractions by hand changed nothing, because
+The builders' r5 custom-VJP null (experiments/bwd_levers.py; from before
+this round, not re-measured) showed the MLP backward residual is XLA's
+in-step *schedule*, not the einsum spelling: re-emitting the same contractions by hand changed nothing, because
 XLA still owned tiling and interleaving. This module takes the next step the
 r5 verdict named — take the backward out of XLA's hands entirely, the same
 move ops/flash_attention.py made for attention — by emitting the whole block
@@ -46,7 +46,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ditl_tpu.utils.compat import tpu_compiler_params
+from ditl_tpu.ops.backend import interpret_default
 
 __all__ = ["fused_mlp_bwd", "supports", "DEFAULT_BLOCKS"]
 
@@ -65,10 +65,6 @@ class BlockSizes(NamedTuple):
 DEFAULT_BLOCKS = BlockSizes(256, 512, 128)
 
 
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 def _pick_blocks(n: int, d: int, f: int, blocks) -> BlockSizes:
     bn, bf, bd = blocks or (0, 0, 0)
     bn, bf, bd = (bn or DEFAULT_BLOCKS.block_n, bf or DEFAULT_BLOCKS.block_f,
@@ -78,9 +74,8 @@ def _pick_blocks(n: int, d: int, f: int, blocks) -> BlockSizes:
 
 def supports(n: int, d: int, f: int, blocks=None) -> bool:
     """True if the kernels can tile (N=B*S tokens, D hidden, F intermediate).
-    Callers (ops/mlp.py) fall back to the einsum-spelled backward otherwise —
-    the bench JSON records which implementation actually ran, so an A/B can
-    never silently measure the fallback."""
+    Otherwise callers (ops/mlp.py) raise on the TPU and give way to the
+    einsum-spelled backward in interpret mode only."""
     bn, bf, bd = _pick_blocks(n, d, f, blocks)
     return (
         n % bn == 0
@@ -211,7 +206,7 @@ def fused_mlp_bwd(
         )
     bn, bf, bd = _pick_blocks(n, d, f, blocks)
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
 
     h2 = h.reshape(n, d)
     g2 = g.reshape(n, d)
@@ -239,7 +234,7 @@ def fused_mlp_bwd(
             jax.ShapeDtypeStruct((f, d), w_down.dtype),
         ),
         scratch_shapes=[pltpu.VMEM((bf, d), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
@@ -266,7 +261,7 @@ def fused_mlp_bwd(
             jax.ShapeDtypeStruct((d, 2 * f), w_gu.dtype),
         ),
         scratch_shapes=[pltpu.VMEM((bd, 2 * f), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -40,8 +40,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ditl_tpu.ops.attention import NEG_INF
+from ditl_tpu.ops.backend import interpret_default
 from ditl_tpu.ops.flash_attention import NUM_LANES, _lane_tile
-from ditl_tpu.utils.compat import shard_map, tpu_compiler_params
 
 __all__ = ["paged_attention", "paged_attention_xla"]
 
@@ -392,7 +392,7 @@ def paged_attention(
                     k_scale=ks_, v_scale=vs_, interpret=interpret,
                 )
 
-            return shard_map(
+            return jax.shard_map(
                 local,
                 mesh=mesh,
                 in_specs=tuple(in_specs),
@@ -430,7 +430,7 @@ def paged_attention(
             "chunk's own KV lives in the tail buffer)"
         )
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_default()
 
     # (B, K, Q*G, D): one grid step's q block is ALL kv heads of one slot —
     # rows ordered query-major within a kv head (row = qi * G + g).
@@ -448,7 +448,7 @@ def paged_attention(
         pltpu.VMEM((g_rows, d), jnp.float32),  # acc
     ]
     out_shape = jax.ShapeDtypeStruct((b, kv_heads, qg_rows, d), q.dtype)
-    compiler_params = tpu_compiler_params(
+    compiler_params = pltpu.CompilerParams(
         dimension_semantics=("parallel", "arbitrary")
     )
 

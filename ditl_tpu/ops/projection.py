@@ -16,10 +16,10 @@ weights) and whose backward emits BOTH gradients from one Pallas kernel:
   both products.
 
 Selected per config via ``ModelConfig.proj_bwd_impl`` for the attention
-projections in models/llama.py; shapes the kernel cannot tile fall back to
-the einsum backward (the bench JSON records the implementation that actually
-ran). Off-TPU the kernel runs in interpret mode so numerics tests run on
-CPU. Mesh composition mirrors ops/attention.py's flash dispatch: Pallas
+projections in models/llama.py; shapes the kernel cannot tile raise on the
+TPU and give way to the einsum backward in interpret mode only
+(ops/backend.py). Off-TPU the kernel runs in interpret mode so numerics
+tests run on CPU. Mesh composition mirrors ops/attention.py's flash dispatch: Pallas
 calls carry no GSPMD partitioning rules, so under a mesh the op is
 shard_map'ed over the batch axes with replicated weights — shard_map's
 transpose inserts the weight-gradient psum.
@@ -35,7 +35,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ditl_tpu.utils.compat import shard_map, tpu_compiler_params
+from ditl_tpu.ops.backend import interpret_default, refuse_on_tpu
 
 __all__ = ["projection", "supports", "effective_bwd_impl", "DEFAULT_BLOCKS"]
 
@@ -52,10 +52,6 @@ class BlockSizes(NamedTuple):
 # (D=2048, F=4096 fused qkv): w tile 2 MB bf16 + (bd, F) f32 scratch 4 MB is
 # the ceiling term; ModelConfig.proj_bwd_block_{n,d} sweep it per chip.
 DEFAULT_BLOCKS = BlockSizes(256, 256)
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _pick_blocks(n: int, d: int, blocks) -> BlockSizes:
@@ -114,7 +110,7 @@ def _pallas_bwd(x, w, g, *, blocks, interpret):
     n = b * s
     bn, bd = _pick_blocks(n, d, blocks)
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     x2 = x.reshape(n, d)
     g2 = g.reshape(n, f)
     n_n, n_d = n // bn, d // bd
@@ -135,7 +131,7 @@ def _pallas_bwd(x, w, g, *, blocks, interpret):
             jax.ShapeDtypeStruct((d, f), w.dtype),
         ),
         scratch_shapes=[pltpu.VMEM((bd, f), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
@@ -157,10 +153,14 @@ def _proj_fwd(x, w, bwd_impl, blocks, interpret):
 
 def _proj_bwd(bwd_impl, blocks, interpret, res, g):
     x, w = res
-    if bwd_impl == "pallas" and supports(
-        x.shape[0] * x.shape[1], x.shape[2], w.shape[1], blocks
-    ):
-        return _pallas_bwd(x, w, g, blocks=blocks, interpret=interpret)
+    if bwd_impl == "pallas":
+        n, d, f = x.shape[0] * x.shape[1], x.shape[2], w.shape[1]
+        if supports(n, d, f, blocks):
+            return _pallas_bwd(x, w, g, blocks=blocks, interpret=interpret)
+        refuse_on_tpu(
+            "proj_bwd_impl='pallas'",
+            f"cannot tile N={n} D={d} F={f} (blocks={blocks})",
+        )
     dx = jnp.einsum("bsf,df->bsd", g, w).astype(x.dtype)
     dw = jnp.einsum("bsd,bsf->df", x, g).astype(w.dtype)
     return dx, dw
@@ -210,7 +210,7 @@ def projection(
     def local(x_, w_):
         return _proj(x_, w_, "pallas", tuple(blocks or ()), interpret)
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh, in_specs=(x_spec, w_spec), out_specs=x_spec,
         check_vma=False,
     )(x, w)

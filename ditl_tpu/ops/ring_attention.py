@@ -30,7 +30,6 @@ import jax
 import jax.numpy as jnp
 
 from ditl_tpu.ops.attention import NEG_INF
-from ditl_tpu.utils.compat import axis_size, shard_map
 
 __all__ = ["ring_attention"]
 
@@ -119,7 +118,7 @@ def _ring_attention_local(
     b, s_local, h, d = q.shape
     kv_heads = k.shape[2]
     groups = h // kv_heads
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     my = jax.lax.axis_index(axis_name)
 
     qg = (q.astype(jnp.float32) * d**-0.5).reshape(b, s_local, kv_heads, groups, d)
@@ -193,7 +192,7 @@ def ring_attention(
             q_, k_, v_, seg_, axis_name=axis_name, causal=causal
         )
 
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=tuple(in_specs),

@@ -26,7 +26,6 @@ including gradients through both all-to-alls.
 from __future__ import annotations
 
 import jax
-from ditl_tpu.utils.compat import shard_map
 
 __all__ = ["ulysses_attention"]
 
@@ -119,7 +118,7 @@ def ulysses_attention(
             out, axis_name, split_axis=1, concat_axis=2, tiled=True
         )
 
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=tuple(in_specs),

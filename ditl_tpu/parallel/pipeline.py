@@ -41,7 +41,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ditl_tpu.parallel.sharding import DEFAULT_RULES
-from ditl_tpu.utils.compat import shard_map
 
 __all__ = ["PIPELINE_RULES", "pipeline_rules", "pipeline_apply"]
 
@@ -145,7 +144,7 @@ def pipeline_apply(
         m=m,
         batch_axes=tuple(ax for ax in batch_ax if mesh.shape.get(ax, 1) > 1),
     )
-    out_mb, aux = shard_map(
+    out_mb, aux = jax.shard_map(
         stage_prog,
         mesh=mesh,
         in_specs=(param_specs, x_spec, extras_specs),

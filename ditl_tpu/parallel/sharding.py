@@ -88,15 +88,31 @@ def pallas_bwd_effective(bwd_impl: str, batch: int, seq: int, d: int, f: int,
     """The backward implementation a Pallas-seamed op will ACTUALLY run —
     the mesh gate above plus the op's own shape predicate on the per-shard
     token count. Shared by ops/mlp.py and ops/projection.py (and through
-    them bench.py's ``bwd_impl`` field) so the two seams cannot diverge."""
+    them bench.py's ``bwd_impl`` field) so the two seams cannot diverge.
+    Giving way to "xla" is for interpret mode only: on the TPU backend a
+    requested kernel that cannot run raises, naming the shape."""
     if bwd_impl != "pallas":
         return bwd_impl
+    from ditl_tpu.ops.backend import refuse_on_tpu
+
+    blocks = tuple(blocks or ())
+    shape = f"batch={batch} seq={seq} d={d} f={f} blocks={blocks}"
     shard = pallas_batch_shards(mesh, rules, batch)
     if shard is None:
+        refuse_on_tpu(
+            "the Pallas backward kernel",
+            f"mesh {dict(mesh.shape)} cannot host it (sequence/tensor "
+            f"parallelism, or batch not divisible by the batch axes) at "
+            f"{shape}",
+        )
         return "xla"
-    return "pallas" if supports_fn(
-        (batch // shard) * seq, d, f, tuple(blocks or ())
-    ) else "xla"
+    if not supports_fn((batch // shard) * seq, d, f, blocks):
+        refuse_on_tpu(
+            "the Pallas backward kernel",
+            f"cannot tile {shape} over {shard} batch shard(s)",
+        )
+        return "xla"
+    return "pallas"
 
 # logical axis -> mesh axis (or tuple of mesh axes, or None = replicated)
 DEFAULT_RULES: dict[str, Any] = {
@@ -157,6 +173,28 @@ def spec_tree(logical_tree: Any, rules: dict[str, Any] | None = None) -> Any:
     return jax.tree.map(
         lambda axes: logical_to_spec(axes, rules), logical_tree, is_leaf=is_axes_leaf
     )
+
+
+def placement(tree: Any) -> dict:
+    """Where a pytree of arrays actually lives, read from the arrays' own
+    shardings rather than from the mesh that was asked for: total bytes, and
+    the bytes each device holds (a replicated leaf counts once per device).
+    The trainer's summary and the server's /v1/stats carry it, so a caller
+    can tell a state spread over four chips from one parked on the first."""
+    per_device: dict[int, int] = {}
+    total = 0
+    for leaf in jax.tree.leaves(tree):
+        if not isinstance(leaf, jax.Array):
+            continue
+        total += leaf.nbytes
+        for shard in leaf.addressable_shards:
+            per_device[shard.device.id] = (
+                per_device.get(shard.device.id, 0) + shard.data.nbytes
+            )
+    return {
+        "bytes": total,
+        "per_device_bytes": {str(d): n for d, n in sorted(per_device.items())},
+    }
 
 
 def named_sharding_tree(mesh, logical_tree: Any, rules: dict[str, Any] | None = None):

@@ -49,53 +49,87 @@ def simulate_devices(n: int) -> None:
     ]
     parts.append(f"--xla_force_host_platform_device_count={n}")
     os.environ["XLA_FLAGS"] = " ".join(parts)
-    os.environ["JAX_NUM_CPU_DEVICES"] = str(n)  # newer-JAX equivalent
+    os.environ["JAX_NUM_CPU_DEVICES"] = str(n)
     os.environ["JAX_PLATFORMS"] = "cpu"
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", n)
-    except AttributeError:
-        # Older jax: no such option; the env settings above (applied before
-        # the first backend touch) carry the device count alone.
-        pass
+    jax.config.update("jax_num_cpu_devices", n)
 
 
-def enable_compile_cache(path: str, *, force: bool = False) -> bool:
-    """Point JAX's persistent compilation cache at ``path`` (idempotent;
-    VERDICT r5 item 9: compile+first-window is 85.6 s per session and pays
-    on every restart, drill, and bench run — the cache amortizes it to one
-    cold run per program).
+# The in-checkout default: found from the package's own location (never the
+# working directory), git-ignored, and fixed — a cache directory that moves
+# never hits, and one outside the checkout dies with a sealed machine.
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_compile_cache",
+)
 
-    The thresholds are dropped to zero so every program is cached — the
-    big training step is one entry; the small host-side programs cost
-    nothing. Returns True when the cache was enabled. On CPU the cache is
-    only honored for single-device processes unless ``force``: this
-    jaxlib's XLA:CPU intermittently aborts (SIGABRT) when deserializing
-    cached executables under the multi-device host platform (the 8-device
-    test sim — see tests/conftest.py and docs/troubleshooting.md §20)."""
-    if not path:
-        return False
+
+def enable_compile_cache() -> str | None:
+    """Turn on JAX's persistent compilation cache (idempotent) and return
+    its directory, or None when refused. The ONE place every program —
+    trainer, server, bench.py, chip_smoke.py's children — enables it.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, jax already reads it and
+    this helper sets no directory in code: the operator (or the machine the
+    program was copied onto) places the cache. Otherwise the cache lives at
+    :data:`DEFAULT_COMPILE_CACHE_DIR`. Either way the thresholds drop to
+    zero so every program is cached — the big training step is one entry;
+    the small host-side programs cost nothing.
+
+    Touches the backend (to ask whether it is a multi-device CPU), so it
+    must only run in the process that owns the device — never in a
+    supervisor parent. On CPU the cache is only honored for single-device,
+    single-process runs: XLA:CPU intermittently aborts (SIGABRT)
+    deserializing cached executables under the multi-device host platform
+    (the 8-device test sim — see tests/conftest.py and
+    docs/troubleshooting.md §20)."""
     import jax
 
-    if (not force and jax.default_backend() == "cpu"
+    if (jax.default_backend() == "cpu"
             and (jax.local_device_count() > 1 or jax.process_count() > 1)):
         logger.debug(
             "compile cache skipped: multi-device/multi-process CPU host "
-            "platform (known-bad executable deserialization in this jaxlib "
-            "— worker SIGSEGV/SIGABRT in the pod drills)"
+            "platform (known-bad executable deserialization — worker "
+            "SIGSEGV/SIGABRT in the pod drills)"
         )
-        return False
-    full = os.path.abspath(os.path.expanduser(path))
-    os.makedirs(full, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", full)
+        return None
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        os.makedirs(DEFAULT_COMPILE_CACHE_DIR, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", DEFAULT_COMPILE_CACHE_DIR)
     # Cache everything: the default thresholds skip exactly the small
     # programs whose re-compiles add up across drills and restarts.
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    logger.info("persistent compilation cache at %s", full)
-    return True
+    path = jax.config.jax_compilation_cache_dir
+    logger.info("persistent compilation cache at %s", path)
+    return path
+
+
+def device_summary() -> dict:
+    """What this process actually runs on, as jax reports it — stamped into
+    the trainer's summary JSON and the server's /v1/stats so a caller (and
+    chip_smoke.py, whose parent never touches jax) reads the device from
+    the program's own output instead of trusting a log line."""
+    from importlib.metadata import PackageNotFoundError, version
+
+    import jax
+    import jaxlib
+
+    try:
+        libtpu = version("libtpu")
+    except PackageNotFoundError:  # a CPU-only installation
+        libtpu = None
+    devices = jax.devices()
+    return {
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "device_count": len(devices),
+        "jax": jax.__version__,
+        "jaxlib": jaxlib.__version__,
+        "libtpu": libtpu,
+    }
 
 
 def init_runtime(config: RuntimeConfig | None = None) -> None:
@@ -135,9 +169,13 @@ def init_runtime(config: RuntimeConfig | None = None) -> None:
             process_id=config.process_id,
         )
         _active_coordinator = config.coordinator_address
+    # This process owns its devices: bring the backend up now, so that
+    # setup_logging's process-0 gate reads the real index (the logger itself
+    # never initialises a backend — utils/logging.py).
+    jax.devices()
     setup_logging(config.log_level)
-    if config.compile_cache_dir:
-        enable_compile_cache(config.compile_cache_dir)
+    if config.compile_cache:
+        enable_compile_cache()
     if config.profiler_port > 0 and jax.process_index() == 0:
         jax.profiler.start_server(config.profiler_port)
         logger.info("jax.profiler server on port %d", config.profiler_port)
@@ -147,7 +185,7 @@ def init_runtime(config: RuntimeConfig | None = None) -> None:
         jax.process_count(),
         jax.local_device_count(),
         jax.device_count(),
-        jax.devices()[0].platform,
+        device_summary(),
     )
     _initialized = True
 
@@ -158,7 +196,7 @@ def _enable_cpu_cross_process_collectives() -> None:
     ("Multiprocess computations aren't implemented on the CPU backend"), so
     any distributed CPU pod — the multi-process drills, or a CPU cluster —
     needs this set BEFORE the backend initializes. No-ops on TPU/GPU
-    platforms and on jax versions without the option."""
+    platforms."""
     import jax
 
     platforms = jax.config.jax_platforms or ""
@@ -167,10 +205,7 @@ def _enable_cpu_cross_process_collectives() -> None:
     # explicitly selected a non-CPU platform.
     if platforms and "cpu" not in platforms.split(","):
         return
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except (AttributeError, ValueError):
-        pass  # older jax (env/XLA flags decide) or gloo not compiled in
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
 
 def reinit_distributed(config: RuntimeConfig) -> None:

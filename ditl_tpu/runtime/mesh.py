@@ -31,7 +31,6 @@ AXIS_ORDER = ("data", "stage", "fsdp", "sequence", "expert", "tensor")
 def build_mesh(config: MeshConfig | None = None, devices=None) -> "jax.sharding.Mesh":
     """Build the global mesh from a MeshConfig (resolving any -1 axis)."""
     import jax
-    from jax.sharding import Mesh
 
     config = config or MeshConfig()
     if devices is None:
@@ -41,20 +40,14 @@ def build_mesh(config: MeshConfig | None = None, devices=None) -> "jax.sharding.
     shape = tuple(by_name[a] for a in AXIS_ORDER)
     # Auto axis types: GSPMD infers intermediate shardings from the constraints
     # we annotate (with_sharding_constraint / in_shardings), which is the
-    # propagation model this framework is designed around. Older jax has no
-    # AxisType at all — every axis is implicitly Auto there.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    kwargs = (
-        {"axis_types": (axis_type.Auto,) * len(AXIS_ORDER)}
-        if axis_type is not None
-        else {}
+    # propagation model this framework is designed around. make_mesh lays
+    # the axes out topology-aware on real TPU slices; a shape the topology
+    # cannot carry raises here rather than being reshaped into a mesh whose
+    # inner axes no longer ride adjacent chips.
+    mesh = jax.make_mesh(
+        shape, AXIS_ORDER, devices=devices,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(AXIS_ORDER),
     )
-    try:
-        # Topology-aware layout when available (real TPU slices).
-        mesh = jax.make_mesh(shape, AXIS_ORDER, devices=devices, **kwargs)
-    except (AttributeError, TypeError, ValueError):
-        device_grid = np.asarray(devices).reshape(shape)
-        mesh = Mesh(device_grid, AXIS_ORDER, **kwargs)
     logger.info("mesh: %s", dict(zip(AXIS_ORDER, shape)))
     return mesh
 

@@ -160,8 +160,6 @@ def compiled_cost(compiled: Any, n_steps: int = 1) -> dict | None:
         ca = compiled.cost_analysis()
     except Exception:  # noqa: BLE001 - backend-dependent, advisory only
         return None
-    if isinstance(ca, (list, tuple)):  # older jax: one dict per device
-        ca = ca[0] if ca else None
     if not isinstance(ca, Mapping):
         return None
     flops = float(ca.get("flops", 0.0))

@@ -465,9 +465,7 @@ class CheckpointManager:
         import jax
         import orbax.checkpoint as ocp
 
-        meta = reader.item_metadata(step)["state"]
-        # Orbax < 0.9 returns the metadata TREE directly; newer wraps it.
-        meta_tree = meta if isinstance(meta, dict) else meta.tree
+        meta_tree = reader.item_metadata(step)["state"].tree
         if "params" not in meta_tree:
             raise ValueError(
                 f"checkpoint step {step} in {self.directory} has no "
@@ -515,20 +513,9 @@ class CheckpointManager:
                         abstract_params,
                     )
                 }
-        try:
-            restore = ocp.args.PyTreeRestore(
-                item=abstract, restore_args=restore_args, partial_restore=True
-            )
-        except TypeError:
-            # Older orbax spells partial restore as "transforms={}": only the
-            # item's keys are read, everything else is dropped unread. That
-            # spelling requires explicit restore_args for every leaf.
-            restore = ocp.args.PyTreeRestore(
-                item=abstract,
-                restore_args=restore_args
-                or jax.tree.map(lambda _: ocp.RestoreArgs(), abstract),
-                transforms={},
-            )
+        restore = ocp.args.PyTreeRestore(
+            item=abstract, restore_args=restore_args, partial_restore=True
+        )
         restored = reader.restore(step, args=ocp.args.Composite(state=restore))
         logger.info("restored params (only) from checkpoint at step %d", step)
         return restored["state"]["params"]

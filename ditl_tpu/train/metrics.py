@@ -195,7 +195,7 @@ class MetricsLogger:
         }
 
     def summary(self) -> dict[str, float]:
-        """BASELINE.md numbers. p50 over steps after compile warm-up."""
+        """The headline numbers. p50 over steps after compile warm-up."""
         times = self.step_times[1:] if len(self.step_times) > 1 else self.step_times
         tps = self.tokens_per_sec_chip[1:] if len(self.tokens_per_sec_chip) > 1 else self.tokens_per_sec_chip
         out: dict[str, float] = {}

@@ -113,10 +113,9 @@ def _build_step_fn(
     def single_loss(params, batch):
         # Cast float32 master params to the compute dtype ONCE per step:
         # per-use casts inside the layers re-read the 4-byte masters at
-        # every matmul (fwd and bwd), costing ~2% step time at 350M on v5e.
-        # Gradients flow back through the cast (bf16 cotangents cast to
-        # f32), which is the precision the bf16 matmuls produced anyway —
-        # measured loss parity in BASELINE.md.
+        # every matmul (fwd and bwd). Gradients flow back through the cast
+        # (bf16 cotangents cast to f32), which is the precision the bf16
+        # matmuls produced anyway.
         cd = jnp.dtype(model_cfg.dtype)
         if cd != jnp.float32:
             def cast(path, p):
@@ -229,7 +228,7 @@ def make_multi_step(
     with zero host dispatch between steps.
 
     Host-side per-step dispatch is pure overhead on TPU (the device idles
-    while the host round-trips; tens of ms/step through remote transports).
+    while the host prepares and enqueues the next step).
     The reference's per-example host loop (ref
     ``src/distributed_inference.py:64-69``) is the extreme version of that
     anti-pattern. Input batches are stacked on a leading window dim

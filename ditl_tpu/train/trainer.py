@@ -26,10 +26,12 @@ from ditl_tpu.data.dataset import load_text_dataset
 from ditl_tpu.data.loader import DataPipeline
 from ditl_tpu.data.tokenizer import get_tokenizer
 from ditl_tpu.models import llama
-from ditl_tpu.parallel.sharding import named_sharding_tree
+from ditl_tpu.native import dataprep
+from ditl_tpu.parallel.sharding import named_sharding_tree, placement
 from ditl_tpu.runtime.consistency import check_cross_host_consistency
 from ditl_tpu.runtime.distributed import (
     barrier,
+    device_summary,
     init_runtime,
     is_coordinator,
     shutdown_runtime,
@@ -774,6 +776,13 @@ def train(config: Config) -> dict[str, Any]:
         summary["val_loss"] = last_val_loss
     summary["params_m"] = n_params / 1e6
     summary["wall_s"] = time.time() - t_start
+    # What this run actually ran on, as jax reports it, and whether the
+    # host data path was the C++ library or its Python fallback — a caller
+    # reads both from the summary instead of trusting a log line.
+    summary["device"] = device_summary()
+    summary["native_dataprep"] = dataprep.loaded()
+    # Parameters + optimizer state by device, from the arrays' shardings.
+    summary["state_placement"] = placement(state)
     # Goodput report: where the wall clock went, conservation-checked (the
     # tier-1 test asserts buckets + other sum to total within 1%).
     summary["goodput"] = tracker.report()

@@ -18,7 +18,7 @@ _handler: logging.Handler | None = None
 
 class _ProcessIndexFilter(logging.Filter):
     """Injects the JAX process index into every record (lazily — jax may not be
-    initialized when logging is configured)."""
+    initialized when logging is configured, and logging never initializes it)."""
 
     def filter(self, record: logging.LogRecord) -> bool:
         record.process_index = _process_index()
@@ -26,12 +26,20 @@ class _ProcessIndexFilter(logging.Filter):
 
 
 def _process_index() -> int:
-    try:
-        import jax
-
-        return jax.process_index()
-    except Exception:
+    """This process's index in the pod, WITHOUT ever initialising a JAX
+    backend. ``jax.process_index()`` asked cold initialises one, and a
+    process that has initialised a backend holds every chip it can see:
+    that is how ``launch gateway``'s parent once took the chips its own
+    replicas needed (found on the v5e, PR 21). Until a backend is up — and
+    supervisors and the gateway never bring one up — the index is 0."""
+    jax = sys.modules.get("jax")
+    if jax is None:
         return 0
+    from jax._src import xla_bridge
+
+    if not xla_bridge.backends_are_initialized():
+        return 0
+    return jax.process_index()
 
 
 def setup_logging(level: str = "INFO", all_processes: bool = False) -> None:

@@ -20,9 +20,9 @@ plus optional tile sweeps over mlp_bwd_block_* / proj_bwd_block_* (pass
 term, so block_d is the lever most likely to move.
 
 Decision rule (the VJP-null protocol): adopt into bench._model_cfg("1b3")
-only on step p50 <= ~545 ms (vs r5's 557.5 ms) across adjacent legs;
-otherwise record a kernel-level definitive null in BASELINE.md and leave
-the flags off. Every leg prints the EFFECTIVE backward impls
+only on a step p50 clearly below the parent's across adjacent legs
+(ROADMAP.md Speed 3 holds the bound); otherwise record a kernel-level
+definitive null in PERF.md and leave the flags off. Every leg prints the EFFECTIVE backward impls
 (bench._effective_bwd_impls) so a silent shape-fallback can never
 masquerade as a null.
 
@@ -65,7 +65,7 @@ def time_step_leg(name, cfg, mesh, tcfg, window, example, chunk, n_windows,
         state = create_train_state(jax.random.key(0), cfg, tcfg)
         multi = make_multi_step(cfg, tcfg, mesh, example, chunk)
         state, m = multi(state, make_global_batch(mesh, window(0)))
-        float(m["loss"][-1])  # full sync (remote transport)
+        float(m["loss"][-1])  # full host sync
         compile_s = time.perf_counter() - t0
         staged = [make_global_batch(mesh, window(w))
                   for w in range(1, n_windows + 1)]

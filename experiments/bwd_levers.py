@@ -1,8 +1,8 @@
 """Round-5 backward levers A/B on the pinned 1b3 config (follow-up to
-bwd_ablation.py). This script's leg list evolved with the round — the
-results of every configuration it ran are recorded in BASELINE.md's r5
-section (gu/di lever sweep, attn_out/inner saves, flash-tile and CE-block
-re-sweeps, the custom-VJP null). CURRENT legs (adjacent, one session):
+bwd_ablation.py). This script's leg list evolved with the round (gu/di
+lever sweep, attn_out/inner saves, flash-tile and CE-block re-sweeps, the
+custom-VJP null — builders' results from before this round, not
+re-measured). CURRENT legs (adjacent, one session):
 
   base         the ADOPTED pinned config (post-r5: fused_gate_up +
                remat="dots_inputs") — fresh anchor
@@ -50,7 +50,7 @@ def time_step_leg(name, cfg, mesh, tcfg, window, example, chunk, n_windows):
         state = create_train_state(jax.random.key(0), cfg, tcfg)
         multi = make_multi_step(cfg, tcfg, mesh, example, chunk)
         state, m = multi(state, make_global_batch(mesh, window(0)))
-        float(m["loss"][-1])  # full sync (remote transport)
+        float(m["loss"][-1])  # full host sync
         compile_s = time.perf_counter() - t0
         staged = [make_global_batch(mesh, window(w))
                   for w in range(1, n_windows + 1)]

@@ -12,9 +12,9 @@ initializes* (hence env mutation at conftest import time).
 import os
 
 # Must happen before JAX's backends initialize (first jax.devices() call).
-# Env vars alone are not enough when something (e.g. a site hook) imported jax
-# before pytest loaded this file — jax snapshots env into its config at import
-# — so set the config directly too.
+# Env vars alone are not enough when a pytest plugin imported jax before this
+# file loaded — jax snapshots env into its config at import — so set the
+# config directly too.
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["JAX_NUM_CPU_DEVICES"] = "8"
 flags = os.environ.get("XLA_FLAGS", "")
@@ -24,15 +24,10 @@ if "xla_force_host_platform_device_count" not in flags:
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", 8)
-except AttributeError:
-    # Older jax: the option doesn't exist; the XLA_FLAGS/env settings above
-    # (applied before the first backend touch) carry the device count alone.
-    pass
+jax.config.update("jax_num_cpu_devices", 8)
 
 # NOTE: jax_compilation_cache_dir was tried here to cut suite wall time and
-# reverted: this jaxlib's XLA:CPU intermittently aborts (SIGABRT) when
+# reverted: XLA:CPU intermittently aborts (SIGABRT) when
 # deserializing cached executables under the 8-device host platform. The
 # fast tier is provided by `-m "not slow"` (pytest.ini) instead.
 

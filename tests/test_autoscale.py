@@ -758,8 +758,7 @@ def test_replay_ab_autoscaler_saves_replica_seconds_at_same_slo():
     from ditl_tpu.telemetry.registry import LATENCY_BUCKETS_S
 
     trace = os.path.join(TRACES_DIR, "burst.jsonl")
-    kw = dict(n_replicas=3, slots=2, speed=1.5, compile_cache_dir="",
-              _model_overrides=_TINY)
+    kw = dict(n_replicas=3, slots=2, speed=1.5, _model_overrides=_TINY)
     off = run_trace_replay_bench(trace, autoscale=False, **kw)
     on = run_trace_replay_bench(
         trace, autoscale=True, min_replicas=2,

@@ -894,7 +894,7 @@ def test_bench_bulk_backlog_row_and_perf_gate():
     trace = os.path.join(TRACES_DIR, "burst.jsonl")
     row = run_trace_replay_bench(
         trace, n_replicas=2, slots=2, speed=1.5, autoscale=False,
-        compile_cache_dir="", bulk_backlog=24, _model_overrides=_TINY)
+        bulk_backlog=24, _model_overrides=_TINY)
     assert "bulk=24" in row["metric"]
     b = row["bulk"]
     assert b["backlog"] == 24

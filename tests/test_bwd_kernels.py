@@ -27,7 +27,7 @@ from ditl_tpu.config import MeshConfig, ModelConfig
 from ditl_tpu.models import llama
 from ditl_tpu.ops import mlp_bwd
 from ditl_tpu.ops import projection as projmod
-from ditl_tpu.ops.mlp import mlp_block, mlp_gu
+from ditl_tpu.ops.mlp import effective_bwd_impl, mlp_block, mlp_gu
 from ditl_tpu.runtime.mesh import build_mesh
 from ditl_tpu.train.step import loss_fn
 
@@ -125,6 +125,25 @@ def test_mlp_gu_pallas_falls_back_on_untileable_shapes(tensors):
 
     np.testing.assert_allclose(np.asarray(f("pallas")), np.asarray(f("xla")),
                                rtol=1e-5, atol=1e-6)
+
+
+def test_untileable_bwd_kernels_raise_on_tpu_backend(tensors, monkeypatch):
+    """The give-way above is for interpret mode only: on the TPU backend a
+    requested backward kernel that cannot run raises, naming the shape —
+    at the dispatch (effective_bwd_impl) and inside both VJPs."""
+    h, w_gu, w_down, _ = tensors
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match=r"cannot tile.*f=96"):
+        effective_bwd_impl("pallas", B, S, D, 96, ())
+    with pytest.raises(ValueError, match=r"cannot tile.*d=200"):
+        projmod.effective_bwd_impl("pallas", B, S, 200, 2 * F, PROJ_BLOCKS)
+    with pytest.raises(ValueError, match=r"mlp_bwd_impl='pallas'.*F=96"):
+        jax.grad(lambda h: jnp.sum(mlp_gu(
+            _identity, h, w_gu[:, : 2 * 96], w_down[:96], "pallas", ()) ** 2))(h)
+    w_odd = jax.random.normal(jax.random.key(3), (D, 96)) * 0.05
+    with pytest.raises(ValueError, match=r"proj_bwd_impl='pallas'.*F=96"):
+        jax.grad(lambda x: jnp.sum(
+            projmod._proj(x, w_odd, "pallas", (), None) ** 2))(h)
 
 
 def test_projection_pallas_matches_autodiff(tensors):

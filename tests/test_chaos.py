@@ -673,11 +673,11 @@ def test_chaos_kill_mid_save_resumes_from_verified_step(tmp_path):
     telemetry_dir = tmp_path / "telemetry"
     cmd = [
         sys.executable, "-m", "ditl_tpu.launch", "--supervise",
-        # No persistent compile cache: this jaxlib intermittently SIGSEGVs
+        # No persistent compile cache: XLA:CPU intermittently SIGSEGVs
         # deserializing cached executables in a relaunched process
         # (troubleshooting §20) — that known crash must not alias the
         # fault this drill injects on purpose.
-        "runtime.compile_cache_dir=",
+        "runtime.compile_cache=false",
         "data.synthetic=true", "data.batch_size=4", "data.seq_len=32",
         "train.total_steps=8", "train.checkpoint_every=2",
         "train.max_restarts=1", "train.log_every=1", "train.warmup_steps=1",

@@ -485,7 +485,6 @@ def test_disagg_fleet_beats_homogeneous_on_mixed_trace(tmp_path):
     kw = dict(
         slots=2, decode_chunk=2, prompt_len=8, max_new=16,
         prefill_chunk=0, token_budget=0,  # unchunked/unbudgeted A/B legs
-        compile_cache_dir="",
         mixed_trace=True,
         _model_overrides=dict(hidden_size=128, intermediate_size=344,
                               num_heads=4, num_kv_heads=2, head_dim=32,

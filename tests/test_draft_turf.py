@@ -5,8 +5,8 @@ PREDICTABLE but not self-repeating: novel affine-chain trajectories share
 almost no verbatim n-grams, so prompt-lookup has nothing to draft from,
 while a draft model trained on the same domain keeps agreeing with the
 target. This test trains tiny target+drafter pairs on the chain and pins
-the acceptance split the TPU benchmark measures at full scale
-(BASELINE.md r4: lookup 1.03 -> auto-disables, drafter 7.11 -> 2.09x)."""
+the acceptance split the TPU benchmark measures at full scale (lookup
+auto-disables, the drafter keeps accepting)."""
 
 import jax
 import jax.numpy as jnp

@@ -106,6 +106,21 @@ def test_supports_gate():
     assert not supports(256, 256, 100)  # bad head dim
 
 
+def test_untileable_flash_gives_way_in_interpret_mode_and_raises_on_tpu(monkeypatch):
+    """A requested kernel that cannot run: in interpret mode (this CPU tier)
+    the dispatch gives way to XLA; on the TPU backend it is an error naming
+    the shape, never a silent substitution (ops/backend.py)."""
+    from ditl_tpu.ops.attention import dot_product_attention
+
+    q, k, v = _make_qkv(jax.random.key(7), 1, 100, 4, 2, 64)
+    out = dot_product_attention(q, k, v, impl="flash")
+    ref = _xla_attention(q, k, v, causal=True, segment_ids=None)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match=r"attention_impl='flash'.*Sq=100 Skv=100 D=64"):
+        dot_product_attention(q, k, v, impl="flash")
+
+
 def test_bf16_forward_close():
     q, k, v = _make_qkv(jax.random.key(4), 1, 256, 4, 2, 64, dtype=jnp.bfloat16)
     ref = _xla_attention(q, k, v, causal=True, segment_ids=None)

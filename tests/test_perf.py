@@ -443,7 +443,7 @@ def test_bench_sweep_smoke_and_regression_gate(tmp_path):
     out = str(tmp_path / "sweep.json")
     cmd = [
         sys.executable, os.path.join(REPO, "bench.py"),
-        "--model", "350m", "--compile-cache-dir", "",
+        "--model", "350m", "--no-compile-cache",
         "--sweep", "loss_block_tokens=256,512", "--sweep-out", out,
     ]
     r = subprocess.run(cmd, env=_bench_env(), capture_output=True, text=True,
@@ -489,7 +489,7 @@ def test_bench_sweep_smoke_and_regression_gate(tmp_path):
     # reuse the other config's numbers (cell keys name only swept knobs)
     mismatched = [
         sys.executable, os.path.join(REPO, "bench.py"),
-        "--model", "1b3", "--compile-cache-dir", "",
+        "--model", "1b3", "--no-compile-cache",
         "--sweep", "loss_block_tokens=256,512", "--sweep-out", out,
     ]
     r4 = subprocess.run(mismatched, env=_bench_env(), capture_output=True,

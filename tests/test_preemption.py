@@ -237,11 +237,11 @@ def test_preemption_resume_with_draft_model_spec(setup):
         if breq is not None and breq.req_id == rb and len(breq.tokens) >= 20:
             break
     eng._preempt_slot(1)
-    held = eng._queue.popleft()  # park b so it cannot resume into slot 1
+    held = eng._queue.pop(0)  # park b so it cannot resume into slot 1
     while any(r is not None for r in eng._slots):
         eng.step()  # drive a to completion; slot 0 frees
     pre_t, pre_f = held.spec_tokens, held.spec_forwards
-    eng._queue.appendleft(held)
+    eng._queue.insert(0, held)
     eng.step()
     assert eng._slots[0] is held  # resumed into the foreign slot
     res = eng.run()

@@ -144,7 +144,7 @@ def test_engine_seq_sharded_int8_kv(setup, seq_mesh):
 
 @pytest.mark.slow
 def test_paged_pools_replicate_over_sequence_axis(setup, seq_mesh, caplog):
-    """The written decision (BASELINE.md r4): paged pools do NOT shard on
+    """The written decision: paged pools do NOT shard on
     the sequence axis — they replicate (correct output, warned loudly),
     because the axis's regime (contexts beyond one chip's HBM, concurrency
     of a few) is exactly where paged capacity-sharing buys nothing. The
