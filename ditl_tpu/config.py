@@ -1036,7 +1036,7 @@ class TelemetryConfig:
 
     # Per-process JSONL journal rotation cap in MiB (0 = unbounded, the
     # historical behavior). With tracing armed, span records arrive per
-    # request and tick instants per scheduler tick — a long serving run
+    # request and tick spans per scheduler tick — a long serving run
     # must not grow its journal without bound. Total footprint stays
     # ~this cap (telemetry/journal.py keeps the newest segments only).
     journal_max_mb: float = 0.0

@@ -87,6 +87,7 @@ def _scatter_rows(cache: jax.Array, chunk: jax.Array, idx: jax.Array) -> jax.Arr
     return jnp.where(in_chunk.reshape(in_chunk.shape + tail), gathered, cache)
 
 
+@jax.named_scope("kv_write")
 def scatter_tail(tail: jax.Array, chunk: jax.Array, off: jax.Array) -> jax.Array:
     """Write ``chunk`` (B, K, S, D) into the decode tail buffer ``tail``
     (B, K, T, D) at per-row column offsets ``off`` (B,) — the speculative
@@ -115,6 +116,7 @@ def _write_one(cache: jax.Array, chunk: jax.Array, idx: jax.Array) -> jax.Array:
     )
 
 
+@jax.named_scope("kv_write")
 def write_kv(layer_cache: dict, k: jax.Array, v: jax.Array, idx: jax.Array) -> dict:
     """Write a (B, S, K, D) K/V chunk into one layer's cache slice at slot
     ``idx`` — scalar (lock-step decode: every row at the same depth) or (B,)
@@ -135,6 +137,7 @@ def write_kv(layer_cache: dict, k: jax.Array, v: jax.Array, idx: jax.Array) -> d
     return out
 
 
+@jax.named_scope("kv_gather")
 def read_kv(layer_cache: dict, dtype) -> tuple[jax.Array, jax.Array]:
     """One layer's full (B, Smax, K, D) K/V in the compute dtype; dequantizes
     int8 caches (XLA fuses the convert+scale into the attention matmul's

@@ -138,6 +138,7 @@ def _fsm_next(ftab: jax.Array, fstate: jax.Array, tok: jax.Array) -> jax.Array:
     return jnp.where(nxt >= 0, nxt, 1)
 
 
+@jax.named_scope("kv_write")
 def _flush_tail_into_pools(pools, tk, tv, starts, pos, table, ps, tail_len):
     """Scatter the tick's tail columns into their pages — ONE scatter per
     pool per tick (amortized over the chunk; per-token in-scan page writes
@@ -481,6 +482,10 @@ class ContinuousEngine:
         # host-only bookkeeping and never touches replicated scheduler
         # state, so pod replicas may disagree about it freely.
         self.tracer = tracer if tracer is not None else NULL_TRACER
+        # The open ``engine.tick`` span of the step() in progress and its
+        # open phase span (armed tracer only; see _phase).
+        self._tick_span = None
+        self._phase_span = None
         # Flight recorder (ISSUE 10): always-on bounded ring of per-tick
         # scheduler snapshots — budget spend, queue-by-class, slot
         # occupancy — recorded as one host dict append per tick and read
@@ -1023,8 +1028,8 @@ class ContinuousEngine:
     def _build_prefill(self, p_bucket: int):
         cfg, smax = self.cfg, self.smax
 
-        def run(params, cache, ids, length, slot, temp, top_p, rng, aid,
-                *fsm):
+        def prefill(params, cache, ids, length, slot, temp, top_p, rng, aid,
+                    *fsm):
             # 1-row view of the shared cache: prefill never touches other slots.
             row = jax.tree.map(
                 lambda c: jax.lax.dynamic_slice_in_dim(c, slot, 1, axis=1), cache
@@ -1063,7 +1068,7 @@ class ContinuousEngine:
                 return (cache, first, c[0], i[0], t[0], *fs)
             return (cache, first, *fs)
 
-        return jax.jit(run, donate_argnums=(1,))
+        return jax.jit(prefill, donate_argnums=(1,))
 
     def _build_decode(self, sampled: bool, topp: bool):
         """One decode program per (any-slot-sampled, any-top-p) combination:
@@ -1079,8 +1084,8 @@ class ContinuousEngine:
 
         guided = self.guided
 
-        def run(params, cache, cur, pos, alive, temps, top_ps, keys, hist,
-                adapters, *extra):
+        def decode(params, cache, cur, pos, alive, temps, top_ps, keys, hist,
+                   adapters, *extra):
             ftab, fstates = (extra[0], extra[1]) if guided else (None, None)
             lp0 = extra[2:] if guided else extra
 
@@ -1143,7 +1148,7 @@ class ContinuousEngine:
                         c.T, jnp.swapaxes(i, 0, 1), jnp.swapaxes(t, 0, 1))
             return (cache, cur, pos, keys, hist, *fs, ys.T)  # ys: (chunk, B)
 
-        return jax.jit(run, donate_argnums=(1,))
+        return jax.jit(decode, donate_argnums=(1,))
 
     def _build_draft_prefill(self, p_bucket: int):
         """Prefill one slot of the DRAFT model's cache with the prompt.
@@ -1153,7 +1158,7 @@ class ContinuousEngine:
         reuse, chunked main prefill) don't apply to its private cache."""
         dcfg = self.draft_cfg
 
-        def run(dparams, dcache, ids, length, slot):
+        def draft_prefill(dparams, dcache, ids, length, slot):
             row = jax.tree.map(
                 lambda c: jax.lax.dynamic_slice_in_dim(c, slot, 1, axis=1),
                 dcache,
@@ -1173,7 +1178,7 @@ class ContinuousEngine:
                 row,
             )
 
-        return jax.jit(run, donate_argnums=(1,))
+        return jax.jit(draft_prefill, donate_argnums=(1,))
 
     def _build_draft_suffix_prefill(self, s_bucket: int):
         """Suffix continuation of the draft cache at an offset — the
@@ -1184,7 +1189,7 @@ class ContinuousEngine:
         dcfg = self.draft_cfg
         slots_iota = jnp.arange(self.smax, dtype=jnp.int32)
 
-        def run(dparams, dcache, ids, offset, slot):
+        def draft_suffix_prefill(dparams, dcache, ids, offset, slot):
             row = jax.tree.map(
                 lambda c: jax.lax.dynamic_slice_in_dim(c, slot, 1, axis=1),
                 dcache,
@@ -1204,7 +1209,7 @@ class ContinuousEngine:
                 row,
             )
 
-        return jax.jit(run, donate_argnums=(1,))
+        return jax.jit(draft_suffix_prefill, donate_argnums=(1,))
 
     def _draft_prefill(self, req: Request, slot: int,
                        ctx: list[int] | None = None) -> None:
@@ -1373,8 +1378,8 @@ class ContinuousEngine:
         guided = self.guided
         model_draft = self.spec_draft == "model"
 
-        def run(params, cache, cur, pos, alive, hist, temps, top_ps, keys,
-                adapters, *extra):
+        def spec_decode(params, cache, cur, pos, alive, hist, temps, top_ps,
+                        keys, adapters, *extra):
             i = 0
             dparams = dcache0 = None
             if model_draft:
@@ -1492,7 +1497,7 @@ class ContinuousEngine:
                     lp, bufs)
 
         donate = (1, 11) if model_draft else (1,)
-        return jax.jit(run, donate_argnums=donate)
+        return jax.jit(spec_decode, donate_argnums=donate)
 
     # -- prefix caching ------------------------------------------------------
 
@@ -1502,7 +1507,7 @@ class ContinuousEngine:
         exactly the prefix)."""
         cfg = self.cfg
 
-        def run(params, ids, length):
+        def prefix_prefill(params, ids, length):
             row = init_cache(cfg, 1, p_bucket)
             q_pos = jnp.arange(p_bucket, dtype=jnp.int32)
             slots = jnp.arange(p_bucket, dtype=jnp.int32)
@@ -1516,13 +1521,13 @@ class ContinuousEngine:
             )
             return row, logits[0, length - 1]
 
-        return jax.jit(run)
+        return jax.jit(prefix_prefill)
 
     def _build_seed(self, p_bucket: int):
         """Copy a registered prefix's KV slice into one slot of the shared
         cache (slots 0..p_bucket of the slot's sequence axis)."""
 
-        def run(cache, row, slot):
+        def seed(cache, row, slot):
             return jax.tree.map(
                 lambda c, r: jax.lax.dynamic_update_slice(
                     c, r.astype(c.dtype), (0, slot, 0) + (0,) * (c.ndim - 3)
@@ -1531,7 +1536,7 @@ class ContinuousEngine:
                 row,
             )
 
-        return jax.jit(run, donate_argnums=(0,))
+        return jax.jit(seed, donate_argnums=(0,))
 
     def _build_suffix_prefill(self, s_bucket: int):
         """Prefill only the suffix of a prompt whose first ``offset`` tokens
@@ -1541,8 +1546,8 @@ class ContinuousEngine:
         cfg, smax = self.cfg, self.smax
         slots_iota = jnp.arange(smax, dtype=jnp.int32)
 
-        def run(params, cache, ids, offset, s_len, slot, temp, top_p, rng,
-                aid, *fsm):
+        def suffix_prefill(params, cache, ids, offset, s_len, slot, temp,
+                           top_p, rng, aid, *fsm):
             row = jax.tree.map(
                 lambda c: jax.lax.dynamic_slice_in_dim(c, slot, 1, axis=1), cache
             )
@@ -1571,7 +1576,7 @@ class ContinuousEngine:
                 return (cache, first, c[0], i[0], t[0], *fs)
             return (cache, first, *fs)
 
-        return jax.jit(run, donate_argnums=(1,))
+        return jax.jit(suffix_prefill, donate_argnums=(1,))
 
     # -- paged programs ------------------------------------------------------
 
@@ -1599,8 +1604,8 @@ class ContinuousEngine:
         cd = jnp.dtype(cfg.dtype)
         quantized = cfg.kv_cache_dtype == "int8"
 
-        def run(params, pools, table_row, ids, offset, s_len, temp, top_p,
-                rng, write_pids, aid, *fsm):
+        def paged_prefill(params, pools, table_row, ids, offset, s_len, temp,
+                          top_p, rng, write_pids, aid, *fsm):
             kp, vp = pools["kp"], pools["vp"]
             L, _, K, _, D = kp.shape
 
@@ -1615,8 +1620,9 @@ class ContinuousEngine:
                 g = jnp.swapaxes(g, 2, 3)
                 return g.reshape(L, 1, maxp * ps, K, D)
 
-            ctx_k = to_row(kp, pools.get("ks"))
-            ctx_v = to_row(vp, pools.get("vs"))
+            with jax.named_scope("kv_gather"):
+                ctx_k = to_row(kp, pools.get("ks"))
+                ctx_v = to_row(vp, pools.get("vs"))
             zeros = jnp.zeros((L, 1, s_bucket, K, D), ctx_k.dtype)
             row = {
                 "k": jnp.concatenate([ctx_k, zeros], axis=2),
@@ -1646,29 +1652,30 @@ class ContinuousEngine:
                 chunk = jax.lax.dynamic_slice_in_dim(r, offset, s_bucket, axis=2)
                 return jnp.swapaxes(chunk.reshape(L, n_wp, ps, K, D), 2, 3)
 
-            chunk_k, chunk_v = to_pages(row["k"]), to_pages(row["v"])
-            out = dict(pools)
-            if quantized:
-                for name, sname, chunk in (("kp", "ks", chunk_k),
-                                           ("vp", "vs", chunk_v)):
-                    vals, sc = _quantize_pages(chunk)
-                    pool, spool = out[name], out[sname]
-                    for j in range(n_wp):
-                        pool = jax.lax.dynamic_update_slice(
-                            pool, vals[:, j:j + 1], (0, write_pids[j], 0, 0, 0)
-                        )
-                        spool = jax.lax.dynamic_update_slice(
-                            spool, sc[:, j:j + 1], (0, write_pids[j], 0, 0, 0)
-                        )
-                    out[name], out[sname] = pool, spool
-            else:
-                for name, chunk in (("kp", chunk_k), ("vp", chunk_v)):
-                    pool = out[name]
-                    for j in range(n_wp):
-                        pool = jax.lax.dynamic_update_slice(
-                            pool, chunk[:, j:j + 1], (0, write_pids[j], 0, 0, 0)
-                        )
-                    out[name] = pool
+            with jax.named_scope("kv_write"):
+                chunk_k, chunk_v = to_pages(row["k"]), to_pages(row["v"])
+                out = dict(pools)
+                if quantized:
+                    for name, sname, chunk in (("kp", "ks", chunk_k),
+                                               ("vp", "vs", chunk_v)):
+                        vals, sc = _quantize_pages(chunk)
+                        pool, spool = out[name], out[sname]
+                        for j in range(n_wp):
+                            pool = jax.lax.dynamic_update_slice(
+                                pool, vals[:, j:j + 1], (0, write_pids[j], 0, 0, 0)
+                            )
+                            spool = jax.lax.dynamic_update_slice(
+                                spool, sc[:, j:j + 1], (0, write_pids[j], 0, 0, 0)
+                            )
+                        out[name], out[sname] = pool, spool
+                else:
+                    for name, chunk in (("kp", chunk_k), ("vp", chunk_v)):
+                        pool = out[name]
+                        for j in range(n_wp):
+                            pool = jax.lax.dynamic_update_slice(
+                                pool, chunk[:, j:j + 1], (0, write_pids[j], 0, 0, 0)
+                            )
+                        out[name] = pool
             last = logits[0, s_len - 1]
             masked = _fsm_mask(fsm[0], fsm[1], last) if self.guided else last
             first = sample_logits(
@@ -1681,7 +1688,7 @@ class ContinuousEngine:
                 return (out, first, c[0], i[0], t[0], *fs)
             return (out, first, *fs)
 
-        return jax.jit(run, donate_argnums=(1,))
+        return jax.jit(paged_prefill, donate_argnums=(1,))
 
     def _build_paged_decode(self, sampled: bool, topp: bool):
         """Paged decode tick with DEFERRED page writes: the chunk's K/V
@@ -1703,8 +1710,8 @@ class ContinuousEngine:
 
         guided = self.guided
 
-        def run(params, pools, cur, pos, alive, temps, top_ps, keys, table,
-                limits, hist, adapters, *extra):
+        def paged_decode(params, pools, cur, pos, alive, temps, top_ps, keys,
+                         table, limits, hist, adapters, *extra):
             ftab, fstates = (extra[0], extra[1]) if guided else (None, None)
             lp0 = extra[2:] if guided else extra
             n_b = pos.shape[0]
@@ -1783,7 +1790,7 @@ class ContinuousEngine:
                         c.T, jnp.swapaxes(i, 0, 1), jnp.swapaxes(t, 0, 1))
             return (out, cur, pos, keys, hist, *fs, ys.T)
 
-        return jax.jit(run, donate_argnums=(1,))
+        return jax.jit(paged_decode, donate_argnums=(1,))
 
     def _build_spec_paged_decode(self, sampled: bool = False):
         """Speculative decode tick, paged cache: same round structure as the
@@ -1813,8 +1820,8 @@ class ContinuousEngine:
         guided = self.guided
         model_draft = self.spec_draft == "model"
 
-        def run(params, pools, cur, pos, alive, table, limits, hist, temps,
-                top_ps, keys, adapters, *extra):
+        def spec_paged_decode(params, pools, cur, pos, alive, table, limits,
+                              hist, temps, top_ps, keys, adapters, *extra):
             i = 0
             dparams = dcache0 = None
             if model_draft:
@@ -1934,7 +1941,7 @@ class ContinuousEngine:
                     rr, lp, bufs)
 
         donate = (1, 13) if model_draft else (1,)
-        return jax.jit(run, donate_argnums=donate)
+        return jax.jit(spec_paged_decode, donate_argnums=donate)
 
     def register_prefix(self, prefix_tokens: list[int]) -> None:
         """Prefill ``prefix_tokens`` once and reuse the KV for every future
@@ -2948,10 +2955,12 @@ class ContinuousEngine:
             self.cur = self.cur.at[slot].set(self.tokenizer.pad_id)
             self.pos = self.pos.at[slot].set(0)
         else:
+            was = self._phase("engine.tick.prefill")
             w0, m0 = time.time(), time.monotonic()
             first = self._paged_prefill_chunk(req, slot, d0, s, s, sub)
             self._record_prefill(req, s, d0, w0,
                                  time.monotonic() - m0, "prompt")
+            self._phase(was)
             self._publish_prompt_pages(req, slot)
             self.cur = self.cur.at[slot].set(first)
             self.pos = self.pos.at[slot].set(len(req.prompt))
@@ -3026,6 +3035,7 @@ class ContinuousEngine:
         req.resume_tokens += pos - d0  # per-request thrash for the ledger
         step = self.prefill_chunk or s
         d = d0
+        was = self._phase("engine.tick.prefill")
         w0, m0 = time.time(), time.monotonic()
         while d < pos:
             n = min(step, pos - d)
@@ -3043,6 +3053,7 @@ class ContinuousEngine:
             # they must show up in the interference attribution too.
             self._record_prefill(req, pos - d0, d0, w0,
                                  time.monotonic() - m0, "resume")
+        self._phase(was)
         self.cur = self.cur.at[slot].set(req.preempt_cur)
         self.pos = self.pos.at[slot].set(pos)
         self.keys = self.keys.at[slot].set(req.preempt_key)
@@ -3513,8 +3524,10 @@ class ContinuousEngine:
             slot_key = jax.random.key(req.seed)
             slot_key, sub = jax.random.split(slot_key)
             req.slot = slot
+            was = self._phase("engine.tick.prefill")
             w0, m0 = time.time(), time.monotonic()
             first = self._prefill_into_slot(req, slot, sub, prefix)
+            self._phase(was)
             if first is not None:
                 # Chunked prefill (first is None) records per chunk in
                 # step()'s advance loop instead. Tokens = the suffix the
@@ -3555,12 +3568,14 @@ class ContinuousEngine:
             if not self._budget_allows(cost):
                 continue
             d_before = req.prefill_pos
+            was = self._phase("engine.tick.prefill")
             w0, m0 = time.time(), time.monotonic()
             self._advance_prefill(req)
             self._record_prefill(
                 req, req.prefill_pos - d_before, d_before, w0,
                 time.monotonic() - m0, "chunk",
             )
+            self._phase(was)
 
     def _snapshot_slots(self) -> list[tuple[Request | None, bool]]:
         """(request, was_prefilling) per slot AT DISPATCH TIME — pipelined
@@ -3939,6 +3954,7 @@ class ContinuousEngine:
         # ONE device_get for every host-consumed output: each separate fetch
         # blocks the host on its own device→host transfer — three
         # sequential fetches per tick serialize three waits into the tick.
+        self._phase("engine.tick.fetch")
         if lp_bufs is not None:
             counts, rr, toks, lp = jax.device_get(
                 (counts, rr, toks, lp_bufs)
@@ -3950,6 +3966,7 @@ class ContinuousEngine:
                 np.asarray(x) for x in jax.device_get((counts, rr, toks))
             )
             lp = None
+        self._phase("engine.tick.harvest")
         if not self.pipeline_ticks or self._probe_timing:
             # Pipelined intervals measure the pipeline period (dispatch to
             # NEXT-step fetch, including foreign host work), not device
@@ -4043,6 +4060,7 @@ class ContinuousEngine:
         import time as _time
 
         (_, key, t0, toks, lp_dev, snapshot) = rec
+        self._phase("engine.tick.fetch")
         if lp_dev is not None:
             # One fetch for everything (see _spec_finish).
             toks, *lp_np = jax.device_get((toks, *lp_dev))
@@ -4051,6 +4069,7 @@ class ContinuousEngine:
         else:
             lp = None
             toks = np.asarray(jax.device_get(toks))
+        self._phase("engine.tick.harvest")
         if self.speculative and (not self.pipeline_ticks or self._probe_timing):
             # See _spec_finish: pipelined intervals are not device cost,
             # but serial probe-tick intervals are.
@@ -4091,10 +4110,52 @@ class ContinuousEngine:
         tick; a finished request's slot decodes one dead chunk before being
         freed (masked out by the harvest snapshot). Token streams are
         identical to serial ticks — per-slot RNG derives from the request
-        seed, never from tick alignment."""
+        seed, never from tick alignment.
+
+        An armed tracer gets one ``engine.tick`` span per call (tick number,
+        slot occupancy, queue depth, prefill seconds: the scheduler cadence)
+        and, as its children, what the engine thread did in it:
+        ``engine.tick.schedule``
+        (deadlines, admission, page top-up), ``.prefill`` (this tick's
+        prefill chunks), ``.dispatch`` (the decode program's enqueue),
+        ``.fetch`` (the tick's one ``device_get``), ``.harvest``
+        (bookkeeping and stream writes) and ``.spill``. They are the
+        shortest host spans over a device idle gap, so a trace reducer
+        labels the gap with what this thread was doing."""
+        self.tick_count += 1
+        if not self.tracer.armed:
+            return self._tick()
+        self._tick_span = self.tracer.start_span(
+            "engine.tick", tick=self.tick_count
+        )
+        try:
+            self._tick()
+        finally:
+            self._phase(None)
+            self._tick_span.end()
+            self._tick_span = None
+
+    def _phase(self, name: str | None) -> str | None:
+        """The engine thread moves on to ``name``: the tick's open phase
+        span ends and a span ``name`` opens as a child of the tick span
+        (None: nothing opens). At most one is open, so a tick's phases never
+        overlap. Returns the phase that was open, for a caller that goes
+        back to it. Outside an armed tracer's tick: nothing, at once."""
+        tick = self._tick_span
+        if tick is None:
+            return None
+        was = self._phase_span
+        if was is not None:
+            was.end()
+        self._phase_span = None if name is None else self.tracer.start_span(
+            name, parent=tick, tick=self.tick_count
+        )
+        return None if was is None else was.name
+
+    @hot_path
+    def _tick(self) -> None:
         # Chaos seam: `delay`/`hang` stall the scheduler (TTFT/stall
         # drills); `error` surfaces through the driver as an engine death.
-        self.tick_count += 1
         maybe_inject("engine.tick", step=self.tick_count)
         prev, self._pending_fetch = self._pending_fetch, None
         probe = self._serial_probe_due()
@@ -4103,6 +4164,7 @@ class ContinuousEngine:
             # interval times a quiet device, not the tail of tick N.
             self._finish_tick(prev)
             prev = None
+        self._phase("engine.tick.schedule")
         self._expire_deadlines()
         # Interference attribution (ISSUE 6): requests that were ALREADY
         # decode-ready before this tick's admissions and prefill chunks are
@@ -4168,10 +4230,9 @@ class ContinuousEngine:
                 victim.interference_pending.append(
                     (culprit_id, culprit_tokens, prefill_s)
                 )
-        if self.tracer.armed:
-            self.tracer.instant(
-                "engine.tick",
-                tick=self.tick_count,
+        if self._tick_span is not None:
+            # The scheduler's state after admission, written with the span.
+            self._tick_span.annotate(
                 slots_busy=sum(r is not None for r in self._slots),
                 prefilling=sum(
                     1 for r in self._slots
@@ -4199,6 +4260,7 @@ class ContinuousEngine:
                 use_spec = self._spec_round_ms is None
             else:
                 use_spec = self._use_spec_tick(active)
+            self._phase("engine.tick.dispatch")
             if use_spec:
                 rec = self._spec_dispatch(alive, sampled)
             else:
@@ -4220,7 +4282,9 @@ class ContinuousEngine:
             # Host-tier spill batch (ISSUE 13): the tick's evicted pages
             # move to host RAM in one batched fetch, AFTER dispatch/harvest
             # so the transfer overlaps nothing on the dispatch stream.
+            self._phase("engine.tick.spill")
             self._process_spills()
+        self._phase(None)
         # Flight recorder (ISSUE 10): one host-dict row per tick into the
         # bounded ring — the black box an incident bundle dumps. Host state
         # only (no device sync); counters are the cumulative values the
@@ -4541,14 +4605,28 @@ class ThreadedEngine:
         return eng.max_queue is not None and len(eng._queue) >= eng.max_queue
 
     def _drive(self) -> None:
+        tracer = self._engine.tracer
         while True:
+            idle_t0 = None
             with self._cond:
                 while (not self._stop and self._engine.pending == 0
                        and not self._calls):
+                    if idle_t0 is None and tracer.armed:
+                        idle_t0 = time.time()
                     self._cond.wait(timeout=0.05)
-                if self._stop:
+                idle_t1 = time.time() if idle_t0 is not None else None
+                stop = self._stop
+                if stop:
                     self._cond.notify_all()
-                    return
+            if idle_t0 is not None:
+                # Nothing was pending: the wait between ticks, so that a
+                # device gap under it reads "idle", not a request's span.
+                # Written after the lock is released: submitters wait on it.
+                tracer.start_span(
+                    "engine.idle", t0=idle_t0, tick=self._engine.tick_count,
+                ).end(idle_t1)
+            if stop:
+                return
             # Device work runs OUTSIDE the lock: submissions (queue appends,
             # thread-safe deque) land while a chunk decodes and are admitted
             # on the next tick; only result handoff needs the lock. Cancels

@@ -135,7 +135,7 @@ class Generator:
         eos_id = jnp.int32(self.tokenizer.eos_id)
         slots = jnp.arange(max_len, dtype=jnp.int32)
 
-        def run(params, input_ids, lengths, rng, adapter_ids=None):
+        def generate(params, input_ids, lengths, rng, adapter_ids=None):
             cache = init_cache(cfg, batch, max_len)
             if mesh is not None:
                 from ditl_tpu.parallel.sharding import named_sharding_tree
@@ -235,7 +235,7 @@ class Generator:
                 out["top_logprobs"] = jnp.swapaxes(top_lp, 0, 1)
             return out
 
-        jitted = jax.jit(run)
+        jitted = jax.jit(generate)
         logger.info(
             "compiling generate program: batch=%d prompt_len=%d max_new=%d",
             batch, prompt_len, gen.max_new_tokens,

@@ -65,6 +65,7 @@ def _apply_top_p(logits: jax.Array, p) -> jax.Array:
     return jnp.where(logits < threshold, -jnp.inf, logits)
 
 
+@jax.named_scope("sample")
 def sample_logits(
     logits: jax.Array,
     rng: jax.Array,

@@ -53,6 +53,7 @@ from ditl_tpu.telemetry.tracing import (
 )
 from ditl_tpu.utils.http11 import KeepAliveHandlerMixin
 from ditl_tpu.utils.logging import get_logger
+from ditl_tpu.utils.profiling import compile_counter, start_profiler_server
 
 logger = get_logger(__name__)
 
@@ -648,6 +649,11 @@ class _Handler(KeepAliveHandlerMixin, BaseHTTPRequestHandler):
                      # the server runs on from the server's own answer.
                      **self.placement_info}
             stats.update(self._load_snapshot())
+            # Programs built by this process and their seconds, cumulative:
+            # read at both ends of a window like the other counters.
+            compiles = compile_counter().snapshot()
+            stats["compile_count_cum"] = compiles["compile_count"]
+            stats["compile_s_cum"] = compiles["compile_s"]
             eng = self._engine_for_stats()
             if eng is not None:
                 stats.update(eng.stats())
@@ -1288,7 +1294,7 @@ class _Handler(KeepAliveHandlerMixin, BaseHTTPRequestHandler):
         cfg, mesh, rules = gen.cfg, gen.mesh, gen.rules
 
         def build():
-            def run(params, ids, lengths):
+            def embed_pooled(params, ids, lengths):
                 q_pos = jnp.arange(plen, dtype=jnp.int32)
                 seg = (q_pos[None, :] < lengths[:, None]).astype(jnp.int32)
                 hidden = llama.forward(
@@ -1302,7 +1308,7 @@ class _Handler(KeepAliveHandlerMixin, BaseHTTPRequestHandler):
                 norm = jnp.linalg.norm(pooled, axis=-1, keepdims=True)
                 return pooled / jnp.maximum(norm, 1e-9)
 
-            return jax.jit(run)
+            return jax.jit(embed_pooled)
 
         with self.device_lock:
             program = lru_program(
@@ -2414,8 +2420,14 @@ def serve(argv: list[str] | None = None) -> int:
         "--trace-dir", default="",
         help="arm end-to-end request tracing (ISSUE 6): span records "
         "(server.request + the engine's queue/prefill/decode lifecycle, "
-        "tick instants) append to {dir}/events-server-<pid>.jsonl; export "
-        "with python -m ditl_tpu.telemetry.trace_export --dir DIR",
+        "tick and tick-phase spans) append to {dir}/events-server-<pid>.jsonl; "
+        "export with python -m ditl_tpu.telemetry.trace_export --dir DIR",
+    )
+    parser.add_argument(
+        "--profiler-port", type=int, default=0,
+        help="> 0: start jax.profiler's server on this port (the trainer's "
+        "runtime.profiler_port), so a device trace of the serving process "
+        "can be captured from TensorBoard/XProf",
     )
     parser.add_argument(
         "--telemetry-override", action="append", default=[],
@@ -2462,6 +2474,8 @@ def serve(argv: list[str] | None = None) -> int:
     from ditl_tpu.runtime.distributed import enable_compile_cache
 
     enable_compile_cache()
+    compile_counter()  # counts every program this process builds from here on
+    start_profiler_server(args.profiler_port)
 
     from ditl_tpu.config import Config, parse_overrides
 
@@ -2487,6 +2501,7 @@ def serve(argv: list[str] | None = None) -> int:
             source=f"server-{tag}",
             max_bytes=telemetry_cfg.journal_max_bytes(),
         ))
+        compile_counter().journal = tracer.journal  # jit.compile events
 
     # Per-tenant usage metering (ISSUE 15): the meter is on by default on
     # process 0 (bounded per-tenant state, terminal-path-only updates);

@@ -327,7 +327,7 @@ class SpeculativeGenerator:
                 cache, named_sharding_tree(mesh, cache_logical_axes(cfg), rules)
             )
 
-        def run(params, input_ids, lengths, n_real):
+        def spec_generate(params, input_ids, lengths, n_real):
             # ---- prefill ----
             cache = shard_cache(init_cache(cfg, batch, max_len))
             p_pos = jnp.arange(prompt_len, dtype=jnp.int32)
@@ -451,7 +451,7 @@ class SpeculativeGenerator:
             "compiling speculative program: batch=%d prompt_len=%d max_new=%d k=%d",
             batch, prompt_len, max_new, k,
         )
-        return jax.jit(run)
+        return jax.jit(spec_generate)
 
     # -- public surface -------------------------------------------------------
 

@@ -362,196 +362,203 @@ def _decoder_layer(
             out = out + lora_delta(lora[name], h, cfg, adapter_ids=adapter_ids)
         return out
 
-    # Attention block
-    h = rms_norm(x, layer_params["attn_norm"]["scale"], cfg.rms_norm_eps)
-    # Named for remat="dots_inputs": h is the qkv projections' WGRAD
-    # operand — saving it keeps the backward's weight-gradient GEMMs fed
-    # from a stored buffer instead of a recompute chain (r5 ablation:
-    # in-step wgrads ran at ~2x their isolated cost under remat="dots").
-    h = checkpoint_name(h, "attn_in")
+    # Attention block. The scopes are the step's stable names (ops/names.py).
+    with jax.named_scope("attn_qkv"):
+        h = rms_norm(x, layer_params["attn_norm"]["scale"], cfg.rms_norm_eps)
+        # Named for remat="dots_inputs": h is the qkv projections' WGRAD
+        # operand — saving it keeps the backward's weight-gradient GEMMs fed
+        # from a stored buffer instead of a recompute chain (r5 ablation:
+        # in-step wgrads ran at ~2x their isolated cost under remat="dots").
+        h = checkpoint_name(h, "attn_in")
 
-    def _bias(t, name):
-        # Qwen2-family q/k/v bias (o stays bias-free).
-        return t + attn[name].astype(t.dtype) if name in attn else t
+        def _bias(t, name):
+            # Qwen2-family q/k/v bias (o stays bias-free).
+            return t + attn[name].astype(t.dtype) if name in attn else t
 
-    if "w_qkv" in attn:
-        # fused_qkv: one (D, (nh+2*nkv)*hd) GEMM replaces the q/k/v trio —
-        # and one dgrad/wgrad pair replaces three each in the backward.
-        if lora is not None:
-            # init_params guards config-time; this closes the runtime hole
-            # (adapters attached post-init by the serving path or a loaded
-            # tree) — silently dropping deltas would serve base outputs.
-            raise ValueError(
-                "fused_qkv does not compose with LoRA adapters (deltas "
-                "target the per-projection names wq/wk/wv)"
+        if "w_qkv" in attn:
+            # fused_qkv: one (D, (nh+2*nkv)*hd) GEMM replaces the q/k/v trio —
+            # and one dgrad/wgrad pair replaces three each in the backward.
+            if lora is not None:
+                # init_params guards config-time; this closes the runtime hole
+                # (adapters attached post-init by the serving path or a loaded
+                # tree) — silently dropping deltas would serve base outputs.
+                raise ValueError(
+                    "fused_qkv does not compose with LoRA adapters (deltas "
+                    "target the per-projection names wq/wk/wv)"
+                )
+            qkv = base_proj(h, attn["w_qkv"])
+            q, k, v = jnp.split(
+                qkv, (nh * hd, (nh + nkv) * hd), axis=-1
             )
-        qkv = base_proj(h, attn["w_qkv"])
-        q, k, v = jnp.split(
-            qkv, (nh * hd, (nh + nkv) * hd), axis=-1
-        )
-        q = _bias(q, "bq").reshape(b, s, nh, hd)
-        k = _bias(k, "bk").reshape(b, s, nkv, hd)
-        v = _bias(v, "bv").reshape(b, s, nkv, hd)
-    else:
-        q = _bias(proj(h, attn["wq"], "wq"), "bq").reshape(b, s, nh, hd)
-        k = _bias(proj(h, attn["wk"], "wk"), "bk").reshape(b, s, nkv, hd)
-        v = _bias(proj(h, attn["wv"], "wv"), "bv").reshape(b, s, nkv, hd)
-    q = apply_rope(q, positions, cfg=cfg)
-    k = apply_rope(k, positions, cfg=cfg)
-    q = _constrain(q, ("batch", "seq", "act_heads", "head_dim"), mesh, rules)
-    k = _constrain(k, ("batch", "seq", "act_kv_heads", "head_dim"), mesh, rules)
+            q = _bias(q, "bq").reshape(b, s, nh, hd)
+            k = _bias(k, "bk").reshape(b, s, nkv, hd)
+            v = _bias(v, "bv").reshape(b, s, nkv, hd)
+        else:
+            q = _bias(proj(h, attn["wq"], "wq"), "bq").reshape(b, s, nh, hd)
+            k = _bias(proj(h, attn["wk"], "wk"), "bk").reshape(b, s, nkv, hd)
+            v = _bias(proj(h, attn["wv"], "wv"), "bv").reshape(b, s, nkv, hd)
+        q = apply_rope(q, positions, cfg=cfg)
+        k = apply_rope(k, positions, cfg=cfg)
+        q = _constrain(q, ("batch", "seq", "act_heads", "head_dim"), mesh, rules)
+        k = _constrain(k, ("batch", "seq", "act_kv_heads", "head_dim"), mesh, rules)
     new_kv = None
-    if layer_cache is not None and "kp" in layer_cache:
-        from ditl_tpu.ops.paged_attention import paged_attention
+    with jax.named_scope("attn_core"):
+        if layer_cache is not None and "kp" in layer_cache:
+            from ditl_tpu.ops.paged_attention import paged_attention
 
-        # Deferred flush: the chunk's K/V go into the tick's small TAIL
-        # buffer (per-token writes into the big page pool inside the decode
-        # scan cost ~7 ms/step on v5e); the kernel reads pages + tail, and
-        # the engine flushes the tail into pages once per tick.
-        tdt = layer_cache["tk"].dtype
-        k_tok = jnp.swapaxes(k, 1, 2).astype(tdt)  # (B, K, S, D)
-        v_tok = jnp.swapaxes(v, 1, 2).astype(tdt)
-        if s == 1:
-            # Plain decode tick: every live slot writes tail column
-            # ``paged["t"]`` (the scan step — slots advance in lock-step
-            # within a tick, each at its own global position).
-            tk = jax.lax.dynamic_update_slice(
-                layer_cache["tk"], k_tok, (0, 0, paged["t"], 0)
+            # Deferred flush: the chunk's K/V go into the tick's small TAIL
+            # buffer (per-token writes into the big page pool inside the decode
+            # scan cost ~7 ms/step on v5e); the kernel reads pages + tail, and
+            # the engine flushes the tail into pages once per tick.
+            tdt = layer_cache["tk"].dtype
+            k_tok = jnp.swapaxes(k, 1, 2).astype(tdt)  # (B, K, S, D)
+            v_tok = jnp.swapaxes(v, 1, 2).astype(tdt)
+            if s == 1:
+                # Plain decode tick: every live slot writes tail column
+                # ``paged["t"]`` (the scan step — slots advance in lock-step
+                # within a tick, each at its own global position).
+                with jax.named_scope("kv_write"):
+                    tk = jax.lax.dynamic_update_slice(
+                        layer_cache["tk"], k_tok, (0, 0, paged["t"], 0)
+                    )
+                    tv = jax.lax.dynamic_update_slice(
+                        layer_cache["tv"], v_tok, (0, 0, paged["t"], 0)
+                    )
+            else:
+                # Speculative verify: K+1 tokens land at per-row tail offsets
+                # ``paged["off"]`` (= pos - starts; slots advance by their own
+                # acceptance, so depths diverge within the tick).
+                from ditl_tpu.infer.cache import scatter_tail
+
+                tk = scatter_tail(layer_cache["tk"], k_tok, paged["off"])
+                tv = scatter_tail(layer_cache["tv"], v_tok, paged["off"])
+            new_kv = {"tk": tk, "tv": tv}
+            attn_out = paged_attention(
+                q[:, 0] if s == 1 else q,
+                layer_cache["kp"], layer_cache["vp"], paged["table"],
+                paged["lengths"], tail_k=tk, tail_v=tv, starts=paged["starts"],
+                k_scale=layer_cache.get("ks"), v_scale=layer_cache.get("vs"),
+                mesh=mesh, rules=rules,
             )
-            tv = jax.lax.dynamic_update_slice(
-                layer_cache["tv"], v_tok, (0, 0, paged["t"], 0)
-            )
-        else:
-            # Speculative verify: K+1 tokens land at per-row tail offsets
-            # ``paged["off"]`` (= pos - starts; slots advance by their own
-            # acceptance, so depths diverge within the tick).
-            from ditl_tpu.infer.cache import scatter_tail
+            if s == 1:
+                attn_out = attn_out[:, None]
+        elif layer_cache is not None and prefill_causal:
+            from ditl_tpu.infer.cache import write_kv
 
-            tk = scatter_tail(layer_cache["tk"], k_tok, paged["off"])
-            tv = scatter_tail(layer_cache["tv"], v_tok, paged["off"])
-        new_kv = {"tk": tk, "tv": tv}
-        attn_out = paged_attention(
-            q[:, 0] if s == 1 else q,
-            layer_cache["kp"], layer_cache["vp"], paged["table"],
-            paged["lengths"], tail_k=tk, tail_v=tv, starts=paged["starts"],
-            k_scale=layer_cache.get("ks"), v_scale=layer_cache.get("vs"),
-            mesh=mesh, rules=rules,
-        )
-        if s == 1:
-            attn_out = attn_out[:, None]
-    elif layer_cache is not None and prefill_causal:
-        from ditl_tpu.infer.cache import write_kv
-
-        # Full prefill from an EMPTY cache (offset 0): every query attends
-        # only chunk positions — pure causal self-attention, so the Pallas
-        # flash kernel applies (the O(S²) score tensor never hits HBM;
-        # 3.4× faster at 8k context than the masked cache read, BASELINE).
-        # Validity (right-padding) rides segment_ids; the cache write is
-        # unchanged.
-        new_kv = write_kv(layer_cache, k, v, cache_index)
-        attn_out = dot_product_attention(
-            q, k, v, causal=True, segment_ids=segment_ids,
-            impl=cfg.attention_impl, mesh=mesh, rules=rules,
-            block_sizes=(cfg.flash_block_q, cfg.flash_block_kv,
-                         cfg.flash_block_q_bwd, cfg.flash_block_kv_bwd),
-        )
-    elif layer_cache is not None:
-        from ditl_tpu.infer.cache import read_kv, write_kv
-
-        new_kv = write_kv(layer_cache, k, v, cache_index)
-        if "k_scale" in new_kv:
-            # int8 cache: hand the raw int8 values + scales to attention so
-            # the dequant fuses into the dots (HBM reads stay int8-sized).
+            # Full prefill from an EMPTY cache (offset 0): every query attends
+            # only chunk positions — pure causal self-attention, so the Pallas
+            # flash kernel applies (the O(S²) score tensor never hits HBM;
+            # 3.4× faster at 8k context than the masked cache read, BASELINE).
+            # Validity (right-padding) rides segment_ids; the cache write is
+            # unchanged.
+            new_kv = write_kv(layer_cache, k, v, cache_index)
             attn_out = dot_product_attention(
-                q, new_kv["k"], new_kv["v"], causal=False, mask=attn_mask,
+                q, k, v, causal=True, segment_ids=segment_ids,
                 impl=cfg.attention_impl, mesh=mesh, rules=rules,
-                k_scale=new_kv["k_scale"], v_scale=new_kv["v_scale"],
+                block_sizes=(cfg.flash_block_q, cfg.flash_block_kv,
+                             cfg.flash_block_q_bwd, cfg.flash_block_kv_bwd),
             )
+        elif layer_cache is not None:
+            from ditl_tpu.infer.cache import read_kv, write_kv
+
+            new_kv = write_kv(layer_cache, k, v, cache_index)
+            if "k_scale" in new_kv:
+                # int8 cache: hand the raw int8 values + scales to attention so
+                # the dequant fuses into the dots (HBM reads stay int8-sized).
+                attn_out = dot_product_attention(
+                    q, new_kv["k"], new_kv["v"], causal=False, mask=attn_mask,
+                    impl=cfg.attention_impl, mesh=mesh, rules=rules,
+                    k_scale=new_kv["k_scale"], v_scale=new_kv["v_scale"],
+                )
+            else:
+                k_full, v_full = read_kv(new_kv, cd)
+                attn_out = dot_product_attention(
+                    q, k_full, v_full, causal=False, mask=attn_mask,
+                    impl=cfg.attention_impl, mesh=mesh, rules=rules,
+                )
         else:
-            k_full, v_full = read_kv(new_kv, cd)
             attn_out = dot_product_attention(
-                q, k_full, v_full, causal=False, mask=attn_mask,
-                impl=cfg.attention_impl, mesh=mesh, rules=rules,
+                q, k, v, causal=True, segment_ids=segment_ids, impl=cfg.attention_impl,
+                mesh=mesh, rules=rules,
+                block_sizes=(cfg.flash_block_q, cfg.flash_block_kv,
+                             cfg.flash_block_q_bwd, cfg.flash_block_kv_bwd),
             )
-    else:
-        attn_out = dot_product_attention(
-            q, k, v, causal=True, segment_ids=segment_ids, impl=cfg.attention_impl,
-            mesh=mesh, rules=rules,
-            block_sizes=(cfg.flash_block_q, cfg.flash_block_kv,
-                         cfg.flash_block_q_bwd, cfg.flash_block_kv_bwd),
-        )
     attn_out = attn_out.reshape(b, s, nh * hd)
     # Named for the remat="attn" policy: saving this one activation means the
     # backward pass never re-runs the attention kernel itself (its recompute
     # is the expensive part of full remat), while everything else (norms,
     # projections, SwiGLU) is still rematerialized.
     attn_out = checkpoint_name(attn_out, "attn_out")
-    x = x + proj(attn_out, attn["wo"], "wo")
-    x = _constrain(x, ("batch", "seq", "act_embed"), mesh, rules)
+    with jax.named_scope("attn_out"):
+        x = x + proj(attn_out, attn["wo"], "wo")
+        x = _constrain(x, ("batch", "seq", "act_embed"), mesh, rules)
 
     # MLP / MoE block
-    h = rms_norm(x, layer_params["mlp_norm"]["scale"], cfg.rms_norm_eps)
-    h = checkpoint_name(h, "mlp_in")  # gate/up wgrad operand (see attn_in)
-    aux = jnp.zeros((), jnp.float32)
-    if "moe" in layer_params:
-        from ditl_tpu.models.moe import moe_block
+    with jax.named_scope("mlp"):
+        h = rms_norm(x, layer_params["mlp_norm"]["scale"], cfg.rms_norm_eps)
+        h = checkpoint_name(h, "mlp_in")  # gate/up wgrad operand (see attn_in)
+        aux = jnp.zeros((), jnp.float32)
+        if "moe" in layer_params:
+            from ditl_tpu.models.moe import moe_block
 
-        mlp_out, aux = moe_block(layer_params["moe"], h, cfg, mesh=mesh, rules=rules)
-    else:
-        mlp = layer_params["mlp"]
-        use_custom_vjp = cfg.mlp_custom_vjp or cfg.mlp_bwd_impl == "pallas"
-        if use_custom_vjp and "w_gu" not in mlp:
-            # Reject-don't-drop: silently falling back to autodiff would
-            # make an A/B of the flag measure byte-identical programs.
-            raise ValueError(
-                "mlp_custom_vjp/mlp_bwd_impl='pallas' require "
-                "fused_gate_up=True (the hand-written backward targets the "
-                "fused w_gu layout)"
-            )
-        if "w_gu" in mlp and use_custom_vjp:
-            if is_quantized_leaf(mlp["w_gu"]) or is_quantized_leaf(mlp["w_down"]):
-                raise ValueError(
-                    "mlp_custom_vjp/mlp_bwd_impl need plain float weights "
-                    "(quantized serving never differentiates — leave it off)"
-                )
-            from ditl_tpu.ops.mlp import mlp_block
-
-            mlp_out = mlp_block(
-                lambda t: _constrain(t, ("batch", "seq", "act_mlp"),
-                                     mesh, rules),
-                h, mlp["w_gu"].astype(cd), mlp["w_down"].astype(cd),
-                bwd_impl=cfg.mlp_bwd_impl,
-                bwd_blocks=(cfg.mlp_bwd_block_n, cfg.mlp_bwd_block_f,
-                            cfg.mlp_bwd_block_d),
-                mesh=mesh, rules=rules,
+            mlp_out, aux = moe_block(
+                layer_params["moe"], h, cfg, mesh=mesh, rules=rules
             )
         else:
-            if "w_gu" in mlp:
-                # fused_gate_up: one (D, 2F) GEMM replaces the gate/up
-                # pair — and one dgrad/wgrad pair replaces two in the
-                # backward.
-                gu = weight_einsum(
-                    "bsd,df->bsf", h, mlp["w_gu"], compute_dtype=cd
+            mlp = layer_params["mlp"]
+            use_custom_vjp = cfg.mlp_custom_vjp or cfg.mlp_bwd_impl == "pallas"
+            if use_custom_vjp and "w_gu" not in mlp:
+                # Reject-don't-drop: silently falling back to autodiff would
+                # make an A/B of the flag measure byte-identical programs.
+                raise ValueError(
+                    "mlp_custom_vjp/mlp_bwd_impl='pallas' require "
+                    "fused_gate_up=True (the hand-written backward targets the "
+                    "fused w_gu layout)"
                 )
-                gate, up = jnp.split(gu, 2, axis=-1)
+            if "w_gu" in mlp and use_custom_vjp:
+                if is_quantized_leaf(mlp["w_gu"]) or is_quantized_leaf(mlp["w_down"]):
+                    raise ValueError(
+                        "mlp_custom_vjp/mlp_bwd_impl need plain float weights "
+                        "(quantized serving never differentiates — leave it off)"
+                    )
+                from ditl_tpu.ops.mlp import mlp_block
+
+                mlp_out = mlp_block(
+                    lambda t: _constrain(t, ("batch", "seq", "act_mlp"),
+                                         mesh, rules),
+                    h, mlp["w_gu"].astype(cd), mlp["w_down"].astype(cd),
+                    bwd_impl=cfg.mlp_bwd_impl,
+                    bwd_blocks=(cfg.mlp_bwd_block_n, cfg.mlp_bwd_block_f,
+                                cfg.mlp_bwd_block_d),
+                    mesh=mesh, rules=rules,
+                )
             else:
-                gate = weight_einsum(
-                    "bsd,df->bsf", h, mlp["w_gate"], compute_dtype=cd
+                if "w_gu" in mlp:
+                    # fused_gate_up: one (D, 2F) GEMM replaces the gate/up
+                    # pair — and one dgrad/wgrad pair replaces two in the
+                    # backward.
+                    gu = weight_einsum(
+                        "bsd,df->bsf", h, mlp["w_gu"], compute_dtype=cd
+                    )
+                    gate, up = jnp.split(gu, 2, axis=-1)
+                else:
+                    gate = weight_einsum(
+                        "bsd,df->bsf", h, mlp["w_gate"], compute_dtype=cd
+                    )
+                    up = weight_einsum(
+                        "bsd,df->bsf", h, mlp["w_up"], compute_dtype=cd
+                    )
+                inner = jax.nn.silu(gate) * up
+                inner = _constrain(inner, ("batch", "seq", "act_mlp"), mesh, rules)
+                # Named so remat policies CAN save it (w_down's wgrad
+                # operand); no shipped policy does — measured
+                # neutral-to-negative on v5e.
+                inner = checkpoint_name(inner, "mlp_inner")
+                mlp_out = weight_einsum(
+                    "bsf,fd->bsd", inner, mlp["w_down"], compute_dtype=cd
                 )
-                up = weight_einsum(
-                    "bsd,df->bsf", h, mlp["w_up"], compute_dtype=cd
-                )
-            inner = jax.nn.silu(gate) * up
-            inner = _constrain(inner, ("batch", "seq", "act_mlp"), mesh, rules)
-            # Named so remat policies CAN save it (w_down's wgrad
-            # operand); no shipped policy does — measured
-            # neutral-to-negative on v5e.
-            inner = checkpoint_name(inner, "mlp_inner")
-            mlp_out = weight_einsum(
-                "bsf,fd->bsd", inner, mlp["w_down"], compute_dtype=cd
-            )
-    x = x + mlp_out
-    x = _constrain(x, ("batch", "seq", "act_embed"), mesh, rules)
+        x = x + mlp_out
+        x = _constrain(x, ("batch", "seq", "act_embed"), mesh, rules)
     if new_kv is not None:
         return x, aux, new_kv
     return x, aux
@@ -611,9 +618,12 @@ def forward(
     # (mask out-of-shard ids + psum over the tensor axis), whose output is
     # already batch-sharded; the embed-axis all-gather this implies is the
     # same per-use weight all-gather FSDP performs everywhere else.
-    table = _constrain(params["embed"]["embedding"].astype(cd), ("vocab", None), mesh, rules)
-    x = table[input_ids]
-    x = _constrain(x, ("batch", "seq", "act_embed"), mesh, rules)
+    with jax.named_scope("embed"):
+        table = _constrain(
+            params["embed"]["embedding"].astype(cd), ("vocab", None), mesh, rules
+        )
+        x = table[input_ids]
+        x = _constrain(x, ("batch", "seq", "act_embed"), mesh, rules)
 
     if cache is not None:
         def cached_layer_fn(carry, xs):
@@ -635,9 +645,13 @@ def forward(
             )
             return y, (aux, new_kv)
 
-        x, (layer_aux, new_cache) = jax.lax.scan(
-            cached_layer_fn, x, (params["layers"], cache)
-        )
+        # Every layer part has a scope of its own, so what is left to this
+        # one is what the scan itself does: slicing each layer's weights and
+        # cache out of the stacked arrays and stacking the new K/V.
+        with jax.named_scope("layer_scan"):
+            x, (layer_aux, new_cache) = jax.lax.scan(
+                cached_layer_fn, x, (params["layers"], cache)
+            )
     elif mesh is not None and mesh.shape.get("stage", 1) > 1:
         # Pipeline parallelism: layers are stage-sharded; microbatches flow
         # through the stages via ppermute (parallel/pipeline.py). Layer bodies
@@ -652,15 +666,16 @@ def forward(
             )
 
         pipe_layer = _apply_remat(pipe_layer, cfg)
-        x, layer_aux = pipeline_apply(
-            pipe_layer,
-            params["layers"],
-            x,
-            (positions, segment_ids),
-            mesh=mesh,
-            rules=rules,
-            n_microbatches=cfg.pipeline_microbatches or None,
-        )
+        with jax.named_scope("layer_scan"):
+            x, layer_aux = pipeline_apply(
+                pipe_layer,
+                params["layers"],
+                x,
+                (positions, segment_ids),
+                mesh=mesh,
+                rules=rules,
+                n_microbatches=cfg.pipeline_microbatches or None,
+            )
         new_cache = None
     else:
         def layer_fn(carry, layer_params):
@@ -676,12 +691,14 @@ def forward(
             )
 
         layer_fn = _apply_remat(layer_fn, cfg)
-        x, layer_aux = jax.lax.scan(
-            layer_fn, x, params["layers"], unroll=cfg.scan_unroll
-        )
+        with jax.named_scope("layer_scan"):
+            x, layer_aux = jax.lax.scan(
+                layer_fn, x, params["layers"], unroll=cfg.scan_unroll
+            )
         new_cache = None
 
-    x = rms_norm(x, params["final_norm"]["scale"], cfg.rms_norm_eps)
+    with jax.named_scope("lm_head"):
+        x = rms_norm(x, params["final_norm"]["scale"], cfg.rms_norm_eps)
     if return_hidden:
         out = (x,)
         if with_aux:
@@ -691,11 +708,12 @@ def forward(
         return out if len(out) > 1 else x
     from ditl_tpu.ops.quant import weight_einsum
 
-    logits = weight_einsum(
-        "bsd,dv->bsv", x, head_weights(params, cfg),
-        compute_dtype=cd, preferred=jnp.float32,
-    )
-    logits = _constrain(logits, ("batch", "seq", "act_vocab"), mesh, rules)
+    with jax.named_scope("lm_head"):
+        logits = weight_einsum(
+            "bsd,dv->bsv", x, head_weights(params, cfg),
+            compute_dtype=cd, preferred=jnp.float32,
+        )
+        logits = _constrain(logits, ("batch", "seq", "act_vocab"), mesh, rules)
     out = (logits,)
     if with_aux:
         out = out + (jnp.sum(layer_aux),)

@@ -169,6 +169,7 @@ def _seq_sharded_decode(
     )(q, k, v, mask, k_scale, v_scale)
 
 
+@jax.named_scope("attn_core")
 def dot_product_attention(
     q: jax.Array,
     k: jax.Array,

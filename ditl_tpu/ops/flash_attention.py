@@ -285,6 +285,7 @@ def _fwd(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
+        name="flash_fwd",
     )(*args)
     return o, lse
 
@@ -516,6 +517,7 @@ def _bwd_impl(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q, k, v, do, lse, delta, *seg_args)
 
     # ---- dk/dv: grid (B, K, n_kv, groups·n_q), accumulate over (g, q) ----
@@ -576,6 +578,7 @@ def _bwd_impl(
             pltpu.VMEM((bkv, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(q, k, v, do, lse, delta, *seg_args)
     return dq, dk, dv
 

@@ -31,6 +31,7 @@ __all__ = ["fused_cross_entropy"]
 
 
 @functools.partial(jax.jit, static_argnames=("block_tokens", "compute_dtype"))
+@jax.named_scope("loss")
 def fused_cross_entropy(
     x: jax.Array,  # (N, D) final hidden states (already final-normed)
     head: jax.Array,  # (D, V) lm head weights

@@ -216,6 +216,7 @@ def fused_mlp_bwd(
 
     dgate, dup, d_w_down = pl.pallas_call(
         functools.partial(_dgu_dwdown_kernel, n_n=n_n),
+        name="mlp_bwd_act",
         grid=(n_f, n_n),
         in_specs=[
             pl.BlockSpec((bn, d), lambda i_f, i_n: (i_n, 0)),    # g
@@ -246,6 +247,7 @@ def fused_mlp_bwd(
 
     dh2, d_w_gu = pl.pallas_call(
         functools.partial(_dh_dwgu_kernel, n_n=n_n),
+        name="mlp_bwd_wgu",
         grid=(n_d, n_n),
         in_specs=[
             pl.BlockSpec((bn, bd), lambda i_d, i_n: (i_n, i_d)),    # h

@@ -1,0 +1,39 @@
+"""The stable names of the work in every device program: what a profiler
+trace, ``benchmarks/layer_metrics/_scopes.py`` and ``PERF_LEDGER.jsonl`` call
+the parts of a step. A refactor may move the code under a name; it may not
+rename it. A scope is a ``jax.named_scope`` path segment (innermost wins);
+a kernel is the ``name=`` of its ``pl.pallas_call``, which the TPU compiler
+also gives to the instruction.
+
+``layer_scan`` is the loop over the layers, and holds only what no layer
+part claims: slicing each layer's weights (and, in a cached forward, its
+K/V cache or page pool) out of the stacked arrays, stacking what the layers
+return, and what remat saves between forward and backward. ``kv_gather`` is
+an explicit read of cached K/V (the contiguous cache's ``read_kv``, a paged
+prefill's gather of its context pages); the paged decode program has none,
+its kernel reads the pages and its whole-pool slice is ``layer_scan``'s."""
+
+SCOPES = (
+    "embed",
+    "attn_qkv",
+    "attn_core",
+    "attn_out",
+    "mlp",
+    "layer_scan",
+    "lm_head",
+    "loss",
+    "optimizer",
+    "kv_gather",
+    "kv_write",
+    "sample",
+)
+
+KERNELS = (
+    "flash_fwd",
+    "flash_bwd_dq",
+    "flash_bwd_dkv",
+    "paged_attention",
+    "mlp_bwd_act",
+    "mlp_bwd_wgu",
+    "proj_bwd",
+)

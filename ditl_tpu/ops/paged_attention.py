@@ -504,6 +504,7 @@ def paged_attention(
             out_shape=out_shape,
             compiler_params=compiler_params,
             interpret=interpret,
+            name="paged_attention",
         )(*args)
         return out_4d(out)
 
@@ -550,5 +551,6 @@ def paged_attention(
         out_shape=out_shape,
         compiler_params=compiler_params,
         interpret=interpret,
+        name="paged_attention",
     )(page_table, lengths, qg, k_pages, v_pages)
     return out.reshape(b, h, d)

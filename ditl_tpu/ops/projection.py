@@ -116,6 +116,7 @@ def _pallas_bwd(x, w, g, *, blocks, interpret):
     n_n, n_d = n // bn, d // bd
     dx2, dw = pl.pallas_call(
         partial(_bwd_kernel, n_n=n_n),
+        name="proj_bwd",
         grid=(n_d, n_n),
         in_specs=[
             pl.BlockSpec((bn, bd), lambda i_d, i_n: (i_n, i_d)),  # x

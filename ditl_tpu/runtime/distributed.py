@@ -176,9 +176,10 @@ def init_runtime(config: RuntimeConfig | None = None) -> None:
     setup_logging(config.log_level)
     if config.compile_cache:
         enable_compile_cache()
-    if config.profiler_port > 0 and jax.process_index() == 0:
-        jax.profiler.start_server(config.profiler_port)
-        logger.info("jax.profiler server on port %d", config.profiler_port)
+    from ditl_tpu.utils.profiling import compile_counter, start_profiler_server
+
+    compile_counter()  # counts every program this process builds from here on
+    start_profiler_server(config.profiler_port)
     logger.info(
         "runtime up: process %d/%d, %d local / %d global devices (%s)",
         jax.process_index(),

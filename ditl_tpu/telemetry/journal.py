@@ -15,7 +15,7 @@ than the skew, and the per-source ``seq`` keeps each process's own story
 internally ordered regardless.
 
 Size control (ISSUE 6 satellite): ``max_bytes`` arms rotation so a
-long-lived serving process (span records arrive per request, tick instants
+long-lived serving process (span records arrive per request, tick spans
 per scheduler tick) cannot grow its journal unboundedly. The journal
 rotates into sibling segments named ``<stem>.rNNNN.jsonl`` — still matching
 the ``events-*.jsonl`` merge glob, and carrying the SAME ``source`` and a

@@ -20,8 +20,8 @@ Span model:
   span context on the upstream request, the replica's server continues the
   trace, and the engine parents its lifecycle spans under the server span —
   so the merged trace nests across process boundaries.
-- **Instants** (``trace.instant`` records, e.g. the engine's per-tick
-  marker) are zero-duration points on a process's track.
+- **Instants** (``trace.instant`` records) are zero-duration points on a
+  process's track.
 
 Cost discipline: a ``Tracer`` with no journal is **unarmed** — span writes
 are skipped entirely, but span/trace IDs are still generated so propagation

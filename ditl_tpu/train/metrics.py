@@ -16,6 +16,15 @@ async, so this is host work, not device time); each flush records its own
 blocking-sync wall as ``sync_s`` on the row that triggered it. The summary
 totals the three, which is where "where did the wall clock go" starts before
 the goodput report (telemetry/goodput.py) finishes it.
+
+The operator's throughput figure: a flush is the only point where the host
+has caught up with the device, so the wall from the end of one flush's sync
+to the end of the next, over the steps flushed, is the one step time that
+holds however far the host runs ahead. It is ``flush_step_s`` on the flushing
+row, and what the ``step N:`` log line prints (the first flush's interval
+starts when the logger is made and holds the compile). Every row also carries
+``compile_count_cum`` / ``compile_s_cum`` (utils/profiling.compile_counter)
+as they stood at its flush.
 """
 
 from __future__ import annotations
@@ -28,6 +37,7 @@ from typing import Any
 from ditl_tpu.annotations import hot_path
 from ditl_tpu.runtime.distributed import is_coordinator
 from ditl_tpu.utils.logging import get_logger
+from ditl_tpu.utils.profiling import compile_counter
 
 logger = get_logger(__name__)
 
@@ -73,6 +83,10 @@ class MetricsLogger:
         self.step_times: list[float] = []
         self.tokens_per_sec_chip: list[float] = []
         self._last_t: float | None = None
+        # When the host last knew the device had caught up: the end of the
+        # previous flush's sync (at first, now).
+        self._last_sync_t = time.perf_counter()
+        self._compiles = compile_counter()
         # (step, metrics, n_steps, dt, data_wait_s) per un-flushed window.
         self._pending: list[tuple[int, Any, int, float | None, float]] = []
         self._metrics_fh = None
@@ -133,9 +147,21 @@ class MetricsLogger:
         # "device-blocked" phase (the host catching up to the async-
         # dispatched step stream).
         t0 = time.perf_counter()
-        host_all = jax.device_get([m for _, m, _, _, _ in self._pending])
-        sync_s = time.perf_counter() - t0
+        with jax.profiler.TraceAnnotation("train.flush"):
+            host_all = jax.device_get([m for _, m, _, _, _ in self._pending])
+        now = time.perf_counter()
+        sync_s = now - t0
         self.sync_s += sync_s
+        # The flush-to-flush wall over the steps flushed: the only interval
+        # that ends in a device sync at both ends.
+        flush_wall_s = now - self._last_sync_t
+        self._last_sync_t = now
+        flush_step_s = flush_wall_s / sum(n for _, _, n, _, _ in self._pending)
+        flush_tps_chip = (
+            sum(float(h.get("n_tokens", 0.0)) for h in host_all)
+            / flush_wall_s / self.n_chips
+        )
+        compiles = self._compiles.snapshot()
         if self.anatomy is not None:
             self.anatomy.add("device_compute", sync_s)
         last_i = len(self._pending) - 1
@@ -154,8 +180,8 @@ class MetricsLogger:
                     step,
                     host.get("loss", float("nan")),
                     host.get("grad_norm", float("nan")),
-                    dt,
-                    tps_chip,
+                    flush_step_s,
+                    flush_tps_chip,
                 )
             if self._metrics_fh is not None:
                 row = {
@@ -164,12 +190,15 @@ class MetricsLogger:
                     "tokens_per_sec_per_chip": round(tps_chip, 2),
                     "data_wait_s": round(data_wait_s, 6),
                     "dispatch_s": round(dt * n_steps, 6),
+                    "compile_count_cum": compiles["compile_count"],
+                    "compile_s_cum": compiles["compile_s"],
                     **{k: round(v, 6) for k, v in host.items()},
                 }
                 if i == last_i:
                     # The sync belongs to the flush, not any single step;
                     # carried on the row that triggered it.
                     row["sync_s"] = round(sync_s, 6)
+                    row["flush_step_s"] = round(flush_step_s, 6)
                 self._metrics_fh.write(json.dumps(row, sort_keys=True) + "\n")
         self._pending.clear()
         if self.on_host_metrics is not None:

@@ -64,27 +64,28 @@ def loss_fn(
         with_aux=True,
         return_hidden=fused,
     )
-    if fused:
-        from ditl_tpu.ops.fused_ce import fused_cross_entropy
+    with jax.named_scope("loss"):
+        if fused:
+            from ditl_tpu.ops.fused_ce import fused_cross_entropy
 
-        d = out.shape[-1]
-        nll_sum = fused_cross_entropy(
-            out[:, :-1].reshape(-1, d),
-            llama.head_weights(params, cfg),
-            targets.reshape(-1).astype(jnp.int32),
-            mask.reshape(-1),
-            block_tokens=cfg.loss_block_tokens,
-            compute_dtype=jnp.dtype(cfg.dtype),
-        )
-        ce = nll_sum / n_tokens
-    else:
-        logits = out[:, :-1]
-        logz = jax.nn.logsumexp(logits, axis=-1)
-        target_logit = jnp.take_along_axis(
-            logits, targets[..., None].astype(jnp.int32), axis=-1
-        )[..., 0]
-        nll = (logz - target_logit) * mask
-        ce = nll.sum() / n_tokens
+            d = out.shape[-1]
+            nll_sum = fused_cross_entropy(
+                out[:, :-1].reshape(-1, d),
+                llama.head_weights(params, cfg),
+                targets.reshape(-1).astype(jnp.int32),
+                mask.reshape(-1),
+                block_tokens=cfg.loss_block_tokens,
+                compute_dtype=jnp.dtype(cfg.dtype),
+            )
+            ce = nll_sum / n_tokens
+        else:
+            logits = out[:, :-1]
+            logz = jax.nn.logsumexp(logits, axis=-1)
+            target_logit = jnp.take_along_axis(
+                logits, targets[..., None].astype(jnp.int32), axis=-1
+            )[..., 0]
+            nll = (logz - target_logit) * mask
+            ce = nll.sum() / n_tokens
     loss = ce + cfg.router_aux_coef * aux if cfg.num_experts > 0 else ce
     return loss, {"loss": ce, "n_tokens": mask.sum()}
 
@@ -130,7 +131,7 @@ def _build_step_fn(
             params = jax.tree_util.tree_map_with_path(cast, params)
         return loss_fn(params, batch, model_cfg, mesh=mesh, rules=rules)
 
-    def step(state: TrainState, batch: dict) -> tuple[TrainState, dict]:
+    def train_step(state: TrainState, batch: dict) -> tuple[TrainState, dict]:
         tx = get_tx(state.params)
         if accum > 1:
             # (B, ...) -> (accum, B/accum, ...): scan microbatches on device.
@@ -157,11 +158,12 @@ def _build_step_fn(
                 state.params, batch
             )
             tokens = aux["n_tokens"]
-        updates, new_opt = tx.update(grads, state.opt_state, state.params)
-        new_params = jax.tree.map(
-            lambda p, u: (p + u.astype(p.dtype)), state.params, updates
-        )
-        grad_norm = optax_global_norm(grads)
+        with jax.named_scope("optimizer"):
+            updates, new_opt = tx.update(grads, state.opt_state, state.params)
+            new_params = jax.tree.map(
+                lambda p, u: (p + u.astype(p.dtype)), state.params, updates
+            )
+            grad_norm = optax_global_norm(grads)
         new_state = TrainState(step=state.step + 1, params=new_params, opt_state=new_opt)
         metrics = {"loss": loss, "n_tokens": tokens, "grad_norm": grad_norm}
         if train_cfg.fault_nan_step > 0:
@@ -175,7 +177,7 @@ def _build_step_fn(
             )
         return new_state, metrics
 
-    return step
+    return train_step
 
 
 def _shardings_for(model_cfg, train_cfg, mesh, example_batch, rules):
@@ -237,7 +239,7 @@ def make_multi_step(
     rules = rules if rules is not None else _default_rules(mesh)
     step = _build_step_fn(model_cfg, train_cfg, mesh, rules)
 
-    def multi(state: TrainState, batches: dict) -> tuple[TrainState, dict]:
+    def train_multi_step(state: TrainState, batches: dict) -> tuple[TrainState, dict]:
         return jax.lax.scan(step, state, batches)
 
     state_sh, batch_sh, metric_sh = _shardings_for(
@@ -249,7 +251,7 @@ def make_multi_step(
         return jax.tree.map(lambda s: NamedSharding(mesh, P(None, *s.spec)), sh)
 
     return jax.jit(
-        multi,
+        train_multi_step,
         in_shardings=(state_sh, window(batch_sh)),
         out_shardings=(state_sh, window(metric_sh)),
         donate_argnums=(0,),

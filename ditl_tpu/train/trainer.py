@@ -60,7 +60,7 @@ from ditl_tpu.train.metrics import MetricsLogger
 from ditl_tpu.train.state import TrainState, create_train_state, state_logical_axes
 from ditl_tpu.train.step import make_eval_step, make_multi_step, make_train_step
 from ditl_tpu.utils.logging import get_logger, setup_logging
-from ditl_tpu.utils.profiling import StepProfiler
+from ditl_tpu.utils.profiling import StepProfiler, compile_counter
 
 logger = get_logger(__name__)
 
@@ -180,6 +180,7 @@ def train(config: Config) -> dict[str, Any]:
             max_bytes=config.telemetry.journal_max_bytes(),
         )
         journal.event("worker.start")
+        compile_counter().journal = journal  # one jit.compile event a program
     # Chaos plane (ditl_tpu/chaos/, ISSUE 5): armed pod-wide from the
     # identical config (the fingerprint covers chaos.*); per-worker
     # targeting via rule `proc=N`. Injections journal into this worker's
@@ -525,8 +526,8 @@ def train(config: Config) -> dict[str, Any]:
                 window = window[: total_steps - global_step]
                 t_window0 = time.perf_counter()
                 metrics.start_step()
-                # Profiler work (start_trace, and maybe_stop's
-                # effects_barrier + trace write) happens INSIDE the window
+                # Profiler work (start_trace, and maybe_stop's wait for the
+                # traced steps + trace write) happens INSIDE the window
                 # interval — timed explicitly and subtracted from the
                 # window wall below, or it would be double-counted into
                 # compile/productive_step and break conservation.
@@ -562,7 +563,9 @@ def train(config: Config) -> dict[str, Any]:
                             )
                         window_metrics = dict(step_metrics, n_tokens=window_tokens)
                 t_prof = time.perf_counter()
-                profiler.maybe_stop(global_step + len(window) - 1)
+                profiler.maybe_stop(
+                    global_step + len(window) - 1, window_metrics
+                )
                 prof_s += time.perf_counter() - t_prof
                 tracker.add("profiler", prof_s)
                 global_step += len(window)
@@ -751,10 +754,11 @@ def train(config: Config) -> dict[str, Any]:
             sampler.stop()
         metrics.close()
         with tracker.span("profiler"):
-            profiler.close()
+            profiler.close(step_metrics)
         if ckpt is not None:
             ckpt.close()
         if journal is not None:
+            compile_counter().journal = None
             journal.event("worker.exit", step=global_step)
             journal.close()
         barrier("end-of-training")

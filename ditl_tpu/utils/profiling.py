@@ -2,19 +2,28 @@
 monitoring story is 'check console output' + nvidia-smi, ref
 ``docs/setup_guide.md:68-71``).
 
-Two mechanisms, both process-0-gated and off by default:
+Two capture mechanisms, both process-0-gated and off by default:
 
-- ``jax.profiler.start_server(port)`` (runtime/distributed.py, config
-  ``runtime.profiler_port``) — live capture from TensorBoard/XProf.
+- ``start_profiler_server(port)`` (here; the trainer's
+  ``runtime.profiler_port`` and the server's ``--profiler-port``) — live
+  capture from TensorBoard/XProf.
 - ``StepProfiler`` (here) — programmatic capture of a step window
   [``profile_start_step``, ``profile_start_step + profile_num_steps``) to
-  ``profile_dir``, viewable in TensorBoard. Each step inside the window is
-  wrapped in a ``StepTraceAnnotation`` so XProf's step view lines up with
-  train steps. Capturing a *window* (not the whole run) keeps trace files
-  bounded and skips the untypical compile step.
+  ``profile_dir``, viewable in TensorBoard. Capturing a *window* (not the
+  whole run) keeps trace files bounded and skips the untypical compile step.
+
+Whoever starts a trace, every dispatched step is wrapped in a
+``StepTraceAnnotation`` (``annotate_step``), so the trace has step marks on
+its own clock. Outside a trace an annotation costs microseconds.
+
+``compile_counter()`` counts compilations where they happen, through
+``jax.monitoring``: the rows of ``metrics_file`` and the server's
+``/v1/stats`` carry its totals.
 """
 
 from __future__ import annotations
+
+import threading
 
 import jax
 
@@ -22,7 +31,78 @@ from ditl_tpu.utils.logging import get_logger
 
 logger = get_logger(__name__)
 
-__all__ = ["StepProfiler", "annotate_step"]
+__all__ = [
+    "CompileCounter",
+    "StepProfiler",
+    "annotate_step",
+    "compile_counter",
+    "start_profiler_server",
+]
+
+# jax 0.9.0 (jax/_src/dispatch.py): lowering to an MLIR module, and the
+# backend's compile; the latter's interval includes a hit's retrieval from
+# the persistent cache. Tracing (``jaxpr_trace_duration``) is left out: a
+# nested jit's trace is timed inside its caller's as well, so a sum over
+# events would count it twice.
+_LOWERING_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+class CompileCounter:
+    """Programs built by this process (compiled, or loaded from the
+    persistent cache) and the seconds that took, cumulative. ``journal``
+    (telemetry/journal.py), when set, gets one ``jit.compile`` event per
+    program with its name and seconds: which step recompiled."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.compile_count = 0  # guarded-by: _lock
+        self.compile_s = 0.0  # guarded-by: _lock
+        self.journal = None
+
+    def on_duration(self, event: str, duration_s: float, **kw) -> None:
+        if event == _LOWERING_EVENT:
+            with self._lock:
+                self.compile_s += duration_s
+        elif event == _BACKEND_COMPILE_EVENT:
+            name = str(kw.get("fun_name", ""))
+            with self._lock:
+                self.compile_count += 1
+                self.compile_s += duration_s
+            journal = self.journal
+            if journal is not None:
+                journal.event("jit.compile", program=name,
+                              compile_s=round(duration_s, 6))
+
+    def snapshot(self) -> dict:
+        with self._lock:
+            return {"compile_count": self.compile_count,
+                    "compile_s": round(self.compile_s, 6)}
+
+
+_counter: CompileCounter | None = None
+_counter_lock = threading.Lock()
+
+
+def compile_counter() -> CompileCounter:
+    """The process's one counter; the first call registers its listener
+    (``jax.monitoring`` listeners are per process and cannot be scoped)."""
+    global _counter
+    with _counter_lock:
+        if _counter is None:
+            _counter = CompileCounter()
+            jax.monitoring.register_event_duration_secs_listener(
+                _counter.on_duration
+            )
+        return _counter
+
+
+def start_profiler_server(port: int) -> None:
+    """``jax.profiler.start_server`` on process 0 when ``port`` > 0: the one
+    switch both programs have for a device trace taken from outside."""
+    if port > 0 and jax.process_index() == 0:
+        jax.profiler.start_server(port)
+        logger.info("jax.profiler server on port %d", port)
 
 
 def annotate_step(step: int):
@@ -37,7 +117,7 @@ class StepProfiler:
         prof.maybe_start(global_step)
         with prof.annotate(global_step):
             state, metrics = train_step(state, batch)
-        prof.maybe_stop(global_step)
+        prof.maybe_stop(global_step, metrics)
 
     ``tracer`` (telemetry/tracing.py, ISSUE 6 satellite): when armed, the
     capture window is recorded as a ``profiler.capture`` span in the
@@ -111,39 +191,48 @@ class StepProfiler:
         ).end()
 
     def annotate(self, step: int):
-        if self._active:
-            return annotate_step(step)
-        import contextlib
+        """The step's mark, inside this profiler's window or not: a trace
+        started by anyone else (``start_profiler_server``, a benchmark's
+        launcher thread) gets step marks too."""
+        return annotate_step(step)
 
-        return contextlib.nullcontext()
-
-    def maybe_stop(self, step: int) -> None:
-        """``step`` is the LAST completed step since ``maybe_start`` — with
+    def maybe_stop(self, step: int, wait_for=None) -> None:
+        """``step`` is the LAST dispatched step since ``maybe_start`` — with
         step windows (train.steps_per_call > 1) the caller passes the window's
         last step, so the trace covers whole windows (rounding the configured
         step count up to a window boundary, never running a full extra
-        window)."""
+        window). ``wait_for``: arrays that step produced (its metrics, or the
+        state); the trace stops only when they are ready, so that it holds
+        the device time of the steps it names. ``jax.effects_barrier()``
+        does not wait for the device: a trace stopped after it held 5 ms of
+        three 1.45 s steps (PERF.md, PR 22)."""
         if self._active and step >= self._stop_after:
-            # Block until device work from the traced steps has finished so
-            # the trace actually contains the device timeline.
-            jax.effects_barrier()
+            jax.block_until_ready(wait_for)
             jax.profiler.stop_trace()
             self._active = False
             self._done = True
             self._record_span(step, partial=False)
             logger.info("profiler: trace written to %s", self.directory)
 
-    def close(self) -> None:
+    def close(self, wait_for=None) -> None:
         """Mirror ``maybe_stop`` for a trainer exiting mid-window (epoch end,
-        exception, total_steps inside the window): effects_barrier first so
-        the trace still contains the device timeline of the steps that DID
-        run, and mark ``_done`` so a reused profiler cannot restart a second
-        window after its trace was finalized (ISSUE 3 satellite)."""
+        exception, total_steps inside the window): wait for ``wait_for``
+        first so the trace still contains the device timeline of the steps
+        that DID run, and mark ``_done`` so a reused profiler cannot restart
+        a second window after its trace was finalized (ISSUE 3 satellite)."""
         if self._active:
-            jax.effects_barrier()
-            jax.profiler.stop_trace()
-            self._active = False
-            self._done = True
+            # The trainer calls this in its ``finally``: a step that failed
+            # on the device raises here again, and must neither leave the
+            # trace open nor hide the first error or the clean-up after it.
+            try:
+                jax.block_until_ready(wait_for)
+            except Exception as e:
+                logger.warning("profiler: the last step did not finish (%r); "
+                               "the trace may lack its device time", e)
+            finally:
+                jax.profiler.stop_trace()
+                self._active = False
+                self._done = True
             self._record_span(self._stop_after, partial=True)
             logger.info("profiler: trace (partial window) written to %s",
                         self.directory)

@@ -211,6 +211,39 @@ def test_prometheus_metrics_endpoint(setup):
         threaded.close()
 
 
+def test_stats_carry_the_compile_counter_and_a_new_shape_raises_it(setup):
+    """ISSUE 23: /v1/stats holds the process's compile counter (cumulative,
+    read at both ends of a window); a request whose prompt needs a program
+    that has not been built raises it."""
+    from ditl_tpu.utils.profiling import compile_counter
+
+    compile_counter()  # serve() registers it before anything compiles
+    params, cfg, tok = setup
+    server, threaded, port = _serve(params, cfg, tok, continuous=True)
+
+    def stats():
+        with urllib.request.urlopen(
+            f"http://127.0.0.1:{port}/v1/stats", timeout=30
+        ) as resp:
+            return json.loads(resp.read())
+
+    try:
+        _post(port, "/v1/completions", {"prompt": "hi", "max_tokens": 3})
+        a = stats()
+        assert a["compile_count_cum"] >= 1 and a["compile_s_cum"] > 0
+        _post(port, "/v1/completions", {"prompt": "hi", "max_tokens": 3})
+        b = stats()  # the same shapes again: nothing compiles
+        assert b["compile_count_cum"] == a["compile_count_cum"]
+        # a prompt in another prefill bucket: a forced recompile
+        _post(port, "/v1/completions", {"prompt": "long " * 20, "max_tokens": 3})
+        c = stats()
+        assert c["compile_count_cum"] > b["compile_count_cum"]
+        assert c["compile_s_cum"] > b["compile_s_cum"]
+    finally:
+        server.shutdown()
+        threaded.close()
+
+
 def _scrape_metrics(port: int) -> str:
     with urllib.request.urlopen(
         f"http://127.0.0.1:{port}/metrics", timeout=30

@@ -457,10 +457,13 @@ def test_engine_lifecycle_spans_nest_under_one_request(tiny, tmp_path):
     assert by_name["engine.prefill"][0]["tokens"] == 20
     assert by_name["engine.decode"][0]["first"] is True
     assert "queue_wait_s" in by_name["engine.queue"][0]
-    # Tick instants mark the scheduler cadence on the same track.
-    instants = [r for r in merge_journals(str(tmp_path))
-                if r.get("event") == "trace.instant"]
-    assert any(r["name"] == "engine.tick" for r in instants)
+    # Tick spans mark the scheduler cadence on the same track, and are the
+    # only records of that name (no second, instant kind).
+    ticks = [r for r in merge_journals(str(tmp_path))
+             if r.get("name") == "engine.tick"]
+    assert ticks and {r["event"] for r in ticks} == {"trace.span"}
+    assert all({"tick", "slots_busy", "prefilling", "queue_depth",
+                "prefill_s"} <= r.keys() for r in ticks)
     journal.close()
 
 
@@ -681,3 +684,96 @@ def test_gateway_trace_merges_across_processes_with_retry(tiny, tmp_path):
     tracks = {ev["args"]["name"] for ev in events if ev["ph"] == "M"}
     assert "gateway" in tracks and len(tracks) >= 2
     assert by_id  # silence linters: structure asserted above
+
+
+# ---------------------------------------------------------------------------
+# engine-thread tick phases (ISSUE 23): what the engine thread was doing
+# ---------------------------------------------------------------------------
+
+TICK_PHASES = ("schedule", "prefill", "dispatch", "fetch", "harvest", "spill")
+
+
+@pytest.fixture(scope="module")
+def tick_spans(tiny, tmp_path_factory):
+    """Span records of a threaded paged engine with a host tier (so that
+    every phase, ``spill`` included, has something to do) serving two
+    requests with a pause between them."""
+    from ditl_tpu.infer.continuous import ContinuousEngine, ThreadedEngine
+    from ditl_tpu.infer.engine import GenerateConfig
+
+    params, cfg, tok = tiny
+    directory = tmp_path_factory.mktemp("ticks")
+    journal = EventJournal(str(directory / "events-engine.jsonl"), source="engine")
+    te = ThreadedEngine(ContinuousEngine(
+        params, cfg, tok, n_slots=2, decode_chunk=4, cache_mode="paged",
+        page_size=16, host_tier_mb=1, gen=GenerateConfig(max_new_tokens=8),
+        tracer=Tracer(journal),
+    ))
+    try:
+        te.generate_one(list(range(1, 21)), max_new_tokens=8)
+        time.sleep(0.2)  # nothing pending: the driver idles
+        te.generate_one(list(range(1, 40)), max_new_tokens=8)
+    finally:
+        te.close()
+        journal.close()
+    return _spans(str(directory))
+
+
+@pytest.mark.parametrize("phase", TICK_PHASES)
+def test_tick_phase_spans_are_children_of_their_tick(tick_spans, phase):
+    ticks = {s["span"]: s for s in tick_spans if s["name"] == "engine.tick"}
+    mine = [s for s in tick_spans if s["name"] == f"engine.tick.{phase}"]
+    assert ticks and mine
+    for s in mine:
+        tick = ticks[s["parent"]]
+        assert s["tick"] == tick["tick"] and s["trace"] == tick["trace"]
+        assert tick["ts"] <= s["ts"] + 1e-6
+        assert s["ts"] + s["dur_s"] <= tick["ts"] + tick["dur_s"] + 1e-3
+
+
+def test_tick_phases_never_overlap_and_fit_inside_the_tick(tick_spans):
+    ticks = [s for s in tick_spans if s["name"] == "engine.tick"]
+    assert len({t["tick"] for t in ticks}) == len(ticks)  # one span a step()
+    for tick in ticks:
+        kids = sorted((s for s in tick_spans if s["parent"] == tick["span"]),
+                      key=lambda s: s["ts"])
+        assert {k["name"].rsplit(".", 1)[1] for k in kids} <= set(TICK_PHASES)
+        assert sum(k["dur_s"] for k in kids) <= tick["dur_s"] + 1e-3
+        for a, b in zip(kids, kids[1:]):
+            assert a["ts"] + a["dur_s"] <= b["ts"] + 1e-3
+
+
+def test_engine_idle_lies_between_ticks(tick_spans):
+    idle = [s for s in tick_spans if s["name"] == "engine.idle"]
+    ticks = [s for s in tick_spans if s["name"] == "engine.tick"]
+    assert idle and all("tick" in s and s["parent"] == "" for s in idle)
+    for s in idle:
+        for t in ticks:  # an idle wait and a tick never share an instant
+            assert (s["ts"] + s["dur_s"] <= t["ts"] + 1e-3
+                    or t["ts"] + t["dur_s"] <= s["ts"] + 1e-3)
+    assert max(s["dur_s"] for s in idle) >= 0.15  # the pause between requests
+
+
+def test_unarmed_engine_opens_no_tick_span(tiny, monkeypatch):
+    """Unarmed: a tick neither writes nor allocates a span for its phases
+    (request spans still mint ids for propagation, as before)."""
+    from ditl_tpu.infer.continuous import ContinuousEngine
+    from ditl_tpu.infer.engine import GenerateConfig
+    from ditl_tpu.telemetry import tracing
+
+    params, cfg, tok = tiny
+    eng = ContinuousEngine(params, cfg, tok, n_slots=2, decode_chunk=4,
+                           gen=GenerateConfig(max_new_tokens=4))
+    started = []
+    real = tracing.Tracer.start_span
+
+    def spy(self, name, *a, **kw):
+        started.append(name)
+        return real(self, name, *a, **kw)
+
+    monkeypatch.setattr(tracing.Tracer, "start_span", spy)
+    eng.submit(list(range(1, 21)), max_new_tokens=4)
+    eng.run()
+    assert not [n for n in started if n.startswith(("engine.tick", "engine.idle"))]
+    assert eng._tick_span is None and eng._phase_span is None
+    assert eng._phase("engine.tick.fetch") is None
