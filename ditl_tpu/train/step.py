@@ -66,7 +66,7 @@ def loss_fn(
     )
     with jax.named_scope("loss"):
         if fused:
-            from ditl_tpu.ops.fused_ce import fused_cross_entropy
+            from ditl_tpu.ops.fused_ce import fused_cross_entropy, loss_partition
 
             d = out.shape[-1]
             nll_sum = fused_cross_entropy(
@@ -76,6 +76,7 @@ def loss_fn(
                 mask.reshape(-1),
                 block_tokens=cfg.loss_block_tokens,
                 compute_dtype=jnp.dtype(cfg.dtype),
+                partition=loss_partition(mesh, rules),
             )
             ce = nll_sum / n_tokens
         else:

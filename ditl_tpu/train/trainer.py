@@ -278,7 +278,20 @@ def train(config: Config) -> dict[str, Any]:
         )
         state = init_fn(rng)
     n_params = llama.num_params(state.params)
-    logger.info("model %s: %.2fM params", model_cfg.name, n_params / 1e6)
+    # Which way the fused loss is laid over this mesh (ops/fused_ce.py): fixed
+    # per compiled step, so written once here, on the step's jit.compile
+    # event and in the summary (None: the naive loss, GSPMD's to partition).
+    loss_part = None
+    if model_cfg.loss_impl == "fused":
+        from ditl_tpu.ops.fused_ce import loss_partition
+
+        loss_part = str(loss_partition(mesh, rules) or "local")
+    for program in ("jit(train_step)", "jit(train_multi_step)"):
+        compile_counter().notes[program] = {"loss_partition": loss_part}
+    logger.info(
+        "model %s: %.2fM params, loss_partition %s",
+        model_cfg.name, n_params / 1e6, loss_part,
+    )
 
     # Checkpoint manager + resume.
     ckpt: CheckpointManager | None = None
@@ -787,6 +800,7 @@ def train(config: Config) -> dict[str, Any]:
     summary["native_dataprep"] = dataprep.loaded()
     # Parameters + optimizer state by device, from the arrays' shardings.
     summary["state_placement"] = placement(state)
+    summary["loss_partition"] = loss_part
     # Goodput report: where the wall clock went, conservation-checked (the
     # tier-1 test asserts buckets + other sum to total within 1%).
     summary["goodput"] = tracker.report()

@@ -52,13 +52,16 @@ class CompileCounter:
     """Programs built by this process (compiled, or loaded from the
     persistent cache) and the seconds that took, cumulative. ``journal``
     (telemetry/journal.py), when set, gets one ``jit.compile`` event per
-    program with its name and seconds: which step recompiled."""
+    program with its name and seconds: which step recompiled. ``notes``
+    maps a program's name to further fields of its event: facts fixed when
+    the program was built (the trainer's ``loss_partition``)."""
 
     def __init__(self):
         self._lock = threading.Lock()
         self.compile_count = 0  # guarded-by: _lock
         self.compile_s = 0.0  # guarded-by: _lock
         self.journal = None
+        self.notes: dict[str, dict] = {}
 
     def on_duration(self, event: str, duration_s: float, **kw) -> None:
         if event == _LOWERING_EVENT:
@@ -72,7 +75,8 @@ class CompileCounter:
             journal = self.journal
             if journal is not None:
                 journal.event("jit.compile", program=name,
-                              compile_s=round(duration_s, 6))
+                              compile_s=round(duration_s, 6),
+                              **self.notes.get(name, {}))
 
     def snapshot(self) -> dict:
         with self._lock:
