@@ -5,15 +5,32 @@ context the tokens really attend to, not at half the sequence. Packed
 documents attend only within themselves, so the traffic file states the mean
 causal context (``attention_context_mean``, counted once from the loader's
 own positions); half the sequence would count operations nobody needs.
+
+A family whose count is not this one (sparse experts, latent attention)
+exports ``forward_flops_per_token(config, context_mean)`` from its reference
+module, ``reference/<config["reference"]>.py``, and that one is used.
 """
 
 from __future__ import annotations
+
+import os
+
+from harness import HERE, load_module
+
+
+def family_count(config: dict):
+    """The reference module's own ``forward_flops_per_token``, or None."""
+    path = os.path.join(HERE, "reference", f"{config['reference']}.py")
+    return getattr(load_module(path), "forward_flops_per_token", None)
 
 
 def forward_flops_per_token(config: dict, context_mean: float) -> float:
     """Matmul FLOPs (2 per multiply-add) of one forward pass, per token.
     ``config``: the published config.json keys of the configuration file.
     ``context_mean``: mean number of keys a query attends to."""
+    own = family_count(config)
+    if own is not None:
+        return own(config, context_mean)
     d = config["hidden_size"]
     nh, nkv = config["num_attention_heads"], config["num_key_value_heads"]
     hd = d // nh

@@ -89,7 +89,8 @@ def start_server(ctx):
     config = ctx.config
     overrides = model_override_args(config, "serve")
     if ctx.rehearsal:
-        overrides += ctx.rehearsal["serve_overrides"]
+        # the shared tiny size, then what this family needs beside it
+        overrides += ctx.rehearsal["serve_overrides"] + config.get("rehearsal_overrides", [])
     vocab = config["vocab_size"]
     for o in overrides:
         if o.startswith("vocab_size="):

@@ -33,6 +33,7 @@ def build_argv(ctx) -> tuple[list[str], list[str]]:
     launch = list(traffic["launch_args"])
     if ctx.rehearsal:
         launch += ctx.rehearsal["train_launch_args"]
+        launch += [f"model.{o}" for o in config.get("rehearsal_overrides", [])]
     own = model_override_args(config, "train")
     model = own + [a[len("model."):] for a in launch if a.startswith("model.")]
     argv = ["--preset", config["preset"]] + [f"model.{o}" for o in own]

@@ -112,9 +112,14 @@ def _map_entry(buf):
     return key, value
 
 
+MODULES_LINE = "XLA Modules"
+_PROGRAM_RUN = re.compile(r"\(\d+\)$")  # jit_paged_decode(1234...): the run's id
+
+
 def _plane(buf) -> dict | None:
     """A device plane's ``XLA Ops`` events and the metadata they point at,
-    or None for any other plane."""
+    with its ``XLA Modules`` events (one per program run), or None for any
+    other plane."""
     name, lines, event_md, stat_md = "", [], [], {}
     for no, v in _fields(buf):
         if no == 2:
@@ -129,7 +134,7 @@ def _plane(buf) -> dict | None:
     m = reduce_trace.DEVICE_PLANE.match(name)
     if not m:
         return None
-    events = []
+    events, modules = [], []
     for line in lines:
         line_name, t0_ns, raw = "", 0, []
         for no, v in _fields(line):
@@ -139,8 +144,9 @@ def _plane(buf) -> dict | None:
                 t0_ns = v
             elif no == 4:
                 raw.append(v)
-        if line_name != reduce_trace.OPS_LINE:
+        if line_name not in (reduce_trace.OPS_LINE, MODULES_LINE):
             continue
+        into = events if line_name == reduce_trace.OPS_LINE else modules
         for ev in raw:
             mid = offset_ps = dur_ps = 0
             for no, v in _fields(ev):
@@ -150,7 +156,7 @@ def _plane(buf) -> dict | None:
                     offset_ps = v
                 elif no == 3:
                     dur_ps = v
-            events.append([mid, t0_ns * 1000 + offset_ps, dur_ps])
+            into.append([mid, t0_ns * 1000 + offset_ps, dur_ps])
     meta = {}
     for entry in event_md:
         key, value = _map_entry(entry)
@@ -170,25 +176,30 @@ def _plane(buf) -> dict | None:
                 if stat_md.get(sid) == "tf_op":
                     tf_op = s if s is not None else stat_md.get(ref, "")
         meta[str(key)] = [reduce_trace.short_name(text), tf_op]
-    return {"device": m.group(1), "events": events, "meta": meta}
+    modules = [[_PROGRAM_RUN.sub("", meta.get(str(mid), ["", ""])[0]), t, d]
+               for mid, t, d in modules]
+    return {"device": m.group(1), "events": events, "meta": meta, "modules": modules}
 
 
 def load(path: str) -> dict:
     """{"devices": {n: [[metadata id, start ps, duration ps], ...]}, "meta":
-    {n: {metadata id: [instruction name, tf_op]}}} from an ``.xplane.pb``, or
-    from the same as gzipped JSON (what ``tests/data`` keeps)."""
+    {n: {metadata id: [instruction name, tf_op]}}, "modules": {n: [[program,
+    start ps, duration ps], ...]}} from an ``.xplane.pb``, or from the same as
+    gzipped JSON (what ``tests/data`` keeps; a cut from before the serving
+    cell has no ``modules``)."""
     if path.endswith(".json.gz"):
         with gzip.open(path, "rt") as f:
             return json.load(f)
     with open(path, "rb") as f:
         space = memoryview(f.read())
-    out = {"devices": {}, "meta": {}}
+    out = {"devices": {}, "meta": {}, "modules": {}}
     for no, v in _fields(space):
         if no == 1:
             plane = _plane(v)
             if plane and plane["events"]:
                 out["devices"][plane["device"]] = plane["events"]
                 out["meta"][plane["device"]] = plane["meta"]
+                out["modules"][plane["device"]] = plane["modules"]
     return out
 
 
@@ -197,16 +208,33 @@ def load(path: str) -> dict:
 # --------------------------------------------------------------------------
 
 
-def seconds_by_name(trace: dict) -> dict:
+def seconds_by_name(trace: dict, program: str | None = None) -> dict:
     """{table name: seconds of self time, mean over the chips}; the key None
-    holds what ran under no table name. Empty without a device event."""
+    holds what ran under no table name. Empty without a device event.
+    ``program``: only the operations of that jitted function (``paged_decode``:
+    those whose path begins ``jit(paged_decode)/``)."""
     n = len(trace["devices"])
+    prefix = f"jit({program})/" if program else ""
     out: dict = {}
     for dev, events in trace["devices"].items():
         meta = trace["meta"][dev]
         for mid, self_ps, _leaf in reduce_trace.self_times(events):
-            name = innermost(meta.get(str(mid), ["", ""])[1])
+            tf_op = meta.get(str(mid), ["", ""])[1]
+            if not tf_op.startswith(prefix):
+                continue
+            name = innermost(tf_op)
             out[name] = out.get(name, 0.0) + self_ps / 1e12 / n
+    return out
+
+
+def seconds_by_program(trace: dict) -> dict:
+    """{program (``jit_paged_decode``): seconds its runs held the device,
+    mean over the chips}, from the ``XLA Modules`` line."""
+    modules = trace.get("modules", {})
+    out: dict = {}
+    for runs in modules.values():
+        for name, _start, dur_ps in runs:
+            out[name] = out.get(name, 0.0) + dur_ps / 1e12 / len(modules)
     return out
 
 
@@ -218,31 +246,55 @@ def trace_file(run: dict) -> str | None:
     return max(found, key=os.path.getmtime) if found else None
 
 
-@functools.lru_cache(maxsize=1)  # five readers ask for the same run's trace
-def _seconds_of(path: str) -> dict:
-    return seconds_by_name(load(path))
+@functools.lru_cache(maxsize=1)  # every reader asks for the same run's trace
+def _loaded(path: str) -> dict:
+    return load(path)
 
 
-def run_seconds_by_name(run: dict) -> dict | None:
+@functools.lru_cache(maxsize=4)
+def _seconds_of(path: str, program: str | None = None) -> dict:
+    return seconds_by_name(_loaded(path), program)
+
+
+def run_seconds_by_name(run: dict, program: str | None = None) -> dict | None:
     """``seconds_by_name`` of the run's own trace; None where there is no
     trace, or none of its events carries a table name (a program from
     before the names, a CPU rehearsal)."""
-    if run.get("trace") is None:
-        return None
-    path = trace_file(run)
+    path = trace_file(run) if run.get("trace") is not None else None
     if path is None:
         return None
-    by_name = _seconds_of(path)
+    by_name = _seconds_of(path, program)
     return by_name if any(k is not None for k in by_name) else None
 
 
-def time_share(run: dict, names) -> float | None:
-    """Self time under ``names`` over the trace's busy time, in percent, mean
-    over the chips. None, never 0, where the trace has none of them."""
-    by_name = run_seconds_by_name(run)
+def time_share(run: dict, names, program: str | None = None) -> float | None:
+    """Self time under ``names`` (inside ``program`` only, where given) over
+    the trace's busy time, in percent, mean over the chips. None, never 0,
+    where the trace has none of them."""
+    by_name = run_seconds_by_name(run, program)
     if by_name is None or not any(n in by_name for n in names):
         return None
     return 100.0 * sum(by_name.get(n, 0.0) for n in names) / run["trace"]["busy_s"]
+
+
+def program_share(run: dict, program: str) -> float | None:
+    """Device time of ``program``'s runs (``jit_paged_prefill``) over the
+    trace's busy time, in percent. None where the trace has no such run."""
+    path = trace_file(run) if run.get("trace") is not None else None
+    by_program = seconds_by_program(_loaded(path)) if path else {}
+    if program not in by_program:
+        return None
+    return 100.0 * by_program[program] / run["trace"]["busy_s"]
+
+
+def gap_share(run: dict, label: str) -> float | None:
+    """Idle time of device 0 whose middle lies in a host span named ``label``
+    (``reduce_trace``'s ``gap_s_by_label``) over the traced window, in
+    percent. None where no gap carries the label."""
+    gaps = (run.get("trace") or {}).get("gap_s_by_label", {})
+    if label not in gaps:
+        return None
+    return 100.0 * gaps[label] / run["trace"]["window_s"]
 
 
 def dump(trace: dict, path: str, from_s: float, to_s: float) -> None:
@@ -251,12 +303,14 @@ def dump(trace: dict, path: str, from_s: float, to_s: float) -> None:
     instructions were given), with the metadata they use, as the gzipped
     JSON that ``load`` reads."""
     lo, hi = from_s * 1e12, to_s * 1e12
-    cut = {"devices": {}, "meta": {}}
+    cut = {"devices": {}, "meta": {}, "modules": {}}
+    inside = lambda e: lo <= e[1] and e[1] + e[2] <= hi  # noqa: E731
     for dev, events in trace["devices"].items():
-        kept = [e for e in events if lo <= e[1] and e[1] + e[2] <= hi]
+        kept = [e for e in events if inside(e)]
         used = {str(e[0]) for e in kept}
         cut["devices"][dev] = kept
         cut["meta"][dev] = {k: v for k, v in trace["meta"][dev].items() if k in used}
+        cut["modules"][dev] = [e for e in trace.get("modules", {}).get(dev, []) if inside(e)]
     with gzip.open(path, "wt") as f:
         json.dump(cut, f, separators=(",", ":"))
 
@@ -280,6 +334,8 @@ def main(argv: list[str]) -> int:
     total = sum(by_name.values())
     for name, s in sorted(by_name.items(), key=lambda kv: -kv[1]):
         print(f"{name or '(no table name)':18s} {s:10.6f} s {100 * s / total:6.2f}%")
+    for name, s in sorted(seconds_by_program(trace).items(), key=lambda kv: -kv[1]):
+        print(f"program {name:28s} {s:10.6f} s {100 * s / total:6.2f}%")
     return 0
 
 

@@ -1,0 +1,12 @@
+"""Idle device time under the engine's ``engine.tick.schedule`` span
+(admission and its small device programs) over the traced window."""
+from layer_metrics import _scopes
+
+LAYER = "Scheduler"
+UNIT = "%"
+MOVES = "tpot_p50_ms"
+SOURCE = "device_trace"
+
+
+def read(run):
+    return _scopes.gap_share(run, "engine.tick.schedule")

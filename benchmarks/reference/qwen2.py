@@ -25,6 +25,21 @@ Parameters come as the pytree the program uses (layers stacked on axis 0),
 because the same seeded values have to go through both sides; each layer is
 sliced out and upcast on its own, so a 7B-wide model in bfloat16 fits one
 chip beside its float32 working copy of one layer.
+
+A family is its reference module. Besides ``forward`` and ``loss``, what
+``reference_check.compare`` asks of the module a configuration's
+``"reference"`` names:
+
+- ``check_sizes(cfg, config) -> [problems]``: the program's ModelConfig
+  against the published sizes of the configuration file;
+- ``sizes(cfg, config) -> dict``: everything ``forward`` and ``loss`` need
+  besides the weights, under the published config's keys;
+- optional, ``perturb(params, cfg, seed) -> params``: what the program's
+  initialiser leaves at a value that would hide a dropped term (here the
+  zero biases);
+- optional, ``forward_flops_per_token(config, context_mean)``: ``flops.py``
+  uses it where a family's count is not the dense decoder's (Qwen2's is: no
+  export).
 """
 
 from __future__ import annotations
@@ -33,6 +48,52 @@ import jax
 import jax.numpy as jnp
 
 F32 = jnp.float32
+
+# ModelConfig field -> key of the published config.json it must equal.
+PUBLISHED = {
+    "hidden_size": "hidden_size",
+    "intermediate_size": "intermediate_size",
+    "num_layers": "num_hidden_layers",
+    "num_heads": "num_attention_heads",
+    "num_kv_heads": "num_key_value_heads",
+    "vocab_size": "vocab_size",
+    "rope_theta": "rope_theta",
+    "rms_norm_eps": "rms_norm_eps",
+    "tie_embeddings": "tie_word_embeddings",
+    "max_seq_len": "max_position_embeddings",
+}
+
+
+def check_sizes(cfg, config: dict) -> list[str]:
+    """The program's ModelConfig against the published sizes in the
+    configuration file: a width that differs is an error, not a note. Two
+    sizes config.json has no key for are fixed by the architecture: biases on
+    q/k/v, and a head as wide as hidden / heads."""
+    want = {field: config[key] for field, key in PUBLISHED.items()}
+    want["head_dim"] = config["hidden_size"] // config["num_attention_heads"]
+    want["attention_bias"] = True
+    return [f"{k}: program {getattr(cfg, k)!r}, configuration file {v!r}"
+            for k, v in want.items() if getattr(cfg, k) != v]
+
+
+def perturb(params, cfg, seed: int):
+    """The q/k/v biases made non-zero: the program's initialiser zeros them,
+    and a zero bias would let a dropped bias pass."""
+    attn = dict(params["layers"]["attn"])
+    for i, name in enumerate(("bq", "bk", "bv")):
+        k = jax.random.fold_in(jax.random.key(seed), 1000 + i)
+        attn[name] = (0.1 * jax.random.normal(k, attn[name].shape, F32)
+                      ).astype(attn[name].dtype)
+    return {**params, "layers": {**params["layers"], "attn": attn}}
+
+
+def sizes(cfg, config: dict) -> dict:
+    """What ``forward`` needs besides the weights, as the program holds it
+    (``check_sizes`` has held the program to the file)."""
+    return {"num_attention_heads": cfg.num_heads,
+            "num_key_value_heads": cfg.num_kv_heads, "head_dim": cfg.head_dim,
+            "rope_theta": cfg.rope_theta, "rms_norm_eps": cfg.rms_norm_eps,
+            "tie_word_embeddings": cfg.tie_embeddings}
 
 
 def _rms_norm(x, scale, eps):
@@ -106,11 +167,13 @@ def forward(params, input_ids, sizes: dict, *, positions=None, segment_ids=None)
         return x @ head
 
 
-def loss(logits, input_ids, loss_mask):
-    """Mean next-token cross-entropy over the masked positions."""
+def loss(outputs, input_ids, loss_mask, sizes: dict | None = None):
+    """Mean next-token cross-entropy over the masked positions. ``outputs``
+    is whatever ``forward`` returned (here the logits; a family whose loss
+    has more terms returns a dict with ``"logits"`` and reads the rest)."""
     targets = input_ids[:, 1:]
     mask = loss_mask[:, 1:].astype(F32)
-    lg = logits[:, :-1]
+    lg = outputs[:, :-1]
     logz = jax.nn.logsumexp(lg, axis=-1)
     tgt = jnp.take_along_axis(lg, targets[..., None], axis=-1)[..., 0]
     return ((logz - tgt) * mask).sum() / jnp.maximum(mask.sum(), 1.0)

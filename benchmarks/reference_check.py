@@ -24,6 +24,7 @@ from harness import load_module
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
+REFERENCE_DIR = os.path.join(HERE, "reference")
 
 SAMPLE_TOKENS = 256
 SAMPLE_ROWS = 2
@@ -75,44 +76,15 @@ def model_config(config: dict, model_overrides: list[str]):
     return parse_overrides(cfg, [f"model.{o}" for o in model_overrides]).model
 
 
-def check_sizes(cfg, config: dict) -> list[str]:
-    """The program's ModelConfig against the published sizes in the
-    configuration file: a width that differs is an error, not a note."""
-    want = {
-        "hidden_size": config["hidden_size"],
-        "intermediate_size": config["intermediate_size"],
-        "num_layers": config["num_hidden_layers"],
-        "num_heads": config["num_attention_heads"],
-        "num_kv_heads": config["num_key_value_heads"],
-        "head_dim": config["hidden_size"] // config["num_attention_heads"],
-        "vocab_size": config["vocab_size"],
-        "rope_theta": config["rope_theta"],
-        "rms_norm_eps": config["rms_norm_eps"],
-        "tie_embeddings": config["tie_word_embeddings"],
-        "attention_bias": True,
-        "max_seq_len": config["max_position_embeddings"],
-    }
-    return [f"{k}: program {getattr(cfg, k)!r}, configuration file {v!r}"
-            for k, v in want.items() if getattr(cfg, k) != v]
-
-
-def seeded_params(cfg, seed: int):
+def seeded_params(cfg, seed: int, ref):
     """The program's own initialiser (so the tree has the program's layout
-    and dtype), with the q/k/v biases made non-zero: the initialiser zeros
-    them, and a zero bias would let a dropped bias pass."""
+    and dtype), then the family's ``perturb`` where it has one."""
     import jax
-    import jax.numpy as jnp
 
     from ditl_tpu.models import llama
 
     params = llama.init_params(jax.random.key(seed), cfg)
-    attn = dict(params["layers"]["attn"])
-    for i, name in enumerate(("bq", "bk", "bv")):
-        k = jax.random.fold_in(jax.random.key(seed), 1000 + i)
-        attn[name] = (0.1 * jax.random.normal(k, attn[name].shape, jnp.float32)
-                      ).astype(attn[name].dtype)
-    params["layers"] = {**params["layers"], "attn": attn}
-    return params
+    return ref.perturb(params, cfg, seed) if hasattr(ref, "perturb") else params
 
 
 def seeded_sample(vocab: int, seed: int, packed: bool):
@@ -144,29 +116,31 @@ def rel_rms(got, ref) -> float:
     return float(np.sqrt(np.mean((got - ref) ** 2)) / (np.sqrt(np.mean(ref ** 2)) + 1e-30))
 
 
-def compare(config: dict, spec: dict, seed: int = 0, quantize: bool = False) -> dict:
+def compare(config: dict, spec: dict, seed: int = 0, quantize: bool = False,
+            reference_dir: str = REFERENCE_DIR) -> dict:
     """Run both sides; returns the verdict record. ``quantize`` runs the
     program side on weight-only int8 weights instead: the demonstration
-    that the tolerance refuses a lower precision (never a cell's own check)."""
+    that the tolerance refuses a lower precision (never a cell's own check).
+
+    Everything that belongs to one family comes from its reference module,
+    ``<reference_dir>/<config["reference"]>.py`` (its docstring lists the
+    hooks): this file knows no architecture."""
     import jax
     import jax.numpy as jnp
 
     from ditl_tpu.models import llama
 
     cfg = model_config(config, spec["model_overrides"])
+    ref = load_module(os.path.join(reference_dir, f"{config['reference']}.py"))
     # The rehearsal runs a tiny model on the CPU: its sizes are not the
     # configuration's, and its verdict never reaches a result line.
-    problems = [] if spec.get("rehearsal") else check_sizes(cfg, config)
+    problems = [] if spec.get("rehearsal") else ref.check_sizes(cfg, config)
     if problems:
         return {"ok": False, "error": "sizes differ from the configuration "
                 "file: " + "; ".join(problems)}
-    ref = load_module(os.path.join(HERE, "reference", f"{config['reference']}.py"))
-    sizes = {"num_attention_heads": cfg.num_heads,
-             "num_key_value_heads": cfg.num_kv_heads, "head_dim": cfg.head_dim,
-             "rope_theta": cfg.rope_theta, "rms_norm_eps": cfg.rms_norm_eps,
-             "tie_word_embeddings": cfg.tie_embeddings}
+    sizes = ref.sizes(cfg, config)
     train = spec["role"] == "train"
-    params = seeded_params(cfg, seed)
+    params = seeded_params(cfg, seed, ref)
     ids, pos, seg, mask = (jnp.asarray(a) for a in
                            seeded_sample(cfg.vocab_size, seed, packed=train))
     kw = {"positions": pos, "segment_ids": seg} if train else {}
@@ -176,7 +150,10 @@ def compare(config: dict, spec: dict, seed: int = 0, quantize: bool = False) -> 
 
         run_params = quantize_weights(params)
     got = jax.jit(lambda p: llama.forward(p, ids, cfg, **kw))(run_params)
-    want = ref.forward(params, ids, sizes, **kw)
+    # the reference's logits, or a dict that holds them under "logits" beside
+    # what else its loss needs (a router's auxiliary term)
+    outputs = ref.forward(params, ids, sizes, **kw)
+    want = outputs["logits"] if isinstance(outputs, dict) else outputs
     out = {
         "logits_rel_rms": rel_rms(got, want),
         "logits_rel_rms_tol": LOGITS_REL_RMS_TOL,
@@ -193,7 +170,7 @@ def compare(config: dict, spec: dict, seed: int = 0, quantize: bool = False) -> 
         batch = {"input_ids": ids, "positions": pos, "segment_ids": seg,
                  "loss_mask": mask}
         got_loss = float(jax.jit(lambda p: loss_fn(p, batch, cfg)[0])(params))
-        want_loss = float(ref.loss(want, ids, mask))
+        want_loss = float(ref.loss(outputs, ids, mask, sizes))
         out.update(loss=got_loss, loss_reference=want_loss,
                    loss_rel=abs(got_loss - want_loss) / abs(want_loss),
                    loss_rel_tol=LOSS_REL_TOL)

@@ -72,7 +72,9 @@ def main() -> int:
         with open(os.path.join(OUT, "runs", f"{args.workload}.s{seed}.t0", "run.json")) as f:
             run = json.load(f)
         row = {"rate_per_s": rate, "seed": seed, **attainment(run, limits),
-               "failed": run["failed"], "setup_s": run["setup_s"]}
+               "failed": run["failed"], "setup_s": run["setup_s"],
+               # a run that reads far off its neighbours: did it compile?
+               "compiles_in_window": run["compiles_in_window"]}
         print(json.dumps({k: round(v, 3) if isinstance(v, float) else v
                           for k, v in row.items()}), flush=True)
     return 0

@@ -49,6 +49,27 @@ def test_open_loop_shares_nothing():
     assert not any(specials & set(r["ids"].tolist()) for r in reqs)
 
 
+def test_chat_steady_7b_keeps_chat_steadys_lengths_at_four_fifths_of_its_own_knee():
+    import math
+
+    a, b = traffic("chat-steady"), traffic("chat-steady-7b")
+    for k in ("generator", "prompt_tokens", "max_tokens", "sharing", "preroll_s",
+              "postroll_s", "tokenizer", "warmup"):
+        assert a[k] == b[k], k
+    assert b["server_args"] == a["server_args"] + ["--pages", "720"]
+    knee = b["knee"]
+    assert knee["cell_rate_per_s"] == b["rate_per_s"] == math.floor(
+        0.8 * knee["knee_rate_per_s"] / 0.5) * 0.5
+    col = knee["columns"].index
+    first = knee["rows"][0]
+    assert first[col("rate_per_s")] == 1.0  # the run the limits were derived from
+    assert b["limits"] == {
+        "ttft_ms": math.ceil(1.5 * first[col("ttft_p95_ms")] / 50) * 50,
+        "tpot_ms": math.ceil(1.5 * first[col("tpot_p95_ms")] / 5) * 5, "attainment": 0.9}
+    held = [r for r in knee["rows"] if r[col("attained")] >= 0.9 and not r[col("backlog_grows")]]
+    assert max(r[col("rate_per_s")] for r in held) == knee["knee_rate_per_s"]
+
+
 def test_lengths_reject_an_unknown_distribution():
     with pytest.raises(ValueError):
         serving.draw_lengths(np.random.default_rng(0), {"dist": "zipf", "min": 1, "max": 2}, 3)

@@ -120,7 +120,7 @@ def test_reads_the_xplane_the_profiler_writes_here_without_a_device(tmp_path):
     f(x).block_until_ready()
     jax.profiler.stop_trace()
     (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
-    assert sc.load(path) == {"devices": {}, "meta": {}}
+    assert sc.load(path) == {"devices": {}, "meta": {}, "modules": {}}
     assert sc.seconds_by_name(sc.load(path)) == {}
 
 
@@ -173,7 +173,7 @@ def test_the_plane_reader_finds_events_and_their_tf_op():
 
 
 # --------------------------------------------------------------------------
-# The seven readers
+# The readers
 # --------------------------------------------------------------------------
 
 
@@ -191,7 +191,7 @@ def test_time_share_readers(monkeypatch, name, want):
     never 0."""
     mod = reader(name)
     by = sc.seconds_by_name(known_trace())
-    monkeypatch.setattr(mod._scopes, "run_seconds_by_name", lambda run: by)
+    monkeypatch.setattr(mod._scopes, "run_seconds_by_name", lambda run, program=None: by)
     got = mod.read({"workload": "w", "trace": {"busy_s": 0.1}})
     assert got == (pytest.approx(want) if want is not None else None)
 
@@ -224,3 +224,111 @@ def test_compile_readers_take_the_rows_counters_and_skip_a_program_without_them(
     old = [{"step": 9, "loss": 1.0}]
     assert reader("compile_s_in_window_train").read({"rows": old}) is None
     assert reader("setup_compile_s_train").read({"rows": old}) is None
+
+
+# --------------------------------------------------------------------------
+# Serving: by program, by gap label, and the five readers of the serving cell
+# --------------------------------------------------------------------------
+
+
+def known_serving_trace():
+    """One chip, 100 ms busy: a decode run of 80 ms (a 50 ms whole-pool slice
+    under layer_scan, a 20 ms paged kernel, 10 ms of mlp) and a prefill run of
+    20 ms (5 ms under ITS layer_scan, 15 ms of mlp)."""
+    return {
+        "devices": {"0": [[1, 0, 50 * MS], [2, 50 * MS, 20 * MS], [3, 70 * MS, 10 * MS],
+                          [4, 100 * MS, 5 * MS], [5, 105 * MS, 15 * MS]]},
+        "meta": {"0": {
+            "1": ["dynamic-slice_bitcast_fusion.8",
+                  "jit(paged_decode)/while/body/closed_call/layer_scan/while/body/squeeze:"],
+            "2": ["paged_attention.3", "jit(paged_decode)/while/body/closed_call/layer_scan/"
+                                       "while/body/attn_core/paged_attention/pallas_call:"],
+            "3": ["fusion.4", "jit(paged_decode)/while/body/closed_call/layer_scan/while/"
+                              "body/mlp/dot_general:"],
+            "4": ["fusion.5", "jit(paged_prefill)/layer_scan/while/body/dynamic_slice:"],
+            "5": ["fusion.6", "jit(paged_prefill)/layer_scan/while/body/mlp/dot_general:"],
+        }},
+        "modules": {"0": [["jit_paged_decode", 0, 80 * MS], ["jit_paged_prefill", 100 * MS, 20 * MS],
+                          ["jit_scatter", 121 * MS, 0]]},
+    }
+
+
+def test_seconds_by_name_inside_one_program_and_seconds_by_program():
+    tr = known_serving_trace()
+    assert sc.seconds_by_name(tr)["layer_scan"] == pytest.approx(0.055)
+    assert sc.seconds_by_name(tr, "paged_decode") == {
+        "layer_scan": pytest.approx(0.050), "paged_attention": pytest.approx(0.020),
+        "mlp": pytest.approx(0.010)}
+    assert sc.seconds_by_name(tr, "no_such_program") == {}
+    by = sc.seconds_by_program(tr)
+    assert by["jit_paged_decode"] == pytest.approx(0.080)
+    assert by["jit_paged_prefill"] == pytest.approx(0.020)
+    assert sc.seconds_by_program(known_trace()) == {}  # a cut from before `modules`
+
+
+def serving_run(monkeypatch, trace, gaps):
+    monkeypatch.setattr(sc, "trace_file", lambda run: "serving.pb")
+    monkeypatch.setattr(sc, "load", lambda path: trace)
+    sc._loaded.cache_clear()
+    sc._seconds_of.cache_clear()
+    return {"workload": "w", "trace": {"busy_s": 0.1, "window_s": 0.125,
+                                       "gap_s_by_label": gaps}}
+
+
+@pytest.mark.parametrize("name,want", [
+    ("layer_scan_time_share_chat", 50.0),  # the decode program's, not the prefill's 5 ms
+    ("paged_attn_time_share_chat", 20.0),
+    ("prefill_device_share", 20.0),
+    ("harvest_idle_share_chat", 8.0),
+    ("schedule_idle_share_chat", 4.0),
+])
+def test_serving_readers_on_a_known_trace(monkeypatch, name, want):
+    mod = reader(name)
+    monkeypatch.setattr(mod, "_scopes", sc)
+    run = serving_run(monkeypatch, known_serving_trace(),
+                      {"engine.tick.harvest": 0.010, "engine.tick.schedule": 0.005,
+                       "unattributed": 0.010})
+    assert mod.read(run) == pytest.approx(want)
+
+
+@pytest.mark.parametrize("name", [
+    "layer_scan_time_share_chat", "paged_attn_time_share_chat", "prefill_device_share",
+    "harvest_idle_share_chat", "schedule_idle_share_chat"])
+def test_serving_readers_find_nothing_in_a_trainers_trace(monkeypatch, name):
+    """None, never 0: a trainer's trace has no decode program, no paged
+    kernel, no prefill run and no engine span; an untraced run has no trace."""
+    mod = reader(name)
+    monkeypatch.setattr(mod, "_scopes", sc)
+    assert mod.read(serving_run(monkeypatch, known_trace(), {"unattributed": 0.004})) is None
+    assert mod.read({"workload": "w", "trace": None}) is None
+
+
+SERVING = os.path.join(BENCH, "tests", "data", "chat7b_scopes.json.gz")
+
+
+def test_the_recorded_serving_cut_gives_its_known_shares(monkeypatch):
+    """A cut of the serving cell's first traced run on the chip (PR 25): one
+    whole decode tick and the prefills beside it, reduced as
+    ``train2k_scopes.json.gz`` was."""
+    with open(SERVING.replace(".json.gz", ".expected.json")) as f:
+        want = json.load(f)
+    trace = sc.load(SERVING)
+    assert sum(len(v) for v in trace["devices"].values()) == want["events"]
+    by = sc.seconds_by_name(trace)
+    for name, s in want["seconds_by_name"].items():
+        assert by[name or None] == pytest.approx(s, rel=1e-9), name
+    for name, s in want["seconds_by_program"].items():
+        assert sc.seconds_by_program(trace)[name] == pytest.approx(s, rel=1e-9), name
+    run = serving_run(monkeypatch, trace, want["gap_s_by_label"])
+    run["trace"].update(busy_s=want["busy_s"], window_s=want["window_s"])
+    for name, value in want["readers"].items():
+        mod = reader(name)
+        monkeypatch.setattr(mod, "_scopes", sc)
+        assert mod.read(run) == pytest.approx(value, rel=1e-9), name
+    assert set(want["readers"]) == {
+        "layer_scan_time_share_chat", "paged_attn_time_share_chat", "prefill_device_share",
+        "harvest_idle_share_chat", "schedule_idle_share_chat"}
+    # the whole-pool slice is nearly all of the decode program's layer_scan
+    decode = sc.seconds_by_name(trace, "paged_decode")
+    assert decode["layer_scan"] <= by["layer_scan"]
+    assert decode["paged_attention"] == pytest.approx(by["paged_attention"])
