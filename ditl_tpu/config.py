@@ -158,11 +158,18 @@ class ModelConfig:
     # Qwen2-style attention: bias on the q/k/v projections (o stays
     # bias-free, matching the family).
     attention_bias: bool = False
+    # OLMoE-style q/k normalisation: an RMSNorm with its own learned scale
+    # over the WHOLE projected q and k vectors (all heads together), between
+    # the projections and RoPE.
+    qk_norm: bool = False
     dtype: str = "bfloat16"  # activation/compute dtype
     param_dtype: str = "float32"  # master parameter dtype
     # MoE (Mixtral-style); num_experts == 0 disables.
     num_experts: int = 0
     num_experts_per_tok: int = 2
+    # Whether the chosen experts' softmax weights are rescaled to sum to 1
+    # (Mixtral does; OLMoE's ``norm_topk_prob: false`` keeps them as they are).
+    norm_topk_prob: bool = True
     router_aux_coef: float = 0.01  # Switch-style load-balancing loss weight
     # LoRA; rank 0 disables.
     lora_rank: int = 0

@@ -138,6 +138,17 @@ def _fsm_next(ftab: jax.Array, fstate: jax.Array, tok: jax.Array) -> jax.Array:
     return jnp.where(nxt >= 0, nxt, 1)
 
 
+def _max_over_mean(counts) -> float:
+    """The busiest expert's load over the mean load, averaged over the
+    layers: (L, E) assignment counts -> 1.0 when balanced, E when one expert
+    takes all; 0.0 before anything was counted."""
+    counts = np.asarray(counts, np.float64)
+    mean = counts.mean(axis=-1)
+    if not mean.all():
+        return 0.0
+    return round(float((counts.max(axis=-1) / mean).mean()), 4)
+
+
 @jax.named_scope("kv_write")
 def _flush_tail_into_pools(pools, tk, tv, starts, pos, table, ps, tail_len):
     """Scatter the tick's tail columns into their pages — ONE scatter per
@@ -833,6 +844,18 @@ class ContinuousEngine:
         # derives from the request seed, never from tick alignment).
         self.pipeline_ticks = bool(pipeline_ticks)
         self._pending_fetch: tuple | None = None
+        # Expert load of a model with experts (paged programs only): what the
+        # live rows of the decode ticks and the real tokens of the prefills
+        # were assigned, per layer and expert. The decode program returns its
+        # tick's counts with its tokens and a prefill's counts wait on the
+        # device for that same fetch, so counting costs no device sync of its
+        # own. Host state; /v1/stats derives its ``moe_*`` fields from it.
+        self.moe = model_cfg.num_experts > 0 and cache_mode == "paged"
+        self.moe_assignments = np.zeros(
+            (model_cfg.num_layers, model_cfg.num_experts), np.int64)
+        self.moe_touched_sum = 0  # sum over decode steps and layers
+        self.moe_decode_steps = 0
+        self._moe_pending: list = []  # prefills' (L, E) counts, on the device
         self._next_id = 0
         self.tick_count = 0  # scheduler ticks (the chaos seam's step index)
         self._prefill_cache: dict[int, Any] = {}
@@ -1604,6 +1627,13 @@ class ContinuousEngine:
         cd = jnp.dtype(cfg.dtype)
         quantized = cfg.kv_cache_dtype == "int8"
 
+        def moe_kw(s_len):
+            # experts: count the chunk's real tokens, not the bucket's padding
+            if not self.moe:
+                return {}
+            real = jnp.arange(s_bucket, dtype=jnp.int32)[None, :] < s_len
+            return {"token_mask": real, "with_moe_counts": True}
+
         def paged_prefill(params, pools, table_row, ids, offset, s_len, temp,
                           top_p, rng, write_pids, aid, *fsm):
             kp, vp = pools["kp"], pools["vp"]
@@ -1634,19 +1664,19 @@ class ContinuousEngine:
                 # over the chunk — flash-kernel path.
                 seg = (jnp.arange(s_bucket, dtype=jnp.int32)[None, :]
                        < s_len).astype(jnp.int32)
-                logits, row = llama.forward(
+                logits, row, *moe_counts = llama.forward(
                     params, ids, cfg, positions=q_pos[None], segment_ids=seg,
                     cache=row, cache_index=offset,
                     mesh=self.mesh, rules=self.rules, prefill_causal=True,
-                    adapter_ids=aid if self.multi_lora else None,
+                    adapter_ids=aid if self.multi_lora else None, **moe_kw(s_len),
                 )
             else:
                 mask = buf_iota[None, None, :] <= q_pos[None, :, None]
-                logits, row = llama.forward(
+                logits, row, *moe_counts = llama.forward(
                     params, ids, cfg, positions=q_pos[None],
                     cache=row, cache_index=offset, attn_mask=mask,
                     mesh=self.mesh, rules=self.rules,
-                    adapter_ids=aid if self.multi_lora else None,
+                    adapter_ids=aid if self.multi_lora else None, **moe_kw(s_len),
                 )
             def to_pages(r):  # (L, 1, s_bucket, K, D) -> (L, n_wp, K, ps, D)
                 chunk = jax.lax.dynamic_slice_in_dim(r, offset, s_bucket, axis=2)
@@ -1685,8 +1715,8 @@ class ContinuousEngine:
             fs = (_fsm_next(fsm[0], fsm[1], first),) if self.guided else ()
             if self.logprobs_k:
                 c, i, t = _lp_stats(last[None], first[None], self.logprobs_k)
-                return (out, first, c[0], i[0], t[0], *fs)
-            return (out, first, *fs)
+                return (out, first, c[0], i[0], t[0], *fs, *moe_counts)
+            return (out, first, *fs, *moe_counts)
 
         return jax.jit(paged_prefill, donate_argnums=(1,))
 
@@ -1709,6 +1739,7 @@ class ContinuousEngine:
         n_lp = self.logprobs_k
 
         guided = self.guided
+        moe = self.moe
 
         def paged_decode(params, pools, cur, pos, alive, temps, top_ps, keys,
                          table, limits, hist, adapters, *extra):
@@ -1725,7 +1756,7 @@ class ContinuousEngine:
             cache_const = dict(pools)  # pools are read-only during the scan
 
             def body(carry, t):
-                tk, tv, cur, pos, done, keys, hist, fst, lp = carry
+                tk, tv, cur, pos, done, keys, hist, fst, lp, moe_acc = carry
                 split = jax.vmap(lambda k: jax.random.split(k, 2))(keys)
                 keys, subs = split[:, 0], split[:, 1]
                 done = done | (pos >= limits)
@@ -1735,7 +1766,7 @@ class ContinuousEngine:
                     "table": table, "lengths": lengths, "starts": starts,
                     "t": t,
                 }
-                logits, tails = llama.forward(
+                logits, tails, *moe_counts = llama.forward(
                     params,
                     cur[:, None],
                     cfg,
@@ -1745,7 +1776,14 @@ class ContinuousEngine:
                     mesh=self.mesh,
                     rules=self.rules,
                     adapter_ids=adapters if self.multi_lora else None,
+                    **({"token_mask": step_alive[:, None],
+                        "with_moe_counts": True} if moe else {}),
                 )
+                if moe:
+                    # the live rows' assignments; the experts they touched
+                    counts, touched = moe_acc
+                    moe_acc = (counts + moe_counts[0],
+                               touched + (moe_counts[0] > 0).sum())
                 tk, tv = tails["tk"], tails["tv"]
                 step_logits = logits[:, 0]
                 nxt = sample_logits(
@@ -1771,12 +1809,14 @@ class ContinuousEngine:
 
                     grow = (~done).astype(jnp.int32)
                     hist = _emit_rows(hist, cur[:, None], pos, grow)
-                return (tk, tv, cur, pos, done, keys, hist, fst, lp), ys
+                return (tk, tv, cur, pos, done, keys, hist, fst, lp, moe_acc), ys
 
             fst0 = fstates if guided else jnp.zeros((), jnp.int32)
-            (tk, tv, cur, pos, done, keys, hist, fst, lp), ys = jax.lax.scan(
+            moe0 = ((jnp.zeros((L, cfg.num_experts), jnp.int32),
+                     jnp.zeros((), jnp.int32)) if moe else ())
+            (tk, tv, cur, pos, done, keys, hist, fst, lp, moe_acc), ys = jax.lax.scan(
                 body, (tk0, tv0, cur, pos, ~alive, keys, hist, fst0,
-                       tuple(lp0)),
+                       tuple(lp0), moe0),
                 jnp.arange(chunk, dtype=jnp.int32),
             )
 
@@ -1787,8 +1827,9 @@ class ContinuousEngine:
             if n_lp:
                 toks, c, i, t = ys
                 return (out, cur, pos, keys, hist, *fs, lp, toks.T,
-                        c.T, jnp.swapaxes(i, 0, 1), jnp.swapaxes(t, 0, 1))
-            return (out, cur, pos, keys, hist, *fs, ys.T)
+                        c.T, jnp.swapaxes(i, 0, 1), jnp.swapaxes(t, 0, 1),
+                        *moe_acc)
+            return (out, cur, pos, keys, hist, *fs, ys.T, *moe_acc)
 
         return jax.jit(paged_decode, donate_argnums=(1,))
 
@@ -2838,13 +2879,20 @@ class ContinuousEngine:
         pids[: min(len(write_pids), n_wp)] = write_pids[:n_wp]
         row = np.zeros((max(ctx, 1),), np.int32)
         row[: min(len(ctx_row), ctx)] = ctx_row[:ctx]
-        return self._take_prefill(program(
+        out = program(
             self.params, self.cache,
             jnp.asarray(row), jnp.asarray(ids), jnp.int32(d),
             jnp.int32(s), jnp.float32(temp), jnp.float32(top_p), rng,
             jnp.asarray(pids), jnp.asarray([adapter], jnp.int32),
             *self._fsm_args(fsm_start),
-        ), slot)
+        )
+        if self.moe:
+            # stays on the device until the next decode tick's fetch
+            *out, counts = out
+            self._moe_pending.append(counts)
+            if len(self._moe_pending) > 64:  # no plain tick drains them
+                self._moe_pending = [sum(self._moe_pending)]
+        return self._take_prefill(out, slot)
 
     def _paged_prefill_chunk(self, req: Request, slot: int, d: int, s: int,
                              s_bucket: int, rng):
@@ -4039,6 +4087,10 @@ class ContinuousEngine:
                 self.temps, self.top_ps, self.keys, self.hist, self.adapters,
                 *fsm_args, *lp_args,
             )
+        moe_dev = ()
+        if self.moe:  # paged: the tick's (L, E) counts and touched sum
+            *res, counts, touched = res
+            moe_dev = (counts, touched)
         if self.guided:
             (self.cache, self.cur, self.pos, self.keys, self.hist,
              self.fstates, *res_rest) = res
@@ -4053,28 +4105,46 @@ class ContinuousEngine:
         else:
             (toks,) = res_rest
             lp_dev = None
-        return ("plain", key, t0, toks, lp_dev, self._snapshot_slots())
+        return ("plain", key, t0, toks, lp_dev, self._snapshot_slots(), moe_dev)
 
     def _plain_finish(self, rec: tuple) -> None:
         """Fetch a dispatched plain tick's outputs + harvest."""
         import time as _time
 
-        (_, key, t0, toks, lp_dev, snapshot) = rec
+        (_, key, t0, toks, lp_dev, snapshot, moe_dev) = rec
         self._phase("engine.tick.fetch")
-        if lp_dev is not None:
-            # One fetch for everything (see _spec_finish).
-            toks, *lp_np = jax.device_get((toks, *lp_dev))
-            lp = tuple(np.asarray(x) for x in lp_np)
-            toks = np.asarray(toks)
-        else:
-            lp = None
-            toks = np.asarray(jax.device_get(toks))
+        moe_pending, self._moe_pending = self._moe_pending, []
+        # One fetch for everything (see _spec_finish): the experts' counts
+        # ride with the tokens.
+        toks, lp_np, moe_np, pending_np = jax.device_get(
+            (toks, lp_dev or (), moe_dev, moe_pending))
+        lp = tuple(np.asarray(x) for x in lp_np) if lp_dev is not None else None
+        toks = np.asarray(toks)
         self._phase("engine.tick.harvest")
+        if moe_np:
+            self._note_moe(*moe_np, pending_np)
         if self.speculative and (not self.pipeline_ticks or self._probe_timing):
             # See _spec_finish: pipelined intervals are not device cost,
             # but serial probe-tick intervals are.
             self._record_tick_time(key, (_time.perf_counter() - t0) * 1e3)
         self._harvest(toks, lp=lp, snapshot=snapshot)
+
+    def _note_moe(self, counts, touched, prefill_counts) -> None:
+        """Add one decode tick's expert counts (and the counts of the
+        prefills that ran before it) to the host's totals; an armed tracer's
+        ``engine.tick`` span carries the tick's own."""
+        counts = np.asarray(counts, np.int64)
+        self.moe_assignments += counts
+        for c in prefill_counts:
+            self.moe_assignments += np.asarray(c, np.int64)
+        self.moe_touched_sum += int(touched)
+        self.moe_decode_steps += self.decode_chunk
+        if self._tick_span is not None:
+            self._tick_span.annotate(
+                moe_assignments=int(counts.sum()), moe_touched=int(touched),
+                moe_steps=self.decode_chunk,
+                moe_load_max_over_mean=_max_over_mean(counts),
+            )
 
     def _finish_tick(self, rec: tuple) -> None:
         (self._spec_finish if rec[0] == "spec" else self._plain_finish)(rec)
@@ -4446,6 +4516,15 @@ class ContinuousEngine:
                 out["resume_prefill_tokens"] = self.resume_prefill_tokens
         if self.multi_lora:
             out["adapters"] = self.n_adapters
+        if self.moe:
+            # Live rows of the paged decode ticks and real tokens of the
+            # paged prefills only; the touched mean is per decode step and
+            # layer (a step with no live row touches none).
+            out["moe_assignments_total"] = int(self.moe_assignments.sum())
+            out["moe_load_max_over_mean"] = _max_over_mean(self.moe_assignments)
+            out["moe_experts_touched_mean"] = round(
+                self.moe_touched_sum
+                / max(1, self.moe_decode_steps * self.cfg.num_layers), 4)
         if self.guided:
             out["guided"] = {
                 "fsm_capacity": self.fsm_capacity,

@@ -1,4 +1,4 @@
-"""HuggingFace checkpoint import: torch Llama/Mixtral weights -> param pytree.
+"""HuggingFace checkpoint import: torch Llama/Qwen2/Mixtral/OLMoE weights -> param pytree.
 
 The reference never loads weights at all — its Llama-3.1-70B lives behind an
 HTTP API (ref ``src/distributed_inference.py:34-41``, ``MODEL_NAME`` in
@@ -66,6 +66,17 @@ def config_from_hf(hf_config: Any, **overrides) -> ModelConfig:
     if getattr(hf_config, "num_local_experts", 0):  # Mixtral
         kwargs["num_experts"] = hf_config.num_local_experts
         kwargs["num_experts_per_tok"] = hf_config.num_experts_per_tok
+    elif getattr(hf_config, "model_type", "") == "olmoe":
+        # q/k normalisation is fixed by the architecture (no config key);
+        # intermediate_size is the width of ONE expert.
+        kwargs["num_experts"] = hf_config.num_experts
+        kwargs["num_experts_per_tok"] = hf_config.num_experts_per_tok
+        kwargs["norm_topk_prob"] = bool(hf_config.norm_topk_prob)
+        kwargs["router_aux_coef"] = hf_config.router_aux_loss_coef
+        kwargs["qk_norm"] = True
+        if getattr(hf_config, "clip_qkv", None) is not None:
+            raise ValueError("OLMoE's clip_qkv is not implemented (the "
+                             "published 1B-7B configs leave it null)")
     scaling = getattr(hf_config, "rope_scaling", None)
     if scaling and scaling.get("rope_type", scaling.get("type")) == "llama3":
         kwargs["rope_scaling_factor"] = scaling["factor"]
@@ -81,6 +92,16 @@ def config_from_hf(hf_config: Any, **overrides) -> ModelConfig:
         )
     kwargs.update(overrides)
     return ModelConfig(**kwargs)
+
+
+def _moe_names(cfg: ModelConfig) -> tuple[str, str, str, str]:
+    """HF's names of an expert layer: (block, gate, up, down). Mixtral:
+    ``block_sparse_moe.gate`` and ``.experts.{j}.w1 / w3 / w2``; OLMoE (the
+    family with q/k normalisation): ``mlp.gate`` and ``.experts.{j}.gate_proj
+    / up_proj / down_proj``."""
+    if cfg.qk_norm:
+        return "mlp", "gate_proj", "up_proj", "down_proj"
+    return "block_sparse_moe", "w1", "w3", "w2"
 
 
 def _np(t) -> np.ndarray:
@@ -102,7 +123,7 @@ def _stack(sd: Mapping[str, Any], template: str, n_layers: int, transpose: bool)
 def params_from_state_dict(
     sd: Mapping[str, Any], cfg: ModelConfig, dtype: str | None = None
 ) -> dict[str, Any]:
-    """HF Llama/Mixtral state dict -> this framework's param pytree (numpy).
+    """HF Llama/Qwen2/Mixtral/OLMoE state dict -> this framework's param pytree (numpy).
 
     ``dtype`` defaults to ``cfg.param_dtype``. Keys follow HF's
     ``model.layers.{i}.*`` naming; both dense (Llama) and sparse (Mixtral)
@@ -155,9 +176,15 @@ def params_from_state_dict(
             "bk": cast(_stack(sd, "model.layers.{i}.self_attn.k_proj.bias", L, False)),
             "bv": cast(_stack(sd, "model.layers.{i}.self_attn.v_proj.bias", L, False)),
         })
-    if cfg.num_experts > 0:  # Mixtral-style sparse MLP
+    if cfg.qk_norm:  # OLMoE-family norms over the whole q and k vectors
+        params["layers"]["attn"].update({
+            "q_norm": cast(_stack(sd, "model.layers.{i}.self_attn.q_norm.weight", L, False)),
+            "k_norm": cast(_stack(sd, "model.layers.{i}.self_attn.k_norm.weight", L, False)),
+        })
+    if cfg.num_experts > 0:  # sparse MLP (Mixtral's or OLMoE's names)
         e = cfg.num_experts
-        router = _stack(sd, "model.layers.{i}.block_sparse_moe.gate.weight", L, True)
+        block, gate_name, up_name, down_name = _moe_names(cfg)
+        router = _stack(sd, "model.layers.{i}." + block + ".gate.weight", L, True)
 
         def experts(w_name: str, transpose: bool) -> np.ndarray:
             return np.stack(
@@ -167,7 +194,7 @@ def params_from_state_dict(
                             (lambda w: w.T if transpose else w)(
                                 _np(
                                     sd[
-                                        f"model.layers.{i}.block_sparse_moe."
+                                        f"model.layers.{i}.{block}."
                                         f"experts.{j}.{w_name}.weight"
                                     ]
                                 )
@@ -183,9 +210,9 @@ def params_from_state_dict(
 
         params["layers"]["moe"] = {
             "router": cast(router),
-            "w_gate": cast(experts("w1", True)),
-            "w_up": cast(experts("w3", True)),
-            "w_down": cast(experts("w2", True)),
+            "w_gate": cast(experts(gate_name, True)),
+            "w_up": cast(experts(up_name, True)),
+            "w_down": cast(experts(down_name, True)),
         }
     elif cfg.fused_gate_up:
         params["layers"]["mlp"] = {
@@ -245,14 +272,18 @@ def state_dict_from_params(params: Mapping[str, Any], cfg: ModelConfig) -> dict[
         if cfg.attention_bias:
             for ours, theirs in (("bq", "q_proj"), ("bk", "k_proj"), ("bv", "v_proj")):
                 sd[f"{p}.self_attn.{theirs}.bias"] = host(layers["attn"][ours][i])
+        if cfg.qk_norm:
+            for name in ("q_norm", "k_norm"):
+                sd[f"{p}.self_attn.{name}.weight"] = host(layers["attn"][name][i])
         if cfg.num_experts > 0:
             moe = layers["moe"]
-            sd[f"{p}.block_sparse_moe.gate.weight"] = host(moe["router"][i]).T
+            block, gate_name, up_name, down_name = _moe_names(cfg)
+            sd[f"{p}.{block}.gate.weight"] = host(moe["router"][i]).T
             for j in range(cfg.num_experts):
-                q = f"{p}.block_sparse_moe.experts.{j}"
-                sd[f"{q}.w1.weight"] = host(moe["w_gate"][i, j]).T
-                sd[f"{q}.w3.weight"] = host(moe["w_up"][i, j]).T
-                sd[f"{q}.w2.weight"] = host(moe["w_down"][i, j]).T
+                q = f"{p}.{block}.experts.{j}"
+                sd[f"{q}.{gate_name}.weight"] = host(moe["w_gate"][i, j]).T
+                sd[f"{q}.{up_name}.weight"] = host(moe["w_up"][i, j]).T
+                sd[f"{q}.{down_name}.weight"] = host(moe["w_down"][i, j]).T
         elif "w_gu" in layers["mlp"]:
             mlp = layers["mlp"]
             f = cfg.intermediate_size
@@ -299,7 +330,19 @@ def export_hf_model(params: Mapping[str, Any], cfg: ModelConfig, path: str) -> N
             "high_freq_factor": cfg.rope_scaling_high_freq_factor,
             "original_max_position_embeddings": cfg.rope_scaling_original_max_len,
         }
-    if cfg.num_experts > 0:
+    if cfg.num_experts > 0 and cfg.qk_norm:
+        from transformers import OlmoeConfig, OlmoeForCausalLM
+
+        common.pop("head_dim", None)  # OlmoeConfig derives it
+        hf_cfg = OlmoeConfig(
+            num_experts=cfg.num_experts,
+            num_experts_per_tok=cfg.num_experts_per_tok,
+            norm_topk_prob=cfg.norm_topk_prob,
+            router_aux_loss_coef=cfg.router_aux_coef,
+            **common,
+        )
+        model = OlmoeForCausalLM(hf_cfg)
+    elif cfg.num_experts > 0:
         hf_cfg = MixtralConfig(
             num_local_experts=cfg.num_experts,
             num_experts_per_tok=cfg.num_experts_per_tok,

@@ -93,6 +93,11 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
             "bk": jnp.zeros((L, nkv * hd), pd),
             "bv": jnp.zeros((L, nkv * hd), pd),
         })
+    if cfg.qk_norm:  # OLMoE-family: RMSNorm over the whole q and k vectors
+        params["layers"]["attn"].update({
+            "q_norm": jnp.ones((L, nh * hd), pd),
+            "k_norm": jnp.ones((L, nkv * hd), pd),
+        })
     if cfg.num_experts > 0:
         from ditl_tpu.models.moe import init_moe_params
 
@@ -139,6 +144,9 @@ def param_logical_axes(cfg: ModelConfig) -> Params:
                     "bk": ("layers", "kv_heads"),
                     "bv": ("layers", "kv_heads")}
                    if cfg.attention_bias else {}),
+                **({"q_norm": ("layers", "heads"),
+                    "k_norm": ("layers", "kv_heads")}
+                   if cfg.qk_norm else {}),
             },
             "mlp_norm": {"scale": ("layers", "norm")},
         },
@@ -310,8 +318,20 @@ def _decoder_layer(
     adapter_ids: jax.Array | None = None,
     paged: dict | None = None,
     prefill_causal: bool = False,
-) -> tuple[jax.Array, jax.Array] | tuple[jax.Array, jax.Array, dict]:
-    """One decoder block. With ``layer_cache`` (this layer's slice of the KV
+    token_mask: jax.Array | None = None,
+    with_moe_counts: bool = False,
+    moe_stack: dict | None = None,
+    layer_index: jax.Array | None = None,
+) -> tuple:
+    """One decoder block: ``(x, aux)``, then ``new_kv`` with a cache, then
+    (``with_moe_counts``) the (E,) assignments each expert got from the tokens
+    ``token_mask`` (B, S) marks live — all of them when None; the router's
+    load-balancing term ``aux`` sees the same tokens. ``moe_stack`` /
+    ``layer_index``: every layer's expert weights and which layer this is
+    (models/moe.py ``experts_in_place``); ``layer_params["moe"]`` then holds
+    only this layer's router.
+
+    With ``layer_cache`` (this layer's slice of the KV
     cache pytree, values shaped (B, Smax, K, D) — plus scales when int8,
     infer/cache.py), the chunk's keys/values are written at slot
     ``cache_index`` and attention runs against the whole cache under
@@ -375,6 +395,15 @@ def _decoder_layer(
             # Qwen2-family q/k/v bias (o stays bias-free).
             return t + attn[name].astype(t.dtype) if name in attn else t
 
+        def _heads(t, n, bias, norm=None):
+            # (B, S, n * hd) -> (B, S, n, hd), after the Qwen2-family bias
+            # and the OLMoE-family norm: an RMSNorm with its own scale over
+            # the WHOLE projected vector (all heads together).
+            t = _bias(t, bias)
+            if norm in attn:
+                t = rms_norm(t, attn[norm], cfg.rms_norm_eps)
+            return t.reshape(b, s, n, hd)
+
         if "w_qkv" in attn:
             # fused_qkv: one (D, (nh+2*nkv)*hd) GEMM replaces the q/k/v trio —
             # and one dgrad/wgrad pair replaces three each in the backward.
@@ -390,13 +419,13 @@ def _decoder_layer(
             q, k, v = jnp.split(
                 qkv, (nh * hd, (nh + nkv) * hd), axis=-1
             )
-            q = _bias(q, "bq").reshape(b, s, nh, hd)
-            k = _bias(k, "bk").reshape(b, s, nkv, hd)
-            v = _bias(v, "bv").reshape(b, s, nkv, hd)
+            q = _heads(q, nh, "bq", "q_norm")
+            k = _heads(k, nkv, "bk", "k_norm")
+            v = _heads(v, nkv, "bv")
         else:
-            q = _bias(proj(h, attn["wq"], "wq"), "bq").reshape(b, s, nh, hd)
-            k = _bias(proj(h, attn["wk"], "wk"), "bk").reshape(b, s, nkv, hd)
-            v = _bias(proj(h, attn["wv"], "wv"), "bv").reshape(b, s, nkv, hd)
+            q = _heads(proj(h, attn["wq"], "wq"), nh, "bq", "q_norm")
+            k = _heads(proj(h, attn["wk"], "wk"), nkv, "bk", "k_norm")
+            v = _heads(proj(h, attn["wv"], "wv"), nkv, "bv")
         q = apply_rope(q, positions, cfg=cfg)
         k = apply_rope(k, positions, cfg=cfg)
         q = _constrain(q, ("batch", "seq", "act_heads", "head_dim"), mesh, rules)
@@ -498,11 +527,14 @@ def _decoder_layer(
         h = rms_norm(x, layer_params["mlp_norm"]["scale"], cfg.rms_norm_eps)
         h = checkpoint_name(h, "mlp_in")  # gate/up wgrad operand (see attn_in)
         aux = jnp.zeros((), jnp.float32)
+        moe_counts = None
         if "moe" in layer_params:
             from ditl_tpu.models.moe import moe_block
 
-            mlp_out, aux = moe_block(
-                layer_params["moe"], h, cfg, mesh=mesh, rules=rules
+            mlp_out, aux, moe_counts = moe_block(
+                {**layer_params["moe"], **(moe_stack or {})}, h, cfg,
+                token_mask=token_mask, mesh=mesh,
+                layer=layer_index if moe_stack else None,
             )
         else:
             mlp = layer_params["mlp"]
@@ -559,9 +591,12 @@ def _decoder_layer(
                 )
         x = x + mlp_out
         x = _constrain(x, ("batch", "seq", "act_embed"), mesh, rules)
-    if new_kv is not None:
-        return x, aux, new_kv
-    return x, aux
+    out = (x, aux) if new_kv is None else (x, aux, new_kv)
+    if with_moe_counts:
+        if moe_counts is None:
+            raise ValueError("with_moe_counts needs a model with experts")
+        out += (moe_counts,)
+    return out
 
 
 def forward(
@@ -581,8 +616,17 @@ def forward(
     adapter_ids: jax.Array | None = None,
     paged: dict | None = None,
     prefill_causal: bool = False,
+    token_mask: jax.Array | None = None,
+    with_moe_counts: bool = False,
 ) -> Any:
     """Token ids (B, S) -> logits (B, S, V) in float32.
+
+    ``token_mask`` (B, S), for a model with experts: the tokens that count
+    (unmasked training tokens; a decode tick's live slots; a prefill bucket's
+    real tokens). The router's load-balancing term and the assignment counts
+    see only these; None counts all. ``with_moe_counts=True`` returns, last,
+    the (L, E) int32 assignments each layer's experts got from them (not
+    under pipeline parallelism).
 
     ``return_hidden=True`` skips the lm-head projection and returns the
     final-normed hidden states (B, S, D) instead of logits — the fused
@@ -590,7 +634,8 @@ def forward(
     full logits tensor is never materialized.
 
     ``with_aux=True`` additionally returns the summed per-layer auxiliary loss
-    (MoE router load balancing; zero for dense models).
+    (MoE router load balancing; zero for dense models; the loss averages it
+    over the layers, train/step.py).
 
     ``cache`` (``{"k": (L,B,Smax,K,D), "v": ...}``, see infer/cache.py) turns
     this into the incremental-decode forward: the chunk's K/V are written into
@@ -626,9 +671,19 @@ def forward(
         x = _constrain(x, ("batch", "seq", "act_embed"), mesh, rules)
 
     if cache is not None:
+        layers, moe_stack = params["layers"], None
+        if "moe" in layers:
+            from ditl_tpu.models.moe import experts_in_place
+
+            if experts_in_place(layers["moe"], b * s * cfg.num_experts_per_tok, mesh):
+                # the loop slices only the router; the kernel addresses the
+                # layer's experts inside the stack
+                moe_stack = {k: v for k, v in layers["moe"].items() if k != "router"}
+                layers = {**layers, "moe": {"router": layers["moe"]["router"]}}
+
         def cached_layer_fn(carry, xs):
-            layer_params, layer_cache = xs
-            y, aux, new_kv = _decoder_layer(
+            layer_params, layer_cache, layer_index = xs
+            y, aux, new_kv, *counts = _decoder_layer(
                 layer_params,
                 carry,
                 cfg=cfg,
@@ -642,15 +697,20 @@ def forward(
                 adapter_ids=adapter_ids,
                 paged=paged,
                 prefill_causal=prefill_causal,
+                token_mask=token_mask,
+                with_moe_counts=with_moe_counts,
+                moe_stack=moe_stack,
+                layer_index=layer_index,
             )
-            return y, (aux, new_kv)
+            return y, (aux, new_kv, *counts)
 
         # Every layer part has a scope of its own, so what is left to this
         # one is what the scan itself does: slicing each layer's weights and
         # cache out of the stacked arrays and stacking the new K/V.
         with jax.named_scope("layer_scan"):
-            x, (layer_aux, new_cache) = jax.lax.scan(
-                cached_layer_fn, x, (params["layers"], cache)
+            x, (layer_aux, new_cache, *moe_counts) = jax.lax.scan(
+                cached_layer_fn, x,
+                (layers, cache, jnp.arange(cfg.num_layers, dtype=jnp.int32)),
             )
     elif mesh is not None and mesh.shape.get("stage", 1) > 1:
         # Pipeline parallelism: layers are stage-sharded; microbatches flow
@@ -658,11 +718,15 @@ def forward(
         # run inside shard_map, so no GSPMD constraints (mesh=None).
         from ditl_tpu.parallel.pipeline import pipeline_apply
 
+        if with_moe_counts:
+            raise ValueError("with_moe_counts is not carried through the "
+                             "pipeline schedule")
+
         def pipe_layer(h, layer_params, ex):
-            pos, seg = ex
+            pos, seg, tmask = ex
             return _decoder_layer(
                 layer_params, h, cfg=cfg, positions=pos, segment_ids=seg,
-                mesh=None, rules=None,
+                mesh=None, rules=None, token_mask=tmask,
             )
 
         pipe_layer = _apply_remat(pipe_layer, cfg)
@@ -671,15 +735,15 @@ def forward(
                 pipe_layer,
                 params["layers"],
                 x,
-                (positions, segment_ids),
+                (positions, segment_ids, token_mask),
                 mesh=mesh,
                 rules=rules,
                 n_microbatches=cfg.pipeline_microbatches or None,
             )
-        new_cache = None
+        new_cache, moe_counts = None, []
     else:
         def layer_fn(carry, layer_params):
-            return _decoder_layer(
+            y, *ys = _decoder_layer(
                 layer_params,
                 carry,
                 cfg=cfg,
@@ -688,23 +752,26 @@ def forward(
                 mesh=mesh,
                 rules=rules,
                 adapter_ids=adapter_ids,
+                token_mask=token_mask,
+                with_moe_counts=with_moe_counts,
             )
+            return y, tuple(ys)
 
         layer_fn = _apply_remat(layer_fn, cfg)
         with jax.named_scope("layer_scan"):
-            x, layer_aux = jax.lax.scan(
+            x, (layer_aux, *moe_counts) = jax.lax.scan(
                 layer_fn, x, params["layers"], unroll=cfg.scan_unroll
             )
         new_cache = None
 
     with jax.named_scope("lm_head"):
         x = rms_norm(x, params["final_norm"]["scale"], cfg.rms_norm_eps)
+    # what follows the logits (or the hidden states), in this order
+    tail = ((jnp.sum(layer_aux),) if with_aux else ()) + (
+        (new_cache,) if cache is not None else ()) + tuple(moe_counts)
     if return_hidden:
         out = (x,)
-        if with_aux:
-            out = out + (jnp.sum(layer_aux),)
-        if cache is not None:
-            out = out + (new_cache,)
+        out = out + tail
         return out if len(out) > 1 else x
     from ditl_tpu.ops.quant import weight_einsum
 
@@ -714,9 +781,5 @@ def forward(
             compute_dtype=cd, preferred=jnp.float32,
         )
         logits = _constrain(logits, ("batch", "seq", "act_vocab"), mesh, rules)
-    out = (logits,)
-    if with_aux:
-        out = out + (jnp.sum(layer_aux),)
-    if cache is not None:
-        out = out + (new_cache,)
+    out = (logits,) + tail
     return out if len(out) > 1 else logits

@@ -116,6 +116,26 @@ PRESETS: dict[str, ModelConfig] = {
         num_experts=8,
         num_experts_per_tok=2,
     ),
+    # OLMoE-1B-7B (allenai, arXiv:2409.02060): 64 experts of width 1024, 8 a
+    # token with their softmax weights NOT renormalised, q/k normalisation,
+    # 16 kv heads (no grouped queries), untied head.
+    "olmoe-1b-7b": ModelConfig(
+        name="olmoe-1b-7b",
+        vocab_size=50304,
+        hidden_size=2048,
+        intermediate_size=1024,
+        num_layers=16,
+        num_heads=16,
+        num_kv_heads=16,
+        head_dim=128,
+        max_seq_len=4096,
+        rope_theta=10000.0,
+        rms_norm_eps=1e-5,
+        qk_norm=True,
+        num_experts=64,
+        num_experts_per_tok=8,
+        norm_topk_prob=False,
+    ),
 }
 
 

@@ -11,7 +11,15 @@ K/V cache or page pool) out of the stacked arrays, stacking what the layers
 return, and what remat saves between forward and backward. ``kv_gather`` is
 an explicit read of cached K/V (the contiguous cache's ``read_kv``, a paged
 prefill's gather of its context pages); the paged decode program has none,
-its kernel reads the pages and its whole-pool slice is ``layer_scan``'s."""
+its kernel reads the pages and its whole-pool slice is ``layer_scan``'s.
+
+``MOE_SCOPES`` are the parts of an expert layer (``models/moe.py``), all
+INSIDE ``mlp``: to a reader that knows only ``SCOPES`` an expert layer's time
+is ``mlp``'s, as a dense layer's is; to one that also knows these (innermost
+wins) ``mlp`` keeps only the norm. ``moe_router``: the router's matmul and
+softmax; ``moe_dispatch``: top-k, the sort by expert and the gather of the
+token rows; ``moe_experts``: the three grouped matmuls; ``moe_combine``: the
+rows back in token order, weighted and summed."""
 
 SCOPES = (
     "embed",
@@ -27,6 +35,18 @@ SCOPES = (
     "kv_write",
     "sample",
 )
+
+MOE_SCOPES = (
+    "moe_router",
+    "moe_dispatch",
+    "moe_experts",
+    "moe_combine",
+)
+
+# The grouped matmul of ``moe_experts`` on one TPU chip is the library's
+# kernel (``jax.experimental.pallas.ops.tpu.megablox``): these are ITS names,
+# forward and the transposed product of the backward pass.
+MOE_KERNELS = ("gmm", "tgmm")
 
 KERNELS = (
     "flash_fwd",

@@ -53,7 +53,9 @@ def loss_fn(
     mask = batch["loss_mask"][:, 1:].astype(jnp.float32)
     n_tokens = jnp.maximum(mask.sum(), 1.0)
     fused = cfg.loss_impl == "fused"
-    out, aux = llama.forward(
+    sparse = cfg.num_experts > 0
+    counted = "moe_load_max_over_mean" in moe_metric_names(cfg, mesh)
+    out, aux, *moe_counts = llama.forward(
         params,
         batch["input_ids"],
         cfg,
@@ -63,6 +65,9 @@ def loss_fn(
         rules=rules,
         with_aux=True,
         return_hidden=fused,
+        # the router's load-balancing term sees the tokens the loss counts
+        token_mask=batch["loss_mask"] if sparse else None,
+        with_moe_counts=counted,
     )
     with jax.named_scope("loss"):
         if fused:
@@ -87,8 +92,27 @@ def loss_fn(
             )[..., 0]
             nll = (logz - target_logit) * mask
             ce = nll.sum() / n_tokens
-    loss = ce + cfg.router_aux_coef * aux if cfg.num_experts > 0 else ce
-    return loss, {"loss": ce, "n_tokens": mask.sum()}
+    metrics = {"loss": ce, "n_tokens": mask.sum()}
+    if not sparse:
+        return ce, metrics
+    # E * sum_e f_e P_e per layer, averaged over the layers (1 when balanced)
+    metrics["router_aux_loss"] = aux / cfg.num_layers
+    if counted:
+        counts = moe_counts[0].astype(jnp.float32)  # (L, E)
+        metrics["moe_load_max_over_mean"] = jnp.mean(
+            counts.max(axis=-1) / jnp.maximum(counts.mean(axis=-1), 1e-9))
+    return ce + cfg.router_aux_coef * metrics["router_aux_loss"], metrics
+
+
+def moe_metric_names(cfg: ModelConfig, mesh) -> tuple[str, ...]:
+    """What a model with experts adds to the step's metrics (and so to the
+    ``metrics_file`` rows). The assignment counts behind the load ratio do
+    not ride the pipeline schedule."""
+    if cfg.num_experts == 0:
+        return ()
+    if mesh is not None and mesh.shape.get("stage", 1) > 1:
+        return ("router_aux_loss",)
+    return ("router_aux_loss", "moe_load_max_over_mean")
 
 
 def batch_logical_axes(example_batch: dict[str, Any]) -> dict[str, tuple]:
@@ -111,6 +135,7 @@ def _build_step_fn(
         return tx
 
     accum = train_cfg.grad_accum_steps
+    moe_names = moe_metric_names(model_cfg, mesh)
 
     def single_loss(params, batch):
         # Cast float32 master params to the compute dtype ONCE per step:
@@ -141,24 +166,28 @@ def _build_step_fn(
             )
 
             def micro_step(carry, mb):
-                grads_acc, loss_acc, tok_acc = carry
+                grads_acc, loss_acc, tok_acc, moe_acc = carry
                 (loss, aux), grads = jax.value_and_grad(single_loss, has_aux=True)(
                     state.params, mb
                 )
                 grads_acc = jax.tree.map(jnp.add, grads_acc, grads)
-                return (grads_acc, loss_acc + loss, tok_acc + aux["n_tokens"]), None
+                moe_acc = tuple(a + aux[k] for a, k in zip(moe_acc, moe_names))
+                return (grads_acc, loss_acc + loss, tok_acc + aux["n_tokens"],
+                        moe_acc), None
 
             zero_grads = jax.tree.map(jnp.zeros_like, state.params)
-            (grads, loss_sum, tokens), _ = jax.lax.scan(
-                micro_step, (zero_grads, 0.0, 0.0), micro
+            (grads, loss_sum, tokens, moe_sums), _ = jax.lax.scan(
+                micro_step, (zero_grads, 0.0, 0.0, (0.0,) * len(moe_names)), micro
             )
             grads = jax.tree.map(lambda g: g / accum, grads)
             loss = loss_sum / accum
+            moe_metrics = {k: v / accum for k, v in zip(moe_names, moe_sums)}
         else:
             (loss, aux), grads = jax.value_and_grad(single_loss, has_aux=True)(
                 state.params, batch
             )
             tokens = aux["n_tokens"]
+            moe_metrics = {k: aux[k] for k in moe_names}
         with jax.named_scope("optimizer"):
             updates, new_opt = tx.update(grads, state.opt_state, state.params)
             new_params = jax.tree.map(
@@ -166,7 +195,8 @@ def _build_step_fn(
             )
             grad_norm = optax_global_norm(grads)
         new_state = TrainState(step=state.step + 1, params=new_params, opt_state=new_opt)
-        metrics = {"loss": loss, "n_tokens": tokens, "grad_norm": grad_norm}
+        metrics = {"loss": loss, "n_tokens": tokens, "grad_norm": grad_norm,
+                   **moe_metrics}
         if train_cfg.fault_nan_step > 0:
             # Anomaly-plane drill (ISSUE 10): a real device NaN in the
             # REPORTED loss at exactly this step — it rides the compiled
@@ -190,7 +220,8 @@ def _shardings_for(model_cfg, train_cfg, mesh, example_batch, rules):
     batch_shardings = named_sharding_tree(mesh, batch_logical_axes(example_batch), rules)
     replicated = NamedSharding(mesh, P())
     metric_shardings = {
-        "loss": replicated, "n_tokens": replicated, "grad_norm": replicated
+        k: replicated for k in ("loss", "n_tokens", "grad_norm",
+                                *moe_metric_names(model_cfg, mesh))
     }
     return state_shardings, batch_shardings, metric_shardings
 

@@ -13,7 +13,11 @@ torch = pytest.importorskip("torch")
 transformers = pytest.importorskip("transformers")
 
 from ditl_tpu.models import llama
-from ditl_tpu.models.convert import config_from_hf, params_from_state_dict
+from ditl_tpu.models.convert import (
+    config_from_hf,
+    params_from_state_dict,
+    state_dict_from_params,
+)
 
 
 def _tiny_hf_llama(tie=False):
@@ -154,6 +158,68 @@ def test_mixtral_logits_parity():
 
     ours = np.asarray(llama.forward(params, jnp.asarray(ids, jnp.int32), cfg))
     np.testing.assert_allclose(ours, hf_logits, rtol=5e-4, atol=5e-4)
+
+
+def _tiny_hf_olmoe(norm_topk_prob=False):
+    cfg_hf = transformers.OlmoeConfig(
+        vocab_size=256, hidden_size=64, intermediate_size=32,
+        num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=4,
+        num_experts=8, num_experts_per_tok=2, norm_topk_prob=norm_topk_prob,
+        max_position_embeddings=128, rms_norm_eps=1e-5, rope_theta=10000.0,
+        tie_word_embeddings=False,
+    )
+    torch.manual_seed(2)
+    model = transformers.OlmoeForCausalLM(cfg_hf).eval()
+    with torch.no_grad():  # a norm scale of 1 would let a dropped scale pass
+        for layer in model.model.layers:
+            for norm in (layer.self_attn.q_norm, layer.self_attn.k_norm):
+                norm.weight.mul_(1.0 + 0.3 * torch.randn_like(norm.weight))
+    return model
+
+
+@pytest.mark.parametrize("norm_topk_prob", [False, True])
+def test_olmoe_logits_parity_and_round_trip(norm_topk_prob):
+    """The published model's Hugging Face names load (``mlp.gate``,
+    ``mlp.experts.{j}.gate_proj / up_proj / down_proj``, ``self_attn.q_norm /
+    k_norm``), the program agrees with ``OlmoeForCausalLM`` on its logits, and
+    the export gives the state dict back. Two layers in float32: a router's
+    near-tie can flip on float noise, so the tolerance is Mixtral's."""
+    model = _tiny_hf_olmoe(norm_topk_prob)
+    cfg = config_from_hf(model.config, dtype="float32")
+    assert (cfg.num_experts, cfg.num_experts_per_tok) == (8, 2)
+    assert cfg.qk_norm and cfg.norm_topk_prob is norm_topk_prob
+    assert not cfg.attention_bias and not cfg.tie_embeddings
+    sd = model.state_dict()
+    params = params_from_state_dict(sd, cfg)
+    assert params["layers"]["attn"]["q_norm"].shape == (2, 64)
+    assert params["layers"]["moe"]["w_gate"].shape == (2, 8, 64, 32)
+
+    rng = np.random.default_rng(2)
+    ids = rng.integers(0, 256, size=(2, 16)).astype(np.int64)
+    with torch.no_grad():
+        hf_logits = model(torch.from_numpy(ids)).logits.numpy()
+    ours = np.asarray(llama.forward(params, jnp.asarray(ids, jnp.int32), cfg))
+    np.testing.assert_allclose(ours, hf_logits, rtol=5e-4, atol=5e-4)
+
+    back = state_dict_from_params(params, cfg)
+    assert set(back) == {k for k in sd if "rotary_emb" not in k}
+    for k, v in back.items():
+        np.testing.assert_allclose(v, sd[k].numpy(), rtol=1e-6, atol=1e-7)
+
+
+def test_olmoe_export_loads_in_transformers(tmp_path):
+    model = _tiny_hf_olmoe()
+    cfg = config_from_hf(model.config, dtype="float32")
+    params = params_from_state_dict(model.state_dict(), cfg)
+    from ditl_tpu.models.convert import export_hf_model
+
+    export_hf_model(params, cfg, str(tmp_path / "out"))
+    again = transformers.OlmoeForCausalLM.from_pretrained(str(tmp_path / "out")).eval()
+    ids = torch.from_numpy(np.arange(12, dtype=np.int64)[None] + 3)
+    with torch.no_grad():
+        np.testing.assert_allclose(again(ids).logits.numpy(), model(ids).logits.numpy(),
+                                   rtol=1e-5, atol=1e-5)
+    assert again.config.norm_topk_prob is False and again.config.num_experts == 8
 
 
 def test_config_from_hf_fields():

@@ -140,11 +140,14 @@ def test_pipeline_moe_aux_matches(devices8):
     # stage axis, or each data shard trains on a different loss).
     shard_vals = [float(np.asarray(s.data)) for s in pipe_loss.addressable_shards]
     assert len(set(shard_vals)) == 1, f"loss diverges across devices: {shard_vals}"
-    # MoE under microbatching is only approximately schedule-invariant: the
-    # capacity-factor dispatch (moe.py) drops tokens per *microbatch*, and the
-    # router aux is averaged over microbatches — both standard semantics for
-    # pipelined MoE, so compare loosely rather than exactly.
+    # The expert layer is token-exact (no capacity, no drop), so the
+    # cross-entropy does not depend on how the batch is cut into microbatches:
+    # float32 summation order only. The router's load-balancing term is a
+    # product of two means, taken per microbatch and averaged by the schedule
+    # (standard for pipelined MoE), so it is not the whole batch's (1.30
+    # against 1.06 here); at its weight of 0.01 that is 4e-4 of the loss.
     np.testing.assert_allclose(
-        float(pipe_aux["loss"]), float(ref_aux["loss"]), rtol=1e-2
+        float(pipe_aux["loss"]), float(ref_aux["loss"]), rtol=1e-5
     )
-    np.testing.assert_allclose(float(pipe_loss), float(ref_loss), rtol=2e-2)
+    np.testing.assert_allclose(float(pipe_loss), float(ref_loss), rtol=1e-3)
+    assert float(pipe_aux["router_aux_loss"]) >= 1.0 - 1e-6

@@ -1,0 +1,98 @@
+"""Compiled for a described v5e, with no chip attached: what this repo's
+first expert layer asks of the TPU's compiler at the published OLMoE widths.
+The Mosaic kernels of the grouped matmul (forward, and the transposed product
+of the backward pass, whose tiles have to fit 16 MB of scoped VMEM: a
+2,048-wide contraction tile did not) and the paged decode kernel with ONE
+query head to a kv head (7 in both Qwen2 sizes). A compile that passes is not
+a chip run: nothing here is a time.
+
+One file, and the topology is described inside a fixture: only one process
+may load the TPU's library at a time (``on-chip-measurement`` guide).
+"""
+
+from __future__ import annotations
+
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from ditl_tpu.models import moe as moe_mod
+from ditl_tpu.models.presets import get_preset
+from ditl_tpu.ops import names
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure to describe it is a skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def tpu_branch(monkeypatch):
+    """The code asks ``jax.default_backend()``, which is the CPU here: take
+    the branch the chip takes."""
+    monkeypatch.setattr(moe_mod, "_use_gmm", lambda rows, mesh: rows % 128 == 0)
+    from ditl_tpu.ops import backend
+
+    monkeypatch.setattr(moe_mod, "interpret_default", lambda: False)
+    monkeypatch.setattr(backend, "interpret_default", lambda: False)
+
+
+def _moe_shapes(cfg, sharding):
+    d, f, e = cfg.hidden_size, cfg.intermediate_size, cfg.num_experts
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sharding)  # noqa: E731
+    return {"router": s((d, e), jnp.float32), "w_gate": s((e, d, f), jnp.bfloat16),
+            "w_up": s((e, d, f), jnp.bfloat16), "w_down": s((e, f, d), jnp.bfloat16)}
+
+
+def _instructions(text: str) -> set[str]:
+    return {m.split(".")[0] for m in re.findall(r"%([\w\-.]+) = [^\n]*custom-call", text)}
+
+
+@pytest.mark.parametrize("rows", [(64, 1), (1, 2048)], ids=["decode-64-slots", "prefill-2048"])
+def test_expert_layer_forward_compiles_at_olmoe_widths(one_chip, tpu_branch, rows):
+    cfg = get_preset("olmoe-1b-7b")
+    h = jax.ShapeDtypeStruct((*rows, cfg.hidden_size), jnp.bfloat16, sharding=one_chip)
+    compiled = jax.jit(lambda m, x: moe_mod.moe_block(m, x, cfg)).lower(
+        _moe_shapes(cfg, one_chip), h).compile()
+    assert names.MOE_KERNELS[0] in _instructions(compiled.as_text())
+
+
+def test_expert_layer_backward_compiles_at_olmoe_widths(one_chip, tpu_branch):
+    cfg = get_preset("olmoe-1b-7b")
+    h = jax.ShapeDtypeStruct((1, 2048, cfg.hidden_size), jnp.bfloat16, sharding=one_chip)
+
+    def loss(m, x):
+        out, aux, _ = moe_mod.moe_block(m, x, cfg)
+        return (out.astype(jnp.float32) ** 2).mean() + aux
+
+    compiled = jax.jit(jax.grad(loss)).lower(_moe_shapes(cfg, one_chip), h).compile()
+    assert set(names.MOE_KERNELS) <= _instructions(compiled.as_text())
+
+
+def test_paged_decode_kernel_compiles_with_one_query_head_a_kv_head(one_chip, tpu_branch):
+    from ditl_tpu.ops.paged_attention import paged_attention
+
+    cfg = get_preset("olmoe-1b-7b")
+    b, h, hd, ps, pages, maxp, tail = 64, cfg.num_heads, cfg.head_dim, 256, 192, 16, 16
+    assert cfg.num_heads == cfg.num_kv_heads
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    args = (s((b, h, hd), jnp.bfloat16), s((pages, h, ps, hd), jnp.bfloat16),
+            s((pages, h, ps, hd), jnp.bfloat16), s((b, maxp), jnp.int32), s((b,), jnp.int32),
+            s((b, h, tail, hd), jnp.bfloat16), s((b, h, tail, hd), jnp.bfloat16),
+            s((b,), jnp.int32))
+    compiled = jax.jit(
+        lambda q, kp, vp, tab, lens, tk, tv, st: paged_attention(
+            q, kp, vp, tab, lens, tail_k=tk, tail_v=tv, starts=st, interpret=False)
+    ).lower(*args).compile()
+    assert "paged_attention" in _instructions(compiled.as_text())
