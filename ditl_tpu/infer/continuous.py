@@ -1753,7 +1753,9 @@ class ContinuousEngine:
             starts = pos
             tk0 = jnp.zeros((L, n_b, K, tail_len, D), dt)
             tv0 = jnp.zeros((L, n_b, K, tail_len, D), dt)
-            cache_const = dict(pools)  # pools are read-only during the scan
+            # Read-only during the scan, and whole: llama.forward keeps them
+            # out of its layer loop and offsets each layer's page table.
+            cache_const = dict(pools)
 
             def body(carry, t):
                 tk, tv, cur, pos, done, keys, hist, fst, lp, moe_acc = carry
@@ -1876,7 +1878,9 @@ class ContinuousEngine:
             starts = pos
             tk0 = jnp.zeros((L, n_b, K, tail_len, D), dt)
             tv0 = jnp.zeros((L, n_b, K, tail_len, D), dt)
-            cache_const = dict(pools)  # pools are read-only during the scan
+            # Read-only during the scan, and whole: llama.forward keeps them
+            # out of its layer loop and offsets each layer's page table.
+            cache_const = dict(pools)
             out0 = jnp.full((n_b, out_len), pad, jnp.int32)
             zeros = jnp.zeros((n_b,), jnp.int32)
             bufs0 = (

@@ -322,6 +322,7 @@ def _decoder_layer(
     with_moe_counts: bool = False,
     moe_stack: dict | None = None,
     layer_index: jax.Array | None = None,
+    pools: dict | None = None,
 ) -> tuple:
     """One decoder block: ``(x, aux)``, then ``new_kv`` with a cache, then
     (``with_moe_counts``) the (E,) assignments each expert got from the tokens
@@ -337,14 +338,21 @@ def _decoder_layer(
     ``cache_index`` and attention runs against the whole cache under
     ``attn_mask`` — the KV-cache prefill/decode path (infer/engine.py).
 
-    When ``layer_cache`` holds page pools + tail buffers (``{"kp", "vp",
-    "tk", "tv"}``; pools (n_pages, K, page_size, D) — kv-heads before page
-    slots, the Mosaic trailing-dim layout of ops/paged_attention.py; tails
-    (B, K, T, D)), ``paged`` carries the tick metadata — ``table``
-    (B, maxp), ``starts``/``lengths`` (B,) and the scan column ``t`` — and
-    this is the single-token paged decode step: the token's K/V land in
-    the tail buffer (returned as this layer's new_kv; the pools are NOT
-    re-emitted) and attention runs through the page table plus the tail."""
+    With ``pools`` this is the paged decode step. ``pools`` holds the page
+    pools of ALL layers, whole (``{"kp", "vp"}``, plus the int8 scales
+    ``{"ks", "vs"}``), layers and pages flattened into one leading axis:
+    (L * n_pages, K, page_size, D) — kv-heads before page slots, the Mosaic
+    trailing-dim layout of ops/paged_attention.py. They are NOT sliced by
+    layer: ``forward`` keeps them outside its layer loop, and ``paged``'s
+    ``table`` (B, maxp) already names this layer's pages inside them
+    (``forward`` adds ``layer * n_pages``), so no layer's pool is ever copied
+    in front of the kernel. ``layer_cache`` then holds only this layer's tail
+    buffers (``{"tk", "tv"}``, (B, K, T, D)) and ``paged`` the rest of the
+    tick metadata — ``starts``/``lengths`` (B,) and the scan column ``t``
+    (or, multi-query verify, the per-row tail offsets ``off``). The token's
+    K/V land in the tail (returned as this layer's new_kv; the pools are
+    never re-emitted) and attention runs through the page table plus the
+    tail."""
     b, s, d = x.shape
     nh, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     cd = _dtype(cfg.dtype)
@@ -432,7 +440,7 @@ def _decoder_layer(
         k = _constrain(k, ("batch", "seq", "act_kv_heads", "head_dim"), mesh, rules)
     new_kv = None
     with jax.named_scope("attn_core"):
-        if layer_cache is not None and "kp" in layer_cache:
+        if pools is not None:
             from ditl_tpu.ops.paged_attention import paged_attention
 
             # Deferred flush: the chunk's K/V go into the tick's small TAIL
@@ -464,9 +472,9 @@ def _decoder_layer(
             new_kv = {"tk": tk, "tv": tv}
             attn_out = paged_attention(
                 q[:, 0] if s == 1 else q,
-                layer_cache["kp"], layer_cache["vp"], paged["table"],
+                pools["kp"], pools["vp"], paged["table"],
                 paged["lengths"], tail_k=tk, tail_v=tv, starts=paged["starts"],
-                k_scale=layer_cache.get("ks"), v_scale=layer_cache.get("vs"),
+                k_scale=pools.get("ks"), v_scale=pools.get("vs"),
                 mesh=mesh, rules=rules,
             )
             if s == 1:
@@ -643,6 +651,15 @@ def forward(
     instead of the causal mask. Returns ``(logits, new_cache)`` (plus aux when
     requested). No remat in this mode — there is no backward pass.
 
+    A ``cache`` that holds page pools (``{"kp", "vp"}`` (L, n_pages, K,
+    page_size, D), int8 pools with their scales ``{"ks", "vs"}``, beside the
+    tick's tails ``{"tk", "tv"}`` (L, B, K, T, D)) with ``paged`` (the tick
+    metadata, see ``_decoder_layer``) makes this a paged decode step. The
+    pools are read in place: they stay out of the layer loop, whole, and every
+    layer addresses its own pages inside them. Only the tails are scanned and
+    returned (``new_cache`` is ``{"tk", "tv"}``); the caller scatters them into
+    the pools once a tick (infer/continuous.py).
+
     ``prefill_causal=True`` (with ``cache``): the chunk prefills an EMPTY
     cache from offset 0, so attention is pure causal self-attention over
     the chunk (validity via ``segment_ids``) and routes through the flash
@@ -681,8 +698,24 @@ def forward(
                 moe_stack = {k: v for k, v in layers["moe"].items() if k != "router"}
                 layers = {**layers, "moe": {"router": layers["moe"]["router"]}}
 
+        pools = None
+        if "kp" in cache:
+            # A paged decode. The pools stay whole and OUTSIDE the loop (the
+            # kernel is a custom call: a scanned pool would be copied out of
+            # the stack, layer by layer, every step): layers and pages become
+            # one axis (a bitcast) and each layer's page table is offset to
+            # its own pages. Only the small tails are scanned.
+            n_pages = cache["kp"].shape[1]
+            pools = {k: v.reshape(-1, *v.shape[2:]) for k, v in cache.items()
+                     if k not in ("tk", "tv")}
+            cache = {"tk": cache["tk"], "tv": cache["tv"]}
+
         def cached_layer_fn(carry, xs):
             layer_params, layer_cache, layer_index = xs
+            layer_paged = paged
+            if pools is not None:
+                layer_paged = {
+                    **paged, "table": paged["table"] + layer_index * n_pages}
             y, aux, new_kv, *counts = _decoder_layer(
                 layer_params,
                 carry,
@@ -695,18 +728,20 @@ def forward(
                 cache_index=cache_index,
                 attn_mask=attn_mask,
                 adapter_ids=adapter_ids,
-                paged=paged,
+                paged=layer_paged,
                 prefill_causal=prefill_causal,
                 token_mask=token_mask,
                 with_moe_counts=with_moe_counts,
                 moe_stack=moe_stack,
                 layer_index=layer_index,
+                pools=pools,
             )
             return y, (aux, new_kv, *counts)
 
         # Every layer part has a scope of its own, so what is left to this
         # one is what the scan itself does: slicing each layer's weights and
-        # cache out of the stacked arrays and stacking the new K/V.
+        # cache (a paged decode: its tails) out of the stacked arrays and
+        # stacking the new K/V.
         with jax.named_scope("layer_scan"):
             x, (layer_aux, new_cache, *moe_counts) = jax.lax.scan(
                 cached_layer_fn, x,

@@ -28,6 +28,16 @@ slicing one kv head keeps (ps, D) as its trailing dims (Mosaic requires the
 last two block dims divisible by (8, 128) or equal to the array's);
 page_table is (B, maxp) int32; lengths (B,) counts valid tokens per slot
 (0 = dead slot -> zero output).
+
+P is whatever the caller's table addresses. The engine's pools are stacked
+over layers, (L, n_pages, K, ps, D); a paged decode (models/llama.py
+``forward``) hands the kernel ALL of them as one pool of P = L * n_pages
+pages (a bitcast) and a table offset by ``layer * n_pages``, because a
+custom call takes whole operands: a pool sliced by layer in front of it is
+a copy of that layer's pool, every layer of every step. The sentinel page
+of dead grid steps is then ABSOLUTE page 0 (layer 0's first page) for every
+layer: it is only ever fetched, never read unmasked, so which page it is
+does not matter — only that consecutive dead steps name the same one.
 """
 
 from __future__ import annotations

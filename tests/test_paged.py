@@ -549,3 +549,118 @@ def test_paged_attention_multi_query_requires_tail():
     with pytest.raises(ValueError, match="multi-query"):
         paged_attention(q, kp, kp, jnp.zeros((2, 2), jnp.int32),
                         jnp.zeros((2,), jnp.int32))
+
+
+# -- the pools stay whole, outside the layer loop -------------------------------
+
+
+def _scan_eqns(jaxpr):
+    """Every ``scan`` equation of a jaxpr, nested ones included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            yield eqn
+        for val in eqn.params.values():
+            for sub in val if isinstance(val, (tuple, list)) else (val,):
+                sub = getattr(sub, "jaxpr", sub)  # ClosedJaxpr -> Jaxpr
+                if hasattr(sub, "eqns"):
+                    yield from _scan_eqns(sub)
+
+
+@pytest.mark.parametrize("kind", ["plain", "int8", "speculative"])
+def test_paged_decode_scans_no_pool(tiny_setup, kind):
+    """A pool that is a scanned input of the layer loop is copied out of the
+    stack, layer by layer, in front of the kernel (a custom call takes whole
+    operands): 44% of the 7B serving cell's device time before PR 27. Every
+    paged decode program keeps its pools loop constants."""
+    import dataclasses
+
+    cfg, params = tiny_setup
+    if kind == "int8":
+        cfg = dataclasses.replace(cfg, kv_cache_dtype="int8")
+    kw = {"speculative": True, "spec_rounds": 2} if kind == "speculative" else {}
+    eng = _paged_engine(params, cfg, **kw)
+    alive = jnp.ones((eng.n_slots,), bool)
+    if kind == "speculative":
+        program = eng._build_spec_paged_decode(False)
+        args = (eng.params, eng.cache, eng.cur, eng.pos, alive,
+                eng._table_device(), eng.limits, eng.hist, eng.temps,
+                eng.top_ps, eng.keys, eng.adapters)
+    else:
+        program = eng._build_paged_decode(False, False)
+        args = (eng.params, eng.cache, eng.cur, eng.pos, alive, eng.temps,
+                eng.top_ps, eng.keys, eng._table_device(), eng.limits,
+                eng.hist, eng.adapters)
+    pool_shapes = {v.shape for v in eng.cache.values()}
+    assert len(pool_shapes) == (2 if kind == "int8" else 1)
+    scans = list(_scan_eqns(jax.make_jaxpr(program)(*args).jaxpr))
+    assert len(scans) >= 2  # the tick's steps, and in each the layer loop
+    for eqn in scans:
+        n_fixed = eqn.params["num_consts"] + eqn.params["num_carry"]
+        scanned = {v.aval.shape for v in eqn.invars[n_fixed:]}
+        assert not scanned & pool_shapes, scanned & pool_shapes
+
+
+@pytest.mark.parametrize("kind", ["plain", "int8", "multi-query"])
+def test_paged_decode_reads_each_layers_own_pages(monkeypatch, kind):
+    """Pools whose layers hold DIFFERENT contents: one decode step through
+    ``forward`` (whole pools, table offset by the layer) equals a plain loop
+    over the layers that hands ``paged_attention_xla`` that layer's own
+    slice. Every layer's new K/V are compared, so one layer reading
+    another's pages shows in the next one's tail (identical layers, as a
+    fresh engine's zero pools are, would hide a wrong offset)."""
+    from ditl_tpu.ops import paged_attention as pa
+
+    cfg = ModelConfig(
+        vocab_size=128, hidden_size=32, intermediate_size=64, num_layers=3,
+        num_heads=4, num_kv_heads=2, head_dim=8, max_seq_len=128,
+        dtype="float32", param_dtype="float32",
+    )
+    params = llama.init_params(jax.random.key(1), cfg)
+    rng = np.random.default_rng(7)
+    L, K, D = cfg.num_layers, cfg.num_kv_heads, cfg.head_dim
+    b, n_pages, ps, maxp, tail = 3, 8, 16, 3, 8
+    s = 3 if kind == "multi-query" else 1
+
+    def normal(*shape):
+        return jnp.asarray(rng.normal(size=shape), jnp.float32)
+
+    pools = {"kp": normal(L, n_pages, K, ps, D), "vp": normal(L, n_pages, K, ps, D)}
+    if kind == "int8":
+        pools = {k: jnp.round(v * 40).astype(jnp.int8) for k, v in pools.items()}
+        pools["ks"] = jnp.abs(normal(L, n_pages, K, 1, ps)) / 40 + 0.01
+        pools["vs"] = jnp.abs(normal(L, n_pages, K, 1, ps)) / 40 + 0.01
+    tails = {"tk": normal(L, b, K, tail, D), "tv": normal(L, b, K, tail, D)}
+    starts = jnp.asarray([35, 16, 0], jnp.int32)  # row 2 is dead
+    pos = starts + jnp.asarray([2, 0, 0], jnp.int32)
+    lengths = jnp.asarray([38, 17, 0], jnp.int32)
+    table = jnp.asarray([[5, 2, 7], [3, 0, 0], [0, 0, 0]], jnp.int32)
+    paged = {"table": table, "lengths": lengths, "starts": starts}
+    if s > 1:
+        paged["off"] = pos - starts  # multi-query verify: per-row tail offsets
+    else:
+        paged["t"] = jnp.int32(2)  # plain tick: the scan column
+    ids = jnp.asarray(rng.integers(1, cfg.vocab_size, (b, s)), jnp.int32)
+    positions = pos[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
+
+    hidden, new = llama.forward(
+        params, ids, cfg, positions=positions, cache={**pools, **tails},
+        paged=paged, return_hidden=True)
+
+    def reference_kernel(q, kp, vp, tab, lens, *, mesh=None, rules=None, **kw):
+        return pa.paged_attention_xla(q, kp, vp, tab, lens, **kw)
+
+    monkeypatch.setattr(pa, "paged_attention", reference_kernel)
+    x = params["embed"]["embedding"][ids]
+    for layer in range(L):
+        x, _, kv = llama._decoder_layer(
+            jax.tree.map(lambda a: a[layer], params["layers"]), x, cfg=cfg,
+            positions=positions, segment_ids=None, mesh=None, rules=None,
+            layer_cache={k: v[layer] for k, v in tails.items()},
+            pools={k: v[layer] for k, v in pools.items()}, paged=paged)
+        for name in ("tk", "tv"):
+            np.testing.assert_allclose(
+                np.asarray(new[name][layer]), np.asarray(kv[name]),
+                atol=2e-5, err_msg=f"layer {layer} {name}")
+    want = llama.rms_norm(x, params["final_norm"]["scale"], cfg.rms_norm_eps)
+    np.testing.assert_allclose(np.asarray(hidden), np.asarray(want), atol=2e-5)
+    assert set(new) == {"tk", "tv"}

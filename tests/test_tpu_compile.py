@@ -42,10 +42,11 @@ def tpu_branch(monkeypatch):
     """The code asks ``jax.default_backend()``, which is the CPU here: take
     the branch the chip takes."""
     monkeypatch.setattr(moe_mod, "_use_gmm", lambda rows, mesh: rows % 128 == 0)
-    from ditl_tpu.ops import backend
+    from ditl_tpu.ops import backend, paged_attention
 
-    monkeypatch.setattr(moe_mod, "interpret_default", lambda: False)
-    monkeypatch.setattr(backend, "interpret_default", lambda: False)
+    # every module that bound the name at its import, whichever came first
+    for module in (moe_mod, backend, paged_attention):
+        monkeypatch.setattr(module, "interpret_default", lambda: False)
 
 
 def _moe_shapes(cfg, sharding):
@@ -96,3 +97,52 @@ def test_paged_decode_kernel_compiles_with_one_query_head_a_kv_head(one_chip, tp
             q, kp, vp, tab, lens, tail_k=tk, tail_v=tv, starts=st, interpret=False)
     ).lower(*args).compile()
     assert "paged_attention" in _instructions(compiled.as_text())
+
+
+@pytest.mark.parametrize(
+    "preset, layers, pages",
+    [("qwen2-7b", 12, 720), ("olmoe-1b-7b", 10, 192)],
+    ids=["qwen2-7b-cut1", "olmoe-1b-7b-cut1"],
+)
+def test_paged_decode_layer_loop_copies_no_pool(one_chip, tpu_branch, preset, layers, pages):
+    """The cached layer loop of one paged decode step at the two serving
+    cells' shapes (64 slots, pages of 256, tail 16). The kernel is a custom
+    call, so a pool that the loop slices by layer is COPIED in front of it
+    (``dynamic-slice_bitcast_fusion.8/.9``, 360 MiB of temporaries, before
+    PR 27). Whole pools addressed through the page table leave no
+    instruction of one layer's pool shape, a flattening that is a bitcast,
+    and temporaries far under one layer's pool."""
+    from ditl_tpu.models import llama
+
+    cfg = get_preset(preset, num_layers=layers, param_dtype="bfloat16")
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    params = jax.tree.map(
+        lambda a: s(a.shape, a.dtype),
+        jax.eval_shape(lambda: llama.init_params(jax.random.key(0), cfg)))
+    b, ps, tail, maxp = 64, 256, 16, 16
+    kv, hd = cfg.num_kv_heads, cfg.head_dim
+    pool = s((layers, pages, kv, ps, hd), jnp.bfloat16)
+    tails = s((layers, b, kv, tail, hd), jnp.bfloat16)
+    row = s((b,), jnp.int32)
+
+    def step(params, kp, vp, tk, tv, cur, pos, table, lengths, starts, t):
+        return llama.forward(
+            params, cur[:, None], cfg, positions=pos[:, None],
+            cache={"kp": kp, "vp": vp, "tk": tk, "tv": tv},
+            paged={"table": table, "lengths": lengths, "starts": starts, "t": t},
+            return_hidden=True)
+
+    compiled = jax.jit(step).lower(
+        params, pool, pool, tails, tails, row, row, s((b, maxp), jnp.int32),
+        row, row, s((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "paged_attention" in _instructions(text)
+
+    def producers(*dims):
+        shape = re.escape("bf16[" + ",".join(map(str, dims)) + "]")
+        return set(re.findall(r" = " + shape + r"\S* ([\w\-]+)\(", text))
+
+    assert not producers(pages, kv, ps, hd)
+    assert producers(layers * pages, kv, ps, hd) <= {"bitcast", "get-tuple-element"}
+    layer_pool_bytes = pages * kv * ps * hd * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_pool_bytes / 10
