@@ -317,16 +317,16 @@ def last_json_line(name: str, out: str) -> dict:
 
 def check_device(name: str, dev: dict, expect: dict | None) -> dict:
     """``dev`` is runtime/distributed.device_summary() as a child printed it."""
-    from bench import _PEAK_FLOPS
-    from ditl_tpu.telemetry.perf import _PEAK_HBM_BW
+    with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "benchmarks", "peaks.json")) as f:
+        peaks = json.load(f)
 
     if dev.get("platform") != "tpu":
         raise SmokeFailure(f"{name}: ran on platform {dev.get('platform')!r}, not tpu")
     kind = str(dev.get("device_kind", "")).lower().strip()
-    if kind not in _PEAK_FLOPS or kind not in _PEAK_HBM_BW:
+    if not isinstance(peaks.get(kind), dict):
         raise SmokeFailure(f"{name}: device_kind {dev.get('device_kind')!r} is "
-                           "missing from the peaks tables (bench._PEAK_FLOPS, "
-                           "telemetry/perf._PEAK_HBM_BW)")
+                           "not in benchmarks/peaks.json")
     if expect is not None and dev != expect:
         raise SmokeFailure(f"{name}: device {dev} differs from the first child's {expect}")
     return dev

@@ -469,7 +469,7 @@ def maybe_inject(site: str, **kwargs) -> Fault | None:
 
 def injected_summary() -> dict | None:
     """The armed plane's :meth:`FaultPlane.summary`, or None when disarmed
-    — bench.py attaches this to its JSON so perf-under-fault rows are
+    — attached to a measurement's JSON, it makes perf-under-fault rows
     attributable."""
     plane = _PLANE
     return None if plane is None else plane.summary()

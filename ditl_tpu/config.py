@@ -946,7 +946,7 @@ class ChaosConfig:
     compact spec string ``site:action[@k=v,...];...`` (see
     ``chaos.parse_rules``); empty = disarmed. The same ``seed`` replays
     the identical fault sequence — drills assert journal-diff equality.
-    Armed by the trainer (``launch.py``) and ``bench.py --chaos``; every
+    Armed by the trainer (``launch.py``); every
     worker of a pod receives the identical rules (the config fingerprint
     covers this section), with per-worker targeting via the rule's
     ``proc=N`` option."""

@@ -56,9 +56,9 @@ Action taxonomy:
 Also here (ISSUE 12 satellites): the :class:`TrafficRecorder` the gateway
 arms with ``--save-trace`` (one JSONL row per admitted request — arrival
 offset, tenant digest, class, prompt/max_new token estimates) and
-:func:`load_trace`, the reader ``bench.py --serve-trace-replay`` drives;
-and :class:`ReplicaSecondsSampler`, the replica-seconds integral the
-autoscaler A/B is graded on.
+:func:`load_trace`, the reader a trace replay drives
+(``tests/gateway_drivers.py``); and :class:`ReplicaSecondsSampler`, the
+replica-seconds integral a replay reports.
 
 Stdlib-only and jax-free like the rest of the gateway package.
 """
@@ -804,8 +804,8 @@ class Actuator:
 
 class ReplicaSecondsSampler:
     """Integral of live replica count over wall time — the resource-cost
-    number the autoscaler A/B is graded on (``bench.py
-    --serve-trace-replay`` embeds it; perf_compare gates it downward).
+    number a trace replay reports for the autoscaler (perf_compare gates it
+    downward).
     Sampling, not transition-tracking: the supervisor mutates liveness
     from several threads and a 50 ms Riemann sum is honest enough for
     runs measured in seconds-to-hours."""
@@ -849,7 +849,7 @@ class TrafficRecorder:
     offset from the first admitted request, tenant digest (the
     credential-safe label, never the bearer token), SLO class, and the
     gateway's tokenizer-free prompt/max_new estimates. The shape
-    ``bench.py --serve-trace-replay`` replays with preserved inter-arrival
+    :func:`load_trace` reads back for a replay with preserved inter-arrival
     times. Line-buffered appends: a killed gateway loses at most the row
     it never wrote (the journal contract)."""
 

@@ -39,7 +39,7 @@ applied to serving:
 
 Like everything in gateway/, this module is stdlib-only and jax-free on
 import. The relay dependency is INJECTED (``bind(dispatch=...)``) so the
-manager is unit-testable against a fake fleet and reusable from bench.
+manager is unit-testable against a fake fleet.
 
 CLI over the on-disk state (no live gateway needed)::
 
@@ -64,6 +64,7 @@ from ditl_tpu.config import BulkConfig
 from ditl_tpu.gateway.admission import sanitize_label
 from ditl_tpu.telemetry.flight import BULK_RING
 from ditl_tpu.telemetry.journal import EventJournal, read_journal
+from ditl_tpu.telemetry.usage import load_usage
 from ditl_tpu.utils.logging import get_logger
 
 logger = get_logger(__name__)
@@ -339,6 +340,20 @@ class BulkJobManager:
             }
             if jrec.get("status") != "ok":
                 job.n_failed += 1
+        if self.usage is not None and job.pending:
+            # A death between _finish_item's journal row and its usage row
+            # leaves a terminal item unbilled, and resume never runs it
+            # again: bill it now. Only this tail can be affected (an item
+            # is flushed after it is billed), so the ledgers beside this
+            # incarnation's are read only when there is one.
+            billed = {
+                r.get("item")
+                for r in load_usage(os.path.dirname(self.usage.path))
+                if r.get("bulk_job") == job_id
+            }
+            for idx, row in sorted(job.pending.items()):
+                if idx not in billed:
+                    self._bill(job, idx, row)
         self._flush_locked_job(job)
         return job
 
@@ -512,14 +527,21 @@ class BulkJobManager:
         return True
 
     def drain(self, timeout_s: float = 60.0) -> bool:
-        """Block until no job is queued/running (tests, bench). Returns
-        False on timeout."""
+        """Block until no job is queued/running and every job thread has
+        finished finalizing (tests, drills). Returns False on timeout."""
         deadline = time.monotonic() + timeout_s
-        while time.monotonic() < deadline:
-            if self.active_jobs() == 0:
-                return True
+        while self.active_jobs() and time.monotonic() < deadline:
             time.sleep(0.02)
-        return self.active_jobs() == 0
+        # A job's terminal state is visible before _finalize_job has saved,
+        # journaled and counted it: join the threads so the caller reads
+        # all of it, not just the state.
+        with self._jobs_lock:
+            threads = [job.thread for job in self._jobs.values()]
+        threads = [t for t in threads if t is not None and t.ident]
+        for t in threads:
+            t.join(max(0.0, deadline - time.monotonic()))
+        return (self.active_jobs() == 0
+                and not any(t.is_alive() for t in threads))
 
     # -- the dispatch loop ---------------------------------------------------
 
@@ -693,17 +715,7 @@ class BulkJobManager:
         self.journal.event("bulk.item", schema=BULK_SCHEMA, job=job.id,
                            **row)
         if self.usage is not None:
-            # bulk_job attribution (ISSUE 15 coupling): the aggregator
-            # bills bulk separately from interactive — rollups preserve
-            # unknown fields, so the row stays filterable downstream.
-            self.usage.record(
-                tenant=job.tenant,
-                outcome="200" if row["status"] == "ok" else "503",
-                slo_class="best_effort",
-                bulk_job=job.id,
-                item=idx,
-                completion_tokens=int(row.get("completion_tokens") or 0),
-            )
+            self._bill(job, idx, row)
         failed = row["status"] != "ok"
         with job.lock:
             if idx in job.done:
@@ -728,6 +740,20 @@ class BulkJobManager:
             self.rate_samples.append((time.time(), self._items_completed))
             self._token_samples.append((time.time(), self._tokens_total))
         self._refresh_gauges()
+
+    def _bill(self, job: _Job, idx: int, row: dict) -> None:
+        """One item's usage row. bulk_job attribution (ISSUE 15
+        coupling): the aggregator bills bulk separately from interactive
+        — rollups preserve unknown fields, so the row stays filterable
+        downstream."""
+        self.usage.record(
+            tenant=job.tenant,
+            outcome="200" if row.get("status") == "ok" else "503",
+            slo_class="best_effort",
+            bulk_job=job.id,
+            item=idx,
+            completion_tokens=int(row.get("completion_tokens") or 0),
+        )
 
     def _flush_locked_job(self, job: _Job) -> None:
         """Contiguous-prefix flush: append every pending row whose idx

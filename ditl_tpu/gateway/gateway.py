@@ -24,8 +24,8 @@ Surface:
 
 The gateway is stdlib-only (no jax import anywhere in ditl_tpu/gateway):
 it must be runnable as a thin front process and unit-testable against stub
-replicas. Wire-up lives in ``launch.py gateway`` (subprocess replicas) and
-``bench.py --serve-replicas`` (in-process fleet benchmark).
+replicas. Wire-up lives in ``launch.py gateway`` (subprocess replicas);
+the drills build in-process fleets (``tests/gateway_drivers.py``).
 """
 
 from __future__ import annotations
@@ -1232,8 +1232,7 @@ class _GatewayHandler(KeepAliveHandlerMixin, BaseHTTPRequestHandler):
             # Traffic recorder (ISSUE 12 satellite): one row per ADMITTED
             # request — throttled requests never reach here, so the saved
             # shape is the demand the fleet actually served, replayable
-            # via bench.py --serve-trace-replay with preserved
-            # inter-arrival times. Tenant rides as the credential-safe
+            # (autoscale.load_trace) with preserved inter-arrival times. Tenant rides as the credential-safe
             # digest, never the bearer token.
             self.recorder.note(
                 tenant=label,
@@ -2431,8 +2430,8 @@ def main(argv: list[str] | None = None) -> int:
                         help="traffic recorder (ISSUE 12): append one "
                         "JSONL row per admitted request (arrival offset, "
                         "tenant digest, class, prompt/max_new token "
-                        "estimates) — the shape bench.py "
-                        "--serve-trace-replay replays")
+                        "estimates) — the shape autoscale.load_trace "
+                        "reads back for a replay")
     parser.add_argument("--recover", default="", metavar="DIR",
                         help="crash recovery (ISSUE 20): adopt the fleet a "
                         "SIGKILLed gateway left behind from DIR's "
@@ -2493,8 +2492,7 @@ def main(argv: list[str] | None = None) -> int:
                     # the role's slot ratio first (a decode_heavy replica
                     # running 2x the slots needs 2x the pool just to keep
                     # per-slot headroom), THEN by the role's extra depth
-                    # (pages_scale) — the same slot-derived-then-scaled
-                    # sizing bench.py uses.
+                    # (pages_scale).
                     scaled = (args.pages * knobs["n_slots"]
                               / max(1, args.slots) * knobs["pages_scale"])
                     cmd += ["--pages", str(max(2, int(scaled)))]

@@ -24,7 +24,7 @@ Three roles:
 
 Everything here is pure stdlib host code over plain numbers and
 ``ReplicaView`` snapshots — unit-testable without jax, importable by the
-gateway (which must stay jax-free) and by bench.py/launchers alike.
+gateway (which must stay jax-free) and by launchers alike.
 """
 
 from __future__ import annotations

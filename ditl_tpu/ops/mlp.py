@@ -1,8 +1,8 @@
 """Fused-gate|up MLP block with a hand-written VJP and, per config, a
 Pallas fused-backward implementation.
 
-The builders' r5 stop-gradient ablation (experiments/bwd_ablation.py;
-figures from before this round, not re-measured) showed the MLP family's
+The builders' r5 stop-gradient ablation (figures from before this round,
+not re-measured; the script is gone, PERF.md section 7) showed the MLP family's
 in-step weight-gradient GEMMs running at ~2x their isolated-peak rates — a property of XLA's backward SCHEDULE, not of
 the GEMM shapes. The first instrument against that was this module's
 custom VJP: the whole block's backward (activation grads and BOTH weight
@@ -110,8 +110,9 @@ def effective_bwd_impl(bwd_impl: str, b: int, s: int, d: int, f: int,
                        blocks=(), mesh=None, rules=None) -> str:
     """The backward implementation ``mlp_block`` will ACTUALLY run for these
     shapes — shared gate logic in parallel/sharding.pallas_bwd_effective,
-    bound to this op's shape predicate; bench.py records the same call, so
-    an A/B can never attribute a delta to a kernel that fell back."""
+    bound to this op's shape predicate; a record that names the backward
+    asks the same call, so an A/B can never attribute a delta to a kernel
+    that fell back."""
     from ditl_tpu.ops import mlp_bwd
     from ditl_tpu.parallel.sharding import pallas_bwd_effective
 

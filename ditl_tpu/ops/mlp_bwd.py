@@ -1,7 +1,7 @@
 """Pallas fused-backward kernels for the fused-gate|up SwiGLU MLP block.
 
-The builders' r5 custom-VJP null (experiments/bwd_levers.py; from before
-this round, not re-measured) showed the MLP backward residual is XLA's
+The builders' r5 custom-VJP null (from before this round, not re-measured;
+the script is gone, PERF.md section 7) showed the MLP backward residual is XLA's
 in-step *schedule*, not the einsum spelling: re-emitting the same contractions by hand changed nothing, because
 XLA still owned tiling and interleaving. This module takes the next step the
 r5 verdict named — take the backward out of XLA's hands entirely, the same
@@ -31,8 +31,8 @@ kernels run in interpret mode, so the same numerics tests run on CPU
 (tests/test_bwd_kernels.py).
 
 Adoption protocol (the VJP-null rigor): the kernel ships behind
-``ModelConfig.mlp_bwd_impl`` and is adopted into the pinned bench config only
-on an adjacent on-chip A/B win (experiments/bwd_kernels.py); a loss is
+``ModelConfig.mlp_bwd_impl`` and becomes a default only on an adjacent
+on-chip A/B win in a benchmark cell; a loss is
 documented as a kernel-level definitive null, never silently dropped.
 """
 

@@ -3,8 +3,8 @@
 The second-largest residual in the r4/r5 backward-schedule accounting
 (~33 ms of a 562 ms step) is the attention qkv/out-projection weight
 gradients — plain ``x^T @ g`` contractions whose in-step rates ran at ~2x
-their isolated cost under XLA's backward schedule (experiments/bwd_levers.py
-``iso`` receipts). This module is the projection-shaped sibling of
+their isolated cost under XLA's backward schedule (the builders' ``iso``
+receipts from before this round, not re-measured). This module is the projection-shaped sibling of
 ops/mlp_bwd.py: a ``custom_vjp`` whose forward is the exact inline einsum
 (bit-identical — same op, same dtypes as ops/quant.weight_einsum on float
 weights) and whose backward emits BOTH gradients from one Pallas kernel:

@@ -69,8 +69,8 @@ def pallas_batch_shards(mesh, rules, batch: int) -> int | None:
     (FSDP-sharded weights are fine: FSDP all-gathers weights per use
     anyway, so replication inside the island matches its cost model.)
     ONE definition shared by the backward-kernel seams (ops/mlp.py,
-    ops/projection.py) and bench.py's ``bwd_impl`` record, so the dispatch
-    and its attribution can never drift apart."""
+    ops/projection.py) and whatever records which backward ran, so the
+    dispatch and its attribution can never drift apart."""
     if mesh is None:
         return 1
     r = rules if rules is not None else DEFAULT_RULES
@@ -87,8 +87,8 @@ def pallas_bwd_effective(bwd_impl: str, batch: int, seq: int, d: int, f: int,
                          blocks, mesh, rules, supports_fn) -> str:
     """The backward implementation a Pallas-seamed op will ACTUALLY run —
     the mesh gate above plus the op's own shape predicate on the per-shard
-    token count. Shared by ops/mlp.py and ops/projection.py (and through
-    them bench.py's ``bwd_impl`` field) so the two seams cannot diverge.
+    token count. Shared by ops/mlp.py and ops/projection.py so the two
+    seams cannot diverge.
     Giving way to "xla" is for interpret mode only: on the TPU backend a
     requested kernel that cannot run raises, naming the shape."""
     if bwd_impl != "pallas":

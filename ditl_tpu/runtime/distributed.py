@@ -69,7 +69,7 @@ DEFAULT_COMPILE_CACHE_DIR = os.path.join(
 def enable_compile_cache() -> str | None:
     """Turn on JAX's persistent compilation cache (idempotent) and return
     its directory, or None when refused. The ONE place every program —
-    trainer, server, bench.py, chip_smoke.py's children — enables it.
+    trainer, server, chip_smoke.py's children — enables it.
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set, jax already reads it and
     this helper sets no directory in code: the operator (or the machine the

@@ -110,11 +110,11 @@ _GIT_REV: str | None = None
 
 # Process-lifetime bundle count (a plain int, NOT a list of manager
 # references — pinning every per-run manager would leak their config
-# snapshots and rings for process lifetime), so bench.py can embed ONE
+# snapshots and rings for process lifetime), so a run can report ONE
 # "incidents this run" count without plumbing managers through fleet
-# factories (chaos/plane.py's injected_summary pattern). Bench captures
-# the value at run start and embeds the delta, so in-process sweep cells
-# never inherit earlier cells' incidents.
+# factories (chaos/plane.py's injected_summary pattern): capture the
+# value at run start and report the delta, so runs sharing a process
+# never inherit each other's incidents.
 _CREATED_TOTAL = 0
 
 

@@ -24,14 +24,14 @@ caller already holds):
   achieved-vs-roofline report: arithmetic intensity (flops/byte), the
   roofline's MFU ceiling at that intensity, and whether the program sits on
   the compute or memory side of the ridge. This is the per-step complement
-  to bench.py's analytic end-of-run MFU scalar.
+  to an analytic end-of-run MFU scalar.
 
 - **Versioned sweep records** (``new_sweep_record`` / ``load_sweep_record``
   / ``record_sweep_cell``): the one JSON format every grid-shaped
-  measurement writes — ``bench.py --sweep``, ``experiments/bwd_kernels.py``,
-  ``experiments/bwd_levers.py`` — so ``perf_compare`` can diff any two of
-  them. Records are **resumable**: one file holds a ``cells`` map keyed by
-  the cell's override spec; a crashed sweep reruns only the missing cells.
+  measurement writes (``run_recorded_cells``), so ``perf_compare`` can diff
+  any two of them. Records are **resumable**: one file holds a ``cells`` map
+  keyed by the cell's override spec; a crashed sweep reruns only the missing
+  cells.
 """
 
 from __future__ import annotations
@@ -47,7 +47,6 @@ __all__ = [
     "StepAnatomy",
     "compiled_cost",
     "roofline",
-    "peak_hbm_bw",
     "git_rev",
     "cell_key",
     "new_sweep_record",
@@ -69,20 +68,6 @@ ANATOMY_BUCKETS = (
 # Version stamped into every bench/sweep record. Bump when a field changes
 # meaning; perf_compare refuses to diff across schema versions.
 SWEEP_SCHEMA = 1
-
-# Peak HBM bandwidth (bytes/s) per device_kind, same EXACT-match discipline
-# as bench._PEAK_FLOPS: unknown kinds omit the roofline instead of guessing.
-_PEAK_HBM_BW = {
-    "tpu v5 lite": 819e9,
-    "tpu v5e": 819e9,
-    "tpu v5litepod": 819e9,
-    "tpu v6 lite": 1640e9,
-    "tpu v6e": 1640e9,
-    "tpu v5p": 2765e9,
-    "tpu v5": 2765e9,
-    "tpu v4": 1228e9,
-    "tpu v4 lite": 614e9,
-}
 
 
 class StepAnatomy:
@@ -178,10 +163,6 @@ def compiled_cost(compiled: Any, n_steps: int = 1) -> dict | None:
     except Exception:  # noqa: BLE001
         pass
     return out
-
-
-def peak_hbm_bw(device_kind: str) -> float | None:
-    return _PEAK_HBM_BW.get(device_kind.lower().strip())
 
 
 def roofline(
@@ -304,19 +285,27 @@ def pop_out_arg(args: list, default: str) -> str:
 
 
 def run_recorded_cells(path, name, meta, items, runner) -> dict:
-    """Shared record-as-you-go loop for A/B and grid scripts
-    (experiments/bwd_kernels.py, bwd_levers.py): each ``(key, payload)``
-    item runs through ``runner(key, payload) -> cell dict`` and lands in
-    the sweep record at ``path`` immediately (atomic write per cell).
-    Resume semantics match ``bench.py --sweep``: cells already recorded
-    WITHOUT an error are skipped, errored cells are retried (a transient
-    failure must not be permanently skipped), and a runner returning an
-    ``{"error": ...}`` cell records the failure so perf_compare's
-    measured-to-crashing gate sees it. Returns ``{key: cell}`` covering
-    both freshly run and resumed cells."""
+    """Record-as-you-go loop for A/B and grid measurements: each
+    ``(key, payload)`` item runs through ``runner(key, payload) -> cell
+    dict`` and lands in the sweep record at ``path`` immediately (atomic
+    write per cell). Resume semantics: cells already recorded WITHOUT an
+    error are skipped, errored cells are retried (a transient failure must
+    not be permanently skipped), a runner returning an ``{"error": ...}``
+    cell records the failure so perf_compare's measured-to-crashing gate
+    sees it, and a record measured under a different base configuration
+    (``meta``) is refused. Returns ``{key: cell}`` covering both freshly
+    run and resumed cells."""
     record = load_sweep_record(path)
     if record is None:
         record = new_sweep_record(name, meta=meta)
+    elif record.get("meta", {}) != dict(meta or {}):
+        # Cell keys name only the swept knobs, so resuming would reuse the
+        # other configuration's numbers — and feed perf_compare
+        # wrong-config baselines.
+        raise ValueError(
+            f"{path} was recorded under a different base config "
+            f"({record.get('meta')} != {dict(meta or {})}); point the "
+            "record elsewhere or delete the stale one")
     out: dict = {}
     for key, payload in items:
         prior = record["cells"].get(key)

@@ -1,14 +1,14 @@
-"""Bench/sweep regression gate (ISSUE 7 tentpole leg 3).
+"""Row/sweep regression gate (ISSUE 7 tentpole leg 3).
 
     python -m ditl_tpu.telemetry.perf_compare old.json new.json \
         [--threshold 0.05]
 
-Diffs two performance records — either two single bench rows (``bench.py``'s
-one-JSON-line output, saved to a file) or two versioned sweep records
-(``bench.py --sweep`` / ``experiments/bwd_kernels.py``) — metric by metric
-against a relative threshold, and **exits nonzero on regression**. This is
-the gate every MFU-push PR runs against the previous round's record: a
-lever that silently lost throughput fails CI instead of shipping.
+Diffs two performance records — either two single rows (one JSON object
+each, saved to a file) or two versioned sweep records
+(``telemetry.perf.run_recorded_cells``) — metric by metric against a
+relative threshold, and **exits nonzero on regression**. Since PR 28 its
+callers are tests (the benchmark, ``benchmarks/``, compares its own runs);
+the rows it names below are the fleet drills' (``tests/gateway_drivers.py``).
 
 Comparison rules:
 
@@ -45,7 +45,7 @@ COMPARE_KEYS = {
     "roofline_mfu_cap": 0,  # informational: config property, never gates
     "step_time_p50_ms": -1,
     "step_ms": -1,
-    # Serving-row keys (ISSUE 8, bench --serve-* rows' hoisted `serving`
+    # Serving-row keys (ISSUE 8, fleet rows' hoisted `serving`
     # block): scheduler-interference p95 regresses when it RISES (a stall
     # crept back into the budgeted tick composition); the measured
     # prefix-cache hit ratio regresses when it FALLS (routing or paging
@@ -59,7 +59,7 @@ COMPARE_KEYS = {
     # gates (batch p95s are reported context, not regressions).
     "interactive_interference_p95_s": -1,
     "interactive_ttft_p95_s": -1,
-    # Autoscaler A/B keys (ISSUE 12, bench --serve-trace-replay rows'
+    # Autoscaler A/B keys (ISSUE 12, trace-replay rows'
     # hoisted `autoscale` block): replica_seconds is the resource cost the
     # autoscaler exists to cut (regresses when it RISES — the on-vs-off
     # A/B gates it next to the ttft_p95_s already above); the interactive
@@ -78,8 +78,8 @@ COMPARE_KEYS = {
     "host_tier_hit_ratio": +1,
     "swap_in_p95_s": -1,
     "handoff_fallback_ratio": -1,
-    # Gateway data-plane overhead keys (ISSUE 14, bench
-    # --serve-gateway-overhead rows' hoisted `gateway_overhead` block):
+    # Gateway data-plane overhead keys (ISSUE 14, gateway-overhead
+    # rows' hoisted `gateway_overhead` block):
     # the stub-replica closed loop isolates the gateway's OWN per-request
     # tax from any device work, so these gate host-side regressions the
     # device benches can't see. Requests/sec through the gateway regresses
@@ -100,15 +100,15 @@ COMPARE_KEYS = {
     # selector loop is that N open streams cost ~13 threads, not ~N.
     "evloop_vs_threaded_rps_ratio": +1,
     "gateway_max_resident_threads": -1,
-    # Usage-metering keys (ISSUE 15, bench --serve-gateway-overhead
-    # --serve-usage-metering rows' hoisted `usage_metering` block): the
+    # Usage-metering keys (ISSUE 15, metered gateway-overhead
+    # rows' hoisted `usage_metering` block): the
     # metered leg's requests/sec regresses when it falls, and the
     # fractional rps cost of arming the ledger regresses when it rises —
     # per-tenant accounting must stay cheap enough that nobody is
     # tempted to turn billing off under load.
     "gateway_rps_metered": +1,
     "metering_overhead_ratio": -1,
-    # Adapter plane keys (ISSUE 16, bench --serve-multi-lora rows' hoisted
+    # Adapter plane keys (ISSUE 16, multi-LoRA serving rows' hoisted
     # `adapters` block): the fractional throughput cost of serving through
     # the stacked adapter gather (vs the base-only A/B leg) regresses when
     # it rises — multi-tenant LoRA is only viable while the per-request
@@ -117,13 +117,13 @@ COMPARE_KEYS = {
     # window where a publication holds a spare row.
     "adapter_gather_overhead_ratio": -1,
     "adapter_swap_p95_s": -1,
-    # Continuous-profiling keys (ISSUE 18, bench --serve-gateway-overhead
+    # Continuous-profiling keys (ISSUE 18, gateway-overhead
     # rows' hoisted `profiler_overhead` block): the profiler-on vs
     # profiler-off req/s ratio regresses when it falls — the always-on
     # sampler + loop-lag watchdog are only "always-on" while they cost
     # within the same-box noise floor of running dark.
     "prof_vs_off_rps_ratio": +1,
-    # Bulk-lane goodput keys (ISSUE 19, bench --serve-bulk-backlog rows'
+    # Bulk-lane goodput keys (ISSUE 19, bulk-backlog replay rows'
     # hoisted `bulk` block): the lane's tokens/sec regresses when it
     # falls — spare decode capacity the offline backlog stopped soaking
     # is throughput thrown away; and the interactive TTFT p95 measured
@@ -136,8 +136,8 @@ COMPARE_KEYS = {
 
 # Per-key noise floors: gated keys whose honest run-to-run spread on a
 # shared box exceeds the default threshold. The evloop-vs-threaded
-# ratio is a quotient of two same-box closed loops — the paired-median
-# estimator in bench.py cancels drift, but ~±10% spread at parity
+# ratio is a quotient of two same-box closed loops — a paired-median
+# estimator cancels drift, but ~±10% spread at parity
 # survives it, so gating the ratio at the generic 5% flags the box's
 # mood as a data-plane regression. 15% still catches any real
 # per-request slowdown while two honest parity rows compare clean.

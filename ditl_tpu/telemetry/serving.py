@@ -352,7 +352,7 @@ class ServingMetrics:
 def merged_histogram(hists: Sequence[Histogram]) -> Histogram:
     """One histogram holding every input's observations (identical bucket
     ladders required) — how fleet-level quantiles are computed from
-    per-replica instruments without a shared registry (bench.py embeds
+    per-replica instruments without a shared registry (a fleet row embeds
     the p50/p95 of the merged interference histogram, not a quantile of
     per-replica quantiles, which would not be a quantile of anything)."""
     if not hists:
@@ -420,8 +420,8 @@ def _subtract(hist: Histogram, snaps) -> None:
 
 def serving_bench_summary(bundles: Sequence["ServingMetrics"],
                           since: dict | None = None) -> dict:
-    """The serving block a ``bench.py --serve-*`` row embeds (ISSUE 8
-    satellite): fleet-merged interference quantiles plus the measured
+    """The serving block a fleet drill's row embeds (ISSUE 8
+    satellite; ``tests/gateway_drivers.py``): fleet-merged interference quantiles plus the measured
     prefix-cache hit ratio, flat numeric keys so
     ``telemetry/perf_compare.py`` can gate them like train metrics.
     ``since`` (a :func:`snapshot_serving` taken after warm-up) restricts

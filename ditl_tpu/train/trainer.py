@@ -601,8 +601,7 @@ def train(config: Config) -> dict[str, Any]:
                 # is subtracted — both have their own buckets): the FIRST
                 # compiled window is compile-dominated, so it is attributed
                 # to the compile badput bucket whole — the same convention
-                # bench.py and summary() use when they drop the warm-up
-                # step from p50.
+                # summary() uses when it drops the warm-up step from p50.
                 dt_window = time.perf_counter() - t_window0 - prof_s
                 if first_window:
                     tracker.add("compile", dt_window)

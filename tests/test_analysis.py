@@ -355,23 +355,21 @@ def test_cli_single_rule_violation_exits_nonzero(capsys):
 
 
 # ---------------------------------------------------------------------------
-# bench stamp + perf_compare gating (CI/tooling satellite)
+# row stamp + perf_compare gating (CI/tooling satellite)
 # ---------------------------------------------------------------------------
 
 
 def test_bench_rows_stamp_analysis_clean():
-    """Every bench row carries the invariant-lint verdict (computed once
-    per process); on this tree it must be True."""
-    sys.path.insert(0, REPO_ROOT)
-    try:
-        import bench
-    finally:
-        sys.path.remove(REPO_ROOT)
-    meta = bench._record_meta()
+    """Every row the fleet drivers hand to perf_compare carries the
+    invariant-lint verdict (computed once per process); on this tree it
+    must be True."""
+    from tests import gateway_drivers
+
+    meta = gateway_drivers._record_meta()
     assert meta["analysis_clean"] is True
     assert "schema" in meta and "git_rev" in meta
     # cached: the second call must not re-run the analyzer
-    assert bench._record_meta()["analysis_clean"] is True
+    assert gateway_drivers._record_meta()["analysis_clean"] is True
 
 
 def test_perf_compare_gates_newly_dirty_tree():

@@ -297,7 +297,7 @@ def test_config_validation():
 
 
 def test_effective_impl_tracks_dispatch_gates(devices8):
-    """The predicate bench.py records must agree with what the dispatch
+    """The predicate a record reports must agree with what the dispatch
     actually runs — including the mesh batch-divisibility gate."""
     from ditl_tpu.ops.mlp import effective_bwd_impl
 
@@ -313,9 +313,30 @@ def test_effective_impl_tracks_dispatch_gates(devices8):
     assert effective_bwd_impl("xla", 8, S, D, F, MLP_BLOCKS, mesh) == "xla"
 
 
-def test_bench_records_per_projection_layout():
-    import bench
+def _effective_proj_bwd_impl(cfg, batch: int, seq: int) -> str:
+    """Which projection backward will actually run for this config — the
+    SAME predicate the dispatch uses (ops/projection.py), over the model's
+    ACTUAL projection layout (fused vs per-projection qkv). A projection
+    set that only partially tiles reports "mixed"."""
+    from ditl_tpu.ops import projection
 
+    d, hd = cfg.hidden_size, cfg.head_dim
+    if cfg.fused_qkv:
+        proj_shapes = [(d, (cfg.num_heads + 2 * cfg.num_kv_heads) * hd)]
+    else:
+        proj_shapes = [(d, cfg.num_heads * hd), (d, cfg.num_kv_heads * hd)]
+    proj_shapes.append((cfg.num_heads * hd, d))  # wo
+    blocks = (cfg.proj_bwd_block_n, cfg.proj_bwd_block_d)
+    effs = {
+        projection.effective_bwd_impl(
+            cfg.proj_bwd_impl, batch, seq, d_in, f, blocks, None
+        )
+        for d_in, f in proj_shapes
+    }
+    return effs.pop() if len(effs) == 1 else "mixed"
+
+
+def test_bench_records_per_projection_layout():
     # Unfused qkv with nkv*hd = 96: wk/wv cannot tile even though the
     # fused-sum shape could — the record must not claim a clean "pallas".
     cfg = ModelConfig(
@@ -324,8 +345,8 @@ def test_bench_records_per_projection_layout():
         dtype="float32", param_dtype="float32", fused_gate_up=True,
         proj_bwd_impl="pallas",
     )
-    eff = bench._effective_bwd_impls(cfg, 2, 32)
-    assert eff["proj"] == "mixed"  # wq/wo tile (128), wk/wv (96) do not
+    # wq/wo tile (128), wk/wv (96) do not
+    assert _effective_proj_bwd_impl(cfg, 2, 32) == "mixed"
 
 
 def test_proj_pallas_rejects_quantized_weights(model_cfg):

@@ -3,8 +3,8 @@
 The rule: where ``JAX_COMPILATION_CACHE_DIR`` is set, the program uses that
 directory and sets none in code; where it is not, every program — from any
 working directory — uses one fixed git-ignored directory inside the
-checkout. The trainer (``init_runtime``), the server (``serve``) and
-bench.py all go through the one helper.
+checkout. The trainer (``init_runtime``) and the server (``serve``) both
+go through the one helper.
 
 Timing assertions are flaky on shared CPU hosts, so the tests assert the
 *mechanism*: which directory a fresh process ends up with, that a first

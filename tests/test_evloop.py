@@ -5,7 +5,7 @@ The claims under test, in order of how expensive they are to get wrong:
 - **Many streams, few threads** — the module's reason to exist: a
   four-digit idle SSE hold must not grow the gateway's resident thread
   count past loop + offload pool (thread-per-stream reads ~N here; the
-  threaded plane is exempt by design and priced in bench.py instead).
+  threaded plane is exempt by design).
 - **Drain under open streams** — every live relay either completes or
   is severed WITH its accounting (``stream_aborts``); completed +
   aborted == opened, zero silent drops.
@@ -20,8 +20,8 @@ The claims under test, in order of how expensive they are to get wrong:
   selects the legacy transport and relays a stream end to end.
 
 The SSE replica stand-ins and the open-loop hold client are imported
-from bench.py (selector-based on both sides, so the drills measure the
-GATEWAY's threads, not scaffolding threads)."""
+from tests/gateway_drivers.py (selector-based on both sides, so the drills
+measure the GATEWAY's threads, not scaffolding threads)."""
 
 from __future__ import annotations
 
@@ -32,13 +32,15 @@ import time
 
 import pytest
 
-from bench import _SelectorSSEStub, gateway_thread_count, hold_open_sse_streams
 from ditl_tpu.config import GatewayConfig
 from ditl_tpu.gateway import (
     Fleet, GatewayMetrics, InProcessReplica, make_gateway,
 )
 from ditl_tpu.gateway.evloop import (
     EventLoopGateway, _BadRequest, _frame_request,
+)
+from tests.gateway_drivers import (
+    _SelectorSSEStub, gateway_thread_count, hold_open_sse_streams,
 )
 
 pytestmark = [pytest.mark.evloop, pytest.mark.gateway]

@@ -183,7 +183,7 @@ def test_incident_dedupe_cooldown_and_counters(tmp_path):
     assert "ditl_incidents_total 2" in samples
     assert "ditl_incidents_suppressed_total 2" in samples
     assert "ditl_incidents_trigger_serving_deadline_storm_total 1" in samples
-    assert incidents_total() >= 2  # process-wide count bench.py embeds
+    assert incidents_total() >= 2  # process-wide count a run's row embeds
 
 
 def test_failed_assembly_does_not_burn_cooldown(tmp_path, monkeypatch):

@@ -1,7 +1,7 @@
 """Performance observatory (ISSUE 7): step-time anatomy conservation,
 roofline cost analysis, HBM accounting degradation, versioned sweep
 records, and the perf_compare regression gate — including THE acceptance
-smoke: a 2-cell ``bench.py --sweep`` on the tiny CPU config whose record
+smoke: a 2-cell recorded sweep of tiny CPU trainer runs whose record
 ``perf_compare`` passes against itself and fails against a synthetically
 degraded copy."""
 
@@ -9,8 +9,6 @@ from __future__ import annotations
 
 import json
 import os
-import subprocess
-import sys
 import time
 
 import pytest
@@ -194,7 +192,7 @@ def test_git_rev_in_this_repo():
 
 
 def test_run_recorded_cells_resume_and_error_retry(tmp_path):
-    """The shared experiment-script loop (bwd_kernels/bwd_levers): cells
+    """The record-as-you-go loop: cells
     recorded without error are skipped on resume, errored cells are
     retried, and runner failures land as error cells perf_compare can
     gate."""
@@ -427,82 +425,107 @@ def test_is_oom_error_classification():
 
 
 # ---------------------------------------------------------------------------
-# THE acceptance smoke: 2-cell --sweep on the tiny CPU config, then
+# THE acceptance smoke: a 2-cell recorded sweep of tiny CPU trainer runs, then
 # perf_compare passes on identical records and fails a degraded copy.
 # ---------------------------------------------------------------------------
 
 
-def _bench_env():
-    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
-    env["JAX_PLATFORMS"] = "cpu"
-    env.pop("JAX_NUM_CPU_DEVICES", None)
-    return env
+_SWEEP_BASE = dict(
+    vocab_size=512, hidden_size=64, intermediate_size=128, num_layers=2,
+    num_heads=4, num_kv_heads=2, head_dim=16, max_seq_len=64,
+    loss_impl="fused",
+)
 
 
-def test_bench_sweep_smoke_and_regression_gate(tmp_path):
+def _train_cell(base: dict, cell: dict) -> dict:
+    """One sweep cell: a tiny fine-tune through the normal Trainer with the
+    cell's ModelConfig knobs over ``base``; the row is what a sweep record
+    holds for it (stamped, with the run's own step anatomy)."""
+    from ditl_tpu.config import (
+        Config, DataConfig, ModelConfig, TrainConfig, parse_overrides,
+    )
+    from ditl_tpu.train.trainer import train
+
+    cfg = parse_overrides(
+        Config(
+            model=ModelConfig(**base),
+            data=DataConfig(synthetic=True, synthetic_examples=64,
+                            batch_size=8, seq_len=32, num_epochs=1),
+            train=TrainConfig(total_steps=5, warmup_steps=1, log_every=2),
+        ),
+        [f"model.{k}={v}" for k, v in cell.items()],
+    )
+    out = train(cfg)
+    anatomy = out["step_anatomy"]
+    step_s = anatomy["wall_step_s"] / anatomy["steps"]
+    return {
+        "schema": SWEEP_SCHEMA,
+        "git_rev": git_rev(),
+        "value": round(8 * 32 / step_s, 1),
+        "unit": "tokens/sec",
+        "step_time_p50_ms": round(step_s * 1e3, 2),
+        "step_anatomy": anatomy,
+        "cell": dict(cell),
+    }
+
+
+def test_bench_sweep_smoke_and_regression_gate(tmp_path, capsys):
+    from ditl_tpu.telemetry.perf import run_recorded_cells
+    from ditl_tpu.telemetry.perf_compare import main as perf_compare
+
     out = str(tmp_path / "sweep.json")
-    cmd = [
-        sys.executable, os.path.join(REPO, "bench.py"),
-        "--model", "350m", "--no-compile-cache",
-        "--sweep", "loss_block_tokens=256,512", "--sweep-out", out,
-    ]
-    r = subprocess.run(cmd, env=_bench_env(), capture_output=True, text=True,
-                       timeout=560, cwd=REPO)
-    assert r.returncode == 0, f"sweep failed:\n{r.stdout}\n{r.stderr}"
-    summary = json.loads(r.stdout.strip().splitlines()[-1])
-    assert summary["completed"] == 2 and summary["failed"] == 0
+    grid = [{"loss_block_tokens": v} for v in ("128", "256")]
+    items = [(cell_key(c), c) for c in grid]
+    ran: list[str] = []
+
+    def sweep(base=_SWEEP_BASE):
+        ran.clear()
+
+        def runner(key, cell):
+            ran.append(key)
+            return _train_cell(base, cell)
+
+        return run_recorded_cells(
+            out, "train-tiny", {"base": dict(base)}, items, runner)
+
+    cells = sweep()
+    assert len(ran) == 2 and not any("error" in c for c in cells.values())
     rec = load_sweep_record(out)
     assert rec is not None and len(rec["cells"]) == 2
     for key, cell in rec["cells"].items():
-        # each cell is a full schema-stamped bench row
+        # each cell is a full schema-stamped row
         assert cell["schema"] == SWEEP_SCHEMA
         assert cell["git_rev"]
         assert cell["value"] > 0 and cell["step_time_p50_ms"] > 0
-        assert cell["vs_baseline"] is None  # swept: no anchor claimed
         assert cell["step_anatomy"]["wall_step_s"] > 0
         assert abs(cell["step_anatomy"]["conservation_error"]) <= 0.05
         assert cell["cell"] == dict(
             kv.split("=") for kv in key.split(","))
 
     # resumable: a second run skips both cells (no recompute)
-    r2 = subprocess.run(cmd, env=_bench_env(), capture_output=True,
-                        text=True, timeout=180, cwd=REPO)
-    assert r2.returncode == 0, r2.stderr
-    summary2 = json.loads(r2.stdout.strip().splitlines()[-1])
-    assert summary2["skipped"] == 2 and summary2["completed"] == 0
+    assert set(sweep()) == set(rec["cells"]) and ran == []
 
     # an ERRORED cell is retried on resume (a transient failure must not
-    # be permanently skipped behind exit 0)
+    # be permanently skipped)
     rec_edit = json.loads(open(out).read())
     victim = sorted(rec_edit["cells"])[0]
     rec_edit["cells"][victim] = {"error": "Injected: transient host OOM"}
     with open(out, "w") as f:
         json.dump(rec_edit, f)
-    r3 = subprocess.run(cmd, env=_bench_env(), capture_output=True,
-                        text=True, timeout=300, cwd=REPO)
-    assert r3.returncode == 0, r3.stderr
-    summary3 = json.loads(r3.stdout.strip().splitlines()[-1])
-    assert summary3["completed"] == 1 and summary3["skipped"] == 1
+    sweep()
+    assert ran == [victim]
     assert "error" not in load_sweep_record(out)["cells"][victim]
 
     # resuming under a DIFFERENT base config must refuse, not silently
     # reuse the other config's numbers (cell keys name only swept knobs)
-    mismatched = [
-        sys.executable, os.path.join(REPO, "bench.py"),
-        "--model", "1b3", "--no-compile-cache",
-        "--sweep", "loss_block_tokens=256,512", "--sweep-out", out,
-    ]
-    r4 = subprocess.run(mismatched, env=_bench_env(), capture_output=True,
-                        text=True, timeout=120, cwd=REPO)
-    assert r4.returncode != 0
-    assert "different base config" in (r4.stdout + r4.stderr)
+    with pytest.raises(ValueError, match="different base config"):
+        sweep({**_SWEEP_BASE, "num_layers": 1})
+    assert ran == []
 
     # the gate: identical records pass ...
-    gate = [sys.executable, "-m", "ditl_tpu.telemetry.perf_compare"]
-    ok = subprocess.run(gate + [out, out], capture_output=True, text=True,
-                        timeout=60, cwd=REPO)
-    assert ok.returncode == 0, ok.stdout + ok.stderr
-    assert "PASS" in ok.stdout
+    capsys.readouterr()
+    assert perf_compare([out, out]) == 0
+    assert "PASS" in capsys.readouterr().out
     # ... and a thresholded degradation exits nonzero
     bad = json.loads(open(out).read())
     for cell in bad["cells"].values():
@@ -511,7 +534,5 @@ def test_bench_sweep_smoke_and_regression_gate(tmp_path):
     bad_path = str(tmp_path / "degraded.json")
     with open(bad_path, "w") as f:
         json.dump(bad, f)
-    fail = subprocess.run(gate + [out, bad_path], capture_output=True,
-                          text=True, timeout=60, cwd=REPO)
-    assert fail.returncode == 1, fail.stdout + fail.stderr
-    assert "REGRESSION" in fail.stdout
+    assert perf_compare([out, bad_path]) == 1
+    assert "REGRESSION" in capsys.readouterr().out

@@ -543,13 +543,19 @@ def test_gateway_stamps_label_ledgers_edge_rows_and_fans_out_usage(
 # ---------------------------------------------------------------------------
 
 
-def _noisy_run(tmp_path, tiny_model, tag: str, chaos_rules: str):
+_LATENCY_FACTOR = 20.0
+_DECODE_CHUNK = 8
+
+
+def _noisy_run(tmp_path, tiny_model, tag: str, storm: bool):
     """One serving leg: warm (compile outside the detector windows),
     flush the compile-polluted histogram window, establish a healthy
     TPOT baseline, then run tenant t_mallory's chunked batch prefills
-    against tenant t_alice's decode stream — with ``chaos_rules``
-    stalling every tick so the TPOT p95 jumps (the storm IS the
-    injected fault); without them an identical healthy run."""
+    against tenant t_alice's decode stream — with ``storm``, every tick
+    is stalled by an injected delay sized from the baseline the detector
+    itself just measured, so the TPOT p95 jumps however loaded the
+    machine is (the storm IS the injected fault); without it an identical
+    healthy run."""
     from ditl_tpu import chaos
     from ditl_tpu.infer.continuous import ContinuousEngine
     from ditl_tpu.infer.engine import GenerateConfig
@@ -575,20 +581,19 @@ def _noisy_run(tmp_path, tiny_model, tag: str, chaos_rules: str):
         # Only the latency-jump detectors are live: storms/queue/ratio
         # detectors are parked high so the drill isolates the tpot_jump
         # + conviction path.
-        # latency_factor 5.0 (not the 3.0 default): the injected 60 ms
-        # per-tick stall clears 5x the sub-10ms healthy baseline with
-        # room to spare, while an ORGANIC jump on a loaded CI machine
-        # (GC pause, scheduler hiccup) must not fire the control leg.
+        # latency_factor 20 (not the 3.0 default): an ORGANIC jump on a
+        # loaded CI machine (GC pause, scheduler hiccup) must not fire
+        # the control leg; the storm leg's stall is sized past it below.
         ServingDetector(storm_threshold=10 ** 6,
                         queue_depth_limit=10 ** 6,
-                        latency_factor=5.0, min_samples=16,
+                        latency_factor=_LATENCY_FACTOR, min_samples=16,
                         min_hit_tokens=10 ** 9),
         check_every=4,
         usage=meter, conviction_share=0.5, conviction_min_tokens=32,
     )
     eng = ContinuousEngine(
-        params, cfg, tok, n_slots=2, decode_chunk=8, prefill_chunk=32,
-        gen=GenerateConfig(max_new_tokens=8),
+        params, cfg, tok, n_slots=2, decode_chunk=_DECODE_CHUNK,
+        prefill_chunk=32, gen=GenerateConfig(max_new_tokens=8),
         metrics=metrics, flight=flight, usage=meter, usage_ledger=ledger,
     )
     short = [tok.bos_id] + tok.encode("hello")
@@ -609,18 +614,32 @@ def _noisy_run(tmp_path, tiny_model, tag: str, chaos_rules: str):
         eng.submit(list(short), tenant="t_alice", max_new_tokens=48)
         eng.submit(list(short), tenant="t_alice", max_new_tokens=48)
         eng.run()
-    if chaos_rules:
-        chaos.arm(chaos.FaultPlane(rules=chaos_rules))
+    # The baseline the jump is measured against is set BEFORE the fault is
+    # armed: the detector holds a TPOT EMA from the clean windows above.
+    baseline = monitor.detector._tpot_ema
+    assert baseline is not None and baseline > 0
+    if storm:
+        # A per-tick stall worth 3x the detector's own threshold over the
+        # baseline it measured on THIS machine just now (TPOT = tick
+        # interval / decode_chunk): a loaded box's slower baseline raises
+        # the stall with it, so the jump never hangs on the box's mood.
+        delay = 3 * _LATENCY_FACTOR * baseline * _DECODE_CHUNK
+        chaos.arm(chaos.FaultPlane(
+            rules=f"engine.tick:delay@delay={delay:.4f},max=60"))
     try:
         # The storm: alice keeps decoding (the victim stream) while
         # mallory's chunked batch prefills burn the scheduler — under
         # injected per-tick stalls the windowed TPOT p95 blows past
-        # 3x the healthy EMA.
+        # the factor times the healthy EMA.
         eng.submit(list(short), tenant="t_alice", max_new_tokens=64)
         for _ in range(4):
             eng.submit(list(batch_prompt), tenant="t_mallory",
                        max_new_tokens=4, slo_class="batch")
-        eng.run()
+        while eng.pending:
+            eng.step()
+            if storm and incidents.created:
+                # Convicted: the rest of the storm need not be sat out.
+                chaos.disarm()
     finally:
         chaos.disarm()
     ledger.close()
@@ -637,13 +656,10 @@ def test_acceptance_noisy_neighbor_conviction_drill(tmp_path, tiny_model):
     aggregator runs."""
     from ditl_tpu.telemetry.incident import list_bundles
 
+    # Injected stall on every tick until the conviction lands: windowed
+    # TPOT p95 jumps while mallory's chunks dominate the conviction window.
     _, _, inc_dir, ledger_dir = _noisy_run(
-        tmp_path, tiny_model, "storm",
-        # 60 ms injected stall per tick, enough ticks to cover the whole
-        # storm phase: windowed TPOT p95 jumps while mallory's chunks
-        # dominate the conviction window.
-        "engine.tick:delay@delay=0.06,max=60",
-    )
+        tmp_path, tiny_model, "storm", storm=True)
     bundles = list_bundles(inc_dir)
     assert len(bundles) == 1, [b["trigger"] for b in bundles]
     m = bundles[0]
@@ -671,7 +687,7 @@ def test_acceptance_noisy_neighbor_conviction_drill(tmp_path, tiny_model):
     # The chaos-free control: identical traffic, ZERO bundles, and the
     # aggregator is deterministic over its ledger.
     _, _, inc_dir2, ledger_dir2 = _noisy_run(
-        tmp_path, tiny_model, "control", "")
+        tmp_path, tiny_model, "control", storm=False)
     assert list_bundles(inc_dir2) == []
     one = json.dumps(rollup(load_usage(ledger_dir2)), sort_keys=True)
     two = json.dumps(rollup(load_usage(ledger_dir2)), sort_keys=True)
@@ -692,13 +708,10 @@ def test_gateway_overhead_metered_ab_and_perf_compare(tmp_path):
     actually written, tenants labeled), and perf_compare gates
     gateway_rps_metered / metering_overhead_ratio — 0 on the pair, 1 on
     a degraded copy."""
-    from bench import run_gateway_overhead_bench
     from ditl_tpu.telemetry.perf_compare import compare_records
+    from tests.gateway_drivers import run_gateway_overhead_bench
 
-    row = run_gateway_overhead_bench(
-        n_replicas=2, requests=60, clients=3, usage_metering=True,
-        usage_dir=str(tmp_path / "usage"),
-    )
+    row = run_gateway_overhead_bench(60, usage_dir=str(tmp_path / "usage"))
     block = row["usage_metering"]
     assert block["schema"] == 1
     assert block["gateway_rps_metered"] > 0
