@@ -150,59 +150,46 @@ def _max_over_mean(counts) -> float:
 
 
 @jax.named_scope("kv_write")
-def _flush_tail_into_pools(pools, tk, tv, starts, pos, table, ps, tail_len):
-    """Scatter the tick's tail columns into their pages — ONE scatter per
-    pool per tick (amortized over the chunk; per-token in-scan page writes
-    cost ~7 ms/step on v5e). Valid columns are j < pos - starts (exactly
-    the tokens the tick committed; rejected speculative positions and dead
-    rows fall outside). Invalid columns aim at sentinel page 0 with
-    row-distinct offsets, whose content is never read unmasked. int8 pools:
-    the tail is quantized HERE (tokens attend at full precision within
-    their own tick, then round once). Shared by the plain and speculative
-    paged decode programs."""
-    n_b = pos.shape[0]
-    b_iota = jnp.arange(n_b, dtype=jnp.int32)
-    L, _, K, _, D = pools["kp"].shape
-    j = jnp.arange(tail_len, dtype=jnp.int32)
-    gpos = starts[:, None] + j[None, :]  # (B, tail_len)
-    valid = j[None, :] < (pos - starts)[:, None]
-    pidx = jnp.take_along_axis(
-        table, jnp.clip(gpos // ps, 0, table.shape[1] - 1), axis=1
-    )
-    pid = jnp.where(valid, pidx, 0).reshape(-1)
-    off = jnp.where(
-        valid, gpos % ps,
-        (b_iota[:, None] * tail_len + j[None, :]) % ps,
-    ).reshape(-1)
-
-    def flush(pool, tail):
-        # tail (L, B, K, T, D) -> (B*T, L, K, D); advanced indices
-        # on pool dims 1 and 3 put the scatter dim first.
-        vals = jnp.transpose(tail, (1, 3, 0, 2, 4)).reshape(
-            n_b * tail_len, L, K, D
-        )
-        return pool.at[:, pid, :, off].set(vals.astype(pool.dtype))
-
-    def flush_scale(spool, scales):
-        # scales (L, B, K, T) -> (B*T, L, K, 1); spool (L,P,K,1,ps)
-        vals = jnp.transpose(scales, (1, 3, 0, 2)).reshape(
-            n_b * tail_len, L, K
-        )[..., None]
-        return spool.at[:, pid, :, :, off].set(vals)
+def _flush_tail_into_pools(pools, tk, tv, starts, pos, table, mesh=None,
+                           rules=None):
+    """Write the tick's tail columns into their pages — ONE flush per tick
+    (amortized over the chunk; per-token in-scan page writes cost ~7 ms/step
+    on v5e), in place: the ``kv_flush`` kernel reads, merges and writes back
+    only the tiles the committed columns land in (ops/kv_flush.py; an XLA
+    scatter here cost a transpose of the whole pool there and back, or 70 ns
+    a row, live or dead). Valid columns are j < pos - starts (exactly the
+    tokens the tick committed; rejected speculative positions and dead rows
+    fall outside) and nothing else is written. int8 pools: the tail is
+    quantized HERE (tokens attend at full precision within their own tick,
+    then round once); their small scale pools take a scatter of single
+    scales, invalid columns aimed past the pool's end and dropped. Shared by
+    the plain and speculative paged decode programs."""
+    from ditl_tpu.ops.kv_flush import kv_flush
 
     out = dict(pools)
     if "ks" in pools:
         from ditl_tpu.infer.cache import _quantize
 
-        qk, sk = _quantize(tk)
-        qv, sv = _quantize(tv)
-        out["kp"] = flush(pools["kp"], qk)
-        out["vp"] = flush(pools["vp"], qv)
-        out["ks"] = flush_scale(pools["ks"], sk)
-        out["vs"] = flush_scale(pools["vs"], sv)
-    else:
-        out["kp"] = flush(pools["kp"], tk)
-        out["vp"] = flush(pools["vp"], tv)
+        (tk, sk), (tv, sv) = _quantize(tk), _quantize(tv)
+        L, n_pages, K, _, ps = pools["ks"].shape
+        j = jnp.arange(tk.shape[3], dtype=jnp.int32)
+        gpos = starts[:, None] + j[None, :]  # (B, tail_len)
+        valid = j[None, :] < (pos - starts)[:, None]
+        pidx = jnp.take_along_axis(
+            table, jnp.clip(gpos // ps, 0, table.shape[1] - 1), axis=1
+        )
+        # index arrays broadcast to the scales' own (L, B, K, T)
+        ix = (jnp.arange(L, dtype=jnp.int32)[:, None, None, None],
+              jnp.where(valid, pidx, n_pages)[None, :, None, :],
+              jnp.arange(K, dtype=jnp.int32)[None, None, :, None],
+              0, (gpos % ps)[None, :, None, :])
+        out["ks"] = pools["ks"].at[ix].set(sk, mode="drop")
+        out["vs"] = pools["vs"].at[ix].set(sv, mode="drop")
+    dt = pools["kp"].dtype
+    out["kp"], out["vp"] = kv_flush(
+        pools["kp"], pools["vp"], tk.astype(dt), tv.astype(dt), table, starts,
+        pos, mesh=mesh, rules=rules,
+    )
     return out
 
 
@@ -1724,11 +1711,11 @@ class ContinuousEngine:
         """Paged decode tick with DEFERRED page writes: the chunk's K/V
         accumulate in small per-layer tail buffers carried through the scan
         (the kernel reads pages + tail; per-token writes into the pooled
-        buffers inside the scan cost ~7 ms/step on v5e), then ONE scatter
-        per pool flushes the tail after the scan. ``limits`` ends a row
+        buffers inside the scan cost ~7 ms/step on v5e), then ONE flush
+        writes the tail into the pools after the scan. ``limits`` ends a row
         exactly at its token budget, so flushed positions never pass the
         pages reserved at admission."""
-        cfg, ps = self.cfg, self.page_size
+        cfg = self.cfg
         pad, eos = self.tokenizer.pad_id, self.tokenizer.eos_id
         chunk = self.decode_chunk
         tail_len = max(chunk, 8)  # Mosaic sublane floor for the tail block
@@ -1823,7 +1810,7 @@ class ContinuousEngine:
             )
 
             out = _flush_tail_into_pools(
-                pools, tk, tv, starts, pos, table, ps, tail_len
+                pools, tk, tv, starts, pos, table, self.mesh, self.rules
             )
             fs = (fst,) if guided else ()
             if n_lp:
@@ -1846,7 +1833,7 @@ class ContinuousEngine:
         tick's (valid = j < pos - starts). ``limits`` caps emission on
         device so flushed positions never pass the pages reserved at
         admission. ``sampled``: see ``_build_spec_decode``."""
-        cfg, ps, smax = self.cfg, self.page_size, self.smax
+        cfg, smax = self.cfg, self.smax
         pad, eos = self.tokenizer.pad_id, self.tokenizer.eos_id
         k, rounds = self.spec_k, self.spec_rounds
         ngram, min_ngram = self.spec_ngram, self.spec_min_ngram
@@ -1978,7 +1965,7 @@ class ContinuousEngine:
                 None, length=rounds,
             )
             pools_out = _flush_tail_into_pools(
-                pools, tk, tv, starts, pos, table, ps, tail_len
+                pools, tk, tv, starts, pos, table, self.mesh, self.rules
             )
             fs = (fst,) if guided else ()
             dc = (dcache,) if model_draft else ()

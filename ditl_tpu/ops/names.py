@@ -11,7 +11,14 @@ K/V cache or page pool) out of the stacked arrays, stacking what the layers
 return, and what remat saves between forward and backward. ``kv_gather`` is
 an explicit read of cached K/V (the contiguous cache's ``read_kv``, a paged
 prefill's gather of its context pages); the paged decode program has none,
-its kernel reads the pages and its whole-pool slice is ``layer_scan``'s.
+its kernel reads the pages in place. ``kv_write`` in a paged decode is each
+step's write of the new K and V into the tick's tail (a
+``dynamic_update_slice`` a layer) and, once a tick, the flush of that tail
+into the page pools: the ``kv_flush`` kernel, which sits inside the scope, so
+a reader that knows only ``SCOPES`` books it there. A layout copy that the
+compiler puts around a write carries no scope at all (the flush's scatter had
+eight of the whole pool a tick until PR 29, a fifth of the 7B serving cell's
+device time, under no name).
 
 ``MOE_SCOPES`` are the parts of an expert layer (``models/moe.py``), all
 INSIDE ``mlp``: to a reader that knows only ``SCOPES`` an expert layer's time
@@ -47,6 +54,11 @@ MOE_SCOPES = (
 # kernel (``jax.experimental.pallas.ops.tpu.megablox``): these are ITS names,
 # forward and the transposed product of the backward pass.
 MOE_KERNELS = ("gmm", "tgmm")
+
+# The tick's flush (``ops/kv_flush.py``), inside ``kv_write``. A tuple of its
+# own, as ``MOE_KERNELS``: ``benchmarks/layer_metrics/_scopes.py`` holds its
+# table equal to ``SCOPES`` / ``KERNELS``.
+CACHE_KERNELS = ("kv_flush",)
 
 KERNELS = (
     "flash_fwd",

@@ -664,3 +664,67 @@ def test_paged_decode_reads_each_layers_own_pages(monkeypatch, kind):
     want = llama.rms_norm(x, params["final_norm"]["scale"], cfg.rms_norm_eps)
     np.testing.assert_allclose(np.asarray(hidden), np.asarray(want), atol=2e-5)
     assert set(new) == {"tk", "tv"}
+
+
+# -- the tick's flush -----------------------------------------------------------
+
+
+@pytest.mark.parametrize("tail_len, pool_dtype", [
+    (8, "float32"), (16, "bfloat16"), (16, "int8"), (27, "bfloat16"), (27, "int8"),
+], ids=["plain-f32", "plain-bf16", "plain-int8", "speculative-bf16",
+        "speculative-int8"])
+def test_flush_writes_the_committed_rows_and_nothing_else(tail_len, pool_dtype):
+    """``_flush_tail_into_pools`` against a NumPy loop over the committed
+    columns, on pools filled with noise: a tail that crosses a page boundary
+    (and a tile boundary inside a page), one that starts on a tile boundary,
+    a dead row (``pos == starts``), a row stopped short of the tail's
+    length, one column; a speculative tick's tail (27 = 3 rounds of 8 + 1)
+    spans up to three tiles. Every row of every page that no committed
+    column names is bit-identical to before, the sentinel page 0 too."""
+    from ditl_tpu.infer.cache import _quantize
+    from ditl_tpu.infer.continuous import _flush_tail_into_pools
+
+    L, P, K, ps, D = 2, 13, 2, 32, 16
+    int8 = pool_dtype == "int8"
+    tdt = jnp.bfloat16 if int8 else jnp.dtype(pool_dtype)
+    rng = np.random.default_rng(tail_len)
+    table = np.array([[1, 2, 3], [4, 5, 0], [6, 7, 0], [8, 12, 0], [9, 10, 11],
+                      [0, 0, 0]], np.int32)
+    #                  crosses a page  tile-aligned  dead  short  one column  free slot
+    starts = np.array([ps - 3,         16,           5,    40,    2 * ps + 1, 0], np.int32)
+    wrote = np.array([tail_len,        tail_len,     0,    5,     1,          0], np.int32)
+    B = len(starts)
+    noise = lambda shape: rng.standard_normal(shape).astype(np.float32)  # noqa: E731
+    if int8:
+        pools = {n: jnp.asarray(rng.integers(-127, 128, (L, P, K, ps, D)), jnp.int8)
+                 for n in ("kp", "vp")}
+        pools.update({n: jnp.asarray(1 + rng.random((L, P, K, 1, ps)), jnp.float32)
+                      for n in ("ks", "vs")})
+    else:
+        pools = {n: jnp.asarray(noise((L, P, K, ps, D)), tdt) for n in ("kp", "vp")}
+    tk = jnp.asarray(noise((L, B, K, tail_len, D)), tdt)
+    tv = jnp.asarray(noise((L, B, K, tail_len, D)), tdt)
+
+    want = {n: np.array(a) for n, a in pools.items()}
+    vals = {"kp": tk, "vp": tv}
+    if int8:
+        quantize = jax.jit(_quantize)  # the flush rounds inside a program too
+        (vals["kp"], sk), (vals["vp"], sv) = quantize(tk), quantize(tv)
+        vals.update(ks=sk, vs=sv)
+    vals = {n: np.asarray(a) for n, a in vals.items()}
+    for b in range(B):
+        for j in range(wrote[b]):
+            p = starts[b] + j
+            page, off = table[b, p // ps], p % ps
+            for n in ("kp", "vp"):
+                want[n][:, page, :, off] = vals[n][:, b, :, j]
+            for n in ("ks", "vs") if int8 else ():
+                want[n][:, page, :, 0, off] = vals[n][:, b, :, j]
+
+    got = jax.jit(_flush_tail_into_pools, donate_argnums=(0,))(
+        pools, tk, tv, jnp.asarray(starts), jnp.asarray(starts + wrote),
+        jnp.asarray(table))
+    assert set(got) == set(want)
+    for n in want:
+        assert got[n].dtype == want[n].dtype
+        np.testing.assert_array_equal(np.asarray(got[n]), want[n], err_msg=n)

@@ -12,6 +12,7 @@ may load the TPU's library at a time (``on-chip-measurement`` guide).
 
 from __future__ import annotations
 
+import math
 import os
 import re
 
@@ -42,10 +43,10 @@ def tpu_branch(monkeypatch):
     """The code asks ``jax.default_backend()``, which is the CPU here: take
     the branch the chip takes."""
     monkeypatch.setattr(moe_mod, "_use_gmm", lambda rows, mesh: rows % 128 == 0)
-    from ditl_tpu.ops import backend, paged_attention
+    from ditl_tpu.ops import backend, kv_flush, paged_attention
 
     # every module that bound the name at its import, whichever came first
-    for module in (moe_mod, backend, paged_attention):
+    for module in (moe_mod, backend, kv_flush, paged_attention):
         monkeypatch.setattr(module, "interpret_default", lambda: False)
 
 
@@ -146,3 +147,42 @@ def test_paged_decode_layer_loop_copies_no_pool(one_chip, tpu_branch, preset, la
     assert producers(layers * pages, kv, ps, hd) <= {"bitcast", "get-tuple-element"}
     layer_pool_bytes = pages * kv * ps * hd * 2
     assert compiled.memory_analysis().temp_size_in_bytes < layer_pool_bytes / 10
+
+
+@pytest.mark.parametrize(
+    "layers, pages, kv",
+    [(12, 720, 4), (10, 192, 16)],
+    ids=["qwen2-7b-cut1", "olmoe-1b-7b-cut1"],
+)
+def test_paged_flush_copies_no_pool(one_chip, tpu_branch, layers, pages, kv):
+    """The tick's flush of its tail into the donated page pools at the two
+    serving cells' shapes (64 slots, pages of 256, tail 16, heads of 128). As
+    an XLA scatter with a window of ``(L, K, D)`` (``pool.at[:, pid, :,
+    off]``, before PR 29) it had the TPU compiler transpose each WHOLE pool
+    to another layout in front of the scatter and back behind it: four
+    pool-sized ``copy`` instructions, 2.11 GiB of temporaries. The
+    ``kv_flush`` kernel takes the pools as they are and gives them back
+    aliased: nothing produces an array of a pool's size but the custom call
+    itself."""
+    from ditl_tpu.infer.continuous import _flush_tail_into_pools
+
+    b, ps, tail, maxp, hd = 64, 256, 16, 16, 128
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    pool = s((layers, pages, kv, ps, hd), jnp.bfloat16)
+    tails = s((layers, b, kv, tail, hd), jnp.bfloat16)
+    row = s((b,), jnp.int32)
+    compiled = jax.jit(_flush_tail_into_pools, donate_argnums=(0,)).lower(
+        {"kp": pool, "vp": pool}, tails, tails, row, row,
+        s((b, maxp), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert names.CACHE_KERNELS[0] in _instructions(text)
+
+    pool_elements = layers * pages * kv * ps * hd
+    producers = set()
+    for dims, op in re.findall(r" = bf16\[([\d,]+)\]\S* ([\w\-]+)\(", text):
+        if math.prod(map(int, dims.split(","))) == pool_elements:
+            producers.add(op)
+    assert producers <= {"bitcast", "parameter", "get-tuple-element", "custom-call"}
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < pool_elements * 2 / 10
+    assert mem.alias_size_in_bytes == 2 * pool_elements * 2  # both pools in place
