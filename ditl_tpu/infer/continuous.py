@@ -20,6 +20,10 @@ wait for the batch to drain. This engine removes both limits the TPU way:
 - **Chunked ticks**: decode runs ``decode_chunk`` steps per program call
   (a ``lax.scan``; zero host round-trips inside), then the host harvests
   finished slots, trims at EOS, and admits queued requests.
+- **A first token of its own**: the token a prefill samples leaves with the
+  tick that ran the prefill — fetched right behind that tick's decode
+  dispatch, put on the request's stream alone — and not with the tick's
+  other ``decode_chunk - 1`` tokens a whole decode program later.
 
 The scheduler (``submit``/``step``/``run``) is deliberately host-side and
 simple — admission policy is not a TPU problem. Per-request sampling params
@@ -246,8 +250,14 @@ class Request:
     # joins decode ticks only once the whole prompt is in the cache.
     prefill_pos: int = 0
     prefilling: bool = False
-    # Streaming: when set, every harvest pushes this chunk's new token ids
-    # (list[int]); a final ``None`` marks completion.
+    # True from the tick that sent the prefill's sampled token (step():
+    # ``tokens[-1]`` is then the slot's still PENDING ``cur``) until the
+    # harvest of the request's first decode tick, whose row leads with that
+    # token and must not emit it again.
+    first_sent: bool = False
+    # Streaming: when set, the first token is pushed alone by the tick that
+    # prefilled the prompt and every harvest pushes its chunk's new token
+    # ids (list[int]); a final ``None`` marks completion.
     stream: Any = None
     # Measured speculative acceptance for THIS request: tokens emitted
     # across its speculative rounds / verify forwards it participated in.
@@ -278,7 +288,7 @@ class Request:
     lp_top_ids: list[list[int]] = field(default_factory=list)
     lp_top: list[list[float]] = field(default_factory=list)
     # Telemetry timestamps (time.monotonic; 0.0 = not yet): submit, slot
-    # admission, first harvested token, last harvest. Host wall clocks only
+    # admission, first token sent, last chunk sent. Host wall clocks only
     # — the latency histograms (telemetry/serving.py) are built from these.
     t_submit: float = 0.0
     t_admitted: float = 0.0
@@ -351,6 +361,12 @@ class Request:
     def slo_rank(self) -> tuple[int, int]:
         """Scheduling order key: class rank, then arrival."""
         return (SLO_CLASSES[self.slo_class], self.req_id)
+
+    def note_logprobs(self, chosen, top_ids, top_lp) -> None:
+        """Append one generated token's stats (host values)."""
+        self.lp_token.append(float(chosen))
+        self.lp_top_ids.append([int(x) for x in top_ids])
+        self.lp_top.append([float(x) for x in top_lp])
 
 
 class ContinuousEngine:
@@ -508,6 +524,11 @@ class ContinuousEngine:
         # Per-tick prefill work [(req_id, tokens, wall_s)] — the
         # interference-attribution input (see step()).
         self._tick_prefills: list[tuple[int, int, float]] = []
+        # Per-tick first tokens [(request, token, logprob stats or None)]:
+        # what this tick's prefills sampled, still on the device until
+        # _send_first_tokens fetches them behind the decode dispatch.
+        self._tick_firsts: list[tuple[Request, Any, Any]] = []
+        self.first_tokens_early = 0
         if n_slots < 1:
             raise ValueError(f"n_slots must be >= 1, got {n_slots}")
         if decode_chunk < 1:
@@ -2407,11 +2428,8 @@ class ContinuousEngine:
             if req.prefill_pos >= len(req.prompt):
                 req.prefilling = False
                 self._publish_prompt_pages(req, req.slot)
-                self.cur = self.cur.at[req.slot].set(first)
-                self.pos = self.pos.at[req.slot].set(len(req.prompt))
                 self.keys = self.keys.at[req.slot].set(slot_key)
-                self._set_hist(req.slot, req.prompt, first)
-                self._draft_prefill(req, req.slot)
+                self._seat_first(req, req.slot, first)
             return
         d = req.prefill_pos
         s = min(self.prefill_chunk, len(req.prompt) - d)
@@ -2432,11 +2450,8 @@ class ContinuousEngine:
         req.prefill_pos += s
         if req.prefill_pos >= len(req.prompt):
             req.prefilling = False
-            self.cur = self.cur.at[req.slot].set(first)
-            self.pos = self.pos.at[req.slot].set(len(req.prompt))
             self.keys = self.keys.at[req.slot].set(slot_key)
-            self._set_hist(req.slot, req.prompt, first)
-            self._draft_prefill(req, req.slot)
+            self._seat_first(req, req.slot, first)
 
     def _take_prefill(self, out, slot: int | None):
         """Unpack a prefill program's outputs: store the new cache and —
@@ -2481,6 +2496,21 @@ class ContinuousEngine:
         self.hist = (
             self.hist.at[slot].set(jnp.asarray(row)).at[slot, n].set(first)
         )
+
+    def _seat_first(self, req: Request, slot: int, first) -> None:
+        """The slot goes live behind its finished prefill: ``first``, the
+        token that prefill sampled, is the pending ``cur`` at the prompt's
+        end, and joins this tick's first tokens (``_send_first_tokens``)
+        with its logprob stats when the request asked for them. All of it
+        stays on the device — no host sync on admission."""
+        self.cur = self.cur.at[slot].set(first)
+        self.pos = self.pos.at[slot].set(len(req.prompt))
+        self._set_hist(slot, req.prompt, first)
+        self._draft_prefill(req, slot)
+        lp = None
+        if req.logprobs is not None:
+            lp = (self.lp_chosen[slot], self.lp_ids[slot], self.lp_top[slot])
+        self._tick_firsts.append((req, first, lp))
 
     # -- paged admission / prefill -------------------------------------------
 
@@ -3001,10 +3031,7 @@ class ContinuousEngine:
                                  time.monotonic() - m0, "prompt")
             self._phase(was)
             self._publish_prompt_pages(req, slot)
-            self.cur = self.cur.at[slot].set(first)
-            self.pos = self.pos.at[slot].set(len(req.prompt))
-            self._set_hist(slot, req.prompt, first)
-            self._draft_prefill(req, slot)
+            self._seat_first(req, slot, first)
         self.temps = self.temps.at[slot].set(req.temperature)
         self.top_ps = self.top_ps.at[slot].set(req.top_p)
         self.keys = self.keys.at[slot].set(slot_key)
@@ -3585,10 +3612,7 @@ class ContinuousEngine:
                 self.cur = self.cur.at[slot].set(self.tokenizer.pad_id)
                 self.pos = self.pos.at[slot].set(self.smax - 1)
             else:
-                self.cur = self.cur.at[slot].set(first)
-                self.pos = self.pos.at[slot].set(len(req.prompt))
-                self._set_hist(slot, req.prompt, first)
-                self._draft_prefill(req, slot)
+                self._seat_first(req, slot, first)
             self.temps = self.temps.at[slot].set(req.temperature)
             self.top_ps = self.top_ps.at[slot].set(req.top_p)
             self.keys = self.keys.at[slot].set(slot_key)
@@ -3625,6 +3649,54 @@ class ContinuousEngine:
             (r, r.prefilling if r is not None else False) for r in self._slots
         ]
 
+    def _live_decode_slots(self, snapshot) -> int:
+        """Slots of ``snapshot`` that decode in its tick: what one tick's
+        device time is shared among (``_deliver``)."""
+        return sum(
+            1 for r, was_p in snapshot
+            if r is not None and not was_p
+            and not r.finished and not r.cancelled
+        )
+
+    def _send_first_tokens(self) -> None:
+        """Send the tokens this tick's prefills sampled: ONE ``device_get``
+        for all of them, called once the tick's decode program is enqueued
+        behind the prefills. The device executes in order, so the fetch
+        returns when the tick's last prefill has run and the device never
+        waits for the host; several requests admitted in one tick share the
+        fetch, so each waits for the last of their prefills. Every request
+        takes this path, streamed or not, logprobs or not: its ``t_first``
+        and TTFT are stamped here. The first decode tick's row still leads
+        with the token (each step emits the PENDING token), so the request
+        is marked ``first_sent`` for ``_harvest`` to skip it. A first token
+        that ends the stream (eos / pad) is not sent: the harvest ends the
+        request as it ends any other. Nor is one whose request page top-up
+        preempted between its prefill and the dispatch: its pending token
+        was captured for the resume, whose tick emits it."""
+        firsts, self._tick_firsts = self._tick_firsts, []
+        firsts = [f for f in firsts if not f[0].preempted]
+        sent = 0
+        if firsts:
+            self._phase("engine.tick.fetch")
+            fetched = jax.device_get([(tok, lp) for _, tok, lp in firsts])
+            self._phase("engine.tick.harvest")
+            eos, pad = self.tokenizer.eos_id, self.tokenizer.pad_id
+            t_now = time.monotonic()
+            n_share = self._live_decode_slots(self._snapshot_slots())
+            for (req, _, _), (tok, lp) in zip(firsts, fetched):
+                tok = int(tok)
+                if tok in (eos, pad):
+                    continue
+                req.tokens.append(tok)
+                req.first_sent = True
+                if lp is not None:
+                    req.note_logprobs(*lp)
+                sent += 1
+                self._deliver(req, [tok], t_now, n_share)
+        self.first_tokens_early += sent
+        if self._tick_span is not None:
+            self._tick_span.annotate(first_tokens=sent)
+
     def _harvest(self, emitted: np.ndarray, counts: np.ndarray | None = None,
                  lp=None, snapshot=None) -> None:
         """``counts`` (speculative ticks): per-row valid-emission counts —
@@ -3634,24 +3706,15 @@ class ContinuousEngine:
         ``lp`` (chosen, top_ids, top_lp arrays, column-aligned with
         ``emitted``): per-token logprob stats, attached to requests that
         asked for them. ``snapshot`` (pipelined ticks): the slot states at
-        dispatch time (see ``_snapshot_slots``)."""
+        dispatch time (see ``_snapshot_slots``). A row's column 0 is the
+        token that was pending at dispatch; in a request's first tick that
+        is the prefill's token, which ``_send_first_tokens`` sent already
+        (``first_sent``), so the chunk delivered here is the row's rest."""
         eos, pad = self.tokenizer.eos_id, self.tokenizer.pad_id
         if snapshot is None:
             snapshot = self._snapshot_slots()
         t_now = time.monotonic()  # one clock read per harvest, shared below
-        # Decode-tick device-time share (ISSUE 15): the slots of one tick
-        # ran ONE device program together, so each live decode slot's
-        # harvest interval is attributed 1/n_share to its request — the
-        # decode half of the per-request device-time estimate (the prefill
-        # half is measured per dispatch in _record_prefill). An estimate
-        # by construction (host wall, pipelined ticks overlap dispatch);
-        # consistent ACROSS tenants, which is what billing shares and
-        # convictions need. Zero device syncs: t_now is already read.
-        n_share = sum(
-            1 for r, was_p in snapshot
-            if r is not None and not was_p
-            and not r.finished and not r.cancelled
-        )
+        n_share = self._live_decode_slots(snapshot)
         for slot, (req, was_prefilling) in enumerate(snapshot):
             if req is None or was_prefilling:
                 # A still-prefilling slot is parked: its decode-row output is
@@ -3664,100 +3727,21 @@ class ContinuousEngine:
                 continue
             fresh: list[int] = []
             row = emitted[slot] if counts is None else emitted[slot][: counts[slot]]
-            for j, tok in enumerate(row):
-                tok = int(tok)
+            j0 = 1 if req.first_sent else 0
+            req.first_sent = False
+            for j in range(j0, len(row)):
+                tok = int(row[j])
                 if tok in (eos, pad) or len(req.tokens) >= req.max_new_tokens:
                     req.finished = True
                     break
                 req.tokens.append(tok)
                 fresh.append(tok)
                 if lp is not None and req.logprobs is not None:
-                    c, ids, top = lp
-                    req.lp_token.append(float(c[slot, j]))
-                    req.lp_top_ids.append([int(x) for x in ids[slot, j]])
-                    req.lp_top.append([float(x) for x in top[slot, j]])
+                    req.note_logprobs(*(x[slot, j] for x in lp))
             if len(req.tokens) >= req.max_new_tokens:
                 req.finished = True
-            if self.cache_mode == "paged":
-                self._win_gen_tokens += len(fresh)  # thrash-guard accounting
             if fresh:
-                m = self.metrics
-                m.tokens_generated.inc(len(fresh))
-                if req.fsm_start > 0:
-                    # Every one of these tokens decoded under the FSM mask.
-                    m.grammar_masked.inc(len(fresh))
-                first_chunk = req.t_first == 0.0
-                if first_chunk:
-                    req.t_first = t_now
-                    if req.t_submit:
-                        ttft = t_now - req.t_submit
-                        m.ttft.observe(ttft)
-                        # Hit/miss split (ISSUE 8): the histogram pair that
-                        # answers "does a prefix-cache hit actually buy
-                        # TTFT" from /metrics alone.
-                        (m.ttft_cache_hit if req.cache_hit_tokens > 0
-                         else m.ttft_cache_miss).observe(ttft)
-                        # Class split (ISSUE 9): the disagg A/B grades
-                        # interactive TTFT specifically.
-                        cls_hist = m.ttft_by_class.get(req.slo_class)
-                        if cls_hist is not None:
-                            cls_hist.observe(ttft)
-                elif req.t_last_emit:
-                    # TPOT: this harvest interval amortized over the chunk's
-                    # tokens, observed once per token. The first chunk is
-                    # excluded (its interval is prefill-dominated — that is
-                    # TTFT's job).
-                    m.decode_token.observe(
-                        (t_now - req.t_last_emit) / len(fresh), n=len(fresh)
-                    )
-                prev_emit = (req.t_last_emit or req.t_prefill_done
-                             or req.t_admitted or req.t_submit)
-                if prev_emit and n_share:
-                    share = max(0.0, t_now - prev_emit) / n_share
-                    req.device_time_est_s += share
-                    if self.usage is not None:
-                        self.usage.note_device(req.tenant, share)
-                if req.request_span is not None:
-                    # One decode span per harvested chunk, covering the
-                    # interval a streaming client actually waited for it;
-                    # interference absorbed since the last harvest rides it
-                    # as the victim-side annotation (culprit = the tick's
-                    # biggest prefill).
-                    prev = req.t_last_emit or req.t_admitted or req.t_submit
-                    dur = max(0.0, t_now - prev) if prev else 0.0
-                    attrs = {"req": req.req_id, "tokens": len(fresh),
-                             "first": first_chunk}
-                    if req.interference_pending:
-                        cid, ctok, _ = max(req.interference_pending,
-                                           key=lambda e: e[2])
-                        attrs.update(
-                            interference_s=round(sum(
-                                s for *_, s in req.interference_pending
-                            ), 6),
-                            interference_culprit=cid,
-                            culprit_prefill_tokens=ctok,
-                        )
-                    w_now = time.time()
-                    self.tracer.start_span(
-                        "engine.decode", parent=req.request_span,
-                        t0=w_now - dur, **attrs,
-                    ).end(t_end=w_now)
-                req.interference_pending.clear()
-                req.t_last_emit = t_now
-            if req.stream is not None and fresh:
-                if req.logprobs is not None and lp is not None:
-                    # Streamed logprobs ride the chunk: the entries for the
-                    # tokens just appended (same OpenAI dict layout as the
-                    # non-streaming path, sliced to the request's N).
-                    n = req.logprobs
-                    k = len(fresh)
-                    req.stream.put((fresh, {
-                        "token_logprobs": req.lp_token[-k:],
-                        "top_ids": [r[:n] for r in req.lp_top_ids[-k:]],
-                        "top_logprobs": [r[:n] for r in req.lp_top[-k:]],
-                    }))
-                else:
-                    req.stream.put(fresh)
+                self._deliver(req, fresh, t_now, n_share)
             if req.finished:
                 self.metrics.completed.inc()
                 if req.t_submit:
@@ -3778,6 +3762,101 @@ class ContinuousEngine:
                         # (and LRU-evictable) for follow-up turns.
                         self._publish_generated_pages(req, slot)
                         self._free_slot_pages(slot)
+
+    def _deliver(self, req: Request, fresh: list[int], t_now: float,
+                 n_share: int) -> None:
+        """Account for ``fresh``, the tokens just appended to ``req.tokens``
+        (their stats to its logprob lists, if it asked for them: submit
+        refuses that on an engine without ``logprobs_k``), and put them on
+        the request's stream as one chunk. A request's first
+        chunk is the one token its prefill sampled (``_send_first_tokens``):
+        TTFT is observed with it, and every later chunk's harvest interval,
+        the first decode tick's included, is TPOT's. ``n_share``: the live
+        decode slots of the tick the interval belongs to. The decode-tick
+        device-time share (ISSUE 15): the slots of one tick ran ONE device
+        program together, so each live slot's interval is attributed
+        1/n_share to its request — the decode half of the per-request
+        device-time estimate (the prefill half is measured per dispatch in
+        _record_prefill). An estimate by construction (host wall, pipelined
+        ticks overlap dispatch); consistent ACROSS tenants, which is what
+        billing shares and convictions need."""
+        m = self.metrics
+        m.tokens_generated.inc(len(fresh))
+        if self.cache_mode == "paged":
+            self._win_gen_tokens += len(fresh)  # thrash-guard accounting
+        if req.fsm_start > 0:
+            # Every one of these tokens decoded under the FSM mask.
+            m.grammar_masked.inc(len(fresh))
+        first_chunk = req.t_first == 0.0
+        if first_chunk:
+            req.t_first = t_now
+            if req.t_submit:
+                ttft = t_now - req.t_submit
+                m.ttft.observe(ttft)
+                # Hit/miss split (ISSUE 8): the histogram pair that
+                # answers "does a prefix-cache hit actually buy
+                # TTFT" from /metrics alone.
+                (m.ttft_cache_hit if req.cache_hit_tokens > 0
+                 else m.ttft_cache_miss).observe(ttft)
+                # Class split (ISSUE 9): the disagg A/B grades
+                # interactive TTFT specifically.
+                cls_hist = m.ttft_by_class.get(req.slo_class)
+                if cls_hist is not None:
+                    cls_hist.observe(ttft)
+        elif req.t_last_emit:
+            # TPOT: this harvest interval amortized over the chunk's
+            # tokens, observed once per token.
+            m.decode_token.observe(
+                (t_now - req.t_last_emit) / len(fresh), n=len(fresh)
+            )
+        prev_emit = (req.t_last_emit or req.t_prefill_done
+                     or req.t_admitted or req.t_submit)
+        if prev_emit and n_share:
+            share = max(0.0, t_now - prev_emit) / n_share
+            req.device_time_est_s += share
+            if self.usage is not None:
+                self.usage.note_device(req.tenant, share)
+        if req.request_span is not None:
+            # One decode span per delivered chunk, covering the
+            # interval a streaming client actually waited for it;
+            # interference absorbed since the last harvest rides it
+            # as the victim-side annotation (culprit = the tick's
+            # biggest prefill).
+            prev = req.t_last_emit or req.t_admitted or req.t_submit
+            dur = max(0.0, t_now - prev) if prev else 0.0
+            attrs = {"req": req.req_id, "tokens": len(fresh),
+                     "first": first_chunk}
+            if req.interference_pending:
+                cid, ctok, _ = max(req.interference_pending,
+                                   key=lambda e: e[2])
+                attrs.update(
+                    interference_s=round(sum(
+                        s for *_, s in req.interference_pending
+                    ), 6),
+                    interference_culprit=cid,
+                    culprit_prefill_tokens=ctok,
+                )
+            w_now = time.time()
+            self.tracer.start_span(
+                "engine.decode", parent=req.request_span,
+                t0=w_now - dur, **attrs,
+            ).end(t_end=w_now)
+        req.interference_pending.clear()
+        req.t_last_emit = t_now
+        if req.stream is not None:
+            if req.logprobs is not None:
+                # Streamed logprobs ride the chunk: the entries for the
+                # tokens just appended (same OpenAI dict layout as the
+                # non-streaming path, sliced to the request's N).
+                n = req.logprobs
+                k = len(fresh)
+                req.stream.put((fresh, {
+                    "token_logprobs": req.lp_token[-k:],
+                    "top_ids": [r[:n] for r in req.lp_top_ids[-k:]],
+                    "top_logprobs": [r[:n] for r in req.lp_top[-k:]],
+                }))
+            else:
+                req.stream.put(fresh)
 
     def freeze_spec_threshold(self) -> None:
         """Pin the speculation threshold to its current value. REQUIRED for
@@ -4164,23 +4243,33 @@ class ContinuousEngine:
         every in-progress chunked prefill, decode one chunk (speculatively
         when armed and predicted to win — see ``_use_spec_tick``).
 
+        A request's first chunk is ONE token: what this step's prefills
+        sampled is fetched right behind the decode dispatch, in front of
+        the tick's own fetch, and sent (``_send_first_tokens``), so a first
+        token waits for the prefills of its tick and not for a decode
+        program besides. The tick's harvest delivers the rest of the row
+        (``decode_chunk - 1`` tokens of a plain tick) as the second chunk.
+
         ``pipeline_ticks``: the tick dispatched here is NOT fetched here —
         it is fetched (and harvested) on the NEXT step, after that step has
         already dispatched its own tick. The host's dispatch+fetch round
         trips overlap with device compute; admission and harvest lag one
-        tick; a finished request's slot decodes one dead chunk before being
-        freed (masked out by the harvest snapshot). Token streams are
+        tick (the first tokens do not: they leave with the step that
+        prefilled); a finished request's slot decodes one dead chunk before
+        being freed (masked out by the harvest snapshot). Token streams are
         identical to serial ticks — per-slot RNG derives from the request
         seed, never from tick alignment.
 
         An armed tracer gets one ``engine.tick`` span per call (tick number,
-        slot occupancy, queue depth, prefill seconds: the scheduler cadence)
+        slot occupancy, queue depth, prefill seconds, ``first_tokens`` sent
+        ahead of the decode fetch: the scheduler cadence)
         and, as its children, what the engine thread did in it:
         ``engine.tick.schedule``
         (deadlines, admission, page top-up), ``.prefill`` (this tick's
         prefill chunks), ``.dispatch`` (the decode program's enqueue),
-        ``.fetch`` (the tick's one ``device_get``), ``.harvest``
-        (bookkeeping and stream writes) and ``.spill``. They are the
+        ``.fetch`` (a ``device_get``: this tick's first tokens, then the
+        tick's tokens), ``.harvest`` (bookkeeping and stream writes, after
+        each fetch) and ``.spill``. They are the
         shortest host spans over a device idle gap, so a trace reducer
         labels the gap with what this thread was doing."""
         self.tick_count += 1
@@ -4326,6 +4415,11 @@ class ContinuousEngine:
                 rec = self._spec_dispatch(alive, sampled)
             else:
                 rec = self._plain_dispatch(active, alive, sampled)
+        # Behind THIS tick's dispatch, in front of any fetch of a tick's
+        # tokens (pipelined: the lagged one's too): the device has the
+        # decode program queued while the host reads what the prefills
+        # sampled.
+        self._send_first_tokens()
         if probe and rec is not None:
             self._probe_ticks_left -= 1
             self._probe_timing = True
@@ -4456,6 +4550,9 @@ class ContinuousEngine:
             ),
             "queue_depth": len(self._queue),
             "max_queue": self.max_queue,
+            # Requests whose first token left with the tick that prefilled
+            # them (every admitted one, bar a first token that is eos).
+            "first_tokens_early_total": self.first_tokens_early,
             "decode_chunk": self.decode_chunk,
             "max_context": self.smax,
             "token_budget": self.token_budget,
@@ -4972,7 +5069,9 @@ class ThreadedEngine:
         tenant: str | None = None,
     ):
         """Submit one request and return an iterator of per-chunk token-id
-        lists as they are decoded (SSE streaming). The submit happens
+        lists as they are decoded (SSE streaming): the first chunk is the
+        one token the prefill sampled, sent by the tick that ran it, every
+        later one what a decode tick's harvest appended. The submit happens
         EAGERLY — ``QueueFullError`` raises here, while the HTTP layer can
         still answer 429; once the SSE headers are out there is no status
         left to send (ADVICE r2). A ``deadline_s`` expiry simply ends the

@@ -229,6 +229,7 @@ _ROWS: tuple = (
     ("ditl_serving_decode_chunk", "gauge", "", "decode tokens per scheduler tick"),
     ("ditl_serving_decode_token_seconds", "histogram", "", "per-token decode latency (harvest interval / chunk tokens)"),
     ("ditl_serving_draining", "gauge", "", "1 while the server is draining (SIGTERM / rolling restart)"),
+    ("ditl_serving_first_tokens_early_total", "gauge", "", "requests whose first token was sent by the tick that ran their prefill, ahead of that tick's decode fetch (lifetime count from /v1/stats)"),
     ("ditl_serving_grammar_masked_tokens_total", "counter", "", "generated tokens decoded under an FSM grammar mask"),
     ("ditl_serving_guided_fsm_capacity", "gauge", "", "grammar FSM table rows available"),
     ("ditl_serving_guided_fsm_rows_used", "gauge", "", "grammar FSM table rows in use"),
@@ -289,7 +290,7 @@ _ROWS: tuple = (
     ("ditl_serving_request_ttft_cache_hit_seconds", "histogram", "", "TTFT of requests whose prompt hit the prefix cache (>= 1 reused token)"),
     ("ditl_serving_request_ttft_cache_miss_seconds", "histogram", "", "TTFT of requests whose prompt missed the prefix cache entirely"),
     ("ditl_serving_request_ttft_interactive_seconds", "histogram", "", "TTFT of interactive-class requests"),
-    ("ditl_serving_request_ttft_seconds", "histogram", "", "submit -> first generated token harvested"),
+    ("ditl_serving_request_ttft_seconds", "histogram", "", "submit -> first generated token sent (by the tick that ran the prefill)"),
     ("ditl_serving_requests_admitted_total", "counter", "", "requests admitted into a slot"),
     ("ditl_serving_requests_completed_total", "counter", "", "requests finished"),
     ("ditl_serving_requests_total", "counter", "", "requests accepted by submit"),

@@ -154,7 +154,8 @@ class ServingMetrics:
         )
         self.ttft = r.histogram(
             f"{PREFIX}_request_ttft_seconds",
-            "submit -> first generated token harvested", LATENCY_BUCKETS_S,
+            "submit -> first generated token sent (by the tick that ran the "
+            "prefill)", LATENCY_BUCKETS_S,
         )
         self.decode_token = r.histogram(
             f"{PREFIX}_decode_token_seconds",

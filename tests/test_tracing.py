@@ -455,7 +455,11 @@ def test_engine_lifecycle_spans_nest_under_one_request(tiny, tmp_path):
             assert s["trace"] == req["trace"], name
     assert by_name["engine.prefill"][0]["kind"] == "prompt"
     assert by_name["engine.prefill"][0]["tokens"] == 20
-    assert by_name["engine.decode"][0]["first"] is True
+    # The first chunk is the one token the prefill sampled, sent by the tick
+    # that ran it; the tick's harvest delivers the rest of its row.
+    first, second = by_name["engine.decode"][:2]
+    assert (first["first"], first["tokens"]) == (True, 1)
+    assert (second["first"], second["tokens"]) == (False, 3)
     assert "queue_wait_s" in by_name["engine.queue"][0]
     # Tick spans mark the scheduler cadence on the same track, and are the
     # only records of that name (no second, instant kind).
@@ -463,7 +467,8 @@ def test_engine_lifecycle_spans_nest_under_one_request(tiny, tmp_path):
              if r.get("name") == "engine.tick"]
     assert ticks and {r["event"] for r in ticks} == {"trace.span"}
     assert all({"tick", "slots_busy", "prefilling", "queue_depth",
-                "prefill_s"} <= r.keys() for r in ticks)
+                "prefill_s", "first_tokens"} <= r.keys() for r in ticks)
+    assert sum(r["first_tokens"] for r in ticks) == 1
     journal.close()
 
 
