@@ -171,6 +171,46 @@ class ModelConfig:
     # (Mixtral does; OLMoE's ``norm_topk_prob: false`` keeps them as they are).
     norm_topk_prob: bool = True
     router_aux_coef: float = 0.01  # Switch-style load-balancing loss weight
+    # LongCat-Flash-style expert layer (models/moe.py ``_shared_moe_block``).
+    # The router has ``num_experts + zero_expert_num`` outputs: the routed
+    # experts first, then ``zero_expert_num`` zero-compute experts, each the
+    # identity (no weights). A per-expert selection bias joins the softmax
+    # probabilities for the CHOICE only (``router_bias``); the chosen
+    # probabilities, as they are, times ``routed_scaling_factor`` weigh the
+    # outputs.
+    zero_expert_num: int = 0
+    routed_scaling_factor: float = 1.0
+    router_bias: bool = False
+    # The width of one routed expert where it differs from the dense FFN's
+    # ``intermediate_size`` (a double layer has both); 0 = the same.
+    expert_ffn_hidden_size: int = 0
+    # The experts held HERE: ``experts_held_count`` routed experts from
+    # index ``experts_held_first`` on (0 = all of them). One chip's share of
+    # an expert-parallel deployment: the router keeps its full width, held
+    # experts are computed, the others' part of the sum is left out (it is
+    # another chip's), zero-compute experts are computed everywhere.
+    experts_held_first: int = 0
+    experts_held_count: int = 0
+    # Multi-head latent attention (DeepSeek-V2 / LongCat-Flash): active when
+    # ``kv_lora_rank > 0``. Queries go through a ``q_lora_rank`` bottleneck
+    # with its own norm; keys and values are up-projected from ONE latent
+    # vector a token (``kv_lora_rank`` wide, normed) plus a rotary key of
+    # ``qk_rope_head_dim`` shared by all heads: what the cache stores. Each
+    # head's query/key is ``qk_nope_head_dim + qk_rope_head_dim`` wide, its
+    # value ``v_head_dim``. ``mla_scale_*``: the normed latents times
+    # sqrt(hidden_size / rank). Rotary pairs are neighbouring elements (2i,
+    # 2i+1), not the two halves. It is implemented inside LongCat-Flash's
+    # shortcut-connected double layer only (models/mla.py: two attention
+    # sublayers and two dense FFNs a layer, and one expert block that reads
+    # the first post-attention norm and joins at the layer's end), so
+    # ``kv_lora_rank > 0`` also selects that block (``double_layer`` below).
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    mla_scale_q_lora: bool = False
+    mla_scale_kv_lora: bool = False
     # LoRA; rank 0 disables.
     lora_rank: int = 0
     lora_alpha: float = 16.0
@@ -255,6 +295,13 @@ class ModelConfig:
     # microbatches per pipeline flush; 0 => one per stage.
     pipeline_microbatches: int = 0
 
+    @property
+    def double_layer(self) -> bool:
+        """Whether the layers are LongCat-Flash's double layer (models/mla.py):
+        the only block latent attention is implemented in, so derived and not
+        a field until a second latent-attention family has to be told apart."""
+        return self.kv_lora_rank > 0
+
     def __post_init__(self):
         # Reject-don't-drop: the MoE block has no fused gate|up layout, so
         # these flags would be silently ignored (an A/B would measure
@@ -269,6 +316,33 @@ class ModelConfig:
                 f"MLP path and do not apply to MoE models (num_experts="
                 f"{self.num_experts}); unset them rather than measuring a "
                 "silently unfused program"
+            )
+        if self.experts_held_first < 0 or self.experts_held_count < 0 or (
+            self.experts_held_first + self.experts_held_count > self.num_experts
+        ):
+            raise ValueError(
+                f"experts held {self.experts_held_first}+"
+                f"{self.experts_held_count} lie outside the {self.num_experts} "
+                "routed experts"
+            )
+        if (self.router_bias or self.routed_scaling_factor != 1.0) and not (
+            self.zero_expert_num or self.experts_held_count
+        ):
+            raise ValueError(
+                "router_bias and routed_scaling_factor belong to the expert "
+                "layer with zero-compute experts or a held share "
+                "(zero_expert_num, experts_held_count): the layer that holds "
+                "every expert would ignore them"
+            )
+        if self.kv_lora_rank > 0 and not (
+            self.num_experts > 0 and self.q_lora_rank > 0 and self.qk_nope_head_dim > 0
+            and self.qk_rope_head_dim > 0 and self.v_head_dim > 0
+        ):
+            raise ValueError(
+                "latent attention (kv_lora_rank > 0) is implemented inside "
+                "LongCat-Flash's double layer only: it needs an expert layer "
+                "and q_lora_rank, qk_nope_head_dim, qk_rope_head_dim and "
+                "v_head_dim set"
             )
         if self.mlp_bwd_impl not in ("xla", "pallas"):
             raise ValueError(

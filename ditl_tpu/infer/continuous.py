@@ -71,6 +71,7 @@ multi-query paged-attention kernel) and int8 KV.
 from __future__ import annotations
 
 import collections
+import math
 import time
 from dataclasses import dataclass, field
 from typing import Any
@@ -195,6 +196,19 @@ def _flush_tail_into_pools(pools, tk, tv, starts, pos, table, mesh=None,
         pos, mesh=mesh, rules=rules,
     )
     return out
+
+
+@jax.named_scope("kv_write")
+def _flush_latent_tail(pools, tc, starts, pos, table):
+    """``_flush_tail_into_pools`` for a latent page pool (models/mla.py):
+    ``tc`` (L, 2, B, T, Dl), one tail an attention sublayer, into ``pools
+    ["cp"]`` (2 L, n_pages, ps, Dl) through the same ``kv_flush`` kernel,
+    in place."""
+    from ditl_tpu.ops.kv_flush import latent_flush
+
+    cp = pools["cp"]
+    tail = tc.reshape(-1, *tc.shape[2:]).astype(cp.dtype)
+    return {"cp": latent_flush(cp, tail, table, starts, pos)}
 
 
 def derive_copy_seed(base: int, i: int) -> int:
@@ -576,6 +590,30 @@ class ContinuousEngine:
             raise ValueError(f"unknown cache_mode {cache_mode!r}")
         self.cache_mode = cache_mode
         self.page_size = page_size
+        # Latent attention (models/mla.py): the cache entry of a token is ONE
+        # latent vector an attention sublayer, kept in a latent page pool.
+        # Modes that cannot carry such a page yet refuse here, by name: none
+        # may run the K/V code on a latent pool.
+        self.latent = model_cfg.kv_lora_rank > 0
+        if self.latent:
+            refused = {
+                "the contiguous cache (cache_mode='contiguous')": cache_mode != "paged",
+                "speculative ticks (speculative=True)": speculative,
+                "int8 page pools (kv_cache_dtype='int8')":
+                    model_cfg.kv_cache_dtype == "int8",
+                "the host tier (host_tier_mb)": bool(host_tier_mb),
+                "a mesh": mesh is not None,
+                "LoRA adapters": model_cfg.lora_rank > 0,
+            }
+            for mode, asked in refused.items():
+                if asked:
+                    raise ValueError(
+                        f"latent attention (kv_lora_rank="
+                        f"{model_cfg.kv_lora_rank}) is served from a latent "
+                        f"page pool, which {mode} cannot carry yet: serve it "
+                        "with cache_mode='paged', plain ticks, bfloat16 pages, "
+                        "no host tier and no mesh"
+                    )
         if cache_mode == "paged":
             if model_cfg.kv_cache_dtype not in ("", "model", "int8"):
                 raise ValueError(
@@ -608,7 +646,16 @@ class ContinuousEngine:
                 1, page_size,
             )
 
+            if self.latent:
+                from ditl_tpu.models.mla import SUBLAYERS, latent_width
+
+                # (2 L, P, ps, Dl): one set of pages an attention sublayer
+                shape = (model_cfg.num_layers * SUBLAYERS, self.n_pages,
+                         page_size, latent_width(model_cfg))
+
             def fresh_pools():
+                if self.latent:
+                    return {"cp": jnp.zeros(shape, dt)}
                 if quantized:
                     return {
                         "kp": jnp.zeros(shape, jnp.int8),
@@ -689,7 +736,9 @@ class ContinuousEngine:
                 model_cfg.num_layers * model_cfg.num_kv_heads
                 * page_size * model_cfg.head_dim
             )
-            if quantized:
+            if self.latent:
+                self.page_bytes = math.prod(shape) // self.n_pages * dt.itemsize
+            elif quantized:
                 scale_vals = (
                     model_cfg.num_layers * model_cfg.num_kv_heads * page_size
                 )
@@ -859,8 +908,16 @@ class ContinuousEngine:
         # device for that same fetch, so counting costs no device sync of its
         # own. Host state; /v1/stats derives its ``moe_*`` fields from it.
         self.moe = model_cfg.num_experts > 0 and cache_mode == "paged"
+        # (L, count_width): one column an expert whose weights live here; a
+        # share of a wider layer (models/moe.py) adds the zero-compute
+        # experts' and the absent experts' totals as two more.
+        from ditl_tpu.models.moe import count_width
+
         self.moe_assignments = np.zeros(
-            (model_cfg.num_layers, model_cfg.num_experts), np.int64)
+            (model_cfg.num_layers, count_width(model_cfg)), np.int64)
+        # Latent attention: the live rows' context lengths summed over the
+        # decode steps, what the latent kernel had to read (per sublayer).
+        self.decode_ctx_tokens = 0
         self.moe_touched_sum = 0  # sum over decode steps and layers
         self.moe_decode_steps = 0
         self._moe_pending: list = []  # prefills' (L, E) counts, on the device
@@ -1642,10 +1699,33 @@ class ContinuousEngine:
             real = jnp.arange(s_bucket, dtype=jnp.int32)[None, :] < s_len
             return {"token_mask": real, "with_moe_counts": True}
 
-        def paged_prefill(params, pools, table_row, ids, offset, s_len, temp,
-                          top_p, rng, write_pids, aid, *fsm):
-            kp, vp = pools["kp"], pools["vp"]
-            L, _, K, _, D = kp.shape
+        # One pair of functions a pool kind, chosen once: ``gather`` makes the
+        # transient row the forward pass attends over (the context pages'
+        # entries, then room for the chunk's), ``write`` puts the chunk's
+        # entries into its pages, in place.
+        def latent_gather(pools, table_row):
+            # (2 L, P, ps, Dl) -> (L, 2, 1, ctx * ps + bucket, Dl)
+            cp = pools["cp"]
+            with jax.named_scope("kv_gather"):
+                ctx = cp[:, table_row[:maxp]].reshape(
+                    cp.shape[0], 1, maxp * ps, cp.shape[-1])
+            row = jnp.concatenate(
+                [ctx, jnp.zeros((cp.shape[0], 1, s_bucket, cp.shape[-1]), ctx.dtype)],
+                axis=2)
+            return {"c": row.reshape(cfg.num_layers, -1, *row.shape[1:])}
+
+        def latent_write(pools, row, offset, write_pids):
+            cp = pools["cp"]
+            c = row["c"].reshape(cp.shape[0], 1, buf, cp.shape[-1])
+            chunk = jax.lax.dynamic_slice_in_dim(c, offset, s_bucket, axis=2)
+            chunk = chunk.reshape(cp.shape[0], n_wp, ps, cp.shape[-1])
+            for j in range(n_wp):
+                cp = jax.lax.dynamic_update_slice(
+                    cp, chunk[:, j:j + 1], (0, write_pids[j], 0, 0))
+            return {"cp": cp}
+
+        def kv_gather(pools, table_row):
+            L, _, K, _, D = pools["kp"].shape
 
             def to_row(pool, scales=None):
                 # (L, ctx_pages, K, ps, D) [+ scales] -> (L, 1, ctx*ps, K, D)
@@ -1659,13 +1739,52 @@ class ContinuousEngine:
                 return g.reshape(L, 1, maxp * ps, K, D)
 
             with jax.named_scope("kv_gather"):
-                ctx_k = to_row(kp, pools.get("ks"))
-                ctx_v = to_row(vp, pools.get("vs"))
+                ctx_k = to_row(pools["kp"], pools.get("ks"))
+                ctx_v = to_row(pools["vp"], pools.get("vs"))
             zeros = jnp.zeros((L, 1, s_bucket, K, D), ctx_k.dtype)
-            row = {
+            return {
                 "k": jnp.concatenate([ctx_k, zeros], axis=2),
                 "v": jnp.concatenate([ctx_v, zeros], axis=2),
             }
+
+        def kv_write(pools, row, offset, write_pids):
+            L, _, K, _, D = pools["kp"].shape
+
+            def to_pages(r):  # (L, 1, s_bucket, K, D) -> (L, n_wp, K, ps, D)
+                chunk = jax.lax.dynamic_slice_in_dim(r, offset, s_bucket, axis=2)
+                return jnp.swapaxes(chunk.reshape(L, n_wp, ps, K, D), 2, 3)
+
+            chunk_k, chunk_v = to_pages(row["k"]), to_pages(row["v"])
+            out = dict(pools)
+            if quantized:
+                for name, sname, chunk in (("kp", "ks", chunk_k),
+                                           ("vp", "vs", chunk_v)):
+                    vals, sc = _quantize_pages(chunk)
+                    pool, spool = out[name], out[sname]
+                    for j in range(n_wp):
+                        pool = jax.lax.dynamic_update_slice(
+                            pool, vals[:, j:j + 1], (0, write_pids[j], 0, 0, 0)
+                        )
+                        spool = jax.lax.dynamic_update_slice(
+                            spool, sc[:, j:j + 1], (0, write_pids[j], 0, 0, 0)
+                        )
+                    out[name], out[sname] = pool, spool
+            else:
+                for name, chunk in (("kp", chunk_k), ("vp", chunk_v)):
+                    pool = out[name]
+                    for j in range(n_wp):
+                        pool = jax.lax.dynamic_update_slice(
+                            pool, chunk[:, j:j + 1], (0, write_pids[j], 0, 0, 0)
+                        )
+                    out[name] = pool
+            return out
+
+        gather, write = (latent_gather, latent_write) if self.latent else (
+            kv_gather, kv_write)
+
+        def paged_prefill(params, pools, table_row, ids, offset, s_len, temp,
+                          top_p, rng, write_pids, aid, *fsm):
+            row = gather(pools, table_row)
             q_pos = offset + jnp.arange(s_bucket, dtype=jnp.int32)
             if maxp == 0:
                 # No context pages (offset 0): pure causal self-attention
@@ -1686,34 +1805,8 @@ class ContinuousEngine:
                     mesh=self.mesh, rules=self.rules,
                     adapter_ids=aid if self.multi_lora else None, **moe_kw(s_len),
                 )
-            def to_pages(r):  # (L, 1, s_bucket, K, D) -> (L, n_wp, K, ps, D)
-                chunk = jax.lax.dynamic_slice_in_dim(r, offset, s_bucket, axis=2)
-                return jnp.swapaxes(chunk.reshape(L, n_wp, ps, K, D), 2, 3)
-
             with jax.named_scope("kv_write"):
-                chunk_k, chunk_v = to_pages(row["k"]), to_pages(row["v"])
-                out = dict(pools)
-                if quantized:
-                    for name, sname, chunk in (("kp", "ks", chunk_k),
-                                               ("vp", "vs", chunk_v)):
-                        vals, sc = _quantize_pages(chunk)
-                        pool, spool = out[name], out[sname]
-                        for j in range(n_wp):
-                            pool = jax.lax.dynamic_update_slice(
-                                pool, vals[:, j:j + 1], (0, write_pids[j], 0, 0, 0)
-                            )
-                            spool = jax.lax.dynamic_update_slice(
-                                spool, sc[:, j:j + 1], (0, write_pids[j], 0, 0, 0)
-                            )
-                        out[name], out[sname] = pool, spool
-                else:
-                    for name, chunk in (("kp", chunk_k), ("vp", chunk_v)):
-                        pool = out[name]
-                        for j in range(n_wp):
-                            pool = jax.lax.dynamic_update_slice(
-                                pool, chunk[:, j:j + 1], (0, write_pids[j], 0, 0, 0)
-                            )
-                        out[name] = pool
+                out = write(pools, row, offset, write_pids)
             last = logits[0, s_len - 1]
             masked = _fsm_mask(fsm[0], fsm[1], last) if self.guided else last
             first = sample_logits(
@@ -1748,6 +1841,7 @@ class ContinuousEngine:
 
         guided = self.guided
         moe = self.moe
+        from ditl_tpu.models.moe import count_width, split_counts
 
         def paged_decode(params, pools, cur, pos, alive, temps, top_ps, keys,
                          table, limits, hist, adapters, *extra):
@@ -1759,14 +1853,20 @@ class ContinuousEngine:
             # nothing for them regardless of table-row state — no reliance
             # on freed slots having zeroed rows.
             starts = pos
-            tk0 = jnp.zeros((L, n_b, K, tail_len, D), dt)
-            tv0 = jnp.zeros((L, n_b, K, tail_len, D), dt)
+            if self.latent:
+                from ditl_tpu.models.mla import SUBLAYERS, latent_width
+
+                tails0 = {"tc": jnp.zeros(
+                    (L, SUBLAYERS, n_b, tail_len, latent_width(cfg)), dt)}
+            else:
+                tails0 = {"tk": jnp.zeros((L, n_b, K, tail_len, D), dt),
+                          "tv": jnp.zeros((L, n_b, K, tail_len, D), dt)}
             # Read-only during the scan, and whole: llama.forward keeps them
             # out of its layer loop and offsets each layer's page table.
             cache_const = dict(pools)
 
             def body(carry, t):
-                tk, tv, cur, pos, done, keys, hist, fst, lp, moe_acc = carry
+                tails, cur, pos, done, keys, hist, fst, lp, moe_acc = carry
                 split = jax.vmap(lambda k: jax.random.split(k, 2))(keys)
                 keys, subs = split[:, 0], split[:, 1]
                 done = done | (pos >= limits)
@@ -1781,7 +1881,7 @@ class ContinuousEngine:
                     cur[:, None],
                     cfg,
                     positions=pos[:, None],
-                    cache={**cache_const, "tk": tk, "tv": tv},
+                    cache={**cache_const, **tails},
                     paged=paged_meta,
                     mesh=self.mesh,
                     rules=self.rules,
@@ -1791,10 +1891,13 @@ class ContinuousEngine:
                 )
                 if moe:
                     # the live rows' assignments; the experts they touched
-                    counts, touched = moe_acc
+                    # (of those whose weights live here)
+                    counts, touched, *ctx = moe_acc
+                    held = split_counts(moe_counts[0], cfg)[0]
                     moe_acc = (counts + moe_counts[0],
-                               touched + (moe_counts[0] > 0).sum())
-                tk, tv = tails["tk"], tails["tv"]
+                               touched + (held > 0).sum(),
+                               # latent attention: what this step's kernel read
+                               *(c + lengths.sum() for c in ctx))
                 step_logits = logits[:, 0]
                 nxt = sample_logits(
                     _fsm_mask(ftab, fst, step_logits) if guided else step_logits,
@@ -1819,20 +1922,26 @@ class ContinuousEngine:
 
                     grow = (~done).astype(jnp.int32)
                     hist = _emit_rows(hist, cur[:, None], pos, grow)
-                return (tk, tv, cur, pos, done, keys, hist, fst, lp, moe_acc), ys
+                return (tails, cur, pos, done, keys, hist, fst, lp, moe_acc), ys
 
             fst0 = fstates if guided else jnp.zeros((), jnp.int32)
-            moe0 = ((jnp.zeros((L, cfg.num_experts), jnp.int32),
-                     jnp.zeros((), jnp.int32)) if moe else ())
-            (tk, tv, cur, pos, done, keys, hist, fst, lp, moe_acc), ys = jax.lax.scan(
-                body, (tk0, tv0, cur, pos, ~alive, keys, hist, fst0,
+            moe0 = ((jnp.zeros((L, count_width(cfg)), jnp.int32),
+                     jnp.zeros((), jnp.int32),
+                     *((jnp.zeros((), jnp.int32),) if self.latent else ()))
+                    if moe else ())
+            (tails, cur, pos, done, keys, hist, fst, lp, moe_acc), ys = jax.lax.scan(
+                body, (tails0, cur, pos, ~alive, keys, hist, fst0,
                        tuple(lp0), moe0),
                 jnp.arange(chunk, dtype=jnp.int32),
             )
 
-            out = _flush_tail_into_pools(
-                pools, tk, tv, starts, pos, table, self.mesh, self.rules
-            )
+            if self.latent:
+                out = _flush_latent_tail(pools, tails["tc"], starts, pos, table)
+            else:
+                out = _flush_tail_into_pools(
+                    pools, tails["tk"], tails["tv"], starts, pos, table,
+                    self.mesh, self.rules
+                )
             fs = (fst,) if guided else ()
             if n_lp:
                 toks, c, i, t = ys
@@ -2718,6 +2827,9 @@ class ContinuousEngine:
         (``ThreadedEngine.call``)."""
         if self.cache_mode != "paged":
             raise BadRequestError("KV handoff requires cache_mode='paged'")
+        if self.latent:
+            raise BadRequestError(
+                "the disaggregated KV handoff cannot carry latent pages yet")
         if adapter_id:
             raise BadRequestError("KV handoff serves the base adapter only")
         ps = self.page_size
@@ -2774,6 +2886,9 @@ class ContinuousEngine:
 
         if self.cache_mode != "paged":
             raise BadRequestError("KV handoff requires cache_mode='paged'")
+        if self.latent:
+            raise BadRequestError(
+                "the disaggregated KV handoff cannot carry latent pages yet")
         meta, pages = deserialize_pages(blob)
         want = {
             "page_size": self.page_size,
@@ -4159,8 +4274,8 @@ class ContinuousEngine:
             )
         moe_dev = ()
         if self.moe:  # paged: the tick's (L, E) counts and touched sum
-            *res, counts, touched = res
-            moe_dev = (counts, touched)
+            n_moe = 3 if self.latent else 2  # and the context tokens read
+            res, moe_dev = res[:-n_moe], tuple(res[-n_moe:])
         if self.guided:
             (self.cache, self.cur, self.pos, self.keys, self.hist,
              self.fstates, *res_rest) = res
@@ -4192,28 +4307,41 @@ class ContinuousEngine:
         toks = np.asarray(toks)
         self._phase("engine.tick.harvest")
         if moe_np:
-            self._note_moe(*moe_np, pending_np)
+            self._note_moe(moe_np, pending_np)
         if self.speculative and (not self.pipeline_ticks or self._probe_timing):
             # See _spec_finish: pipelined intervals are not device cost,
             # but serial probe-tick intervals are.
             self._record_tick_time(key, (_time.perf_counter() - t0) * 1e3)
         self._harvest(toks, lp=lp, snapshot=snapshot)
 
-    def _note_moe(self, counts, touched, prefill_counts) -> None:
+    def _note_moe(self, tick, prefill_counts) -> None:
         """Add one decode tick's expert counts (and the counts of the
         prefills that ran before it) to the host's totals; an armed tracer's
-        ``engine.tick`` span carries the tick's own."""
+        ``engine.tick`` span carries the tick's own. With latent attention
+        the tick also brings the context tokens its steps read."""
+        from ditl_tpu.models.moe import split_counts
+
+        counts, touched, *ctx = tick  # the decode program's moe_acc
         counts = np.asarray(counts, np.int64)
         self.moe_assignments += counts
         for c in prefill_counts:
             self.moe_assignments += np.asarray(c, np.int64)
         self.moe_touched_sum += int(touched)
         self.moe_decode_steps += self.decode_chunk
+        extra = {}
+        if ctx:
+            self.decode_ctx_tokens += int(ctx[0])
+            extra["decode_ctx_tokens"] = int(ctx[0])
+        held, zero, absent = split_counts(counts, self.cfg)
+        if held.shape != counts.shape:  # a share of a wider expert layer
+            extra.update(moe_assign_held=int(held.sum()),
+                         moe_assign_zero=int(zero.sum()),
+                         moe_assign_absent=int(absent.sum()))
         if self._tick_span is not None:
             self._tick_span.annotate(
                 moe_assignments=int(counts.sum()), moe_touched=int(touched),
                 moe_steps=self.decode_chunk,
-                moe_load_max_over_mean=_max_over_mean(counts),
+                moe_load_max_over_mean=_max_over_mean(held), **extra,
             )
 
     def _finish_tick(self, rec: tuple) -> None:
@@ -4608,8 +4736,18 @@ class ContinuousEngine:
             # Live rows of the paged decode ticks and real tokens of the
             # paged prefills only; the touched mean is per decode step and
             # layer (a step with no live row touches none).
+            from ditl_tpu.models.moe import split_counts
+
+            held, zero, absent = split_counts(self.moe_assignments, self.cfg)
             out["moe_assignments_total"] = int(self.moe_assignments.sum())
-            out["moe_load_max_over_mean"] = _max_over_mean(self.moe_assignments)
+            out["moe_load_max_over_mean"] = _max_over_mean(held)
+            if held.shape != self.moe_assignments.shape:
+                # a share of a wider expert layer: by the kind of the expert
+                out["moe_assign_held"] = int(held.sum())
+                out["moe_assign_zero"] = int(zero.sum())
+                out["moe_assign_absent"] = int(absent.sum())
+            if self.latent:
+                out["decode_ctx_tokens"] = self.decode_ctx_tokens
             out["moe_experts_touched_mean"] = round(
                 self.moe_touched_sum
                 / max(1, self.moe_decode_steps * self.cfg.num_layers), 4)

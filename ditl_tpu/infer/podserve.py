@@ -416,6 +416,10 @@ class PodContinuousDriver:
     how the protocol is unit-tested."""
 
     def __init__(self, engine, *, poll_s: float = 0.02):
+        if getattr(engine, "latent", False):
+            raise ValueError(
+                "pod serving cannot carry a latent page pool yet (latent "
+                "attention is served by one process on one chip)")
         self._engine = engine
         # Per-host wall-clock calibration would desync pod tick decisions.
         engine.freeze_spec_threshold()

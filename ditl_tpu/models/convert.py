@@ -1,4 +1,4 @@
-"""HuggingFace checkpoint import: torch Llama/Qwen2/Mixtral/OLMoE weights -> param pytree.
+"""HuggingFace checkpoint import: torch Llama/Qwen2/Mixtral/OLMoE/LongCat-Flash weights -> param pytree.
 
 The reference never loads weights at all — its Llama-3.1-70B lives behind an
 HTTP API (ref ``src/distributed_inference.py:34-41``, ``MODEL_NAME`` in
@@ -120,6 +120,84 @@ def _stack(sd: Mapping[str, Any], template: str, n_layers: int, transpose: bool)
     return np.stack(mats, axis=0)
 
 
+# LongCat-Flash's double layer (models/mla.py): our leaf -> HF's module path
+# under ``model.layers.{i}`` (``modeling_longcat_flash.py`` as recalled: two
+# attention modules, two dense MLPs and four norms a layer in ModuleLists
+# indexed by the half ``{j}``, one expert block ``mlp`` whose router holds a
+# ``classifier`` and the selection bias as a buffer). Matrices transpose.
+_DOUBLE_ATTN = {
+    "w_qa": "self_attn.{j}.q_a_proj.weight", "q_norm": "self_attn.{j}.q_a_layernorm.weight",
+    "w_qb": "self_attn.{j}.q_b_proj.weight", "w_kva": "self_attn.{j}.kv_a_proj_with_mqa.weight",
+    "kv_norm": "self_attn.{j}.kv_a_layernorm.weight", "w_kvb": "self_attn.{j}.kv_b_proj.weight",
+    "wo": "self_attn.{j}.o_proj.weight",
+}
+_DOUBLE_MLP = {"w_gate": "mlps.{j}.gate_proj.weight", "w_up": "mlps.{j}.up_proj.weight",
+               "w_down": "mlps.{j}.down_proj.weight"}
+_DOUBLE_NORMS = {"attn_norm": "input_layernorm.{j}.weight",
+                 "mlp_norm": "post_attention_layernorm.{j}.weight"}
+_DOUBLE_EXPERT = {"w_gate": "gate_proj", "w_up": "up_proj", "w_down": "down_proj"}
+
+
+def _double_layer_items(cfg: ModelConfig):
+    """(path in our ``layers`` tree, HF template with ``{i}``, transpose?) of
+    every leaf of the double layer; an expert leaf's template keeps ``{e}``
+    for the HELD experts' published indices."""
+    for j in range(2):
+        for tree, table in (("attn", _DOUBLE_ATTN), ("mlp", _DOUBLE_MLP)):
+            for ours, theirs in table.items():
+                yield ((tree, f"sub{j}", ours), "model.layers.{i}." + theirs.format(j=j),
+                       not ours.endswith("norm"))
+    yield ("moe", "router"), "model.layers.{i}.mlp.router.classifier.weight", True
+    if cfg.router_bias:
+        yield (("moe", "router_bias"), "model.layers.{i}.mlp.router.e_score_correction_bias",
+               False)
+    for ours, theirs in _DOUBLE_EXPERT.items():
+        yield ("moe", ours), "model.layers.{i}.mlp.experts.{e}." + theirs + ".weight", True
+
+
+def _double_layer_from_state_dict(sd, cfg: ModelConfig, cast) -> dict[str, Any]:
+    from ditl_tpu.models.moe import held_experts
+
+    L = cfg.num_layers
+    first, count = held_experts(cfg)
+    layers: dict[str, Any] = {
+        ours: {"scale": cast(np.stack([
+            _stack(sd, "model.layers.{i}." + theirs.format(j=j), L, False)
+            for j in range(2)], axis=1))}
+        for ours, theirs in _DOUBLE_NORMS.items()
+    }
+    for path, template, transpose in _double_layer_items(cfg):
+        if "{e}" in template:
+            leaf = np.stack([_stack(sd, template.replace("{e}", str(first + e)), L, transpose)
+                             for e in range(count)], axis=1)
+        else:
+            leaf = _stack(sd, template, L, transpose)
+        node = layers
+        for key in path[:-1]:
+            node = node.setdefault(key, {})
+        node[path[-1]] = leaf.astype(np.float32) if path[-1] == "router_bias" else cast(leaf)
+    return layers
+
+
+def _double_layer_state_dict(layers, cfg: ModelConfig, host) -> dict[str, np.ndarray]:
+    from ditl_tpu.models.moe import held_experts
+
+    first, count = held_experts(cfg)
+    sd: dict[str, np.ndarray] = {}
+    for i in range(cfg.num_layers):
+        for ours, theirs in _DOUBLE_NORMS.items():
+            for j in range(2):
+                sd[f"model.layers.{i}." + theirs.format(j=j)] = host(layers[ours]["scale"][i, j])
+        for path, template, transpose in _double_layer_items(cfg):
+            leaf = layers
+            for key in path:
+                leaf = leaf[key]
+            for e in range(count if "{e}" in template else 1):
+                w = host(leaf[i, e] if "{e}" in template else leaf[i])
+                sd[template.format(i=i, e=first + e)] = w.T if transpose else w
+    return sd
+
+
 def params_from_state_dict(
     sd: Mapping[str, Any], cfg: ModelConfig, dtype: str | None = None
 ) -> dict[str, Any]:
@@ -135,6 +213,13 @@ def params_from_state_dict(
     def cast(x: np.ndarray) -> np.ndarray:
         return x.astype(pd)
 
+    if cfg.double_layer:  # LongCat-Flash; a share loads only the experts it holds
+        return {
+            "embed": {"embedding": cast(_np(sd["model.embed_tokens.weight"]))},
+            "layers": _double_layer_from_state_dict(sd, cfg, cast),
+            "final_norm": {"scale": cast(_np(sd["model.norm.weight"]))},
+            "lm_head": {"kernel": cast(_np(sd["lm_head.weight"]).T)},
+        }
     params: dict[str, Any] = {
         "embed": {"embedding": cast(_np(sd["model.embed_tokens.weight"]))},
         "layers": {
@@ -254,6 +339,10 @@ def state_dict_from_params(params: Mapping[str, Any], cfg: ModelConfig) -> dict[
         "model.embed_tokens.weight": host(params["embed"]["embedding"]),
         "model.norm.weight": host(params["final_norm"]["scale"]),
     }
+    if cfg.double_layer:
+        sd.update(_double_layer_state_dict(layers, cfg, host))
+        sd["lm_head.weight"] = host(params["lm_head"]["kernel"]).T
+        return sd
     for i in range(L):
         p = f"model.layers.{i}"
         sd[f"{p}.input_layernorm.weight"] = host(layers["attn_norm"]["scale"][i])

@@ -62,6 +62,23 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
     def dense(key, shape, fan_in):
         return (jax.random.normal(key, shape) * (1.0 / math.sqrt(fan_in))).astype(pd)
 
+    if cfg.double_layer:
+        # LongCat-Flash (models/mla.py): a tree of its own under "layers",
+        # its leaves drawn in ``param_dtype`` so that 9.6 GiB of bfloat16
+        # weights are built on a 16 GB chip; the presets that came before
+        # keep the eager float32 draws below, and their numbers.
+        from ditl_tpu.models.mla import init_double_layer_params
+
+        if cfg.tie_embeddings or cfg.lora_rank > 0:
+            raise ValueError("the double layer has an untied head and no LoRA")
+        return {
+            "embed": {"embedding": (
+                jax.random.normal(next(keys), (cfg.vocab_size, d)) * 0.02).astype(pd)},
+            "layers": init_double_layer_params(next(keys), cfg),
+            "final_norm": {"scale": jnp.ones((d,), pd)},
+            "lm_head": {"kernel": dense(next(keys), (d, cfg.vocab_size), d)},
+        }
+
     params: Params = {
         "embed": {
             "embedding": (jax.random.normal(next(keys), (cfg.vocab_size, d)) * 0.02).astype(pd)
@@ -129,6 +146,15 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
 
 def param_logical_axes(cfg: ModelConfig) -> Params:
     """Same structure as ``init_params``, leaves are logical-axis tuples."""
+    if cfg.double_layer:
+        from ditl_tpu.models.mla import double_layer_logical_axes
+
+        return {
+            "embed": {"embedding": ("vocab", "embed")},
+            "layers": double_layer_logical_axes(cfg),
+            "final_norm": {"scale": ("norm",)},
+            "lm_head": {"kernel": ("embed", "vocab")},
+        }
     axes: Params = {
         "embed": {"embedding": ("vocab", "embed")},
         "layers": {
@@ -301,6 +327,57 @@ def _apply_remat(layer_fn, cfg: ModelConfig):
 # ---------------------------------------------------------------------------
 # Forward
 # ---------------------------------------------------------------------------
+
+
+def dense_mlp(mlp: dict, h: jax.Array, *, cfg: ModelConfig, mesh, rules) -> jax.Array:
+    """The dense SwiGLU FFN on the normed input ``h`` (B, S, D), before the
+    residual: ``_decoder_layer``'s, and each of the two a double layer has
+    (models/mla.py). ``mlp``: ``w_gate`` and ``w_up``, or the fused ``w_gu``,
+    and ``w_down``, plain or quantized."""
+    from ditl_tpu.ops.quant import is_quantized_leaf, weight_einsum
+
+    cd = jnp.dtype(cfg.dtype)
+    use_custom_vjp = cfg.mlp_custom_vjp or cfg.mlp_bwd_impl == "pallas"
+    if use_custom_vjp and "w_gu" not in mlp:
+        # Reject-don't-drop: silently falling back to autodiff would
+        # make an A/B of the flag measure byte-identical programs.
+        raise ValueError(
+            "mlp_custom_vjp/mlp_bwd_impl='pallas' require "
+            "fused_gate_up=True (the hand-written backward targets the "
+            "fused w_gu layout)"
+        )
+    if "w_gu" in mlp and use_custom_vjp:
+        if is_quantized_leaf(mlp["w_gu"]) or is_quantized_leaf(mlp["w_down"]):
+            raise ValueError(
+                "mlp_custom_vjp/mlp_bwd_impl need plain float weights "
+                "(quantized serving never differentiates — leave it off)"
+            )
+        from ditl_tpu.ops.mlp import mlp_block
+
+        return mlp_block(
+            lambda t: _constrain(t, ("batch", "seq", "act_mlp"), mesh, rules),
+            h, mlp["w_gu"].astype(cd), mlp["w_down"].astype(cd),
+            bwd_impl=cfg.mlp_bwd_impl,
+            bwd_blocks=(cfg.mlp_bwd_block_n, cfg.mlp_bwd_block_f,
+                        cfg.mlp_bwd_block_d),
+            mesh=mesh, rules=rules,
+        )
+    if "w_gu" in mlp:
+        # fused_gate_up: one (D, 2F) GEMM replaces the gate/up
+        # pair — and one dgrad/wgrad pair replaces two in the
+        # backward.
+        gu = weight_einsum("bsd,df->bsf", h, mlp["w_gu"], compute_dtype=cd)
+        gate, up = jnp.split(gu, 2, axis=-1)
+    else:
+        gate = weight_einsum("bsd,df->bsf", h, mlp["w_gate"], compute_dtype=cd)
+        up = weight_einsum("bsd,df->bsf", h, mlp["w_up"], compute_dtype=cd)
+    inner = jax.nn.silu(gate) * up
+    inner = _constrain(inner, ("batch", "seq", "act_mlp"), mesh, rules)
+    # Named so remat policies CAN save it (w_down's wgrad
+    # operand); no shipped policy does — measured
+    # neutral-to-negative on v5e.
+    inner = checkpoint_name(inner, "mlp_inner")
+    return weight_einsum("bsf,fd->bsd", inner, mlp["w_down"], compute_dtype=cd)
 
 
 def _decoder_layer(
@@ -545,58 +622,7 @@ def _decoder_layer(
                 layer=layer_index if moe_stack else None,
             )
         else:
-            mlp = layer_params["mlp"]
-            use_custom_vjp = cfg.mlp_custom_vjp or cfg.mlp_bwd_impl == "pallas"
-            if use_custom_vjp and "w_gu" not in mlp:
-                # Reject-don't-drop: silently falling back to autodiff would
-                # make an A/B of the flag measure byte-identical programs.
-                raise ValueError(
-                    "mlp_custom_vjp/mlp_bwd_impl='pallas' require "
-                    "fused_gate_up=True (the hand-written backward targets the "
-                    "fused w_gu layout)"
-                )
-            if "w_gu" in mlp and use_custom_vjp:
-                if is_quantized_leaf(mlp["w_gu"]) or is_quantized_leaf(mlp["w_down"]):
-                    raise ValueError(
-                        "mlp_custom_vjp/mlp_bwd_impl need plain float weights "
-                        "(quantized serving never differentiates — leave it off)"
-                    )
-                from ditl_tpu.ops.mlp import mlp_block
-
-                mlp_out = mlp_block(
-                    lambda t: _constrain(t, ("batch", "seq", "act_mlp"),
-                                         mesh, rules),
-                    h, mlp["w_gu"].astype(cd), mlp["w_down"].astype(cd),
-                    bwd_impl=cfg.mlp_bwd_impl,
-                    bwd_blocks=(cfg.mlp_bwd_block_n, cfg.mlp_bwd_block_f,
-                                cfg.mlp_bwd_block_d),
-                    mesh=mesh, rules=rules,
-                )
-            else:
-                if "w_gu" in mlp:
-                    # fused_gate_up: one (D, 2F) GEMM replaces the gate/up
-                    # pair — and one dgrad/wgrad pair replaces two in the
-                    # backward.
-                    gu = weight_einsum(
-                        "bsd,df->bsf", h, mlp["w_gu"], compute_dtype=cd
-                    )
-                    gate, up = jnp.split(gu, 2, axis=-1)
-                else:
-                    gate = weight_einsum(
-                        "bsd,df->bsf", h, mlp["w_gate"], compute_dtype=cd
-                    )
-                    up = weight_einsum(
-                        "bsd,df->bsf", h, mlp["w_up"], compute_dtype=cd
-                    )
-                inner = jax.nn.silu(gate) * up
-                inner = _constrain(inner, ("batch", "seq", "act_mlp"), mesh, rules)
-                # Named so remat policies CAN save it (w_down's wgrad
-                # operand); no shipped policy does — measured
-                # neutral-to-negative on v5e.
-                inner = checkpoint_name(inner, "mlp_inner")
-                mlp_out = weight_einsum(
-                    "bsf,fd->bsd", inner, mlp["w_down"], compute_dtype=cd
-                )
+            mlp_out = dense_mlp(layer_params["mlp"], h, cfg=cfg, mesh=mesh, rules=rules)
         x = x + mlp_out
         x = _constrain(x, ("batch", "seq", "act_embed"), mesh, rules)
     out = (x, aux) if new_kv is None else (x, aux, new_kv)
@@ -687,28 +713,41 @@ def forward(
         x = table[input_ids]
         x = _constrain(x, ("batch", "seq", "act_embed"), mesh, rules)
 
+    block = _decoder_layer
+    if cfg.double_layer:
+        # LongCat-Flash: the same scans carry its double layer (models/mla.py)
+        from ditl_tpu.models.mla import double_layer as block
+
     if cache is not None:
         layers, moe_stack = params["layers"], None
         if "moe" in layers:
-            from ditl_tpu.models.moe import experts_in_place
+            from ditl_tpu.models.moe import experts_in_place, grouped_rows
 
-            if experts_in_place(layers["moe"], b * s * cfg.num_experts_per_tok, mesh):
+            if experts_in_place(layers["moe"], grouped_rows(cfg, b * s), mesh):
                 # the loop slices only the router; the kernel addresses the
                 # layer's experts inside the stack
-                moe_stack = {k: v for k, v in layers["moe"].items() if k != "router"}
-                layers = {**layers, "moe": {"router": layers["moe"]["router"]}}
+                in_loop = ("router", "router_bias")
+                moe_stack = {k: v for k, v in layers["moe"].items() if k not in in_loop}
+                layers = {**layers, "moe": {k: v for k, v in layers["moe"].items()
+                                            if k in in_loop}}
 
         pools = None
-        if "kp" in cache:
+        tails = ("tk", "tv", "tc")
+        if "kp" in cache or "cp" in cache:
             # A paged decode. The pools stay whole and OUTSIDE the loop (the
             # kernel is a custom call: a scanned pool would be copied out of
             # the stack, layer by layer, every step): layers and pages become
             # one axis (a bitcast) and each layer's page table is offset to
-            # its own pages. Only the small tails are scanned.
-            n_pages = cache["kp"].shape[1]
+            # its own pages. Only the small tails are scanned. A latent pool
+            # (``cp``: (2 L, n_pages, ps, Dl), one set of pages an attention
+            # SUBLAYER) is addressed the same way, two sets a layer.
+            n_pages = cache["cp" if "cp" in cache else "kp"].shape[1]
             pools = {k: v.reshape(-1, *v.shape[2:]) for k, v in cache.items()
-                     if k not in ("tk", "tv")}
-            cache = {"tk": cache["tk"], "tv": cache["tv"]}
+                     if k not in tails}
+            cache = {k: v for k, v in cache.items() if k in tails}
+            if "cp" in pools:
+                paged = {**paged, "n_pages": n_pages}
+                n_pages *= 2
 
         def cached_layer_fn(carry, xs):
             layer_params, layer_cache, layer_index = xs
@@ -716,7 +755,7 @@ def forward(
             if pools is not None:
                 layer_paged = {
                     **paged, "table": paged["table"] + layer_index * n_pages}
-            y, aux, new_kv, *counts = _decoder_layer(
+            y, aux, new_kv, *counts = block(
                 layer_params,
                 carry,
                 cfg=cfg,
@@ -759,7 +798,7 @@ def forward(
 
         def pipe_layer(h, layer_params, ex):
             pos, seg, tmask = ex
-            return _decoder_layer(
+            return block(
                 layer_params, h, cfg=cfg, positions=pos, segment_ids=seg,
                 mesh=None, rules=None, token_mask=tmask,
             )
@@ -778,7 +817,7 @@ def forward(
         new_cache, moe_counts = None, []
     else:
         def layer_fn(carry, layer_params):
-            y, *ys = _decoder_layer(
+            y, *ys = block(
                 layer_params,
                 carry,
                 cfg=cfg,

@@ -35,6 +35,24 @@ them from touching a live row, and leaving them in keeps one static shape
 and no ragged tail whose contents the kernel would leave undefined. They are
 kept out of what is COUNTED: the load-balancing term and the per-expert
 assignment counts see live rows only.
+
+**A share of a wider expert layer** (LongCat-Flash; ``shares_experts``). The
+router there has ``num_experts + zero_expert_num`` outputs and this chip
+holds ``experts_held_count`` of the routed experts, one rank's share of an
+expert-parallel deployment. A (token, choice) pair is then one of three
+kinds: its expert is HELD (computed here, by the same grouped matmuls),
+ZERO-COMPUTE (the identity: the token's own row times the weight, no gather,
+no matmul; ``moe_zero``) or ABSENT (another chip's: its part of the sum is
+left out here, in program and reference alike, and nothing stands in for the
+chips that are not there). Only held pairs of LIVE rows are gathered: they
+are sorted to the front and the grouped matmuls run over a buffer of
+``held_rows`` rows, an eighth of ``T * k`` (2% of the pairs are held on
+random weights at 16 of 512), once for every such buffer the held pairs
+fill: no pair is dropped however the router skews, and a step whose rows
+chose no held expert runs no matmul at all. The rows come back by a
+scatter-add over tokens in float32. ``counts`` then has ``n_held + 2``
+entries: each held expert's assignments, then the zero-compute experts'
+and the absent ones' totals (``split_counts``).
 """
 
 from __future__ import annotations
@@ -48,13 +66,83 @@ import jax.numpy as jnp
 from ditl_tpu.config import ModelConfig
 from ditl_tpu.ops.backend import interpret_default
 
-__all__ = ["init_moe_params", "moe_logical_axes", "moe_block", "load_balancing_loss"]
+__all__ = ["init_moe_params", "moe_logical_axes", "moe_block", "load_balancing_loss",
+           "shares_experts", "held_experts", "count_width", "split_counts",
+           "grouped_rows", "lean_dense"]
+
+
+def shares_experts(cfg: ModelConfig) -> bool:
+    """Whether the expert layer is LongCat-Flash's kind (module docstring):
+    zero-compute experts or a held share; its selection bias and scaled
+    weights exist only with one of them (``ModelConfig`` refuses them
+    otherwise). False for Mixtral and OLMoE, whose path is the one above."""
+    return bool(cfg.zero_expert_num or cfg.experts_held_count)
+
+
+def held_experts(cfg: ModelConfig) -> tuple[int, int]:
+    """(first index, count) of the routed experts whose weights live here."""
+    if cfg.experts_held_count:
+        return cfg.experts_held_first, cfg.experts_held_count
+    return 0, cfg.num_experts
+
+
+def count_width(cfg: ModelConfig) -> int:
+    """Entries of ``moe_block``'s ``counts``: one an expert, or, for a share,
+    one a held expert and the zero-compute and absent totals."""
+    return held_experts(cfg)[1] + 2 if shares_experts(cfg) else cfg.num_experts
+
+
+def split_counts(counts, cfg: ModelConfig):
+    """``counts`` (..., count_width) -> (per held expert (..., n_held),
+    zero-compute total (...), absent total (...)); a layer that holds every
+    expert and has no zero-compute ones reads (counts, 0, 0)."""
+    if not shares_experts(cfg):
+        return counts, counts[..., 0] * 0, counts[..., 0] * 0
+    return counts[..., :-2], counts[..., -2], counts[..., -1]
+
+
+def grouped_rows(cfg: ModelConfig, tokens: int) -> int:
+    """Rows of the buffer the grouped matmuls see for ``tokens`` tokens: all
+    ``T * k`` pairs, or a share's ``held_rows``."""
+    pairs = tokens * cfg.num_experts_per_tok
+    return held_rows(pairs) if shares_experts(cfg) else pairs
+
+
+def held_rows(pairs: int) -> int:
+    """A share's buffer: an eighth of the pairs (four times what 16 of 512
+    experts draw on random weights), in whole row tiles of the kernel."""
+    tile = GMM_TILING[0]
+    return max(tile, -(-(pairs // 8) // tile) * tile)
+
+
+def lean_dense(key, shape, fan_in: int, pd) -> jax.Array:
+    """A seeded normal leaf of standard deviation ``1 / sqrt(fan_in)`` drawn
+    and cast inside ONE program, so that no float32 copy of it is ever
+    resident: a 9.6 GiB bfloat16 tree is built under a 16 GB chip (the eager
+    draw-then-cast of ``llama.init_params`` holds a leaf three times over in
+    float32, which the OLMoE cell's peak of 16.65 GB shows). Not the same
+    numbers as that eager draw's, so the presets that had it keep it."""
+    std = 1.0 / math.sqrt(fan_in)
+    return jax.jit(
+        lambda k: (jax.random.normal(k, shape, jnp.float32) * std).astype(pd))(key)
 
 
 def init_moe_params(rng: jax.Array, cfg: ModelConfig) -> dict[str, Any]:
     pd = jnp.dtype(cfg.param_dtype)
     d, f, L, E = cfg.hidden_size, cfg.intermediate_size, cfg.num_layers, cfg.num_experts
     k1, k2, k3, k4 = jax.random.split(rng, 4)
+    if shares_experts(cfg):
+        f = cfg.expert_ffn_hidden_size or f
+        n_held = held_experts(cfg)[1]
+        out = {
+            "router": lean_dense(k1, (L, d, E + cfg.zero_expert_num), d, pd),
+            "w_gate": lean_dense(k2, (L, n_held, d, f), d, pd),
+            "w_up": lean_dense(k3, (L, n_held, d, f), d, pd),
+            "w_down": lean_dense(k4, (L, n_held, f, d), f, pd),
+        }
+        if cfg.router_bias:  # float32 like the probabilities it joins
+            out["router_bias"] = jnp.zeros((L, E + cfg.zero_expert_num), jnp.float32)
+        return out
 
     def dense(key, shape, fan_in):
         return (jax.random.normal(key, shape) * (1.0 / math.sqrt(fan_in))).astype(pd)
@@ -70,6 +158,7 @@ def init_moe_params(rng: jax.Array, cfg: ModelConfig) -> dict[str, Any]:
 def moe_logical_axes(cfg: ModelConfig) -> dict[str, Any]:
     return {
         "router": ("layers", "embed", None),
+        **({"router_bias": ("layers", None)} if cfg.router_bias else {}),
         "w_gate": ("layers", "expert", "embed", "mlp"),
         "w_up": ("layers", "expert", "embed", "mlp"),
         "w_down": ("layers", "expert", "mlp", "embed"),
@@ -176,6 +265,9 @@ def moe_block(
     decides which grouped matmul runs, see ``_use_gmm``). ``layer``: the
     index of this layer where ``moe``'s expert weights are the whole stack
     (``experts_in_place``; the router is this layer's own)."""
+    if shares_experts(cfg):
+        return _shared_moe_block(moe, h, cfg, token_mask=token_mask, mesh=mesh,
+                                 layer=layer)
     b, s, d = h.shape
     e, k = cfg.num_experts, cfg.num_experts_per_tok
     cd = h.dtype
@@ -215,4 +307,86 @@ def moe_block(
         out = (pairs * top_w[..., None]).sum(axis=1).astype(cd)
 
     aux = load_balancing_loss(gates, counts, live)
+    return out.reshape(b, s, d), aux, counts.astype(jnp.int32)
+
+
+def _shared_moe_block(moe, h, cfg: ModelConfig, *, token_mask, mesh, layer):
+    """``moe_block`` for a share of a wider expert layer (module docstring):
+    (B, S, D) -> ((B, S, D), aux, counts (n_held + 2,))."""
+    b, s, d = h.shape
+    e, k = cfg.num_experts, cfg.num_experts_per_tok
+    first, n_held = held_experts(cfg)
+    cd = h.dtype
+    t = b * s
+    x = h.reshape(t, d)
+    live = (jnp.ones((t,), bool) if token_mask is None
+            else token_mask.reshape(t).astype(bool))
+
+    with jax.named_scope("moe_router"):
+        gates = jax.nn.softmax(
+            jnp.einsum("td,de->te", x.astype(jnp.float32),
+                       moe["router"].astype(jnp.float32)),
+            axis=-1,
+        )  # (T, E + Z) f32
+
+    with jax.named_scope("moe_dispatch"):
+        choose = gates + moe["router_bias"] if "router_bias" in moe else gates
+        _, top_idx = jax.lax.top_k(choose, k)  # the bias chooses, and only chooses
+        top_w = jnp.take_along_axis(gates, top_idx, axis=-1)
+        if cfg.norm_topk_prob:
+            top_w = top_w / jnp.maximum(top_w.sum(axis=-1, keepdims=True), 1e-9)
+        top_w = top_w * cfg.routed_scaling_factor
+        pair_expert = top_idx.reshape(t * k)  # token-major
+        pair_live = jnp.repeat(live, k)
+        chosen = (pair_expert[:, None] == jnp.arange(e + cfg.zero_expert_num,
+                                                     dtype=pair_expert.dtype)
+                  ) & pair_live[:, None]
+        per_expert = chosen.sum(axis=0, dtype=jnp.int32)  # live rows only
+        sizes = per_expert[first:first + n_held]  # rows of each held group
+        n_zero = per_expert[e:].sum()
+        counts = jnp.concatenate([
+            sizes, jnp.stack([n_zero, per_expert.sum() - sizes.sum() - n_zero])])
+        # held pairs of live rows first, by expert; everything else behind
+        local = pair_expert - first
+        key = jnp.where((local >= 0) & (local < n_held) & pair_live, local, n_held)
+        m = held_rows(t * k)
+        order = jnp.argsort(key, stable=True)
+        order = jnp.concatenate([order, jnp.zeros((m,), order.dtype)])
+        ends = jnp.cumsum(sizes)
+        n_rows = ends[-1]
+        pair_w = top_w.reshape(t * k)
+
+    def one_buffer(i, acc):
+        # rows [i * m, (i + 1) * m) of the sorted held pairs
+        with jax.named_scope("moe_dispatch"):
+            at = i * m + jnp.arange(m, dtype=jnp.int32)
+            valid = at < n_rows
+            pair = jax.lax.dynamic_slice(order, (i * m,), (m,))
+            tok = pair // k
+            xs = x[tok]
+            part = jnp.clip(jnp.minimum(ends, (i + 1) * m)
+                            - jnp.maximum(ends - sizes, i * m), 0, m)
+            row_expert = jnp.minimum(key[pair], n_held - 1)
+        with jax.named_scope("moe_experts"):
+            gate = _grouped(xs, moe["w_gate"], part, row_expert, cd, mesh, layer)
+            up = _grouped(xs, moe["w_up"], part, row_expert, cd, mesh, layer)
+            ys = _grouped(jax.nn.silu(gate) * up, moe["w_down"], part, row_expert, cd,
+                          mesh, layer)
+        with jax.named_scope("moe_combine"):
+            # rows past the held pairs belong to no group: the kernel leaves
+            # them as they were, so they are masked, not weighted by zero
+            rows = jnp.where(valid[:, None], ys.astype(jnp.float32), 0.0)
+            return acc.at[tok].add(rows * pair_w[pair][:, None])
+
+    out = jax.lax.fori_loop(0, (n_rows + m - 1) // m, one_buffer,
+                            jnp.zeros((t, d), jnp.float32))
+    with jax.named_scope("moe_combine"):
+        with jax.named_scope("moe_zero"):
+            # the identities: the token's own row, once, times their weights
+            w_zero = (top_w * (top_idx >= e)).sum(axis=-1)
+            out = out + x.astype(jnp.float32) * w_zero[:, None]
+        out = out.astype(cd)
+
+    aux = load_balancing_loss(gates, per_expert.astype(jnp.float32),
+                              live.astype(jnp.float32))
     return out.reshape(b, s, d), aux, counts.astype(jnp.int32)

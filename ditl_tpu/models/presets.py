@@ -136,6 +136,41 @@ PRESETS: dict[str, ModelConfig] = {
         num_experts_per_tok=8,
         norm_topk_prob=False,
     ),
+    # LongCat-Flash-Chat (meituan-longcat, 560 B parameters): 28 shortcut-
+    # connected double layers, latent attention (MLA), a 768-way softmax
+    # router over 512 routed experts of width 2,048 and 256 identity
+    # (zero-compute) experts, 12 a token, weights 6 x the probabilities as
+    # they are, a selection bias for the choice only. No chip holds a layer
+    # (39.9 GB in bf16): serve a share with ``model.experts_held_first`` /
+    # ``model.experts_held_count``, fewer layers and a slice of the
+    # vocabulary (benchmarks/configs/longcat-flash-cut1.json).
+    "longcat-flash": ModelConfig(
+        name="longcat-flash",
+        vocab_size=131072,
+        hidden_size=6144,
+        intermediate_size=12288,
+        expert_ffn_hidden_size=2048,
+        num_layers=28,
+        num_heads=64,
+        num_kv_heads=64,
+        head_dim=192,  # a query's / key's: 128 without position + 64 rotary
+        max_seq_len=131072,
+        rope_theta=10000000.0,
+        rms_norm_eps=1e-5,
+        q_lora_rank=1536,
+        kv_lora_rank=512,
+        qk_nope_head_dim=128,
+        qk_rope_head_dim=64,
+        v_head_dim=128,
+        mla_scale_q_lora=True,
+        mla_scale_kv_lora=True,
+        num_experts=512,
+        zero_expert_num=256,
+        num_experts_per_tok=12,
+        norm_topk_prob=False,
+        routed_scaling_factor=6.0,
+        router_bias=True,
+    ),
 }
 
 

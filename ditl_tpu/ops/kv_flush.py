@@ -45,14 +45,17 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ditl_tpu.ops.backend import interpret_default
 
-__all__ = ["kv_flush"]
+__all__ = ["kv_flush", "latent_flush"]
 
 
-def _kv_flush_kernel(tab, st, pos, tk_ref, tv_ref, kp_ref, vp_ref,
-                     kp_out, vp_out, *, w):
+def _kv_flush_kernel(tab, st, pos, *refs, w):
+    """``refs``: the tails, the pools and the pools' outputs, as many of each
+    (K and V: two; a latent pool: one)."""
     del tab  # the index maps' alone
+    n_pools = len(refs) // 3
+    tails, ins, outs = (refs[i * n_pools:(i + 1) * n_pools] for i in range(3))
     ib, iw = pl.program_id(0), pl.program_id(2)
-    kv_heads, t_len = tk_ref.shape[2], tk_ref.shape[3]
+    kv_heads, t_len = tails[0].shape[2], tails[0].shape[3]
     s = st[ib]
     n = pos[ib] - s
     # window row r holds position base + r, the tail's column base + r - s
@@ -60,13 +63,12 @@ def _kv_flush_kernel(tab, st, pos, tk_ref, tv_ref, kp_ref, vp_ref,
     col = base - s + jax.lax.broadcasted_iota(jnp.int32, (w, 1), 0)
     valid = (col >= 0) & (col < jnp.minimum(n, t_len))
     # int8 and bf16 values are exact in bf16; float32 keeps its own width
-    exact32 = tk_ref.dtype == jnp.float32
+    exact32 = tails[0].dtype == jnp.float32
     cdt = jnp.float32 if exact32 else jnp.bfloat16
     onehot = (col == jax.lax.broadcasted_iota(jnp.int32, (w, t_len), 1)).astype(cdt)
     precision = jax.lax.Precision.HIGHEST if exact32 else None
 
-    for tail_ref, in_ref, out_ref in ((tk_ref, kp_ref, kp_out),
-                                      (tv_ref, vp_ref, vp_out)):
+    for tail_ref, in_ref, out_ref in zip(tails, ins, outs):
         for k in range(kv_heads):
             new = jax.lax.dot(
                 onehot, tail_ref[0, 0, k].astype(cdt),
@@ -112,8 +114,31 @@ def kv_flush(
             out_specs=(spec, spec),
             check_vma=False,
         )(k_pool, v_pool, tail_k, tail_v, page_table, starts, pos)
+    return tuple(_flush((k_pool, v_pool), (tail_k, tail_v), page_table, starts, pos,
+                        interpret))
+
+
+def latent_flush(
+    pool: jax.Array,  # (L, P, ps, D): one latent entry a token (models/mla.py)
+    tail: jax.Array,  # (L, B, T, D), in the pool's dtype
+    page_table: jax.Array,  # (B, maxp) int32
+    starts: jax.Array,
+    pos: jax.Array,
+    *,
+    interpret: bool | None = None,
+) -> jax.Array:
+    """``kv_flush`` for a latent page pool: the same kernel over ONE pool with
+    one "head" (a bitcast of the pool and the tail: no copy)."""
+    (out,) = _flush((pool[:, :, None],), (tail[:, :, None],), page_table, starts, pos,
+                    interpret)
+    return out[:, :, 0]
+
+
+def _flush(pools, tails, page_table, starts, pos, interpret):
     if interpret is None:
         interpret = interpret_default()
+    k_pool, tail_k = pools[0], tails[0]
+    n = len(pools)
     n_layers, _, kv_heads, ps, d = k_pool.shape
     n_slots, t_len = tail_k.shape[1], tail_k.shape[3]
     maxp = page_table.shape[1]
@@ -139,16 +164,15 @@ def kv_flush(
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(n_slots, n_layers, n_w),
-            in_specs=[tail, tail, window, window],
-            out_specs=[window, window],
+            in_specs=[tail] * n + [window] * n,
+            out_specs=[window] * n,
         ),
-        out_shape=[jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
-                   jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype)],
-        # operands count the scalar-prefetch ones: 3 + (tk, tv, kp, vp)
-        input_output_aliases={5: 0, 6: 1},
+        out_shape=[jax.ShapeDtypeStruct(p.shape, p.dtype) for p in pools],
+        # operands count the scalar-prefetch ones: 3 + the tails + the pools
+        input_output_aliases={3 + n + i: i for i in range(n)},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")
         ),
         interpret=interpret,
         name="kv_flush",
-    )(page_table, starts, pos, tail_k, tail_v, k_pool, v_pool)
+    )(page_table, starts, pos, *tails, *pools)

@@ -26,7 +26,20 @@ is ``mlp``'s, as a dense layer's is; to one that also knows these (innermost
 wins) ``mlp`` keeps only the norm. ``moe_router``: the router's matmul and
 softmax; ``moe_dispatch``: top-k, the sort by expert and the gather of the
 token rows; ``moe_experts``: the three grouped matmuls; ``moe_combine``: the
-rows back in token order, weighted and summed."""
+rows back in token order, weighted and summed.
+
+``MLA_SCOPES`` are the parts of a latent attention sublayer (``models/
+mla.py``), each INSIDE the scope of ``SCOPES`` it refines, so a reader that
+knows only ``SCOPES`` still books the time: ``mla_q`` (the query's
+down-projection, norm, scale, up-projection, rotation and, in decode, the
+absorption of ``Wkvb``'s key half) and ``mla_kv`` (the latent's projection,
+norm, scale and rotation; in prefill its decompression through ``Wkvb``)
+inside ``attn_qkv``, ``mla_kv`` again inside ``attn_out`` for the value half
+of the absorption; ``mla_attn`` inside ``attn_core``: a decode step's
+attention over the latent pool (the kernel ``mla_paged_attention`` and what
+surrounds it). The latent's write into the tick's tail and the tail's flush
+are ``kv_write``. ``moe_zero`` (``MOE_ZERO_SCOPES``), inside ``moe_combine``:
+the zero-compute experts' weighted identity."""
 
 SCOPES = (
     "embed",
@@ -49,6 +62,18 @@ MOE_SCOPES = (
     "moe_experts",
     "moe_combine",
 )
+
+MLA_SCOPES = (
+    "mla_q",
+    "mla_kv",
+    "mla_attn",
+)
+
+MOE_ZERO_SCOPES = ("moe_zero",)
+
+# Decode attention over a latent page pool (``ops/mla_attention.py``), inside
+# ``mla_attn``.
+MLA_KERNELS = ("mla_paged_attention",)
 
 # The grouped matmul of ``moe_experts`` on one TPU chip is the library's
 # kernel (``jax.experimental.pallas.ops.tpu.megablox``): these are ITS names,

@@ -25,7 +25,10 @@ import jax.numpy as jnp
 __all__ = ["quantize_weights", "is_quantized_leaf", "weight_einsum"]
 
 # Param-tree leaves that are (…, d_in, d_out) matmul weights.
-_QUANT_KEYS = ("wq", "wk", "wv", "w_qkv", "wo", "w_gate", "w_up", "w_gu", "w_down", "kernel")
+_QUANT_KEYS = ("wq", "wk", "wv", "w_qkv", "wo", "w_gate", "w_up", "w_gu", "w_down", "kernel",
+               # latent attention's projections (models/mla.py); ``w_kvb`` stays
+               # float: its halves are folded into the query and the output
+               "w_qa", "w_qb", "w_kva")
 
 
 def is_quantized_leaf(w: Any) -> bool:

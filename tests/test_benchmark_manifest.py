@@ -59,3 +59,47 @@ def test_at_most_a_quarter_of_the_cells_ask_for_four_chips(manifest_mod):
     four = sum(c["chips"] == 4 for c in cells)
     assert four <= max(1, len(cells) // 4)
     assert all(c["chips"] in (1, 4) for c in cells)
+
+
+def test_every_why_and_source_is_1_to_200_printable_ascii_characters(manifest_mod):
+    """The driver reads a configuration's ``why`` and ``source`` and a cell's
+    ``why`` under one rule: 1 to 200 characters, ASCII and printable, one
+    line. ``manifest.validate`` checks only a cell's ``why``, so a manifest it
+    passed was refused for a configuration's (ledger, PR 31)."""
+    m = manifest_mod.load()
+    texts = [(f"{kind} {row['name']}: {key}", row[key])
+             for kind, rows, keys in (("configuration", m["configs"], ("why", "source")),
+                                      ("cell", m["workloads"], ("why",)))
+             for row in rows for key in keys]
+    assert len(texts) == 2 * len(m["configs"]) + len(m["workloads"])
+    for where, text in texts:
+        assert 1 <= len(text) <= 200, (where, len(text))
+        assert text.isascii() and text.isprintable(), where
+
+
+def test_the_longcat_cell_is_listed_only_under_readers_that_are_never_absent(manifest_mod):
+    """On a NEW cell a metric missing from the traced line is a refusal, not
+    a note (ledger, PR 30: "metrics lacks harvest_idle_share_chat"). So the
+    cell stays off the readers that return None on a trace that happens to
+    hold none of what they look for, and off those that read another kernel
+    or keys this configuration does not have."""
+    m = manifest_mod.load()
+    cell = "longcat-flash-cut1.chat-wide-mla"
+    listed = {x["name"] for x in manifest_mod.metrics_for(m, "per_layer", cell)}
+    for name, why in {
+        # idle gaps under one host span: absent when the traced 3 s hold no such gap
+        "harvest_idle_share_chat": "gap_share returns None",
+        "schedule_idle_share_chat": "gap_share returns None",
+        # whole runs of the prefill program: absent when the traced 3 s hold none
+        "prefill_device_share": "program_share returns None",
+        "paged_attn_time_share_chat": "the K/V kernel, which this configuration never runs",
+        "moe_experts_roofline_decode": "reads intermediate_size and num_hidden_layers",
+    }.items():
+        assert name not in listed, (name, why)
+    mine = {x["name"] for x in m["per_layer"] if x.get("workloads") == [cell]}
+    assert mine == {"mla_attn_time_share_chat", "mla_attn_roofline_decode",
+                    "mla_proj_time_share_chat", "moe_held_roofline_decode",
+                    "moe_zero_assign_share_chat", "moe_held_assign_share_chat"}
+    assert mine <= listed and len(listed) == 24
+    assert {x["name"] for x in manifest_mod.metrics_for(m, "end_to_end", cell)} == {
+        "setup_s", "ttft_p95_ms", "tpot_p50_ms"}

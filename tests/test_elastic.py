@@ -69,16 +69,28 @@ def test_pod_controller_relaunches_full_pod_on_bumped_port(tmp_path):
     # on a different coordinator port.
     # Per-WORKER flag files: a shared flag would race (worker 0 creates it,
     # worker 1 reads it as already present and exits 0 in generation 0).
+    # A generation-0 worker leaves only once its sibling's flag is there
+    # too: the controller tears the pod down at the FIRST non-zero exit, and
+    # on a loaded machine that came before the other interpreter had started
+    # (no flag, so it failed generation 1 as well: two restarts, seen twice in
+    # whole tier-1 runs of PR 33).
     code = (
-        "import os, sys; p = sys.argv[1]; ok = os.path.exists(p); "
-        "open(p, 'w').close(); sys.exit(0 if ok else 1)"
+        "import os, sys, time\n"
+        "p, other = sys.argv[1:3]\n"
+        "ok = os.path.exists(p)\n"
+        "open(p, 'w').close()\n"
+        "t = time.time()\n"
+        "while not ok and not os.path.exists(other) and time.time() - t < 20:\n"
+        "    time.sleep(0.01)\n"
+        "sys.exit(0 if ok else 1)\n"
     )
     seen_ports: list[int] = []
 
     def build(i, n, port, attempt):
         if i == 0:
             seen_ports.append(port)
-        return _cmd(code, str(tmp_path / f"gen-0-ran-{i}"))
+        return _cmd(code, str(tmp_path / f"gen-0-ran-{i}"),
+                    str(tmp_path / f"gen-0-ran-{1 - i}"))
 
     ctl = PodController(2, build, max_pod_restarts=2, poll_s=0.05)
     result = ctl.run(timeout_s=60)

@@ -186,3 +186,49 @@ def test_paged_flush_copies_no_pool(one_chip, tpu_branch, layers, pages, kv):
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < pool_elements * 2 / 10
     assert mem.alias_size_in_bytes == 2 * pool_elements * 2  # both pools in place
+
+
+def test_latent_decode_kernel_compiles_at_the_longcat_cells_shapes(one_chip):
+    """``mla_paged_attention`` as ``longcat-flash-cut1.chat-wide-mla`` runs it:
+    128 slots, 64 heads against ONE 640-wide entry a token (512 of it the
+    value), pages of 256 in a pool of 8 sublayers x 1,280 pages addressed as
+    one, 16 pages a slot and a 16-column tail."""
+    from ditl_tpu.ops.mla_attention import mla_paged_attention
+
+    b, h, dl, vw, ps, pages, maxp, tail = 128, 64, 640, 512, 256, 8 * 1280, 16, 16
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    args = (s((b, h, dl), jnp.bfloat16), s((pages, ps, dl), jnp.bfloat16),
+            s((b, maxp), jnp.int32), s((b,), jnp.int32), s((b, tail, dl), jnp.bfloat16),
+            s((b,), jnp.int32))
+    compiled = jax.jit(
+        lambda q, pool, tab, lens, tl, st: mla_paged_attention(
+            q, pool, tab, lens, tail=tl, starts=st, value_width=vw, scale=192 ** -0.5,
+            interpret=False)
+    ).lower(*args).compile()
+    assert names.MLA_KERNELS[0] in _instructions(compiled.as_text())
+
+
+def test_latent_flush_copies_no_pool(one_chip, tpu_branch):
+    """The tick's flush of its latent tails (4 layers x 2 sublayers) into the
+    donated latent pool at the longcat cell's shapes: the same ``kv_flush``
+    kernel over one pool with one head, a bitcast of the pool on the way in
+    and out, and nothing of the pool's size produced but the custom call."""
+    from ditl_tpu.infer.continuous import _flush_latent_tail
+
+    b, ps, tail, maxp, dl, pages = 128, 256, 16, 16, 640, 1280
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    row = s((b,), jnp.int32)
+    compiled = jax.jit(_flush_latent_tail, donate_argnums=(0,)).lower(
+        {"cp": s((8, pages, ps, dl), jnp.bfloat16)}, s((4, 2, b, tail, dl), jnp.bfloat16),
+        row, row, s((b, maxp), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert names.CACHE_KERNELS[0] in _instructions(text)
+    pool_elements = 8 * pages * ps * dl
+    producers = set()
+    for dims, op in re.findall(r" = bf16\[([\d,]+)\]\S* ([\w\-]+)\(", text):
+        if math.prod(map(int, dims.split(","))) == pool_elements:
+            producers.add(op)
+    assert producers <= {"bitcast", "parameter", "get-tuple-element", "custom-call"}
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < pool_elements * 2 / 10
+    assert mem.alias_size_in_bytes == pool_elements * 2  # the pool in place
