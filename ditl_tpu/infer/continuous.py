@@ -17,13 +17,20 @@ wait for the batch to drain. This engine removes both limits the TPU way:
   length bucket against a 1-row slice of the shared cache, then the slice is
   written back at the slot index. Other slots' state is untouched, so
   admission never disturbs in-flight decodes.
-- **Chunked ticks**: decode runs ``decode_chunk`` steps per program call
-  (a ``lax.scan``; zero host round-trips inside), then the host harvests
-  finished slots, trims at EOS, and admits queued requests.
+- **Chunked, double-buffered ticks**: decode runs ``decode_chunk`` steps per
+  program call (a ``lax.scan``; zero host round-trips inside; 4 by default,
+  ~50 ms of device time at serving sizes). Each ``step`` admits queued
+  requests, enqueues the NEXT program, and only then fetches and harvests
+  the program enqueued one step earlier (finished slots, EOS trim, stream
+  writes), so the device runs one tick while the host harvests the other.
+  A short program is what an arriving request waits out before it is
+  admitted; the overlap is what keeps a short program's per-tick host work
+  off the device's clock.
 - **A first token of its own**: the token a prefill samples leaves with the
   tick that ran the prefill — fetched right behind that tick's decode
   dispatch, put on the request's stream alone — and not with the tick's
-  other ``decode_chunk - 1`` tokens a whole decode program later.
+  other ``decode_chunk - 1`` tokens, which the lagged harvest delivers a
+  step later.
 
 The scheduler (``submit``/``step``/``run``) is deliberately host-side and
 simple — admission policy is not a TPU problem. Per-request sampling params
@@ -106,6 +113,14 @@ __all__ = ["BadRequestError", "ContinuousEngine", "DeadlineExceededError",
 # eviction order reversed. The names ride the HTTP surface (`slo_class`
 # payload / `X-SLO-Class` header), so changing them is an API change.
 SLO_CLASSES: dict[str, int] = {"interactive": 0, "batch": 1, "best_effort": 2}
+
+
+def tail_width(decode_chunk: int) -> int:
+    """Columns of a paged decode tick's tail buffers: one a step of the
+    program, and no fewer than the 8 sublanes Mosaic wants of the tail block
+    (a 4-step program fills columns 0-3; ``pos - starts`` masks the rest in
+    the attention kernels and in the flush)."""
+    return max(decode_chunk, 8)
 
 
 def _quantize_pages(chunk: jax.Array) -> tuple[jax.Array, jax.Array]:
@@ -393,7 +408,7 @@ class ContinuousEngine:
         tokenizer: Tokenizer,
         *,
         n_slots: int = 8,
-        decode_chunk: int = 16,
+        decode_chunk: int = 4,
         gen: GenerateConfig | None = None,
         seed: int = 0,
         max_cache_len: int | None = None,
@@ -416,7 +431,7 @@ class ContinuousEngine:
         fsm_capacity: int = 0,
         draft_params: llama.Params | None = None,
         draft_cfg: ModelConfig | None = None,
-        pipeline_ticks: bool = False,
+        pipeline_ticks: bool = True,
         admission: str = "reserve",
         token_budget: int = 0,
         thrash_window: int = 32,
@@ -893,14 +908,24 @@ class ContinuousEngine:
         # and every consumer below indexes/pops the head.
         self._queue: list[Request] = []
         self._completed: dict[int, Request] = {}
-        # Double-buffered (pipelined) ticks: dispatch tick N+1 before
-        # fetching tick N's outputs, so the host→device dispatch and
-        # device→host fetch (host time every tick pays, on any machine)
-        # overlap with device compute instead of serializing with it. Harvest and
-        # admission lag one tick; outputs are token-identical (per-slot RNG
-        # derives from the request seed, never from tick alignment).
+        # Double-buffered (pipelined) ticks, the default and what the server
+        # runs: dispatch tick N+1 before fetching tick N's outputs, so the
+        # host→device dispatch, the device→host fetch and the harvest (host
+        # time every tick pays, on any machine) overlap with device compute
+        # instead of serializing with it. Harvest and admission lag one
+        # tick; outputs are token-identical (per-slot RNG derives from the
+        # request seed, never from tick alignment). False is the serial
+        # order (dispatch, fetch, harvest in one step): what the identity
+        # tests compare against and what a speculative probe tick runs.
         self.pipeline_ticks = bool(pipeline_ticks)
         self._pending_fetch: tuple | None = None
+        # Steps that fetched and harvested one tick while the next tick's
+        # program was already enqueued, and the rows of harvested ticks
+        # whose request had already finished or been cancelled (the lag's
+        # price: one dead chunk a finished row). /v1/stats and, per step,
+        # the ``engine.tick`` span's ``overlapped`` / ``dead_rows``.
+        self.ticks_overlapped = 0
+        self.dead_chunk_rows = 0
         # Expert load of a model with experts (paged programs only): what the
         # live rows of the decode ticks and the real tokens of the prefills
         # were assigned, per layer and expert. The decode program returns its
@@ -1832,7 +1857,7 @@ class ContinuousEngine:
         cfg = self.cfg
         pad, eos = self.tokenizer.pad_id, self.tokenizer.eos_id
         chunk = self.decode_chunk
-        tail_len = max(chunk, 8)  # Mosaic sublane floor for the tail block
+        tail_len = tail_width(chunk)
         L, K, D = cfg.num_layers, cfg.num_kv_heads, cfg.head_dim
         dt = jnp.dtype(cfg.dtype)
 
@@ -1930,8 +1955,12 @@ class ContinuousEngine:
                      *((jnp.zeros((), jnp.int32),) if self.latent else ()))
                     if moe else ())
             (tails, cur, pos, done, keys, hist, fst, lp, moe_acc), ys = jax.lax.scan(
-                body, (tails0, cur, pos, ~alive, keys, hist, fst0,
-                       tuple(lp0), moe0),
+                # A row whose pending token is the pad already ended in an
+                # earlier tick (``cur = where(done, pad, nxt)``): the dead
+                # chunk it decodes before the lagged harvest frees its slot
+                # reads no page, writes no tail column and counts nothing.
+                body, (tails0, cur, pos, ~alive | (cur == pad), keys, hist,
+                       fst0, tuple(lp0), moe0),
                 jnp.arange(chunk, dtype=jnp.int32),
             )
 
@@ -3839,6 +3868,7 @@ class ContinuousEngine:
                 # Pipelined ticks: the slot decoded one extra (dead) chunk
                 # after the request finished or was cancelled — its row is
                 # garbage and the request already completed/streamed.
+                self.dead_chunk_rows += 1
                 continue
             fresh: list[int] = []
             row = emitted[slot] if counts is None else emitted[slot][: counts[slot]]
@@ -4378,19 +4408,27 @@ class ContinuousEngine:
         program besides. The tick's harvest delivers the rest of the row
         (``decode_chunk - 1`` tokens of a plain tick) as the second chunk.
 
-        ``pipeline_ticks``: the tick dispatched here is NOT fetched here —
-        it is fetched (and harvested) on the NEXT step, after that step has
-        already dispatched its own tick. The host's dispatch+fetch round
-        trips overlap with device compute; admission and harvest lag one
-        tick (the first tokens do not: they leave with the step that
-        prefilled); a finished request's slot decodes one dead chunk before
-        being freed (masked out by the harvest snapshot). Token streams are
-        identical to serial ticks — per-slot RNG derives from the request
-        seed, never from tick alignment.
+        Ticks are double-buffered (``pipeline_ticks``, the default): the
+        tick dispatched here is NOT fetched here — it is fetched (and
+        harvested) on the NEXT step, after that step has already dispatched
+        its own tick. The host's dispatch, fetch and harvest overlap with
+        device compute, which is what lets the program be short (4 steps)
+        without its per-tick host work idling the device; admission and
+        harvest lag one tick (the first tokens do not: they leave with the
+        step that prefilled), so an arriving request waits out between one
+        and two programs; a finished request's slot decodes one dead chunk
+        before being freed (masked out by the harvest snapshot, and dead on
+        the device too where the row ended on a pad). Token streams are
+        identical to serial ticks (``pipeline_ticks=False``: dispatch,
+        fetch and harvest in one step) — per-slot RNG derives from the
+        request seed, never from tick alignment.
 
         An armed tracer gets one ``engine.tick`` span per call (tick number,
         slot occupancy, queue depth, prefill seconds, ``first_tokens`` sent
-        ahead of the decode fetch: the scheduler cadence)
+        ahead of the decode fetch: the scheduler cadence; ``overlapped``, 1
+        when this step fetched and harvested one tick while its own decode
+        program was already enqueued; ``dead_rows``, the harvested rows
+        whose request had already finished or been cancelled)
         and, as its children, what the engine thread did in it:
         ``engine.tick.schedule``
         (deadlines, admission, page top-up), ``.prefill`` (this tick's
@@ -4406,10 +4444,15 @@ class ContinuousEngine:
         self._tick_span = self.tracer.start_span(
             "engine.tick", tick=self.tick_count
         )
+        overlapped0, dead0 = self.ticks_overlapped, self.dead_chunk_rows
         try:
             self._tick()
         finally:
             self._phase(None)
+            self._tick_span.annotate(
+                overlapped=self.ticks_overlapped - overlapped0,
+                dead_rows=self.dead_chunk_rows - dead0,
+            )
             self._tick_span.end()
             self._tick_span = None
 
@@ -4558,6 +4601,10 @@ class ContinuousEngine:
         elif self.pipeline_ticks:
             self._pending_fetch = rec
             if prev is not None:
+                # The device holds this step's program while the host
+                # fetches and harvests the one before it.
+                if rec is not None:
+                    self.ticks_overlapped += 1
                 self._finish_tick(prev)
         elif rec is not None:
             self._finish_tick(rec)
@@ -4681,6 +4728,10 @@ class ContinuousEngine:
             # Requests whose first token left with the tick that prefilled
             # them (every admitted one, bar a first token that is eos).
             "first_tokens_early_total": self.first_tokens_early,
+            # Steps that harvested one tick under the next tick's program,
+            # and the dead chunks' rows that lag cost (see step).
+            "ticks_overlapped_total": self.ticks_overlapped,
+            "dead_chunk_rows_total": self.dead_chunk_rows,
             "decode_chunk": self.decode_chunk,
             "max_context": self.smax,
             "token_budget": self.token_budget,
@@ -4782,9 +4833,19 @@ class ContinuousEngine:
         finished requests' token lists by id (no unbounded history kept)."""
         while self.pending:
             self.step()
+        self.drain()
         out = {rid: req.tokens for rid, req in sorted(self._completed.items())}
         self._completed.clear()
         return out
+
+    def drain(self) -> None:
+        """Fetch and harvest the tick a double-buffered step left pending.
+        With nothing queued and every slot free that is a dead chunk, but
+        its fetch carries the last prefills' expert counts and it holds the
+        tick's device buffers: an engine going idle drains it."""
+        prev, self._pending_fetch = self._pending_fetch, None
+        if prev is not None:
+            self._finish_tick(prev)
 
     def generate(self, prompts: list[str], **submit_kw) -> list[str]:
         """Text in, text out (convenience parity with engine.Generator)."""
@@ -4960,6 +5021,8 @@ class ThreadedEngine:
                     self._engine.cancel(rid)
                 if self._engine.pending:
                     self._engine.step()
+                if not self._engine.pending:
+                    self._engine.drain()  # going idle: the last dead chunk
             except BaseException as e:  # device/compile errors must not
                 # wedge the server: fail every waiter loudly and stop.
                 logger.exception("continuous engine driver died")

@@ -2319,13 +2319,6 @@ def serve(argv: list[str] | None = None) -> int:
         "set pessimistic max_tokens",
     )
     parser.add_argument(
-        "--pipeline-ticks", action="store_true",
-        help="double-buffered decode ticks for --engine continuous: "
-        "dispatch tick N+1 before fetching tick N, overlapping host "
-        "dispatch/fetch round trips with device compute (harvest and "
-        "admission lag one tick; outputs are token-identical)",
-    )
-    parser.add_argument(
         "--fsm-capacity", type=int, default=0,
         help="arm guided (grammar-constrained) decoding on --engine "
         "continuous: total DFA states servable at once (device table rows; "
@@ -2608,8 +2601,6 @@ def serve(argv: list[str] | None = None) -> int:
     if args.fsm_capacity and args.pod:
         parser.error("--fsm-capacity does not compose with --pod yet (the "
                      "tick broadcast does not carry grammar registrations)")
-    if args.pipeline_ticks and args.engine != "continuous":
-        parser.error("--pipeline-ticks requires --engine continuous")
     if args.host_tier_mb and (
         args.engine != "continuous" or args.cache_mode != "paged"
     ):
@@ -2628,7 +2619,8 @@ def serve(argv: list[str] | None = None) -> int:
         parser.error("--kv-handoff requires a solo paged continuous "
                      "engine (--engine continuous --cache-mode paged, "
                      "no --pod)")
-    # --pipeline-ticks and --admission optimistic both compose with --pod:
+    # Double-buffered ticks (every continuous engine runs them) and
+    # --admission optimistic both compose with --pod:
     # the lagged harvest and the preemption decisions (_topup_pages /
     # _pick_victim) are deterministic functions of the replicated scheduler
     # state, so every replica double-buffers, preempts, and resumes
@@ -2797,7 +2789,6 @@ def serve(argv: list[str] | None = None) -> int:
             logprobs_k=args.logprobs_k,
             fsm_capacity=args.fsm_capacity,
             draft_params=draft_params, draft_cfg=draft_cfg,
-            pipeline_ticks=args.pipeline_ticks,
             admission=args.admission,
             token_budget=args.token_budget,
             host_tier_mb=args.host_tier_mb,

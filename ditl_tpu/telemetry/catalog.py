@@ -226,6 +226,7 @@ _ROWS: tuple = (
     ("ditl_serving_admission_degrades", "gauge", "", "lifetime anti-thrash degrade windows (stats mirror)"),
     ("ditl_serving_client_disconnects_total", "counter", "", "in-flight generations cancelled because the client vanished mid-stream"),
     ("ditl_serving_deadline_expired_total", "counter", "", "requests evicted from the queue/slots at their deadline (expired work stops consuming engine ticks)"),
+    ("ditl_serving_dead_chunk_rows_total", "gauge", "", "rows of harvested decode ticks whose request had already finished or been cancelled: the one dead chunk a slot decodes before a double-buffered tick's lagged harvest frees it (lifetime count from /v1/stats)"),
     ("ditl_serving_decode_chunk", "gauge", "", "decode tokens per scheduler tick"),
     ("ditl_serving_decode_token_seconds", "histogram", "", "per-token decode latency (harvest interval / chunk tokens)"),
     ("ditl_serving_draining", "gauge", "", "1 while the server is draining (SIGTERM / rolling restart)"),
@@ -308,6 +309,7 @@ _ROWS: tuple = (
     ("ditl_serving_speculative_threshold", "gauge", "", "predicted-acceptance threshold for speculating"),
     ("ditl_serving_speculative_ticks", "gauge", "", "ticks counted by the speculation decision path"),
     ("ditl_serving_staged", "gauge", "", "requests staged for the next pod tick broadcast", True),
+    ("ditl_serving_ticks_overlapped_total", "gauge", "", "scheduler steps that fetched and harvested one decode tick while the next tick's program was already enqueued on the device (double-buffered ticks; lifetime count from /v1/stats)"),
     ("ditl_serving_token_budget", "gauge", "", "per-tick token budget (0 = unbudgeted)"),
     ("ditl_serving_tokens_generated_total", "counter", "", "tokens generated (all requests)"),
     ("ditl_serving_tpot_interference_batch_seconds", "histogram", "", "per-tick decode delay absorbed by batch-class victims because the tick also ran another request's prefill"),

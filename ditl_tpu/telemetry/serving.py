@@ -13,10 +13,12 @@ Semantics worth pinning (the vLLM-style contract, adapted to chunked ticks):
 - **queue wait**: submit -> the admission that moved the request into a slot.
   A preemption-resume is NOT a second admission (the request never left the
   user's perspective of "running").
-- **TTFT**: submit -> the harvest that delivered the first generated token to
-  the host. Harvests happen once per decode tick, so TTFT is quantized by the
-  tick (decode_chunk steps) — that IS when a streaming client can first see
-  the token, so the quantization is honest, not an artifact.
+- **TTFT**: submit -> the first generated token on the host. It leaves with
+  the step that ran the request's prefill, fetched right behind that step's
+  decode dispatch, so a streaming client first sees a token after the wait
+  for admission (ticks are double-buffered: between one and two decode
+  programs of ``decode_chunk`` steps, 4 by default) plus that step's
+  prefills — not after a decode tick besides.
 - **per-token decode latency**: harvest-interval / tokens-in-chunk, observed
   once per token of the chunk. The histogram's shape answers "TPOT p50/p99".
 - **grammar-masked tokens**: generated tokens whose request carried an FSM

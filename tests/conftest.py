@@ -94,9 +94,6 @@ _SLOW_TESTS = {
     "tests/test_preemption.py::test_preemption_streaming_and_pipelined",
     "tests/test_preemption.py::test_optimistic_with_guided_early_finish",
     "tests/test_preemption.py::test_cancel_of_preempted_request_that_finished_while_queued",
-    "tests/test_pipeline_ticks.py::test_pipelined_matches_serial_greedy_with_slot_reuse",
-    "tests/test_pipeline_ticks.py::test_pipelined_matches_serial_sampled",
-    "tests/test_pipeline_ticks.py::test_pipelined_matches_serial_chunked_prefill",
     "tests/test_pipeline_ticks.py::test_pipelined_cancel_mid_flight",
     "tests/test_podserve.py::test_pod_continuous_generate_many_and_guided_rejection",
     "tests/test_podserve.py::test_pod_continuous_generate_many_overflow_abandons_siblings",

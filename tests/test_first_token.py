@@ -5,7 +5,9 @@ behind the decode dispatch and puts it on the request's stream alone
 (``ContinuousEngine._send_first_tokens``), and the tick's harvest delivers
 the rest of the row. These tests pin, on the CPU and by counts and
 identities only: the token streams are what ``engine.run()`` returns without
-a stream, in every scheduler mode; the one-token chunk is on the stream
+a stream, in every scheduler mode (the default engine, whose ticks are
+double-buffered, at 4 and at 16 steps a program, and the serial order it is
+held against); the one-token chunk is on the stream
 before the tick's own fetch begins; the paths that end or interrupt a request
 around its first token end it once; and the counter that says it engaged."""
 
@@ -26,7 +28,7 @@ from ditl_tpu.telemetry.journal import EventJournal, merge_journals
 from ditl_tpu.telemetry.tracing import Tracer
 from ditl_tpu.telemetry.usage import UsageLedger, load_usage, usage_ledger_path
 
-CHUNK = 4  # decode steps a plain tick
+CHUNK = 4  # decode steps a plain tick: the engine's default
 PREFILL_CHUNK = 16
 MAX_NEW = 11
 
@@ -41,8 +43,8 @@ def setup():
     return llama.init_params(jax.random.key(0), cfg), cfg, ByteTokenizer()
 
 
-def _engine(setup, *, cache="paged", spec=False, pipe=False, chunked=False,
-            tok=None, **kw):
+def _engine(setup, *, cache="paged", spec=False, serial=False, chunk=CHUNK,
+            chunked=False, tok=None, **kw):
     params, cfg, tok0 = setup
     kw.setdefault("n_slots", 3)
     kw.setdefault("gen", GenerateConfig(max_new_tokens=MAX_NEW, temperature=0.0))
@@ -53,9 +55,11 @@ def _engine(setup, *, cache="paged", spec=False, pipe=False, chunked=False,
         # threshold 0: every tick speculates, so two runs of one engine take
         # the same programs whatever its acceptance average has become
         kw.update(speculative=True, spec_k=3, spec_threshold=0.0)
+    if serial:  # the order the default engine's double-buffered one is held to
+        kw["pipeline_ticks"] = False
     return ContinuousEngine(
-        params, cfg, tok or tok0, decode_chunk=CHUNK, cache_mode=cache,
-        pipeline_ticks=pipe, prefill_chunk=PREFILL_CHUNK if chunked else 0, **kw)
+        params, cfg, tok or tok0, decode_chunk=chunk, cache_mode=cache,
+        prefill_chunk=PREFILL_CHUNK if chunked else 0, **kw)
 
 
 def _prompts(tok):
@@ -82,13 +86,17 @@ def _drain(q):
     return items
 
 
+# the serial order at 4 steps a program; the default engine at 4 and at 16
+TICKS = {"serial-4": dict(serial=True, chunk=4), "default-4": dict(serial=False, chunk=4),
+         "default-16": dict(serial=False, chunk=16)}
+over_ticks = pytest.mark.parametrize("ticks", TICKS.values(), ids=TICKS.keys())
+
 MODES = [
-    pytest.param(dict(cache=c, spec=s, pipe=p, chunked=k),
-                 id="-".join((c, "spec" if s else "plain",
-                              "pipelined" if p else "serial",
+    pytest.param(dict(cache=c, spec=s, chunked=k, **TICKS[t]),
+                 id="-".join((c, "spec" if s else "plain", t,
                               "chunked" if k else "whole")))
-    for c, s, p, k in itertools.product(
-        ("paged", "contiguous"), (False, True), (False, True), (False, True))
+    for c, s, t, k in itertools.product(
+        ("paged", "contiguous"), (False, True), TICKS, (False, True))
 ]
 
 
@@ -105,14 +113,22 @@ def mode_engine(request, setup, tmp_path_factory):
 
 def test_streamed_chunks_are_the_run_tokens(mode_engine, setup):
     """(a) Greedy and sampled: the chunks of a stream, concatenated, are the
-    tokens ``run()`` returns for the same prompt and seed without one."""
-    eng, _, _ = mode_engine
+    tokens ``run()`` returns for the same prompt and seed without one, and
+    what the serial engine of the same mode returns."""
+    eng, mode, _ = mode_engine
     prompts = _prompts(setup[2])
     kw = [dict(temperature=t, seed=s) for t, s in SAMPLING]
     rids = [eng.submit(p, **k) for p, k in zip(prompts, kw)]
     res = eng.run()
     golden = [res[r] for r in rids]
     assert all(golden), golden
+    if not mode["serial"] and mode["cache"] == "paged":
+        # (contiguous caches are held to the serial order, by the same kind of
+        # identity, in tests/test_pipeline_ticks.py)
+        held_to = _engine(setup, **{**mode, "serial": True})
+        rids = [held_to.submit(p, **k) for p, k in zip(prompts, kw)]
+        res = held_to.run()
+        assert [res[r] for r in rids] == golden
     qs = [queue.Queue() for _ in prompts]
     rids = [eng.submit(p, stream=q, **k) for p, q, k in zip(prompts, qs, kw)]
     res = eng.run()
@@ -124,15 +140,15 @@ def test_streamed_chunks_are_the_run_tokens(mode_engine, setup):
 
 def test_first_token_is_out_before_the_ticks_fetch(mode_engine, setup, monkeypatch):
     """(b) The step that finishes a request's prefill puts a one-token chunk
-    on its stream, and it is there when that tick's (or, pipelined, any
-    tick's) own fetch begins. A serial plain tick adds the rest of its row,
-    ``decode_chunk - 1`` tokens, in the same step; a pipelined one with the
-    next step."""
+    on its stream, and it is there when that tick's (or, double-buffered,
+    any tick's) own fetch begins. A serial plain tick adds the rest of its
+    row, ``decode_chunk - 1`` tokens, in the same step; the default engine
+    with the next step."""
     eng, mode, _ = mode_engine
     tok = setup[2]
     q = queue.Queue()
     seen_at_fetch = []
-    eng.step()  # idle; pipelined, it fetches the last run's trailing tick
+    eng.step()  # idle: nothing queued, and run() drained the trailing tick
     for name in ("_plain_finish", "_spec_finish"):
         finish = getattr(eng, name)
 
@@ -155,13 +171,15 @@ def test_first_token_is_out_before_the_ticks_fetch(mode_engine, setup, monkeypat
     after_step = list(q.queue)
     first = after_step[0]
     assert len(first) == 1
-    if mode["pipe"]:
+    if not mode["serial"]:
         assert after_step == [first] and not seen_at_fetch
         eng.step()
         after_step = list(q.queue)
+    if after_step[-1] is None:  # 16 steps a program: the row ended in its first
+        assert mode["chunk"] > MAX_NEW and after_step.pop() is None
     assert len(after_step) == 2
     if not mode["spec"]:
-        assert len(after_step[1]) == CHUNK - 1
+        assert len(after_step[1]) == min(mode["chunk"], MAX_NEW) - 1
     res = eng.run()
     (tokens,) = res.values()
     assert sum(_drain(q), []) == tokens
@@ -190,7 +208,7 @@ def test_counter_and_tick_span_count_the_first_tokens(mode_engine, setup):
 
 
 def _golden(setup, prompt, **kw):
-    eng = _engine(setup, **kw)
+    eng = _engine(setup, serial=True, **kw)
     rid = eng.submit(prompt)
     return eng.run()[rid]
 
@@ -242,22 +260,22 @@ def test_max_new_tokens_one(setup, tmp_path, cache):
     assert (row["outcome"], row["generated_tokens"]) == ("200", 1)
 
 
-@pytest.mark.parametrize("pipe", [False, True], ids=["serial", "pipelined"])
-def test_cancel_right_after_the_first_token(setup, tmp_path, pipe):
-    """Pipelined, the cancel falls between the early token and the harvest
+@over_ticks
+def test_cancel_right_after_the_first_token(setup, tmp_path, ticks):
+    """Double-buffered, the cancel falls between the early token and the harvest
     of its tick, which then skips the dead request: one terminal None, one
     usage row that bills what was sent, no slot or page left."""
     tok = setup[2]
     prompt = _prompts(tok)[2]
     want = _golden(setup, prompt)
-    eng, ledger = _ledgered(setup, tmp_path, pipe=pipe)
+    eng, ledger = _ledgered(setup, tmp_path, **ticks)
     q = queue.Queue()
     rid = eng.submit(prompt, stream=q)
     eng.step()
     sent = sum(q.queue, [])
-    assert sent == (want[:1] if pipe else want[:CHUNK])
+    assert sent == (want[:CHUNK] if ticks["serial"] else want[:1])
     assert eng.cancel(rid)
-    eng.step()  # pipelined: the cancelled request's tick is harvested here
+    eng.step()  # double-buffered: the cancelled request's tick is harvested here
     assert not eng.pending
     assert sum(_drain(q), []) == sent
     (row,) = _usage_rows(ledger, tmp_path)
@@ -279,7 +297,7 @@ def test_preempted_between_its_prefill_and_the_dispatch(setup):
     token is not sent early (the resume's tick emits it), and both streams
     are what an uncontended engine gives."""
     gen = GenerateConfig(max_new_tokens=24)
-    solo = _engine(setup, gen=gen)
+    solo = _engine(setup, gen=gen, serial=True)
     ra, rb = solo.submit(PROMPT_A), solo.submit(PROMPT_B)
     ref = solo.run()
     eng = _engine(setup, gen=gen, n_pages=5, admission="optimistic")
@@ -300,15 +318,15 @@ def test_preempted_between_its_prefill_and_the_dispatch(setup):
     assert eng.stats()["first_tokens_early_total"] == 1
 
 
-@pytest.mark.parametrize("pipe", [False, True], ids=["serial", "pipelined"])
-def test_preempt_then_resume_streams(setup, pipe):
+@over_ticks
+def test_preempt_then_resume_streams(setup, ticks):
     """A pool too small for both: the younger is preempted in flight, after
     its first token went out, and resumed. Nothing is sent twice."""
     gen = GenerateConfig(max_new_tokens=96)
-    solo = _engine(setup, gen=gen, max_cache_len=None)
+    solo = _engine(setup, gen=gen, max_cache_len=None, serial=True)
     ra, rb = solo.submit(PROMPT_A), solo.submit(PROMPT_B)
     ref = solo.run()
-    eng = _engine(setup, gen=gen, n_pages=10, admission="optimistic", pipe=pipe)
+    eng = _engine(setup, gen=gen, n_pages=10, admission="optimistic", **ticks)
     qa, qb = queue.Queue(), queue.Queue()
     a, b = eng.submit(PROMPT_A, stream=qa), eng.submit(PROMPT_B, stream=qb)
     res = eng.run()

@@ -1,12 +1,18 @@
-"""Double-buffered (pipelined) decode ticks: ``pipeline_ticks=True``
-dispatches tick N+1 before fetching tick N, so the host round trips overlap
-device compute. These tests pin the contract that makes that safe to turn
-on anywhere: outputs are TOKEN-IDENTICAL to serial ticks across every
-composition (slot reuse, chunked prefill, paged+int8 pools, speculative
-ticks, sampling, logprobs, streaming), and the one-tick harvest lag never
-leaks a dead request's garbage chunk (finished/cancelled snapshot guards).
+"""Double-buffered (pipelined) decode ticks, the engine's default and what
+the server runs: each step dispatches tick N+1 before fetching tick N, so the
+host's dispatch, fetch and harvest overlap device compute and the program can
+be short (``decode_chunk`` 4). These tests pin the contract that makes that
+safe everywhere: outputs of the DEFAULT engine are TOKEN-IDENTICAL to serial
+ticks (``pipeline_ticks=False``) at 4 and at 16 steps a program across every
+composition (slot reuse, chunked prefill, paged pools, a latent page pool, an
+expert layer and its counters, speculative ticks, sampling, logprobs,
+streaming), the one-tick harvest lag never leaks a dead request's garbage
+chunk (finished/cancelled snapshot guards), a request that arrives under a
+pending program is prefilled by the next step, and the counters that say the
+overlap engages and what it costs.
 """
 
+import dataclasses
 import queue as _queue
 
 import jax
@@ -17,6 +23,11 @@ from ditl_tpu.data.tokenizer import ByteTokenizer
 from ditl_tpu.infer.continuous import ContinuousEngine
 from ditl_tpu.infer.engine import GenerateConfig
 from ditl_tpu.models import llama
+from ditl_tpu.models.presets import get_preset
+from ditl_tpu.telemetry.journal import EventJournal, merge_journals
+from ditl_tpu.telemetry.tracing import Tracer
+
+CHUNKS = (4, 16)  # the serving default, and the 16-step tick it replaced
 
 
 @pytest.fixture(scope="module")
@@ -30,22 +41,50 @@ def setup():
     return params, cfg, ByteTokenizer()
 
 
-def _run_both(setup, prompts, *, submit_kw=None, **engine_kw):
-    """Generate with serial and pipelined engines; return (serial, piped)."""
+@pytest.fixture(scope="module")
+def moe_setup():
+    """OLMoE's shape in small: 16 narrow experts, 4 a token."""
+    cfg = ModelConfig(
+        name="olmoe-small", vocab_size=512, hidden_size=64, intermediate_size=32,
+        num_layers=2, num_heads=4, num_kv_heads=4, head_dim=16, max_seq_len=128,
+        qk_norm=True, num_experts=16, num_experts_per_tok=4, norm_topk_prob=False,
+        dtype="float32", param_dtype="float32", remat="none",
+    )
+    return llama.init_params(jax.random.key(1), cfg), cfg, ByteTokenizer()
+
+
+@pytest.fixture(scope="module")
+def latent_setup():
+    """LongCat-Flash's shape in small: latent attention through a latent page
+    pool (``kv_lora_rank > 0``) and a share of a wider expert layer."""
+    cfg = dataclasses.replace(
+        get_preset("longcat-flash"), vocab_size=512, hidden_size=64,
+        intermediate_size=128, expert_ffn_hidden_size=32, num_layers=2, num_heads=4,
+        num_kv_heads=4, head_dim=24, q_lora_rank=32, kv_lora_rank=32,
+        qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16, num_experts=32,
+        zero_expert_num=16, num_experts_per_tok=4, experts_held_first=8,
+        experts_held_count=8, max_seq_len=128, dtype="float32",
+    )
+    return llama.init_params(jax.random.key(2), cfg), cfg, ByteTokenizer()
+
+
+def _run_both(setup, prompts, chunk=4, *, submit_kw=None, **engine_kw):
+    """Generate with the serial engine and with the default one; returns
+    ((serial tokens, serial stats), (default tokens, default stats))."""
     params, cfg, tok = setup
     engine_kw.setdefault("n_slots", 2)
-    engine_kw.setdefault("decode_chunk", 4)
-    engine_kw.setdefault("gen", GenerateConfig(max_new_tokens=10))
+    engine_kw.setdefault("gen", GenerateConfig(max_new_tokens=20))
     outs = []
-    for pipeline in (False, True):
+    for order in (dict(pipeline_ticks=False), {}):
         eng = ContinuousEngine(
-            params, cfg, tok, pipeline_ticks=pipeline, **engine_kw
+            params, cfg, tok, decode_chunk=chunk, **order, **engine_kw
         )
+        assert eng.pipeline_ticks is not bool(order)
         rids = [
             eng.submit(p, **(submit_kw or {})) for p in prompts
         ]
         res = eng.run()
-        outs.append([res[r] for r in rids])
+        outs.append(([res[r] for r in rids], eng.stats()))
     return outs
 
 
@@ -56,55 +95,79 @@ PROMPTS = [
     [1, 2, 3],
     [1] + list(range(60, 75)),
 ]
+# Repetitive prompts: lookup speculation actually fires.
+REPETITIVE = [[1] + list(range(5, 13)) * 4, [1] + list(range(20, 28)) * 4]
+
+IDENTITY = {
+    "greedy-slot-reuse": dict(),
+    "sampled": dict(submit_kw=dict(temperature=0.8, top_p=0.9, seed=11)),
+    "chunked-prefill": dict(prefill_chunk=6),
+    "paged": dict(cache_mode="paged", page_size=16),
+    "speculative": dict(prompts=REPETITIVE, speculative=True, spec_threshold=0.0,
+                        gen=GenerateConfig(max_new_tokens=16)),
+}
 
 
-def test_pipelined_matches_serial_greedy_with_slot_reuse(setup):
-    serial, piped = _run_both(setup, PROMPTS)
-    assert piped == serial
+@pytest.mark.parametrize("chunk", CHUNKS)
+@pytest.mark.parametrize("case", IDENTITY)
+def test_default_engine_matches_serial(setup, case, chunk):
+    kw = dict(IDENTITY[case])
+    (serial, _), (default, stats) = _run_both(
+        setup, kw.pop("prompts", PROMPTS), chunk, **kw)
+    assert default == serial
     assert all(len(t) > 0 for t in serial)
+    assert stats["ticks_overlapped_total"] > 0 and stats["decode_chunk"] == chunk
 
 
-def test_pipelined_matches_serial_sampled(setup):
-    serial, piped = _run_both(
-        setup, PROMPTS,
-        submit_kw=dict(temperature=0.8, top_p=0.9, seed=11),
-    )
-    assert piped == serial
+@pytest.mark.parametrize("chunk", CHUNKS)
+@pytest.mark.parametrize("model", ["expert-layer", "latent-pool"])
+def test_default_engine_matches_serial_with_experts(request, model, chunk):
+    """A paged engine with an expert layer, and one with a latent page pool
+    besides: the tokens, and the counters that ride the lagged fetch (the
+    live rows' expert assignments, a prefill's counts waiting for the next
+    fetch, the context tokens the latent kernel read). A dead chunk's rows
+    are dead on the device too, so they count nothing."""
+    setup = request.getfixturevalue(
+        "moe_setup" if model == "expert-layer" else "latent_setup")
+    (serial, want), (default, got) = _run_both(
+        setup, PROMPTS, chunk, cache_mode="paged", page_size=16)
+    assert default == serial and all(serial)
+    keys = ["moe_assignments_total"]
+    if model == "latent-pool":
+        keys += ["decode_ctx_tokens", "moe_assign_held", "moe_assign_zero",
+                 "moe_assign_absent"]
+    assert want["moe_assignments_total"] > 0
+    assert {k: got[k] for k in keys} == {k: want[k] for k in keys}
+    assert got["dead_chunk_rows_total"] == len(PROMPTS)
+    assert want["dead_chunk_rows_total"] == want["ticks_overlapped_total"] == 0
 
 
-def test_pipelined_matches_serial_chunked_prefill(setup):
-    serial, piped = _run_both(setup, PROMPTS, prefill_chunk=6)
-    assert piped == serial
+def test_a_row_that_ended_on_eos_counts_nothing_in_its_dead_chunk(moe_setup):
+    """A row that ends by its token budget is dead in the next program by
+    ``limits``; one that ends on the end-of-text id is dead there because its
+    pending token is the pad. Either way the dead chunk adds nothing to the
+    expert counters, so they equal the serial engine's."""
+    import copy
+
+    params, cfg, tok = moe_setup
+    (golden, _), _ = _run_both(moe_setup, PROMPTS[:2], cache_mode="paged", page_size=16)
+    ends = copy.copy(tok)
+    ends.eos_id = golden[0][6]  # the first request now ends mid-program
+    (serial, want), (default, got) = _run_both(
+        (params, cfg, ends), PROMPTS[:2], cache_mode="paged", page_size=16)
+    assert default == serial and serial[0] == golden[0][:6]
+    assert got["moe_assignments_total"] == want["moe_assignments_total"] > 0
+    assert got["dead_chunk_rows_total"] == 2
 
 
-@pytest.mark.slow
-def test_pipelined_matches_serial_paged(setup):
-    serial, piped = _run_both(
-        setup, PROMPTS, cache_mode="paged", page_size=16,
-    )
-    assert piped == serial
-
-
-@pytest.mark.slow
-def test_pipelined_matches_serial_speculative(setup):
-    # Repetitive prompts: lookup speculation actually fires.
-    prompts = [[1] + list(range(5, 13)) * 4, [1] + list(range(20, 28)) * 4]
-    serial, piped = _run_both(
-        setup, prompts, speculative=True, spec_threshold=0.0,
-        gen=GenerateConfig(max_new_tokens=16),
-    )
-    assert piped == serial
-
-
-@pytest.mark.slow
-def test_pipelined_matches_serial_logprobs(setup):
+@pytest.mark.parametrize("chunk", CHUNKS)
+def test_default_engine_matches_serial_logprobs(setup, chunk):
     params, cfg, tok = setup
     outs = []
-    for pipeline in (False, True):
+    for order in (dict(pipeline_ticks=False), {}):
         eng = ContinuousEngine(
-            params, cfg, tok, n_slots=2, decode_chunk=4,
-            gen=GenerateConfig(max_new_tokens=8), logprobs_k=3,
-            pipeline_ticks=pipeline,
+            params, cfg, tok, n_slots=2, decode_chunk=chunk,
+            gen=GenerateConfig(max_new_tokens=8), logprobs_k=3, **order,
         )
         rids = [eng.submit(p, logprobs=2) for p in PROMPTS[:3]]
         done = {}
@@ -119,6 +182,84 @@ def test_pipelined_matches_serial_logprobs(setup):
     assert outs[0] == outs[1]
 
 
+def test_a_request_arriving_under_a_pending_program_is_prefilled_by_the_next_step(
+        setup, monkeypatch):
+    """The wait the short, double-buffered tick exists to bound: a request
+    submitted while a decode program is pending is admitted, and its prefill
+    dispatched, within two ``step()`` calls (the very next one, with a free
+    slot), and its first token is on its stream before that step's lagged
+    fetch of the pending program begins."""
+    params, cfg, tok = setup
+    eng = ContinuousEngine(params, cfg, tok, n_slots=2,
+                           gen=GenerateConfig(max_new_tokens=40))
+    assert eng.decode_chunk == 4 and eng.pipeline_ticks  # the defaults
+    eng.submit(PROMPTS[0])
+    eng.step()
+    eng.step()
+    assert eng._pending_fetch is not None  # a program is on the device
+    q: _queue.Queue = _queue.Queue()
+    seen_at_fetch = []
+    finish = eng._plain_finish
+
+    def spy(rec):
+        seen_at_fetch.append(list(q.queue))
+        return finish(rec)
+
+    monkeypatch.setattr(eng, "_plain_finish", spy)
+    admitted = eng.metrics.admitted.value
+    rid = eng.submit(PROMPTS[1], stream=q)
+    steps = 0
+    while q.empty():
+        eng.step()
+        steps += 1
+        assert steps <= 2
+    assert steps == 1 and eng.metrics.admitted.value == admitted + 1
+    assert not any(r.req_id == rid for r in eng._queue)
+    (first,) = list(q.queue)
+    assert len(first) == 1
+    # the step's one lagged fetch began with the token already out
+    assert seen_at_fetch == [[first]]
+    assert eng._pending_fetch is not None
+    eng.run()
+
+
+def test_overlap_and_dead_rows_on_the_spans_and_in_stats(setup, tmp_path):
+    """Every ``engine.tick`` span carries ``overlapped`` and ``dead_rows``;
+    they add up to ``ticks_overlapped_total`` / ``dead_chunk_rows_total`` of
+    ``/v1/stats``. Each finished request costs exactly one dead row (the
+    chunk its slot decoded before the lagged harvest freed it); the serial
+    order overlaps nothing and has no dead row."""
+    params, cfg, tok = setup
+    for order in (dict(pipeline_ticks=False), {}):
+        d = tmp_path / ("serial" if order else "default")
+        journal = EventJournal(str(d / "events-engine.jsonl"), source="engine")
+        eng = ContinuousEngine(params, cfg, tok, n_slots=2, tracer=Tracer(journal),
+                               gen=GenerateConfig(max_new_tokens=10), **order)
+        for p in PROMPTS[:3]:
+            eng.submit(p)
+        while eng.pending:
+            eng.step()
+        stats = eng.stats()
+        journal.close()
+        ticks = [r for r in merge_journals(str(d)) if r.get("name") == "engine.tick"]
+        assert ticks and all("overlapped" in r and "dead_rows" in r for r in ticks)
+        assert {r["overlapped"] for r in ticks} <= {0, 1}
+        assert sum(r["overlapped"] for r in ticks) == stats["ticks_overlapped_total"]
+        assert sum(r["dead_rows"] for r in ticks) == stats["dead_chunk_rows_total"]
+        if order:
+            assert stats["ticks_overlapped_total"] == stats["dead_chunk_rows_total"] == 0
+            continue
+        # the first step of a busy stretch has nothing to harvest yet
+        assert ticks[0]["overlapped"] == 0
+        assert stats["ticks_overlapped_total"] >= len(ticks) - 2
+        # two of three requests ended in harvested ticks; the last one's dead
+        # chunk is still pending, and going idle drains it
+        assert eng._pending_fetch is not None
+        eng.drain()
+        assert eng._pending_fetch is None
+        assert eng.stats()["dead_chunk_rows_total"] == 3
+
+
 def test_pipelined_streaming_chunks_and_sentinel(setup):
     """Streams deliver the same tokens (one tick later is fine) and exactly
     one terminal None; the lagged harvest must not double-fire either."""
@@ -128,7 +269,7 @@ def test_pipelined_streaming_chunks_and_sentinel(setup):
         eng = ContinuousEngine(
             params, cfg, tok, n_slots=2, decode_chunk=4,
             gen=GenerateConfig(max_new_tokens=10),
-            pipeline_ticks=pipeline,
+            **({} if pipeline else dict(pipeline_ticks=False)),
         )
         q: _queue.Queue = _queue.Queue()
         eng.submit(PROMPTS[0], stream=q)
@@ -152,12 +293,12 @@ def test_pipelined_cancel_mid_flight(setup):
     params, cfg, tok = setup
     gen = GenerateConfig(max_new_tokens=24)
     ref = ContinuousEngine(params, cfg, tok, n_slots=2, decode_chunk=4,
-                           gen=gen)
+                           gen=gen, pipeline_ticks=False)
     keep_ref = ref.submit(PROMPTS[0])
     expected = ref.run()[keep_ref]
 
     eng = ContinuousEngine(params, cfg, tok, n_slots=2, decode_chunk=4,
-                           gen=gen, pipeline_ticks=True)
+                           gen=gen)
     keep = eng.submit(PROMPTS[0])
     q: _queue.Queue = _queue.Queue()
     victim = eng.submit(PROMPTS[2], stream=q)
@@ -181,7 +322,7 @@ def test_pipelined_cancel_mid_flight(setup):
 def ref_single(setup, prompt, gen):
     params, cfg, tok = setup
     eng = ContinuousEngine(params, cfg, tok, n_slots=2, decode_chunk=4,
-                           gen=gen)
+                           gen=gen, pipeline_ticks=False)
     rid = eng.submit(prompt)
     return eng.run()[rid]
 
@@ -195,7 +336,7 @@ def test_pipelined_self_calibrates_spec_threshold(setup):
     threshold_source=="measured"; double-buffering then re-engages."""
     params, cfg, tok = setup
     eng = ContinuousEngine(
-        params, cfg, tok, n_slots=2, decode_chunk=4, pipeline_ticks=True,
+        params, cfg, tok, n_slots=2, decode_chunk=4,
         speculative=True, gen=GenerateConfig(max_new_tokens=16),
     )
     rids = [eng.submit([1] + list(range(5, 25))),
@@ -217,9 +358,8 @@ def test_pipelined_spec_auto_threshold_greedy_identity(setup):
     """Self-calibration must not change greedy tokens: spec and plain ticks
     are bit-exact for greedy rows, so however the warmup and the measured
     threshold steer tick choices, outputs match the serial engine."""
-    prompts = [[1] + list(range(5, 13)) * 4, [1] + list(range(20, 28)) * 4]
-    serial, piped = _run_both(
-        setup, prompts, speculative=True,
+    (serial, _), (piped, _) = _run_both(
+        setup, REPETITIVE, speculative=True,
         gen=GenerateConfig(max_new_tokens=16),
     )
     assert piped == serial
@@ -231,7 +371,7 @@ def test_frozen_threshold_skips_probe_warmup(setup):
     pod's lockstep cadence) — the first dispatched tick is pipelined."""
     params, cfg, tok = setup
     eng = ContinuousEngine(
-        params, cfg, tok, n_slots=2, decode_chunk=4, pipeline_ticks=True,
+        params, cfg, tok, n_slots=2, decode_chunk=4,
         speculative=True, gen=GenerateConfig(max_new_tokens=8),
     )
     eng.freeze_spec_threshold()

@@ -12,6 +12,7 @@ may load the TPU's library at a time (``on-chip-measurement`` guide).
 
 from __future__ import annotations
 
+import inspect
 import math
 import os
 import re
@@ -21,9 +22,17 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from ditl_tpu.infer.continuous import ContinuousEngine, tail_width
 from ditl_tpu.models import moe as moe_mod
 from ditl_tpu.models.presets import get_preset
 from ditl_tpu.ops import names
+
+# The tail widths the engines build: the serving cells' (the constructor's
+# default ``decode_chunk``, a 4-step program in an 8-column tail) and
+# ``benchmarks/paged_check.py``'s 16-step ticks.
+_CHUNK = inspect.signature(ContinuousEngine).parameters["decode_chunk"].default
+over_tails = pytest.mark.parametrize(
+    "tail", [tail_width(_CHUNK), tail_width(16)], ids=[f"tick-{_CHUNK}", "tick-16"])
 
 
 @pytest.fixture(scope="module")
@@ -82,11 +91,12 @@ def test_expert_layer_backward_compiles_at_olmoe_widths(one_chip, tpu_branch):
     assert set(names.MOE_KERNELS) <= _instructions(compiled.as_text())
 
 
-def test_paged_decode_kernel_compiles_with_one_query_head_a_kv_head(one_chip, tpu_branch):
+@over_tails
+def test_paged_decode_kernel_compiles_with_one_query_head_a_kv_head(one_chip, tpu_branch, tail):
     from ditl_tpu.ops.paged_attention import paged_attention
 
     cfg = get_preset("olmoe-1b-7b")
-    b, h, hd, ps, pages, maxp, tail = 64, cfg.num_heads, cfg.head_dim, 256, 192, 16, 16
+    b, h, hd, ps, pages, maxp = 64, cfg.num_heads, cfg.head_dim, 256, 192, 16
     assert cfg.num_heads == cfg.num_kv_heads
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
     args = (s((b, h, hd), jnp.bfloat16), s((pages, h, ps, hd), jnp.bfloat16),
@@ -100,14 +110,16 @@ def test_paged_decode_kernel_compiles_with_one_query_head_a_kv_head(one_chip, tp
     assert "paged_attention" in _instructions(compiled.as_text())
 
 
+@over_tails
 @pytest.mark.parametrize(
     "preset, layers, pages",
     [("qwen2-7b", 12, 720), ("olmoe-1b-7b", 10, 192)],
     ids=["qwen2-7b-cut1", "olmoe-1b-7b-cut1"],
 )
-def test_paged_decode_layer_loop_copies_no_pool(one_chip, tpu_branch, preset, layers, pages):
+def test_paged_decode_layer_loop_copies_no_pool(one_chip, tpu_branch, preset, layers, pages,
+                                                tail):
     """The cached layer loop of one paged decode step at the two serving
-    cells' shapes (64 slots, pages of 256, tail 16). The kernel is a custom
+    cells' shapes (64 slots, pages of 256, either tail). The kernel is a custom
     call, so a pool that the loop slices by layer is COPIED in front of it
     (``dynamic-slice_bitcast_fusion.8/.9``, 360 MiB of temporaries, before
     PR 27). Whole pools addressed through the page table leave no
@@ -120,7 +132,7 @@ def test_paged_decode_layer_loop_copies_no_pool(one_chip, tpu_branch, preset, la
     params = jax.tree.map(
         lambda a: s(a.shape, a.dtype),
         jax.eval_shape(lambda: llama.init_params(jax.random.key(0), cfg)))
-    b, ps, tail, maxp = 64, 256, 16, 16
+    b, ps, maxp = 64, 256, 16
     kv, hd = cfg.num_kv_heads, cfg.head_dim
     pool = s((layers, pages, kv, ps, hd), jnp.bfloat16)
     tails = s((layers, b, kv, tail, hd), jnp.bfloat16)
@@ -149,14 +161,15 @@ def test_paged_decode_layer_loop_copies_no_pool(one_chip, tpu_branch, preset, la
     assert compiled.memory_analysis().temp_size_in_bytes < layer_pool_bytes / 10
 
 
+@over_tails
 @pytest.mark.parametrize(
     "layers, pages, kv",
     [(12, 720, 4), (10, 192, 16)],
     ids=["qwen2-7b-cut1", "olmoe-1b-7b-cut1"],
 )
-def test_paged_flush_copies_no_pool(one_chip, tpu_branch, layers, pages, kv):
+def test_paged_flush_copies_no_pool(one_chip, tpu_branch, layers, pages, kv, tail):
     """The tick's flush of its tail into the donated page pools at the two
-    serving cells' shapes (64 slots, pages of 256, tail 16, heads of 128). As
+    serving cells' shapes (64 slots, pages of 256, either tail, heads of 128). As
     an XLA scatter with a window of ``(L, K, D)`` (``pool.at[:, pid, :,
     off]``, before PR 29) it had the TPU compiler transpose each WHOLE pool
     to another layout in front of the scatter and back behind it: four
@@ -166,7 +179,7 @@ def test_paged_flush_copies_no_pool(one_chip, tpu_branch, layers, pages, kv):
     itself."""
     from ditl_tpu.infer.continuous import _flush_tail_into_pools
 
-    b, ps, tail, maxp, hd = 64, 256, 16, 16, 128
+    b, ps, maxp, hd = 64, 256, 16, 128
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
     pool = s((layers, pages, kv, ps, hd), jnp.bfloat16)
     tails = s((layers, b, kv, tail, hd), jnp.bfloat16)
@@ -188,14 +201,15 @@ def test_paged_flush_copies_no_pool(one_chip, tpu_branch, layers, pages, kv):
     assert mem.alias_size_in_bytes == 2 * pool_elements * 2  # both pools in place
 
 
-def test_latent_decode_kernel_compiles_at_the_longcat_cells_shapes(one_chip):
+@over_tails
+def test_latent_decode_kernel_compiles_at_the_longcat_cells_shapes(one_chip, tail):
     """``mla_paged_attention`` as ``longcat-flash-cut1.chat-wide-mla`` runs it:
     128 slots, 64 heads against ONE 640-wide entry a token (512 of it the
     value), pages of 256 in a pool of 8 sublayers x 1,280 pages addressed as
-    one, 16 pages a slot and a 16-column tail."""
+    one, 16 pages a slot and the tick's tail."""
     from ditl_tpu.ops.mla_attention import mla_paged_attention
 
-    b, h, dl, vw, ps, pages, maxp, tail = 128, 64, 640, 512, 256, 8 * 1280, 16, 16
+    b, h, dl, vw, ps, pages, maxp = 128, 64, 640, 512, 256, 8 * 1280, 16
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
     args = (s((b, h, dl), jnp.bfloat16), s((pages, ps, dl), jnp.bfloat16),
             s((b, maxp), jnp.int32), s((b,), jnp.int32), s((b, tail, dl), jnp.bfloat16),
@@ -208,14 +222,15 @@ def test_latent_decode_kernel_compiles_at_the_longcat_cells_shapes(one_chip):
     assert names.MLA_KERNELS[0] in _instructions(compiled.as_text())
 
 
-def test_latent_flush_copies_no_pool(one_chip, tpu_branch):
+@over_tails
+def test_latent_flush_copies_no_pool(one_chip, tpu_branch, tail):
     """The tick's flush of its latent tails (4 layers x 2 sublayers) into the
     donated latent pool at the longcat cell's shapes: the same ``kv_flush``
     kernel over one pool with one head, a bitcast of the pool on the way in
     and out, and nothing of the pool's size produced but the custom call."""
     from ditl_tpu.infer.continuous import _flush_latent_tail
 
-    b, ps, tail, maxp, dl, pages = 128, 256, 16, 16, 640, 1280
+    b, ps, maxp, dl, pages = 128, 256, 16, 640, 1280
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
     row = s((b,), jnp.int32)
     compiled = jax.jit(_flush_latent_tail, donate_argnums=(0,)).lower(
