@@ -550,14 +550,19 @@ class ContinuousEngine:
         self.usage_ledger = usage_ledger
         if usage is not None:
             usage.bind(self.metrics.registry)
-        # Per-tick prefill work [(req_id, tokens, wall_s)] — the
-        # interference-attribution input (see step()).
-        self._tick_prefills: list[tuple[int, int, float]] = []
+        # Per-tick prefill work [(req_id, tokens, wall_s, padded tokens)]
+        # in enqueue order — the interference-attribution input (see
+        # step()), and what an armed tracer's ``engine.prefill`` and first
+        # ``engine.decode`` spans say a first token queued behind.
+        self._tick_prefills: list[tuple[int, int, float, int]] = []
         # Per-tick first tokens [(request, token, logprob stats or None)]:
         # what this tick's prefills sampled, still on the device until
         # _send_first_tokens fetches them behind the decode dispatch.
         self._tick_firsts: list[tuple[Request, Any, Any]] = []
         self.first_tokens_early = 0
+        # Whether this step began with a decode program dispatched and not
+        # yet fetched: what ``engine.prefill`` spans say as ``decode_queued``.
+        self._decode_queued = False
         if n_slots < 1:
             raise ValueError(f"n_slots must be >= 1, got {n_slots}")
         if decode_chunk < 1:
@@ -2450,13 +2455,14 @@ class ContinuousEngine:
                 )
 
     def _prefill_into_slot(self, req: Request, slot: int, rng,
-                           prefix) -> jax.Array | None:
+                           prefix) -> tuple[jax.Array | None, int]:
         """Fill the slot's cache for ``req``'s prompt and return the first
-        sampled token. ``prefix`` is the caller's ``_match_prefix`` result
-        (``_admit`` already computed it for the token-budget gate — one
-        scan per admission, not two). Uses the matched prefix's KV when
+        sampled token with the padded length the prefill program ran at
+        (0: no program ran). ``prefix`` is the caller's ``_match_prefix``
+        result (``_admit`` already computed it for the token-budget gate —
+        one scan per admission, not two). Uses the matched prefix's KV when
         present (seed copy + suffix-only prefill), else the full prefill
-        program. Returns ``None`` when chunked prefill takes over (the
+        program. The token is ``None`` when chunked prefill takes over (the
         request finishes prefilling across subsequent ticks, see
         ``_advance_prefill``)."""
         d0 = 0 if prefix is None else prefix[2]
@@ -2472,7 +2478,7 @@ class ContinuousEngine:
                 )
             req.prefill_pos = d0
             req.prefilling = True
-            return None
+            return None, 0
         if prefix is None:
             p_bucket = min(_next_pow2(len(req.prompt), floor=16), self.smax)
             if p_bucket not in self._prefill_cache:
@@ -2486,7 +2492,7 @@ class ContinuousEngine:
                 jnp.float32(req.temperature), jnp.float32(req.top_p), rng,
                 jnp.asarray([req.adapter_id], jnp.int32),
                 *self._fsm_args(req.fsm_start),
-            ), slot)
+            ), slot), p_bucket
         row, last_logits, d = prefix
         p_bucket = row["k"].shape[2]
         if p_bucket not in self._seed_cache:
@@ -2524,8 +2530,8 @@ class ContinuousEngine:
             if self.logprobs_k:
                 first, c, i, t = out
                 self._store_lp(slot, c, i, t)
-                return first
-            return out[0] if self.guided else out
+                return first, 0
+            return (out[0] if self.guided else out), 0
         s_bucket = min(_next_pow2(s, floor=16), self.smax - d)
         if s_bucket not in self._suffix_prefill:
             logger.info("compiling suffix prefill for bucket %d", s_bucket)
@@ -2538,7 +2544,7 @@ class ContinuousEngine:
             jnp.float32(req.top_p), rng,
             jnp.asarray([req.adapter_id], jnp.int32),
             *self._fsm_args(req.fsm_start),
-        ), slot)
+        ), slot), s_bucket
 
     def _chunk_bucket(self, d: int, s: int) -> int:
         """Write-window bucket for a prefill chunk of ``s`` tokens at offset
@@ -2549,17 +2555,18 @@ class ContinuousEngine:
             return self.prefill_chunk
         return min(_next_pow2(s, floor=16), self.smax - d)
 
-    def _advance_prefill(self, req: Request) -> None:
+    def _advance_prefill(self, req: Request) -> int:
         """One chunk of a chunked prefill (reuses the suffix-prefill program —
         a chunk IS a suffix continuation at offset ``prefill_pos``). The
         final chunk's sample becomes the request's first token, and the slot
         key is (re)derived from the request seed so sampling stays
-        reproducible no matter how many decode ticks ran while parked."""
+        reproducible no matter how many decode ticks ran while parked.
+        Returns the padded length the chunk's program ran at."""
         if self.cache_mode == "paged":
             d = req.prefill_pos
             s = min(self.prefill_chunk, len(req.prompt) - d)
             slot_key, sub = jax.random.split(jax.random.key(req.seed))
-            first = self._paged_prefill_chunk(
+            first, bucket = self._paged_prefill_chunk(
                 req, req.slot, d, s, self.prefill_chunk, sub
             )
             req.prefill_pos += s
@@ -2568,7 +2575,7 @@ class ContinuousEngine:
                 self._publish_prompt_pages(req, req.slot)
                 self.keys = self.keys.at[req.slot].set(slot_key)
                 self._seat_first(req, req.slot, first)
-            return
+            return bucket
         d = req.prefill_pos
         s = min(self.prefill_chunk, len(req.prompt) - d)
         s_bucket = self._chunk_bucket(d, s)
@@ -2590,6 +2597,7 @@ class ContinuousEngine:
             req.prefilling = False
             self.keys = self.keys.at[req.slot].set(slot_key)
             self._seat_first(req, req.slot, first)
+        return s_bucket
 
     def _take_prefill(self, out, slot: int | None):
         """Unpack a prefill program's outputs: store the new cache and —
@@ -3021,7 +3029,8 @@ class ContinuousEngine:
                            rng, slot: int | None = None, adapter: int = 0,
                            fsm_start: int = 0):
         """Compile-on-miss + call of the (s_bucket, ctx_pages) prefill
-        program — the one shared path for slot prefills and page warming."""
+        program — the one shared path for slot prefills and page warming.
+        Returns (first token, the padded length the program ran at)."""
         ps, maxp = self.page_size, self.maxp
         s_bucket = min(_next_pow2(max(s_bucket, ps), floor=ps), maxp * ps)
         ctx = self._ctx_pages_bucket(d)
@@ -3057,11 +3066,12 @@ class ContinuousEngine:
             self._moe_pending.append(counts)
             if len(self._moe_pending) > 64:  # no plain tick drains them
                 self._moe_pending = [sum(self._moe_pending)]
-        return self._take_prefill(out, slot)
+        return self._take_prefill(out, slot), s_bucket
 
     def _paged_prefill_chunk(self, req: Request, slot: int, d: int, s: int,
                              s_bucket: int, rng):
-        """Run one paged prefill program call over prompt[d:d+s]."""
+        """Run one paged prefill program call over prompt[d:d+s]: (first
+        token, padded length), as ``_run_paged_prefill`` returns them."""
         ps = self.page_size
         return self._run_paged_prefill(
             req.prompt[d: d + s], d, s, s_bucket,
@@ -3170,9 +3180,9 @@ class ContinuousEngine:
         else:
             was = self._phase("engine.tick.prefill")
             w0, m0 = time.time(), time.monotonic()
-            first = self._paged_prefill_chunk(req, slot, d0, s, s, sub)
+            first, bucket = self._paged_prefill_chunk(req, slot, d0, s, s, sub)
             self._record_prefill(req, s, d0, w0,
-                                 time.monotonic() - m0, "prompt")
+                                 time.monotonic() - m0, "prompt", bucket)
             self._phase(was)
             self._publish_prompt_pages(req, slot)
             self._seat_first(req, slot, first)
@@ -3247,22 +3257,23 @@ class ContinuousEngine:
         d = d0
         was = self._phase("engine.tick.prefill")
         w0, m0 = time.time(), time.monotonic()
+        padded = 0
         while d < pos:
             n = min(step, pos - d)
-            self._run_paged_prefill(
+            padded += self._run_paged_prefill(
                 ctx[d: d + n], d, n, n,
                 ctx_row=self._table[slot],
                 write_pids=self._table[slot, d // ps:],
                 temp=req.temperature, top_p=req.top_p,
                 rng=jax.random.key(req.seed), slot=slot,
                 adapter=req.adapter_id, fsm_start=req.fsm_start,
-            )
+            )[1]
             d += n
         if pos > d0:
             # Resume prefills monopolize ticks exactly like fresh ones —
             # they must show up in the interference attribution too.
             self._record_prefill(req, pos - d0, d0, w0,
-                                 time.monotonic() - m0, "resume")
+                                 time.monotonic() - m0, "resume", padded)
         self._phase(was)
         self.cur = self.cur.at[slot].set(req.preempt_cur)
         self.pos = self.pos.at[slot].set(pos)
@@ -3672,12 +3683,13 @@ class ContinuousEngine:
         )
 
     def _record_prefill(self, req: Request, tokens: int, offset: int,
-                        w0: float, dt: float, kind: str) -> None:
-        """Register one prefill dispatch: feeds this tick's interference
-        attribution (step()), debits the tick's token-budget allowance,
-        and — when tracing — writes the chunk's span under the request's
-        lifecycle span."""
-        self._tick_prefills.append((req.req_id, tokens, dt))
+                        w0: float, dt: float, kind: str, bucket: int) -> None:
+        """Register one prefill dispatch (``bucket``: the padded length its
+        program ran at, a resume's chunks summed): feeds this tick's
+        interference attribution (step()), debits the tick's token-budget
+        allowance, and — when tracing — writes the chunk's span under the
+        request's lifecycle span."""
+        self._tick_prefills.append((req.req_id, tokens, dt, bucket))
         self._tick_prefill_spent += tokens
         if self._tick_prefill_left is not None:
             self._tick_prefill_left = max(0, self._tick_prefill_left - tokens)
@@ -3695,9 +3707,17 @@ class ContinuousEngine:
             self.usage.note_prefill(req.tenant, tokens)
             self.usage.note_device(req.tenant, dt)
         if req.request_span is not None:
+            # Besides the dispatch wall: the step, the padded length the
+            # program ran at, and what was enqueued on the device in front
+            # of it (this step's earlier prefills; the decode program the
+            # step before dispatched, which no one has fetched yet).
+            ahead = self._tick_prefills[:-1]
             self.tracer.start_span(
                 "engine.prefill", parent=req.request_span, t0=w0,
                 req=req.req_id, offset=offset, tokens=tokens, kind=kind,
+                tick=self.tick_count, bucket=bucket, ahead=len(ahead),
+                ahead_tokens=sum(e[3] for e in ahead),
+                decode_queued=int(self._decode_queued),
             ).end(t_end=w0 + dt)
 
     def _admit(self) -> None:
@@ -3736,7 +3756,7 @@ class ContinuousEngine:
             req.slot = slot
             was = self._phase("engine.tick.prefill")
             w0, m0 = time.time(), time.monotonic()
-            first = self._prefill_into_slot(req, slot, sub, prefix)
+            first, bucket = self._prefill_into_slot(req, slot, sub, prefix)
             self._phase(was)
             if first is not None:
                 # Chunked prefill (first is None) records per chunk in
@@ -3746,7 +3766,7 @@ class ContinuousEngine:
                 # gate above charged only `s` against).
                 self._record_prefill(
                     req, s, d0, w0,
-                    time.monotonic() - m0, "prompt",
+                    time.monotonic() - m0, "prompt", bucket,
                 )
             self._slots[slot] = req
             if first is None:
@@ -3777,10 +3797,10 @@ class ContinuousEngine:
             d_before = req.prefill_pos
             was = self._phase("engine.tick.prefill")
             w0, m0 = time.time(), time.monotonic()
-            self._advance_prefill(req)
+            bucket = self._advance_prefill(req)
             self._record_prefill(
                 req, req.prefill_pos - d_before, d_before, w0,
-                time.monotonic() - m0, "chunk",
+                time.monotonic() - m0, "chunk", bucket,
             )
             self._phase(was)
 
@@ -3821,7 +3841,9 @@ class ContinuousEngine:
         firsts = [f for f in firsts if not f[0].preempted]
         sent = 0
         if firsts:
+            traced = self._tick_span is not None
             self._phase("engine.tick.fetch")
+            m0 = time.monotonic() if traced else 0.0
             fetched = jax.device_get([(tok, lp) for _, tok, lp in firsts])
             self._phase("engine.tick.harvest")
             eos, pad = self.tokenizer.eos_id, self.tokenizer.pad_id
@@ -3836,10 +3858,28 @@ class ContinuousEngine:
                 if lp is not None:
                     req.note_logprobs(*lp)
                 sent += 1
-                self._deliver(req, [tok], t_now, n_share)
+                self._deliver(
+                    req, [tok], t_now, n_share,
+                    self._first_attrs(req, len(firsts), t_now - m0)
+                    if traced else None,
+                )
         self.first_tokens_early += sent
         if self._tick_span is not None:
             self._tick_span.annotate(first_tokens=sent)
+
+    def _first_attrs(self, req: Request, shared: int, fetch_wait_s: float) -> dict:
+        """What a request's first ``engine.decode`` span says of the fetch
+        that delivered its token (armed tracer only): the step, how long
+        the ``device_get`` blocked, the requests that shared it, and the
+        padded tokens of the prefills enqueued BEHIND this request's own in
+        the step, which the shared fetch made it wait for."""
+        behind = 0
+        for rid, _, _, bucket in reversed(self._tick_prefills):
+            if rid == req.req_id:
+                break
+            behind += bucket
+        return {"tick": self.tick_count, "fetch_wait_s": round(fetch_wait_s, 6),
+                "shared": shared, "behind_tokens": behind}
 
     def _harvest(self, emitted: np.ndarray, counts: np.ndarray | None = None,
                  lp=None, snapshot=None) -> None:
@@ -3909,7 +3949,7 @@ class ContinuousEngine:
                         self._free_slot_pages(slot)
 
     def _deliver(self, req: Request, fresh: list[int], t_now: float,
-                 n_share: int) -> None:
+                 n_share: int, first_attrs: dict | None = None) -> None:
         """Account for ``fresh``, the tokens just appended to ``req.tokens``
         (their stats to its logprob lists, if it asked for them: submit
         refuses that on an engine without ``logprobs_k``), and put them on
@@ -3924,7 +3964,9 @@ class ContinuousEngine:
         device-time estimate (the prefill half is measured per dispatch in
         _record_prefill). An estimate by construction (host wall, pipelined
         ticks overlap dispatch); consistent ACROSS tenants, which is what
-        billing shares and convictions need."""
+        billing shares and convictions need. ``first_attrs``: further
+        attributes of the first chunk's ``engine.decode`` span
+        (``_first_attrs``)."""
         m = self.metrics
         m.tokens_generated.inc(len(fresh))
         if self.cache_mode == "paged":
@@ -3971,6 +4013,8 @@ class ContinuousEngine:
             dur = max(0.0, t_now - prev) if prev else 0.0
             attrs = {"req": req.req_id, "tokens": len(fresh),
                      "first": first_chunk}
+            if first_attrs:
+                attrs.update(first_attrs)
             if req.interference_pending:
                 cid, ctok, _ = max(req.interference_pending,
                                    key=lambda e: e[2])
@@ -4485,6 +4529,7 @@ class ContinuousEngine:
             # interval times a quiet device, not the tail of tick N.
             self._finish_tick(prev)
             prev = None
+        self._decode_queued = prev is not None
         self._phase("engine.tick.schedule")
         self._expire_deadlines()
         # Interference attribution (ISSUE 6): requests that were ALREADY
@@ -4524,13 +4569,13 @@ class ContinuousEngine:
             r for r in self._slots
             if r is not None and r.prefilling and id(r) not in seen
         ])
-        prefill_s = sum(dt for _, _, dt in self._tick_prefills)
+        prefill_s = sum(e[2] for e in self._tick_prefills)
         if self._tick_prefills and prefill_s > 0 and decode_ready:
             # One histogram observation per victim per tick (the aggregate
             # answer "how much decode delay is prefill causing"), plus a
             # per-victim annotation naming the biggest culprit — consumed
             # by the next harvest's decode span.
-            culprit_id, culprit_tokens, _ = max(
+            culprit_id, culprit_tokens, *_ = max(
                 self._tick_prefills, key=lambda e: e[2]
             )
             self.interference_max_s = max(self.interference_max_s, prefill_s)

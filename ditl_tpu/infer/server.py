@@ -360,6 +360,10 @@ class _Handler(KeepAliveHandlerMixin, BaseHTTPRequestHandler):
     def log_message(self, *args):  # route through our logger, not stderr
         logger.debug("http: " + args[0], *args[1:])
 
+    def send_response(self, code, message=None):
+        self._status = code  # the ``server.request`` span's ``status``
+        super().send_response(code, message)
+
     def _request_id(self) -> str:
         """Stable per-request id: the client's sanitized ``X-Request-Id``
         or a generated one — echoed on EVERY response (success, 429, 504,
@@ -839,6 +843,10 @@ class _Handler(KeepAliveHandlerMixin, BaseHTTPRequestHandler):
     def do_POST(self):
         self._rid = None  # fresh id per request on keep-alive connections
         self._adapter_fp = None  # set by _resolve_adapter per request
+        # An armed tracer's ``server.request`` span starts HERE, before the
+        # body is read, and ends with the status the response went out with.
+        self._t_entry = time.time() if self.tracer.armed else None
+        self._status = None
         try:
             length = int(self.headers.get("Content-Length", 0))
             raw = self.rfile.read(length)
@@ -1063,8 +1071,10 @@ class _Handler(KeepAliveHandlerMixin, BaseHTTPRequestHandler):
                 [prompt_ids], gen, adapter_ids
             )[0]
 
-    def _send_sse(self, events) -> None:
+    def _send_sse(self, events, span=None) -> None:
         """Stream pre-serialized JSON events as Server-Sent Events.
+        ``span`` (an armed tracer's ``server.request``): gets ``events``,
+        the events flushed to the socket.
 
         A client that vanishes mid-stream (broken pipe / reset on write)
         CANCELS the in-flight generation deterministically: closing the
@@ -1083,10 +1093,12 @@ class _Handler(KeepAliveHandlerMixin, BaseHTTPRequestHandler):
         # also flips the stdlib's close_connection for us (ISSUE 14).
         self.send_header("Connection", "close")
         self.end_headers()
+        n = 0
         try:
             for event in events:
                 self.wfile.write(f"data: {json.dumps(event)}\n\n".encode())
                 self.wfile.flush()
+                n += 1
             self.wfile.write(b"data: [DONE]\n\n")
             self.wfile.flush()
         except OSError:  # BrokenPipeError/ConnectionError are subclasses
@@ -1098,6 +1110,8 @@ class _Handler(KeepAliveHandlerMixin, BaseHTTPRequestHandler):
             )
         finally:
             events.close()
+            if span is not None:
+                span.annotate(events=n)
 
     def _multi_complete(
         self, payload: dict, prompt: str, gen, *, chat: bool, n: int,
@@ -1470,6 +1484,19 @@ class _Handler(KeepAliveHandlerMixin, BaseHTTPRequestHandler):
                     tenant=tenant,
                 )
 
+        # An armed tracer's span gets ``first_write_s``: the SSE writer
+        # resumes the generator below once the event it yielded is flushed,
+        # so the instant after the first yield that carried a token is when
+        # that token was on the socket.
+        span = trace if self.tracer.armed else None
+        pending = span is not None
+
+        def flushed():
+            nonlocal pending
+            if pending:
+                pending = False
+                span.annotate(first_write_s=round(time.time() - span.t0, 6))
+
         def events():
             if chat:
                 yield event("", role="assistant")  # role-announcement chunk
@@ -1512,6 +1539,7 @@ class _Handler(KeepAliveHandlerMixin, BaseHTTPRequestHandler):
                             "text_offset": offsets,
                         }
                     yield event("".join(tok_strs), logprobs=lpj)
+                    flushed()
             elif stream_iter is not None:
                 tok = self.threaded_engine.tokenizer
                 for chunk in stream_iter:
@@ -1519,11 +1547,13 @@ class _Handler(KeepAliveHandlerMixin, BaseHTTPRequestHandler):
                     text = tracker.push(tok.decode(chunk))
                     if text:
                         yield event(text)
+                        flushed()
                     if tracker.hit:
                         break  # stream_one cancels the abandoned request
                 tail = tracker.flush()
                 if tail:
                     yield event(tail)
+                    flushed()
             else:
                 # The lock-step stream generates fully before emitting, so
                 # greedy streamed requests benefit from speculation the same
@@ -1544,6 +1574,7 @@ class _Handler(KeepAliveHandlerMixin, BaseHTTPRequestHandler):
                     tracker.hit = True
                 if text:
                     yield event(text)
+                    flushed()
             finish = (
                 "stop"
                 if tracker.hit or n_gen < gen.max_new_tokens
@@ -1551,7 +1582,7 @@ class _Handler(KeepAliveHandlerMixin, BaseHTTPRequestHandler):
             )
             yield event("", finish=finish)
 
-        self._send_sse(events())
+        self._send_sse(events(), span)
 
     def _complete(self, payload: dict, *, chat: bool) -> None:
         # Request tracing (ISSUE 6): continue the client's/gateway's trace
@@ -1559,10 +1590,16 @@ class _Handler(KeepAliveHandlerMixin, BaseHTTPRequestHandler):
         # spans chain under this span via submit(trace=...), so the merged
         # timeline nests gateway -> server -> engine across processes. The
         # span also covers the stream-write leg (SSE chunks relay inside
-        # _stream_complete before this method returns).
+        # _stream_complete before this method returns). An armed tracer's
+        # span starts at the handler's entry (do_POST) and carries the
+        # flush of a streamed request's first token on this clock
+        # (``first_write_s``, _stream_complete; the submit is the start of
+        # the ``engine.queue`` span under it), with ``events`` (_send_sse)
+        # and ``status`` at its end.
         span = self.tracer.start_span(
             "server.request",
             parent=parse_traceparent(self.headers.get("traceparent")),
+            t0=self._t_entry,
             request_id=self._request_id(),
             route="chat" if chat else "completions",
         )
@@ -2062,6 +2099,8 @@ class _Handler(KeepAliveHandlerMixin, BaseHTTPRequestHandler):
             logger.exception("completion failed")
             self._send_json(500, {"error": {"message": str(e)}})
         finally:
+            if self.tracer.armed:
+                span.annotate(status=self._status)
             span.end()
 
 

@@ -385,6 +385,17 @@ def render_markdown() -> str:
     lines.append(f"{len(CATALOG)} families "
                  f"({sum(1 for e in CATALOG if not e.optional)} required, "
                  f"{sum(1 for e in CATALOG if e.optional)} optional).")
+    lines += [
+        "",
+        "## Span attributes",
+        "",
+        "No `/metrics` family carries a span's attributes: an armed tracer "
+        "(`--trace-dir`) writes them to the span journal. Those of a "
+        "request's first token (`first_write_s`, `bucket`, `ahead_tokens`, "
+        "`behind_tokens`, `fetch_wait_s` and the rest) are defined in "
+        "`docs/design.md`, \"A request's first token\", with the recipe "
+        "that reads them: `python benchmarks/layer_metrics/_ttft.py RUN_DIR`.",
+    ]
     return "\n".join(lines) + "\n"
 
 

@@ -100,6 +100,6 @@ def test_the_longcat_cell_is_listed_only_under_readers_that_are_never_absent(man
     assert mine == {"mla_attn_time_share_chat", "mla_attn_roofline_decode",
                     "mla_proj_time_share_chat", "moe_held_roofline_decode",
                     "moe_zero_assign_share_chat", "moe_held_assign_share_chat"}
-    assert mine <= listed and len(listed) == 25  # 24 of PR 33 and tick_overlap_share_chat
+    assert mine <= listed and len(listed) == 31  # 24 of PR 33, tick_overlap_share_chat, PR 36's six
     assert {x["name"] for x in manifest_mod.metrics_for(m, "end_to_end", cell)} == {
         "setup_s", "ttft_p95_ms", "tpot_p50_ms"}
