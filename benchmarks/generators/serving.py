@@ -219,7 +219,7 @@ def judge(counted: list[dict]) -> list[dict]:
     """Sets each counted request's ``ok`` and returns the failed ones. Empty
     stops are correct answers only while they are at most a hundredth of the
     window's requests; beyond that every one of them is a failure (and is
-    charged the window's length by ``ttft_p95_ms``, as any failure is), so a
+    charged the window's length by ``ttft_client_p95_ms``, as any failure is), so a
     change that ends streams early cannot pass for a faster one."""
     empties = [r for r in counted if empty_stop(r)]
     allow = len(empties) <= EMPTY_STOP_SHARE * len(counted)
@@ -301,16 +301,20 @@ async def counters(port: int) -> dict:
 
 
 async def traced_window(ctx, child: Child, t0: float) -> dict:
-    """In a traced run: start the device trace ``start_s`` into the window,
-    stop it ``seconds`` later, from the launcher's thread in the process that
-    holds the chip (``serve()`` has no switch for a device trace)."""
-    spec = ctx.traffic["trace"]
+    """In a traced run: start the device trace ``seconds`` before the window
+    closes and stop it as it closes (later by what the start took), from the
+    launcher's thread in the process that holds the chip (``serve()`` has no
+    switch for a device trace). Writing the capture stalls the server for a
+    second or so: at the window's end that falls on the post-roll, which
+    nobody counts, and not on a twentieth of the window's requests (PERF.md
+    section 6, PR 36)."""
     loop = asyncio.get_running_loop()
     trace_dir = os.path.join(ctx.run_dir, "trace")
-    await asyncio.sleep(max(0.0, t0 + min(spec["start_s"], ctx.seconds / 3) - now()))
+    length = min(ctx.traffic["trace"]["seconds"], ctx.seconds / 3)
+    await asyncio.sleep(max(0.0, t0 + ctx.seconds - length - now()))
     start = await loop.run_in_executor(
         None, lambda: child.command("trace_start", dir=trace_dir))
-    await asyncio.sleep(min(spec["seconds"], ctx.seconds / 3))
+    await asyncio.sleep(length)
     stop = await loop.run_in_executor(
         None, lambda: child.command("trace_stop", timeout_s=300))
     return {"start": start, "stop": stop}
@@ -391,6 +395,7 @@ def run_serving(ctx, drive) -> dict:
         "failed": len(failed),
         "setup_s": state["t0"] - ctx.t_start,
         "window_s": float(ctx.seconds),
+        "window_t0": state["t0"],  # on the requests' clock: spread.py cuts by it
         "window_wall": [state["wall0"], state["wall0"] + ctx.seconds],
         "chips": ctx.chips,
         "requests": counted,

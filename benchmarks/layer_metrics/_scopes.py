@@ -279,22 +279,22 @@ def time_share(run: dict, names, program: str | None = None) -> float | None:
 
 def program_share(run: dict, program: str) -> float | None:
     """Device time of ``program``'s runs (``jit_paged_prefill``) over the
-    trace's busy time, in percent. None where the trace has no such run."""
+    trace's busy time, in percent: 0.0 where the trace holds no such run,
+    None only where the run has no trace (a line that lacks a listed metric
+    is a refusal: ledger, PR 30)."""
     path = trace_file(run) if run.get("trace") is not None else None
-    by_program = seconds_by_program(_loaded(path)) if path else {}
-    if program not in by_program:
+    if path is None:
         return None
-    return 100.0 * by_program[program] / run["trace"]["busy_s"]
+    return 100.0 * seconds_by_program(_loaded(path)).get(program, 0.0) / run["trace"]["busy_s"]
 
 
 def gap_share(run: dict, label: str) -> float | None:
     """Idle time of device 0 whose middle lies in a host span named ``label``
     (``reduce_trace``'s ``gap_s_by_label``) over the traced window, in
-    percent. None where no gap carries the label."""
-    gaps = (run.get("trace") or {}).get("gap_s_by_label", {})
-    if label not in gaps:
+    percent: 0.0 where no gap carries the label, None only without a trace."""
+    if run.get("trace") is None:
         return None
-    return 100.0 * gaps[label] / run["trace"]["window_s"]
+    return 100.0 * run["trace"].get("gap_s_by_label", {}).get(label, 0.0) / run["trace"]["window_s"]
 
 
 def dump(trace: dict, path: str, from_s: float, to_s: float) -> None:

@@ -6,7 +6,7 @@ from harness import percentile
 
 LAYER = "Scheduler"
 UNIT = "tokens"
-MOVES = "ttft_p95_ms"
+MOVES = "tpot_p50_ms"
 SOURCE = "host_clock"
 
 

@@ -13,7 +13,7 @@ from layer_metrics import _ttft
 
 LAYER = "Scheduler"
 UNIT = "%"
-MOVES = "ttft_p95_ms"
+MOVES = "tpot_p50_ms"
 SOURCE = "device_trace"
 
 

@@ -12,7 +12,7 @@ from layer_metrics import _ttft
 
 LAYER = "Scheduler"
 UNIT = "tokens"
-MOVES = "ttft_p95_ms"
+MOVES = "tpot_p50_ms"
 SOURCE = "program_counter"
 
 

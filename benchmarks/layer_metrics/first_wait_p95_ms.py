@@ -11,7 +11,7 @@ from layer_metrics import _ttft
 
 LAYER = "Scheduler"
 UNIT = "ms"
-MOVES = "ttft_p95_ms"
+MOVES = "tpot_p50_ms"
 SOURCE = "program_span"
 
 

@@ -5,7 +5,7 @@ from harness import percentile
 
 LAYER = "Server front"
 UNIT = "ms"
-MOVES = "ttft_p95_ms"
+MOVES = "tpot_p50_ms"
 SOURCE = "host_clock"
 
 

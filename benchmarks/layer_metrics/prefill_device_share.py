@@ -5,7 +5,7 @@ from layer_metrics import _scopes
 
 LAYER = "Model step"
 UNIT = "%"
-MOVES = "ttft_p95_ms"
+MOVES = "tpot_p50_ms"
 SOURCE = "device_trace"
 
 

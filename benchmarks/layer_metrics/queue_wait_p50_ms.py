@@ -4,7 +4,7 @@ from harness import percentile
 
 LAYER = "Scheduler"
 UNIT = "ms"
-MOVES = "ttft_p95_ms"
+MOVES = "tpot_p50_ms"
 SOURCE = "program_span"
 
 

@@ -16,7 +16,7 @@ from layer_metrics import _scopes
 
 LAYER = "Scheduler"
 UNIT = "%"
-MOVES = "ttft_p95_ms"
+MOVES = "tpot_p50_ms"
 SOURCE = "program_span"
 
 

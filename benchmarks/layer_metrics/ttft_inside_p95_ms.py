@@ -2,7 +2,7 @@
 requests of ``first_write_s``, from the handler's entry to the flush of the
 first SSE event that carried a token (``_ttft.py``). Over the requests clear
 of the device profiler's capture (``_ttft.quiet``), so it is the tail of the
-undisturbed server: the same run's client ``ttft_p95_ms`` counts from the
+undisturbed server: the same run's ``ttft_client_p95_ms`` counts from the
 instant a request was DUE and over the whole window, and the difference is
 the client, the connect, the accept queue and the capture's stop. 0.0 on a
 journal whose ``server.request`` spans lack ``first_write_s``; None only
@@ -11,7 +11,7 @@ from layer_metrics import _ttft
 
 LAYER = "Server front"
 UNIT = "ms"
-MOVES = "ttft_p95_ms"
+MOVES = "tpot_p50_ms"
 SOURCE = "program_span"
 
 

@@ -3,7 +3,7 @@ from layer_metrics import _lib
 
 LAYER = "Server front"
 UNIT = "ms"
-MOVES = "ttft_p95_ms"
+MOVES = "tpot_p50_ms"
 SOURCE = "host_clock"
 
 

@@ -20,7 +20,7 @@ def traffic(name):
 
 def test_open_loop_is_deterministic_in_the_seed():
     t = traffic("chat-steady")
-    a, b, c = (open_loop.schedule(t, s, 30, VOCAB) for s in (7, 7, 8))
+    a, b, c = (open_loop.schedule(t, s, 30, VOCAB) for s in (7, 7, 2**31 + 8))
     assert [r["due_s"] for r in a] == [r["due_s"] for r in b]
     assert all((x["ids"] == y["ids"]).all() for x, y in zip(a, b))
     assert [r["due_s"] for r in a] != [r["due_s"] for r in c]
@@ -92,7 +92,7 @@ def test_ttft_counts_from_the_due_instant_and_a_failure_counts_as_the_window():
     run = {"window_s": 30.0, "requests": [record(0.0, 0.3, 0.5, 1.5, 1, 11)] * 19
            + [record(0.0, 0.3, None, None, 0, 0, ok=False)]}
     assert reader("layer_metrics", "ttft_p50_ms").read(run) == pytest.approx(500.0)
-    assert reader("end_to_end", "ttft_p95_ms").read(run) > 500.0  # the failure: 30,000 ms
+    assert reader("layer_metrics", "ttft_client_p95_ms").read(run) > 500.0  # the failure: 30,000 ms
     assert reader("layer_metrics", "generator_late_p95_ms").read(run) == pytest.approx(300.0)
     # 10 tokens arrived in the second after the first event.
     assert reader("end_to_end", "tpot_p50_ms").read(run) == pytest.approx(100.0)
@@ -125,7 +125,7 @@ def test_a_refused_empty_answer_is_charged_the_window_by_ttft():
 
     reqs = [empty() for _ in range(40)] + [record(0.0, 0.0, 0.5, 1.5, 1, 11) for _ in range(160)]
     serving.judge(reqs)
-    ttft = load_module(os.path.join(BENCH, "end_to_end", "ttft_p95_ms.py"))
+    ttft = load_module(os.path.join(BENCH, "layer_metrics", "ttft_client_p95_ms.py"))
     assert ttft.read({"window_s": 30.0, "requests": reqs}) == pytest.approx(30_000.0)
 
 

@@ -36,21 +36,70 @@ def test_readers_agree_with_their_manifest_entries():
                     entry["layer"], entry["moves"], entry["source"]), entry["name"]
 
 
+SERVING = ("qwen2-7b-cut1.chat-steady-7b", "olmoe-1b-7b-cut1.chat-steady-moe",
+           "longcat-flash-cut1.chat-wide-mla")
+
+
 def test_the_serving_cell_and_its_metrics():
+    """Written so that a later PR's new cell or reader does not turn it red
+    (it asserted ONE serving cell and 16 metrics, and was red from PR 26 on)."""
     m = M.load()
     cell = M.cell(m, "qwen2-7b-cut1.chat-steady-7b")
     assert (cell["chips"], cell["config"], cell["traffic"]) == (1, "qwen2-7b-cut1", "chat-steady-7b")
     e2e = {e["name"]: e for e in M.metrics_for(m, "end_to_end", cell["name"])}
-    assert set(e2e) == {"setup_s", "ttft_p95_ms", "tpot_p50_ms"}
-    for name in ("ttft_p95_ms", "tpot_p50_ms"):
-        assert e2e[name]["workloads"] == [cell["name"]] and 0.01 <= e2e[name]["bound"] <= 0.1
+    assert {"setup_s", "tpot_p50_ms"} <= set(e2e)
+    assert set(SERVING) <= set(e2e["tpot_p50_ms"]["workloads"])
+    assert 0.01 <= e2e["tpot_p50_ms"]["bound"] <= 0.1
     per_layer = M.metrics_for(m, "per_layer", cell["name"])
-    assert len(per_layer) == 16
-    assert {p["moves"] for p in per_layer} == {"ttft_p95_ms", "tpot_p50_ms"}
-    # the trainer cells report none of them, and keep their own thirteen
-    assert len(M.metrics_for(m, "per_layer", "qwen2-0.5b.train-2k")) == 12
-    assert len(M.metrics_for(m, "per_layer", "qwen2-7b-cut4.train-fsdp4-4k")) == 13
+    assert len(per_layer) >= 26  # PR 39's count: harvest_idle_share_chat gone, ttft_client_p95_ms in
+    # every arrow ends at an end-to-end metric the cell reports: tpot_p50_ms is its only one
+    assert {p["moves"] for p in per_layer} == {"tpot_p50_ms"}
+    # the trainer cells report none of them, and keep their own
+    for trainer, least in (("qwen2-0.5b.train-2k", 12), ("qwen2-7b-cut4.train-fsdp4-4k", 13)):
+        mine = M.metrics_for(m, "per_layer", trainer)
+        assert len(mine) >= least
+        assert {p["moves"] for p in mine} == {"train_tokens_per_s_per_chip", "setup_s"}
     assert sum(w["chips"] == 4 for w in m["workloads"]) <= max(1, len(m["workloads"]) // 4)
+
+
+def test_harvest_idle_share_chat_is_gone_and_nothing_lists_it():
+    """Since PR 35 the harvest runs under the next tick's program: no idle
+    gap lies under ``engine.tick.harvest`` (ledger, PR 36: 4.7e-05 and null),
+    and ``tick_overlap_share_chat`` says so as a number."""
+    m = M.load()
+    assert "harvest_idle_share_chat" not in {p["name"] for p in m["per_layer"]}
+    assert not os.path.exists(M.reader_path("per_layer", "harvest_idle_share_chat"))
+    overlap = next(p for p in m["per_layer"] if p["name"] == "tick_overlap_share_chat")
+    assert set(SERVING) <= set(overlap["workloads"])
+
+
+def test_the_ttft_tail_is_read_in_every_serving_cell_and_held_to_no_bound():
+    """A p95 over the 230-306 requests of a 51 s window spreads by 3.4-5.5% from
+    the draw alone, over half the widest bound there is (PERF.md section 2): the
+    driver's check refused it end to end, so it is a per-layer metric of every
+    serving cell under another name, its reader what ``end_to_end/ttft_p95_ms.py``
+    was."""
+    m = M.load()
+    assert not [e for e in m["end_to_end"] if e["name"].startswith("ttft")]
+    assert not os.path.exists(M.reader_path("end_to_end", "ttft_p95_ms"))
+    tail = next(p for p in m["per_layer"] if p["name"] == "ttft_client_p95_ms")
+    assert tail["workloads"] == list(SERVING) and tail["source"] == "host_clock"
+    for cell in SERVING:
+        assert {e["name"] for e in M.metrics_for(m, "end_to_end", cell)} == {"setup_s", "tpot_p50_ms"}
+
+
+def test_every_open_loop_cell_counts_two_hundred_requests_a_window():
+    """A p95 wants ten samples beyond it: 200 requests. ``run_seconds`` is one
+    number for every cell, so the slowest rate decides (4.5 req/s: 45 s)."""
+    m = M.load()
+    seen = 0
+    for w in m["workloads"]:
+        with open(M.traffic_path(w["traffic"])) as f:
+            t = json.load(f)
+        if t["generator"] == "open_loop":
+            seen += 1
+            assert t["rate_per_s"] * m["run_seconds"] >= 200, w["name"]
+    assert seen >= 3
 
 
 def broken(change):
@@ -64,7 +113,7 @@ def test_a_bad_name_unit_or_arrow_is_refused():
     assert broken(lambda m: m["end_to_end"][0].update(unit="tokens per second"))
     assert broken(lambda m: m["per_layer"][0].update(moves="no_such_metric"))
     # an arrow at a metric the cell does not report
-    assert broken(lambda m: m["per_layer"][0].update(moves="ttft_p95_ms"))
+    assert broken(lambda m: m["per_layer"][0].update(moves="tpot_p50_ms"))
     assert broken(lambda m: m["end_to_end"][0].update(bound=0.5))
     assert broken(lambda m: m.update(extra=1))
     assert broken(lambda m: m["configs"][1]["reduced"].append("hidden_size"))

@@ -279,7 +279,6 @@ def serving_run(monkeypatch, trace, gaps):
     ("layer_scan_time_share_chat", 50.0),  # the decode program's, not the prefill's 5 ms
     ("paged_attn_time_share_chat", 20.0),
     ("prefill_device_share", 20.0),
-    ("harvest_idle_share_chat", 8.0),
     ("schedule_idle_share_chat", 4.0),
 ])
 def test_serving_readers_on_a_known_trace(monkeypatch, name, want):
@@ -291,15 +290,25 @@ def test_serving_readers_on_a_known_trace(monkeypatch, name, want):
     assert mod.read(run) == pytest.approx(want)
 
 
-@pytest.mark.parametrize("name", [
-    "layer_scan_time_share_chat", "paged_attn_time_share_chat", "prefill_device_share",
-    "harvest_idle_share_chat", "schedule_idle_share_chat"])
+@pytest.mark.parametrize("name", ["layer_scan_time_share_chat", "paged_attn_time_share_chat"])
 def test_serving_readers_find_nothing_in_a_trainers_trace(monkeypatch, name):
-    """None, never 0: a trainer's trace has no decode program, no paged
-    kernel, no prefill run and no engine span; an untraced run has no trace."""
+    """None, never 0, for a share of busy time by NAME: a trainer's trace has
+    no decode program and no paged kernel; an untraced run has no trace."""
     mod = reader(name)
     monkeypatch.setattr(mod, "_scopes", sc)
     assert mod.read(serving_run(monkeypatch, known_trace(), {"unattributed": 0.004})) is None
+    assert mod.read({"workload": "w", "trace": None}) is None
+
+
+@pytest.mark.parametrize("name", ["prefill_device_share", "schedule_idle_share_chat"])
+def test_a_share_of_the_trace_reads_zero_where_the_trace_holds_none(monkeypatch, name):
+    """A traced window that happens to hold no prefill run, or no idle gap
+    under the span, HAS a share of them: 0.0. A listed metric missing from a
+    traced line refuses a PR (ledger, PR 30, PR 32 notes), so None is kept for
+    the run without a trace."""
+    mod = reader(name)
+    monkeypatch.setattr(mod, "_scopes", sc)
+    assert mod.read(serving_run(monkeypatch, known_trace(), {"unattributed": 0.004})) == 0.0
     assert mod.read({"workload": "w", "trace": None}) is None
 
 
@@ -327,7 +336,7 @@ def test_the_recorded_serving_cut_gives_its_known_shares(monkeypatch):
         assert mod.read(run) == pytest.approx(value, rel=1e-9), name
     assert set(want["readers"]) == {
         "layer_scan_time_share_chat", "paged_attn_time_share_chat", "prefill_device_share",
-        "harvest_idle_share_chat", "schedule_idle_share_chat"}
+        "schedule_idle_share_chat"}
     # the whole-pool slice is nearly all of the decode program's layer_scan
     decode = sc.seconds_by_name(trace, "paged_decode")
     assert decode["layer_scan"] <= by["layer_scan"]
