@@ -18,9 +18,45 @@ Design (TPU-first):
   The log-sum-exp residual is stored lane-replicated the same way.
 - **GQA-native**: H query heads share H//K KV heads; the KV block index map
   divides the head index, so KV tiles are fetched once per group.
-- **Causal block skipping**: fully-masked KV blocks are predicated off with
-  ``pl.when`` (the grid still visits them; compute and the second matmul are
-  skipped).
+- **Block skipping**: a grid step whose block is fully masked is predicated
+  off with ``pl.when`` (the grid still visits it; compute and both matmuls are
+  skipped). Without segment ids that is causality's test alone. With them the
+  wrappers reduce the ids, once a call and outside the kernels, to a
+  ``(min, max)`` range for every query block and every key block (forward and
+  backward tiles apart) and hand the kernels those as scalar-prefetch operands
+  (SMEM): a block is needed when it is causally reachable AND the two ranges
+  meet (``q_min <= kv_max and kv_min <= q_max``), a few scalar reads a step.
+  On packed rows as the loader makes them (ids 1-based and non-decreasing
+  along a row) two blocks share a document exactly when their ranges meet, so
+  the test is exact; for any other layout it is conservative, and a block it
+  lets through is still masked element by element by ``_block_mask``. Rows of
+  documents a tenth as long as the row keep 71% of the causal 512 x 512
+  blocks at 2,048 tokens and 43% at 4,096.
+- **The inner axis walks the hull**: three more prefetch operands a kernel
+  are the hull ``[first, last]`` of every outer block's needed inner blocks
+  (key blocks for ``flash_fwd`` / ``flash_bwd_dq``; query blocks for
+  ``flash_bwd_dkv``) and the widest hull of the call. The inner grid axis
+  takes that many steps and no more (a dynamic grid bound: 2-3 on the
+  trainer's rows where the axis has 4 or 8 blocks), and the index maps lay
+  every walk so that it ENDS on its last needed block; the steps in front of
+  a narrower hull name its first block, which is then already in VMEM when
+  it is needed, so a skipped step copies nothing: neither K/V nor, in
+  ``flash_bwd_dkv``, q, dO, lse and delta. On the v5e a skipped step's cost
+  was its copies and the copies it left exposed: the predicate alone takes
+  7% off a layer's two forwards and backward at the 2,048-token cell's
+  shapes, copying nothing for skipped steps 25%, ending each walk on a
+  needed block (the next walk's first copy then runs under compute) 35%,
+  and visiting only the widest hull 40-46% (PERF.md section 6, PR 40).
+- **The same numbers**: a skipped block contributed exactly nothing. In both
+  backward kernels ``p = exp(NEG_INF - lse)`` is 0; in the forward whatever a
+  fully masked block adds to ``m``, ``l`` and ``acc`` is wiped by ``alpha =
+  exp(m_prev - m_next) = 0`` at the row's first real key, and every row has
+  one when queries and keys carry ONE id array (its own position). So
+  outputs, ``lse`` and all three gradients are bit-equal to kernels that skip
+  on causality alone. The one input on which they would differ is separate
+  query and key ids under which some query row shares a segment with NO key:
+  there the unskipped forward returns a mean of V and this one 0. The public
+  ``flash_attention`` cannot reach it.
 - **Custom VJP**: backward runs two Pallas kernels — one accumulating dq over
   KV blocks, one accumulating dk/dv over (group × query) blocks — both
   recomputing p from the saved log-sum-exp (FlashAttention-2 style).
@@ -117,6 +153,152 @@ def _block_mask(
 
 
 # ---------------------------------------------------------------------------
+# Which blocks a packed row needs
+# ---------------------------------------------------------------------------
+
+
+def _block_ranges(seg: jax.Array, block: int) -> tuple[jax.Array, jax.Array]:
+    """``(B, S)`` segment ids -> the (min, max) id of every ``block`` tokens,
+    each ``(B, S // block)`` int32."""
+    b, s = seg.shape
+    tiles = seg.astype(jnp.int32).reshape(b, s // block, block)
+    return tiles.min(axis=-1), tiles.max(axis=-1)
+
+
+def _reachable(n_q: int, n_kv: int, blocks: BlockSizes, causal: bool) -> jax.Array:
+    """``(n_q, n_kv)`` bool: the blocks causality leaves (all, if not causal)."""
+    iq = jnp.arange(n_q, dtype=jnp.int32)[:, None]
+    ikv = jnp.arange(n_kv, dtype=jnp.int32)[None, :]
+    reach = (iq + 1) * blocks.block_q - 1 >= ikv * blocks.block_kv
+    return reach if causal else jnp.ones_like(reach)
+
+
+def _needed_blocks(q_rng, kv_rng, blocks: BlockSizes, causal: bool) -> jax.Array:
+    """``(B, n_q, n_kv)`` bool, the kernels' block predicate laid out whole:
+    causally reachable, and the two blocks' id ranges meet."""
+    (q_lo, q_hi), (kv_lo, kv_hi) = q_rng, kv_rng
+    meet = jnp.logical_and(q_lo[:, :, None] <= kv_hi[:, None, :],
+                           kv_lo[:, None, :] <= q_hi[:, :, None])
+    reach = _reachable(q_lo.shape[1], kv_lo.shape[1], blocks, causal)
+    return jnp.logical_and(meet, reach[None])
+
+
+def _hull(needed: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """First and last needed index along the last axis (int32). A row that
+    needs nothing keeps the whole axis: its steps are predicated off anyway."""
+    n = needed.shape[-1]
+    first = jnp.argmax(needed, axis=-1).astype(jnp.int32)
+    last = (n - 1 - jnp.argmax(needed[..., ::-1], axis=-1)).astype(jnp.int32)
+    return first, last
+
+
+def block_counts(
+    segment_ids: jax.Array,  # (B, S)
+    *,
+    causal: bool = True,
+    block_q: int = 512,
+    block_kv: int = 512,
+) -> tuple[jax.Array, jax.Array]:
+    """``(reachable, needed)``: how many blocks of the forward kernel's grid
+    causality leaves for this batch, a head, and how many of those the
+    predicate keeps (int32 scalars). The arithmetic the kernels' operands are
+    made with, for the trainer's counter."""
+    s = segment_ids.shape[1]
+    blocks = _pick_blocks(s, s, block_q, block_kv)
+    needed = _needed_blocks(
+        _block_ranges(segment_ids, blocks.block_q),
+        _block_ranges(segment_ids, blocks.block_kv), blocks, causal)
+    reach = _reachable(*needed.shape[1:], blocks, causal)
+    return (segment_ids.shape[0] * jnp.sum(reach, dtype=jnp.int32),
+            jnp.sum(needed, dtype=jnp.int32))
+
+
+def _skip_operands(q_seg, kv_seg, blocks: BlockSizes, causal: bool):
+    """The kernels' scalar-prefetch operands, seven int32 arrays: the id
+    ranges of the query blocks ``(B, n_q)`` and of the key blocks
+    ``(B, n_kv)``, the hull ``[first, last]`` of each query block's needed
+    key blocks, and the widest hull of the call ``(1,)``, which is how many
+    steps the inner grid axis takes. Returned twice: for the kernels whose
+    inner axis walks key blocks, and with the hulls of each KEY block's
+    needed query blocks ``(B, n_kv)`` for the dk/dv kernel, whose inner axis
+    walks query blocks."""
+    q_rng = _block_ranges(q_seg, blocks.block_q)
+    kv_rng = _block_ranges(kv_seg, blocks.block_kv)
+    needed = _needed_blocks(q_rng, kv_rng, blocks, causal)
+
+    def operands(needed):
+        first, last = _hull(needed)
+        return (*q_rng, *kv_rng, first, last,
+                jnp.max(last - first + 1).reshape(1))
+
+    return operands(needed), operands(jnp.swapaxes(needed, 1, 2))
+
+
+def _walk(step, ib, outer, n: int, skip):
+    """Where a step of the inner grid axis stands: ``(block, inside, last
+    step)``. Without ``skip`` operands the axis is the ``n`` blocks. With
+    them it is as long as the call's widest hull and ENDS on the outer
+    block's last needed block: the steps in front of a narrower hull are
+    outside it, and name its first block, so nothing is copied for them.
+    Ending a walk on a needed block matters: the pipeline starts the copy of
+    the next walk's first blocks one step ahead, and under a skipped step
+    that copy has no compute to hide behind."""
+    if not skip:
+        return step, True, n - 1
+    first, last, width = skip[4:]
+    at = last[ib, outer] - (width[0] - 1 - step)
+    return jnp.maximum(at, first[ib, outer]), at >= first[ib, outer], width[0] - 1
+
+
+def _unfold(inner, n: int, skip):
+    """``(group, step)`` of the dk/dv kernel's inner axis, which folds the
+    GQA group loop into the walk over query blocks (``n`` steps a group, or
+    the widest hull's with ``skip``)."""
+    if not skip:
+        return inner // n, inner % n
+    width = skip[6][0]
+    return jax.lax.div(inner, width), jax.lax.rem(inner, width)
+
+
+def _block_needed(ib, iq, ikv, inside, *, causal: bool, block_q: int,
+                  block_kv: int, skip):
+    """The grid step's predicate. With causal masking, blocks strictly above
+    the diagonal contribute nothing; with ``skip`` (SMEM refs,
+    ``_skip_operands``), neither do blocks whose id ranges do not meet, nor
+    a step outside its hull (``_walk``). Compute and both matmuls are
+    skipped."""
+    needed = (iq + 1) * block_q - 1 >= ikv * block_kv if causal else True
+    if skip is None:
+        return needed
+    q_lo, q_hi, kv_lo, kv_hi = skip[:4]
+    meet = jnp.logical_and(q_lo[ib, iq] <= kv_hi[ib, ikv],
+                           kv_lo[ib, ikv] <= q_hi[ib, iq])
+    meet = jnp.logical_and(inside, meet)
+    return meet if needed is True else jnp.logical_and(needed, meet)
+
+
+def _pallas(kernel, skip, *, grid, in_specs, out_specs, scratch_shapes, **kw):
+    """``pl.pallas_call``; with ``skip`` operands, through a scalar-prefetch
+    grid spec: they reach the index maps as trailing arguments and the kernel
+    as leading refs."""
+    if skip is None:
+        return pl.pallas_call(
+            kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
+            scratch_shapes=scratch_shapes, **kw)
+
+    def with_skip(*refs):
+        kernel(*refs[len(skip):], skip=refs[:len(skip)])
+
+    call = pl.pallas_call(
+        with_skip,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(skip), grid=grid, in_specs=in_specs,
+            out_specs=out_specs, scratch_shapes=scratch_shapes),
+        **kw)
+    return functools.partial(call, *skip)
+
+
+# ---------------------------------------------------------------------------
 # Forward kernel
 # ---------------------------------------------------------------------------
 
@@ -138,21 +320,21 @@ def _fwd_kernel(
     block_q: int,
     block_kv: int,
     n_kv: int,
+    skip=None,
 ):
+    ib = pl.program_id(0)
     iq = pl.program_id(2)
-    ikv = pl.program_id(3)
+    step = pl.program_id(3)
+    ikv, inside, last_step = _walk(step, ib, iq, n_kv, skip)
 
-    @pl.when(ikv == 0)
+    @pl.when(step == 0)
     def _init():
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    # With causal masking, blocks strictly above the diagonal contribute
-    # nothing: skip their compute (the grid still visits them).
-    needed = (
-        (iq + 1) * block_q - 1 >= ikv * block_kv if causal else True
-    )
+    needed = _block_needed(ib, iq, ikv, inside, causal=causal, block_q=block_q,
+                           block_kv=block_kv, skip=skip)
 
     @pl.when(needed)
     def _compute():
@@ -193,7 +375,7 @@ def _fwd_kernel(
         )  # (block_q, D)
         acc_scr[...] = acc_scr[...] * _lane_tile(alpha, acc_scr.shape[-1]) + pv
 
-    @pl.when(ikv == n_kv - 1)
+    @pl.when(step == last_step)
     def _finalize():
         l = l_scr[...]
         # Fully-masked rows have l == 0; emit 0 there instead of NaN.
@@ -221,13 +403,15 @@ def _fwd(
     groups = h // kv_heads
     bq, bkv = blocks
     n_q, n_kv = s_q // bq, s_kv // bkv
-    grid = (b, h, n_q, n_kv)
+    skip = (None if q_seg is None
+            else _skip_operands(q_seg, kv_seg, blocks, causal)[0])
+    grid = (b, h, n_q, n_kv if skip is None else skip[6][0])
 
-    def q_map(ib, ih, iq, ikv):
+    def q_map(ib, ih, iq, step, *skip):
         return (ib, ih, iq, 0)
 
-    def kv_map(ib, ih, iq, ikv):
-        return (ib, ih // groups, ikv, 0)
+    def kv_map(ib, ih, iq, step, *skip):
+        return (ib, ih // groups, _walk(step, ib, iq, n_kv, skip)[0], 0)
 
     in_specs = [
         pl.BlockSpec((1, 1, bq, d), q_map),
@@ -237,11 +421,14 @@ def _fwd(
     args = [q, k, v]
     if q_seg is not None:
         in_specs.append(
-            pl.BlockSpec((1, bq, NUM_LANES), lambda ib, ih, iq, ikv: (ib, iq, 0))
+            pl.BlockSpec((1, bq, NUM_LANES),
+                         lambda ib, ih, iq, ikv, *skip: (ib, iq, 0))
         )
         in_specs.append(
             pl.BlockSpec(
-                (1, NUM_SUBLANES, bkv), lambda ib, ih, iq, ikv: (ib, 0, ikv)
+                (1, NUM_SUBLANES, bkv),
+                lambda ib, ih, iq, step, *skip: (
+                    ib, 0, _walk(step, ib, iq, n_kv, skip)[0]),
             )
         )
         args.append(
@@ -270,8 +457,9 @@ def _fwd(
         pl.BlockSpec((1, 1, bq, d), q_map),
         pl.BlockSpec((1, 1, bq, NUM_LANES), q_map),
     )
-    o, lse = pl.pallas_call(
+    o, lse = _pallas(
         kernel,
+        skip,
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
@@ -312,17 +500,19 @@ def _dq_kernel(
     block_q: int,
     block_kv: int,
     n_kv: int,
+    skip=None,
 ):
+    ib = pl.program_id(0)
     iq = pl.program_id(2)
-    ikv = pl.program_id(3)
+    step = pl.program_id(3)
+    ikv, inside, last_step = _walk(step, ib, iq, n_kv, skip)
 
-    @pl.when(ikv == 0)
+    @pl.when(step == 0)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    needed = (
-        (iq + 1) * block_q - 1 >= ikv * block_kv if causal else True
-    )
+    needed = _block_needed(ib, iq, ikv, inside, causal=causal, block_q=block_q,
+                           block_kv=block_kv, skip=skip)
 
     @pl.when(needed)
     def _compute():
@@ -352,7 +542,7 @@ def _dq_kernel(
             ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
 
-    @pl.when(ikv == n_kv - 1)
+    @pl.when(step == last_step)
     def _finalize():
         dq_ref[0, 0] = dq_scr[...].astype(dq_ref.dtype)
 
@@ -377,21 +567,22 @@ def _dkv_kernel(
     block_kv: int,
     n_q: int,
     n_inner: int,
+    skip=None,
 ):
     """Grid (B, K, n_kv, groups * n_q): the innermost (sequential) dim folds
     the GQA group loop into the q loop so dk/dv accumulation is race-free."""
+    ib = pl.program_id(0)
     ikv = pl.program_id(2)
     inner = pl.program_id(3)
-    iq = inner % n_q
+    iq, inside, _ = _walk(_unfold(inner, n_q, skip)[1], ib, ikv, n_q, skip)
 
     @pl.when(inner == 0)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    needed = (
-        (iq + 1) * block_q - 1 >= ikv * block_kv if causal else True
-    )
+    needed = _block_needed(ib, iq, ikv, inside, causal=causal, block_q=block_q,
+                           block_kv=block_kv, skip=skip)
 
     @pl.when(needed)
     def _compute():
@@ -426,7 +617,7 @@ def _dkv_kernel(
             ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
 
-    @pl.when(inner == n_inner - 1)
+    @pl.when(inner == (n_inner if skip is None else pl.num_programs(3)) - 1)
     def _finalize():
         dk_ref[0, 0] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_scr[...].astype(dv_ref.dtype)
@@ -462,22 +653,24 @@ def _bwd_impl(
     )
 
     seg_args = [None, None]
+    skip = skip_dkv = None
     if q_seg is not None:
         q_seg_b = jax.lax.broadcast_in_dim(q_seg, (b, s_q, NUM_LANES), (0, 1))
         kv_seg_b = jax.lax.broadcast_in_dim(
             kv_seg, (b, NUM_SUBLANES, s_kv), (0, 2)
         )
         seg_args = [q_seg_b, kv_seg_b]
+        skip, skip_dkv = _skip_operands(q_seg, kv_seg, blocks, causal)
 
     # dk = scale·dsᵀq_unscaled = dsᵀ(scale·q): pre-scaling q once inside the
     # kernels folds the scale into both s and dk, so no post-multiply needed.
 
     # ---- dq: grid (B, H, n_q, n_kv), accumulate over kv blocks ----
-    def q_map(ib, ih, iq, ikv):
+    def q_map(ib, ih, iq, step, *skip):
         return (ib, ih, iq, 0)
 
-    def kv_map(ib, ih, iq, ikv):
-        return (ib, ih // groups, ikv, 0)
+    def kv_map(ib, ih, iq, step, *skip):
+        return (ib, ih // groups, _walk(step, ib, iq, n_kv, skip)[0], 0)
 
     dq_in_specs = [
         pl.BlockSpec((1, 1, bq, d), q_map),
@@ -489,17 +682,20 @@ def _bwd_impl(
     ]
     if q_seg is not None:
         dq_in_specs.append(
-            pl.BlockSpec((1, bq, NUM_LANES), lambda ib, ih, iq, ikv: (ib, iq, 0))
+            pl.BlockSpec((1, bq, NUM_LANES),
+                         lambda ib, ih, iq, ikv, *skip: (ib, iq, 0))
         )
         dq_in_specs.append(
             pl.BlockSpec(
-                (1, NUM_SUBLANES, bkv), lambda ib, ih, iq, ikv: (ib, 0, ikv)
+                (1, NUM_SUBLANES, bkv),
+                lambda ib, ih, iq, step, *skip: (
+                    ib, 0, _walk(step, ib, iq, n_kv, skip)[0]),
             )
         )
     else:
         dq_in_specs += [None, None]
 
-    dq = pl.pallas_call(
+    dq = _pallas(
         functools.partial(
             _dq_kernel,
             scale=scale,
@@ -508,7 +704,8 @@ def _bwd_impl(
             block_kv=bkv,
             n_kv=n_kv,
         ),
-        grid=(b, h, n_q, n_kv),
+        skip,
+        grid=(b, h, n_q, n_kv if skip is None else skip[6][0]),
         in_specs=dq_in_specs,
         out_specs=pl.BlockSpec((1, 1, bq, d), q_map),
         out_shape=jax.ShapeDtypeStruct((b, h, s_q, d), q.dtype),
@@ -523,10 +720,12 @@ def _bwd_impl(
     # ---- dk/dv: grid (B, K, n_kv, groups·n_q), accumulate over (g, q) ----
     n_inner = groups * n_q
 
-    def q_map2(ib, ikh, ikv, inner):
-        return (ib, ikh * groups + inner // n_q, inner % n_q, 0)
+    def q_map2(ib, ikh, ikv, inner, *skip):
+        group, step = _unfold(inner, n_q, skip)
+        return (ib, ikh * groups + group,
+                _walk(step, ib, ikv, n_q, skip)[0], 0)
 
-    def kv_map2(ib, ikh, ikv, inner):
+    def kv_map2(ib, ikh, ikv, inner, *skip):
         return (ib, ikh, ikv, 0)
 
     dkv_in_specs = [
@@ -541,19 +740,21 @@ def _bwd_impl(
         dkv_in_specs.append(
             pl.BlockSpec(
                 (1, bq, NUM_LANES),
-                lambda ib, ikh, ikv, inner: (ib, inner % n_q, 0),
+                lambda ib, ikh, ikv, inner, *skip: (
+                    ib, _walk(_unfold(inner, n_q, skip)[1], ib, ikv, n_q,
+                              skip)[0], 0),
             )
         )
         dkv_in_specs.append(
             pl.BlockSpec(
                 (1, NUM_SUBLANES, bkv),
-                lambda ib, ikh, ikv, inner: (ib, 0, ikv),
+                lambda ib, ikh, ikv, inner, *skip: (ib, 0, ikv),
             )
         )
     else:
         dkv_in_specs += [None, None]
 
-    dk, dv = pl.pallas_call(
+    dk, dv = _pallas(
         functools.partial(
             _dkv_kernel,
             scale=scale,
@@ -563,7 +764,9 @@ def _bwd_impl(
             n_q=n_q,
             n_inner=n_inner,
         ),
-        grid=(b, kv_heads, n_kv, n_inner),
+        skip_dkv,
+        grid=(b, kv_heads, n_kv,
+              n_inner if skip_dkv is None else groups * skip_dkv[6][0]),
         in_specs=dkv_in_specs,
         out_specs=(
             pl.BlockSpec((1, 1, bkv, d), kv_map2),
@@ -637,6 +840,11 @@ def flash_attention(
     Takes/returns the model's (B, S, H, D) layout. Raises ``ValueError`` on
     shapes the kernel cannot tile. ``block_*_bwd`` size the backward kernels'
     tiles independently (0 = same as forward).
+
+    ``segment_ids`` serve queries and keys alike, so every row attends at
+    least to itself: that is what lets the kernels skip a block whose queries
+    and keys share no id without changing a bit of the result (module
+    docstring). ``None`` runs the kernels without the skip operands.
     """
     b, s_q, h, d = q.shape
     _, s_kv, kv_heads, _ = k.shape

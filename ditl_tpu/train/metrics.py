@@ -24,7 +24,9 @@ holds however far the host runs ahead. It is ``flush_step_s`` on the flushing
 row, and what the ``step N:`` log line prints (the first flush's interval
 starts when the logger is made and holds the compile). Every row also carries
 ``compile_count_cum`` / ``compile_s_cum`` (utils/profiling.compile_counter)
-as they stood at its flush.
+as they stood at its flush, and every device metric the step reports under
+the step's own name (``train/step.py``: the experts' load ratio, the flash
+kernels' block counts).
 """
 
 from __future__ import annotations

@@ -115,6 +115,28 @@ def moe_metric_names(cfg: ModelConfig, mesh) -> tuple[str, ...]:
     return ("router_aux_loss", "moe_load_max_over_mean")
 
 
+def flash_metric_names(cfg: ModelConfig, mesh, rules: dict, batch) -> tuple[str, ...]:
+    """What a step adds to its metrics where its attention is the flash
+    kernel over packed rows: the causally reachable blocks of the forward
+    kernel's grid over the step's batch, and those its predicate keeps
+    (``ops/flash_attention.block_counts``; once a step, not once a layer).
+    Nothing where another path runs: no segment ids, a sharded sequence (ring
+    attention's own loop), a length the kernel cannot tile."""
+    from ditl_tpu.ops import flash_attention as fa
+    from ditl_tpu.parallel.sharding import mesh_axes_size
+
+    seg = batch.get("segment_ids")
+    if cfg.attention_impl != "flash" or seg is None:
+        return ()
+    if mesh is not None and mesh_axes_size(mesh, rules.get("seq")) > 1:
+        return ()
+    s = seg.shape[-1]
+    if not fa.supports(s, s, cfg.head_dim, cfg.flash_block_q or 512,
+                       cfg.flash_block_kv or 512):
+        return ()
+    return ("flash_blocks_reachable", "flash_blocks_needed")
+
+
 def batch_logical_axes(example_batch: dict[str, Any]) -> dict[str, tuple]:
     return {k: ("batch",) + (None,) * (v.ndim - 1) for k, v in example_batch.items()}
 
@@ -197,6 +219,13 @@ def _build_step_fn(
         new_state = TrainState(step=state.step + 1, params=new_params, opt_state=new_opt)
         metrics = {"loss": loss, "n_tokens": tokens, "grad_norm": grad_norm,
                    **moe_metrics}
+        flash_names = flash_metric_names(model_cfg, mesh, rules, batch)
+        if flash_names:
+            from ditl_tpu.ops.flash_attention import block_counts
+
+            metrics.update(zip(flash_names, block_counts(
+                batch["segment_ids"], block_q=model_cfg.flash_block_q or 512,
+                block_kv=model_cfg.flash_block_kv or 512)))
         if train_cfg.fault_nan_step > 0:
             # Anomaly-plane drill (ISSUE 10): a real device NaN in the
             # REPORTED loss at exactly this step — it rides the compiled
@@ -221,7 +250,9 @@ def _shardings_for(model_cfg, train_cfg, mesh, example_batch, rules):
     replicated = NamedSharding(mesh, P())
     metric_shardings = {
         k: replicated for k in ("loss", "n_tokens", "grad_norm",
-                                *moe_metric_names(model_cfg, mesh))
+                                *moe_metric_names(model_cfg, mesh),
+                                *flash_metric_names(model_cfg, mesh, rules,
+                                                    example_batch))
     }
     return state_shardings, batch_shardings, metric_shardings
 

@@ -129,3 +129,180 @@ def test_bf16_forward_close():
     np.testing.assert_allclose(
         out.astype(np.float32), ref.astype(np.float32), atol=3e-2, rtol=3e-2
     )
+
+
+# ---------------------------------------------------------------------------
+# Packed rows: blocks that lie between two documents are skipped
+# ---------------------------------------------------------------------------
+
+S = 512
+
+
+def _ids(*runs):
+    """((id, length), ...) -> (2, S) int32, the second row the first shifted
+    by a document so the rows of a batch differ."""
+    row = np.concatenate([np.full(n, i, np.int32) for i, n in runs])
+    assert row.shape == (S,)
+    return np.stack([row, np.where(row > 0, row + 1, 0)])
+
+
+LAYOUTS = {
+    "one-document": _ids((1, S)),
+    "three-on-block-edges": _ids((1, 128), (2, 256), (3, 128)),
+    "three-inside-blocks": _ids((1, 100), (2, 230), (3, 182)),
+    "many": _ids(*[(i + 1, 32) for i in range(15)], (16, 32)),
+    # what the serving prefill sends: 1 over the prompt, 0 over the bucket's padding
+    "trailing-zero-padding": _ids((1, 300), (0, 212)),
+    # ids that come back: the range test may only be conservative here
+    "non-monotone": _ids((2, 100), (1, 156), (3, 128), (1, 128)),
+}
+# (block_q, block_kv, block_q_bwd, block_kv_bwd): the second's backward tiles
+# differ from its forward's
+BLOCKS = [(128, 128, 0, 0), (256, 128, 128, 256)]
+
+
+def _out_and_grads(attn, q, k, v):
+    out, vjp = jax.vjp(attn, q, k, v)
+    return (out, *vjp(0.5 + out))
+
+
+def _cover_everything(monkeypatch):
+    """Ranges that meet every other: the predicate is causality's alone, as
+    before the kernels knew of segments."""
+    from ditl_tpu.ops import flash_attention as fa
+
+    def whole(seg, block):
+        lo = jnp.zeros((seg.shape[0], seg.shape[1] // block), jnp.int32)
+        return lo, lo + np.iinfo(np.int32).max
+
+    monkeypatch.setattr(fa, "_block_ranges", whole)
+
+
+@pytest.mark.parametrize("blocks", BLOCKS, ids=lambda b: "x".join(map(str, b)))
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("layout", LAYOUTS)
+def test_packed_rows_match_xla_and_the_kernels_that_skip_nothing(
+        monkeypatch, layout, causal, blocks):
+    seg = jnp.asarray(LAYOUTS[layout])
+    q, k, v = _make_qkv(jax.random.key(11), 2, S, 4, 2, 64)
+    bq, bkv, bqb, bkvb = blocks
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=causal, segment_ids=seg, block_q=bq,
+                               block_kv=bkv, block_q_bwd=bqb, block_kv_bwd=bkvb)
+
+    got = _out_and_grads(flash, q, k, v)
+    want = _out_and_grads(
+        lambda q, k, v: _xla_attention(q, k, v, causal=causal, segment_ids=seg), q, k, v)
+    for g, w, name in zip(got, want, ("out", "dq", "dk", "dv")):
+        np.testing.assert_allclose(g, w, atol=5e-4, rtol=5e-4, err_msg=name)
+    # the same numbers, not close ones: a skipped block contributed nothing
+    _cover_everything(monkeypatch)
+    for g, w, name in zip(got, _out_and_grads(flash, q, k, v), ("out", "dq", "dk", "dv")):
+        np.testing.assert_array_equal(g, w, err_msg=name)
+
+
+@pytest.mark.parametrize("heads", [(2, 2), (4, 2), (8, 2)], ids=lambda h: f"{h[0]}q-{h[1]}kv")
+def test_packed_rows_with_gqa_groups_match_the_kernels_that_skip_nothing(monkeypatch, heads):
+    seg = jnp.asarray(LAYOUTS["three-inside-blocks"])
+    q, k, v = _make_qkv(jax.random.key(12), 2, S, *heads, 64, dtype=jnp.bfloat16)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=True, segment_ids=seg, block_q=128,
+                               block_kv=128)
+
+    got = _out_and_grads(flash, q, k, v)
+    ref = _xla_attention(q, k, v, causal=True, segment_ids=seg)
+    np.testing.assert_allclose(got[0].astype(np.float32), ref.astype(np.float32),
+                               atol=3e-2, rtol=3e-2)
+    _cover_everything(monkeypatch)
+    for g, w in zip(got, _out_and_grads(flash, q, k, v)):
+        np.testing.assert_array_equal(g, w)
+
+
+def _pallas_calls(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _pallas_calls(sub)
+
+
+@pytest.mark.parametrize("with_segments,operands", [(False, 0), (True, 7)])
+def test_only_a_call_with_segment_ids_carries_prefetch_operands(with_segments, operands):
+    q, k, v = _make_qkv(jax.random.key(13), 1, 256, 2, 1, 64)
+    seg = jnp.ones((1, 256), jnp.int32) if with_segments else None
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, segment_ids=seg, block_q=128, block_kv=128))
+
+    calls = list(_pallas_calls(jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(q, k, v).jaxpr))
+    assert len(calls) == 3  # flash_fwd, flash_bwd_dq, flash_bwd_dkv
+    assert [c.params["grid_mapping"].num_index_operands for c in calls] == [operands] * 3
+
+
+def _brute_counts(seg, bq, bkv, causal):
+    """Blocks with a row at or past a column (causal), and of those the
+    blocks in which some query and some key it may see share an id."""
+    pos = np.arange(seg.shape[1])
+    reachable = needed = 0
+    for row in seg:
+        for q0 in range(0, len(row), bq):
+            for k0 in range(0, len(row), bkv):
+                see = (pos[q0:q0 + bq, None] >= pos[None, k0:k0 + bkv]) | (not causal)
+                same = row[q0:q0 + bq, None] == row[None, k0:k0 + bkv]
+                reachable += bool(see.any())
+                needed += bool((see & same).any())
+    return reachable, needed
+
+
+@pytest.mark.parametrize("blocks", [(128, 128), (256, 128), (128, 256)],
+                         ids=lambda b: "x".join(map(str, b)))
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("layout", LAYOUTS)
+def test_block_counts_against_a_brute_force_count(layout, causal, blocks):
+    from ditl_tpu.ops.flash_attention import block_counts
+
+    seg = LAYOUTS[layout]
+    reachable, needed = map(int, block_counts(
+        jnp.asarray(seg), causal=causal, block_q=blocks[0], block_kv=blocks[1]))
+    want_reachable, want_needed = _brute_counts(seg, *blocks, causal)
+    assert reachable == want_reachable
+    if layout == "non-monotone":  # conservative: never fewer than there are
+        assert want_needed <= needed <= reachable
+    else:  # exact for the loader's rows: ids that never come back
+        assert needed == want_needed
+
+
+def test_block_counts_fixed_points():
+    from ditl_tpu.ops.flash_attention import block_counts
+
+    one = jnp.ones((3, S), jnp.int32)
+    own = jnp.asarray(np.repeat(np.arange(1, 5, dtype=np.int32), 128)[None])
+    # one document a row: every causal block is needed, 100%
+    assert tuple(map(int, block_counts(one, block_q=128, block_kv=128))) == (30, 30)
+    # every block its own document: the diagonal only
+    assert tuple(map(int, block_counts(own, block_q=128, block_kv=128))) == (10, 4)
+    assert tuple(map(int, block_counts(own, causal=False, block_q=128, block_kv=128))) == (16, 4)
+    # the defaults are the kernels': 512 x 512, so S is one block
+    assert tuple(map(int, block_counts(own))) == (1, 1)
+
+
+@pytest.mark.parametrize("layout,width", [
+    ("one-document", 4),  # the last query block reaches every key block
+    ("three-on-block-edges", 2),  # the 256-token document is two blocks
+    ("three-inside-blocks", 3),  # block 2 holds the ends of documents 2 and 3
+    ("many", 1),  # every block its own documents: the diagonal
+    ("trailing-zero-padding", 3),  # the prompt's three blocks; the padding's two
+], ids=lambda v: str(v))
+def test_the_inner_axis_takes_the_widest_hulls_steps(layout, width):
+    """The kernels' last prefetch operand, which is their inner grid bound:
+    the widest run of needed blocks over the call's outer blocks, for the
+    walk over key blocks and for the dk/dv kernel's walk over query blocks."""
+    from ditl_tpu.ops.flash_attention import BlockSizes, _skip_operands
+
+    seg = jnp.asarray(LAYOUTS[layout])
+    over_kv, over_q = _skip_operands(seg, seg, BlockSizes(128, 128), True)
+    for first, last, widest in (over_kv[4:], over_q[4:]):
+        assert widest.shape == (1,) and int(widest[0]) == width
+        assert int(jnp.max(last - first + 1)) == width and bool(jnp.all(first <= last))

@@ -247,3 +247,35 @@ def test_latent_flush_copies_no_pool(one_chip, tpu_branch, tail):
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < pool_elements * 2 / 10
     assert mem.alias_size_in_bytes == pool_elements * 2  # the pool in place
+
+
+@pytest.mark.parametrize("kernels", [("flash_fwd",), ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")],
+                         ids=["forward", "backward"])
+@pytest.mark.parametrize("b,h,kv,s,d", [(16, 14, 2, 2048, 64), (2, 28, 4, 4096, 128)],
+                         ids=["train-2k", "train-fsdp4-4k-a-chip"])
+def test_flash_kernels_compile_with_their_prefetch_operands(one_chip, kernels, b, h, kv, s, d):
+    """The three flash kernels as the trainer cells run them, on packed rows:
+    each takes the blocks' id ranges and the hull of its needed blocks as
+    scalar-prefetch operands and walks an inner grid axis whose bound is the
+    widest hull, a value of the call (``ops/flash_attention.py``): what
+    Mosaic makes of that, interpret mode cannot say. ``qwen2-0.5b.train-2k``'s batch of 16 rows, 14/2
+    heads of 64; ``qwen2-7b-cut4.train-fsdp4-4k``'s 2 rows a chip, 28/4 of 128."""
+    from ditl_tpu.ops.flash_attention import flash_attention
+
+    sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+
+    def attend(q, k, v, seg):
+        return flash_attention(q, k, v, causal=True, segment_ids=seg, interpret=False)
+
+    def loss(q, k, v, seg):
+        return jnp.sum(attend(q, k, v, seg).astype(jnp.float32))
+
+    fn = attend if len(kernels) == 1 else jax.grad(loss, argnums=(0, 1, 2))
+    compiled = jax.jit(fn).lower(
+        sd((b, s, h, d), jnp.bfloat16), sd((b, s, kv, d), jnp.bfloat16),
+        sd((b, s, kv, d), jnp.bfloat16), sd((b, s), jnp.int32)).compile()
+    # outside the trainer's scopes an instruction is named for its transform
+    # too (``jvp_flash_fwd_``, ``transpose_jvp_flash_bwd_dq__``)
+    calls = _instructions(compiled.as_text())
+    assert set(kernels) <= set(names.KERNELS)
+    assert all(any(k in call for call in calls) for k in kernels), calls

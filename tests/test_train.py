@@ -286,3 +286,74 @@ def test_train_step_attention_bias(tiny_model_cfg, example_batch):
     assert float(m["loss"]) < float(m0["loss"])
     b1 = np.asarray(state.params["layers"]["attn"]["bq"])
     assert np.abs(b1 - b0).max() > 0  # the bias actually trains
+
+
+# ---------------------------------------------------------------------------
+# The flash kernels' block counts in the step's metrics (ISSUE 40)
+# ---------------------------------------------------------------------------
+
+
+def _packed_batch(b=8, s=256):
+    """Rows of three documents: in the even rows the third starts on token
+    128, a block's edge, in the odd rows 8 tokens before it."""
+    rng = np.random.default_rng(1)
+    seg = np.stack([np.repeat([1, 2, 3], [64, 64 - 8 * (r % 2), s - 128 + 8 * (r % 2)])
+                    for r in range(b)])
+    return {
+        "input_ids": rng.integers(3, 500, size=(b, s)).astype(np.int32),
+        "loss_mask": np.ones((b, s), np.float32),
+        "labels": np.zeros((b,), np.int32),
+        "segment_ids": seg.astype(np.int32),
+        "positions": np.tile(np.arange(s, dtype=np.int32), (b, 1)),
+    }
+
+
+def _flash_cfg(tiny_model_cfg, **kw):
+    return dataclasses.replace(tiny_model_cfg, **{
+        "attention_impl": "flash", "head_dim": 64, "num_heads": 4, "num_kv_heads": 2,
+        "flash_block_q": 128, "flash_block_kv": 128, "max_seq_len": 256, **kw})
+
+
+def test_a_packed_flash_step_counts_its_blocks_into_the_metrics_rows(tiny_model_cfg, tmp_path):
+    import json
+
+    from ditl_tpu.train.metrics import MetricsLogger
+
+    batch = _packed_batch()
+    _, state, gb, step = _setup(_flash_cfg(tiny_model_cfg), batch,
+                                MeshConfig(data=4, tensor=2))
+    state, metrics = step(state, gb)
+    # 8 rows of 2 x 2 blocks of 128, 3 of them causally reachable; an even
+    # row's second query block is one document that its first key block
+    # holds nothing of
+    assert int(metrics["flash_blocks_reachable"]) == 24
+    assert int(metrics["flash_blocks_needed"]) == 20
+    path = tmp_path / "rows.jsonl"
+    logger = MetricsLogger(log_every=1, n_chips=1, metrics_file=str(path))
+    logger.start_step()
+    logger.end_step(0, metrics)
+    logger.close()
+    (row,) = [json.loads(ln) for ln in path.read_text().splitlines()]
+    assert (row["flash_blocks_reachable"], row["flash_blocks_needed"]) == (24.0, 20.0)
+
+
+@pytest.mark.parametrize("change,names", [
+    ({}, ("flash_blocks_reachable", "flash_blocks_needed")),
+    ({"attention_impl": "xla"}, ()),
+    ({"head_dim": 16}, ()),  # a head the kernel cannot tile: XLA runs
+    ({"no_segment_ids": True}, ()),
+    ({"sequence": 2}, ()),  # a sharded sequence: ring attention's own loop
+], ids=["flash-packed", "xla", "untileable", "no-segment-ids", "sequence-sharded"])
+def test_the_block_counts_are_reported_only_where_the_flash_kernel_runs(
+        tiny_model_cfg, change, names):
+    from ditl_tpu.parallel.sharding import DEFAULT_RULES
+    from ditl_tpu.train.step import flash_metric_names
+
+    change = dict(change)
+    batch = _packed_batch()
+    if change.pop("no_segment_ids", False):
+        del batch["segment_ids"]
+    mesh = build_mesh(MeshConfig(data=8 // change.get("sequence", 1),
+                                 sequence=change.pop("sequence", 1)))
+    cfg = _flash_cfg(tiny_model_cfg, **change)
+    assert flash_metric_names(cfg, mesh, DEFAULT_RULES, batch) == names
