@@ -211,6 +211,42 @@ class ModelConfig:
     v_head_dim: int = 0
     mla_scale_q_lora: bool = False
     mla_scale_kv_lora: bool = False
+    # A stack of UNLIKE layers (Granite-4.0-H: Mamba-2 state-space mixers
+    # beside attention): one letter a layer, ``m`` a Mamba-2 mixer, ``a``
+    # grouped-query attention, every layer followed by the dense FFN; "" =
+    # every layer attention (every other family). ``num_layers`` letters; the
+    # stack is scanned by PERIODS, the shortest prefix the string repeats
+    # (``layer_period``; models/ssm.py). A mixer has ``ssm_heads`` heads of
+    # ``ssm_head_dim`` (the inner width is their product), ONE group of
+    # ``ssm_state`` state columns, a causal depthwise convolution of
+    # ``ssm_conv`` taps with bias, and runs a whole sequence in chunks of
+    # ``ssm_chunk`` tokens (ops/ssd.py).
+    layer_types: str = ""
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_conv: int = 4
+    ssm_chunk: int = 256
+    # "rope" rotates queries and keys; "nope" applies no positional term at
+    # all (Granite-4.0-H: position comes from the recurrence alone).
+    position_embedding: str = "rope"
+    # Granite's four scalars, each 1 (or 0 = the default) elsewhere: the
+    # embedding's output times ``embedding_multiplier``; every sublayer's
+    # output times ``residual_multiplier`` before it joins the stream;
+    # attention scores times ``attention_multiplier`` in place of 1 /
+    # sqrt(head_dim) (0 = that default); logits divided by ``logits_scaling``.
+    # The dtype the residual stream is carried in between sublayers ("" =
+    # ``dtype``, every family before Granite-4.0-H). Every norm's output and
+    # so every matmul stays in ``dtype``; only the sums ``x + f(x)`` are kept
+    # wider. Granite's 80 sublayers each add 0.22 f(x) to a stream several
+    # times larger: in bfloat16 the stream's own rounding, 2^-9 of x at every
+    # sum, is most of the forward pass's error (3.4% of the logits' rms on
+    # the chip against 1.9% at mid widths with a float32 stream: PERF.md §6).
+    residual_dtype: str = ""
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
+    logits_scaling: float = 1.0
     # LoRA; rank 0 disables.
     lora_rank: int = 0
     lora_alpha: float = 16.0
@@ -302,7 +338,38 @@ class ModelConfig:
         a field until a second latent-attention family has to be told apart."""
         return self.kv_lora_rank > 0
 
+    @property
+    def layer_period(self) -> str:
+        """One period of ``layer_types``: its shortest prefix that, repeated,
+        gives the whole string ("" for a stack of identical layers)."""
+        t = self.layer_types
+        return next((t[:n] for n in range(1, len(t) + 1)
+                     if len(t) % n == 0 and t[:n] * (len(t) // n) == t), "")
+
     def __post_init__(self):
+        if self.layer_types:
+            if len(self.layer_types) != self.num_layers or set(self.layer_types) - {"m", "a"}:
+                raise ValueError(
+                    f"layer_types {self.layer_types!r} must be num_layers "
+                    f"({self.num_layers}) letters, each 'm' or 'a'")
+            if "m" in self.layer_types and not (
+                    self.ssm_heads > 0 and self.ssm_head_dim > 0 and self.ssm_state > 0
+                    and self.ssm_conv > 1 and self.ssm_chunk > 0):
+                raise ValueError(
+                    "a state-space layer ('m' in layer_types) needs ssm_heads, "
+                    "ssm_head_dim, ssm_state, ssm_conv and ssm_chunk set")
+            if self.num_experts > 0 or self.kv_lora_rank > 0 or self.lora_rank > 0 or (
+                    not self.fused_gate_up or self.fused_qkv):
+                raise ValueError(
+                    "layer_types (models/ssm.py) carries dense layers with "
+                    "fused_gate_up and unfused q/k/v only: no experts, no "
+                    "latent attention, no LoRA")
+        if self.residual_dtype and self.kv_lora_rank > 0:
+            raise ValueError(
+                "residual_dtype is not carried by the double layer (models/mla.py)")
+        if self.position_embedding not in ("rope", "nope"):
+            raise ValueError(
+                f"unknown position_embedding {self.position_embedding!r} (rope|nope)")
         # Reject-don't-drop: the MoE block has no fused gate|up layout, so
         # these flags would be silently ignored (an A/B would measure
         # byte-identical programs) — the same failure mode the dense-path

@@ -634,6 +634,45 @@ class ContinuousEngine:
                         "with cache_mode='paged', plain ticks, bfloat16 pages, "
                         "no host tier and no mesh"
                     )
+        # A hybrid stack (models/ssm.py): keys and values belong to its
+        # attention layers alone, and every state-space mixer keeps a STATE A
+        # SLOT (``self.cache["ssm"]``, ``["conv"]``: fixed size, rewritten
+        # every token) beside the page pool, in the same donated tree. What
+        # cannot carry such a state yet refuses here, by the option's name.
+        self.recurrent = "m" in model_cfg.layer_types
+        self.kv_layers = model_cfg.layer_types.count("a") or model_cfg.num_layers
+        # The width a page stores a head at. A hybrid stack's 64-wide heads
+        # are stored in whole lanes of 128, the upper half zeros (stored, and
+        # counted as stored): a pool whose last dimension is 64 lives on the
+        # chip in another layout than the kernels read, and the compiler
+        # copied both pools whole in front of every decode tick (2 x 2 GiB of
+        # temporaries in the program compiled for a described v5e).
+        self.pool_head_dim = model_cfg.head_dim
+        if self.recurrent:
+            self.pool_head_dim = -(-model_cfg.head_dim // 128) * 128
+        # live rows summed over the decode ticks' steps: each read and wrote
+        # its state once a mixer
+        self.ssm_row_steps = 0
+        if self.recurrent:
+            refused = {
+                "the contiguous cache (cache_mode='contiguous')": cache_mode != "paged",
+                "speculative ticks (speculative=True)": speculative,
+                "int8 page pools (kv_cache_dtype='int8')":
+                    model_cfg.kv_cache_dtype == "int8",
+                "the host tier (host_tier_mb)": bool(host_tier_mb),
+                "a mesh (mesh, and pod serving over it)": mesh is not None,
+                "LoRA adapters (lora_rank)": model_cfg.lora_rank > 0
+                    or "lora" in params.get("layers", {}),
+            }
+            for mode, asked in refused.items():
+                if asked:
+                    raise ValueError(
+                        f"a state-space layer (layer_types="
+                        f"{model_cfg.layer_types!r}) keeps a recurrent state a "
+                        f"slot, which {mode} cannot carry yet: serve it with "
+                        "cache_mode='paged', plain ticks, bfloat16 pages, no "
+                        "host tier, no mesh and no adapters"
+                    )
         if cache_mode == "paged":
             if model_cfg.kv_cache_dtype not in ("", "model", "int8"):
                 raise ValueError(
@@ -656,13 +695,13 @@ class ContinuousEngine:
             # (L, P, K, ps, D): kv-heads before page slots so the Pallas
             # kernel's per-head blocks keep (ps, D) trailing dims.
             shape = (
-                model_cfg.num_layers, self.n_pages, model_cfg.num_kv_heads,
-                page_size, model_cfg.head_dim,
+                self.kv_layers, self.n_pages, model_cfg.num_kv_heads,
+                page_size, self.pool_head_dim,
             )
             dt = jnp.dtype(model_cfg.dtype)
             quantized = model_cfg.kv_cache_dtype == "int8"
             scale_shape = (
-                model_cfg.num_layers, self.n_pages, model_cfg.num_kv_heads,
+                self.kv_layers, self.n_pages, model_cfg.num_kv_heads,
                 1, page_size,
             )
 
@@ -683,7 +722,12 @@ class ContinuousEngine:
                         "ks": jnp.ones(scale_shape, jnp.float32),
                         "vs": jnp.ones(scale_shape, jnp.float32),
                     }
-                return {"kp": jnp.zeros(shape, dt), "vp": jnp.zeros(shape, dt)}
+                pools = {"kp": jnp.zeros(shape, dt), "vp": jnp.zeros(shape, dt)}
+                if self.recurrent:
+                    from ditl_tpu.models.ssm import init_state
+
+                    pools.update(init_state(model_cfg, n_slots))
+                return pools
 
             if mesh is not None:
                 from ditl_tpu.ops.attention import _mesh_axes_size
@@ -753,14 +797,14 @@ class ContinuousEngine:
             # (_host_swap_in) — the effective shared-prefix working set
             # becomes a config knob instead of a hardware constant.
             per_val = (
-                model_cfg.num_layers * model_cfg.num_kv_heads
-                * page_size * model_cfg.head_dim
+                self.kv_layers * model_cfg.num_kv_heads
+                * page_size * self.pool_head_dim
             )
             if self.latent:
                 self.page_bytes = math.prod(shape) // self.n_pages * dt.itemsize
             elif quantized:
                 scale_vals = (
-                    model_cfg.num_layers * model_cfg.num_kv_heads * page_size
+                    self.kv_layers * model_cfg.num_kv_heads * page_size
                 )
                 self.page_bytes = 2 * per_val + 2 * scale_vals * 4
             else:
@@ -1815,6 +1859,20 @@ class ContinuousEngine:
         def paged_prefill(params, pools, table_row, ids, offset, s_len, temp,
                           top_p, rng, write_pids, aid, *fsm):
             row = gather(pools, table_row)
+            rec_kw = {}
+            if self.recurrent:
+                # The slot's recurrent state rides the transient row: what a
+                # chunk before this one left, or zeros at a sequence's start
+                # (whatever the slot's last tenant left is never read). The
+                # bucket's padding leaves it at the last real token's.
+                from ditl_tpu.models.ssm import SLOT_AXIS
+
+                slot, *fsm = fsm
+                for k, axis in SLOT_AXIS.items():
+                    was = jax.lax.dynamic_slice_in_dim(pools[k], slot, 1, axis=axis)
+                    row[k] = jnp.where(offset > 0, was, jnp.zeros_like(was))
+                real = jnp.arange(s_bucket, dtype=jnp.int32)[None, :] < s_len
+                rec_kw = {"token_mask": real}
             q_pos = offset + jnp.arange(s_bucket, dtype=jnp.int32)
             if maxp == 0:
                 # No context pages (offset 0): pure causal self-attention
@@ -1826,6 +1884,7 @@ class ContinuousEngine:
                     cache=row, cache_index=offset,
                     mesh=self.mesh, rules=self.rules, prefill_causal=True,
                     adapter_ids=aid if self.multi_lora else None, **moe_kw(s_len),
+                    **rec_kw,
                 )
             else:
                 mask = buf_iota[None, None, :] <= q_pos[None, :, None]
@@ -1834,9 +1893,14 @@ class ContinuousEngine:
                     cache=row, cache_index=offset, attn_mask=mask,
                     mesh=self.mesh, rules=self.rules,
                     adapter_ids=aid if self.multi_lora else None, **moe_kw(s_len),
+                    **rec_kw,
                 )
             with jax.named_scope("kv_write"):
                 out = write(pools, row, offset, write_pids)
+                if self.recurrent:  # seat the slot's state
+                    for k, axis in SLOT_AXIS.items():
+                        out[k] = jax.lax.dynamic_update_slice_in_dim(
+                            pools[k], row[k], slot, axis=axis)
             last = logits[0, s_len - 1]
             masked = _fsm_mask(fsm[0], fsm[1], last) if self.guided else last
             first = sample_logits(
@@ -1863,7 +1927,7 @@ class ContinuousEngine:
         pad, eos = self.tokenizer.pad_id, self.tokenizer.eos_id
         chunk = self.decode_chunk
         tail_len = tail_width(chunk)
-        L, K, D = cfg.num_layers, cfg.num_kv_heads, cfg.head_dim
+        L, K, D = cfg.num_layers, cfg.num_kv_heads, self.pool_head_dim
         dt = jnp.dtype(cfg.dtype)
 
         track = self.speculative
@@ -1871,6 +1935,7 @@ class ContinuousEngine:
 
         guided = self.guided
         moe = self.moe
+        recurrent = self.recurrent
         from ditl_tpu.models.moe import count_width, split_counts
 
         def paged_decode(params, pools, cur, pos, alive, temps, top_ps, keys,
@@ -1889,11 +1954,17 @@ class ContinuousEngine:
                 tails0 = {"tc": jnp.zeros(
                     (L, SUBLAYERS, n_b, tail_len, latent_width(cfg)), dt)}
             else:
-                tails0 = {"tk": jnp.zeros((L, n_b, K, tail_len, D), dt),
-                          "tv": jnp.zeros((L, n_b, K, tail_len, D), dt)}
+                tails0 = {"tk": jnp.zeros((self.kv_layers, n_b, K, tail_len, D), dt),
+                          "tv": jnp.zeros((self.kv_layers, n_b, K, tail_len, D), dt)}
             # Read-only during the scan, and whole: llama.forward keeps them
             # out of its layer loop and offsets each layer's page table.
-            cache_const = dict(pools)
+            cache_const = {k: v for k, v in pools.items() if k not in ("ssm", "conv")}
+            if recurrent:
+                # The slots' recurrent state is rewritten every step: it
+                # rides the scan's carry beside the tails (their keys are its
+                # own), each mixer updating its entry in place, and
+                # ``moe_acc`` counts the live rows that did.
+                tails0 = {**tails0, "ssm": pools["ssm"], "conv": pools["conv"]}
 
             def body(carry, t):
                 tails, cur, pos, done, keys, hist, fst, lp, moe_acc = carry
@@ -1918,7 +1989,10 @@ class ContinuousEngine:
                     adapter_ids=adapters if self.multi_lora else None,
                     **({"token_mask": step_alive[:, None],
                         "with_moe_counts": True} if moe else {}),
+                    **({"token_mask": step_alive[:, None]} if recurrent else {}),
                 )
+                if recurrent:
+                    moe_acc = (moe_acc[0] + step_alive.sum(dtype=jnp.int32),)
                 if moe:
                     # the live rows' assignments; the experts they touched
                     # (of those whose weights live here)
@@ -1959,6 +2033,8 @@ class ContinuousEngine:
                      jnp.zeros((), jnp.int32),
                      *((jnp.zeros((), jnp.int32),) if self.latent else ()))
                     if moe else ())
+            if recurrent:
+                moe0 = (jnp.zeros((), jnp.int32),)
             (tails, cur, pos, done, keys, hist, fst, lp, moe_acc), ys = jax.lax.scan(
                 # A row whose pending token is the pad already ended in an
                 # earlier tick (``cur = where(done, pad, nxt)``): the dead
@@ -1973,9 +2049,11 @@ class ContinuousEngine:
                 out = _flush_latent_tail(pools, tails["tc"], starts, pos, table)
             else:
                 out = _flush_tail_into_pools(
-                    pools, tails["tk"], tails["tv"], starts, pos, table,
+                    cache_const, tails["tk"], tails["tv"], starts, pos, table,
                     self.mesh, self.rules
                 )
+                if recurrent:
+                    out.update(ssm=tails["ssm"], conv=tails["conv"])
             fs = (fst,) if guided else ()
             if n_lp:
                 toks, c, i, t = ys
@@ -2147,6 +2225,11 @@ class ContinuousEngine:
         device memory until ``clear_prefixes``."""
         if not prefix_tokens:
             raise ValueError("prefix must be non-empty")
+        if self.recurrent:
+            raise ValueError(
+                "register_prefix cannot serve a state-space layer: a prefix's "
+                "pages are reusable only with the recurrent state at their "
+                "boundary, which nothing keeps yet")
         if self.multi_lora:
             raise ValueError(
                 "register_prefix with a multi-adapter stack is unsupported "
@@ -2676,6 +2759,8 @@ class ContinuousEngine:
 
     def _publish_tokens(self, tokens: list[int], slot: int,
                         adapter_id: int = 0) -> None:
+        if self.recurrent:
+            return  # pages without the state at their boundary serve nobody
         ps = self.page_size
         n_full = len(tokens) // ps
         self.allocator.publish_chain(
@@ -2864,9 +2949,10 @@ class ContinuousEngine:
         (``ThreadedEngine.call``)."""
         if self.cache_mode != "paged":
             raise BadRequestError("KV handoff requires cache_mode='paged'")
-        if self.latent:
+        if self.latent or self.recurrent:
             raise BadRequestError(
-                "the disaggregated KV handoff cannot carry latent pages yet")
+                "the disaggregated KV handoff (export_kv / import_kv) cannot "
+                "carry latent pages or a recurrent state yet")
         if adapter_id:
             raise BadRequestError("KV handoff serves the base adapter only")
         ps = self.page_size
@@ -2923,9 +3009,10 @@ class ContinuousEngine:
 
         if self.cache_mode != "paged":
             raise BadRequestError("KV handoff requires cache_mode='paged'")
-        if self.latent:
+        if self.latent or self.recurrent:
             raise BadRequestError(
-                "the disaggregated KV handoff cannot carry latent pages yet")
+                "the disaggregated KV handoff (export_kv / import_kv) cannot "
+                "carry latent pages or a recurrent state yet")
         meta, pages = deserialize_pages(blob)
         want = {
             "page_size": self.page_size,
@@ -3058,6 +3145,7 @@ class ContinuousEngine:
             jnp.asarray(row), jnp.asarray(ids), jnp.int32(d),
             jnp.int32(s), jnp.float32(temp), jnp.float32(top_p), rng,
             jnp.asarray(pids), jnp.asarray([adapter], jnp.int32),
+            *((jnp.int32(slot),) if self.recurrent else ()),
             *self._fsm_args(fsm_start),
         )
         if self.moe:
@@ -3117,7 +3205,10 @@ class ContinuousEngine:
         if req.preempted:
             return self._resume_paged_slot(slot, req)
         ps = self.page_size
-        matched = self.allocator.match_prefix(
+        # A hit on pages would skip tokens whose recurrent state nobody kept:
+        # with a state-space layer the content cache is not consulted (and
+        # ``_publish_tokens`` publishes nothing).
+        matched = [] if self.recurrent else self.allocator.match_prefix(
             req.prompt, ps, root=-req.adapter_id
         )  # retained
         # Host-tier swap-in (ISSUE 13): extend the HBM run from the host
@@ -3210,7 +3301,9 @@ class ContinuousEngine:
         ctx = req.prompt + req.tokens
         pos = len(ctx)  # cur's write position
         cap = len(req.prompt) + req.max_new_tokens
-        matched = self.allocator.match_prefix(ctx, ps, root=-req.adapter_id)
+        # a recurrent state was dropped with the slot: all of ctx runs again
+        matched = [] if self.recurrent else self.allocator.match_prefix(
+            ctx, ps, root=-req.adapter_id)
         # Budget gate: the resume's chunks run back-to-back inside THIS
         # admission (they never interleave across ticks — see below), so
         # the whole unmatched remainder is this tick's prefill cost.
@@ -4350,6 +4443,8 @@ class ContinuousEngine:
         if self.moe:  # paged: the tick's (L, E) counts and touched sum
             n_moe = 3 if self.latent else 2  # and the context tokens read
             res, moe_dev = res[:-n_moe], tuple(res[-n_moe:])
+        elif self.recurrent and self.cache_mode == "paged":
+            res, moe_dev = res[:-1], tuple(res[-1:])  # the tick's row steps
         if self.guided:
             (self.cache, self.cur, self.pos, self.keys, self.hist,
              self.fstates, *res_rest) = res
@@ -4380,7 +4475,12 @@ class ContinuousEngine:
         lp = tuple(np.asarray(x) for x in lp_np) if lp_dev is not None else None
         toks = np.asarray(toks)
         self._phase("engine.tick.harvest")
-        if moe_np:
+        if self.recurrent:
+            self.ssm_row_steps += int(moe_np[0])
+            if self._tick_span is not None:
+                self._tick_span.annotate(ssm_row_steps=int(moe_np[0]),
+                                         ssm_steps=self.decode_chunk)
+        elif moe_np:
             self._note_moe(moe_np, pending_np)
         if self.speculative and (not self.pipeline_ticks or self._probe_timing):
             # See _spec_finish: pipelined intervals are not device cost,
@@ -4828,6 +4928,14 @@ class ContinuousEngine:
                 out["resume_prefill_tokens"] = self.resume_prefill_tokens
         if self.multi_lora:
             out["adapters"] = self.n_adapters
+        if self.recurrent:
+            from ditl_tpu.models.ssm import state_bytes_per_slot
+
+            per_slot = state_bytes_per_slot(self.cfg)
+            out["ssm_state_bytes_per_slot"] = per_slot
+            out["ssm_state_bytes_resident"] = per_slot * self.n_slots
+            out["ssm_slots_seated"] = sum(r is not None for r in self._slots)
+            out["ssm_row_steps_total"] = self.ssm_row_steps
         if self.moe:
             # Live rows of the paged decode ticks and real tokens of the
             # paged prefills only; the touched mean is per decode step and

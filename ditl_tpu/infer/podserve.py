@@ -420,6 +420,10 @@ class PodContinuousDriver:
             raise ValueError(
                 "pod serving cannot carry a latent page pool yet (latent "
                 "attention is served by one process on one chip)")
+        if getattr(engine, "recurrent", False):
+            raise ValueError(
+                "pod serving cannot carry a recurrent state a slot yet (a "
+                "state-space layer is served by one process on one chip)")
         self._engine = engine
         # Per-host wall-clock calibration would desync pod tick decisions.
         engine.freeze_spec_threshold()

@@ -1,4 +1,4 @@
-"""HuggingFace checkpoint import: torch Llama/Qwen2/Mixtral/OLMoE/LongCat-Flash weights -> param pytree.
+"""HuggingFace checkpoint import: torch Llama/Qwen2/Mixtral/OLMoE/LongCat-Flash/Granite-4.0-H weights -> param pytree.
 
 The reference never loads weights at all — its Llama-3.1-70B lives behind an
 HTTP API (ref ``src/distributed_inference.py:34-41``, ``MODEL_NAME`` in
@@ -198,6 +198,80 @@ def _double_layer_state_dict(layers, cfg: ModelConfig, host) -> dict[str, np.nda
     return sd
 
 
+# Granite-4.0-H's hybrid stack (models/ssm.py): our leaf under a position's
+# subtree -> the ``granitemoehybrid`` checkpoint's name under
+# ``model.layers.{i}`` (``modeling_granitemoehybrid.py`` as recalled, never
+# held against a published checkpoint: the mixer is ``mamba`` with
+# ``in_proj`` [z | xBC | dt], a depthwise ``conv1d`` whose weight is (C, 1,
+# K), ``dt_bias``, ``A_log``, ``D``, the gated ``norm`` and ``out_proj``; the
+# FFN is ``shared_mlp`` with a fused gate|up ``input_linear`` and
+# ``output_linear``; attention layers are ``self_attn`` as Llama's). Layer
+# ``i`` is position ``i % period`` of period ``i // period``.
+_HYBRID_MIXER = {"dt_bias": "mamba.dt_bias", "A_log": "mamba.A_log", "D": "mamba.D",
+                 "norm": "mamba.norm.weight", "conv_b": "mamba.conv1d.bias"}
+_HYBRID_ATTN = {"wq": "q_proj", "wk": "k_proj", "wv": "v_proj", "wo": "o_proj"}
+
+
+def _hybrid_layer_from_state_dict(sd, cfg: ModelConfig, i: int) -> dict[str, Any]:
+    p = f"model.layers.{i}."
+    inner = cfg.ssm_heads * cfg.ssm_head_dim
+    layer: dict[str, Any] = {
+        "attn_norm": {"scale": _np(sd[p + "input_layernorm.weight"])},
+        "mlp_norm": {"scale": _np(sd[p + "post_attention_layernorm.weight"])},
+        "mlp": {"w_gu": _np(sd[p + "shared_mlp.input_linear.weight"]).T,
+                "w_down": _np(sd[p + "shared_mlp.output_linear.weight"]).T},
+    }
+    if cfg.layer_types[i] == "a":
+        layer["attn"] = {ours: _np(sd[p + f"self_attn.{theirs}.weight"]).T
+                         for ours, theirs in _HYBRID_ATTN.items()}
+        return layer
+    w_in = _np(sd[p + "mamba.in_proj.weight"]).T  # (D, z | xBC | dt)
+    layer["ssm"] = {
+        "w_in": w_in[:, :-cfg.ssm_heads], "w_dt": w_in[:, -cfg.ssm_heads:],
+        "conv_w": _np(sd[p + "mamba.conv1d.weight"])[:, 0, :].T,  # (C, 1, K) -> (K, C)
+        "w_out": _np(sd[p + "mamba.out_proj.weight"]).T,
+        **{ours: _np(sd[p + theirs]) for ours, theirs in _HYBRID_MIXER.items()},
+    }
+    assert w_in.shape[1] == 2 * inner + 2 * cfg.ssm_state + cfg.ssm_heads
+    return layer
+
+
+def _hybrid_from_state_dict(sd, cfg: ModelConfig, cast) -> dict[str, Any]:
+    import jax
+
+    period = len(cfg.layer_period)
+    return {
+        f"sub{j}": jax.tree.map(
+            lambda *leaves: cast(np.stack(leaves)),
+            *[_hybrid_layer_from_state_dict(sd, cfg, i)
+              for i in range(j, cfg.num_layers, period)])
+        for j in range(period)
+    }
+
+
+def _hybrid_state_dict(layers, cfg: ModelConfig, host) -> dict[str, np.ndarray]:
+    period = len(cfg.layer_period)
+    sd: dict[str, np.ndarray] = {}
+    for i in range(cfg.num_layers):
+        sub, n, p = layers[f"sub{i % period}"], i // period, f"model.layers.{i}."
+        sd[p + "input_layernorm.weight"] = host(sub["attn_norm"]["scale"][n])
+        sd[p + "post_attention_layernorm.weight"] = host(sub["mlp_norm"]["scale"][n])
+        sd[p + "shared_mlp.input_linear.weight"] = host(sub["mlp"]["w_gu"][n]).T
+        sd[p + "shared_mlp.output_linear.weight"] = host(sub["mlp"]["w_down"][n]).T
+        if "attn" in sub:
+            for ours, theirs in _HYBRID_ATTN.items():
+                sd[p + f"self_attn.{theirs}.weight"] = host(sub["attn"][ours][n]).T
+            continue
+        m = sub["ssm"]
+        sd[p + "mamba.in_proj.weight"] = np.concatenate(
+            [host(m["w_in"][n]), host(m["w_dt"][n])], axis=1).T
+        sd[p + "mamba.conv1d.weight"] = host(m["conv_w"][n]).T[:, None, :]
+        sd[p + "mamba.out_proj.weight"] = host(m["w_out"][n]).T
+        for ours, theirs in _HYBRID_MIXER.items():
+            sd[p + theirs] = host(m[ours][n])
+    return sd
+
+
 def params_from_state_dict(
     sd: Mapping[str, Any], cfg: ModelConfig, dtype: str | None = None
 ) -> dict[str, Any]:
@@ -213,6 +287,15 @@ def params_from_state_dict(
     def cast(x: np.ndarray) -> np.ndarray:
         return x.astype(pd)
 
+    if cfg.layer_types:  # Granite-4.0-H: a subtree a position of the period
+        tree = {
+            "embed": {"embedding": cast(_np(sd["model.embed_tokens.weight"]))},
+            "layers": _hybrid_from_state_dict(sd, cfg, cast),
+            "final_norm": {"scale": cast(_np(sd["model.norm.weight"]))},
+        }
+        if not cfg.tie_embeddings:
+            tree["lm_head"] = {"kernel": cast(_np(sd["lm_head.weight"]).T)}
+        return tree
     if cfg.double_layer:  # LongCat-Flash; a share loads only the experts it holds
         return {
             "embed": {"embedding": cast(_np(sd["model.embed_tokens.weight"]))},
@@ -339,6 +422,11 @@ def state_dict_from_params(params: Mapping[str, Any], cfg: ModelConfig) -> dict[
         "model.embed_tokens.weight": host(params["embed"]["embedding"]),
         "model.norm.weight": host(params["final_norm"]["scale"]),
     }
+    if cfg.layer_types:
+        sd.update(_hybrid_state_dict(layers, cfg, host))
+        if not cfg.tie_embeddings:
+            sd["lm_head.weight"] = host(params["lm_head"]["kernel"]).T
+        return sd
     if cfg.double_layer:
         sd.update(_double_layer_state_dict(layers, cfg, host))
         sd["lm_head.weight"] = host(params["lm_head"]["kernel"]).T

@@ -79,6 +79,20 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
             "lm_head": {"kernel": dense(next(keys), (d, cfg.vocab_size), d)},
         }
 
+    if cfg.layer_types:
+        # Granite-4.0-H (models/ssm.py): a subtree a position of the period
+        from ditl_tpu.models.ssm import init_hybrid_params
+
+        tree = {
+            "embed": {"embedding": (
+                jax.random.normal(next(keys), (cfg.vocab_size, d)) * 0.02).astype(pd)},
+            "layers": init_hybrid_params(next(keys), cfg),
+            "final_norm": {"scale": jnp.ones((d,), pd)},
+        }
+        if not cfg.tie_embeddings:
+            tree["lm_head"] = {"kernel": dense(next(keys), (d, cfg.vocab_size), d)}
+        return tree
+
     params: Params = {
         "embed": {
             "embedding": (jax.random.normal(next(keys), (cfg.vocab_size, d)) * 0.02).astype(pd)
@@ -154,6 +168,15 @@ def param_logical_axes(cfg: ModelConfig) -> Params:
             "layers": double_layer_logical_axes(cfg),
             "final_norm": {"scale": ("norm",)},
             "lm_head": {"kernel": ("embed", "vocab")},
+        }
+    if cfg.layer_types:
+        from ditl_tpu.models.ssm import hybrid_logical_axes
+
+        return {
+            "embed": {"embedding": ("vocab", "embed")},
+            "layers": hybrid_logical_axes(cfg),
+            "final_norm": {"scale": ("norm",)},
+            **({} if cfg.tie_embeddings else {"lm_head": {"kernel": ("embed", "vocab")}}),
         }
     axes: Params = {
         "embed": {"embedding": ("vocab", "embed")},
@@ -469,7 +492,7 @@ def _decoder_layer(
 
     # Attention block. The scopes are the step's stable names (ops/names.py).
     with jax.named_scope("attn_qkv"):
-        h = rms_norm(x, layer_params["attn_norm"]["scale"], cfg.rms_norm_eps)
+        h = rms_norm(x, layer_params["attn_norm"]["scale"], cfg.rms_norm_eps).astype(cd)
         # Named for remat="dots_inputs": h is the qkv projections' WGRAD
         # operand — saving it keeps the backward's weight-gradient GEMMs fed
         # from a stored buffer instead of a recompute chain (r5 ablation:
@@ -511,8 +534,28 @@ def _decoder_layer(
             q = _heads(proj(h, attn["wq"], "wq"), nh, "bq", "q_norm")
             k = _heads(proj(h, attn["wk"], "wk"), nkv, "bk", "k_norm")
             v = _heads(proj(h, attn["wv"], "wv"), nkv, "bv")
-        q = apply_rope(q, positions, cfg=cfg)
-        k = apply_rope(k, positions, cfg=cfg)
+        if cfg.position_embedding == "rope":
+            q = apply_rope(q, positions, cfg=cfg)
+            k = apply_rope(k, positions, cfg=cfg)
+        # The width the cache stores a head at: ``head_dim``, or whole lanes
+        # of 128 where the engine pads a narrower head's pages with zeros
+        # (infer/continuous.py ``pool_head_dim``). Zeros add nothing to a
+        # score and come back as zero columns of the output, cut off below.
+        width = hd
+        if pools is not None:
+            width = pools["kp"].shape[-1]
+        elif layer_cache is not None:
+            width = layer_cache["k"].shape[-1]
+        if cfg.attention_multiplier or width != hd:
+            # every attention path scales its scores by 1 / sqrt(the width it
+            # sees): the query carries the ratio to the configured scale
+            # (Granite's 1 / 64 over 128 stored lanes: 0.177; over its own 64:
+            # 1 / 8, exact in any float)
+            scale = cfg.attention_multiplier or hd ** -0.5
+            q = (q.astype(jnp.float32) * (scale * math.sqrt(width))).astype(q.dtype)
+        if width != hd:
+            lanes = [(0, 0)] * 3 + [(0, width - hd)]
+            q, k, v = jnp.pad(q, lanes), jnp.pad(k, lanes), jnp.pad(v, lanes)
         q = _constrain(q, ("batch", "seq", "act_heads", "head_dim"), mesh, rules)
         k = _constrain(k, ("batch", "seq", "act_kv_heads", "head_dim"), mesh, rules)
     new_kv = None
@@ -597,19 +640,23 @@ def _decoder_layer(
                 block_sizes=(cfg.flash_block_q, cfg.flash_block_kv,
                              cfg.flash_block_q_bwd, cfg.flash_block_kv_bwd),
             )
+    if width != hd:
+        attn_out = attn_out.reshape(b, s, nh, width)[..., :hd]
     attn_out = attn_out.reshape(b, s, nh * hd)
     # Named for the remat="attn" policy: saving this one activation means the
     # backward pass never re-runs the attention kernel itself (its recompute
     # is the expensive part of full remat), while everything else (norms,
     # projections, SwiGLU) is still rematerialized.
     attn_out = checkpoint_name(attn_out, "attn_out")
+    res = cfg.residual_multiplier  # Granite's; 1 leaves the sums as they were
     with jax.named_scope("attn_out"):
-        x = x + proj(attn_out, attn["wo"], "wo")
+        out = proj(attn_out, attn["wo"], "wo")
+        x = x + (out if res == 1.0 else res * out)
         x = _constrain(x, ("batch", "seq", "act_embed"), mesh, rules)
 
     # MLP / MoE block
     with jax.named_scope("mlp"):
-        h = rms_norm(x, layer_params["mlp_norm"]["scale"], cfg.rms_norm_eps)
+        h = rms_norm(x, layer_params["mlp_norm"]["scale"], cfg.rms_norm_eps).astype(cd)
         h = checkpoint_name(h, "mlp_in")  # gate/up wgrad operand (see attn_in)
         aux = jnp.zeros((), jnp.float32)
         moe_counts = None
@@ -623,7 +670,7 @@ def _decoder_layer(
             )
         else:
             mlp_out = dense_mlp(layer_params["mlp"], h, cfg=cfg, mesh=mesh, rules=rules)
-        x = x + mlp_out
+        x = x + (mlp_out if res == 1.0 else res * mlp_out)
         x = _constrain(x, ("batch", "seq", "act_embed"), mesh, rules)
     out = (x, aux) if new_kv is None else (x, aux, new_kv)
     if with_moe_counts:
@@ -711,12 +758,32 @@ def forward(
             params["embed"]["embedding"].astype(cd), ("vocab", None), mesh, rules
         )
         x = table[input_ids]
+        if cfg.embedding_multiplier != 1.0:
+            x = x * cfg.embedding_multiplier
+        if cfg.residual_dtype:
+            # the stream alone; every norm's output goes back to ``dtype``
+            x = x.astype(_dtype(cfg.residual_dtype))
         x = _constrain(x, ("batch", "seq", "act_embed"), mesh, rules)
 
-    block = _decoder_layer
+    block, n_scan = _decoder_layer, cfg.num_layers
     if cfg.double_layer:
         # LongCat-Flash: the same scans carry its double layer (models/mla.py)
         from ditl_tpu.models.mla import double_layer as block
+    rec = None  # a hybrid stack's recurrent state, carried beside the stream
+    if cfg.layer_types:
+        # Granite-4.0-H: one scan step a PERIOD of unlike layers (models/ssm.py)
+        from ditl_tpu.models.ssm import hybrid_period as block
+        from ditl_tpu.models.ssm import period_counts
+
+        n_scan, _, attn_per = period_counts(cfg)
+        if cache is not None:
+            if "ssm" not in cache:
+                raise ValueError(
+                    "a cached forward of a hybrid stack needs the sequences' "
+                    "recurrent state beside the keys and values (cache['ssm'], "
+                    "cache['conv']: models/ssm.py init_state)")
+            rec = {k: cache[k] for k in ("ssm", "conv")}
+            cache = {k: v for k, v in cache.items() if k not in rec}
 
     if cache is not None:
         layers, moe_stack = params["layers"], None
@@ -748,6 +815,14 @@ def forward(
             if "cp" in pools:
                 paged = {**paged, "n_pages": n_pages}
                 n_pages *= 2
+            elif rec is not None:
+                paged = {**paged, "n_pages": n_pages}
+                n_pages *= attn_per
+        if rec is not None:
+            # keys and values are the attention layers': (periods, a period's
+            # attention layers, ...) for the scan, and back after it
+            cache = {k: v.reshape(n_scan, attn_per, *v.shape[1:])
+                     for k, v in cache.items()}
 
         def cached_layer_fn(carry, xs):
             layer_params, layer_cache, layer_index = xs
@@ -755,6 +830,9 @@ def forward(
             if pools is not None:
                 layer_paged = {
                     **paged, "table": paged["table"] + layer_index * n_pages}
+            extra = {}
+            if rec is not None:
+                carry, extra["rec"] = carry
             y, aux, new_kv, *counts = block(
                 layer_params,
                 carry,
@@ -774,7 +852,11 @@ def forward(
                 moe_stack=moe_stack,
                 layer_index=layer_index,
                 pools=pools,
+                **extra,
             )
+            if rec is not None:
+                *counts, new_rec = counts
+                y = (y, new_rec)
             return y, (aux, new_kv, *counts)
 
         # Every layer part has a scope of its own, so what is left to this
@@ -783,9 +865,13 @@ def forward(
         # stacking the new K/V.
         with jax.named_scope("layer_scan"):
             x, (layer_aux, new_cache, *moe_counts) = jax.lax.scan(
-                cached_layer_fn, x,
-                (layers, cache, jnp.arange(cfg.num_layers, dtype=jnp.int32)),
+                cached_layer_fn, x if rec is None else (x, rec),
+                (layers, cache, jnp.arange(n_scan, dtype=jnp.int32)),
             )
+        if rec is not None:
+            x, rec = x
+            new_cache = {k: v.reshape(-1, *v.shape[2:])
+                         for k, v in new_cache.items()} | rec
     elif mesh is not None and mesh.shape.get("stage", 1) > 1:
         # Pipeline parallelism: layers are stage-sharded; microbatches flow
         # through the stages via ppermute (parallel/pipeline.py). Layer bodies
@@ -840,6 +926,11 @@ def forward(
 
     with jax.named_scope("lm_head"):
         x = rms_norm(x, params["final_norm"]["scale"], cfg.rms_norm_eps)
+        if cfg.logits_scaling != 1.0:
+            # the head is linear: dividing its input divides the logits, for
+            # the fused loss (which applies the head itself) as for this one
+            x = x / cfg.logits_scaling
+        x = x.astype(cd)  # the head's input, whatever the stream was kept in
     # what follows the logits (or the hidden states), in this order
     tail = ((jnp.sum(layer_aux),) if with_aux else ()) + (
         (new_cache,) if cache is not None else ()) + tuple(moe_counts)

@@ -171,6 +171,39 @@ PRESETS: dict[str, ModelConfig] = {
         routed_scaling_factor=6.0,
         router_bias=True,
     ),
+    # Granite-4.0-H-Micro (ibm-granite, 3.19 B parameters, model_type
+    # granitemoehybrid with no routed experts in this size): 40 layers in
+    # four periods of nine Mamba-2 mixers and one grouped-query attention
+    # layer (indices 5, 15, 25, 35), no positional embedding, a fused gate|up
+    # FFN of 8,192 after every mixer, tied head, and Granite's four scalars.
+    # Whole on one chip in bfloat16 (5.94 GiB); models/ssm.py.
+    "granite-4.0-h-micro": ModelConfig(
+        name="granite-4.0-h-micro",
+        vocab_size=100352,
+        hidden_size=2048,
+        intermediate_size=8192,
+        num_layers=40,
+        num_heads=32,
+        num_kv_heads=8,
+        head_dim=64,
+        max_seq_len=131072,
+        rope_theta=10000.0,
+        rms_norm_eps=1e-5,
+        tie_embeddings=True,
+        fused_gate_up=True,
+        layer_types="mmmmmammmm" * 4,
+        ssm_heads=64,
+        ssm_head_dim=64,
+        ssm_state=128,
+        ssm_conv=4,
+        ssm_chunk=256,
+        position_embedding="nope",
+        residual_dtype="float32",
+        embedding_multiplier=12.0,
+        residual_multiplier=0.22,
+        attention_multiplier=0.015625,
+        logits_scaling=8.0,
+    ),
 }
 
 

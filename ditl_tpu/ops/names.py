@@ -39,7 +39,17 @@ of the absorption; ``mla_attn`` inside ``attn_core``: a decode step's
 attention over the latent pool (the kernel ``mla_paged_attention`` and what
 surrounds it). The latent's write into the tick's tail and the tail's flush
 are ``kv_write``. ``moe_zero`` (``MOE_ZERO_SCOPES``), inside ``moe_combine``:
-the zero-compute experts' weighted identity."""
+the zero-compute experts' weighted identity.
+
+``SSM_SCOPES`` are the parts of a Mamba-2 state-space mixer (``models/
+ssm.py``), each INSIDE the scope of ``SCOPES`` it refines: ``ssm_in`` (the
+input projection, the causal convolution and its activation, the step sizes)
+inside ``attn_qkv``; ``ssm_scan`` (a whole sequence's chunked scan, or a
+decode step's read of the row's state, its update, ``S C`` and the write
+back) inside ``attn_core``; ``ssm_out`` (the gate, the norm and the output
+projection) inside ``attn_out``. The seat of a slot's state after a prefill
+is ``kv_write``. ``ssd_step`` (``SSM_KERNELS``), inside ``ssm_scan``: the
+decode step's update of the live rows' states in place (``ops/ssd.py``)."""
 
 SCOPES = (
     "embed",
@@ -70,6 +80,15 @@ MLA_SCOPES = (
 )
 
 MOE_ZERO_SCOPES = ("moe_zero",)
+
+SSM_SCOPES = (
+    "ssm_in",
+    "ssm_scan",
+    "ssm_out",
+)
+
+# The decode step's state update (``ops/ssd.py``), inside ``ssm_scan``.
+SSM_KERNELS = ("ssd_step",)
 
 # Decode attention over a latent page pool (``ops/mla_attention.py``), inside
 # ``mla_attn``.

@@ -28,7 +28,9 @@ __all__ = ["quantize_weights", "is_quantized_leaf", "weight_einsum"]
 _QUANT_KEYS = ("wq", "wk", "wv", "w_qkv", "wo", "w_gate", "w_up", "w_gu", "w_down", "kernel",
                # latent attention's projections (models/mla.py); ``w_kvb`` stays
                # float: its halves are folded into the query and the output
-               "w_qa", "w_qb", "w_kva")
+               "w_qa", "w_qb", "w_kva",
+               # a state-space mixer's projections (models/ssm.py)
+               "w_in", "w_dt", "w_out")
 
 
 def is_quantized_leaf(w: Any) -> bool:

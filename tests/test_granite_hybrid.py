@@ -1,0 +1,202 @@
+"""Granite-4.0-H's stack (ISSUE 41): the period scan of Mamba-2 mixers and
+attention layers against the plain reference, the chunked recurrence against
+its token-by-token form, the trainer's loss and gradients, the checkpoint
+names. Tiny sizes on the CPU, seeded random weights; the serving engine has
+tests/test_ssm_serving.py."""
+
+from __future__ import annotations
+
+import dataclasses
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(ROOT, "benchmarks")
+for _p in (ROOT, BENCH):
+    if _p not in sys.path:
+        sys.path.insert(0, _p)
+
+from harness import load_module  # noqa: E402
+
+from ditl_tpu.models import llama, ssm  # noqa: E402
+from ditl_tpu.models.presets import get_preset  # noqa: E402
+from ditl_tpu.ops import ssd  # noqa: E402
+
+ref = load_module(os.path.join(BENCH, "reference", "granite_hybrid.py"))
+
+# Both sides compute in float32 on the same weights and differ in the order
+# of their sums (chunks of 16 with masked matmuls against a scan over tokens;
+# a scan over periods against a loop over layers): 1e-6 relative is what
+# float32 leaves of that; 1e-4 gives it a hundred times of room and is twenty
+# times under what a bfloat16 state (2^-9 a rounding, every token) leaves.
+TOL = 1e-4
+
+TINY = dict(vocab_size=512, hidden_size=32, intermediate_size=64, num_heads=4, num_kv_heads=2,
+            head_dim=8, ssm_heads=4, ssm_head_dim=16, ssm_state=8, ssm_chunk=16,
+            attention_multiplier=1 / 16, max_seq_len=1024, dtype="float32", remat="none")
+
+
+def tiny(period="mma", periods=1, **kw):
+    return dataclasses.replace(
+        get_preset("granite-4.0-h-micro"),
+        **{"num_layers": len(period) * periods, "layer_types": period * periods, **TINY, **kw})
+
+
+def rel(got, want):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    return float(np.sqrt(np.mean((got - want) ** 2)) / np.sqrt(np.mean(want ** 2)))
+
+
+def seeded(cfg, seed=0):
+    return ref.perturb(llama.init_params(jax.random.key(seed), cfg), cfg, seed)
+
+
+def packed(rows, s, cuts):
+    seg = np.ones((rows, s), np.int32)
+    for r, row_cuts in enumerate(cuts):
+        seg[r] = np.searchsorted(np.asarray(row_cuts), np.arange(s), side="right") + 1
+    return jnp.asarray(seg)
+
+
+@pytest.mark.parametrize("period, periods, segments", [
+    ("mma", 1, False), ("mma", 2, False), ("mmmmmammmm", 1, False),
+    ("mma", 2, True), ("amm", 1, True),
+], ids=["one-period", "two-periods", "published-period", "two-periods-packed",
+        "attention-first-packed"])
+def test_forward_matches_the_reference(period, periods, segments):
+    """37 tokens a row: two chunks of 16 and a tail of 5. Packed rows: three
+    documents, one cut inside a chunk and one on a chunk's edge."""
+    cfg = tiny(period, periods)
+    params = seeded(cfg)
+    ids = jax.random.randint(jax.random.key(1), (2, 37), 3, cfg.vocab_size)
+    kw = {"segment_ids": packed(2, 37, [(10, 32), (16, 30)])} if segments else {}
+    got = llama.forward(params, ids, cfg, **kw)
+    want = ref.forward(params, ids, ref.sizes(cfg, {}), **kw)
+    assert rel(got, want) < TOL
+
+
+def test_loss_and_every_gradient_match_the_reference_through_the_trainers_loss_fn():
+    from ditl_tpu.train.step import loss_fn
+
+    cfg = tiny("mma", 2)
+    params = seeded(cfg)
+    ids = jax.random.randint(jax.random.key(2), (2, 40), 3, cfg.vocab_size)
+    seg = packed(2, 40, [(13,), (16, 29)])
+    pos = jnp.broadcast_to(jnp.arange(40, dtype=jnp.int32), ids.shape)
+    mask = jnp.ones(ids.shape, jnp.float32)
+    batch = {"input_ids": ids, "positions": pos, "segment_ids": seg, "loss_mask": mask}
+    sizes = ref.sizes(cfg, {})
+
+    def want_fn(p):
+        return ref.loss(ref.forward(p, ids, sizes, segment_ids=seg), ids, mask, sizes)
+
+    got, g_got = jax.value_and_grad(lambda p: loss_fn(p, batch, cfg)[0])(params)
+    want, g_want = jax.value_and_grad(want_fn)(params)
+    assert abs(float(got) - float(want)) / float(want) < TOL
+    flat_got, flat_want = jax.tree.leaves_with_path(g_got), jax.tree.leaves(g_want)
+    assert len(flat_got) == len(flat_want)
+    for (path, a), b in zip(flat_got, flat_want):
+        assert float(jnp.abs(b).max()) > 0, path  # every leaf is reached
+        assert rel(a, b) < 10 * TOL, jax.tree_util.keystr(path)
+
+
+def _token_by_token(x, dt, a, bmat, cmat, state):
+    ys = []
+    for t in range(x.shape[1]):
+        y, state = ssd.ssd_step(state, x[:, t], dt[:, t], a, bmat[:, t], cmat[:, t])
+        ys.append(y)
+    return jnp.stack(ys, axis=1), state
+
+
+@pytest.mark.parametrize("s, chunk", [(37, 16), (16, 16), (5, 16), (50, 8)],
+                         ids=["two-chunks-and-a-tail", "one-chunk", "under-a-chunk", "six-chunks"])
+def test_the_chunked_form_equals_the_token_by_token_form(s, chunk):
+    """With an initial state in and the final state out, and a padded tail
+    (``dt = 0``) that leaves the state exactly as the last real token left it."""
+    k = jax.random.split(jax.random.key(s), 6)
+    b, h, p, n = 2, 4, 16, 8
+    x = jax.random.normal(k[0], (b, s, h, p))
+    dt = jax.nn.softplus(jax.random.normal(k[1], (b, s, h)) - 2.0)
+    a = -jnp.exp(jax.random.uniform(k[2], (h,), minval=0.0, maxval=2.5))
+    bmat, cmat = jax.random.normal(k[3], (b, s, n)), jax.random.normal(k[4], (b, s, n))
+    s0 = jax.random.normal(k[5], (b, h, p, n))
+    y, last = ssd.ssd_scan(x, dt, a, bmat, cmat, chunk=chunk, state=s0)
+    y_want, last_want = _token_by_token(x, dt, a, bmat, cmat, s0)
+    assert rel(y, y_want) < TOL and rel(last, last_want) < TOL
+    real = s - 3  # the last three positions are padding
+    dt_pad = dt.at[:, real:].set(0.0)
+    _, last_pad = ssd.ssd_scan(x, dt_pad, a, bmat, cmat, chunk=chunk, state=s0)
+    _, last_real = ssd.ssd_scan(x[:, :real], dt[:, :real], a, bmat[:, :real], cmat[:, :real],
+                                chunk=chunk, state=s0)
+    assert rel(last_pad, last_real) < 1e-6
+
+
+@pytest.mark.pallas
+@pytest.mark.parametrize("alive", [(1, 1, 1, 1, 1, 1), (0, 1, 0, 0, 1, 0), (0, 0, 0, 0, 0, 0),
+                                   (0, 0, 0, 0, 0, 1), (1, 0, 0, 0, 0, 0)],
+                         ids=["all", "dead-first-between-last", "none", "last", "first"])
+def test_the_interpreted_step_kernel_equals_the_plain_step(alive):
+    """``ssd_step`` over the stacked state: live rows updated in place, a dead
+    row's state as it was (whichever live row's block its grid step sits on),
+    every other mixer's entry untouched."""
+    k = jax.random.split(jax.random.key(7), 6)
+    n_mix, b, h, p, n = 3, 6, 4, 16, 128
+    stack = jax.random.normal(k[0], (n_mix, b, h, p, n))
+    x = jax.random.normal(k[1], (b, h, p))
+    a = -jnp.exp(jax.random.normal(k[2], (h,)))
+    bvec, cvec = jax.random.normal(k[3], (b, n)), jax.random.normal(k[4], (b, n))
+    dt = jax.nn.softplus(jax.random.normal(k[5], (b, h))) * jnp.asarray(alive, jnp.float32)[:, None]
+    live = jnp.asarray(alive, bool)
+    y_want, s_want = ssd.ssd_step_rows(stack, jnp.int32(1), x, dt, a, bvec, cvec, live)
+    y, s = jax.jit(lambda st, at: ssd.ssd_step_rows(st, at, x, dt, a, bvec, cvec, live,
+                                                    interpret=True))(stack, jnp.int32(1))
+    assert rel(y[live], y_want[live]) < 1e-5 if any(alive) else True
+    assert not bool(jnp.any(y[~live]))
+    assert float(jnp.abs(s - s_want).max()) < 1e-5
+    assert bool(jnp.all(s[:, ~live] == stack[:, ~live])) and bool(jnp.all(s[0] == stack[0]))
+
+
+def test_the_preset_is_the_published_model():
+    cfg = get_preset("granite-4.0-h-micro")
+    assert cfg.layer_period == "mmmmmammmm" and ssm.period_counts(cfg) == (4, 9, 1)
+    assert [i for i, t in enumerate(cfg.layer_types) if t == "a"] == [5, 15, 25, 35]
+    shapes = jax.eval_shape(lambda: llama.init_params(jax.random.key(0), cfg))
+    # ISSUE 41's count: 36 mixers + 4 attention layers + the tied embedding
+    assert llama.num_params(shapes) == 3_191_396_096
+    assert ssm.state_bytes_per_slot(cfg) == 36 * (64 * 64 * 128 * 4 + 3 * 4352 * 2)
+    axes = llama.param_logical_axes(cfg)
+    assert jax.tree.structure(shapes) == jax.tree.structure(
+        axes, is_leaf=lambda x: isinstance(x, tuple))
+
+
+@pytest.mark.parametrize("kw, said", [
+    (dict(layer_types="mmx"), "each 'm' or 'a'"),
+    (dict(layer_types="mm"), "num_layers"),
+    (dict(ssm_state=0), "ssm_heads"),
+    (dict(fused_gate_up=False), "fused_gate_up"),
+    (dict(position_embedding="alibi"), "position_embedding"),
+])
+def test_a_setting_the_stack_cannot_run_is_refused(kw, said):
+    with pytest.raises(ValueError, match=said):
+        tiny("mma", 1, **kw)
+
+
+def test_the_converter_round_trips_the_hybrid_tree():
+    from ditl_tpu.models.convert import params_from_state_dict, state_dict_from_params
+
+    cfg = tiny("mma", 2)
+    params = seeded(cfg)
+    hf = state_dict_from_params(params, cfg)
+    assert "model.layers.0.mamba.in_proj.weight" in hf
+    assert hf["model.layers.0.mamba.in_proj.weight"].shape == (
+        2 * 64 + 2 * 8 + 4, cfg.hidden_size)
+    assert "model.layers.2.self_attn.q_proj.weight" in hf
+    assert "model.layers.5.shared_mlp.input_linear.weight" in hf
+    back = params_from_state_dict(hf, cfg)
+    for (path, a), b in zip(jax.tree.leaves_with_path(params), jax.tree.leaves(back)):
+        assert a.shape == b.shape and bool(jnp.all(a == b)), jax.tree_util.keystr(path)

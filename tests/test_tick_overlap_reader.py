@@ -87,7 +87,10 @@ def test_no_traced_run_no_metric(reader):
 
 def test_the_readers_constants_are_the_manifests(reader):
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        (entry,) = [m for m in json.load(f)["per_layer"] if m["name"] == NAME]
+        manifest = json.load(f)
+    (entry,) = [m for m in manifest["per_layer"] if m["name"] == NAME]
     assert (entry["layer"], entry["unit"], entry["moves"], entry["source"]) == (
         reader.LAYER, reader.UNIT, reader.MOVES, reader.SOURCE)
-    assert entry["better"] == "higher" and len(entry["workloads"]) == 3
+    # every serving cell: three until PR 41 added the fourth
+    serving = [w["name"] for w in manifest["workloads"] if "chat" in w["traffic"]]
+    assert entry["better"] == "higher" and entry["workloads"] == serving and len(serving) >= 3
