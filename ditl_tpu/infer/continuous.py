@@ -1915,6 +1915,18 @@ class ContinuousEngine:
 
         return jax.jit(paged_prefill, donate_argnums=(1,))
 
+    def _attn_steps(self, starts: jax.Array, alive: jax.Array) -> dict:
+        """The decode attention kernels' work list (``ops/paged_attention.py``
+        ``decode_steps``), built ONCE a decode program, in front of its
+        scan: ``starts`` and the page table are constants in there and a row
+        only ever ends, so the steps that exist are those of the rows
+        ``alive`` at the program's start. Every layer of every step walks it."""
+        from ditl_tpu.ops.paged_attention import decode_steps
+
+        with jax.named_scope("attn_core"), jax.named_scope("attn_steps"):
+            return decode_steps(starts, alive, page_size=self.page_size,
+                                max_pages=self.maxp)
+
     def _build_paged_decode(self, sampled: bool, topp: bool):
         """Paged decode tick with DEFERRED page writes: the chunk's K/V
         accumulate in small per-layer tail buffers carried through the scan
@@ -1948,6 +1960,8 @@ class ContinuousEngine:
             # nothing for them regardless of table-row state — no reliance
             # on freed slots having zeroed rows.
             starts = pos
+            done0 = ~alive | (cur == pad)
+            steps = self._attn_steps(starts, ~done0 & (pos < limits))
             if self.latent:
                 from ditl_tpu.models.mla import SUBLAYERS, latent_width
 
@@ -1975,7 +1989,7 @@ class ContinuousEngine:
                 lengths = jnp.where(step_alive, pos + 1, 0)
                 paged_meta = {
                     "table": table, "lengths": lengths, "starts": starts,
-                    "t": t,
+                    "t": t, "steps": steps,
                 }
                 logits, tails, *moe_counts = llama.forward(
                     params,
@@ -2040,7 +2054,7 @@ class ContinuousEngine:
                 # earlier tick (``cur = where(done, pad, nxt)``): the dead
                 # chunk it decodes before the lagged harvest frees its slot
                 # reads no page, writes no tail column and counts nothing.
-                body, (tails0, cur, pos, ~alive | (cur == pad), keys, hist,
+                body, (tails0, cur, pos, done0, keys, hist,
                        fst0, tuple(lp0), moe0),
                 jnp.arange(chunk, dtype=jnp.int32),
             )
@@ -2059,8 +2073,8 @@ class ContinuousEngine:
                 toks, c, i, t = ys
                 return (out, cur, pos, keys, hist, *fs, lp, toks.T,
                         c.T, jnp.swapaxes(i, 0, 1), jnp.swapaxes(t, 0, 1),
-                        *moe_acc)
-            return (out, cur, pos, keys, hist, *fs, ys.T, *moe_acc)
+                        *moe_acc, steps["count"])
+            return (out, cur, pos, keys, hist, *fs, ys.T, *moe_acc, steps["count"])
 
         return jax.jit(paged_decode, donate_argnums=(1,))
 
@@ -2105,6 +2119,7 @@ class ContinuousEngine:
             lp0 = extra[i + 2 :] if guided else extra[i:]
             n_b = pos.shape[0]
             starts = pos
+            steps = self._attn_steps(starts, alive & (pos < limits))
             tk0 = jnp.zeros((L, n_b, K, tail_len, D), dt)
             tv0 = jnp.zeros((L, n_b, K, tail_len, D), dt)
             # Read-only during the scan, and whole: llama.forward keeps them
@@ -2143,7 +2158,7 @@ class ContinuousEngine:
                 lengths = jnp.where(live, pos + 1, 0)
                 paged_meta = {
                     "table": table, "lengths": lengths, "starts": starts,
-                    "off": pos - starts,
+                    "off": pos - starts, "steps": steps,
                 }
                 logits, tails = llama.forward(
                     params, tokens_in, cfg, positions=positions,
@@ -4439,6 +4454,9 @@ class ContinuousEngine:
                 self.temps, self.top_ps, self.keys, self.hist, self.adapters,
                 *fsm_args, *lp_args,
             )
+        walked_dev = ()
+        if self.cache_mode == "paged":  # the attention kernels' list's count
+            res, walked_dev = res[:-1], tuple(res[-1:])
         moe_dev = ()
         if self.moe:  # paged: the tick's (L, E) counts and touched sum
             n_moe = 3 if self.latent else 2  # and the context tokens read
@@ -4459,22 +4477,30 @@ class ContinuousEngine:
         else:
             (toks,) = res_rest
             lp_dev = None
-        return ("plain", key, t0, toks, lp_dev, self._snapshot_slots(), moe_dev)
+        return ("plain", key, t0, toks, lp_dev, self._snapshot_slots(), moe_dev,
+                walked_dev)
 
     def _plain_finish(self, rec: tuple) -> None:
         """Fetch a dispatched plain tick's outputs + harvest."""
         import time as _time
 
-        (_, key, t0, toks, lp_dev, snapshot, moe_dev) = rec
+        (_, key, t0, toks, lp_dev, snapshot, moe_dev, walked_dev) = rec
         self._phase("engine.tick.fetch")
         moe_pending, self._moe_pending = self._moe_pending, []
         # One fetch for everything (see _spec_finish): the experts' counts
-        # ride with the tokens.
-        toks, lp_np, moe_np, pending_np = jax.device_get(
-            (toks, lp_dev or (), moe_dev, moe_pending))
+        # and the attention kernels' step count ride with the tokens.
+        toks, lp_np, moe_np, pending_np, walked_np = jax.device_get(
+            (toks, lp_dev or (), moe_dev, moe_pending, walked_dev))
         lp = tuple(np.asarray(x) for x in lp_np) if lp_dev is not None else None
         toks = np.asarray(toks)
         self._phase("engine.tick.harvest")
+        if walked_np and self._tick_span is not None:
+            # a call of the decode attention kernel walked this many steps,
+            # of the rectangle of every slot by every page-table position
+            # and the tail (what it walked before PR 42)
+            self._tick_span.annotate(
+                attn_steps_walked=int(walked_np[0]),
+                attn_steps_rect=self.n_slots * (self.maxp + 1))
         if self.recurrent:
             self.ssm_row_steps += int(moe_np[0])
             if self._tick_span is not None:

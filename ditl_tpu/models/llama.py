@@ -448,8 +448,11 @@ def _decoder_layer(
     (``forward`` adds ``layer * n_pages``), so no layer's pool is ever copied
     in front of the kernel. ``layer_cache`` then holds only this layer's tail
     buffers (``{"tk", "tv"}``, (B, K, T, D)) and ``paged`` the rest of the
-    tick metadata — ``starts``/``lengths`` (B,) and the scan column ``t``
-    (or, multi-query verify, the per-row tail offsets ``off``). The token's
+    tick metadata — ``starts``/``lengths`` (B,), the scan column ``t``
+    (or, multi-query verify, the per-row tail offsets ``off``) and, where the
+    program built it, ``steps``: the kernel's work list, which holds rows and
+    steps and no page, so it is every layer's (``ops/paged_attention.py``
+    ``decode_steps``). The token's
     K/V land in the tail (returned as this layer's new_kv; the pools are
     never re-emitted) and attention runs through the page table plus the
     tail."""
@@ -595,7 +598,7 @@ def _decoder_layer(
                 pools["kp"], pools["vp"], paged["table"],
                 paged["lengths"], tail_k=tk, tail_v=tv, starts=paged["starts"],
                 k_scale=pools.get("ks"), v_scale=pools.get("vs"),
-                mesh=mesh, rules=rules,
+                steps=paged.get("steps"), mesh=mesh, rules=rules,
             )
             if s == 1:
                 attn_out = attn_out[:, None]

@@ -267,7 +267,7 @@ def _mla_sublayer(a, h, *, cfg: ModelConfig, positions, allowed, cache, cache_in
                 lat = mla_paged_attention(
                     q_full, pool, paged["table"], paged["lengths"], tail=new_cache,
                     starts=paged["starts"], value_width=r,
-                    scale=(nope + rope) ** -0.5)  # (B, H, r)
+                    scale=(nope + rope) ** -0.5, steps=paged.get("steps"))  # (B, H, r)
         else:
             attn = _decompressed_attention(
                 jnp.concatenate([q_nope, q_rope], axis=-1), k, v, allowed)

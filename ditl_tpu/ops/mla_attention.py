@@ -14,10 +14,14 @@ tick's tail (the deferred flush of ops/paged_attention.py).
 - ``mla_paged_attention_xla``: gather the pages, then masked attention; the
   oracle of the tests.
 - ``mla_paged_attention`` (Pallas/Mosaic, kernel name
-  ``mla_paged_attention``): grid ``(B, maxp + 1)``, the page table on the
-  scalar-prefetch channel as in ``paged_attention``; each page is fetched
-  ONCE and used as key and as value, bfloat16 dots with float32
-  accumulation, online softmax over pages, the tail as the last grid step.
+  ``mla_paged_attention``): a one-axis grid over ``paged_attention``'s work
+  list (``decode_steps``: each live row's flushed pages, then its tail; its
+  traced count is the grid's length), the lists and the page table on the
+  scalar-prefetch channel; each page is fetched ONCE and used as key and as
+  value, bfloat16 dots with float32 accumulation, online softmax over a
+  row's pages, the tail as the row's last step. A row with ``lengths == 0``
+  comes out exactly zero, listed or not (selected behind the call: a row
+  the walk never visits is memory nobody wrote).
   At LongCat-Flash's widths 64 heads x 2 x (576 + 512) = 139,264 operations
   a context token over 1,280 stored bytes (benchmarks/mla_counts.py): 109
   FLOP/B against the v5e's 240, memory-bound with little to spare.
@@ -40,6 +44,7 @@ from jax.experimental.pallas import tpu as pltpu
 from ditl_tpu.ops.attention import NEG_INF
 from ditl_tpu.ops.backend import interpret_default
 from ditl_tpu.ops.flash_attention import NUM_LANES, _lane_tile
+from ditl_tpu.ops.paged_attention import decode_steps, walk_length, walk_maps, zero_dead_rows
 
 __all__ = ["mla_paged_attention", "mla_paged_attention_xla"]
 
@@ -100,10 +105,11 @@ def _accumulate(q_ref, kv_ref, m_scr, l_scr, acc_scr, *, scale, base, limit):
     acc_scr[...] = acc_scr[...] * _lane_tile(alpha, vw) + pv
 
 
-def _mla_kernel(table_ref, lengths_ref, starts_ref, q_ref, pool_ref, tail_ref, o_ref,
-                m_scr, l_scr, acc_scr, *, scale: float, page_size: int, n_pages: int):
+def _mla_kernel(rows_ref, ks_ref, table_ref, lengths_ref, starts_ref, q_ref, pool_ref,
+                tail_ref, o_ref, m_scr, l_scr, acc_scr, *, scale: float, page_size: int):
     del table_ref  # the index maps' alone
-    b, p = pl.program_id(0), pl.program_id(1)
+    i = pl.program_id(0)
+    b, p = rows_ref[i], ks_ref[i]  # step i of the work list: row b's p-th
 
     @pl.when(p == 0)
     def _init():
@@ -112,6 +118,7 @@ def _mla_kernel(table_ref, lengths_ref, starts_ref, q_ref, pool_ref, tail_ref, o
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     length, start = lengths_ref[b], starts_ref[b]
+    n_pages = pl.cdiv(start, page_size)  # the row's page steps; then its tail
     page_limit = jnp.minimum(start, length)
     base = p * page_size
 
@@ -142,14 +149,16 @@ def mla_paged_attention(
     starts: jax.Array,  # (B,) tokens resident in pages
     value_width: int,
     scale: float,
+    steps: dict[str, jax.Array] | None = None,  # ``decode_steps``' list
     interpret: bool | None = None,
 ) -> jax.Array:
-    """(B, H, value_width): see the module docstring. Off the TPU, unless
-    ``interpret`` is asked for, this is ``mla_paged_attention_xla``: the
-    interpreted kernel walks its grid of ``B x (maxp + 1)`` steps through a
-    loop that carries the whole pool (8 s a decode tick on the CPU at test
-    sizes, against 0.15 s), so the engine's CPU runs take the gather and
-    ``tests/test_longcat.py`` holds the interpreted kernel to it."""
+    """(B, H, value_width): see the module docstring. ``steps`` has to name
+    every row with ``lengths > 0``; left out, it is built here from
+    ``lengths > 0``. Off the TPU, unless ``interpret`` is asked for, this is
+    ``mla_paged_attention_xla``: the interpreted kernel walks its steps
+    through a loop that carries the whole pool (seconds a decode tick on the
+    CPU at test sizes, against 0.15 s), so the engine's CPU runs take the
+    gather and ``tests/test_longcat.py`` holds the interpreted kernel to it."""
     if interpret is None:
         if interpret_default():
             return mla_paged_attention_xla(
@@ -161,21 +170,14 @@ def mla_paged_attention(
     maxp = page_table.shape[1]
     tail = tail.astype(pool.dtype)
 
-    def page_map(ib, ip, tab, lens, st):
-        # pages that hold flushed tokens; everything else names sentinel page
-        # 0, and consecutive identical blocks are not fetched again
-        pi = jnp.minimum(ip, maxp - 1)
-        live = (ip < maxp) & (pi * ps < jnp.minimum(st[ib], lens[ib]))
-        return jnp.where(live, tab[ib, pi], 0), 0, 0
-
-    def slot_map(ib, ip, tab, lens, st):
-        return ib, 0, 0
-
-    return pl.pallas_call(
-        functools.partial(_mla_kernel, scale=scale, page_size=ps, n_pages=maxp),
+    if steps is None:
+        steps = decode_steps(starts, lengths > 0, page_size=ps, max_pages=maxp)
+    slot_map, page_map = walk_maps(ps, maxp, trailing=2)
+    out = pl.pallas_call(
+        functools.partial(_mla_kernel, scale=scale, page_size=ps),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(b, maxp + 1),
+            num_scalar_prefetch=5,
+            grid=(walk_length(steps),),
             in_specs=[
                 pl.BlockSpec((1, heads, dl), slot_map),
                 pl.BlockSpec((1, ps, dl), page_map),
@@ -189,8 +191,9 @@ def mla_paged_attention(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, heads, value_width), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="mla_paged_attention",
-    )(page_table, lengths, starts, q.astype(pool.dtype), pool, tail)
+    )(steps["rows"], steps["ks"], page_table, lengths, starts, q.astype(pool.dtype), pool,
+      tail)
+    return zero_dead_rows(out, lengths)

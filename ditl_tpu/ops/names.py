@@ -49,7 +49,12 @@ decode step's read of the row's state, its update, ``S C`` and the write
 back) inside ``attn_core``; ``ssm_out`` (the gate, the norm and the output
 projection) inside ``attn_out``. The seat of a slot's state after a prefill
 is ``kv_write``. ``ssd_step`` (``SSM_KERNELS``), inside ``ssm_scan``: the
-decode step's update of the live rows' states in place (``ops/ssd.py``)."""
+decode step's update of the live rows' states in place (``ops/ssd.py``).
+
+``attn_steps`` (``ATTN_SCOPES``), inside ``attn_core``: the work list of the
+paged decode kernels (``ops/paged_attention.py`` ``decode_steps``), built
+once a decode program in front of its scan; a reader that knows only
+``SCOPES`` books its few microseconds a tick to ``attn_core``."""
 
 SCOPES = (
     "embed",
@@ -86,6 +91,8 @@ SSM_SCOPES = (
     "ssm_scan",
     "ssm_out",
 )
+
+ATTN_SCOPES = ("attn_steps",)
 
 # The decode step's state update (``ops/ssd.py``), inside ``ssm_scan``.
 SSM_KERNELS = ("ssd_step",)

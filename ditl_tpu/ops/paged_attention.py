@@ -14,13 +14,37 @@ Two implementations, equal by construction (tested against each other):
 - ``paged_attention_xla``: gather pages -> contiguous (B, maxp*ps, K, D) ->
   masked GQA attention. Materializes the gathered cache every step (double
   HBM traffic); used as the correctness reference and the CPU path.
-- ``paged_attention`` (Pallas/Mosaic): grid (B, kv_heads, maxp); the page
-  table rides the scalar-prefetch channel so each grid step's *block index
-  map* fetches the right physical page from HBM — no gathered copy is ever
-  materialized. Online softmax over pages (same lane-replicated row-stat
-  scheme as ops/flash_attention.py). Pages past a slot's length are mapped
-  to page 0 by the host table; Mosaic's revisit optimization skips the
-  re-fetch of an identical block index, so dead tail pages cost ~nothing.
+- ``paged_attention`` (Pallas/Mosaic): the page table rides the
+  scalar-prefetch channel so each grid step's *block index map* fetches the
+  right physical page from HBM — no gathered copy is ever materialized.
+  Online softmax over pages (same lane-replicated row-stat scheme as
+  ops/flash_attention.py); one grid step consumes one page for ALL kv heads.
+
+The decode path (the tail kernel, and ``ops/mla_attention.py``'s) walks a
+WORK LIST, not a rectangle. A grid of every slot by every page-table position
+costs its steps whether they do anything: at 64 slots x (16 pages + the tail)
+with 14 rows live on two pages each, 1,088 steps a call of which ~40 work, and
+every dead ROW still moves its q, tail and output block (160-245 us a call on
+a v5e where the live rows' bytes take 10-25: PERF.md section 5). So the grid
+has ONE axis whose length is the traced count of the steps that exist:
+``decode_steps`` lists, row by row, each live row's flushed pages ``k = 0 ..
+ceil(starts / page_size) - 1`` and then its tail-and-finalize step; the lists
+ride the scalar-prefetch channel beside the table and the index maps read
+step ``i``'s row and page from them. A row's scratch is initialised at its
+``k == 0`` and its output written at its tail step. The list is a function of
+``starts`` and of which rows are alive, both constants inside a decode
+program (a row may END inside it: its steps stay, predicated off by
+``lengths == 0`` as on the rectangle), so the engine builds it once a
+program, in front of the scan, and every layer of every step walks the same
+one; pages are named through the table, so a layer's offset never touches it.
+
+The dead-row contract: a row with ``lengths == 0`` comes out exactly zero. A
+listed row that ended writes zeros at its tail step (the ``l == 0`` guard); a
+row the walk never visits is memory nobody wrote, so the wrapper selects
+zeros for every ``lengths == 0`` row behind the call; an empty list walks one
+step that does nothing. The axis is ``arbitrary`` (steps of a row must follow
+each other): nothing is lost on a single-TensorCore chip (v5e); a two-core
+chip would want the list split by core, at a row boundary.
 
 Layouts: q is (B, H, D) — one query token per slot (the decode tick shape);
 pools are (P, K, ps, D) — kv-heads BEFORE page slots, so a Pallas block
@@ -35,9 +59,10 @@ over layers, (L, n_pages, K, ps, D); a paged decode (models/llama.py
 pages (a bitcast) and a table offset by ``layer * n_pages``, because a
 custom call takes whole operands: a pool sliced by layer in front of it is
 a copy of that layer's pool, every layer of every step. The sentinel page
-of dead grid steps is then ABSOLUTE page 0 (layer 0's first page) for every
-layer: it is only ever fetched, never read unmasked, so which page it is
-does not matter — only that consecutive dead steps name the same one.
+that the page steps of an ended row name is then ABSOLUTE page 0 (layer 0's
+first page) for every layer: it is only ever fetched, never read unmasked,
+so which page it is does not matter — only that consecutive such steps name
+the same one (a block index that repeats is not fetched again).
 """
 
 from __future__ import annotations
@@ -53,7 +78,7 @@ from ditl_tpu.ops.attention import NEG_INF
 from ditl_tpu.ops.backend import interpret_default
 from ditl_tpu.ops.flash_attention import NUM_LANES, _lane_tile
 
-__all__ = ["paged_attention", "paged_attention_xla"]
+__all__ = ["decode_steps", "paged_attention", "paged_attention_xla"]
 
 
 def paged_attention_xla(
@@ -242,7 +267,42 @@ def _paged_kernel(
         _finalize_out(o_ref, m_scr, l_scr, acc_scr)
 
 
+def decode_steps(
+    starts: jax.Array,  # (B,) int32 — tokens resident in pages
+    alive: jax.Array,  # (B,) bool — rows that may attend during the program
+    *,
+    page_size: int,
+    max_pages: int,
+) -> dict[str, jax.Array]:
+    """The work list of the decode kernels (module docstring): every step
+    that exists, row by row. A row with ``alive`` has ``ceil(starts /
+    page_size)`` page steps ``k = 0, 1, ...`` and then its tail-and-finalize
+    step ``k = ceil(starts / page_size)``; a row without has none.
+
+    ``rows`` / ``ks``: (B * (max_pages + 1),) int32, step ``i``'s row and its
+    ``k``; ``count`` (): the steps that exist (entries past it name row 0,
+    ``k`` 0 and are never walked). ``starts <= max_pages * page_size``, which
+    is what a page table that wide can address. One cumulative sum over the
+    rows and one comparison a (step, row): build it once where ``starts``
+    and ``alive`` are constants (a decode program: in front of its scan)."""
+    b = starts.shape[0]
+    per_row = jnp.where(alive, pl.cdiv(starts, page_size) + 1, 0).astype(jnp.int32)
+    ends = jnp.cumsum(per_row)  # (B,) one past each row's last step
+    i = jnp.arange(b * (max_pages + 1), dtype=jnp.int32)
+    # the rows whose steps all lie in front of step i: its row's index
+    rows = jnp.minimum(jnp.sum(i[:, None] >= ends[None, :], axis=1), b - 1)
+    ks = i - (ends - per_row)[rows]
+    walked = i < ends[-1]
+    return {
+        "rows": jnp.where(walked, rows, 0).astype(jnp.int32),
+        "ks": jnp.where(walked, ks, 0).astype(jnp.int32),
+        "count": ends[-1],
+    }
+
+
 def _paged_tail_kernel(
+    rows_ref,  # scalar prefetch: (S,) int32 — the work list's rows
+    ks_ref,  # scalar prefetch: (S,) int32 — and their steps
     table_ref,  # scalar prefetch: (B, maxp) int32
     lengths_ref,  # scalar prefetch: (B,) int32
     starts_ref,  # scalar prefetch: (B,) int32 — tokens resident in pages
@@ -253,26 +313,28 @@ def _paged_tail_kernel(
             # m_scr, l_scr, acc_scr
     scale: float,
     page_size: int,
-    n_pages: int,
     quantized: bool,
     q_groups: int | None = None,
 ):
-    """Deferred-flush variant: grid (B, maxp + 1). Steps p < maxp consume
-    flushed pages (positions < starts[b]); the final step consumes the hot
-    TAIL block — the current decode chunk's KV, held in a small contiguous
-    buffer until the per-tick flush (positions [starts, lengths)). With
-    ``quantized``, the pools are int8 and their per-position scales factor
-    out of the dots; the tail stays float until the flush. ``q_groups``
-    (speculative verify): the q block packs Q query tokens; per-query
-    causal limits apply to the TAIL only — every page column precedes
-    ``starts``, which every query's limit already covers."""
+    """Deferred-flush variant: grid (n_steps,), step ``i`` of the work list
+    (``decode_steps``) is ``(b, p) = (rows[i], ks[i])``. Steps ``p <
+    ceil(starts[b] / page_size)`` consume row b's flushed pages (positions <
+    starts[b]) in order; the row's last step consumes the hot TAIL block —
+    the current decode chunk's KV, held in a small contiguous buffer until
+    the per-tick flush (positions [starts, lengths)) — and writes the row's
+    output. With ``quantized``, the pools are int8 and their per-position
+    scales factor out of the dots; the tail stays float until the flush.
+    ``q_groups`` (speculative verify): the q block packs Q query tokens;
+    per-query causal limits apply to the TAIL only — every page column
+    precedes ``starts``, which every query's limit already covers."""
     if quantized:
-        ks_ref, vs_ref, tk_ref, tv_ref, o_ref, m_scr, l_scr, acc_scr = rest
+        kscale_ref, vscale_ref, tk_ref, tv_ref, o_ref, m_scr, l_scr, acc_scr = rest
     else:
-        ks_ref = vs_ref = None
+        kscale_ref = vscale_ref = None
         tk_ref, tv_ref, o_ref, m_scr, l_scr, acc_scr = rest
-    b = pl.program_id(0)
-    p = pl.program_id(1)
+    i = pl.program_id(0)
+    b = rows_ref[i]
+    p = ks_ref[i]
 
     @pl.when(p == 0)
     def _init():
@@ -282,6 +344,7 @@ def _paged_tail_kernel(
 
     length = lengths_ref[b]
     start = starts_ref[b]
+    n_pages = pl.cdiv(start, page_size)  # the row's page steps; then its tail
     page_limit = jnp.minimum(start, length)
     base = p * page_size
 
@@ -290,7 +353,7 @@ def _paged_tail_kernel(
         _accumulate_block(
             q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr,
             scale=scale, base=base, width=page_size, limit=page_limit,
-            ks_ref=ks_ref, vs_ref=vs_ref,
+            ks_ref=kscale_ref, vs_ref=vscale_ref,
         )
 
     @pl.when((p == n_pages) & (length > start))
@@ -306,6 +369,46 @@ def _paged_tail_kernel(
         _finalize_out(o_ref, m_scr, l_scr, acc_scr)
 
 
+def walk_maps(page_size: int, max_pages: int, trailing: int):
+    """The block index maps of a walk over ``decode_steps``' list, for
+    operands with ``trailing`` dims behind the leading one: ``slot_map``
+    names step ``i``'s ROW (q, tail, output), ``page_map`` its page (pools,
+    scales). A page step names the row's ``k``-th page while it holds
+    tokens the row still attends to (``< min(starts, lengths)``); a row that
+    ended inside the program names sentinel page 0 instead, and the tail
+    step names the row's LAST page again: consecutive identical block
+    indices are not fetched again, so neither costs a fetch."""
+    zeros = (0,) * trailing
+
+    def slot_map(i, rows, ks, tab, lens, st):
+        return (rows[i], *zeros)
+
+    def page_map(i, rows, ks, tab, lens, st):
+        b = rows[i]
+        last = jnp.maximum(pl.cdiv(st[b], page_size) - 1, 0)
+        col = jnp.minimum(jnp.minimum(ks[i], last), max_pages - 1)
+        live = col * page_size < jnp.minimum(st[b], lens[b])
+        return (jnp.where(live, tab[b, col], 0), *zeros)
+
+    return slot_map, page_map
+
+
+def walk_length(steps: dict[str, jax.Array]) -> jax.Array:
+    """The walk's grid length: the list's count, and one step where the
+    list is empty (a program run with no live row), so that the compiled
+    loop never has zero trips: that step is row 0's ``k`` 0, on which
+    nothing accumulates because its ``lengths`` is 0."""
+    return jnp.maximum(steps["count"], 1)
+
+
+def zero_dead_rows(out: jax.Array, lengths: jax.Array) -> jax.Array:
+    """A row the walk never visits is memory nobody wrote, and a listed row
+    that ended writes zeros only at its tail step: the contract's zeros for
+    every row with ``lengths == 0`` are selected here, outside the call."""
+    live = (lengths > 0).reshape(-1, *(1,) * (out.ndim - 1))
+    return jnp.where(live, out, jnp.zeros((), out.dtype))
+
+
 def paged_attention(
     q: jax.Array,  # (B, H, D); (B, Q, H, D) = multi-query speculative verify
     k_pages: jax.Array,  # (P, K, ps, D)
@@ -318,6 +421,7 @@ def paged_attention(
     starts: jax.Array | None = None,  # (B,) tokens resident in pages
     k_scale: jax.Array | None = None,  # (P, K, 1, ps) — int8 pools
     v_scale: jax.Array | None = None,
+    steps: dict[str, jax.Array] | None = None,  # ``decode_steps``' list
     interpret: bool | None = None,
     mesh=None,
     rules=None,
@@ -325,9 +429,14 @@ def paged_attention(
     """Pallas paged GQA decode attention (see module docstring).
 
     With ``tail_k/tail_v/starts`` (the deferred-flush decode path), the
-    grid gains one final step that accumulates the hot tail block —
-    positions [starts, lengths) held in a small contiguous buffer — so
-    per-token page writes never happen inside the decode scan.
+    grid is a walk over ``steps``, the work list of ``decode_steps``: each
+    row's flushed pages, then one step that accumulates its hot tail block
+    — positions [starts, lengths) held in a small contiguous buffer — so
+    per-token page writes never happen inside the decode scan. ``steps``
+    has to name every row with ``lengths > 0`` (built from these ``starts``
+    and an ``alive`` that covers them; a decode program builds it once, in
+    front of its scan); left out, it is built here from ``lengths > 0``. A
+    row with ``lengths == 0`` comes out as zeros, in the list or not.
 
     4-D ``q`` (requires the tail path) is the speculative K+1-token verify:
     Q queries per slot share every page fetch — the whole point of
@@ -338,7 +447,9 @@ def paged_attention(
     With a ``mesh``, the kernel is shard_mapped over the TENSOR axis:
     pools, tails and q/output split on kv-heads (the rule table's
     ``act_kv_heads``), page table / lengths / starts replicated — heads
-    are independent in attention, so no collectives are induced. The
+    are independent in attention, so no collectives are induced; the
+    work list is replicated with the table (where the rule table also
+    splits the batch, each shard builds the list of its own rows). The
     batch axes stay unsharded here (a paged pool is one shared resource;
     multi-host paged serving replicates the batch like the pod protocols
     do)."""
@@ -380,6 +491,8 @@ def paged_attention(
             args = [q, k_pages, v_pages, page_table, lengths]
             has_tail = tail_k is not None
             has_scale = k_scale is not None
+            # a list over ALL rows is no shard's list once the batch is split
+            has_steps = steps is not None and has_tail and dp == 1
             if has_tail:
                 in_specs += [tail_spec, tail_spec, row_spec]
                 args += [tail_k, tail_v, starts]
@@ -389,17 +502,24 @@ def paged_attention(
                 )
                 in_specs += [scale_spec, scale_spec]
                 args += [k_scale, v_scale]
+            if has_steps:
+                replicated = jax.sharding.PartitionSpec()
+                in_specs += [replicated] * 3
+                args += [steps["rows"], steps["ks"], steps["count"]]
 
             def local(q_, kp_, vp_, tab_, lens_, *rest):
-                tk_ = tv_ = st_ = ks_ = vs_ = None
+                tk_ = tv_ = st_ = ks_ = vs_ = steps_ = None
                 if has_tail:
                     tk_, tv_, st_, *rest = rest
+                if has_steps:
+                    *rest, rows_, steps_ks_, count_ = rest
+                    steps_ = {"rows": rows_, "ks": steps_ks_, "count": count_}
                 if has_scale:
                     ks_, vs_ = rest
                 return paged_attention(
                     q_, kp_, vp_, tab_, lens_,
                     tail_k=tk_, tail_v=tv_, starts=st_,
-                    k_scale=ks_, v_scale=vs_, interpret=interpret,
+                    k_scale=ks_, v_scale=vs_, steps=steps_, interpret=interpret,
                 )
 
             return jax.shard_map(
@@ -458,9 +578,6 @@ def paged_attention(
         pltpu.VMEM((g_rows, d), jnp.float32),  # acc
     ]
     out_shape = jax.ShapeDtypeStruct((b, kv_heads, qg_rows, d), q.dtype)
-    compiler_params = pltpu.CompilerParams(
-        dimension_semantics=("parallel", "arbitrary")
-    )
 
     def out_4d(o):
         o = o.reshape(b, kv_heads, nq, groups, d).transpose(0, 2, 1, 3, 4)
@@ -468,25 +585,17 @@ def paged_attention(
         return o if multi_q else o[:, 0]
 
     if has_tail:
-        # Page fetches clamp to pages holding FLUSHED tokens (< starts) and
-        # redirect everything else to sentinel page 0 (Mosaic's revisit
-        # optimization skips the duplicate fetch); the final grid step
-        # consumes the tail block instead of a page.
-        def page_map(ib, ip, tab, lens, st):
-            pi = jnp.minimum(ip, maxp - 1)
-            live = (ip < maxp) & (pi * ps < jnp.minimum(st[ib], lens[ib]))
-            return jnp.where(live, tab[ib, pi], 0), 0, 0, 0
-
-        def slot_map(ib, ip, tab, lens, st):
-            return (ib, 0, 0, 0)
-
+        if steps is None:
+            steps = decode_steps(starts, lengths > 0, page_size=ps, max_pages=maxp)
+        slot_map, page_map = walk_maps(ps, maxp, trailing=3)
         quantized = k_scale is not None
         in_specs = [
             pl.BlockSpec((1, kv_heads, qg_rows, d), slot_map),
             pl.BlockSpec((1, kv_heads, ps, d), page_map),
             pl.BlockSpec((1, kv_heads, ps, d), page_map),
         ]
-        args = [page_table, lengths, starts, qg, k_pages, v_pages]
+        args = [steps["rows"], steps["ks"], page_table, lengths, starts,
+                qg, k_pages, v_pages]
         if quantized:
             in_specs += [
                 pl.BlockSpec((1, kv_heads, 1, ps), page_map),
@@ -501,22 +610,22 @@ def paged_attention(
         out = pl.pallas_call(
             functools.partial(
                 _paged_tail_kernel, scale=d**-0.5, page_size=ps,
-                n_pages=maxp, quantized=quantized,
-                q_groups=groups if nq > 1 else None,
+                quantized=quantized, q_groups=groups if nq > 1 else None,
             ),
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=3,
-                grid=(b, maxp + 1),
+                num_scalar_prefetch=5,
+                grid=(walk_length(steps),),
                 in_specs=in_specs,
                 out_specs=pl.BlockSpec((1, kv_heads, qg_rows, d), slot_map),
                 scratch_shapes=scratch,
             ),
             out_shape=out_shape,
-            compiler_params=compiler_params,
+            # one axis, in the list's order: a row's steps follow each other
+            compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
             interpret=interpret,
             name="paged_attention",
         )(*args)
-        return out_4d(out)
+        return out_4d(zero_dead_rows(out, lengths))
 
     if k_scale is not None:
         raise ValueError(
@@ -559,7 +668,9 @@ def paged_attention(
             scratch_shapes=scratch,
         ),
         out_shape=out_shape,
-        compiler_params=compiler_params,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
+        ),
         interpret=interpret,
         name="paged_attention",
     )(page_table, lengths, qg, k_pages, v_pages)

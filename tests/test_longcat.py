@@ -29,6 +29,7 @@ from ditl_tpu.models import llama, mla  # noqa: E402
 from ditl_tpu.models import moe as moe_mod  # noqa: E402
 from ditl_tpu.models.presets import get_preset  # noqa: E402
 from ditl_tpu.ops import mla_attention  # noqa: E402
+from tests import rect_walk  # noqa: E402
 
 ref = load_module(os.path.join(BENCH, "reference", "longcat_flash.py"))
 
@@ -121,6 +122,38 @@ def test_the_interpreted_kernel_equals_the_gather():
     got = mla_attention.mla_paged_attention(q, pool, table, lengths, interpret=True, **kw)
     assert rel(got, want) < 1e-5
     assert not np.asarray(got[2]).any()  # a dead slot: zeros, not NaN
+
+
+@pytest.mark.pallas
+@pytest.mark.parametrize("name", list(rect_walk.SCENARIOS))
+def test_the_latent_walk_over_the_list_gives_the_rectangles_numbers(name):
+    """``mla_paged_attention`` on its work list against the rectangular walk
+    it replaced (``tests/rect_walk.py``: the same ``_accumulate`` on a grid
+    of every slot by every page-table position) and against the gather: a
+    row with ``lengths > 0`` bit-equal, a row with ``lengths == 0`` exactly
+    zero whether the list names it or not; an empty list all zeros."""
+    from ditl_tpu.ops.paged_attention import decode_steps
+
+    starts, lengths, listed = rect_walk.rows_of(name)
+    ps, maxp = rect_walk.PAGE_SIZE, rect_walk.MAX_PAGES
+    b, h, dl, vw, t = len(starts), 4, 128, 64, 8
+    ks = jax.random.split(jax.random.key(3), 4)
+    q = jax.random.normal(ks[0], (b, h, dl))
+    pool = jax.random.normal(ks[1], (23, ps, dl))
+    tail = jax.random.normal(ks[2], (b, t, dl))
+    table = jax.random.randint(ks[3], (b, maxp), 1, 23)
+    kw = dict(tail=tail, starts=starts, value_width=vw, scale=0.1)
+    steps = decode_steps(starts, listed, page_size=ps, max_pages=maxp)
+    got = np.asarray(mla_attention.mla_paged_attention(
+        q, pool, table, lengths, steps=steps, interpret=True, **kw))
+    rect = np.asarray(rect_walk.mla_paged_attention_rect(q, pool, table, lengths, **kw))
+    live = np.asarray(lengths) > 0
+    np.testing.assert_array_equal(got[live], rect[live])
+    assert not got[~live].any() and np.isfinite(got).all()
+    want = mla_attention.mla_paged_attention_xla(q, pool, table, lengths, **kw)
+    np.testing.assert_allclose(got, np.asarray(want), atol=2e-5)
+    own = mla_attention.mla_paged_attention(q, pool, table, lengths, interpret=True, **kw)
+    np.testing.assert_array_equal(np.asarray(own), got)  # the list a caller leaves out
 
 
 def _moe_of(cfg, full, first, count):

@@ -151,6 +151,17 @@ def test_scope_is_in_the_lowered_paged_programs(name, paged):
     assert want in found
 
 
+def test_the_decode_programs_work_list_is_built_inside_attn_core(paged):
+    """``attn_steps`` sits inside ``attn_core``, so a reader that knows only
+    ``SCOPES`` still books the list's time to a name (PR 42), and in front of
+    the program's scan: once a tick, not once a step."""
+    assert names.ATTN_SCOPES == ("attn_steps",)
+    text = paged["_build_paged_decode"]
+    assert re.search(r"attn_core/attn_steps/", text)
+    assert not re.search(r"while/body[^\n\"]*attn_steps", text)
+    assert not _has_segment(paged["_build_paged_prefill"], "attn_steps")
+
+
 def test_backward_and_rematerialised_parts_keep_their_scope(train_text):
     """Scopes survive ``jax.checkpoint`` and autodiff as path segments: the
     rematerialised forward and the backward of a part are found under the

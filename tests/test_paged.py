@@ -7,6 +7,8 @@ bounded by resident tokens (not slots x max context), and admission waits
 instead of faulting when the pool is full.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -18,6 +20,7 @@ from ditl_tpu.infer.continuous import ContinuousEngine
 from ditl_tpu.infer.engine import GenerateConfig, Generator
 from ditl_tpu.infer.paged_cache import PageAllocator, block_keys
 from ditl_tpu.models import llama
+from tests import rect_walk
 
 pytestmark = pytest.mark.pallas
 
@@ -646,7 +649,7 @@ def test_paged_decode_reads_each_layers_own_pages(monkeypatch, kind):
         params, ids, cfg, positions=positions, cache={**pools, **tails},
         paged=paged, return_hidden=True)
 
-    def reference_kernel(q, kp, vp, tab, lens, *, mesh=None, rules=None, **kw):
+    def reference_kernel(q, kp, vp, tab, lens, *, mesh=None, rules=None, steps=None, **kw):
         return pa.paged_attention_xla(q, kp, vp, tab, lens, **kw)
 
     monkeypatch.setattr(pa, "paged_attention", reference_kernel)
@@ -728,3 +731,137 @@ def test_flush_writes_the_committed_rows_and_nothing_else(tail_len, pool_dtype):
     for n in want:
         assert got[n].dtype == want[n].dtype
         np.testing.assert_array_equal(np.asarray(got[n]), want[n], err_msg=n)
+
+
+# -- the work list of the decode kernels (PR 42) ----------------------------------
+
+
+def _brute_force_steps(starts, alive, ps):
+    return [(row, k) for row in range(len(starts)) if alive[row]
+            for k in range(-(-int(starts[row]) // ps) + 1)]
+
+
+@pytest.mark.parametrize("name", list(rect_walk.SCENARIOS))
+def test_decode_steps_is_the_enumeration_of_the_listed_rows_steps(name):
+    """The list builder alone: every listed row's page steps in order, then
+    its tail step, rows in order; nothing of a row that is not listed; the
+    entries past the count name row 0's step 0."""
+    from ditl_tpu.ops.paged_attention import decode_steps
+
+    starts, _, listed = rect_walk.rows_of(name)
+    ps, maxp = rect_walk.PAGE_SIZE, rect_walk.MAX_PAGES
+    steps = jax.jit(lambda s, a: decode_steps(s, a, page_size=ps, max_pages=maxp))(
+        starts, listed)
+    want = _brute_force_steps(np.asarray(starts), np.asarray(listed), ps)
+    count = int(steps["count"])
+    assert steps["rows"].shape == steps["ks"].shape == (len(starts) * (maxp + 1),)
+    assert steps["rows"].dtype == steps["ks"].dtype == jnp.int32
+    assert count == len(want)
+    got = list(zip(np.asarray(steps["rows"]).tolist(), np.asarray(steps["ks"]).tolist()))
+    assert got[:count] == want
+    assert set(got[count:]) <= {(0, 0)}
+
+
+def _walk_case(variant, seed=11):
+    """Operands of one call: (args, kw) for ``paged_attention`` and its
+    oracles, without the rows' ``lengths`` / ``starts``."""
+    from ditl_tpu.infer.cache import _quantize
+
+
+    rng = np.random.default_rng(seed)
+    ps, maxp = rect_walk.PAGE_SIZE, rect_walk.MAX_PAGES
+    b, h, kv_heads, d, pool, tail = 6, 8, 4, 32, 29, 8
+    nq = 3 if variant == "multi-query" else 1
+    normal = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)  # noqa: E731
+    q = normal(b, nq, h, d) if nq > 1 else normal(b, h, d)
+    kp, vp = normal(pool, kv_heads, ps, d), normal(pool, kv_heads, ps, d)
+    kw = {"tail_k": normal(b, kv_heads, tail, d), "tail_v": normal(b, kv_heads, tail, d)}
+    if variant == "int8":
+        kq, ks = _quantize(jnp.swapaxes(kp, 1, 2))
+        vq, vs = _quantize(jnp.swapaxes(vp, 1, 2))
+        kp, vp = jnp.swapaxes(kq, 1, 2), jnp.swapaxes(vq, 1, 2)
+        kw.update(k_scale=jnp.swapaxes(ks, 1, 2)[:, :, None, :],
+                  v_scale=jnp.swapaxes(vs, 1, 2)[:, :, None, :])
+    table = jnp.asarray(rng.integers(1, pool, size=(b, maxp)), jnp.int32)
+    return (q, kp, vp, table), kw
+
+
+@pytest.mark.pallas
+@pytest.mark.parametrize("variant", ["plain", "int8", "multi-query"])
+@pytest.mark.parametrize("name", list(rect_walk.SCENARIOS))
+def test_the_walk_over_the_list_gives_the_rectangles_numbers(name, variant):
+    """The work-list kernel against the rectangular walk it replaced (the
+    same step bodies on a grid of every slot by every page-table position:
+    ``tests/rect_walk.py``) and against the XLA gather: a row with
+    ``lengths > 0`` bit-equal to the rectangle's, a row with ``lengths ==
+    0`` exactly zero whether the list names it or not."""
+    from ditl_tpu.ops.paged_attention import (decode_steps, paged_attention,
+                                              paged_attention_xla)
+
+    starts, lengths, listed = rect_walk.rows_of(name)
+    (q, kp, vp, table), kw = _walk_case(variant)
+    steps = decode_steps(starts, listed, page_size=rect_walk.PAGE_SIZE,
+                         max_pages=rect_walk.MAX_PAGES)
+    got = np.asarray(paged_attention(q, kp, vp, table, lengths, starts=starts, steps=steps,
+                                     interpret=True, **kw))
+    rect = np.asarray(rect_walk.paged_attention_rect(q, kp, vp, table, lengths,
+                                                     starts=starts, **kw))
+    live = np.asarray(lengths) > 0
+    np.testing.assert_array_equal(got[live], rect[live])
+    assert not got[~live].any() and np.isfinite(got).all()
+    ref = paged_attention_xla(q, kp, vp, table, lengths, starts=starts, **kw)
+    np.testing.assert_allclose(got, np.asarray(ref), atol=1e-4 if variant == "int8" else 2e-5)
+    # the list a caller leaves out is the one of the rows with lengths > 0
+    own = np.asarray(paged_attention(q, kp, vp, table, lengths, starts=starts,
+                                     interpret=True, **kw))
+    np.testing.assert_array_equal(own, got)
+
+
+@pytest.mark.pallas
+@pytest.mark.parametrize("n_devices", [2, 4], ids=["tensor-2", "data-2-tensor-2"])
+@pytest.mark.parametrize("variant", ["plain", "int8", "multi-query"])
+def test_the_walk_on_a_tensor_mesh_equals_one_devices(variant, n_devices):
+    """The ``shard_map`` over kv heads, the list replicated like the table
+    (and, where the batch is split too, each shard's list its own rows'):
+    the same numbers as the unsharded call, dead rows zero."""
+    from ditl_tpu.config import MeshConfig
+    from ditl_tpu.ops.paged_attention import decode_steps, paged_attention
+    from ditl_tpu.runtime.mesh import build_mesh
+
+    starts, lengths, listed = rect_walk.rows_of("ended-inside-the-tick")
+    (q, kp, vp, table), kw = _walk_case(variant)
+    steps = decode_steps(starts, listed, page_size=rect_walk.PAGE_SIZE,
+                         max_pages=rect_walk.MAX_PAGES)
+    call = functools.partial(paged_attention, q, kp, vp, table, lengths, starts=starts,
+                             steps=steps, interpret=True, **kw)
+    one = np.asarray(call())
+    mesh = build_mesh(MeshConfig(data=-1, tensor=2), devices=jax.devices()[:n_devices])
+    sharded = np.asarray(jax.jit(lambda: call(mesh=mesh))())
+    np.testing.assert_array_equal(sharded, one)
+    assert not sharded[np.asarray(lengths) == 0].any()
+
+
+def test_a_traced_engines_tick_span_counts_the_steps_walked(tiny_setup, tmp_path):
+    """The decode program returns its list's count with the tick's tokens;
+    an armed tracer's ``engine.tick`` span carries it beside the rectangle
+    the kernels walked before: ``attn_steps_walked <= attn_steps_rect``,
+    and a tick's count is the steps of the rows that decoded in it."""
+    from ditl_tpu.telemetry.journal import EventJournal, merge_journals
+    from ditl_tpu.telemetry.tracing import Tracer
+
+    cfg, params = tiny_setup
+    journal = EventJournal(str(tmp_path / "events-engine.jsonl"), source="engine")
+    eng = _paged_engine(params, cfg, gen=GenerateConfig(max_new_tokens=12),
+                        tracer=Tracer(journal))
+    eng.generate(["hello paged world", "abc"])
+    journal.close()
+    ticks = [r for r in merge_journals(str(tmp_path)) if r.get("name") == "engine.tick"]
+    counted = [t for t in ticks if "attn_steps_walked" in t]
+    assert counted
+    rect = eng.n_slots * (eng.maxp + 1)
+    for t in counted:
+        assert t["attn_steps_rect"] == rect
+        assert 0 <= t["attn_steps_walked"] <= rect
+    # pages of 16: 18 tokens growing to 30 are two page steps and the tail
+    # step, 4 growing to 16 one page step and the tail step
+    assert max(t["attn_steps_walked"] for t in counted) == 5

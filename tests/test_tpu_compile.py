@@ -91,22 +91,42 @@ def test_expert_layer_backward_compiles_at_olmoe_widths(one_chip, tpu_branch):
     assert set(names.MOE_KERNELS) <= _instructions(compiled.as_text())
 
 
+def _steps(starts, alive, ps, maxp):
+    """The kernels' work list, built in the compiled program as a decode
+    program builds it (``decode_steps``: rows, ks and the traced count that
+    is the grid's length)."""
+    from ditl_tpu.ops.paged_attention import decode_steps
+
+    return decode_steps(starts, alive, page_size=ps, max_pages=maxp)
+
+
 @over_tails
-def test_paged_decode_kernel_compiles_with_one_query_head_a_kv_head(one_chip, tpu_branch, tail):
+@pytest.mark.parametrize("h, kv, pages", [
+    (16, 16, 10 * 192),  # OLMoE: ONE query head a kv head
+    (28, 4, 12 * 720),  # Qwen2-7B: 7 a kv head
+    (32, 8, 4 * 512),  # Granite: 64-wide heads, stored on 128 lanes
+], ids=["olmoe-1b-7b-cut1", "qwen2-7b-cut1", "granite-4.0-h-micro"])
+def test_paged_decode_kernel_compiles_on_its_work_list_at_the_cells_shapes(
+        one_chip, tpu_branch, tail, h, kv, pages):
+    """``paged_attention`` as the serving cells run it: 64 slots, pages of
+    256 in all layers' pools addressed as one, 16 pages a slot, either tail,
+    the work list's rows / steps on the scalar-prefetch channel and its count
+    the length of the one-axis grid. The instruction keeps the kernel's name:
+    the readers and ``_scopes.py`` find it by that."""
     from ditl_tpu.ops.paged_attention import paged_attention
 
-    cfg = get_preset("olmoe-1b-7b")
-    b, h, hd, ps, pages, maxp = 64, cfg.num_heads, cfg.head_dim, 256, 192, 16
-    assert cfg.num_heads == cfg.num_kv_heads
+    b, hd, ps, maxp = 64, 128, 256, 16
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
-    args = (s((b, h, hd), jnp.bfloat16), s((pages, h, ps, hd), jnp.bfloat16),
-            s((pages, h, ps, hd), jnp.bfloat16), s((b, maxp), jnp.int32), s((b,), jnp.int32),
-            s((b, h, tail, hd), jnp.bfloat16), s((b, h, tail, hd), jnp.bfloat16),
-            s((b,), jnp.int32))
+    args = (s((b, h, hd), jnp.bfloat16), s((pages, kv, ps, hd), jnp.bfloat16),
+            s((pages, kv, ps, hd), jnp.bfloat16), s((b, maxp), jnp.int32), s((b,), jnp.int32),
+            s((b, kv, tail, hd), jnp.bfloat16), s((b, kv, tail, hd), jnp.bfloat16),
+            s((b,), jnp.int32), s((b,), jnp.bool_))
     compiled = jax.jit(
-        lambda q, kp, vp, tab, lens, tk, tv, st: paged_attention(
-            q, kp, vp, tab, lens, tail_k=tk, tail_v=tv, starts=st, interpret=False)
+        lambda q, kp, vp, tab, lens, tk, tv, st, alive: paged_attention(
+            q, kp, vp, tab, lens, tail_k=tk, tail_v=tv, starts=st,
+            steps=_steps(st, alive, ps, maxp), interpret=False)
     ).lower(*args).compile()
+    assert names.KERNELS[3] == "paged_attention"
     assert "paged_attention" in _instructions(compiled.as_text())
 
 
@@ -142,7 +162,8 @@ def test_paged_decode_layer_loop_copies_no_pool(one_chip, tpu_branch, preset, la
         return llama.forward(
             params, cur[:, None], cfg, positions=pos[:, None],
             cache={"kp": kp, "vp": vp, "tk": tk, "tv": tv},
-            paged={"table": table, "lengths": lengths, "starts": starts, "t": t},
+            paged={"table": table, "lengths": lengths, "starts": starts, "t": t,
+                   "steps": _steps(starts, lengths > 0, ps, maxp)},
             return_hidden=True)
 
     compiled = jax.jit(step).lower(
@@ -206,20 +227,22 @@ def test_latent_decode_kernel_compiles_at_the_longcat_cells_shapes(one_chip, tai
     """``mla_paged_attention`` as ``longcat-flash-cut1.chat-wide-mla`` runs it:
     128 slots, 64 heads against ONE 640-wide entry a token (512 of it the
     value), pages of 256 in a pool of 8 sublayers x 1,280 pages addressed as
-    one, 16 pages a slot and the tick's tail."""
+    one, 16 pages a slot, the tick's tail, and the work list (its count the
+    grid's length). The instruction keeps the kernel's name."""
     from ditl_tpu.ops.mla_attention import mla_paged_attention
 
     b, h, dl, vw, ps, pages, maxp = 128, 64, 640, 512, 256, 8 * 1280, 16
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
     args = (s((b, h, dl), jnp.bfloat16), s((pages, ps, dl), jnp.bfloat16),
             s((b, maxp), jnp.int32), s((b,), jnp.int32), s((b, tail, dl), jnp.bfloat16),
-            s((b,), jnp.int32))
+            s((b,), jnp.int32), s((b,), jnp.bool_))
     compiled = jax.jit(
-        lambda q, pool, tab, lens, tl, st: mla_paged_attention(
+        lambda q, pool, tab, lens, tl, st, alive: mla_paged_attention(
             q, pool, tab, lens, tail=tl, starts=st, value_width=vw, scale=192 ** -0.5,
-            interpret=False)
+            steps=_steps(st, alive, ps, maxp), interpret=False)
     ).lower(*args).compile()
-    assert names.MLA_KERNELS[0] in _instructions(compiled.as_text())
+    assert names.MLA_KERNELS == ("mla_paged_attention",)
+    assert "mla_paged_attention" in _instructions(compiled.as_text())
 
 
 @over_tails
