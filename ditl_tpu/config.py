@@ -211,6 +211,40 @@ class ModelConfig:
     v_head_dim: int = 0
     mla_scale_q_lora: bool = False
     mla_scale_kv_lora: bool = False
+    # DeepSeek-V3.2 (models/dsa.py), selected by ``index_topk > 0``: latent
+    # attention in a SINGLE pre-norm block whose every query attends to the
+    # ``index_topk`` cached tokens a lightning indexer scores highest
+    # (``index_n_heads`` heads of ``index_head_dim``, fed by the query latent;
+    # one index key a token, cached beside the latent entry).
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    # YaRN on the rotary frequencies of that block (``rope_yarn_factor > 0``):
+    # each frequency a blend of ``f`` and ``f / factor`` by a linear ramp
+    # between the correction dimensions of ``beta_fast`` and ``beta_slow``
+    # rotations over ``original_max_len`` positions; the softmax scale times
+    # ``(0.1 * mscale_all_dim * ln(factor) + 1) ** 2`` (models/dsa.py). cos and
+    # sin are never scaled: the source's ``mscale`` equals ``mscale_all_dim``,
+    # whose ratio would scale them.
+    rope_yarn_factor: float = 0.0
+    rope_yarn_original_max_len: int = 4096
+    rope_yarn_beta_fast: float = 32.0
+    rope_yarn_beta_slow: float = 1.0
+    rope_yarn_mscale_all_dim: float = 0.0
+    # Its expert layer (models/moe.py ``_shared_moe_block``): ``scoring_func``
+    # "sigmoid" scores each expert on its own (float32) where "softmax"
+    # scores them against each other; with ``n_group > 0`` the experts lie
+    # in ``n_group`` equal groups, a group's score is the sum of its two
+    # largest biased scores, and a token chooses inside its ``topk_group``
+    # best groups only; ``n_shared_experts`` SwiGLU experts of the routed
+    # experts' width (side by side: one FFN of n x width) see every token.
+    # The first ``first_k_dense_replace`` layers have the dense FFN of
+    # ``intermediate_size`` instead of experts.
+    scoring_func: str = "softmax"
+    n_group: int = 0
+    topk_group: int = 0
+    n_shared_experts: int = 0
+    first_k_dense_replace: int = 0
     # A stack of UNLIKE layers (Granite-4.0-H: Mamba-2 state-space mixers
     # beside attention): one letter a layer, ``m`` a Mamba-2 mixer, ``a``
     # grouped-query attention, every layer followed by the dense FFN; "" =
@@ -335,8 +369,15 @@ class ModelConfig:
     def double_layer(self) -> bool:
         """Whether the layers are LongCat-Flash's double layer (models/mla.py):
         the only block latent attention is implemented in, so derived and not
-        a field until a second latent-attention family has to be told apart."""
-        return self.kv_lora_rank > 0
+        a field: the second latent-attention family (``dsa_layer``) is told
+        apart by its indexer."""
+        return self.kv_lora_rank > 0 and self.index_topk == 0
+
+    @property
+    def dsa_layer(self) -> bool:
+        """Whether the layers are DeepSeek-V3.2's (models/dsa.py): latent
+        attention in a single pre-norm block, behind a lightning indexer."""
+        return self.index_topk > 0
 
     @property
     def layer_period(self) -> str:
@@ -407,9 +448,58 @@ class ModelConfig:
         ):
             raise ValueError(
                 "latent attention (kv_lora_rank > 0) is implemented inside "
-                "LongCat-Flash's double layer only: it needs an expert layer "
-                "and q_lora_rank, qk_nope_head_dim, qk_rope_head_dim and "
-                "v_head_dim set"
+                "LongCat-Flash's double layer and DeepSeek-V3.2's block only: "
+                "it needs an expert layer and q_lora_rank, qk_nope_head_dim, "
+                "qk_rope_head_dim and v_head_dim set"
+            )
+        if self.index_topk > 0 and not (
+            self.kv_lora_rank > 0 and self.index_n_heads > 0
+            and 0 < self.qk_rope_head_dim <= self.index_head_dim
+            and 0 < self.first_k_dense_replace < self.num_layers
+            and not (self.mla_scale_q_lora or self.mla_scale_kv_lora
+                     or self.zero_expert_num)
+        ):
+            raise ValueError(
+                "a lightning indexer (index_topk > 0) selects inside "
+                "DeepSeek-V3.2's block (models/dsa.py): it needs latent "
+                "attention (kv_lora_rank), index_n_heads, an index_head_dim "
+                "of at least qk_rope_head_dim, leading dense layers "
+                "(0 < first_k_dense_replace < num_layers), and none of the "
+                "double layer's mla_scale_* and zero_expert_num"
+            )
+        if self.index_topk == 0 and (
+            self.index_n_heads or self.index_head_dim or self.rope_yarn_factor
+            or self.first_k_dense_replace
+        ):
+            raise ValueError(
+                "index_n_heads, index_head_dim, rope_yarn_* and "
+                "first_k_dense_replace belong to DeepSeek-V3.2's block "
+                "(index_topk > 0): every other block would ignore them"
+            )
+        if self.scoring_func not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"unknown scoring_func {self.scoring_func!r} (softmax|sigmoid)")
+        grouped = self.n_group > 0 or self.topk_group > 0
+        if (self.scoring_func != "softmax" or grouped or self.n_shared_experts) and not (
+            self.experts_held_count
+        ):
+            raise ValueError(
+                "scoring_func, n_group / topk_group and n_shared_experts belong "
+                "to the expert layer with a held share (experts_held_count): "
+                "the layer that holds every expert would ignore them"
+            )
+        if grouped and not (
+            0 < self.topk_group <= self.n_group and self.zero_expert_num == 0
+            and self.num_experts % self.n_group == 0
+            and self.num_experts // self.n_group >= 2
+            and self.topk_group * (self.num_experts // self.n_group)
+            >= self.num_experts_per_tok
+        ):
+            raise ValueError(
+                f"n_group {self.n_group} / topk_group {self.topk_group}: the "
+                f"{self.num_experts} routed experts must lie in equal groups of "
+                "at least two, and the chosen groups must hold the "
+                f"{self.num_experts_per_tok} choices"
             )
         if self.mlp_bwd_impl not in ("xla", "pallas"):
             raise ValueError(

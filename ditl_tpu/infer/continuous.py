@@ -213,17 +213,26 @@ def _flush_tail_into_pools(pools, tk, tv, starts, pos, table, mesh=None,
     return out
 
 
+# A latent pool and the name of its entries in a tick's tails and in a
+# prefill's transient row; DeepSeek-V3.2's index-key pool beside it
+# (models/dsa.py) lives under the same page table and goes wherever it goes.
+LATENT_POOLS = {"cp": ("tc", "c"), "ip": ("ti", "i")}
+
+
 @jax.named_scope("kv_write")
-def _flush_latent_tail(pools, tc, starts, pos, table):
-    """``_flush_tail_into_pools`` for a latent page pool (models/mla.py):
-    ``tc`` (L, 2, B, T, Dl), one tail an attention sublayer, into ``pools
-    ["cp"]`` (2 L, n_pages, ps, Dl) through the same ``kv_flush`` kernel,
-    in place."""
+def _flush_latent_tail(pools, tails, starts, pos, table):
+    """``_flush_tail_into_pools`` for latent page pools (models/mla.py; with
+    models/dsa.py's index keys, two of them): each tail (L, sublayers, B, T,
+    D), one an attention sublayer, into its pool (sublayers x L, n_pages, ps,
+    D) through the same ``kv_flush`` kernel, in place."""
     from ditl_tpu.ops.kv_flush import latent_flush
 
-    cp = pools["cp"]
-    tail = tc.reshape(-1, *tc.shape[2:]).astype(cp.dtype)
-    return {"cp": latent_flush(cp, tail, table, starts, pos)}
+    out = {}
+    for name, pool in pools.items():
+        tail = tails[LATENT_POOLS[name][0]]
+        tail = tail.reshape(-1, *tail.shape[2:]).astype(pool.dtype)
+        out[name] = latent_flush(pool, tail, table, starts, pos)
+    return out
 
 
 def derive_copy_seed(base: int, i: int) -> int:
@@ -615,6 +624,12 @@ class ContinuousEngine:
         # Modes that cannot carry such a page yet refuse here, by name: none
         # may run the K/V code on a latent pool.
         self.latent = model_cfg.kv_lora_rank > 0
+        # DeepSeek-V3.2 (models/dsa.py): an INDEX-KEY pool beside the latent
+        # pool, same page ids; whatever cannot carry a latent page cannot
+        # carry its index keys either, so the refusals below cover both.
+        self.indexed = model_cfg.dsa_layer
+        # live rows' selected entries summed over the decode steps and layers
+        self.dsa_selected_tokens = 0
         if self.latent:
             refused = {
                 "the contiguous cache (cache_mode='contiguous')": cache_mode != "paged",
@@ -709,10 +724,15 @@ class ContinuousEngine:
                 from ditl_tpu.models.mla import SUBLAYERS, latent_width
 
                 # (2 L, P, ps, Dl): one set of pages an attention sublayer
-                shape = (model_cfg.num_layers * SUBLAYERS, self.n_pages,
+                # (DeepSeek-V3.2's block has one)
+                self.sublayers = 1 if self.indexed else SUBLAYERS
+                shape = (model_cfg.num_layers * self.sublayers, self.n_pages,
                          page_size, latent_width(model_cfg))
+                index_shape = (*shape[:3], model_cfg.index_head_dim)
 
             def fresh_pools():
+                if self.indexed:
+                    return {"cp": jnp.zeros(shape, dt), "ip": jnp.zeros(index_shape, dt)}
                 if self.latent:
                     return {"cp": jnp.zeros(shape, dt)}
                 if quantized:
@@ -800,8 +820,12 @@ class ContinuousEngine:
                 self.kv_layers * model_cfg.num_kv_heads
                 * page_size * self.pool_head_dim
             )
+            self.index_pool_bytes = 0
             if self.latent:
                 self.page_bytes = math.prod(shape) // self.n_pages * dt.itemsize
+                if self.indexed:
+                    self.index_pool_bytes = math.prod(index_shape) * dt.itemsize
+                    self.page_bytes += self.index_pool_bytes // self.n_pages
             elif quantized:
                 scale_vals = (
                     self.kv_layers * model_cfg.num_kv_heads * page_size
@@ -987,8 +1011,9 @@ class ContinuousEngine:
         # experts' and the absent experts' totals as two more.
         from ditl_tpu.models.moe import count_width
 
+        self.moe_layers = model_cfg.num_layers - model_cfg.first_k_dense_replace
         self.moe_assignments = np.zeros(
-            (model_cfg.num_layers, count_width(model_cfg)), np.int64)
+            (self.moe_layers, count_width(model_cfg)), np.int64)
         # Latent attention: the live rows' context lengths summed over the
         # decode steps, what the latent kernel had to read (per sublayer).
         self.decode_ctx_tokens = 0
@@ -1778,25 +1803,37 @@ class ContinuousEngine:
         # entries, then room for the chunk's), ``write`` puts the chunk's
         # entries into its pages, in place.
         def latent_gather(pools, table_row):
-            # (2 L, P, ps, Dl) -> (L, 2, 1, ctx * ps + bucket, Dl)
-            cp = pools["cp"]
-            with jax.named_scope("kv_gather"):
-                ctx = cp[:, table_row[:maxp]].reshape(
-                    cp.shape[0], 1, maxp * ps, cp.shape[-1])
-            row = jnp.concatenate(
-                [ctx, jnp.zeros((cp.shape[0], 1, s_bucket, cp.shape[-1]), ctx.dtype)],
-                axis=2)
-            return {"c": row.reshape(cfg.num_layers, -1, *row.shape[1:])}
+            # each pool (2 L, P, ps, D) -> (L, 2, 1, ctx * ps + bucket, D)
+            row = {}
+            # Page by page, each a slice of the pool copied into its place in
+            # the row: ONE gather of all the pages made the compiler copy the
+            # whole pool in lane slices first (2 x 1.04 + 0.52 GiB of
+            # temporaries at this PR's cell, whatever the context: seen in
+            # the buffer assignment compiled for a described v5e).
+            for name, cp in pools.items():
+                def put(j, r, cp=cp):
+                    page = jax.lax.dynamic_slice(
+                        cp, (0, table_row[j], 0, 0), (cp.shape[0], 1, ps, cp.shape[-1]))
+                    return jax.lax.dynamic_update_slice(r, page, (0, 0, j * ps, 0))
+
+                with jax.named_scope("kv_gather"):
+                    r = jax.lax.fori_loop(
+                        0, maxp, put,
+                        jnp.zeros((cp.shape[0], 1, buf, cp.shape[-1]), cp.dtype))
+                row[LATENT_POOLS[name][1]] = r.reshape(cfg.num_layers, -1, *r.shape[1:])
+            return row
 
         def latent_write(pools, row, offset, write_pids):
-            cp = pools["cp"]
-            c = row["c"].reshape(cp.shape[0], 1, buf, cp.shape[-1])
-            chunk = jax.lax.dynamic_slice_in_dim(c, offset, s_bucket, axis=2)
-            chunk = chunk.reshape(cp.shape[0], n_wp, ps, cp.shape[-1])
-            for j in range(n_wp):
-                cp = jax.lax.dynamic_update_slice(
-                    cp, chunk[:, j:j + 1], (0, write_pids[j], 0, 0))
-            return {"cp": cp}
+            out = {}
+            for name, cp in pools.items():
+                c = row[LATENT_POOLS[name][1]].reshape(cp.shape[0], 1, buf, cp.shape[-1])
+                chunk = jax.lax.dynamic_slice_in_dim(c, offset, s_bucket, axis=2)
+                chunk = chunk.reshape(cp.shape[0], n_wp, ps, cp.shape[-1])
+                for j in range(n_wp):
+                    cp = jax.lax.dynamic_update_slice(
+                        cp, chunk[:, j:j + 1], (0, write_pids[j], 0, 0))
+                out[name] = cp
+            return out
 
         def kv_gather(pools, table_row):
             L, _, K, _, D = pools["kp"].shape
@@ -1901,6 +1938,7 @@ class ContinuousEngine:
                     for k, axis in SLOT_AXIS.items():
                         out[k] = jax.lax.dynamic_update_slice_in_dim(
                             pools[k], row[k], slot, axis=axis)
+            moe_counts = moe_counts[:1]  # the experts'; a decode tick's has more
             last = logits[0, s_len - 1]
             masked = _fsm_mask(fsm[0], fsm[1], last) if self.guided else last
             first = sample_logits(
@@ -1963,10 +2001,13 @@ class ContinuousEngine:
             done0 = ~alive | (cur == pad)
             steps = self._attn_steps(starts, ~done0 & (pos < limits))
             if self.latent:
-                from ditl_tpu.models.mla import SUBLAYERS, latent_width
+                from ditl_tpu.models.mla import latent_width
 
                 tails0 = {"tc": jnp.zeros(
-                    (L, SUBLAYERS, n_b, tail_len, latent_width(cfg)), dt)}
+                    (L, self.sublayers, n_b, tail_len, latent_width(cfg)), dt)}
+                if self.indexed:
+                    tails0["ti"] = jnp.zeros(
+                        (L, 1, n_b, tail_len, cfg.index_head_dim), dt)
             else:
                 tails0 = {"tk": jnp.zeros((self.kv_layers, n_b, K, tail_len, D), dt),
                           "tv": jnp.zeros((self.kv_layers, n_b, K, tail_len, D), dt)}
@@ -2012,10 +2053,13 @@ class ContinuousEngine:
                     # (of those whose weights live here)
                     counts, touched, *ctx = moe_acc
                     held = split_counts(moe_counts[0], cfg)[0]
+                    # latent attention: the context tokens this step's rows
+                    # had, and (models/dsa.py) the entries they selected,
+                    # summed over the layers
+                    read = (lengths.sum(), *(m.sum() for m in moe_counts[1:]))
                     moe_acc = (counts + moe_counts[0],
                                touched + (held > 0).sum(),
-                               # latent attention: what this step's kernel read
-                               *(c + lengths.sum() for c in ctx))
+                               *(c + n for c, n in zip(ctx, read)))
                 step_logits = logits[:, 0]
                 nxt = sample_logits(
                     _fsm_mask(ftab, fst, step_logits) if guided else step_logits,
@@ -2043,9 +2087,9 @@ class ContinuousEngine:
                 return (tails, cur, pos, done, keys, hist, fst, lp, moe_acc), ys
 
             fst0 = fstates if guided else jnp.zeros((), jnp.int32)
-            moe0 = ((jnp.zeros((L, count_width(cfg)), jnp.int32),
+            moe0 = ((jnp.zeros((self.moe_layers, count_width(cfg)), jnp.int32),
                      jnp.zeros((), jnp.int32),
-                     *((jnp.zeros((), jnp.int32),) if self.latent else ()))
+                     *((jnp.zeros((), jnp.int32),) * (self.latent + self.indexed)))
                     if moe else ())
             if recurrent:
                 moe0 = (jnp.zeros((), jnp.int32),)
@@ -2060,7 +2104,7 @@ class ContinuousEngine:
             )
 
             if self.latent:
-                out = _flush_latent_tail(pools, tails["tc"], starts, pos, table)
+                out = _flush_latent_tail(pools, tails, starts, pos, table)
             else:
                 out = _flush_tail_into_pools(
                     cache_const, tails["tk"], tails["tv"], starts, pos, table,
@@ -4459,7 +4503,8 @@ class ContinuousEngine:
             res, walked_dev = res[:-1], tuple(res[-1:])
         moe_dev = ()
         if self.moe:  # paged: the tick's (L, E) counts and touched sum
-            n_moe = 3 if self.latent else 2  # and the context tokens read
+            # and the context tokens read, and the entries selected of them
+            n_moe = 2 + self.latent + self.indexed
             res, moe_dev = res[:-n_moe], tuple(res[-n_moe:])
         elif self.recurrent and self.cache_mode == "paged":
             res, moe_dev = res[:-1], tuple(res[-1:])  # the tick's row steps
@@ -4532,6 +4577,10 @@ class ContinuousEngine:
         if ctx:
             self.decode_ctx_tokens += int(ctx[0])
             extra["decode_ctx_tokens"] = int(ctx[0])
+        if len(ctx) > 1:  # an indexer chose among them, in every layer
+            self.dsa_selected_tokens += int(ctx[1])
+            extra.update(dsa_ctx_tokens=int(ctx[0]) * self.cfg.num_layers,
+                         dsa_selected_tokens=int(ctx[1]))
         held, zero, absent = split_counts(counts, self.cfg)
         if held.shape != counts.shape:  # a share of a wider expert layer
             extra.update(moe_assign_held=int(held.sum()),
@@ -4978,9 +5027,13 @@ class ContinuousEngine:
                 out["moe_assign_absent"] = int(absent.sum())
             if self.latent:
                 out["decode_ctx_tokens"] = self.decode_ctx_tokens
+            if self.indexed:
+                out["dsa_ctx_tokens"] = self.decode_ctx_tokens * self.cfg.num_layers
+                out["dsa_selected_tokens"] = self.dsa_selected_tokens
+                out["index_pool_bytes"] = self.index_pool_bytes
             out["moe_experts_touched_mean"] = round(
                 self.moe_touched_sum
-                / max(1, self.moe_decode_steps * self.cfg.num_layers), 4)
+                / max(1, self.moe_decode_steps * self.moe_layers), 4)
         if self.guided:
             out["guided"] = {
                 "fsm_capacity": self.fsm_capacity,

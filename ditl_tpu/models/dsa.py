@@ -1,0 +1,600 @@
+"""DeepSeek-V3.2's block: multi-head latent attention in a SINGLE pre-norm
+block, sparse by a lightning indexer (DeepSeek Sparse Attention), and an FFN
+that is dense in the first ``first_k_dense_replace`` layers and an expert
+layer with a shared expert in the rest. ``llama.forward`` hands its layers to
+``stack`` when ``cfg.dsa_layer``.
+
+With ``n`` an RMSNorm with its own scale, ``t`` a query position and
+``s <= t`` a key position::
+
+    h = x + Attn(n(x));   y = h + FFN(n(h))
+
+    Attn(z): cq = n_q(z Wqa);  q = cq Wqb -> heads of [q_nope | q_rope]
+             [ckv | kr] = z Wkva;  c = n_kv(ckv);  [k_nope | v] = c Wkvb
+             qI = cq Wq_b -> index heads, rotary on the first qk_rope_head_dim
+             kI = LayerNorm(z Wk), one a token, rotary likewise
+             w  = z Wproj * index_n_heads ** -0.5 * index_head_dim ** -0.5
+             I[t, s] = sum_j w[t, j] * relu(qI[t, j] . kI[s])
+             S_t = the min(index_topk, t + 1) positions s <= t of largest I
+             scores = (q_nope . k_nope + rope(q_rope) . rope(kr)) * scale
+             out = (softmax over S_t only) v Wo
+    rotary:  neighbouring pairs (2i, 2i+1) at YaRN's frequencies
+             (``yarn_inv_freq``);  scale = (nope + rope) ** -0.5 * m ** 2,
+             m = 0.1 * mscale_all_dim * ln(factor) + 1
+    FFN:     SwiGLU of ``intermediate_size`` (dense layers) or
+             ``models/moe.py``'s share with sigmoid scores, group-limited
+             choice and a shared expert.
+
+A token's cache entry is the latent ``[c | rope(kr)]`` (``mla.latent_width``:
+576 values stored at 640) AND its index key ``rope(kI)`` (``index_head_dim``
+values), in two page pools under ONE page table.
+
+Attention is always the ABSORBED form (``Wkvb``'s key half folded into the
+query, its value half behind the attention), because a query reads the few
+entries it selected and not a decompressed copy of the context:
+
+- a forward without cache and a prefill chunk (``_select_attend``): index
+  scores of the whole chunk against the row (its context pages' entries, then
+  the chunk's), ``top_indices`` a query, then the selected entries gathered
+  and attended in blocks of ``Q_BLOCK`` queries;
+- a paged decode step (``_decode_attend``): index scores over the row's pages
+  of the index pool and the tick's tail, ``top_indices`` a row, the selected
+  latent entries gathered out of the pool through the page table, the tail's
+  entries beside them under the selection's mask.
+
+Where the buffer is no longer than ``index_topk`` (a static fact) everything
+is selected: the indexer is skipped and attention is dense over the valid
+entries (a decode step: ``ops/mla_attention.py``'s kernel). No path attends
+to more than ``index_topk`` entries of a longer buffer.
+
+Scopes (``ops/names.py`` ``DSA_SCOPES``): ``dsa_index`` (inside ``attn_qkv``
+the indexer's projections, inside ``attn_core`` its scores), ``dsa_select``
+and ``dsa_gather`` inside ``attn_core``; ``mla_q`` / ``mla_kv`` / ``mla_attn``
+as the double layer has them.
+
+Serving only as far as the cache goes, like the double layer: paged pools,
+plain ticks; the engine refuses the rest (infer/continuous.py). The
+multi-token-prediction module is not implemented.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ditl_tpu.config import ModelConfig
+from ditl_tpu.models.mla import latent_width, rope_interleaved
+
+__all__ = ["init_dsa_params", "dsa_logical_axes", "stack", "yarn_inv_freq",
+           "softmax_scale", "top_indices", "Q_BLOCK", "TAP"]
+
+Q_BLOCK = 32  # queries a block of the index scores and of the selected attention
+
+# What the programs chose, for a check that must see it (benchmarks/
+# dsa_check.py: the ENGINE's prefill and decode programs, not a pass of its
+# own): while this is a function, every attention sublayer traced calls it on
+# the host (``jax.debug.callback``) as ``TAP(what, layer, arrays)``, ``what``
+# "chunk" (a forward without cache, a prefill chunk) or "step" (a paged decode
+# step), ``arrays`` the indexer's normed input ``h`` (B, S, D), the queries'
+# ``positions`` (B, S), ``real`` (B, S; None: all) false on a bucket's padding
+# and on dead rows, and ``chosen``: None where everything was selected, else
+# ``top_indices``' ``(idx, ok)`` into the buffer the scores were taken over (a
+# chunk's: positions; a step's: the row's ``pages`` page positions in order,
+# then the tail from ``starts``). None, as everywhere in serving, traces
+# nothing.
+TAP = None
+
+
+def _tap(what: str, layer, **arrays):
+    if TAP is not None:
+        jax.debug.callback(functools.partial(TAP, what), layer, arrays)
+
+
+def yarn_inv_freq(cfg: ModelConfig) -> np.ndarray:
+    """The ``qk_rope_head_dim / 2`` rotary frequencies under YaRN. With ``f_i
+    = theta ** (-2i / dim)``, ``corr(r) = dim * ln(L / (2 pi r)) / (2 ln
+    theta)`` the dimension that makes ``r`` rotations over the original ``L``
+    positions, ``low = floor(corr(beta_fast))``, ``high = ceil(corr(
+    beta_slow))`` and ``ramp_i = clip((i - low) / (high - low), 0, 1)``,
+    frequency ``i`` is ``f_i * (1 - ramp_i) + f_i / factor * ramp_i``: fast
+    dimensions keep their frequency, slow ones are interpolated. Plain
+    ``f_i`` without a factor."""
+    dim, theta = cfg.qk_rope_head_dim, cfg.rope_theta
+    freq = 1.0 / (theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim))
+    if cfg.rope_yarn_factor <= 0:
+        return freq.astype(np.float32)
+
+    def corr(rotations: float) -> float:
+        return (dim * math.log(cfg.rope_yarn_original_max_len / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(corr(cfg.rope_yarn_beta_fast)), 0)
+    high = min(math.ceil(corr(cfg.rope_yarn_beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low) / (high - low), 0, 1)
+    return (freq * (1 - ramp) + freq / cfg.rope_yarn_factor * ramp).astype(np.float32)
+
+
+def softmax_scale(cfg: ModelConfig) -> float:
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    if cfg.rope_yarn_factor > 0 and cfg.rope_yarn_mscale_all_dim:
+        m = 0.1 * cfg.rope_yarn_mscale_all_dim * math.log(cfg.rope_yarn_factor) + 1.0
+        scale *= m * m
+    return scale
+
+
+# ---------------------------------------------------------------------------
+# Parameters
+# ---------------------------------------------------------------------------
+
+
+def _kinds(cfg: ModelConfig) -> dict[str, int]:
+    """The two stacks of ``params["layers"]`` and their depths."""
+    return {"dense": cfg.first_k_dense_replace,
+            "sparse": cfg.num_layers - cfg.first_k_dense_replace}
+
+
+def init_dsa_params(rng: jax.Array, cfg: ModelConfig) -> dict[str, Any]:
+    """``{"dense": ..., "sparse": ...}``: the leading dense layers and the
+    expert layers, each a stack of its own depth (a scan a stack). Leaves are
+    drawn in ``param_dtype`` (``moe.lean_dense``)."""
+    from ditl_tpu.models.moe import init_moe_params, lean_dense
+
+    pd = jnp.dtype(cfg.param_dtype)
+    d, f, nh = cfg.hidden_size, cfg.intermediate_size, cfg.num_heads
+    qr, kr = cfg.q_lora_rank, cfg.kv_lora_rank
+    nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    hi, di = cfg.index_n_heads, cfg.index_head_dim
+
+    def one(rng, n, dense_ffn):
+        keys = iter(jax.random.split(rng, 16))
+
+        def dense(shape, fan_in):
+            return lean_dense(next(keys), (n,) + shape, fan_in, pd)
+
+        out = {
+            "attn_norm": {"scale": jnp.ones((n, d), pd)},
+            "attn": {
+                "w_qa": dense((d, qr), d),
+                "q_norm": jnp.ones((n, qr), pd),
+                "w_qb": dense((qr, nh * (nope + rope)), qr),
+                "w_kva": dense((d, kr + rope), d),
+                "kv_norm": jnp.ones((n, kr), pd),
+                "w_kvb": dense((kr, nh * (nope + vd)), kr),
+                "wo": dense((nh * vd, d), nh * vd),
+            },
+            "index": {
+                "wq_b": dense((qr, hi * di), qr),
+                "wk": dense((d, di), d),
+                "k_norm": {"scale": jnp.ones((n, di), pd), "bias": jnp.zeros((n, di), pd)},
+                "w_proj": dense((d, hi), d),
+            },
+            "mlp_norm": {"scale": jnp.ones((n, d), pd)},
+        }
+        if dense_ffn:
+            out["mlp"] = {"w_gate": dense((d, f), d), "w_up": dense((d, f), d),
+                          "w_down": dense((f, d), f)}
+        else:
+            out["moe"] = init_moe_params(next(keys), cfg, n_layers=n)
+        return out
+
+    k_dense, k_sparse = jax.random.split(rng)
+    kinds = _kinds(cfg)
+    return {"dense": one(k_dense, kinds["dense"], True),
+            "sparse": one(k_sparse, kinds["sparse"], False)}
+
+
+def dsa_logical_axes(cfg: ModelConfig) -> dict[str, Any]:
+    from ditl_tpu.models.moe import moe_logical_axes
+
+    def one(dense_ffn):
+        out = {
+            "attn_norm": {"scale": ("layers", "norm")},
+            "attn": {
+                "w_qa": ("layers", "embed", None),
+                "q_norm": ("layers", "norm"),
+                "w_qb": ("layers", None, "heads"),
+                "w_kva": ("layers", "embed", None),
+                "kv_norm": ("layers", "norm"),
+                "w_kvb": ("layers", None, "heads"),
+                "wo": ("layers", "heads", "embed"),
+            },
+            "index": {
+                "wq_b": ("layers", None, "heads"),
+                "wk": ("layers", "embed", None),
+                "k_norm": {"scale": ("layers", "norm"), "bias": ("layers", "norm")},
+                "w_proj": ("layers", "embed", None),
+            },
+            "mlp_norm": {"scale": ("layers", "norm")},
+        }
+        if dense_ffn:
+            out["mlp"] = {"w_gate": ("layers", "embed", "mlp"),
+                          "w_up": ("layers", "embed", "mlp"),
+                          "w_down": ("layers", "mlp", "embed")}
+        else:
+            out["moe"] = moe_logical_axes(cfg)
+        return out
+
+    return {"dense": one(True), "sparse": one(False)}
+
+
+# ---------------------------------------------------------------------------
+# The indexer, the selection, the attention over what was selected
+# ---------------------------------------------------------------------------
+
+
+def _layer_norm(x, norm, eps):
+    xf = x.astype(jnp.float32)
+    mean = jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean((xf - mean) ** 2, axis=-1, keepdims=True)
+    out = (xf - mean) * jax.lax.rsqrt(var + eps)
+    return (out * norm["scale"].astype(jnp.float32)
+            + norm["bias"].astype(jnp.float32)).astype(x.dtype)
+
+
+def _rope_front(x, positions, inv_freq, rope: int):
+    """Rotary on the first ``rope`` values of each head. x: (B, S, H, D)."""
+    return jnp.concatenate(
+        [rope_interleaved(x[..., :rope], positions, 0.0, inv_freq=inv_freq),
+         x[..., rope:]], axis=-1)
+
+
+def index_scores(qi: jax.Array, w: jax.Array, keys: jax.Array) -> jax.Array:
+    """``I[t, s] = sum_j w[t, j] * relu(qI[t, j] . kI[s])``. qi: (B, S, Hi, Di),
+    w: (B, S, Hi) float32, keys: (B, N, Di) -> (B, S, N) float32."""
+    s = jnp.einsum("bqhd,bnd->bqhn", qi, keys.astype(qi.dtype),
+                   preferred_element_type=jnp.float32)
+    return jnp.einsum("bqhn,bqh->bqn", jax.nn.relu(s), w)
+
+
+def top_indices(scores: jax.Array, k: int) -> tuple[jax.Array, jax.Array]:
+    """The ``k`` largest of each row of ``scores`` (..., N), invalid entries
+    at -inf: ``(indices (..., k) int32, ok (..., k))``, ``ok`` false where
+    the row had fewer than ``k`` valid entries and an invalid one filled the
+    place. Exact, ties to the lower index. Measured on a v5e at 32 rows of
+    33,800 (PERF.md section 6, PR 44): ``jax.lax.top_k`` 1.48 ms; a bit-wise
+    threshold search 0.26 ms for the MASK, but turning a mask into the list a
+    gather needs cost more than it saved (sort 1.3, scatter 5.4, search 11.0
+    ms), so the list comes from ``top_k``."""
+    vals, idx = jax.lax.top_k(scores, k)
+    return idx.astype(jnp.int32), vals > -jnp.inf
+
+
+def _attend(q_full, entries, ok, *, scale: float, r: int, per_query: bool):
+    """Absorbed attention of each query over its entries. q_full: (B, S, H,
+    Dl); entries (B, S, K, Dl) (``per_query``: every query its own, gathered)
+    or (B, N, Dl) (shared); ok: (B, S, K or N) -> (B, S, H, r), float32
+    softmax. A query with no entry at all comes out as the mean of whatever
+    it was handed: the caller discards such rows."""
+    from ditl_tpu.ops.attention import NEG_INF
+
+    eq = "bqhd,bqkd->bqhk" if per_query else "bqhd,bkd->bqhk"
+    s = jnp.einsum(eq, q_full, entries, preferred_element_type=jnp.float32) * scale
+    s = jnp.where(ok[:, :, None, :], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1).astype(entries.dtype)
+    ev = "bqhk,bqkd->bqhd" if per_query else "bqhk,bkd->bqhd"
+    return jnp.einsum(ev, p, entries[..., :r])
+
+
+def _blocks(fn, xs: tuple, s: int):
+    """``fn`` over blocks of ``Q_BLOCK`` queries (axis 1 of every array of
+    ``xs``), one after the other; a ragged last block is padded with zeros
+    (queries that see nothing) and cut off again. Each block is written into
+    ONE output buffer the loop carries, so block i + 1 follows block i and
+    only one block's gathered entries are live at a time."""
+    n = Q_BLOCK
+    if s <= n:
+        return fn(*xs)
+    nb = -(-s // n)
+    if s % n:
+        xs = tuple(jnp.pad(x, ((0, 0), (0, nb * n - s)) + ((0, 0),) * (x.ndim - 2))
+                   for x in xs)
+
+    def block(i):
+        return fn(*(jax.lax.dynamic_slice_in_dim(x, i * n, n, axis=1) for x in xs))
+
+    first = jax.eval_shape(block, 0)
+    out = jnp.zeros((first.shape[0], nb * n, *first.shape[2:]), first.dtype)
+    out = jax.lax.fori_loop(
+        0, nb, lambda i, o: jax.lax.dynamic_update_slice_in_dim(o, block(i), i * n, axis=1),
+        out)
+    return out[:, :s]
+
+
+def _select_attend(q_full, qi, w, entries, keys, valid, *, cfg: ModelConfig):
+    """Every query of a chunk over the row it sits in. entries: (B, N, Dl),
+    keys: (B, N, Di), valid: (B, S, N) (causal, same document, written).
+    -> (lat (B, S, H, r), selected tokens (), ``top_indices``' (idx, ok) or
+    None where everything valid is selected)."""
+    s, n = q_full.shape[1], entries.shape[1]
+    k, r = cfg.index_topk, cfg.kv_lora_rank
+    scale = softmax_scale(cfg)
+    if n <= k:  # everything valid is selected: dense, and no indexer
+        with jax.named_scope("mla_attn"):
+            lat = _blocks(lambda q, v: _attend(q, entries, v, scale=scale, r=r,
+                                               per_query=False), (q_full, valid), s)
+        return lat, valid.sum(), None
+    with jax.named_scope("dsa_index"):
+        scores = _blocks(lambda q, ww: index_scores(q, ww, keys), (qi, w), s)
+    with jax.named_scope("dsa_select"):
+        idx, ok = top_indices(jnp.where(valid, scores, -jnp.inf), k)  # (B, S, k)
+
+    def block(q, ix, good):
+        with jax.named_scope("dsa_gather"):
+            sel = jax.vmap(lambda e, i: e[i])(entries, ix)  # (B, n_q, k, Dl)
+        with jax.named_scope("mla_attn"):
+            return _attend(q, sel, good, scale=scale, r=r, per_query=True)
+
+    lat = _blocks(block, (q_full, idx, ok), s)
+    return lat, ok.sum(), (idx, ok)
+
+
+def paged_index_scores(qi, w, ipool, table):
+    """A decode step's index scores over each row's PAGES, in page-table
+    order (which is position order): qi (B, Hi, Di), w (B, Hi) float32,
+    ipool (P, ps, Di), table (B, maxp) -> (B, maxp * ps) float32. Positions
+    no page of the row holds score against whatever the table names there:
+    the caller masks by the row's length."""
+    b, maxp = table.shape
+    keys = ipool[table].reshape(b, maxp * ipool.shape[1], ipool.shape[-1])
+    return index_scores(qi[:, None], w[:, None], keys)[:, 0]
+
+
+def _decode_attend(q_full, qi, w, *, cfg: ModelConfig, pools, tails, paged):
+    """One decode step, every row over its pages and the tick's tail.
+    q_full: (B, H, Dl), qi: (B, Hi, Di), w: (B, Hi); pools: the flat latent
+    and index pools (L * P, ps, .); tails: this layer's (B, T, .), this
+    step's entry already written; ``paged["table"]`` names this layer's
+    pages. -> (lat (B, H, r), selected tokens (), ``top_indices``' (idx, ok)
+    or None where no row can hold more than ``index_topk`` tokens)."""
+    cp, ip = pools["cp"], pools["ip"]
+    tc, ti = tails
+    table, lengths, starts = paged["table"], paged["lengths"], paged["starts"]
+    b, maxp = table.shape
+    ps, t = cp.shape[1], tc.shape[1]
+    n = maxp * ps
+    k, r = cfg.index_topk, cfg.kv_lora_rank
+    scale = softmax_scale(cfg)
+    if n + t <= k:  # no row can hold more than index_topk tokens: dense
+        from ditl_tpu.ops.mla_attention import mla_paged_attention
+
+        with jax.named_scope("mla_attn"):
+            lat = mla_paged_attention(
+                q_full, cp, table, lengths, tail=tc, starts=starts, value_width=r,
+                scale=scale, steps=paged.get("steps"))
+        return lat, lengths.sum(), None
+    in_pages = jnp.arange(n, dtype=jnp.int32)[None, :] < jnp.minimum(starts, lengths)[:, None]
+    in_tail = (starts[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]) < lengths[:, None]
+    with jax.named_scope("dsa_index"):
+        scores = jnp.concatenate(
+            [paged_index_scores(qi, w, ip, table),
+             index_scores(qi[:, None], w[:, None], ti)[:, 0]], axis=1)  # (B, n + T)
+    with jax.named_scope("dsa_select"):
+        valid = jnp.concatenate([in_pages, in_tail], axis=1)
+        idx, ok = top_indices(jnp.where(valid, scores, -jnp.inf), k)  # (B, k)
+        at = jnp.minimum(idx, n - 1)
+        flat = jnp.take_along_axis(table, at // ps, axis=1) * ps + at % ps
+        ok_pages = ok & (idx < n)
+        ok_tail = ((idx[:, :, None] == n + jnp.arange(t, dtype=jnp.int32))
+                   & ok[:, :, None]).any(axis=1)  # (B, T)
+    with jax.named_scope("dsa_gather"):
+        sel = cp.reshape(-1, cp.shape[-1])[flat]  # (B, k, Dl)
+    with jax.named_scope("mla_attn"):
+        entries = jnp.concatenate([sel, tc.astype(sel.dtype)], axis=1)
+        good = jnp.concatenate([ok_pages, ok_tail], axis=1)
+        lat = _attend(q_full[:, None], entries[:, None], good[:, None], scale=scale,
+                      r=r, per_query=True)[:, 0]
+        lat = jnp.where(lengths[:, None, None] > 0, lat, 0).astype(q_full.dtype)
+    return lat, ok.sum(), (idx, ok)
+
+
+def _attention(a, ix, h, *, cfg: ModelConfig, positions, allowed, cache, cache_index,
+               paged, pools, cd, layer, token_mask):
+    """The attention sublayer of layer ``layer`` on the normed input ``h`` (B,
+    S, D): ``(out (B, S, D) before the residual, new cache or None, selected
+    tokens)``. ``cache``: None; a prefill's row ``{"c": (B, Smax, Dl),
+    "i": (B, Smax, Di)}`` (written at ``cache_index``, attended under
+    ``allowed`` (B, S, Smax)); or, with ``pools``, this layer's tails
+    ``{"tc": (B, T, Dl), "ti": (B, T, Di)}`` of a paged decode step."""
+    from ditl_tpu.models.llama import rms_norm
+    from ditl_tpu.ops.quant import weight_einsum
+
+    b, s, _ = h.shape
+    nh, eps = cfg.num_heads, cfg.rms_norm_eps
+    r, hi, di = cfg.kv_lora_rank, cfg.index_n_heads, cfg.index_head_dim
+    nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    w_kvb = a["w_kvb"].astype(cd).reshape(r, nh, nope + vd)
+    inv_freq = jnp.asarray(yarn_inv_freq(cfg))
+    pad = latent_width(cfg) - r - rope
+
+    with jax.named_scope("attn_qkv"):
+        with jax.named_scope("mla_q"):
+            cq = rms_norm(weight_einsum("bsd,dr->bsr", h, a["w_qa"], compute_dtype=cd),
+                          a["q_norm"], eps)
+            q = weight_einsum("bsr,rf->bsf", cq, a["w_qb"], compute_dtype=cd)
+            q = q.reshape(b, s, nh, nope + rope)
+            q_rope = rope_interleaved(q[..., nope:], positions, 0.0, inv_freq=inv_freq)
+            # Wkvb's key half folded into the query: kv_lora_rank wide, against c
+            q_lat = jnp.einsum("bshn,rhn->bshr", q[..., :nope], w_kvb[..., :nope])
+            q_full = jnp.concatenate(
+                [q_lat, q_rope, jnp.zeros((b, s, nh, pad), q_lat.dtype)], axis=-1)
+        with jax.named_scope("mla_kv"):
+            ckr = weight_einsum("bsd,df->bsf", h, a["w_kva"], compute_dtype=cd)
+            c = rms_norm(ckr[..., :r], a["kv_norm"], eps)
+            kr = rope_interleaved(ckr[..., None, r:], positions, 0.0,
+                                  inv_freq=inv_freq)[:, :, 0]
+            entry = jnp.concatenate(
+                [c, kr, jnp.zeros((b, s, pad), c.dtype)], axis=-1)  # (B, S, Dl)
+        with jax.named_scope("dsa_index"):
+            qi = weight_einsum("bsr,rf->bsf", cq, ix["wq_b"], compute_dtype=cd)
+            qi = _rope_front(qi.reshape(b, s, hi, di), positions, inv_freq, rope)
+            ki = _layer_norm(weight_einsum("bsd,df->bsf", h, ix["wk"], compute_dtype=cd),
+                             ix["k_norm"], eps)
+            ki = _rope_front(ki[:, :, None], positions, inv_freq, rope)[:, :, 0]
+            w = weight_einsum("bsd,dh->bsh", h, ix["w_proj"], compute_dtype=cd,
+                              preferred=jnp.float32) * (hi ** -0.5 * di ** -0.5)
+
+    with jax.named_scope("attn_core"):
+        if pools is not None:
+            with jax.named_scope("kv_write"):
+                new_cache = {
+                    "tc": jax.lax.dynamic_update_slice(
+                        cache["tc"], entry.astype(cache["tc"].dtype), (0, paged["t"], 0)),
+                    "ti": jax.lax.dynamic_update_slice(
+                        cache["ti"], ki.astype(cache["ti"].dtype), (0, paged["t"], 0)),
+                }
+            lat, n_sel, chosen = _decode_attend(
+                q_full[:, 0], qi[:, 0], w[:, 0], cfg=cfg, pools=pools,
+                tails=(new_cache["tc"], new_cache["ti"]), paged=paged)
+            lat = lat[:, None]
+            _tap("step", layer, h=h, positions=positions, real=token_mask, chosen=chosen,
+                 starts=paged["starts"],
+                 pages=paged["table"].shape[1] * pools["cp"].shape[1])
+        else:
+            entries, keys, new_cache = entry, ki, None
+            if cache is not None:  # a prefill's row: all of it is context
+                new_cache = {
+                    "c": jax.lax.dynamic_update_slice(
+                        cache["c"], entry.astype(cache["c"].dtype), (0, cache_index, 0)),
+                    "i": jax.lax.dynamic_update_slice(
+                        cache["i"], ki.astype(cache["i"].dtype), (0, cache_index, 0)),
+                }
+                entries, keys = new_cache["c"].astype(cd), new_cache["i"].astype(cd)
+            lat, n_sel, chosen = _select_attend(q_full, qi, w, entries, keys, allowed,
+                                                cfg=cfg)
+            _tap("chunk", layer, h=h, positions=positions, real=token_mask, chosen=chosen)
+    with jax.named_scope("attn_out"):
+        with jax.named_scope("mla_kv"):
+            # Wkvb's value half, folded behind the attention
+            attn = jnp.einsum("bshr,rhv->bshv", lat.astype(cd), w_kvb[..., nope:])
+        out = weight_einsum("bsf,fd->bsd", attn.reshape(b, s, nh * vd), a["wo"],
+                            compute_dtype=cd)
+    return out, new_cache, n_sel
+
+
+def _block(lp, x, *, cfg: ModelConfig, positions, allowed, mesh, rules, layer_cache,
+           cache_index, paged, pools, token_mask, moe_stack, layer_index, layer):
+    """Layer ``layer`` of the model, ``layer_index`` of its stack: ``(x, aux,
+    new cache or None, expert counts (count_width,) or None (a dense layer),
+    selected tokens ())``.
+    ``layer_cache`` / the returned cache: a prefill's row ``{"c": (1,
+    B, Smax, Dl), "i": (1, B, Smax, Di)}`` or a decode step's tails ``{"tc":
+    (1, B, T, Dl), "ti": ...}`` (the axis of one: the double layer's two
+    sublayers, which the engine's trees carry)."""
+    from ditl_tpu.models.llama import _constrain, dense_mlp, rms_norm
+    from ditl_tpu.models.moe import moe_block
+
+    cd = jnp.dtype(cfg.dtype)
+    eps = cfg.rms_norm_eps
+    cache = None if layer_cache is None else {k: v[0] for k, v in layer_cache.items()}
+    with jax.named_scope("attn_qkv"):
+        h = rms_norm(x, lp["attn_norm"]["scale"], eps)
+    out, new_cache, n_sel = _attention(
+        lp["attn"], lp["index"], h, cfg=cfg, positions=positions, allowed=allowed,
+        cache=cache, cache_index=cache_index, paged=paged, pools=pools, cd=cd, layer=layer,
+        token_mask=token_mask)
+    with jax.named_scope("attn_out"):
+        x = _constrain(x + out, ("batch", "seq", "act_embed"), mesh, rules)
+    with jax.named_scope("mlp"):
+        u = rms_norm(x, lp["mlp_norm"]["scale"], eps)
+        if "mlp" in lp:
+            y = dense_mlp(lp["mlp"], u, cfg=cfg, mesh=mesh, rules=rules)
+            aux, counts = jnp.zeros((), jnp.float32), None
+        else:
+            y, aux, counts = moe_block(
+                {**lp["moe"], **(moe_stack or {})}, u, cfg, token_mask=token_mask,
+                mesh=mesh, layer=layer_index if moe_stack else None)
+        x = _constrain(x + y, ("batch", "seq", "act_embed"), mesh, rules)
+    if new_cache is not None:
+        new_cache = {k: v[None] for k, v in new_cache.items()}
+    return x, aux, new_cache, counts, n_sel.astype(jnp.int32)
+
+
+def stack(layers, x, *, cfg: ModelConfig, positions, segment_ids, mesh, rules,
+          cache=None, cache_index=None, attn_mask=None, paged=None,
+          prefill_causal=False, token_mask=None):
+    """All layers: the leading dense stack, then the expert stack, each a
+    scan. -> ``(x, layer_aux (L,), new cache or None, expert counts (expert
+    layers, count_width), selected tokens (L,))``.
+
+    ``cache`` as ``llama.forward`` gets it: a prefill's rows ``{"c": (L, 1,
+    B, Smax, Dl), "i": (L, 1, B, Smax, Di)}``, or the page pools ``{"cp": (L,
+    P, ps, Dl), "ip": (L, P, ps, Di)}`` beside the tick's tails ``{"tc": (L,
+    1, B, T, Dl), "ti": (L, 1, B, T, Di)}`` (then only the tails come back).
+    The pools stay whole and outside the scans, every layer's pages one axis,
+    and each layer's page table is offset to its own (as ``llama.forward``
+    does for every other pool)."""
+    from ditl_tpu.models.llama import _apply_remat
+    from ditl_tpu.models.moe import experts_in_place, grouped_rows
+
+    if mesh is not None and mesh.shape.get("stage", 1) > 1:
+        raise ValueError("pipeline parallelism does not carry DeepSeek-V3.2's "
+                         "two stacks (models/dsa.py)")
+    b, s, _ = x.shape
+    pools = None
+    if cache is not None and "cp" in cache:
+        n_pages = cache["cp"].shape[1]
+        pools = {k: cache[k].reshape(-1, *cache[k].shape[2:]) for k in ("cp", "ip")}
+        cache = {k: cache[k] for k in ("tc", "ti")}
+    if pools is not None:
+        allowed = None
+    elif cache is not None and not prefill_causal:
+        allowed = attn_mask  # (B, S, Smax), the engine's
+    else:
+        idx = jnp.arange(s)
+        allowed = jnp.broadcast_to(idx[None, :, None] >= idx[None, None, :], (b, s, s))
+        if segment_ids is not None:
+            allowed = allowed & (segment_ids[:, :, None] == segment_ids[:, None, :])
+        if cache is not None:
+            # a prefill of an EMPTY row from offset 0: the chunk attends to
+            # itself, wherever it sits in the row
+            smax = cache["c"].shape[3]
+            allowed = jax.lax.dynamic_update_slice(
+                jnp.zeros((b, s, smax), bool), allowed, (0, 0, cache_index))
+
+    outs, first = [], 0
+    for kind, depth in _kinds(cfg).items():
+        lp_stack, moe_stack = layers[kind], None
+        if cache is not None and kind == "sparse" and experts_in_place(
+                lp_stack["moe"], grouped_rows(cfg, b * s), mesh):
+            # the scan slices only the router and the shared expert; the
+            # kernel addresses the layer's experts inside the stack
+            in_loop = ("router", "router_bias", "shared")
+            moe_stack = {k: v for k, v in lp_stack["moe"].items() if k not in in_loop}
+            lp_stack = {**lp_stack, "moe": {k: v for k, v in lp_stack["moe"].items()
+                                            if k in in_loop}}
+
+        def layer_fn(carry, xs, first=first, moe_stack=moe_stack):
+            lp, layer_cache, i = xs
+            layer_paged = paged
+            if pools is not None:
+                layer_paged = {**paged, "table": paged["table"] + (first + i) * n_pages}
+            y, *ys = _block(
+                lp, carry, cfg=cfg, positions=positions, allowed=allowed, mesh=mesh,
+                rules=rules, layer_cache=layer_cache, cache_index=cache_index,
+                paged=layer_paged, pools=pools, token_mask=token_mask,
+                moe_stack=moe_stack, layer_index=i, layer=first + i)
+            return y, tuple(ys)
+
+        if cache is None:
+            layer_fn = _apply_remat(layer_fn, cfg)
+        part = None if cache is None else {
+            k: v[first:first + depth] for k, v in cache.items()}
+        with jax.named_scope("layer_scan"):
+            x, ys = jax.lax.scan(
+                layer_fn, x, (lp_stack, part, jnp.arange(depth, dtype=jnp.int32)))
+        outs.append(ys)
+        first += depth
+
+    def joined(parts):  # the two stacks' outputs, one after the other
+        parts = [p for p in parts if p is not None]
+        return jax.tree.map(lambda *leaves: jnp.concatenate(leaves), *parts) if parts else None
+
+    aux, new_cache, counts, n_sel = (joined(parts) for parts in zip(*outs))
+    return x, aux, new_cache, counts, n_sel

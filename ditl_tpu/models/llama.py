@@ -79,6 +79,21 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
             "lm_head": {"kernel": dense(next(keys), (d, cfg.vocab_size), d)},
         }
 
+    if cfg.dsa_layer:
+        # DeepSeek-V3.2 (models/dsa.py): a leading dense stack and an expert
+        # stack under "layers", drawn in ``param_dtype`` like the double layer
+        from ditl_tpu.models.dsa import init_dsa_params
+
+        if cfg.tie_embeddings or cfg.lora_rank > 0:
+            raise ValueError("DeepSeek-V3.2's block has an untied head and no LoRA")
+        return {
+            "embed": {"embedding": (
+                jax.random.normal(next(keys), (cfg.vocab_size, d)) * 0.02).astype(pd)},
+            "layers": init_dsa_params(next(keys), cfg),
+            "final_norm": {"scale": jnp.ones((d,), pd)},
+            "lm_head": {"kernel": dense(next(keys), (d, cfg.vocab_size), d)},
+        }
+
     if cfg.layer_types:
         # Granite-4.0-H (models/ssm.py): a subtree a position of the period
         from ditl_tpu.models.ssm import init_hybrid_params
@@ -166,6 +181,15 @@ def param_logical_axes(cfg: ModelConfig) -> Params:
         return {
             "embed": {"embedding": ("vocab", "embed")},
             "layers": double_layer_logical_axes(cfg),
+            "final_norm": {"scale": ("norm",)},
+            "lm_head": {"kernel": ("embed", "vocab")},
+        }
+    if cfg.dsa_layer:
+        from ditl_tpu.models.dsa import dsa_logical_axes
+
+        return {
+            "embed": {"embedding": ("vocab", "embed")},
+            "layers": dsa_logical_axes(cfg),
             "final_norm": {"scale": ("norm",)},
             "lm_head": {"kernel": ("embed", "vocab")},
         }
@@ -788,7 +812,21 @@ def forward(
             rec = {k: cache[k] for k in ("ssm", "conv")}
             cache = {k: v for k, v in cache.items() if k not in rec}
 
-    if cache is not None:
+    if cfg.dsa_layer:
+        # DeepSeek-V3.2: a leading dense stack and an expert stack, each a
+        # scan of its own (models/dsa.py), cached or not
+        from ditl_tpu.models.dsa import stack
+
+        if adapter_ids is not None:
+            raise ValueError("LoRA adapters are not implemented for DeepSeek-V3.2's block")
+        x, layer_aux, new_cache, *moe_counts = stack(
+            params["layers"], x, cfg=cfg, positions=positions, segment_ids=segment_ids,
+            mesh=mesh, rules=rules, cache=cache, cache_index=cache_index,
+            attn_mask=attn_mask, paged=paged, prefill_causal=prefill_causal,
+            token_mask=token_mask)
+        if not with_moe_counts:  # the experts' counts, then the tokens selected
+            moe_counts = []
+    elif cache is not None:
         layers, moe_stack = params["layers"], None
         if "moe" in layers:
             from ditl_tpu.models.moe import experts_in_place, grouped_rows

@@ -1,7 +1,9 @@
 """LongCat-Flash's block: a shortcut-connected DOUBLE layer whose attention
 is multi-head latent attention (MLA). ``llama.forward``'s layer scan carries
 it in place of ``_decoder_layer`` when ``cfg.double_layer`` (every layer of
-that model is alike, so one stack suffices).
+that model is alike, so one stack suffices). Latent attention in a SINGLE
+pre-norm block, behind an indexer, is DeepSeek-V3.2's (models/dsa.py), which
+shares ``latent_width`` and ``rope_interleaved`` with this file.
 
 With ``n`` an RMSNorm with its own scale, for layer input ``x``::
 
@@ -161,11 +163,15 @@ def double_layer_logical_axes(cfg: ModelConfig) -> dict[str, Any]:
     }
 
 
-def rope_interleaved(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+def rope_interleaved(x: jax.Array, positions: jax.Array, theta: float,
+                     inv_freq: jax.Array | None = None) -> jax.Array:
     """Rotary embedding over neighbouring pairs (2i, 2i+1). x: (B, S, H, D);
-    positions: (B, S)."""
+    positions: (B, S). ``inv_freq`` (D / 2,): the frequencies where they are
+    not ``theta``'s own (models/dsa.py: YaRN)."""
     d = x.shape[-1]
-    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    inv = inv_freq
+    if inv is None:
+        inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
     ang = positions[..., None].astype(jnp.float32) * inv  # (B, S, D/2)
     cos, sin = jnp.cos(ang)[:, :, None, :], jnp.sin(ang)[:, :, None, :]
     xf = x.astype(jnp.float32).reshape(*x.shape[:-1], d // 2, 2)

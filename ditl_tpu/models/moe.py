@@ -53,6 +53,16 @@ chose no held expert runs no matmul at all. The rows come back by a
 scatter-add over tokens in float32. ``counts`` then has ``n_held + 2``
 entries: each held expert's assignments, then the zero-compute experts'
 and the absent ones' totals (``split_counts``).
+
+DeepSeek-V3.2's expert layer is such a share too, with three things of its
+own (``ModelConfig.scoring_func``, ``n_group``, ``n_shared_experts``): the
+scores are SIGMOIDS of the router's logits, each expert on its own; the
+choice (on scores plus bias) is group-limited: a group's score is the sum of
+its two largest, the ``topk_group`` best groups stay and the k largest inside
+them are chosen; the chosen scores (without the bias), renormalised where
+``norm_topk_prob``, times ``routed_scaling_factor`` weigh the outputs; and a
+SHARED expert, a dense SwiGLU FFN every token passes through, joins the sum
+(scope ``moe_shared``; every chip of the deployment holds it whole).
 """
 
 from __future__ import annotations
@@ -127,9 +137,13 @@ def lean_dense(key, shape, fan_in: int, pd) -> jax.Array:
         lambda k: (jax.random.normal(k, shape, jnp.float32) * std).astype(pd))(key)
 
 
-def init_moe_params(rng: jax.Array, cfg: ModelConfig) -> dict[str, Any]:
+def init_moe_params(rng: jax.Array, cfg: ModelConfig,
+                    n_layers: int | None = None) -> dict[str, Any]:
+    """``n_layers``: the depth of the stack where not every layer has experts
+    (models/dsa.py); ``cfg.num_layers`` otherwise."""
     pd = jnp.dtype(cfg.param_dtype)
-    d, f, L, E = cfg.hidden_size, cfg.intermediate_size, cfg.num_layers, cfg.num_experts
+    d, f, E = cfg.hidden_size, cfg.intermediate_size, cfg.num_experts
+    L = cfg.num_layers if n_layers is None else n_layers
     k1, k2, k3, k4 = jax.random.split(rng, 4)
     if shares_experts(cfg):
         f = cfg.expert_ffn_hidden_size or f
@@ -142,6 +156,14 @@ def init_moe_params(rng: jax.Array, cfg: ModelConfig) -> dict[str, Any]:
         }
         if cfg.router_bias:  # float32 like the probabilities it joins
             out["router_bias"] = jnp.zeros((L, E + cfg.zero_expert_num), jnp.float32)
+        if cfg.n_shared_experts:
+            fs = cfg.n_shared_experts * f
+            k5, k6, k7 = jax.random.split(k4, 3)
+            out["shared"] = {
+                "w_gate": lean_dense(k5, (L, d, fs), d, pd),
+                "w_up": lean_dense(k6, (L, d, fs), d, pd),
+                "w_down": lean_dense(k7, (L, fs, d), fs, pd),
+            }
         return out
 
     def dense(key, shape, fan_in):
@@ -159,6 +181,10 @@ def moe_logical_axes(cfg: ModelConfig) -> dict[str, Any]:
     return {
         "router": ("layers", "embed", None),
         **({"router_bias": ("layers", None)} if cfg.router_bias else {}),
+        **({"shared": {"w_gate": ("layers", "embed", "mlp"),
+                       "w_up": ("layers", "embed", "mlp"),
+                       "w_down": ("layers", "mlp", "embed")}}
+           if cfg.n_shared_experts else {}),
         "w_gate": ("layers", "expert", "embed", "mlp"),
         "w_up": ("layers", "expert", "embed", "mlp"),
         "w_down": ("layers", "expert", "mlp", "embed"),
@@ -323,14 +349,15 @@ def _shared_moe_block(moe, h, cfg: ModelConfig, *, token_mask, mesh, layer):
             else token_mask.reshape(t).astype(bool))
 
     with jax.named_scope("moe_router"):
-        gates = jax.nn.softmax(
-            jnp.einsum("td,de->te", x.astype(jnp.float32),
-                       moe["router"].astype(jnp.float32)),
-            axis=-1,
-        )  # (T, E + Z) f32
+        logits = jnp.einsum("td,de->te", x.astype(jnp.float32),
+                            moe["router"].astype(jnp.float32))
+        gates = (jax.nn.sigmoid(logits) if cfg.scoring_func == "sigmoid"
+                 else jax.nn.softmax(logits, axis=-1))  # (T, E + Z) f32
 
     with jax.named_scope("moe_dispatch"):
         choose = gates + moe["router_bias"] if "router_bias" in moe else gates
+        if cfg.n_group:
+            choose = group_limited(choose, cfg.n_group, cfg.topk_group)
         _, top_idx = jax.lax.top_k(choose, k)  # the bias chooses, and only chooses
         top_w = jnp.take_along_axis(gates, top_idx, axis=-1)
         if cfg.norm_topk_prob:
@@ -386,7 +413,29 @@ def _shared_moe_block(moe, h, cfg: ModelConfig, *, token_mask, mesh, layer):
             w_zero = (top_w * (top_idx >= e)).sum(axis=-1)
             out = out + x.astype(jnp.float32) * w_zero[:, None]
         out = out.astype(cd)
+    if "shared" in moe:
+        from ditl_tpu.ops.quant import weight_einsum
 
+        with jax.named_scope("moe_shared"):
+            sh = moe["shared"]
+            act = jax.nn.silu(
+                weight_einsum("td,df->tf", x, sh["w_gate"], compute_dtype=cd)
+            ) * weight_einsum("td,df->tf", x, sh["w_up"], compute_dtype=cd)
+            out = out + weight_einsum("tf,fd->td", act, sh["w_down"], compute_dtype=cd)
+
+    if cfg.scoring_func == "sigmoid":  # the term is defined on shares of 1
+        gates = gates / jnp.maximum(gates.sum(axis=-1, keepdims=True), 1e-9)
     aux = load_balancing_loss(gates, per_expert.astype(jnp.float32),
                               live.astype(jnp.float32))
     return out.reshape(b, s, d), aux, counts.astype(jnp.int32)
+
+
+def group_limited(choose: jax.Array, n_group: int, topk_group: int) -> jax.Array:
+    """``choose`` (T, E) with every expert outside the token's ``topk_group``
+    best groups at -inf. The experts lie in ``n_group`` equal consecutive
+    groups; a group's score is the sum of its two largest entries."""
+    t, e = choose.shape
+    best2 = jax.lax.top_k(choose.reshape(t, n_group, e // n_group), 2)[0].sum(axis=-1)
+    _, kept = jax.lax.top_k(best2, topk_group)  # (T, topk_group)
+    keep = (kept[..., None] == jnp.arange(n_group)).any(axis=1)  # (T, n_group)
+    return jnp.where(jnp.repeat(keep, e // n_group, axis=1), choose, -jnp.inf)

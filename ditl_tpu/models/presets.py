@@ -204,6 +204,55 @@ PRESETS: dict[str, ModelConfig] = {
         attention_multiplier=0.015625,
         logits_scaling=8.0,
     ),
+    # DeepSeek-V3.2 (deepseek-ai, 671 B parameters, model_type deepseek_v32):
+    # 61 layers, the first three with a dense FFN of 18,432 and the rest with
+    # 256 routed experts of 2,048 (8 a token, chosen by sigmoid scores inside
+    # the best 4 of 8 groups) beside one shared expert; latent attention in a
+    # single pre-norm block, 128 heads, YaRN x 40 over 4,096 positions; a
+    # lightning indexer (64 heads of 128) picks the 2,048 cached tokens a
+    # query attends to. No chip holds a layer: served as a share
+    # (``experts_held_*``, fewer layers; benchmarks/configs/
+    # deepseek-v3.2-cut1.json); models/dsa.py. The multi-token-prediction
+    # module (num_nextn_predict_layers 1) is not implemented.
+    "deepseek-v3.2": ModelConfig(
+        name="deepseek-v3.2",
+        vocab_size=129280,
+        hidden_size=7168,
+        intermediate_size=18432,
+        expert_ffn_hidden_size=2048,
+        num_layers=61,
+        num_heads=128,
+        num_kv_heads=128,
+        head_dim=192,  # a query's / key's: 128 without position + 64 rotary
+        max_seq_len=163840,
+        rope_theta=10000.0,
+        rms_norm_eps=1e-6,
+        q_lora_rank=1536,
+        kv_lora_rank=512,
+        qk_nope_head_dim=128,
+        qk_rope_head_dim=64,
+        v_head_dim=128,
+        index_n_heads=64,
+        index_head_dim=128,
+        index_topk=2048,
+        rope_yarn_factor=40.0,
+        rope_yarn_original_max_len=4096,
+        rope_yarn_beta_fast=32.0,
+        rope_yarn_beta_slow=1.0,
+        rope_yarn_mscale_all_dim=1.0,
+        num_experts=256,
+        num_experts_per_tok=8,
+        norm_topk_prob=True,
+        routed_scaling_factor=2.5,
+        router_bias=True,
+        scoring_func="sigmoid",
+        n_group=8,
+        topk_group=4,
+        n_shared_experts=1,
+        first_k_dense_replace=3,
+        experts_held_first=0,
+        experts_held_count=256,
+    ),
 }
 
 

@@ -51,6 +51,20 @@ projection) inside ``attn_out``. The seat of a slot's state after a prefill
 is ``kv_write``. ``ssd_step`` (``SSM_KERNELS``), inside ``ssm_scan``: the
 decode step's update of the live rows' states in place (``ops/ssd.py``).
 
+``DSA_SCOPES`` are the parts of DeepSeek-V3.2's sparse attention (``models/
+dsa.py``), each INSIDE a scope of ``SCOPES``: ``dsa_index`` is the lightning
+indexer, inside ``attn_qkv`` its projections (the index queries from the
+query latent, the token's index key with its LayerNorm and rotation, the
+heads' weights) and inside ``attn_core`` its scores of a query against every
+cached index key; ``dsa_select``, inside ``attn_core``: the top-k of those
+scores and the page-table arithmetic that turns positions into pool rows;
+``dsa_gather``, inside ``attn_core``: the read of the selected latent
+entries out of the pool (a decode step) or the prefill's row. The attention
+over what was gathered is ``mla_attn``, the projections around it ``mla_q``
+and ``mla_kv``, the writes of both entries ``kv_write``. ``moe_shared``
+(``MOE_SHARED_SCOPES``), inside ``mlp``: the shared expert's dense SwiGLU FFN
+(``models/moe.py``).
+
 ``attn_steps`` (``ATTN_SCOPES``), inside ``attn_core``: the work list of the
 paged decode kernels (``ops/paged_attention.py`` ``decode_steps``), built
 once a decode program in front of its scan; a reader that knows only
@@ -85,6 +99,14 @@ MLA_SCOPES = (
 )
 
 MOE_ZERO_SCOPES = ("moe_zero",)
+
+MOE_SHARED_SCOPES = ("moe_shared",)
+
+DSA_SCOPES = (
+    "dsa_index",
+    "dsa_select",
+    "dsa_gather",
+)
 
 SSM_SCOPES = (
     "ssm_in",

@@ -37,8 +37,12 @@ def is_quantized_leaf(w: Any) -> bool:
     return isinstance(w, dict) and set(w) == {"q", "scale"}
 
 
+@jax.jit
 def _quantize_matrix(w: jax.Array) -> dict[str, jax.Array]:
-    """Symmetric per-output-channel int8 over the input (contraction) dim."""
+    """Symmetric per-output-channel int8 over the input (contraction) dim.
+    One compiled program a shape: written op by op it holds three float32
+    copies of the leaf at once, which a stack of experts does not leave room
+    for beside the weights (PERF.md section 6, PR 44)."""
     wf = jnp.asarray(w, jnp.float32)
     amax = jnp.max(jnp.abs(wf), axis=-2, keepdims=True)  # (..., 1, d_out)
     scale = jnp.where(amax > 0, amax / 127.0, 1.0)

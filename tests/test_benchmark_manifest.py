@@ -92,12 +92,71 @@ def test_the_longcat_cell_is_listed_only_under_readers_that_are_never_absent(man
         "moe_experts_roofline_decode": "reads intermediate_size and num_hidden_layers",
     }.items():
         assert name not in listed, (name, why)
-    mine = {x["name"] for x in m["per_layer"] if x.get("workloads") == [cell]}
+    mine = {x["name"] for x in m["per_layer"]
+            if x.get("workloads", [None])[0] == cell and x["name"].startswith(("mla_", "moe_"))}
     assert mine == {"mla_attn_time_share_chat", "mla_attn_roofline_decode",
                     "mla_proj_time_share_chat", "moe_held_roofline_decode",
                     "moe_zero_assign_share_chat", "moe_held_assign_share_chat"}
+    # three of the six read DeepSeek-V3.2's cell too (PR 44): the scopes and the
+    # span keys are the same; the two rooflines' counts are LongCat's alone
+    shared = {x["name"] for x in m["per_layer"] if x["name"] in mine and len(x["workloads"]) > 1}
+    assert shared == {"mla_proj_time_share_chat", "mla_attn_time_share_chat",
+                      "moe_held_assign_share_chat"}
     # 24 of PR 33, tick_overlap_share_chat, PR 36's six, PR 39's
     # ttft_client_p95_ms; "at least", so the next reader does not redden it
     assert mine <= listed and len(listed) >= 32
     assert {x["name"] for x in manifest_mod.metrics_for(m, "end_to_end", cell)} == {
         "setup_s", "tpot_p50_ms"}
+
+
+def test_the_deepseek_cell_is_a_closed_loop_listed_under_what_it_can_report(manifest_mod):
+    """``deepseek-v3.2-cut1.docs-32k-dsa`` (PR 44): its own six readers, the
+    ``*_chat`` readers that are never absent for it, the server's and the
+    scheduler's readers that count from the server's own spans, no reader
+    whose counts are another configuration's, and none that counts from
+    ``due`` of an open loop (a closed loop's ``due`` is the instant a turn was
+    sent). Every reader it is NOT under is named here with the reason."""
+    m = manifest_mod.load()
+    cell = "deepseek-v3.2-cut1.docs-32k-dsa"
+    listed = {x["name"] for x in manifest_mod.metrics_for(m, "per_layer", cell)}
+    mine = {x["name"] for x in m["per_layer"] if x.get("workloads") == [cell]}
+    assert mine == {"dsa_time_share_chat", "dsa_select_time_share_chat",
+                    "dsa_index_roofline_decode", "dsa_attn_roofline_decode",
+                    "dsa_selected_share_chat", "moe_shared_time_share_chat"}
+    assert all(x["moves"] == "tpot_p50_ms" for x in m["per_layer"] if x["name"] in mine)
+    for name, why in {
+        "mla_attn_roofline_decode": "counts LongCat's 8 sublayers of whole contexts",
+        "moe_held_roofline_decode": "reads expert_ffn_hidden_size",
+        "moe_experts_roofline_decode": "reads OLMoE's keys",
+        "moe_zero_assign_share_chat": "no zero-compute experts",
+        "paged_attn_time_share_chat": "the K/V kernel, which this configuration never runs",
+        "attn_steps_walked_share_chat": "the sparse path walks no list of (row, page) steps",
+        "ttft_p50_ms": "counts from due", "ttft_client_p95_ms": "counts from due",
+        "generator_late_p95_ms": "a schedule to be late on",
+        "ssm_time_share_chat": "no state-space mixer",
+        "ssm_scan_time_share_chat": "no state-space mixer",
+        "ssm_state_roofline_decode": "no state-space mixer",
+    }.items():
+        assert name not in listed, (name, why)
+    serving = {x["name"] for x in m["per_layer"]
+               if any(".chat-" in w for w in x.get("workloads", []))}
+    assert serving - listed == {
+        "mla_attn_roofline_decode", "moe_held_roofline_decode", "moe_experts_roofline_decode",
+        "moe_zero_assign_share_chat", "paged_attn_time_share_chat",
+        "attn_steps_walked_share_chat", "ttft_p50_ms", "ttft_client_p95_ms",
+        "generator_late_p95_ms", "ssm_time_share_chat", "ssm_scan_time_share_chat",
+        "ssm_state_roofline_decode"}
+    assert mine <= listed and len(listed) == len(mine) + 28
+    assert {x["name"] for x in manifest_mod.metrics_for(m, "end_to_end", cell)} == {
+        "setup_s", "tpot_p50_ms"}
+    with open(manifest_mod.traffic_path("docs-32k-dsa")) as f:
+        traffic = json.load(f)
+    assert traffic["generator"] == "doc_sessions" and "rate_per_s" not in traffic
+    assert (traffic["documents"], traffic["doc_tokens"], traffic["sessions"]) == (12, 32768, 32)
+    args = traffic["server_args"]
+    assert args[args.index("--slots") + 1] == "32"
+    pages = int(args[args.index("--pages") + 1])
+    assert pages >= 12 * 128 + 32 * 4  # every document and every row's own pages
+    assert int(args[args.index("--max-cache-len") + 1]) >= 32768 + 256 + 512
+    assert os.path.exists(os.path.join(ROOT, args[args.index("--tokenizer") + 1],
+                                       "tokenizer.json"))

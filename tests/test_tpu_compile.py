@@ -257,7 +257,8 @@ def test_latent_flush_copies_no_pool(one_chip, tpu_branch, tail):
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
     row = s((b,), jnp.int32)
     compiled = jax.jit(_flush_latent_tail, donate_argnums=(0,)).lower(
-        {"cp": s((8, pages, ps, dl), jnp.bfloat16)}, s((4, 2, b, tail, dl), jnp.bfloat16),
+        {"cp": s((8, pages, ps, dl), jnp.bfloat16)},
+        {"tc": s((4, 2, b, tail, dl), jnp.bfloat16)},
         row, row, s((b, maxp), jnp.int32)).compile()
     text = compiled.as_text()
     assert names.CACHE_KERNELS[0] in _instructions(text)
@@ -383,4 +384,125 @@ def test_granite_prefill_buckets_compile_under_the_tenth_spare_line(one_chip, tp
         s((bucket // 256,), jnp.int32), s((1,), jnp.int32), scalar_i).compile()
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= 36 * 64 * 64 * 64 * 128 * 4  # the state in place
+    assert _total_bytes(compiled) < _TENTH_SPARE
+
+
+@pytest.mark.parametrize("bucket, ctx, temp_gib", [(2048, 0, 0.6), (256, 8, 0.6)],
+                         ids=["the-cells-longest-prompt", "over-eight-cached-pages"])
+def test_longcat_prefill_reads_its_context_page_by_page(one_chip, tpu_branch, bucket, ctx,
+                                                        temp_gib):
+    """``jit_paged_prefill`` of ``longcat-flash-cut1.chat-wide-mla`` (1,280
+    pages of 256 in a latent pool of 8 sublayers). Its cell's prompts share no
+    prefix and are not chunked, so they reach the programs without context
+    pages only, whose buffers PR 44's page-by-page ``latent_gather`` left as
+    they were (0.489 GiB of temporaries at 2,048 tokens, before and after).
+    Over cached pages the ONE gather it replaced made the compiler copy the
+    pool whole first: 2.507 GiB of temporaries at 8 context pages, 15.27 GiB
+    in all, over the line; the loop needs 0.312 (PERF.md section 6, PR 44)."""
+    from ditl_tpu.data.tokenizer import ByteTokenizer
+    from ditl_tpu.models import llama
+
+    cfg = get_preset("longcat-flash", num_layers=4, vocab_size=16384, experts_held_first=0,
+                     experts_held_count=16, param_dtype="bfloat16")
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    params = jax.tree.map(
+        lambda a: s(a.shape, a.dtype),
+        jax.eval_shape(lambda: llama.init_params(jax.random.key(0), cfg)))
+    eng = ContinuousEngine({}, cfg, ByteTokenizer(), n_slots=1, cache_mode="paged",
+                           page_size=256, max_cache_len=4096, n_pages=2)
+    cache = {k: s((v.shape[0], 1280, *v.shape[2:]), v.dtype) for k, v in eng.cache.items()}
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    scalar_i, scalar_f = s((), jnp.int32), s((), jnp.float32)
+    compiled = eng._build_paged_prefill(bucket, ctx).lower(
+        params, cache, s((max(ctx, 1),), jnp.int32), s((1, bucket), jnp.int32), scalar_i,
+        scalar_i, scalar_f, scalar_f,
+        jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=one_chip),
+        s((bucket // 256,), jnp.int32), s((1,), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 8 * 1280 * 256 * 640 * 2  # the pool in place
+    assert mem.temp_size_in_bytes < temp_gib * _GIB
+    assert _total_bytes(compiled) < _TENTH_SPARE
+
+
+# deepseek-v3.2-cut1.docs-32k-dsa (ISSUE 44): one chip's share of 16, 32 slots,
+# 2,048 pages of 256 tokens in a latent pool and an index-key pool, rows of up
+# to 132 pages.
+def _deepseek_cell(one_chip):
+    """(engine whose programs are the cell's, abstract params, abstract
+    cache) with nothing of the model's size allocated."""
+    from ditl_tpu.data.tokenizer import ByteTokenizer
+    from ditl_tpu.models import llama
+
+    cfg = get_preset("deepseek-v3.2", num_layers=5, first_k_dense_replace=1, vocab_size=16160,
+                     experts_held_first=0, experts_held_count=16, param_dtype="bfloat16")
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    params = jax.tree.map(
+        lambda a: s(a.shape, a.dtype),
+        jax.eval_shape(lambda: llama.init_params(jax.random.key(0), cfg)))
+    eng = ContinuousEngine({}, cfg, ByteTokenizer(), n_slots=1, cache_mode="paged",
+                           page_size=256, max_cache_len=33792, n_pages=2)
+    pages = 2048
+    cache = {"cp": s((5, pages, 256, 640), jnp.bfloat16),
+             "ip": s((5, pages, 256, 128), jnp.bfloat16)}
+    assert {k: v.shape[2:] for k, v in eng.cache.items()} == {
+        "cp": (256, 640), "ip": (256, 128)}  # the engine's own pools are so laid out
+    return eng, params, cache, s
+
+
+def _whole_pool_copies(text: str) -> set[str]:
+    """Instructions that produce an array of either whole pool's shape."""
+    producers = set()
+    for width in (640, 128):
+        shape = re.escape(f"bf16[5,2048,256,{width}]")
+        producers |= set(re.findall(r" = " + shape + r"\S* ([\w\-]+)\(", text))
+    return producers - {"bitcast", "parameter", "get-tuple-element", "custom-call", "while",
+                        "dynamic-update-slice"}
+
+
+def test_deepseek_decode_program_compiles_in_place_under_the_tenth_spare_line(
+        one_chip, tpu_branch):
+    """``jit_paged_decode`` of the cell: index scores over 132 pages a row, the
+    top-2,048, the gather of the selected latent entries, the held experts'
+    ``gmm`` inside the stack, both pools flushed in place by ``kv_flush`` and
+    aliased to the outputs; the whole under the tenth-spare line."""
+    eng, params, cache, s = _deepseek_cell(one_chip)
+    slots = 32
+    row_i, row_f = s((slots,), jnp.int32), s((slots,), jnp.float32)
+    keys = jax.eval_shape(lambda: jax.vmap(jax.random.key)(jnp.arange(slots, dtype=jnp.uint32)))
+    keys = jax.ShapeDtypeStruct(keys.shape, keys.dtype, sharding=one_chip)
+    compiled = eng._build_paged_decode(False, False).lower(
+        params, cache, row_i, row_i, s((slots,), jnp.bool_), row_f, row_f, keys,
+        s((slots, 132), jnp.int32), row_i, s((slots, 1), jnp.int32), row_i).compile()
+    text = compiled.as_text()
+    calls = _instructions(text)
+    assert names.CACHE_KERNELS[0] in calls and "gmm" in calls
+    assert not _whole_pool_copies(text)
+    mem = compiled.memory_analysis()
+    pool_bytes = 5 * 2048 * 256 * (640 + 128) * 2
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < 1.5 * _GIB
+    assert _total_bytes(compiled) < _TENTH_SPARE
+
+
+@pytest.mark.parametrize("bucket, ctx", [(1024, 0), (1024, 64), (1024, 128), (256, 128)],
+                         ids=["document-first-chunk", "document-mid", "document-last-chunks",
+                              "question-over-a-cached-document"])
+def test_deepseek_prefill_buckets_compile_under_the_tenth_spare_line(
+        one_chip, tpu_branch, bucket, ctx):
+    """The prefill programs the cell reaches: a document's 1,024-token chunks
+    over 0 to 128 context pages of BOTH pools and a turn's 256-token bucket
+    over a whole cached document. The context is read page by page: one
+    gather of all pages made the compiler copy both pools whole in lane
+    slices first (2.6 GiB of temporaries, whatever the context)."""
+    eng, params, cache, s = _deepseek_cell(one_chip)
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    scalar_i, scalar_f = s((), jnp.int32), s((), jnp.float32)
+    compiled = eng._build_paged_prefill(bucket, ctx).lower(
+        params, cache, s((max(ctx, 1),), jnp.int32), s((1, bucket), jnp.int32), scalar_i,
+        scalar_i, scalar_f, scalar_f,
+        jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=one_chip),
+        s((bucket // 256,), jnp.int32), s((1,), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 5 * 2048 * 256 * (640 + 128) * 2  # both pools in place
+    assert mem.temp_size_in_bytes < 1.5 * _GIB
     assert _total_bytes(compiled) < _TENTH_SPARE
