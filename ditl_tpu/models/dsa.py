@@ -36,11 +36,16 @@ entries it selected and not a decompressed copy of the context:
 - a forward without cache and a prefill chunk (``_select_attend``): index
   scores of the whole chunk against the row (its context pages' entries, then
   the chunk's), ``top_indices`` a query, then the selected entries gathered
-  and attended in blocks of ``Q_BLOCK`` queries;
+  and attended, each in blocks of ``Q_BLOCK`` queries;
 - a paged decode step (``_decode_attend``): index scores over the row's pages
   of the index pool and the tick's tail, ``top_indices`` a row, the selected
   latent entries gathered out of the pool through the page table, the tail's
   entries beside them under the selection's mask.
+
+``top_indices`` sorts nothing (a sort of every row's 33,800 scores was the
+cell's largest device operation): a threshold search finds the k-th largest
+score, compares, sums and two small 0/1 matmuls turn the selected mask into
+the list of positions, in position order. Every reader takes it as a set.
 
 Where the buffer is no longer than ``index_topk`` (a static fact) everything
 is selected: the indexer is skipped and attention is dense over the valid
@@ -71,7 +76,7 @@ from ditl_tpu.config import ModelConfig
 from ditl_tpu.models.mla import latent_width, rope_interleaved
 
 __all__ = ["init_dsa_params", "dsa_logical_axes", "stack", "yarn_inv_freq",
-           "softmax_scale", "top_indices", "Q_BLOCK", "TAP"]
+           "softmax_scale", "top_indices", "Q_BLOCK", "LANES", "TAP"]
 
 Q_BLOCK = 32  # queries a block of the index scores and of the selected attention
 
@@ -253,17 +258,100 @@ def index_scores(qi: jax.Array, w: jax.Array, keys: jax.Array) -> jax.Array:
     return jnp.einsum("bqhn,bqh->bqn", jax.nn.relu(s), w)
 
 
+LANES = 128  # scores a chunk of the selection's two-level count
+
+
+def _order_key(x: jax.Array) -> jax.Array:
+    """float32 -> int32 whose signed order is the floats' total order (-inf
+    < negatives < -0.0 < 0.0 < positives), as ``jax.lax.top_k`` compares."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+    return bits ^ ((bits >> 31) & jnp.int32(0x7FFFFFFF))
+
+
+def _kth_key(keys: jax.Array, k: int) -> jax.Array:
+    """The ``k``-th largest of each row of ``keys`` (R, N) int32: its bits
+    from the top down, a bit is set where at least ``k`` keys reach the
+    prefix with it. 32 passes of a compare and a count, no sort."""
+    low = jnp.int32(-2 ** 31)  # the sign flip between signed and unsigned order
+
+    def bit(i, prefix):  # prefix: the unsigned pattern found so far
+        cand = prefix | (jnp.int32(1) << (31 - i))
+        reach = (keys >= (cand ^ low)[:, None]).sum(axis=1, dtype=jnp.int32)
+        return jnp.where(reach >= k, cand, prefix)
+
+    return jax.lax.fori_loop(0, 32, bit, jnp.zeros(keys.shape[:1], jnp.int32)) ^ low
+
+
+def _compact(keys: jax.Array, thr: jax.Array, k: int) -> tuple[jax.Array, jax.Array]:
+    """The positions of the ``k`` selected keys of each row, ascending, made
+    of compares, sums and two small matmuls on 0/1 values. keys: (R, nc,
+    LANES) int32, thr: (R,) their ``k``-th largest. Selected: every key above
+    ``thr`` and the first ``need = k - #above`` keys equal to it. Every count
+    is a whole number under 2 ** 24 in float32, or at most 256 in bfloat16."""
+    nc = keys.shape[1]
+    lanes = jnp.arange(LANES, dtype=jnp.int32)
+    above, equal = keys > thr[:, None, None], keys == thr[:, None, None]
+    # inclusive counts inside a chunk: one upper-triangular 0/1 matmul
+    upto = (lanes[:, None] <= lanes[None, :]).astype(jnp.bfloat16)
+    inc_a, inc_e = jnp.einsum("xrcm,ml->xrcl", jnp.stack([above, equal]).astype(jnp.bfloat16),
+                              upto, preferred_element_type=jnp.float32)
+    # and the chunks before it: a cumulative sum over nc chunks, not N entries
+    tot_a, tot_e = inc_a[..., -1], inc_e[..., -1]
+    off_a, off_e = jnp.cumsum(tot_a, axis=1) - tot_a, jnp.cumsum(tot_e, axis=1) - tot_e
+    need = (k - tot_a.sum(axis=1))[:, None]  # (R, 1)
+    upto_e = off_e[..., None] + inc_e  # equal keys up to and with this one
+    sel = above | (equal & (upto_e <= need[..., None]))
+    rank = off_a[..., None] + inc_a + jnp.minimum(upto_e, need[..., None])  # 1-based, of a selected
+    off = off_a + jnp.minimum(off_e, need)  # (R, nc): selected before the chunk
+    # a chunk's selected lanes carry their rank mod 256 (a chunk holds at most
+    # 128 of them, so each value once), negative where the entry is invalid
+    pay = (rank - 1) % 256 + 1
+    pay = jnp.where(sel, jnp.where(keys > _order_key(jnp.float32(-jnp.inf)), pay, -pay), 0)
+    # slot j's chunk: the last whose offset is at most j (an empty chunk
+    # shares its offset with the next one)
+    j = jnp.arange(k, dtype=jnp.float32)
+    chunk = (off[:, None, :] <= j[None, :, None]).sum(axis=-1, dtype=jnp.int32) - 1  # (R, k)
+    mine = chunk[..., None] == jnp.arange(nc, dtype=jnp.int32)  # (R, k, nc), one-hot
+    got = jnp.einsum("rkc,rcl->rkl", mine.astype(jnp.bfloat16), pay.astype(jnp.bfloat16),
+                     preferred_element_type=jnp.float32)  # (R, k, LANES): that chunk's row
+    hit = jnp.abs(got) == (j % 256 + 1)[None, :, None]
+    lane = (hit * lanes).sum(axis=-1)
+    return chunk * LANES + lane, (hit & (got > 0)).any(axis=-1)
+
+
 def top_indices(scores: jax.Array, k: int) -> tuple[jax.Array, jax.Array]:
-    """The ``k`` largest of each row of ``scores`` (..., N), invalid entries
-    at -inf: ``(indices (..., k) int32, ok (..., k))``, ``ok`` false where
-    the row had fewer than ``k`` valid entries and an invalid one filled the
-    place. Exact, ties to the lower index. Measured on a v5e at 32 rows of
-    33,800 (PERF.md section 6, PR 44): ``jax.lax.top_k`` 1.48 ms; a bit-wise
-    threshold search 0.26 ms for the MASK, but turning a mask into the list a
-    gather needs cost more than it saved (sort 1.3, scatter 5.4, search 11.0
-    ms), so the list comes from ``top_k``."""
-    vals, idx = jax.lax.top_k(scores, k)
-    return idx.astype(jnp.int32), vals > -jnp.inf
+    """The ``k`` largest of each row of ``scores`` (..., N) float32, invalid
+    entries at -inf: ``(indices (..., k) int32, ok (..., k))``, ``ok`` false
+    where the row had fewer than ``k`` valid entries and an invalid one (the
+    lowest positions first) filled the place. Exactly ``jax.lax.top_k``'s SET
+    (ties to the lower index), in ascending POSITION, not by score: attention
+    over the entries, the counters and ``TAP``'s readers read sets.
+
+    No sort: ``top_k`` of 2,048 sorts the whole row (device time on a v5e at
+    32 rows of 33,800: 1,216 us, the largest operation of the cell; this: 157
+    us, PERF.md section 6, PR 45). A bit-wise threshold search on the floats'
+    ordered integer keys finds the k-th largest (``_kth_key``), which gives
+    the set as a MASK; the list a gather needs is a compaction of that mask.
+    Three compactions lost to the sort they replaced (PR 44: a sort of the
+    masked iota, a cumulative sum over the row and a scatter, ``searchsorted``
+    into that sum: 1.2 x, 4 x and 8 x ``top_k``'s time): each sorts the row
+    again or moves scalars one at a time. ``_compact`` counts in two levels
+    over chunks of ``LANES`` and fetches each output slot's chunk with a
+    one-hot matmul. Search and compaction run a block of ``Q_BLOCK`` rows at
+    a time: a block's keys (4 MB) stay in fast memory through the search's 32
+    passes and its (rows, k, chunks) one-hot stays tens of megabytes."""
+    *lead, n = scores.shape
+    nc = -(-n // LANES)
+    rows = scores.reshape(1, -1, n)
+    if nc * LANES > n:  # -inf behind the row loses every tie by sitting last
+        rows = jnp.pad(rows, ((0, 0), (0, 0), (0, nc * LANES - n)), constant_values=-jnp.inf)
+
+    def block(keys):  # (1, rows of a block, nc * LANES)
+        idx, ok = _compact(keys[0].reshape(-1, nc, LANES), _kth_key(keys[0], k), k)
+        return idx[None], ok[None]
+
+    idx, ok = _blocks(block, (_order_key(rows),), rows.shape[1])
+    return idx.reshape(*lead, k), ok.reshape(*lead, k)
 
 
 def _attend(q_full, entries, ok, *, scale: float, r: int, per_query: bool):
@@ -286,8 +374,9 @@ def _blocks(fn, xs: tuple, s: int):
     """``fn`` over blocks of ``Q_BLOCK`` queries (axis 1 of every array of
     ``xs``), one after the other; a ragged last block is padded with zeros
     (queries that see nothing) and cut off again. Each block is written into
-    ONE output buffer the loop carries, so block i + 1 follows block i and
-    only one block's gathered entries are live at a time."""
+    ONE output buffer (one for each array ``fn`` returns) the loop carries,
+    so block i + 1 follows block i and only one block's temporaries are live
+    at a time."""
     n = Q_BLOCK
     if s <= n:
         return fn(*xs)
@@ -300,11 +389,12 @@ def _blocks(fn, xs: tuple, s: int):
         return fn(*(jax.lax.dynamic_slice_in_dim(x, i * n, n, axis=1) for x in xs))
 
     first = jax.eval_shape(block, 0)
-    out = jnp.zeros((first.shape[0], nb * n, *first.shape[2:]), first.dtype)
+    out = jax.tree.map(lambda f: jnp.zeros((f.shape[0], nb * n, *f.shape[2:]), f.dtype), first)
     out = jax.lax.fori_loop(
-        0, nb, lambda i, o: jax.lax.dynamic_update_slice_in_dim(o, block(i), i * n, axis=1),
-        out)
-    return out[:, :s]
+        0, nb, lambda i, o: jax.tree.map(
+            lambda whole, part: jax.lax.dynamic_update_slice_in_dim(whole, part, i * n, axis=1),
+            o, block(i)), out)
+    return jax.tree.map(lambda o: o[:, :s], out)
 
 
 def _select_attend(q_full, qi, w, entries, keys, valid, *, cfg: ModelConfig):
@@ -379,7 +469,10 @@ def _decode_attend(q_full, qi, w, *, cfg: ModelConfig, pools, tails, paged):
         valid = jnp.concatenate([in_pages, in_tail], axis=1)
         idx, ok = top_indices(jnp.where(valid, scores, -jnp.inf), k)  # (B, k)
         at = jnp.minimum(idx, n - 1)
-        flat = jnp.take_along_axis(table, at // ps, axis=1) * ps + at % ps
+        # each selected position's page by a compare and a sum over the
+        # row's table: a gather of k scalars a row takes 0.67 ms on a v5e
+        mine = (at // ps)[:, :, None] == jnp.arange(maxp, dtype=jnp.int32)
+        flat = (mine * table[:, None, :]).sum(axis=-1) * ps + at % ps
         ok_pages = ok & (idx < n)
         ok_tail = ((idx[:, :, None] == n + jnp.arange(t, dtype=jnp.int32))
                    & ok[:, :, None]).any(axis=1)  # (B, T)

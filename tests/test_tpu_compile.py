@@ -459,6 +459,18 @@ def _whole_pool_copies(text: str) -> set[str]:
                         "dynamic-update-slice"}
 
 
+def _assert_selection_sorts_nothing(text: str):
+    """No compiled instruction traced under the scope ``dsa_select`` is a
+    sort or a top-k, by opcode or by custom-call target (ISSUE 45)."""
+    ops = set()
+    for line in text.splitlines():
+        if re.search(r'op_name="[^"]*/dsa_select/', line):
+            found = re.findall(r' = \S+ ([\w\-]+)\(|custom_call_target="(\w+)"', line)
+            ops.update(name for pair in found for name in pair if name)
+    assert ops, "no instruction carries the scope dsa_select"
+    assert not {o for o in ops if re.search(r"sort|top_?k", o, re.IGNORECASE)}, ops
+
+
 def test_deepseek_decode_program_compiles_in_place_under_the_tenth_spare_line(
         one_chip, tpu_branch):
     """``jit_paged_decode`` of the cell: index scores over 132 pages a row, the
@@ -477,6 +489,7 @@ def test_deepseek_decode_program_compiles_in_place_under_the_tenth_spare_line(
     calls = _instructions(text)
     assert names.CACHE_KERNELS[0] in calls and "gmm" in calls
     assert not _whole_pool_copies(text)
+    _assert_selection_sorts_nothing(text)
     mem = compiled.memory_analysis()
     pool_bytes = 5 * 2048 * 256 * (640 + 128) * 2
     assert mem.alias_size_in_bytes >= pool_bytes
@@ -502,6 +515,8 @@ def test_deepseek_prefill_buckets_compile_under_the_tenth_spare_line(
         scalar_i, scalar_f, scalar_f,
         jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=one_chip),
         s((bucket // 256,), jnp.int32), s((1,), jnp.int32)).compile()
+    if 256 * ctx + bucket > eng.cfg.index_topk:  # else everything is selected: no indexer
+        _assert_selection_sorts_nothing(compiled.as_text())
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= 5 * 2048 * 256 * (640 + 128) * 2  # both pools in place
     assert mem.temp_size_in_bytes < 1.5 * _GIB
