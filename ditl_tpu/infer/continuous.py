@@ -1965,6 +1965,15 @@ class ContinuousEngine:
             return decode_steps(starts, alive, page_size=self.page_size,
                                 max_pages=self.maxp)
 
+    def _index_steps(self, starts: jax.Array, alive: jax.Array) -> dict:
+        """The index scores' work list (``ops/dsa_index.py`` ``index_steps``:
+        ``_attn_steps``' with a group of pages for a page), built beside it."""
+        from ditl_tpu.ops.dsa_index import index_steps
+
+        with jax.named_scope("attn_core"), jax.named_scope("attn_steps"):
+            return index_steps(starts, alive, page_size=self.page_size,
+                               max_pages=self.maxp)
+
     def _build_paged_decode(self, sampled: bool, topp: bool):
         """Paged decode tick with DEFERRED page writes: the chunk's K/V
         accumulate in small per-layer tail buffers carried through the scan
@@ -1999,7 +2008,11 @@ class ContinuousEngine:
             # on freed slots having zeroed rows.
             starts = pos
             done0 = ~alive | (cur == pad)
-            steps = self._attn_steps(starts, ~done0 & (pos < limits))
+            listed = ~done0 & (pos < limits)
+            steps = self._attn_steps(starts, listed)
+            # the indexer scores a row's pages only where it has to choose
+            walks_index = self.indexed and self.maxp * self.page_size + tail_len > cfg.index_topk
+            index_steps = self._index_steps(starts, listed) if walks_index else None
             if self.latent:
                 from ditl_tpu.models.mla import latent_width
 
@@ -2032,6 +2045,8 @@ class ContinuousEngine:
                     "table": table, "lengths": lengths, "starts": starts,
                     "t": t, "steps": steps,
                 }
+                if walks_index:
+                    paged_meta["index_steps"] = index_steps
                 logits, tails, *moe_counts = llama.forward(
                     params,
                     cur[:, None],
@@ -2057,6 +2072,11 @@ class ContinuousEngine:
                     # had, and (models/dsa.py) the entries they selected,
                     # summed over the layers
                     read = (lengths.sum(), *(m.sum() for m in moe_counts[1:]))
+                    if self.indexed:
+                        # and the pages its index walk fetched: a live row's
+                        # flushed pages, in every layer
+                        live_pages = -(-jnp.minimum(starts, lengths) // self.page_size)
+                        read += (live_pages.sum() * (L if walks_index else 0),)
                     moe_acc = (counts + moe_counts[0],
                                touched + (held > 0).sum(),
                                *(c + n for c, n in zip(ctx, read)))
@@ -2089,7 +2109,7 @@ class ContinuousEngine:
             fst0 = fstates if guided else jnp.zeros((), jnp.int32)
             moe0 = ((jnp.zeros((self.moe_layers, count_width(cfg)), jnp.int32),
                      jnp.zeros((), jnp.int32),
-                     *((jnp.zeros((), jnp.int32),) * (self.latent + self.indexed)))
+                     *((jnp.zeros((), jnp.int32),) * (self.latent + 2 * self.indexed)))
                     if moe else ())
             if recurrent:
                 moe0 = (jnp.zeros((), jnp.int32),)
@@ -4504,7 +4524,7 @@ class ContinuousEngine:
         moe_dev = ()
         if self.moe:  # paged: the tick's (L, E) counts and touched sum
             # and the context tokens read, and the entries selected of them
-            n_moe = 2 + self.latent + self.indexed
+            n_moe = 2 + self.latent + 2 * self.indexed
             res, moe_dev = res[:-n_moe], tuple(res[-n_moe:])
         elif self.recurrent and self.cache_mode == "paged":
             res, moe_dev = res[:-1], tuple(res[-1:])  # the tick's row steps
@@ -4580,7 +4600,7 @@ class ContinuousEngine:
         if len(ctx) > 1:  # an indexer chose among them, in every layer
             self.dsa_selected_tokens += int(ctx[1])
             extra.update(dsa_ctx_tokens=int(ctx[0]) * self.cfg.num_layers,
-                         dsa_selected_tokens=int(ctx[1]))
+                         dsa_selected_tokens=int(ctx[1]), dsa_index_pages=int(ctx[2]))
         held, zero, absent = split_counts(counts, self.cfg)
         if held.shape != counts.shape:  # a share of a wider expert layer
             extra.update(moe_assign_held=int(held.sum()),

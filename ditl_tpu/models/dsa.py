@@ -40,7 +40,13 @@ entries it selected and not a decompressed copy of the context:
 - a paged decode step (``_decode_attend``): index scores over the row's pages
   of the index pool and the tick's tail, ``top_indices`` a row, the selected
   latent entries gathered out of the pool through the page table, the tail's
-  entries beside them under the selection's mask.
+  entries beside them under the selection's mask. On the TPU the scores over
+  the pages are ``ops/dsa_index.py``'s kernel ``dsa_index_scores``: a walk
+  over each live row's pages where they lie, several pages a step, which
+  writes only the positions the row still attends to (``_page_scores``;
+  whatever else the array holds is selected away in front of
+  ``top_indices``); off the TPU, and as the tests' oracle,
+  ``paged_index_scores`` gathers the pages (three passes over the keys).
 
 ``top_indices`` sorts nothing (a sort of every row's 33,800 scores was the
 cell's largest device operation): a threshold search finds the k-th largest
@@ -53,7 +59,8 @@ entries (a decode step: ``ops/mla_attention.py``'s kernel). No path attends
 to more than ``index_topk`` entries of a longer buffer.
 
 Scopes (``ops/names.py`` ``DSA_SCOPES``): ``dsa_index`` (inside ``attn_qkv``
-the indexer's projections, inside ``attn_core`` its scores), ``dsa_select``
+the indexer's projections, inside ``attn_core`` its scores: in decode the
+kernel ``dsa_index_scores``, ``DSA_KERNELS``), ``dsa_select``
 and ``dsa_gather`` inside ``attn_core``; ``mla_q`` / ``mla_kv`` / ``mla_attn``
 as the double layer has them.
 
@@ -436,6 +443,23 @@ def paged_index_scores(qi, w, ipool, table):
     return index_scores(qi[:, None], w[:, None], keys)[:, 0]
 
 
+def _page_scores(qi, w, ipool, paged):
+    """``paged_index_scores`` of a decode step: on the TPU the kernel that
+    scores each live row's pages where they lie (``ops/dsa_index.py``), which
+    writes nothing at positions ``>= min(starts, lengths)``: the caller
+    selects those away. Off the TPU the gather: the interpreted kernel
+    carries the whole pool through its grid loop, as ``mla_paged_attention``
+    does."""
+    from ditl_tpu.ops.backend import interpret_default
+
+    if interpret_default():
+        return paged_index_scores(qi, w, ipool, paged["table"])
+    from ditl_tpu.ops.dsa_index import dsa_index_scores
+
+    return dsa_index_scores(qi, w, ipool, paged["table"], paged["lengths"], paged["starts"],
+                            steps=paged.get("index_steps"))
+
+
 def _decode_attend(q_full, qi, w, *, cfg: ModelConfig, pools, tails, paged):
     """One decode step, every row over its pages and the tick's tail.
     q_full: (B, H, Dl), qi: (B, Hi, Di), w: (B, Hi); pools: the flat latent
@@ -463,7 +487,7 @@ def _decode_attend(q_full, qi, w, *, cfg: ModelConfig, pools, tails, paged):
     in_tail = (starts[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]) < lengths[:, None]
     with jax.named_scope("dsa_index"):
         scores = jnp.concatenate(
-            [paged_index_scores(qi, w, ip, table),
+            [_page_scores(qi, w, ip, paged),
              index_scores(qi[:, None], w[:, None], ti)[:, 0]], axis=1)  # (B, n + T)
     with jax.named_scope("dsa_select"):
         valid = jnp.concatenate([in_pages, in_tail], axis=1)

@@ -56,8 +56,13 @@ dsa.py``), each INSIDE a scope of ``SCOPES``: ``dsa_index`` is the lightning
 indexer, inside ``attn_qkv`` its projections (the index queries from the
 query latent, the token's index key with its LayerNorm and rotation, the
 heads' weights) and inside ``attn_core`` its scores of a query against every
-cached index key; ``dsa_select``, inside ``attn_core``: the top-k of those
-scores and the page-table arithmetic that turns positions into pool rows;
+cached index key: in a paged decode step on the TPU the kernel
+``dsa_index_scores`` (``DSA_KERNELS``, ``ops/dsa_index.py``: each live row's
+pages of the index pool scored where they lie) and, around it, the small
+einsum over the tick's tail and the concatenation of the two; in a prefill,
+and off the TPU, a gather of the keys and two einsums; ``dsa_select``, inside
+``attn_core``: the top-k of those scores and the page-table arithmetic that
+turns positions into pool rows;
 ``dsa_gather``, inside ``attn_core``: the read of the selected latent
 entries out of the pool (a decode step) or the prefill's row. The attention
 over what was gathered is ``mla_attn``, the projections around it ``mla_q``
@@ -122,6 +127,10 @@ SSM_KERNELS = ("ssd_step",)
 # Decode attention over a latent page pool (``ops/mla_attention.py``), inside
 # ``mla_attn``.
 MLA_KERNELS = ("mla_paged_attention",)
+
+# A decode step's index scores over the row's pages of the index pool
+# (``ops/dsa_index.py``), inside ``attn_core/dsa_index``.
+DSA_KERNELS = ("dsa_index_scores",)
 
 # The grouped matmul of ``moe_experts`` on one TPU chip is the library's
 # kernel (``jax.experimental.pallas.ops.tpu.megablox``): these are ITS names,

@@ -201,6 +201,35 @@ def test_the_engine_counts_the_context_it_scored_and_the_entries_it_selected():
     assert eng.moe_assignments.shape == (2, 8 + 2)
 
 
+def test_a_traced_engines_tick_span_counts_the_index_pages_walked(tmp_path):
+    """``dsa_index_pages`` of an ``engine.tick`` span: the flushed pages of
+    the rows live in each step of the tick's program, in every layer, which
+    is what the index walk fetches (``ops/dsa_index.py``; here, off the TPU,
+    the gather stands in and the count is the program's own arithmetic)."""
+    from ditl_tpu.telemetry.journal import EventJournal, merge_journals
+    from ditl_tpu.telemetry.tracing import Tracer
+
+    cfg = tiny()
+    journal = EventJournal(str(tmp_path / "events-engine.jsonl"), source="engine")
+    eng = engine(cfg, n_slots=2, max_cache_len=96, tracer=Tracer(journal))
+    tok = ByteTokenizer()
+    prompts = [[tok.bos_id] + list(range(7, 7 + n)) for n in (20, 36)]  # 21 and 37 tokens
+    ids = [eng.submit(p, max_new_tokens=12, temperature=0.0) for p in prompts]
+    out = eng.run()
+    journal.close()
+    ticks = [r for r in merge_journals(str(tmp_path)) if "dsa_index_pages" in r]
+    chunk, ps, layers = eng.decode_chunk, eng.page_size, cfg.num_layers
+    # both rows decode through the first program: 2 and 3 pages, every step
+    assert ticks[0]["dsa_index_pages"] == (2 + 3) * chunk * layers
+    # a row's step j runs in its program j // chunk, whose starts are the
+    # tokens it held when that program began
+    want = sum(-(-(len(p) + j // chunk * chunk) // ps)
+               for p, i in zip(prompts, ids) for j in range(len(out[i]))) * layers
+    assert sum(t["dsa_index_pages"] for t in ticks) == want
+    assert all(t["dsa_index_pages"] * ps >= t["dsa_ctx_tokens"] - chunk * 2 * layers * chunk
+               for t in ticks)
+
+
 @pytest.mark.parametrize("mode, kw", [
     ("contiguous cache", dict(cache_mode="contiguous")),
     ("speculative ticks", dict(cache_mode="paged", speculative=True)),

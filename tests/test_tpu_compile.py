@@ -471,10 +471,34 @@ def _assert_selection_sorts_nothing(text: str):
     assert not {o for o in ops if re.search(r"sort|top_?k", o, re.IGNORECASE)}, ops
 
 
+def test_index_scores_kernel_compiles_at_the_deepseek_cells_shapes(one_chip):
+    """``dsa_index_scores`` as ``deepseek-v3.2-cut1.docs-32k-dsa`` runs it: 32
+    slots, 64 index heads against one 128-wide key a token, pages of 256 in a
+    pool of 5 layers x 2,048 pages addressed as one, 132 pages a slot walked
+    12 a step, the pool left in HBM. The instruction keeps the kernel's name."""
+    from ditl_tpu.ops.dsa_index import dsa_index_scores, index_steps, pages_a_step
+
+    b, hi, di, ps, pages, maxp = 32, 64, 128, 256, 5 * 2048, 132
+    assert pages_a_step(maxp, ps) == 12
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    args = (s((b, hi, di), jnp.bfloat16), s((b, hi), jnp.float32),
+            s((pages, ps, di), jnp.bfloat16), s((b, maxp), jnp.int32), s((b,), jnp.int32),
+            s((b,), jnp.int32), s((b,), jnp.bool_))
+    compiled = jax.jit(
+        lambda q, w, pool, tab, lens, st, alive: dsa_index_scores(
+            q, w, pool, tab, lens, st,
+            steps=index_steps(st, alive, page_size=ps, max_pages=maxp))
+    ).lower(*args).compile()
+    assert names.DSA_KERNELS[0] in _instructions(compiled.as_text())
+    # neither a copy of the pool (671 MB) nor the rows' gathered keys (277 MB)
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 * 2 ** 20
+
+
 def test_deepseek_decode_program_compiles_in_place_under_the_tenth_spare_line(
         one_chip, tpu_branch):
-    """``jit_paged_decode`` of the cell: index scores over 132 pages a row, the
-    top-2,048, the gather of the selected latent entries, the held experts'
+    """``jit_paged_decode`` of the cell: index scores over 132 pages a row by
+    the kernel that reads them in place, the top-2,048, the gather of the
+    selected latent entries, the held experts'
     ``gmm`` inside the stack, both pools flushed in place by ``kv_flush`` and
     aliased to the outputs; the whole under the tenth-spare line."""
     eng, params, cache, s = _deepseek_cell(one_chip)
@@ -488,12 +512,16 @@ def test_deepseek_decode_program_compiles_in_place_under_the_tenth_spare_line(
     text = compiled.as_text()
     calls = _instructions(text)
     assert names.CACHE_KERNELS[0] in calls and "gmm" in calls
+    assert names.DSA_KERNELS[0] in calls  # the index scores read the pages in place
     assert not _whole_pool_copies(text)
     _assert_selection_sorts_nothing(text)
+    # no gather of the rows' index keys: nothing of (slots, 132 pages, 256, 128)
+    assert not re.search(r"bf16\[32,(132,256|33792),128\]", text)
     mem = compiled.memory_analysis()
     pool_bytes = 5 * 2048 * 256 * (640 + 128) * 2
     assert mem.alias_size_in_bytes >= pool_bytes
-    assert mem.temp_size_in_bytes < 1.5 * _GIB
+    # 0.85 GiB; 0.97 while the index keys were gathered (277 MB a layer)
+    assert mem.temp_size_in_bytes < 0.9 * _GIB
     assert _total_bytes(compiled) < _TENTH_SPARE
 
 
