@@ -78,7 +78,6 @@ multi-query paged-attention kernel) and int8 KV.
 from __future__ import annotations
 
 import collections
-import math
 import time
 from dataclasses import dataclass, field
 from typing import Any
@@ -93,6 +92,7 @@ from ditl_tpu.config import ModelConfig
 from ditl_tpu.data.tokenizer import Tokenizer
 from ditl_tpu.infer.cache import init_cache
 from ditl_tpu.infer.engine import GenerateConfig, _next_pow2
+from ditl_tpu.infer.page_format import page_format
 from ditl_tpu.infer.sampling import sample_logits
 from ditl_tpu.models import llama
 from ditl_tpu.telemetry.flight import TICK_RING, FlightRecorder
@@ -113,24 +113,6 @@ __all__ = ["BadRequestError", "ContinuousEngine", "DeadlineExceededError",
 # eviction order reversed. The names ride the HTTP surface (`slo_class`
 # payload / `X-SLO-Class` header), so changing them is an API change.
 SLO_CLASSES: dict[str, int] = {"interactive": 0, "batch": 1, "best_effort": 2}
-
-
-def tail_width(decode_chunk: int) -> int:
-    """Columns of a paged decode tick's tail buffers: one a step of the
-    program, and no fewer than the 8 sublanes Mosaic wants of the tail block
-    (a 4-step program fills columns 0-3; ``pos - starts`` masks the rest in
-    the attention kernels and in the flush)."""
-    return max(decode_chunk, 8)
-
-
-def _quantize_pages(chunk: jax.Array) -> tuple[jax.Array, jax.Array]:
-    """(L, n, K, ps, D) float -> int8 values + (L, n, K, 1, ps) f32 scales
-    — one quantization recipe for both cache modes (infer/cache._quantize,
-    symmetric per-position absmax over the last axis)."""
-    from ditl_tpu.infer.cache import _quantize
-
-    q, scale = _quantize(chunk)
-    return q, scale[:, :, :, None, :]
 
 
 def _lp_stats(step_logits: jax.Array, tok: jax.Array, k: int):
@@ -167,72 +149,6 @@ def _max_over_mean(counts) -> float:
     if not mean.all():
         return 0.0
     return round(float((counts.max(axis=-1) / mean).mean()), 4)
-
-
-@jax.named_scope("kv_write")
-def _flush_tail_into_pools(pools, tk, tv, starts, pos, table, mesh=None,
-                           rules=None):
-    """Write the tick's tail columns into their pages — ONE flush per tick
-    (amortized over the chunk; per-token in-scan page writes cost ~7 ms/step
-    on v5e), in place: the ``kv_flush`` kernel reads, merges and writes back
-    only the tiles the committed columns land in (ops/kv_flush.py; an XLA
-    scatter here cost a transpose of the whole pool there and back, or 70 ns
-    a row, live or dead). Valid columns are j < pos - starts (exactly the
-    tokens the tick committed; rejected speculative positions and dead rows
-    fall outside) and nothing else is written. int8 pools: the tail is
-    quantized HERE (tokens attend at full precision within their own tick,
-    then round once); their small scale pools take a scatter of single
-    scales, invalid columns aimed past the pool's end and dropped. Shared by
-    the plain and speculative paged decode programs."""
-    from ditl_tpu.ops.kv_flush import kv_flush
-
-    out = dict(pools)
-    if "ks" in pools:
-        from ditl_tpu.infer.cache import _quantize
-
-        (tk, sk), (tv, sv) = _quantize(tk), _quantize(tv)
-        L, n_pages, K, _, ps = pools["ks"].shape
-        j = jnp.arange(tk.shape[3], dtype=jnp.int32)
-        gpos = starts[:, None] + j[None, :]  # (B, tail_len)
-        valid = j[None, :] < (pos - starts)[:, None]
-        pidx = jnp.take_along_axis(
-            table, jnp.clip(gpos // ps, 0, table.shape[1] - 1), axis=1
-        )
-        # index arrays broadcast to the scales' own (L, B, K, T)
-        ix = (jnp.arange(L, dtype=jnp.int32)[:, None, None, None],
-              jnp.where(valid, pidx, n_pages)[None, :, None, :],
-              jnp.arange(K, dtype=jnp.int32)[None, None, :, None],
-              0, (gpos % ps)[None, :, None, :])
-        out["ks"] = pools["ks"].at[ix].set(sk, mode="drop")
-        out["vs"] = pools["vs"].at[ix].set(sv, mode="drop")
-    dt = pools["kp"].dtype
-    out["kp"], out["vp"] = kv_flush(
-        pools["kp"], pools["vp"], tk.astype(dt), tv.astype(dt), table, starts,
-        pos, mesh=mesh, rules=rules,
-    )
-    return out
-
-
-# A latent pool and the name of its entries in a tick's tails and in a
-# prefill's transient row; DeepSeek-V3.2's index-key pool beside it
-# (models/dsa.py) lives under the same page table and goes wherever it goes.
-LATENT_POOLS = {"cp": ("tc", "c"), "ip": ("ti", "i")}
-
-
-@jax.named_scope("kv_write")
-def _flush_latent_tail(pools, tails, starts, pos, table):
-    """``_flush_tail_into_pools`` for latent page pools (models/mla.py; with
-    models/dsa.py's index keys, two of them): each tail (L, sublayers, B, T,
-    D), one an attention sublayer, into its pool (sublayers x L, n_pages, ps,
-    D) through the same ``kv_flush`` kernel, in place."""
-    from ditl_tpu.ops.kv_flush import latent_flush
-
-    out = {}
-    for name, pool in pools.items():
-        tail = tails[LATENT_POOLS[name][0]]
-        tail = tail.reshape(-1, *tail.shape[2:]).astype(pool.dtype)
-        out[name] = latent_flush(pool, tail, table, starts, pos)
-    return out
 
 
 def derive_copy_seed(base: int, i: int) -> int:
@@ -619,75 +535,28 @@ class ContinuousEngine:
             raise ValueError(f"unknown cache_mode {cache_mode!r}")
         self.cache_mode = cache_mode
         self.page_size = page_size
-        # Latent attention (models/mla.py): the cache entry of a token is ONE
-        # latent vector an attention sublayer, kept in a latent page pool.
-        # Modes that cannot carry such a page yet refuse here, by name: none
-        # may run the K/V code on a latent pool.
-        self.latent = model_cfg.kv_lora_rank > 0
-        # DeepSeek-V3.2 (models/dsa.py): an INDEX-KEY pool beside the latent
-        # pool, same page ids; whatever cannot carry a latent page cannot
-        # carry its index keys either, so the refusals below cover both.
-        self.indexed = model_cfg.dsa_layer
-        # live rows' selected entries summed over the decode steps and layers
-        self.dsa_selected_tokens = 0
-        if self.latent:
-            refused = {
-                "the contiguous cache (cache_mode='contiguous')": cache_mode != "paged",
-                "speculative ticks (speculative=True)": speculative,
-                "int8 page pools (kv_cache_dtype='int8')":
-                    model_cfg.kv_cache_dtype == "int8",
-                "the host tier (host_tier_mb)": bool(host_tier_mb),
-                "a mesh": mesh is not None,
-                "LoRA adapters": model_cfg.lora_rank > 0,
-            }
-            for mode, asked in refused.items():
-                if asked:
-                    raise ValueError(
-                        f"latent attention (kv_lora_rank="
-                        f"{model_cfg.kv_lora_rank}) is served from a latent "
-                        f"page pool, which {mode} cannot carry yet: serve it "
-                        "with cache_mode='paged', plain ticks, bfloat16 pages, "
-                        "no host tier and no mesh"
-                    )
-        # A hybrid stack (models/ssm.py): keys and values belong to its
-        # attention layers alone, and every state-space mixer keeps a STATE A
-        # SLOT (``self.cache["ssm"]``, ``["conv"]``: fixed size, rewritten
-        # every token) beside the page pool, in the same donated tree. What
-        # cannot carry such a state yet refuses here, by the option's name.
-        self.recurrent = "m" in model_cfg.layer_types
-        self.kv_layers = model_cfg.layer_types.count("a") or model_cfg.num_layers
-        # The width a page stores a head at. A hybrid stack's 64-wide heads
-        # are stored in whole lanes of 128, the upper half zeros (stored, and
-        # counted as stored): a pool whose last dimension is 64 lives on the
-        # chip in another layout than the kernels read, and the compiler
-        # copied both pools whole in front of every decode tick (2 x 2 GiB of
-        # temporaries in the program compiled for a described v5e).
-        self.pool_head_dim = model_cfg.head_dim
-        if self.recurrent:
-            self.pool_head_dim = -(-model_cfg.head_dim // 128) * 128
-        # live rows summed over the decode ticks' steps: each read and wrote
-        # its state once a mixer
-        self.ssm_row_steps = 0
-        if self.recurrent:
-            refused = {
-                "the contiguous cache (cache_mode='contiguous')": cache_mode != "paged",
-                "speculative ticks (speculative=True)": speculative,
-                "int8 page pools (kv_cache_dtype='int8')":
-                    model_cfg.kv_cache_dtype == "int8",
-                "the host tier (host_tier_mb)": bool(host_tier_mb),
-                "a mesh (mesh, and pod serving over it)": mesh is not None,
-                "LoRA adapters (lora_rank)": model_cfg.lora_rank > 0
-                    or "lora" in params.get("layers", {}),
-            }
-            for mode, asked in refused.items():
-                if asked:
-                    raise ValueError(
-                        f"a state-space layer (layer_types="
-                        f"{model_cfg.layer_types!r}) keeps a recurrent state a "
-                        f"slot, which {mode} cannot carry yet: serve it with "
-                        "cache_mode='paged', plain ticks, bfloat16 pages, no "
-                        "host tier, no mesh and no adapters"
-                    )
+        # What a token's cache entry is (infer/page_format.py), derived from
+        # the model. The format owns the pools, the prefill row, the tick's
+        # tails and their flush; what it cannot carry yet it refuses here, by
+        # the option's name, and nothing below asks which kind it is.
+        # (the paged branch below is what refuses a page_size that is no size)
+        self.maxp = -(-self.smax // max(page_size, 1))
+        # Default pool = the contiguous capacity; page 0 is the sentinel.
+        self.n_pages = n_pages or (n_slots * self.maxp + 1)
+        self.page_format = page_format(
+            model_cfg, n_pages=self.n_pages, page_size=page_size, n_slots=n_slots,
+            decode_chunk=decode_chunk, mesh=mesh, rules=rules)
+        asked = {
+            "contiguous": cache_mode != "paged",
+            "speculative": speculative,
+            "int8": model_cfg.kv_cache_dtype == "int8",
+            "host tier": bool(host_tier_mb),
+            "mesh": mesh is not None,
+            "adapters": model_cfg.lora_rank > 0 or "lora" in params.get("layers", {}),
+        }
+        self.page_format.refuse(*(mode for mode, on in asked.items() if on))
+        # lifetime sums of the decode ticks' scalar counters, by name
+        self.tick_totals = dict.fromkeys(self.page_format.counters, 0)
         if cache_mode == "paged":
             if model_cfg.kv_cache_dtype not in ("", "model", "int8"):
                 raise ValueError(
@@ -704,103 +573,15 @@ class ContinuousEngine:
                 )
             from ditl_tpu.infer.paged_cache import PageAllocator
 
-            self.maxp = -(-self.smax // page_size)
-            # Default pool = the contiguous capacity; page 0 is the sentinel.
-            self.n_pages = n_pages or (n_slots * self.maxp + 1)
-            # (L, P, K, ps, D): kv-heads before page slots so the Pallas
-            # kernel's per-head blocks keep (ps, D) trailing dims.
-            shape = (
-                self.kv_layers, self.n_pages, model_cfg.num_kv_heads,
-                page_size, self.pool_head_dim,
-            )
-            dt = jnp.dtype(model_cfg.dtype)
-            quantized = model_cfg.kv_cache_dtype == "int8"
-            scale_shape = (
-                self.kv_layers, self.n_pages, model_cfg.num_kv_heads,
-                1, page_size,
-            )
-
-            if self.latent:
-                from ditl_tpu.models.mla import SUBLAYERS, latent_width
-
-                # (2 L, P, ps, Dl): one set of pages an attention sublayer
-                # (DeepSeek-V3.2's block has one)
-                self.sublayers = 1 if self.indexed else SUBLAYERS
-                shape = (model_cfg.num_layers * self.sublayers, self.n_pages,
-                         page_size, latent_width(model_cfg))
-                index_shape = (*shape[:3], model_cfg.index_head_dim)
-
-            def fresh_pools():
-                if self.indexed:
-                    return {"cp": jnp.zeros(shape, dt), "ip": jnp.zeros(index_shape, dt)}
-                if self.latent:
-                    return {"cp": jnp.zeros(shape, dt)}
-                if quantized:
-                    return {
-                        "kp": jnp.zeros(shape, jnp.int8),
-                        "vp": jnp.zeros(shape, jnp.int8),
-                        "ks": jnp.ones(scale_shape, jnp.float32),
-                        "vs": jnp.ones(scale_shape, jnp.float32),
-                    }
-                pools = {"kp": jnp.zeros(shape, dt), "vp": jnp.zeros(shape, dt)}
-                if self.recurrent:
-                    from ditl_tpu.models.ssm import init_state
-
-                    pools.update(init_state(model_cfg, n_slots))
-                return pools
-
             if mesh is not None:
-                from ditl_tpu.ops.attention import _mesh_axes_size
-                from ditl_tpu.parallel.sharding import (
-                    DEFAULT_RULES,
-                    named_sharding_tree,
-                    seq_shards,
-                )
-
-                if seq_shards(mesh, rules) > 1:
-                    # Deliberate: page pools shard kv-heads/tensor only and
-                    # REPLICATE over the sequence axis — paged capacity
-                    # does not scale with it. The sequence axis exists for
-                    # contexts that exceed one chip's HBM, where
-                    # concurrency is inherently tiny and paged's capacity
-                    # sharing buys nothing; use the contiguous cache there
-                    # (it context-shards over the axis).
-                    logger.warning(
-                        "cache_mode='paged' on a sequence-sharded mesh: "
-                        "page pools replicate over the sequence axis (no "
-                        "context-capacity scaling); long-context serving "
-                        "should use the contiguous cache"
-                    )
-                r = rules if rules is not None else DEFAULT_RULES
-                tp = _mesh_axes_size(mesh, r.get("act_kv_heads"))
-                if tp > 1 and (model_cfg.num_kv_heads % tp
-                               or model_cfg.num_heads % tp):
-                    raise ValueError(
-                        f"paged cache with a mesh shards kv-heads over the "
-                        f"tensor axis: heads {model_cfg.num_heads}/"
-                        f"{model_cfg.num_kv_heads} must divide tp={tp}"
-                    )
-                dp = _mesh_axes_size(mesh, r.get("batch"))
-                if dp > 1 and n_slots % dp:
-                    # Fail at construction: the kernel would silently fall
-                    # back to the unsharded GSPMD path, resharding the whole
-                    # page pool every decode step (ADVICE r2).
-                    raise ValueError(
-                        f"paged cache with a mesh shards slots over the "
-                        f"data axes: n_slots={n_slots} must divide dp={dp}"
-                    )
-                pool_axes = ("layers", None, "act_kv_heads", None, "head_dim")
-                axes_tree = {"kp": pool_axes, "vp": pool_axes}
-                if quantized:
-                    scale_axes = ("layers", None, "act_kv_heads", None, None)
-                    axes_tree.update({"ks": scale_axes, "vs": scale_axes})
-                shardings = named_sharding_tree(mesh, axes_tree, rules)
                 # Allocate sharded-from-birth: materializing the full pool
                 # on one device first would OOM exactly the configurations
                 # sharding exists for.
-                self.cache = jax.jit(fresh_pools, out_shardings=shardings)()
+                self.cache = jax.jit(
+                    self.page_format.fresh,
+                    out_shardings=self.page_format.shardings())()
             else:
-                self.cache = fresh_pools()
+                self.cache = self.page_format.fresh()
             self.allocator = PageAllocator(
                 self.n_pages, on_evict=self._on_pages_evicted,
                 # Chain collection costs O(group depth) inside alloc on
@@ -816,23 +597,9 @@ class ContinuousEngine:
             # _process_spills) and swap back in on admission miss
             # (_host_swap_in) — the effective shared-prefix working set
             # becomes a config knob instead of a hardware constant.
-            per_val = (
-                self.kv_layers * model_cfg.num_kv_heads
-                * page_size * self.pool_head_dim
-            )
-            self.index_pool_bytes = 0
-            if self.latent:
-                self.page_bytes = math.prod(shape) // self.n_pages * dt.itemsize
-                if self.indexed:
-                    self.index_pool_bytes = math.prod(index_shape) * dt.itemsize
-                    self.page_bytes += self.index_pool_bytes // self.n_pages
-            elif quantized:
-                scale_vals = (
-                    self.kv_layers * model_cfg.num_kv_heads * page_size
-                )
-                self.page_bytes = 2 * per_val + 2 * scale_vals * 4
-            else:
-                self.page_bytes = 2 * per_val * dt.itemsize
+            self.page_bytes = self.page_format.page_bytes
+            self.index_pool_bytes = self.page_format.stats(self.tick_totals, 0).get(
+                "index_pool_bytes", 0)
             if host_tier_mb < 0:
                 raise ValueError(
                     f"host_tier_mb must be >= 0, got {host_tier_mb}"
@@ -1014,9 +781,6 @@ class ContinuousEngine:
         self.moe_layers = model_cfg.num_layers - model_cfg.first_k_dense_replace
         self.moe_assignments = np.zeros(
             (self.moe_layers, count_width(model_cfg)), np.int64)
-        # Latent attention: the live rows' context lengths summed over the
-        # decode steps, what the latent kernel had to read (per sublayer).
-        self.decode_ctx_tokens = 0
         self.moe_touched_sum = 0  # sum over decode steps and layers
         self.moe_decode_steps = 0
         self._moe_pending: list = []  # prefills' (L, E) counts, on the device
@@ -1782,134 +1546,22 @@ class ContinuousEngine:
         ``s_len`` writes garbage that stays masked until decode overwrites
         it (the same write-then-unmask invariant as the contiguous suffix
         prefill)."""
-        cfg, ps = self.cfg, self.page_size
+        cfg, fmt = self.cfg, self.page_format
         maxp = ctx_pages
-        n_wp = s_bucket // ps
-        buf = maxp * ps + s_bucket
-        buf_iota = jnp.arange(buf, dtype=jnp.int32)
+        buf_iota = jnp.arange(maxp * self.page_size + s_bucket, dtype=jnp.int32)
 
-        cd = jnp.dtype(cfg.dtype)
-        quantized = cfg.kv_cache_dtype == "int8"
-
-        def moe_kw(s_len):
-            # experts: count the chunk's real tokens, not the bucket's padding
-            if not self.moe:
+        def real_kw(s_len):
+            # experts count, and a slot's state advances on, the chunk's real
+            # tokens, not the bucket's padding
+            if not (self.moe or fmt.masks_tokens):
                 return {}
             real = jnp.arange(s_bucket, dtype=jnp.int32)[None, :] < s_len
-            return {"token_mask": real, "with_moe_counts": True}
-
-        # One pair of functions a pool kind, chosen once: ``gather`` makes the
-        # transient row the forward pass attends over (the context pages'
-        # entries, then room for the chunk's), ``write`` puts the chunk's
-        # entries into its pages, in place.
-        def latent_gather(pools, table_row):
-            # each pool (2 L, P, ps, D) -> (L, 2, 1, ctx * ps + bucket, D)
-            row = {}
-            # Page by page, each a slice of the pool copied into its place in
-            # the row: ONE gather of all the pages made the compiler copy the
-            # whole pool in lane slices first (2 x 1.04 + 0.52 GiB of
-            # temporaries at this PR's cell, whatever the context: seen in
-            # the buffer assignment compiled for a described v5e).
-            for name, cp in pools.items():
-                def put(j, r, cp=cp):
-                    page = jax.lax.dynamic_slice(
-                        cp, (0, table_row[j], 0, 0), (cp.shape[0], 1, ps, cp.shape[-1]))
-                    return jax.lax.dynamic_update_slice(r, page, (0, 0, j * ps, 0))
-
-                with jax.named_scope("kv_gather"):
-                    r = jax.lax.fori_loop(
-                        0, maxp, put,
-                        jnp.zeros((cp.shape[0], 1, buf, cp.shape[-1]), cp.dtype))
-                row[LATENT_POOLS[name][1]] = r.reshape(cfg.num_layers, -1, *r.shape[1:])
-            return row
-
-        def latent_write(pools, row, offset, write_pids):
-            out = {}
-            for name, cp in pools.items():
-                c = row[LATENT_POOLS[name][1]].reshape(cp.shape[0], 1, buf, cp.shape[-1])
-                chunk = jax.lax.dynamic_slice_in_dim(c, offset, s_bucket, axis=2)
-                chunk = chunk.reshape(cp.shape[0], n_wp, ps, cp.shape[-1])
-                for j in range(n_wp):
-                    cp = jax.lax.dynamic_update_slice(
-                        cp, chunk[:, j:j + 1], (0, write_pids[j], 0, 0))
-                out[name] = cp
-            return out
-
-        def kv_gather(pools, table_row):
-            L, _, K, _, D = pools["kp"].shape
-
-            def to_row(pool, scales=None):
-                # (L, ctx_pages, K, ps, D) [+ scales] -> (L, 1, ctx*ps, K, D)
-                if maxp == 0:
-                    return jnp.zeros((L, 1, 0, K, D), cd)
-                g = pool[:, table_row]
-                if scales is not None:
-                    sc = scales[:, table_row][:, :, :, 0, :]  # (L, maxp, K, ps)
-                    g = (g.astype(jnp.float32) * sc[..., None]).astype(cd)
-                g = jnp.swapaxes(g, 2, 3)
-                return g.reshape(L, 1, maxp * ps, K, D)
-
-            with jax.named_scope("kv_gather"):
-                ctx_k = to_row(pools["kp"], pools.get("ks"))
-                ctx_v = to_row(pools["vp"], pools.get("vs"))
-            zeros = jnp.zeros((L, 1, s_bucket, K, D), ctx_k.dtype)
-            return {
-                "k": jnp.concatenate([ctx_k, zeros], axis=2),
-                "v": jnp.concatenate([ctx_v, zeros], axis=2),
-            }
-
-        def kv_write(pools, row, offset, write_pids):
-            L, _, K, _, D = pools["kp"].shape
-
-            def to_pages(r):  # (L, 1, s_bucket, K, D) -> (L, n_wp, K, ps, D)
-                chunk = jax.lax.dynamic_slice_in_dim(r, offset, s_bucket, axis=2)
-                return jnp.swapaxes(chunk.reshape(L, n_wp, ps, K, D), 2, 3)
-
-            chunk_k, chunk_v = to_pages(row["k"]), to_pages(row["v"])
-            out = dict(pools)
-            if quantized:
-                for name, sname, chunk in (("kp", "ks", chunk_k),
-                                           ("vp", "vs", chunk_v)):
-                    vals, sc = _quantize_pages(chunk)
-                    pool, spool = out[name], out[sname]
-                    for j in range(n_wp):
-                        pool = jax.lax.dynamic_update_slice(
-                            pool, vals[:, j:j + 1], (0, write_pids[j], 0, 0, 0)
-                        )
-                        spool = jax.lax.dynamic_update_slice(
-                            spool, sc[:, j:j + 1], (0, write_pids[j], 0, 0, 0)
-                        )
-                    out[name], out[sname] = pool, spool
-            else:
-                for name, chunk in (("kp", chunk_k), ("vp", chunk_v)):
-                    pool = out[name]
-                    for j in range(n_wp):
-                        pool = jax.lax.dynamic_update_slice(
-                            pool, chunk[:, j:j + 1], (0, write_pids[j], 0, 0, 0)
-                        )
-                    out[name] = pool
-            return out
-
-        gather, write = (latent_gather, latent_write) if self.latent else (
-            kv_gather, kv_write)
+            return {"token_mask": real, **({"with_moe_counts": True} if self.moe else {})}
 
         def paged_prefill(params, pools, table_row, ids, offset, s_len, temp,
-                          top_p, rng, write_pids, aid, *fsm):
-            row = gather(pools, table_row)
-            rec_kw = {}
-            if self.recurrent:
-                # The slot's recurrent state rides the transient row: what a
-                # chunk before this one left, or zeros at a sequence's start
-                # (whatever the slot's last tenant left is never read). The
-                # bucket's padding leaves it at the last real token's.
-                from ditl_tpu.models.ssm import SLOT_AXIS
-
-                slot, *fsm = fsm
-                for k, axis in SLOT_AXIS.items():
-                    was = jax.lax.dynamic_slice_in_dim(pools[k], slot, 1, axis=axis)
-                    row[k] = jnp.where(offset > 0, was, jnp.zeros_like(was))
-                real = jnp.arange(s_bucket, dtype=jnp.int32)[None, :] < s_len
-                rec_kw = {"token_mask": real}
+                          top_p, rng, write_pids, aid, slot=None, *fsm):
+            # ``slot``: ``fmt.slot_operand``'s, None where nothing is seated a slot
+            row = fmt.gather(pools, table_row, maxp, s_bucket, offset=offset, slot=slot)
             q_pos = offset + jnp.arange(s_bucket, dtype=jnp.int32)
             if maxp == 0:
                 # No context pages (offset 0): pure causal self-attention
@@ -1920,8 +1572,7 @@ class ContinuousEngine:
                     params, ids, cfg, positions=q_pos[None], segment_ids=seg,
                     cache=row, cache_index=offset,
                     mesh=self.mesh, rules=self.rules, prefill_causal=True,
-                    adapter_ids=aid if self.multi_lora else None, **moe_kw(s_len),
-                    **rec_kw,
+                    adapter_ids=aid if self.multi_lora else None, **real_kw(s_len),
                 )
             else:
                 mask = buf_iota[None, None, :] <= q_pos[None, :, None]
@@ -1929,16 +1580,12 @@ class ContinuousEngine:
                     params, ids, cfg, positions=q_pos[None],
                     cache=row, cache_index=offset, attn_mask=mask,
                     mesh=self.mesh, rules=self.rules,
-                    adapter_ids=aid if self.multi_lora else None, **moe_kw(s_len),
-                    **rec_kw,
+                    adapter_ids=aid if self.multi_lora else None, **real_kw(s_len),
                 )
-            with jax.named_scope("kv_write"):
-                out = write(pools, row, offset, write_pids)
-                if self.recurrent:  # seat the slot's state
-                    for k, axis in SLOT_AXIS.items():
-                        out[k] = jax.lax.dynamic_update_slice_in_dim(
-                            pools[k], row[k], slot, axis=axis)
-            moe_counts = moe_counts[:1]  # the experts'; a decode tick's has more
+            out = fmt.write(pools, row, offset, write_pids, slot=slot)
+            # the chunk's counters, by name as a decode tick's are (what else
+            # the forward pass counted is a decode tick's to count)
+            counters = {"moe_counts": moe_counts[0]} if self.moe else {}
             last = logits[0, s_len - 1]
             masked = _fsm_mask(fsm[0], fsm[1], last) if self.guided else last
             first = sample_logits(
@@ -1948,8 +1595,8 @@ class ContinuousEngine:
             fs = (_fsm_next(fsm[0], fsm[1], first),) if self.guided else ()
             if self.logprobs_k:
                 c, i, t = _lp_stats(last[None], first[None], self.logprobs_k)
-                return (out, first, c[0], i[0], t[0], *fs, *moe_counts)
-            return (out, first, *fs, *moe_counts)
+                return (out, first, c[0], i[0], t[0], *fs, counters)
+            return (out, first, *fs, counters)
 
         return jax.jit(paged_prefill, donate_argnums=(1,))
 
@@ -1965,15 +1612,6 @@ class ContinuousEngine:
             return decode_steps(starts, alive, page_size=self.page_size,
                                 max_pages=self.maxp)
 
-    def _index_steps(self, starts: jax.Array, alive: jax.Array) -> dict:
-        """The index scores' work list (``ops/dsa_index.py`` ``index_steps``:
-        ``_attn_steps``' with a group of pages for a page), built beside it."""
-        from ditl_tpu.ops.dsa_index import index_steps
-
-        with jax.named_scope("attn_core"), jax.named_scope("attn_steps"):
-            return index_steps(starts, alive, page_size=self.page_size,
-                               max_pages=self.maxp)
-
     def _build_paged_decode(self, sampled: bool, topp: bool):
         """Paged decode tick with DEFERRED page writes: the chunk's K/V
         accumulate in small per-layer tail buffers carried through the scan
@@ -1982,19 +1620,15 @@ class ContinuousEngine:
         writes the tail into the pools after the scan. ``limits`` ends a row
         exactly at its token budget, so flushed positions never pass the
         pages reserved at admission."""
-        cfg = self.cfg
+        cfg, fmt = self.cfg, self.page_format
         pad, eos = self.tokenizer.pad_id, self.tokenizer.eos_id
         chunk = self.decode_chunk
-        tail_len = tail_width(chunk)
-        L, K, D = cfg.num_layers, cfg.num_kv_heads, self.pool_head_dim
-        dt = jnp.dtype(cfg.dtype)
 
         track = self.speculative
         n_lp = self.logprobs_k
 
         guided = self.guided
         moe = self.moe
-        recurrent = self.recurrent
         from ditl_tpu.models.moe import count_width, split_counts
 
         def paged_decode(params, pools, cur, pos, alive, temps, top_ps, keys,
@@ -2010,32 +1644,23 @@ class ContinuousEngine:
             done0 = ~alive | (cur == pad)
             listed = ~done0 & (pos < limits)
             steps = self._attn_steps(starts, listed)
-            # the indexer scores a row's pages only where it has to choose
-            walks_index = self.indexed and self.maxp * self.page_size + tail_len > cfg.index_topk
-            index_steps = self._index_steps(starts, listed) if walks_index else None
-            if self.latent:
-                from ditl_tpu.models.mla import latent_width
-
-                tails0 = {"tc": jnp.zeros(
-                    (L, self.sublayers, n_b, tail_len, latent_width(cfg)), dt)}
-                if self.indexed:
-                    tails0["ti"] = jnp.zeros(
-                        (L, 1, n_b, tail_len, cfg.index_head_dim), dt)
-            else:
-                tails0 = {"tk": jnp.zeros((self.kv_layers, n_b, K, tail_len, D), dt),
-                          "tv": jnp.zeros((self.kv_layers, n_b, K, tail_len, D), dt)}
+            format_meta = fmt.tick_meta(starts, listed, table)
             # Read-only during the scan, and whole: llama.forward keeps them
-            # out of its layer loop and offsets each layer's page table.
-            cache_const = {k: v for k, v in pools.items() if k not in ("ssm", "conv")}
-            if recurrent:
-                # The slots' recurrent state is rewritten every step: it
-                # rides the scan's carry beside the tails (their keys are its
-                # own), each mixer updating its entry in place, and
-                # ``moe_acc`` counts the live rows that did.
-                tails0 = {**tails0, "ssm": pools["ssm"], "conv": pools["conv"]}
+            # out of its layer loop and offsets each layer's page table. What
+            # the format rewrites every step rides the scan's carry beside
+            # the tails (their keys are its own).
+            cache_const, carried = fmt.split(pools)
+            tails0 = {**fmt.tails0(n_b), **carried}
+            # The tick's counters, by name (``_note_tick`` reads them so): the
+            # experts' here, the format's own by the format.
+            acc0 = dict.fromkeys(fmt.counters, jnp.zeros((), jnp.int32))
+            if moe:
+                acc0.update(
+                    moe_counts=jnp.zeros((self.moe_layers, count_width(cfg)), jnp.int32),
+                    moe_touched=jnp.zeros((), jnp.int32))
 
             def body(carry, t):
-                tails, cur, pos, done, keys, hist, fst, lp, moe_acc = carry
+                tails, cur, pos, done, keys, hist, fst, lp, acc = carry
                 split = jax.vmap(lambda k: jax.random.split(k, 2))(keys)
                 keys, subs = split[:, 0], split[:, 1]
                 done = done | (pos >= limits)
@@ -2043,10 +1668,8 @@ class ContinuousEngine:
                 lengths = jnp.where(step_alive, pos + 1, 0)
                 paged_meta = {
                     "table": table, "lengths": lengths, "starts": starts,
-                    "t": t, "steps": steps,
+                    "t": t, "steps": steps, **format_meta,
                 }
-                if walks_index:
-                    paged_meta["index_steps"] = index_steps
                 logits, tails, *moe_counts = llama.forward(
                     params,
                     cur[:, None],
@@ -2057,29 +1680,20 @@ class ContinuousEngine:
                     mesh=self.mesh,
                     rules=self.rules,
                     adapter_ids=adapters if self.multi_lora else None,
-                    **({"token_mask": step_alive[:, None],
-                        "with_moe_counts": True} if moe else {}),
-                    **({"token_mask": step_alive[:, None]} if recurrent else {}),
+                    **({"token_mask": step_alive[:, None]}
+                       if moe or fmt.masks_tokens else {}),
+                    **({"with_moe_counts": True} if moe else {}),
                 )
-                if recurrent:
-                    moe_acc = (moe_acc[0] + step_alive.sum(dtype=jnp.int32),)
+                # what the forward pass counted, named where it returns it
+                counted = dict(zip(("moe_counts", "dsa_selected"), moe_counts))
+                acc = fmt.count(acc, alive=step_alive, lengths=lengths, starts=starts,
+                                meta=format_meta, counted=counted)
                 if moe:
                     # the live rows' assignments; the experts they touched
                     # (of those whose weights live here)
-                    counts, touched, *ctx = moe_acc
-                    held = split_counts(moe_counts[0], cfg)[0]
-                    # latent attention: the context tokens this step's rows
-                    # had, and (models/dsa.py) the entries they selected,
-                    # summed over the layers
-                    read = (lengths.sum(), *(m.sum() for m in moe_counts[1:]))
-                    if self.indexed:
-                        # and the pages its index walk fetched: a live row's
-                        # flushed pages, in every layer
-                        live_pages = -(-jnp.minimum(starts, lengths) // self.page_size)
-                        read += (live_pages.sum() * (L if walks_index else 0),)
-                    moe_acc = (counts + moe_counts[0],
-                               touched + (held > 0).sum(),
-                               *(c + n for c, n in zip(ctx, read)))
+                    held = split_counts(counted["moe_counts"], cfg)[0]
+                    acc = {**acc, "moe_counts": acc["moe_counts"] + counted["moe_counts"],
+                           "moe_touched": acc["moe_touched"] + (held > 0).sum()}
                 step_logits = logits[:, 0]
                 nxt = sample_logits(
                     _fsm_mask(ftab, fst, step_logits) if guided else step_logits,
@@ -2104,41 +1718,29 @@ class ContinuousEngine:
 
                     grow = (~done).astype(jnp.int32)
                     hist = _emit_rows(hist, cur[:, None], pos, grow)
-                return (tails, cur, pos, done, keys, hist, fst, lp, moe_acc), ys
+                return (tails, cur, pos, done, keys, hist, fst, lp, acc), ys
 
             fst0 = fstates if guided else jnp.zeros((), jnp.int32)
-            moe0 = ((jnp.zeros((self.moe_layers, count_width(cfg)), jnp.int32),
-                     jnp.zeros((), jnp.int32),
-                     *((jnp.zeros((), jnp.int32),) * (self.latent + 2 * self.indexed)))
-                    if moe else ())
-            if recurrent:
-                moe0 = (jnp.zeros((), jnp.int32),)
-            (tails, cur, pos, done, keys, hist, fst, lp, moe_acc), ys = jax.lax.scan(
+            (tails, cur, pos, done, keys, hist, fst, lp, acc), ys = jax.lax.scan(
                 # A row whose pending token is the pad already ended in an
                 # earlier tick (``cur = where(done, pad, nxt)``): the dead
                 # chunk it decodes before the lagged harvest frees its slot
                 # reads no page, writes no tail column and counts nothing.
                 body, (tails0, cur, pos, done0, keys, hist,
-                       fst0, tuple(lp0), moe0),
+                       fst0, tuple(lp0), acc0),
                 jnp.arange(chunk, dtype=jnp.int32),
             )
 
-            if self.latent:
-                out = _flush_latent_tail(pools, tails, starts, pos, table)
-            else:
-                out = _flush_tail_into_pools(
-                    cache_const, tails["tk"], tails["tv"], starts, pos, table,
-                    self.mesh, self.rules
-                )
-                if recurrent:
-                    out.update(ssm=tails["ssm"], conv=tails["conv"])
+            out = fmt.flush(cache_const, tails, starts, pos, table)
+            # the attention kernels' list's count rides with the scan's
+            counters = {**acc, "attn_steps_walked": steps["count"]}
             fs = (fst,) if guided else ()
             if n_lp:
                 toks, c, i, t = ys
                 return (out, cur, pos, keys, hist, *fs, lp, toks.T,
                         c.T, jnp.swapaxes(i, 0, 1), jnp.swapaxes(t, 0, 1),
-                        *moe_acc, steps["count"])
-            return (out, cur, pos, keys, hist, *fs, ys.T, *moe_acc, steps["count"])
+                        counters)
+            return (out, cur, pos, keys, hist, *fs, ys.T, counters)
 
         return jax.jit(paged_decode, donate_argnums=(1,))
 
@@ -2159,8 +1761,7 @@ class ContinuousEngine:
         ngram, min_ngram = self.spec_ngram, self.spec_min_ngram
         out_len = rounds * (k + 1)
         tail_len = max(rounds * (k + 1), 8)
-        L, K, D = cfg.num_layers, cfg.num_kv_heads, cfg.head_dim
-        dt = jnp.dtype(cfg.dtype)
+        fmt = self.page_format
         q_idx = jnp.arange(k + 1, dtype=jnp.int32)
 
         from ditl_tpu.infer.speculative import _emit_rows, device_lookup_draft
@@ -2184,11 +1785,10 @@ class ContinuousEngine:
             n_b = pos.shape[0]
             starts = pos
             steps = self._attn_steps(starts, alive & (pos < limits))
-            tk0 = jnp.zeros((L, n_b, K, tail_len, D), dt)
-            tv0 = jnp.zeros((L, n_b, K, tail_len, D), dt)
+            tails0 = fmt.tails0(n_b, tail_len)
             # Read-only during the scan, and whole: llama.forward keeps them
             # out of its layer loop and offsets each layer's page table.
-            cache_const = dict(pools)
+            cache_const, _ = fmt.split(pools)
             out0 = jnp.full((n_b, out_len), pad, jnp.int32)
             zeros = jnp.zeros((n_b,), jnp.int32)
             bufs0 = (
@@ -2199,7 +1799,7 @@ class ContinuousEngine:
             )
 
             def body(carry, _):
-                (tk, tv, dcache, cur, pos, done, hist, out, n_out, rr, keys,
+                (tails, dcache, cur, pos, done, hist, out, n_out, rr, keys,
                  fst, lp, bufs) = carry
                 done = done | (pos >= limits)
                 live = ~done
@@ -2226,11 +1826,10 @@ class ContinuousEngine:
                 }
                 logits, tails = llama.forward(
                     params, tokens_in, cfg, positions=positions,
-                    cache={**cache_const, "tk": tk, "tv": tv},
+                    cache={**cache_const, **tails},
                     paged=paged_meta, mesh=self.mesh, rules=self.rules,
                     adapter_ids=adapters if self.multi_lora else None,
                 )
-                tk, tv = tails["tk"], tails["tv"]
                 if guided:
                     # See _build_spec_decode: per-position path-state masks.
                     path = self._fsm_spec_path(ftab, fst, draft)
@@ -2273,21 +1872,19 @@ class ContinuousEngine:
                     fst = jnp.where(done, fst, _fsm_next(ftab, s_at, nxt_tok))
                 cur = jnp.where(done, pad, nxt_tok)
                 rr = rr + live.astype(jnp.int32)
-                return (tk, tv, dcache, cur, pos, done, hist, out, n_out,
+                return (tails, dcache, cur, pos, done, hist, out, n_out,
                         rr, keys, fst, lp, bufs), None
 
             fst0 = fstates if guided else jnp.zeros((), jnp.int32)
             dc0 = dcache0 if model_draft else jnp.zeros((), jnp.int32)
-            (tk, tv, dcache, cur, pos, done, hist, out, n_out, rr, keys,
+            (tails, dcache, cur, pos, done, hist, out, n_out, rr, keys,
              fst, lp, bufs), _ = jax.lax.scan(
                 body,
-                (tk0, tv0, dc0, cur, pos, ~alive, hist, out0, zeros, zeros,
+                (tails0, dc0, cur, pos, ~alive, hist, out0, zeros, zeros,
                  keys, fst0, tuple(lp0), bufs0),
                 None, length=rounds,
             )
-            pools_out = _flush_tail_into_pools(
-                pools, tk, tv, starts, pos, table, self.mesh, self.rules
-            )
+            pools_out = fmt.flush(cache_const, tails, starts, pos, table)
             fs = (fst,) if guided else ()
             dc = (dcache,) if model_draft else ()
             return (pools_out, *dc, cur, pos, hist, keys, *fs, out, n_out,
@@ -2304,11 +1901,7 @@ class ContinuousEngine:
         device memory until ``clear_prefixes``."""
         if not prefix_tokens:
             raise ValueError("prefix must be non-empty")
-        if self.recurrent:
-            raise ValueError(
-                "register_prefix cannot serve a state-space layer: a prefix's "
-                "pages are reusable only with the recurrent state at their "
-                "boundary, which nothing keeps yet")
+        self.page_format.refuse("registered prefix")
         if self.multi_lora:
             raise ValueError(
                 "register_prefix with a multi-adapter stack is unsupported "
@@ -2838,8 +2431,8 @@ class ContinuousEngine:
 
     def _publish_tokens(self, tokens: list[int], slot: int,
                         adapter_id: int = 0) -> None:
-        if self.recurrent:
-            return  # pages without the state at their boundary serve nobody
+        if not self.page_format.publishes:
+            return
         ps = self.page_size
         n_full = len(tokens) // ps
         self.allocator.publish_chain(
@@ -3028,10 +2621,7 @@ class ContinuousEngine:
         (``ThreadedEngine.call``)."""
         if self.cache_mode != "paged":
             raise BadRequestError("KV handoff requires cache_mode='paged'")
-        if self.latent or self.recurrent:
-            raise BadRequestError(
-                "the disaggregated KV handoff (export_kv / import_kv) cannot "
-                "carry latent pages or a recurrent state yet")
+        self.page_format.refuse("handoff", error=BadRequestError)
         if adapter_id:
             raise BadRequestError("KV handoff serves the base adapter only")
         ps = self.page_size
@@ -3088,10 +2678,7 @@ class ContinuousEngine:
 
         if self.cache_mode != "paged":
             raise BadRequestError("KV handoff requires cache_mode='paged'")
-        if self.latent or self.recurrent:
-            raise BadRequestError(
-                "the disaggregated KV handoff (export_kv / import_kv) cannot "
-                "carry latent pages or a recurrent state yet")
+        self.page_format.refuse("handoff", error=BadRequestError)
         meta, pages = deserialize_pages(blob)
         want = {
             "page_size": self.page_size,
@@ -3224,13 +2811,13 @@ class ContinuousEngine:
             jnp.asarray(row), jnp.asarray(ids), jnp.int32(d),
             jnp.int32(s), jnp.float32(temp), jnp.float32(top_p), rng,
             jnp.asarray(pids), jnp.asarray([adapter], jnp.int32),
-            *((jnp.int32(slot),) if self.recurrent else ()),
+            self.page_format.slot_operand(slot),
             *self._fsm_args(fsm_start),
         )
-        if self.moe:
+        *out, counters = out
+        if "moe_counts" in counters:
             # stays on the device until the next decode tick's fetch
-            *out, counts = out
-            self._moe_pending.append(counts)
+            self._moe_pending.append(counters["moe_counts"])
             if len(self._moe_pending) > 64:  # no plain tick drains them
                 self._moe_pending = [sum(self._moe_pending)]
         return self._take_prefill(out, slot), s_bucket
@@ -3284,12 +2871,10 @@ class ContinuousEngine:
         if req.preempted:
             return self._resume_paged_slot(slot, req)
         ps = self.page_size
-        # A hit on pages would skip tokens whose recurrent state nobody kept:
-        # with a state-space layer the content cache is not consulted (and
-        # ``_publish_tokens`` publishes nothing).
-        matched = [] if self.recurrent else self.allocator.match_prefix(
+        # a format that publishes no pages consults no content cache either
+        matched = self.allocator.match_prefix(
             req.prompt, ps, root=-req.adapter_id
-        )  # retained
+        ) if self.page_format.publishes else []  # retained
         # Host-tier swap-in (ISSUE 13): extend the HBM run from the host
         # store before deciding how much prefill this admission costs. If
         # admission then defers (budget/pool), the swapped pages stay
@@ -3380,9 +2965,9 @@ class ContinuousEngine:
         ctx = req.prompt + req.tokens
         pos = len(ctx)  # cur's write position
         cap = len(req.prompt) + req.max_new_tokens
-        # a recurrent state was dropped with the slot: all of ctx runs again
-        matched = [] if self.recurrent else self.allocator.match_prefix(
-            ctx, ps, root=-req.adapter_id)
+        # what a format keeps a slot went with the slot: all of ctx runs again
+        matched = self.allocator.match_prefix(
+            ctx, ps, root=-req.adapter_id) if self.page_format.publishes else []
         # Budget gate: the resume's chunks run back-to-back inside THIS
         # admission (they never interleave across ticks — see below), so
         # the whole unmatched remainder is this tick's prefill cost.
@@ -4518,16 +4103,9 @@ class ContinuousEngine:
                 self.temps, self.top_ps, self.keys, self.hist, self.adapters,
                 *fsm_args, *lp_args,
             )
-        walked_dev = ()
-        if self.cache_mode == "paged":  # the attention kernels' list's count
-            res, walked_dev = res[:-1], tuple(res[-1:])
-        moe_dev = ()
-        if self.moe:  # paged: the tick's (L, E) counts and touched sum
-            # and the context tokens read, and the entries selected of them
-            n_moe = 2 + self.latent + 2 * self.indexed
-            res, moe_dev = res[:-n_moe], tuple(res[-n_moe:])
-        elif self.recurrent and self.cache_mode == "paged":
-            res, moe_dev = res[:-1], tuple(res[-1:])  # the tick's row steps
+        counters_dev = {}
+        if self.cache_mode == "paged":  # the tick's counters, by name
+            *res, counters_dev = res
         if self.guided:
             (self.cache, self.cur, self.pos, self.keys, self.hist,
              self.fstates, *res_rest) = res
@@ -4542,76 +4120,64 @@ class ContinuousEngine:
         else:
             (toks,) = res_rest
             lp_dev = None
-        return ("plain", key, t0, toks, lp_dev, self._snapshot_slots(), moe_dev,
-                walked_dev)
+        return ("plain", key, t0, toks, lp_dev, self._snapshot_slots(), counters_dev)
 
     def _plain_finish(self, rec: tuple) -> None:
         """Fetch a dispatched plain tick's outputs + harvest."""
         import time as _time
 
-        (_, key, t0, toks, lp_dev, snapshot, moe_dev, walked_dev) = rec
+        (_, key, t0, toks, lp_dev, snapshot, counters_dev) = rec
         self._phase("engine.tick.fetch")
         moe_pending, self._moe_pending = self._moe_pending, []
-        # One fetch for everything (see _spec_finish): the experts' counts
-        # and the attention kernels' step count ride with the tokens.
-        toks, lp_np, moe_np, pending_np, walked_np = jax.device_get(
-            (toks, lp_dev or (), moe_dev, moe_pending, walked_dev))
+        # One fetch for everything (see _spec_finish): the tick's counters
+        # and the prefills' expert counts ride with the tokens.
+        toks, lp_np, counters, pending_np = jax.device_get(
+            (toks, lp_dev or (), counters_dev, moe_pending))
         lp = tuple(np.asarray(x) for x in lp_np) if lp_dev is not None else None
         toks = np.asarray(toks)
         self._phase("engine.tick.harvest")
-        if walked_np and self._tick_span is not None:
-            # a call of the decode attention kernel walked this many steps,
-            # of the rectangle of every slot by every page-table position
-            # and the tail (what it walked before PR 42)
-            self._tick_span.annotate(
-                attn_steps_walked=int(walked_np[0]),
-                attn_steps_rect=self.n_slots * (self.maxp + 1))
-        if self.recurrent:
-            self.ssm_row_steps += int(moe_np[0])
-            if self._tick_span is not None:
-                self._tick_span.annotate(ssm_row_steps=int(moe_np[0]),
-                                         ssm_steps=self.decode_chunk)
-        elif moe_np:
-            self._note_moe(moe_np, pending_np)
+        if counters:
+            self._note_tick(counters, pending_np)
         if self.speculative and (not self.pipeline_ticks or self._probe_timing):
             # See _spec_finish: pipelined intervals are not device cost,
             # but serial probe-tick intervals are.
             self._record_tick_time(key, (_time.perf_counter() - t0) * 1e3)
         self._harvest(toks, lp=lp, snapshot=snapshot)
 
-    def _note_moe(self, tick, prefill_counts) -> None:
-        """Add one decode tick's expert counts (and the counts of the
-        prefills that ran before it) to the host's totals; an armed tracer's
-        ``engine.tick`` span carries the tick's own. With latent attention
-        the tick also brings the context tokens its steps read."""
-        from ditl_tpu.models.moe import split_counts
+    def _note_tick(self, tick: dict, prefill_counts) -> None:
+        """One paged decode tick's counters, read by name (the decode
+        program's last output): the scalars into the host's lifetime totals,
+        the experts' counts (and those of the prefills that ran before the
+        tick) into ``moe_assignments``; an armed tracer's ``engine.tick`` span
+        carries the tick's own and what the format derives from them."""
+        attrs = {name: int(n) for name, n in tick.items() if np.ndim(n) == 0}
+        for name in self.tick_totals:
+            self.tick_totals[name] += attrs[name]
+        if "moe_counts" in tick:
+            counts = np.asarray(tick["moe_counts"], np.int64)
+            self.moe_assignments += counts
+            for c in prefill_counts:
+                self.moe_assignments += np.asarray(c, np.int64)
+            self.moe_touched_sum += attrs["moe_touched"]
+            self.moe_decode_steps += self.decode_chunk
+        if self._tick_span is None:
+            return
+        attrs.update(self.page_format.span_attrs(attrs, self.decode_chunk))
+        # a call of the decode attention kernel walked this many steps, of
+        # the rectangle of every slot by every page-table position and the
+        # tail (what it walked before PR 42)
+        attrs["attn_steps_rect"] = self.n_slots * (self.maxp + 1)
+        if "moe_counts" in tick:
+            from ditl_tpu.models.moe import split_counts
 
-        counts, touched, *ctx = tick  # the decode program's moe_acc
-        counts = np.asarray(counts, np.int64)
-        self.moe_assignments += counts
-        for c in prefill_counts:
-            self.moe_assignments += np.asarray(c, np.int64)
-        self.moe_touched_sum += int(touched)
-        self.moe_decode_steps += self.decode_chunk
-        extra = {}
-        if ctx:
-            self.decode_ctx_tokens += int(ctx[0])
-            extra["decode_ctx_tokens"] = int(ctx[0])
-        if len(ctx) > 1:  # an indexer chose among them, in every layer
-            self.dsa_selected_tokens += int(ctx[1])
-            extra.update(dsa_ctx_tokens=int(ctx[0]) * self.cfg.num_layers,
-                         dsa_selected_tokens=int(ctx[1]), dsa_index_pages=int(ctx[2]))
-        held, zero, absent = split_counts(counts, self.cfg)
-        if held.shape != counts.shape:  # a share of a wider expert layer
-            extra.update(moe_assign_held=int(held.sum()),
-                         moe_assign_zero=int(zero.sum()),
-                         moe_assign_absent=int(absent.sum()))
-        if self._tick_span is not None:
-            self._tick_span.annotate(
-                moe_assignments=int(counts.sum()), moe_touched=int(touched),
-                moe_steps=self.decode_chunk,
-                moe_load_max_over_mean=_max_over_mean(held), **extra,
-            )
+            held, zero, absent = split_counts(counts, self.cfg)
+            if held.shape != counts.shape:  # a share of a wider expert layer
+                attrs.update(moe_assign_held=int(held.sum()),
+                             moe_assign_zero=int(zero.sum()),
+                             moe_assign_absent=int(absent.sum()))
+            attrs.update(moe_assignments=int(counts.sum()), moe_steps=self.decode_chunk,
+                         moe_load_max_over_mean=_max_over_mean(held))
+        self._tick_span.annotate(**attrs)
 
     def _finish_tick(self, rec: tuple) -> None:
         (self._spec_finish if rec[0] == "spec" else self._plain_finish)(rec)
@@ -5023,14 +4589,10 @@ class ContinuousEngine:
                 out["resume_prefill_tokens"] = self.resume_prefill_tokens
         if self.multi_lora:
             out["adapters"] = self.n_adapters
-        if self.recurrent:
-            from ditl_tpu.models.ssm import state_bytes_per_slot
-
-            per_slot = state_bytes_per_slot(self.cfg)
-            out["ssm_state_bytes_per_slot"] = per_slot
-            out["ssm_state_bytes_resident"] = per_slot * self.n_slots
-            out["ssm_slots_seated"] = sum(r is not None for r in self._slots)
-            out["ssm_row_steps_total"] = self.ssm_row_steps
+        # what the page format adds: its sizes, and what it derives from the
+        # lifetime totals of the tick counters it fills
+        out.update(self.page_format.stats(
+            self.tick_totals, sum(r is not None for r in self._slots)))
         if self.moe:
             # Live rows of the paged decode ticks and real tokens of the
             # paged prefills only; the touched mean is per decode step and
@@ -5045,12 +4607,6 @@ class ContinuousEngine:
                 out["moe_assign_held"] = int(held.sum())
                 out["moe_assign_zero"] = int(zero.sum())
                 out["moe_assign_absent"] = int(absent.sum())
-            if self.latent:
-                out["decode_ctx_tokens"] = self.decode_ctx_tokens
-            if self.indexed:
-                out["dsa_ctx_tokens"] = self.decode_ctx_tokens * self.cfg.num_layers
-                out["dsa_selected_tokens"] = self.dsa_selected_tokens
-                out["index_pool_bytes"] = self.index_pool_bytes
             out["moe_experts_touched_mean"] = round(
                 self.moe_touched_sum
                 / max(1, self.moe_decode_steps * self.moe_layers), 4)

@@ -416,14 +416,8 @@ class PodContinuousDriver:
     how the protocol is unit-tested."""
 
     def __init__(self, engine, *, poll_s: float = 0.02):
-        if getattr(engine, "latent", False):
-            raise ValueError(
-                "pod serving cannot carry a latent page pool yet (latent "
-                "attention is served by one process on one chip)")
-        if getattr(engine, "recurrent", False):
-            raise ValueError(
-                "pod serving cannot carry a recurrent state a slot yet (a "
-                "state-space layer is served by one process on one chip)")
+        # what a pod cannot carry yet of the engine's cache (infer/page_format.py)
+        engine.page_format.refuse("pod")
         self._engine = engine
         # Per-host wall-clock calibration would desync pod tick decisions.
         engine.freeze_spec_threshold()

@@ -65,7 +65,7 @@ and ``dsa_gather`` inside ``attn_core``; ``mla_q`` / ``mla_kv`` / ``mla_attn``
 as the double layer has them.
 
 Serving only as far as the cache goes, like the double layer: paged pools,
-plain ticks; the engine refuses the rest (infer/continuous.py). The
+plain ticks; the engine refuses the rest (infer/page_format.py). The
 multi-token-prediction module is not implemented.
 """
 

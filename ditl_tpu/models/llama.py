@@ -566,7 +566,7 @@ def _decoder_layer(
             k = apply_rope(k, positions, cfg=cfg)
         # The width the cache stores a head at: ``head_dim``, or whole lanes
         # of 128 where the engine pads a narrower head's pages with zeros
-        # (infer/continuous.py ``pool_head_dim``). Zeros add nothing to a
+        # (infer/page_format.py ``KVPages.head_dim``). Zeros add nothing to a
         # score and come back as zero columns of the output, cut off below.
         width = hd
         if pools is not None:
@@ -758,7 +758,7 @@ def forward(
     pools are read in place: they stay out of the layer loop, whole, and every
     layer addresses its own pages inside them. Only the tails are scanned and
     returned (``new_cache`` is ``{"tk", "tv"}``); the caller scatters them into
-    the pools once a tick (infer/continuous.py).
+    the pools once a tick (infer/page_format.py ``flush``).
 
     ``prefill_causal=True`` (with ``cache``): the chunk prefills an EMPTY
     cache from offset 0, so attention is pure causal self-attention over

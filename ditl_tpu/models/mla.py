@@ -49,7 +49,7 @@ value half of the absorption inside ``attn_out``), ``mla_attn`` inside
 Serving only as far as the cache goes: latent pages (``{"cp"}``, a tick's
 tail ``{"tc"}``) and a prefill's transient row (``{"c"}``). The contiguous
 cache, int8 pools, speculative ticks, the host tier, the handoff and a mesh
-refuse at engine construction (infer/continuous.py).
+refuse at engine construction (infer/page_format.py).
 """
 
 from __future__ import annotations

@@ -3,7 +3,7 @@ pools, in place, touching only the tiles they land in.
 
 A tick accumulates its new K and V in a small tail buffer (``(L, B, K, T,
 D)``, column ``j`` of slot ``b`` = position ``starts[b] + j``) and flushes
-it once, after its scan (infer/continuous.py ``_flush_tail_into_pools``).
+it once, after its scan (infer/page_format.py ``_flush_tail_into_pools``).
 Valid columns are ``j < pos[b] - starts[b]``. Written as an XLA scatter the
 flush costs what the compiler makes of the scatter's window, never what it
 writes (25 MB a tick on the 7B serving cell): a window of ``(L, K, D)`` had

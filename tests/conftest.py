@@ -73,6 +73,35 @@ def example_batch():
     }
 
 
+@pytest.fixture(scope="session")
+def one_chip():
+    """One device of a DESCRIBED v5e, for the ``test_tpu_compile_*`` files
+    (tests/tpu_compile.py). The TPU's library is loaded here, by the first
+    test that asks, and never while a module is imported."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure to describe it is a skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def tpu_branch(monkeypatch):
+    """The code asks ``jax.default_backend()``, which is the CPU here: take
+    the branch the chip takes."""
+    from ditl_tpu.models import moe as moe_mod
+    from ditl_tpu.ops import backend, kv_flush, paged_attention, ssd
+
+    monkeypatch.setattr(moe_mod, "_use_gmm", lambda rows, mesh: rows % 128 == 0)
+    # every module that bound the name at its import, whichever came first
+    for module in (moe_mod, backend, kv_flush, paged_attention, ssd):
+        monkeypatch.setattr(module, "interpret_default", lambda: False)
+
+
 # ---------------------------------------------------------------------------
 # Test tiers: the default run (`pytest -q`) excludes tests marked `slow`
 # (pytest.ini addopts) and finishes in ~2-3 minutes on this box (load-

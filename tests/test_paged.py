@@ -685,7 +685,7 @@ def test_flush_writes_the_committed_rows_and_nothing_else(tail_len, pool_dtype):
     spans up to three tiles. Every row of every page that no committed
     column names is bit-identical to before, the sentinel page 0 too."""
     from ditl_tpu.infer.cache import _quantize
-    from ditl_tpu.infer.continuous import _flush_tail_into_pools
+    from ditl_tpu.infer.page_format import _flush_tail_into_pools
 
     L, P, K, ps, D = 2, 13, 2, 32, 16
     int8 = pool_dtype == "int8"

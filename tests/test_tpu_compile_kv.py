@@ -1,0 +1,172 @@
+"""Compiled for a described v5e, with no chip attached (tests/tpu_compile.py):
+K/V pages and the trainer's flash kernels. The paged decode kernel with ONE
+query head to a kv head (7 in both Qwen2 sizes) on its work list, the cached
+layer loop that copies no pool, the tick's flush in place, and the three flash
+kernels with their scalar-prefetch operands.
+"""
+
+from __future__ import annotations
+
+import math
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from ditl_tpu.models.presets import get_preset
+from ditl_tpu.ops import names
+from tests.tpu_compile import _instructions, _steps, over_tails
+
+@over_tails
+@pytest.mark.parametrize("h, kv, pages", [
+    (16, 16, 10 * 192),  # OLMoE: ONE query head a kv head
+    (28, 4, 12 * 720),  # Qwen2-7B: 7 a kv head
+    (32, 8, 4 * 512),  # Granite: 64-wide heads, stored on 128 lanes
+], ids=["olmoe-1b-7b-cut1", "qwen2-7b-cut1", "granite-4.0-h-micro"])
+def test_paged_decode_kernel_compiles_on_its_work_list_at_the_cells_shapes(
+        one_chip, tpu_branch, tail, h, kv, pages):
+    """``paged_attention`` as the serving cells run it: 64 slots, pages of
+    256 in all layers' pools addressed as one, 16 pages a slot, either tail,
+    the work list's rows / steps on the scalar-prefetch channel and its count
+    the length of the one-axis grid. The instruction keeps the kernel's name:
+    the readers and ``_scopes.py`` find it by that."""
+    from ditl_tpu.ops.paged_attention import paged_attention
+
+    b, hd, ps, maxp = 64, 128, 256, 16
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    args = (s((b, h, hd), jnp.bfloat16), s((pages, kv, ps, hd), jnp.bfloat16),
+            s((pages, kv, ps, hd), jnp.bfloat16), s((b, maxp), jnp.int32), s((b,), jnp.int32),
+            s((b, kv, tail, hd), jnp.bfloat16), s((b, kv, tail, hd), jnp.bfloat16),
+            s((b,), jnp.int32), s((b,), jnp.bool_))
+    compiled = jax.jit(
+        lambda q, kp, vp, tab, lens, tk, tv, st, alive: paged_attention(
+            q, kp, vp, tab, lens, tail_k=tk, tail_v=tv, starts=st,
+            steps=_steps(st, alive, ps, maxp), interpret=False)
+    ).lower(*args).compile()
+    assert names.KERNELS[3] == "paged_attention"
+    assert "paged_attention" in _instructions(compiled.as_text())
+
+
+@over_tails
+@pytest.mark.parametrize(
+    "preset, layers, pages",
+    [("qwen2-7b", 12, 720), ("olmoe-1b-7b", 10, 192)],
+    ids=["qwen2-7b-cut1", "olmoe-1b-7b-cut1"],
+)
+def test_paged_decode_layer_loop_copies_no_pool(one_chip, tpu_branch, preset, layers, pages,
+                                                tail):
+    """The cached layer loop of one paged decode step at the two serving
+    cells' shapes (64 slots, pages of 256, either tail). The kernel is a custom
+    call, so a pool that the loop slices by layer is COPIED in front of it
+    (``dynamic-slice_bitcast_fusion.8/.9``, 360 MiB of temporaries, before
+    PR 27). Whole pools addressed through the page table leave no
+    instruction of one layer's pool shape, a flattening that is a bitcast,
+    and temporaries far under one layer's pool."""
+    from ditl_tpu.models import llama
+
+    cfg = get_preset(preset, num_layers=layers, param_dtype="bfloat16")
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    params = jax.tree.map(
+        lambda a: s(a.shape, a.dtype),
+        jax.eval_shape(lambda: llama.init_params(jax.random.key(0), cfg)))
+    b, ps, maxp = 64, 256, 16
+    kv, hd = cfg.num_kv_heads, cfg.head_dim
+    pool = s((layers, pages, kv, ps, hd), jnp.bfloat16)
+    tails = s((layers, b, kv, tail, hd), jnp.bfloat16)
+    row = s((b,), jnp.int32)
+
+    def step(params, kp, vp, tk, tv, cur, pos, table, lengths, starts, t):
+        return llama.forward(
+            params, cur[:, None], cfg, positions=pos[:, None],
+            cache={"kp": kp, "vp": vp, "tk": tk, "tv": tv},
+            paged={"table": table, "lengths": lengths, "starts": starts, "t": t,
+                   "steps": _steps(starts, lengths > 0, ps, maxp)},
+            return_hidden=True)
+
+    compiled = jax.jit(step).lower(
+        params, pool, pool, tails, tails, row, row, s((b, maxp), jnp.int32),
+        row, row, s((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "paged_attention" in _instructions(text)
+
+    def producers(*dims):
+        shape = re.escape("bf16[" + ",".join(map(str, dims)) + "]")
+        return set(re.findall(r" = " + shape + r"\S* ([\w\-]+)\(", text))
+
+    assert not producers(pages, kv, ps, hd)
+    assert producers(layers * pages, kv, ps, hd) <= {"bitcast", "get-tuple-element"}
+    layer_pool_bytes = pages * kv * ps * hd * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_pool_bytes / 10
+
+
+@over_tails
+@pytest.mark.parametrize(
+    "layers, pages, kv",
+    [(12, 720, 4), (10, 192, 16)],
+    ids=["qwen2-7b-cut1", "olmoe-1b-7b-cut1"],
+)
+def test_paged_flush_copies_no_pool(one_chip, tpu_branch, layers, pages, kv, tail):
+    """The tick's flush of its tail into the donated page pools at the two
+    serving cells' shapes (64 slots, pages of 256, either tail, heads of 128). As
+    an XLA scatter with a window of ``(L, K, D)`` (``pool.at[:, pid, :,
+    off]``, before PR 29) it had the TPU compiler transpose each WHOLE pool
+    to another layout in front of the scatter and back behind it: four
+    pool-sized ``copy`` instructions, 2.11 GiB of temporaries. The
+    ``kv_flush`` kernel takes the pools as they are and gives them back
+    aliased: nothing produces an array of a pool's size but the custom call
+    itself."""
+    from ditl_tpu.infer.page_format import _flush_tail_into_pools
+
+    b, ps, maxp, hd = 64, 256, 16, 128
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    pool = s((layers, pages, kv, ps, hd), jnp.bfloat16)
+    tails = s((layers, b, kv, tail, hd), jnp.bfloat16)
+    row = s((b,), jnp.int32)
+    compiled = jax.jit(_flush_tail_into_pools, donate_argnums=(0,)).lower(
+        {"kp": pool, "vp": pool}, tails, tails, row, row,
+        s((b, maxp), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert names.CACHE_KERNELS[0] in _instructions(text)
+
+    pool_elements = layers * pages * kv * ps * hd
+    producers = set()
+    for dims, op in re.findall(r" = bf16\[([\d,]+)\]\S* ([\w\-]+)\(", text):
+        if math.prod(map(int, dims.split(","))) == pool_elements:
+            producers.add(op)
+    assert producers <= {"bitcast", "parameter", "get-tuple-element", "custom-call"}
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < pool_elements * 2 / 10
+    assert mem.alias_size_in_bytes == 2 * pool_elements * 2  # both pools in place
+
+
+@pytest.mark.parametrize("kernels", [("flash_fwd",), ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")],
+                         ids=["forward", "backward"])
+@pytest.mark.parametrize("b,h,kv,s,d", [(16, 14, 2, 2048, 64), (2, 28, 4, 4096, 128)],
+                         ids=["train-2k", "train-fsdp4-4k-a-chip"])
+def test_flash_kernels_compile_with_their_prefetch_operands(one_chip, kernels, b, h, kv, s, d):
+    """The three flash kernels as the trainer cells run them, on packed rows:
+    each takes the blocks' id ranges and the hull of its needed blocks as
+    scalar-prefetch operands and walks an inner grid axis whose bound is the
+    widest hull, a value of the call (``ops/flash_attention.py``): what
+    Mosaic makes of that, interpret mode cannot say. ``qwen2-0.5b.train-2k``'s batch of 16 rows, 14/2
+    heads of 64; ``qwen2-7b-cut4.train-fsdp4-4k``'s 2 rows a chip, 28/4 of 128."""
+    from ditl_tpu.ops.flash_attention import flash_attention
+
+    sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+
+    def attend(q, k, v, seg):
+        return flash_attention(q, k, v, causal=True, segment_ids=seg, interpret=False)
+
+    def loss(q, k, v, seg):
+        return jnp.sum(attend(q, k, v, seg).astype(jnp.float32))
+
+    fn = attend if len(kernels) == 1 else jax.grad(loss, argnums=(0, 1, 2))
+    compiled = jax.jit(fn).lower(
+        sd((b, s, h, d), jnp.bfloat16), sd((b, s, kv, d), jnp.bfloat16),
+        sd((b, s, kv, d), jnp.bfloat16), sd((b, s), jnp.int32)).compile()
+    # outside the trainer's scopes an instruction is named for its transform
+    # too (``jvp_flash_fwd_``, ``transpose_jvp_flash_bwd_dq__``)
+    calls = _instructions(compiled.as_text())
+    assert set(kernels) <= set(names.KERNELS)
+    assert all(any(k in call for call in calls) for k in kernels), calls
