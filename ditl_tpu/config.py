@@ -264,6 +264,21 @@ class ModelConfig:
     # "rope" rotates queries and keys; "nope" applies no positional term at
     # all (Granite-4.0-H: position comes from the recurrence alone).
     position_embedding: str = "rope"
+    # A stack with WINDOW attention layers (Trinity / afmoe: models/swa.py),
+    # selected by a ``w`` in ``layer_types``: a ``w`` layer's query i sees key j
+    # iff ``0 <= i - j < sliding_window`` (itself among them), an ``a`` layer's
+    # every ``j <= i``. ``position_embedding = "rope_window"`` rotates queries
+    # and keys in the ``w`` layers and applies no positional term in the ``a``
+    # layers. In that stack ``qk_norm`` is an RMSNorm over each HEAD's values
+    # (one weight of ``head_dim`` for the queries, one for the keys),
+    # ``attn_gate`` multiplies the attention output, before ``wo``, by the
+    # sigmoid of a projection of the sublayer's normed input (``wg``), and
+    # ``sandwich_norm`` norms each sublayer's OUTPUT too, before it joins the
+    # stream (four norms a layer). Its first ``first_k_dense_replace`` layers
+    # have the dense FFN, the others the expert layer with a held share.
+    sliding_window: int = 0
+    attn_gate: bool = False
+    sandwich_norm: bool = False
     # Granite's four scalars, each 1 (or 0 = the default) elsewhere: the
     # embedding's output times ``embedding_multiplier``; every sublayer's
     # output times ``residual_multiplier`` before it joins the stream;
@@ -380,6 +395,11 @@ class ModelConfig:
         return self.index_topk > 0
 
     @property
+    def window_layer(self) -> bool:
+        """Whether the stack has window attention layers (models/swa.py)."""
+        return "w" in self.layer_types
+
+    @property
     def layer_period(self) -> str:
         """One period of ``layer_types``: its shortest prefix that, repeated,
         gives the whole string ("" for a stack of identical layers)."""
@@ -387,19 +407,56 @@ class ModelConfig:
         return next((t[:n] for n in range(1, len(t) + 1)
                      if len(t) % n == 0 and t[:n] * (len(t) // n) == t), "")
 
+    def _check_window_stack(self) -> None:
+        """What a stack with window layers (models/swa.py) can run; everything
+        else is refused by name."""
+        if self.sliding_window <= 0:
+            raise ValueError(
+                "a window attention layer ('w' in layer_types) needs "
+                "sliding_window > 0")
+        if "m" in self.layer_types:
+            raise ValueError(
+                "layer_types mixes state-space ('m') and window ('w') layers: "
+                "models/swa.py carries attention layers only")
+        if not 0 <= self.first_k_dense_replace < self.num_layers:
+            raise ValueError(
+                f"first_k_dense_replace {self.first_k_dense_replace} must leave an "
+                f"expert layer among the {self.num_layers}")
+        if not (self.num_experts > 0 and self.experts_held_count > 0):
+            raise ValueError(
+                "a stack with window layers has the expert layer with a held share "
+                "behind its leading dense layers (num_experts, experts_held_count)")
+        for what, on in (("latent attention (kv_lora_rank)", self.kv_lora_rank > 0),
+                         ("LoRA (lora_rank)", self.lora_rank > 0),
+                         ("fused_qkv", self.fused_qkv),
+                         ("attention_bias", self.attention_bias),
+                         ("zero-compute experts (zero_expert_num)", self.zero_expert_num > 0),
+                         ("residual_dtype", bool(self.residual_dtype)),
+                         ("attention_multiplier / residual_multiplier",
+                          bool(self.attention_multiplier) or self.residual_multiplier != 1.0),
+                         ("attention_impl other than xla or flash",
+                          self.attention_impl not in ("xla", "flash")),
+                         ("tie_embeddings", self.tie_embeddings)):
+            if on:
+                raise ValueError(
+                    f"a stack with window layers (models/swa.py) does not carry {what}")
+
     def __post_init__(self):
         if self.layer_types:
-            if len(self.layer_types) != self.num_layers or set(self.layer_types) - {"m", "a"}:
+            if len(self.layer_types) != self.num_layers or set(self.layer_types) - {
+                    "m", "a", "w"}:
                 raise ValueError(
                     f"layer_types {self.layer_types!r} must be num_layers "
-                    f"({self.num_layers}) letters, each 'm' or 'a'")
+                    f"({self.num_layers}) letters, each 'm', 'a' or 'w'")
             if "m" in self.layer_types and not (
                     self.ssm_heads > 0 and self.ssm_head_dim > 0 and self.ssm_state > 0
                     and self.ssm_conv > 1 and self.ssm_chunk > 0):
                 raise ValueError(
                     "a state-space layer ('m' in layer_types) needs ssm_heads, "
                     "ssm_head_dim, ssm_state, ssm_conv and ssm_chunk set")
-            if self.num_experts > 0 or self.kv_lora_rank > 0 or self.lora_rank > 0 or (
+            if self.window_layer:
+                self._check_window_stack()
+            elif self.num_experts > 0 or self.kv_lora_rank > 0 or self.lora_rank > 0 or (
                     not self.fused_gate_up or self.fused_qkv):
                 raise ValueError(
                     "layer_types (models/ssm.py) carries dense layers with "
@@ -408,9 +465,18 @@ class ModelConfig:
         if self.residual_dtype and self.kv_lora_rank > 0:
             raise ValueError(
                 "residual_dtype is not carried by the double layer (models/mla.py)")
-        if self.position_embedding not in ("rope", "nope"):
+        if self.position_embedding not in ("rope", "nope", "rope_window"):
             raise ValueError(
-                f"unknown position_embedding {self.position_embedding!r} (rope|nope)")
+                f"unknown position_embedding {self.position_embedding!r} "
+                "(rope|nope|rope_window)")
+        if not self.window_layer and (
+                self.sliding_window or self.attn_gate or self.sandwich_norm
+                or self.position_embedding == "rope_window"):
+            raise ValueError(
+                "sliding_window, attn_gate, sandwich_norm and position_embedding="
+                "'rope_window' belong to a stack with window attention layers (a "
+                "'w' in layer_types, models/swa.py): every other block would "
+                "ignore them")
         # Reject-don't-drop: the MoE block has no fused gate|up layout, so
         # these flags would be silently ignored (an A/B would measure
         # byte-identical programs) — the same failure mode the dense-path
@@ -469,12 +535,13 @@ class ModelConfig:
             )
         if self.index_topk == 0 and (
             self.index_n_heads or self.index_head_dim or self.rope_yarn_factor
-            or self.first_k_dense_replace
+            or (self.first_k_dense_replace and not self.window_layer)
         ):
             raise ValueError(
-                "index_n_heads, index_head_dim, rope_yarn_* and "
-                "first_k_dense_replace belong to DeepSeek-V3.2's block "
-                "(index_topk > 0): every other block would ignore them"
+                "index_n_heads, index_head_dim and rope_yarn_* belong to "
+                "DeepSeek-V3.2's block (index_topk > 0), first_k_dense_replace to "
+                "it and to a stack with window layers: every other block would "
+                "ignore them"
             )
         if self.scoring_func not in ("softmax", "sigmoid"):
             raise ValueError(

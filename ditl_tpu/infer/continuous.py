@@ -362,6 +362,7 @@ class ContinuousEngine:
         thrash_window: int = 32,
         host_tier_mb: float = 0,
         spill_max_pages_per_tick: int = 32,
+        window_pages: int = 0,
         metrics: ServingMetrics | None = None,
         tracer: Tracer | None = None,
         flight: FlightRecorder | None = None,
@@ -545,7 +546,7 @@ class ContinuousEngine:
         self.n_pages = n_pages or (n_slots * self.maxp + 1)
         self.page_format = page_format(
             model_cfg, n_pages=self.n_pages, page_size=page_size, n_slots=n_slots,
-            decode_chunk=decode_chunk, mesh=mesh, rules=rules)
+            decode_chunk=decode_chunk, mesh=mesh, rules=rules, window_pages=window_pages)
         asked = {
             "contiguous": cache_mode != "paged",
             "speculative": speculative,
@@ -571,8 +572,6 @@ class ContinuousEngine:
                     f"prefill_chunk {prefill_chunk} must be a multiple of "
                     f"page_size {page_size} (chunk starts must be page-aligned)"
                 )
-            from ditl_tpu.infer.paged_cache import PageAllocator
-
             if mesh is not None:
                 # Allocate sharded-from-birth: materializing the full pool
                 # on one device first would OOM exactly the configurations
@@ -582,8 +581,8 @@ class ContinuousEngine:
                     out_shardings=self.page_format.shardings())()
             else:
                 self.cache = self.page_format.fresh()
-            self.allocator = PageAllocator(
-                self.n_pages, on_evict=self._on_pages_evicted,
+            self.allocator = self.page_format.allocator(
+                on_evict=self._on_pages_evicted,
                 # Chain collection costs O(group depth) inside alloc on
                 # the admission path — pay it only when something consumes
                 # the payload (host-tier spills, handoff-pid attribution).
@@ -635,6 +634,9 @@ class ContinuousEngine:
             self._table_dirty = True
             self._table_dev: Any = None
             self._slot_pages: list[list[int]] = [[] for _ in range(n_slots)]
+            # the logical pages each slot holds in a pool that keeps fewer
+            # than all of a row's pages (``PageAllocator.hold``)
+            self._slot_span: list[tuple[int, int]] = [(0, 0)] * n_slots
             self.limits = jnp.zeros((n_slots,), jnp.int32)
             if admission not in ("reserve", "optimistic"):
                 raise ValueError(
@@ -2321,6 +2323,8 @@ class ContinuousEngine:
             d = req.prefill_pos
             s = min(self.prefill_chunk, len(req.prompt) - d)
             slot_key, sub = jax.random.split(jax.random.key(req.seed))
+            if not self._hold_or_preempt(req.slot, d, d + s):
+                return 0
             first, bucket = self._paged_prefill_chunk(
                 req, req.slot, d, s, self.prefill_chunk, sub
             )
@@ -2415,7 +2419,54 @@ class ContinuousEngine:
 
     # -- paged admission / prefill -------------------------------------------
 
+    def _hold_pages(self, slot: int, start: int, end: int) -> None:
+        """Before a program over the slot's positions ``[start, end)``: the
+        allocator moves the slot's hold where a pool keeps only the pages a
+        row still attends to (``PageAllocator.hold``; MemoryError where that
+        pool cannot give the pages)."""
+        span = self.allocator.hold(
+            self._slot_pages[slot], self._slot_span[slot], start, end)
+        if span != self._slot_span[slot]:
+            self._slot_span[slot] = span
+            self._table_dirty = True
+
+    def _hold_or_preempt(self, slot: int, start: int, end: int) -> bool:
+        """``_hold_pages`` for a row that is already running: where the pool
+        is exhausted, preempt as ``_topup_pages`` does (the worst-ranked
+        other request first, the needy one itself last). False where the
+        slot's own request was preempted."""
+        req = self._slots[slot]
+        while True:
+            try:
+                self._hold_pages(slot, start, end)
+                return True
+            except MemoryError:
+                victim = self._pick_victim(req)
+                if victim is None:
+                    self._preempt_slot(slot)
+                    return False
+                self._preempt_slot(victim)
+
+    def _hold_decode_pages(self) -> None:
+        """Every decoding slot's hold, before a decode tick's dispatch: from
+        the position the host knows the row has reached (the device may be
+        ``lag`` ticks ahead: a lower bound, so nothing it still reads is let
+        go) to the last position the tick can write."""
+        if self.cache_mode != "paged" or not self.allocator.holds_spans:
+            return
+        adv = self._tick_advance_bound() * (2 if self.pipeline_ticks else 1)
+        for slot, req in enumerate(self._slots):
+            if req is None or req.prefilling or req.finished or req.cancelled:
+                continue
+            # (a first token sent early is in ``tokens`` before its step ran)
+            at = len(req.prompt) + len(req.tokens)
+            self._hold_or_preempt(
+                slot, max(at - 1, len(req.prompt)),
+                min(at + adv, len(req.prompt) + req.max_new_tokens))
+
     def _free_slot_pages(self, slot: int) -> None:
+        self._slot_span[slot] = self.allocator.hold(
+            self._slot_pages[slot], self._slot_span[slot], None)
         for pid in self._slot_pages[slot]:
             self.allocator.release(pid)
         self._slot_pages[slot] = []
@@ -2806,11 +2857,12 @@ class ContinuousEngine:
         pids[: min(len(write_pids), n_wp)] = write_pids[:n_wp]
         row = np.zeros((max(ctx, 1),), np.int32)
         row[: min(len(ctx_row), ctx)] = ctx_row[:ctx]
+        row_dev, pids_dev = self.page_format.prefill_tables(row, pids, d, ctx)
         out = program(
             self.params, self.cache,
-            jnp.asarray(row), jnp.asarray(ids), jnp.int32(d),
+            row_dev, jnp.asarray(ids), jnp.int32(d),
             jnp.int32(s), jnp.float32(temp), jnp.float32(top_p), rng,
-            jnp.asarray(pids), jnp.asarray([adapter], jnp.int32),
+            pids_dev, jnp.asarray([adapter], jnp.int32),
             self.page_format.slot_operand(slot),
             *self._fsm_args(fsm_start),
         )
@@ -2904,8 +2956,6 @@ class ContinuousEngine:
             for pid in matched:
                 self.allocator.release(pid)
             return False
-        self._queue.pop(0)
-        self._note_admitted(req)
         # Handoff attribution (ISSUE 13): matched pages installed by
         # import_kv count under the `handoff` tier label on their first
         # reuse — the counter the handoff drill pins reused == shipped on.
@@ -2915,19 +2965,26 @@ class ContinuousEngine:
             if hand:
                 self._handoff_pids.difference_update(hand)
                 handoff_tokens = len(hand) * ps
+        pages = matched + fresh
+        s = len(req.prompt) - d0
+        chunked = bool(self.prefill_chunk and s > self.prefill_chunk)
+        self._slot_pages[slot] = pages
+        try:  # a chunked prefill takes its hold chunk by chunk
+            self._hold_pages(slot, d0, d0 if chunked else len(req.prompt))
+        except MemoryError:
+            self._free_slot_pages(slot)
+            return False
+        self._queue.pop(0)
+        self._note_admitted(req)
         self._note_prefix_cache(req, d0, host_tokens=host_tokens,
                                 handoff_tokens=handoff_tokens)
-        pages = matched + fresh
-        self._slot_pages[slot] = pages
         self._table[slot, :] = 0
         self._table[slot, : len(pages)] = pages
         self._table_dirty = True
-        d0 = len(matched) * ps
         slot_key, sub = jax.random.split(jax.random.key(req.seed))
         req.slot = slot
         self._slots[slot] = req
-        s = len(req.prompt) - d0
-        if self.prefill_chunk and s > self.prefill_chunk:
+        if chunked:
             req.prefill_pos = d0
             req.prefilling = True
             self.cur = self.cur.at[slot].set(self.tokenizer.pad_id)
@@ -2982,6 +3039,11 @@ class ContinuousEngine:
             n_total = worst
         n_total = max(n_total, len(matched))
         try:
+            # the chunks below run back to back: every pool has to have room
+            # for them before the first one starts
+            if not self.allocator.room(
+                    min(self.prefill_chunk or pos, pos - len(matched) * ps)):
+                raise MemoryError("no room for the resume's chunks")
             fresh = self.allocator.alloc(n_total - len(matched))
         except MemoryError:
             for pid in matched:
@@ -3017,6 +3079,7 @@ class ContinuousEngine:
         padded = 0
         while d < pos:
             n = min(step, pos - d)
+            self._hold_pages(slot, d, d + n)
             padded += self._run_paged_prefill(
                 ctx[d: d + n], d, n, n,
                 ctx_row=self._table[slot],
@@ -3827,7 +3890,8 @@ class ContinuousEngine:
             # would race with a still-pending pipelined tick's device read
             # of this table — nondeterministic garbage gathers. The copy is
             # private to the device array; the host never touches it again.
-            self._table_dev = jnp.asarray(self._table.copy())
+            self._table_dev = self.page_format.device_table(
+                self._table.copy(), self._slot_span)
             self._table_dirty = False
         return self._table_dev
 
@@ -4369,6 +4433,7 @@ class ContinuousEngine:
                 prefill_s=round(prefill_s, 6),
             )
         self._topup_pages()  # optimistic paged admission; may preempt
+        self._hold_decode_pages()
         occupied = [r is not None and not r.prefilling for r in self._slots]
         rec = None
         if any(occupied):  # host-side check: no device sync on idle ticks

@@ -8,7 +8,10 @@ the format cannot carry (``refuse``), a prefill's row (``gather``, ``write``), a
 decode tick's tails and their one write into the pools (``tails0``, ``split``,
 ``flush``), and the tick's counters with what derives from them.
 
-Two page layouts and one component. ``KVPages``: keys and values a kv-head
+Three page layouts and one component. ``WindowKVPages`` (a stack with window
+attention layers, models/swa.py): TWO pools of keys and values, the full
+layers' and the window layers', page ids of their own; a row keeps every page
+of the first and, of the second, only those inside its window. ``KVPages``: keys and values a kv-head
 (bf16, or int8 with a scale a position) and, for a stack with state-space
 mixers (models/ssm.py), the mixers' STATE A SLOT beside them in the same
 donated tree. ``LatentPages``: one latent vector an attention sublayer
@@ -34,7 +37,8 @@ from ditl_tpu.utils.logging import get_logger
 
 logger = get_logger(__name__)
 
-__all__ = ["KVPages", "LatentPages", "MODES", "PageFormat", "page_format", "tail_width"]
+__all__ = ["KVPages", "LatentPages", "MODES", "PageFormat", "WindowKVPages", "page_format",
+           "tail_width"]
 
 
 def tail_width(decode_chunk: int) -> int:
@@ -155,7 +159,11 @@ class PageFormat:
     publishes = True
 
     def __init__(self, cfg: ModelConfig, *, n_pages: int, page_size: int, n_slots: int,
-                 decode_chunk: int, mesh=None, rules=None):
+                 decode_chunk: int, mesh=None, rules=None, window_pages: int = 0):
+        if window_pages and not cfg.window_layer:
+            raise ValueError(
+                "window_pages sizes the window layers' page pool: this model has "
+                "no window attention layer")
         self.cfg = cfg
         self.n_pages, self.page_size, self.n_slots = n_pages, page_size, n_slots
         self.tail_len = tail_width(decode_chunk)
@@ -197,6 +205,23 @@ class PageFormat:
         """What a decode step's ``paged`` metadata carries for this format beside
         the table and the kernels' work list; built once a program."""
         return {}
+
+    def allocator(self, **kw):
+        """The host's bookkeeping of this format's page ids."""
+        from ditl_tpu.infer.paged_cache import PageAllocator
+
+        return PageAllocator(self.n_pages, **kw)
+
+    def device_table(self, table, spans):
+        """The page table a decode program takes, from the host's ``table``
+        (slots, pages a row) and the spans the rows hold (``hold``)."""
+        return jnp.asarray(table)
+
+    def prefill_tables(self, row, pids, d: int, ctx_pages: int):
+        """A prefill program's ``table_row`` and ``write_pids`` operands, from
+        the row's first ``ctx_pages`` page ids and the pages the chunk at
+        position ``d`` writes."""
+        return jnp.asarray(row), jnp.asarray(pids)
 
 
 class KVPages(PageFormat):
@@ -544,7 +569,209 @@ class LatentPages(PageFormat):
         return out
 
 
+class WindowKVPages(PageFormat):
+    """Keys and values of a stack with window attention layers (models/swa.py)
+    in TWO pools: the full layers' ``kp`` / ``vp`` (full layers, P, K, ps, D)
+    and the window layers' ``wkp`` / ``wvp`` (window layers, P_win, K, ps, D),
+    page ids of their own (infer/paged_cache.py ``WindowedAllocator``: a
+    window page is the companion of the full page that holds the same tokens).
+
+    What the layout guarantees: (a) a live row holds at most ``ceil(window /
+    page_size) + 1`` window pages of context plus those of the chunk or tick
+    in flight; the pages behind go back as its position passes them, in
+    chunked prefill and in decode alike; (b) a published prefix keeps its
+    full pages whole and the window pages it had when it was published, and
+    a hit is granted at the longest length whose last window both pools hold;
+    (c) eviction, preemption and resume go through the same two calls
+    (``hold``, ``match_prefix``), so both pools' counts move together; (d) a
+    tick's tails are flushed into both pools through ``kv_flush``; (e) every
+    mode of ``MODES`` is refused by name: the layout carries plain paged
+    ticks of one process on one chip with the content cache, nothing else
+    yet."""
+
+    counters = ("window_pages_walked", "full_pages_walked")
+
+    def __init__(self, cfg: ModelConfig, **kw):
+        from ditl_tpu.models.swa import layer_kinds
+
+        super().__init__(cfg, **kw)
+        is_w, _ = layer_kinds(cfg)
+        self.win_layers = [i for i, w in enumerate(is_w) if w]
+        self.full_layers = [i for i, w in enumerate(is_w) if not w]
+        self.window = cfg.sliding_window
+        self.reach = -(-self.window // self.page_size)
+        # a row's span, and room for a prefix a document to stay cached: a rule
+        # where the server gives no size
+        self.window_pages = kw.get("window_pages") or min(
+            self.n_pages, self.n_slots * (self.reach + 6) + 1)
+        k, ps, d = cfg.num_kv_heads, self.page_size, cfg.head_dim
+        # a pool of no layers would have no page to name: one layer at least
+        self.shape = (max(1, len(self.full_layers)), self.n_pages, k, ps, d)
+        self.win_shape = (max(1, len(self.win_layers)), self.window_pages, k, ps, d)
+        per_layer = 2 * k * ps * d * self.dtype.itemsize
+        self.page_bytes = per_layer * self.shape[0]
+        self.window_page_bytes = per_layer * self.win_shape[0]
+        said = {**_OPTION, "pod": "pod serving", "handoff": "the disaggregated KV handoff",
+                "registered prefix": "register_prefix"}
+        self.refused = {
+            mode: f"a stack with window attention layers (layer_types="
+                  f"{cfg.layer_types!r}) keeps two page pools, which {option} cannot "
+                  "carry yet: serve it with cache_mode='paged', plain ticks, bfloat16 "
+                  "pages, no host tier, no mesh and no adapters, one process on one chip"
+            for mode, option in said.items()}
+        self.alloc = None
+        self._seen = (0, 0)
+
+    def allocator(self, **kw):
+        from ditl_tpu.infer.paged_cache import WindowedAllocator
+
+        self.alloc = WindowedAllocator(self.n_pages, self.window_pages, window=self.window,
+                                       page_size=self.page_size, **kw)
+        return self.alloc
+
+    def fresh(self) -> dict[str, jax.Array]:
+        return {"kp": jnp.zeros(self.shape, self.dtype), "vp": jnp.zeros(self.shape, self.dtype),
+                "wkp": jnp.zeros(self.win_shape, self.dtype),
+                "wvp": jnp.zeros(self.win_shape, self.dtype)}
+
+    def device_table(self, table, spans):
+        import numpy as np
+
+        return jnp.asarray(np.stack([table, self.alloc.window_table(table, spans)]))
+
+    def prefill_tables(self, row, pids, d: int, ctx_pages: int):
+        import numpy as np
+
+        # the window layers' context: the last pages in front of the chunk
+        wctx = min(ctx_pages, self.reach)
+        first = d // self.page_size - wctx
+        wrow = np.zeros((max(wctx, 1),), np.int32)
+        for j in range(wctx):
+            if 0 <= first + j < len(row):
+                wrow[j] = self.alloc.companion[row[first + j]]
+        return ((jnp.asarray(row), jnp.asarray(wrow)),
+                (jnp.asarray(pids), jnp.asarray(self.alloc.companion[pids])))
+
+    def gather(self, pools, table_row, ctx_pages: int, s_bucket: int, *, offset, slot=None):
+        """The context a prefill chunk attends over: every cached page for the
+        full layers, the last ``ceil(window / page_size)`` for the window
+        layers (``prefill_tables``); the chunk's own entries stay the forward
+        pass's."""
+        def to_row(pool, ids, n):
+            if n == 0:
+                return jnp.zeros((pool.shape[0], 1, 0, *pool.shape[2:3], pool.shape[-1]),
+                                 self.dtype)
+            # page by page, each a slice of the pool copied into its place:
+            # ONE gather of all the pages makes the compiler copy the whole
+            # pool first (``LatentPages.gather``: 2 GiB of temporaries here)
+            def put(j, g):
+                page = jax.lax.dynamic_slice(
+                    pool, (0, ids[j], 0, 0, 0), (pool.shape[0], 1, *pool.shape[2:]))
+                return jax.lax.dynamic_update_slice(g, page, (0, j, 0, 0, 0))
+
+            g = jax.lax.fori_loop(
+                0, n, put, jnp.zeros((pool.shape[0], n, *pool.shape[2:]), pool.dtype))
+            g = jnp.swapaxes(g, 2, 3)  # (L, n, ps, K, D)
+            return g.reshape(pool.shape[0], 1, n * self.page_size, *g.shape[3:])
+
+        row_f, row_w = table_row
+        wctx = min(ctx_pages, self.reach)
+        with jax.named_scope("kv_gather"):
+            return {"k": to_row(pools["kp"], row_f, ctx_pages),
+                    "v": to_row(pools["vp"], row_f, ctx_pages),
+                    "wk": to_row(pools["wkp"], row_w, wctx),
+                    "wv": to_row(pools["wvp"], row_w, wctx)}
+
+    @jax.named_scope("kv_write")
+    def write(self, pools, row, offset, write_pids, *, slot=None):
+        """The chunk's keys and values, every layer's (``row``: (L, 1, S, K,
+        D), what the forward pass returned), into the pages of both pools."""
+        import numpy as np
+
+        ps = self.page_size
+        out = dict(pools)
+        for names, layers, pids in ((("kp", "vp"), self.full_layers, write_pids[0]),
+                                    (("wkp", "wvp"), self.win_layers, write_pids[1])):
+            if not layers:
+                continue
+            n_wp = pids.shape[0]
+            for name, r in zip(names, (row["k"], row["v"])):
+                r = r[np.asarray(layers)][:, 0, :n_wp * ps]  # (layers, S, K, D)
+                chunk = jnp.swapaxes(r.reshape(len(layers), n_wp, ps, *r.shape[2:]), 2, 3)
+                for j in range(n_wp):
+                    out[name] = jax.lax.dynamic_update_slice(
+                        out[name], chunk[:, j:j + 1].astype(out[name].dtype),
+                        (0, pids[j], 0, 0, 0))
+        return out
+
+    def tails0(self, n_b: int, tail_len: int | None = None) -> dict[str, jax.Array]:
+        shape = (self.cfg.num_layers, n_b, self.cfg.num_kv_heads, tail_len or self.tail_len,
+                 self.cfg.head_dim)
+        return {"tk": jnp.zeros(shape, self.dtype), "tv": jnp.zeros(shape, self.dtype)}
+
+    def split(self, pools) -> tuple[dict, dict]:
+        return dict(pools), {}
+
+    @jax.named_scope("kv_write")
+    def flush(self, pools, carried, starts, pos, table) -> dict:
+        import numpy as np
+
+        from ditl_tpu.ops.kv_flush import kv_flush
+
+        out = dict(pools)
+        for (kn, vn), layers, tab in ((("kp", "vp"), self.full_layers, table[0]),
+                                      (("wkp", "wvp"), self.win_layers, table[1])):
+            if layers:
+                at = np.asarray(layers)
+                out[kn], out[vn] = kv_flush(
+                    pools[kn], pools[vn], carried["tk"][at].astype(pools[kn].dtype),
+                    carried["tv"][at].astype(pools[vn].dtype), tab, starts, pos)
+        return out
+
+    def tick_meta(self, starts, listed, table) -> dict:
+        from ditl_tpu.ops.paged_attention import decode_steps
+
+        with jax.named_scope("attn_core"), jax.named_scope("attn_steps"):
+            wsteps = decode_steps(starts, listed, page_size=self.page_size,
+                                  max_pages=table.shape[-1], window=self.window)
+            rows = listed.sum(dtype=jnp.int32)
+            full = jnp.where(listed, -(-starts // self.page_size), 0).sum(dtype=jnp.int32)
+        # each list's PAGE steps (the tail step a listed row is neither's)
+        return {"table": table[0], "wtable": table[1], "wsteps": wsteps,
+                "page_steps": (wsteps["count"] - rows, full)}
+
+    def count(self, acc, *, alive, lengths, starts, meta, counted):
+        win, full = meta["page_steps"]  # a call of each kernel walked them
+        return {**acc, "window_pages_walked": acc["window_pages_walked"] + win,
+                "full_pages_walked": acc["full_pages_walked"] + full}
+
+    def span_attrs(self, tick, decode_chunk):
+        al = self.alloc
+        was, self._seen = self._seen, (al.released, al.freed)
+        return {"window_pages_released": al.released - was[0],
+                "window_pages_freed": al.freed - was[1],
+                "window_pages_live": al.window_pages - 1 - al.n_window_free,
+                "window_pages_total": al.window_pages - 1}
+
+    def stats(self, totals, slots_seated):
+        al = self.alloc
+        return {"window_pages_total": al.window_pages - 1,
+                "window_pages_free": al.n_window_free,
+                "window_pages_cached_evictable": al.n_window_cached,
+                "window_pages_released_total": al.released,
+                "window_pages_freed_total": al.freed,
+                "window_pool_evictions": al.window_evictions,
+                "window_kv_bytes_per_token": self.window_page_bytes // self.page_size,
+                "prefix_hits_whole": al.hits_whole, "prefix_hits_short": al.hits_short,
+                "prefix_hits_refused": al.hits_refused,
+                "window_pages_walked_total": totals["window_pages_walked"],
+                "full_pages_walked_total": totals["full_pages_walked"]}
+
+
 def page_format(cfg: ModelConfig, **kw) -> PageFormat:
     """The format of ``cfg``'s cache entries (derived, never a setting): latent
-    pages where attention is latent, K/V pages otherwise."""
-    return (LatentPages if cfg.kv_lora_rank > 0 else KVPages)(cfg, **kw)
+    pages where attention is latent, two K/V pools where the stack has window
+    layers, K/V pages otherwise."""
+    if cfg.kv_lora_rank > 0:
+        return LatentPages(cfg, **kw)
+    return (WindowKVPages if cfg.window_layer else KVPages)(cfg, **kw)

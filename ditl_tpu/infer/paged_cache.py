@@ -36,7 +36,9 @@ from __future__ import annotations
 
 from collections import OrderedDict, deque
 
-__all__ = ["EvictedPage", "PageAllocator", "block_keys"]
+import numpy as np
+
+__all__ = ["EvictedPage", "PageAllocator", "WindowedAllocator", "block_keys"]
 
 PageKey = tuple[int, tuple[int, ...]]
 
@@ -270,6 +272,23 @@ class PageAllocator:
         if self._ref[pid] == 0:
             self._free.append(pid)
 
+    # whether ``hold`` does anything (the engine skips its walk over the
+    # slots, every tick, where it does not)
+    holds_spans = False
+
+    def hold(self, pages: list[int], span: tuple[int, int],
+             start: int | None, end: int = 0) -> tuple[int, int]:
+        """A row whose pages are ``pages`` (by logical index) is about to run
+        a program over positions ``[start, end)`` (``None``: it ends). One pool
+        keeps every page of a row until the row ends, so there is nothing to
+        do; ``WindowedAllocator`` moves the row's hold on its second pool."""
+        return span
+
+    def room(self, tokens: int) -> bool:
+        """Whether ``hold`` can follow a row through prefill chunks of
+        ``tokens`` tokens, whatever it holds now (one pool: always)."""
+        return True
+
     # -- content cache -------------------------------------------------------
 
     def lookup(self, key: PageKey) -> int | None:
@@ -342,3 +361,172 @@ class PageAllocator:
         for pid in pages:
             self.retain(pid)
         return pages
+
+
+class WindowedAllocator(PageAllocator):
+    """Two pools, page ids of their own, for a stack with window attention
+    layers (models/swa.py; infer/page_format.py ``WindowKVPages``).
+
+    The pool this class inherits is the FULL layers': a row keeps every page
+    of it, the content cache publishes and evicts them, all as before. The
+    WINDOW layers' pool hangs off it: a full page ``f`` may have a COMPANION
+    window page ``companion[f]`` that holds the same tokens' keys and values
+    in the window layers. A companion is held by the rows that still attend
+    to it and, if ``f`` is published and had one at that moment, by the
+    content cache; held by nobody it is free.
+
+    - A row holds the companions of a SPAN of its logical pages (``hold``):
+      from the page of the first position its next program can attend to,
+      ``start - (window - 1)``, to the last page that program writes. As the
+      row's position passes a page, ``hold`` gives that companion up
+      (``released``; ``freed`` where that emptied it). So a live row holds at
+      most ``ceil(window / page_size) + 1`` window pages of context plus
+      those of the chunk or tick in flight.
+    - Publishing a full page makes the cache a holder of the companion it has
+      then: at the end of a prefill or of an answer those are the pages
+      inside the last window, which is what a later hit can need.
+    - A hit of ``n`` pages is usable at length ``m <= n`` only where the
+      pages ``[m - reach, m)`` all have companions (``reach = ceil(window /
+      page_size)``): ``match_prefix`` grants the longest such ``m`` and gives
+      back the rest (``hits_whole`` / ``hits_short`` / ``hits_refused``).
+    - Under pressure ``hold`` evicts the least recently used companion that
+      only the cache holds (``window_evictions``); the full page stays
+      published, and a later hit over it is shortened or refused.
+    """
+
+    holds_spans = True
+
+    def __init__(self, n_pages: int, window_pages: int, *, window: int, page_size: int,
+                 **kw):
+        super().__init__(n_pages, **kw)
+        if window_pages < 2:
+            raise ValueError(f"need >= 2 window pages (page 0 is reserved), got {window_pages}")
+        self.window_pages, self.window, self.page_size = window_pages, window, page_size
+        self.reach = -(-window // page_size)
+        self.companion = np.zeros((n_pages,), np.int32)
+        self._wfree: deque[int] = deque(range(1, window_pages))
+        self._wref = [0] * window_pages
+        # full pages whose companion the content cache holds, least recent first
+        self._wcached: OrderedDict[int, None] = OrderedDict()
+        self.released = self.freed = self.window_evictions = 0
+        self.hits_whole = self.hits_short = self.hits_refused = 0
+
+    @property
+    def n_window_free(self) -> int:
+        return len(self._wfree)
+
+    @property
+    def n_window_cached(self) -> int:
+        """Companions only the content cache holds (reclaimable)."""
+        return sum(1 for f in list(self._wcached) if self._wref[self.companion[f]] == 1)
+
+    def _drop(self, f: int, *, by_row: bool) -> None:
+        w = int(self.companion[f])
+        self._wref[w] -= 1
+        if self._wref[w] < 0:
+            raise AssertionError(f"double release of window page {w}")
+        self.released += by_row
+        if self._wref[w] == 0:
+            self.companion[f] = 0
+            self._wfree.append(w)
+            self.freed += by_row
+
+    def _acquire(self, f: int) -> None:
+        w = int(self.companion[f])
+        if w:
+            self._wref[w] += 1
+            return
+        if self._wfree:
+            w = self._wfree.popleft()
+        else:
+            victim = next((g for g in self._wcached
+                           if self._wref[self.companion[g]] == 1), None)
+            if victim is None:
+                raise MemoryError(
+                    f"window page pool exhausted ({self.window_pages} pages, 0 evictable)")
+            del self._wcached[victim]
+            w = int(self.companion[victim])
+            self.companion[victim] = 0
+            self.window_evictions += 1
+        self.companion[f] = w
+        self._wref[w] = 1
+
+    def pages_of(self, start: int | None, end: int) -> tuple[int, int]:
+        """The logical pages a program over positions ``[start, end)`` reads or
+        writes in the window layers."""
+        if start is None:
+            return 0, 0
+        lo = max(start - (self.window - 1), 0) // self.page_size
+        return lo, max(lo, -(-end // self.page_size))
+
+    def room(self, tokens: int) -> bool:
+        need = self.reach + 2 + -(-tokens // self.page_size)
+        return len(self._wfree) + self.n_window_cached >= need
+
+    def hold(self, pages, span, start, end=0):
+        a, b = span
+        lo, hi = self.pages_of(start, end)
+        hi = min(hi, len(pages))
+        lo = min(lo, hi)
+        got: list[int] = []
+        try:
+            for i in range(lo, hi):
+                if not a <= i < b:
+                    self._acquire(pages[i])
+                    got.append(i)
+        except MemoryError:
+            for i in got:  # a failed move leaves the row's hold as it was
+                self._drop(pages[i], by_row=False)
+            raise
+        for i in range(a, b):
+            if not lo <= i < hi:
+                self._drop(pages[i], by_row=True)
+        return lo, hi
+
+    def alloc(self, n: int) -> list[int]:
+        out = super().alloc(n)
+        for pid in out:
+            if self.companion[pid]:
+                raise AssertionError(f"page {pid} recycled with a companion")
+        return out
+
+    def publish(self, key: PageKey, pid: int) -> None:
+        fresh = key not in self._key_to_page
+        super().publish(key, pid)
+        if fresh and self.companion[pid] and pid not in self._wcached:
+            self._wref[self.companion[pid]] += 1
+            self._wcached[pid] = None
+
+    def _unpublish(self, key: PageKey, pid: int, *, claimed: bool) -> None:
+        if pid in self._wcached:
+            del self._wcached[pid]
+            self._drop(pid, by_row=False)
+        super()._unpublish(key, pid, claimed=claimed)
+
+    def match_prefix(self, tokens, page_size, root=0):
+        pages = super().match_prefix(tokens, page_size, root)
+        n = len(pages)
+        m = n
+        while m > 0 and not all(self.companion[p] for p in pages[max(0, m - self.reach):m]):
+            m -= 1
+        for pid in pages[m:]:
+            self.release(pid)
+        if n:
+            if m == n:
+                self.hits_whole += 1
+            elif m:
+                self.hits_short += 1
+            else:
+                self.hits_refused += 1
+        for pid in pages[max(0, m - self.reach):m]:
+            if pid in self._wcached:
+                self._wcached.move_to_end(pid)
+        return pages[:m]
+
+    def window_table(self, table: np.ndarray, spans: list[tuple[int, int]]) -> np.ndarray:
+        """The window layers' page table: the companions of each row's held
+        span, the sentinel everywhere else."""
+        out = np.zeros_like(table)
+        for slot, (lo, hi) in enumerate(spans):
+            out[slot, lo:hi] = self.companion[table[slot, lo:hi]]
+        return out

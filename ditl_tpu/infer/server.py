@@ -2394,6 +2394,12 @@ def serve(argv: list[str] | None = None) -> int:
         "equivalent (slots x max context)",
     )
     parser.add_argument(
+        "--window-pages", type=int, default=0,
+        help="size of the WINDOW layers' page pool where the model has window "
+        "attention layers (two pools, page ids of their own: "
+        "infer/page_format.py WindowKVPages); 0 = slots x (pages a window + 6)",
+    )
+    parser.add_argument(
         "--quantize", choices=("none", "int8"), default="none",
         help="weight-only int8 (halves decode HBM reads; ops/quant.py)",
     )
@@ -2832,6 +2838,7 @@ def serve(argv: list[str] | None = None) -> int:
             token_budget=args.token_budget,
             host_tier_mb=args.host_tier_mb,
             spill_max_pages_per_tick=args.spill_max_pages_per_tick,
+            window_pages=args.window_pages,
             tracer=tracer,
             # Incident plane (ISSUE 10): shared metrics bundle + flight
             # recorder + detector monitor when --incident-dir armed them.

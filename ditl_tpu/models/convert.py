@@ -1,4 +1,4 @@
-"""HuggingFace checkpoint import: torch Llama/Qwen2/Mixtral/OLMoE/LongCat-Flash/Granite-4.0-H weights -> param pytree.
+"""HuggingFace checkpoint import: torch Llama/Qwen2/Mixtral/OLMoE/LongCat-Flash/Granite-4.0-H/Trinity weights -> param pytree.
 
 The reference never loads weights at all — its Llama-3.1-70B lives behind an
 HTTP API (ref ``src/distributed_inference.py:34-41``, ``MODEL_NAME`` in
@@ -272,6 +272,91 @@ def _hybrid_state_dict(layers, cfg: ModelConfig, host) -> dict[str, np.ndarray]:
     return sd
 
 
+# Trinity (``model_type: afmoe``; models/swa.py), as the family's public
+# modelling code names its tensors (recalled: this sandbox has no network, and
+# no checkpoint is fetched). A layer has four norms, ``self_attn`` with a
+# ``gate_proj`` beside q/k/v/o and a ``q_norm`` / ``k_norm`` a head, and
+# ``mlp``: the dense SwiGLU in the leading layers, else ``router.gate``,
+# ``expert_bias``, ``shared_experts`` and ``experts.{e}``. A share loads only
+# the experts it holds (``experts_held_first`` on).
+_AFMOE_NORMS = {"attn_norm": "input_layernorm", "attn_post_norm": "post_attention_layernorm",
+                "mlp_norm": "pre_mlp_layernorm", "mlp_post_norm": "post_mlp_layernorm"}
+_AFMOE_ATTN = {"wq": "q_proj", "wk": "k_proj", "wv": "v_proj", "wo": "o_proj", "wg": "gate_proj"}
+_AFMOE_MLP = {"w_gate": "gate_proj", "w_up": "up_proj", "w_down": "down_proj"}
+
+
+def _afmoe_layer_from_state_dict(sd, cfg: ModelConfig, i: int) -> dict[str, Any]:
+    from ditl_tpu.models.moe import held_experts
+
+    p = f"model.layers.{i}."
+    layer: dict[str, Any] = {
+        ours: {"scale": _np(sd[p + f"{theirs}.weight"])} for ours, theirs in _AFMOE_NORMS.items()}
+    layer["attn"] = {ours: _np(sd[p + f"self_attn.{theirs}.weight"]).T
+                     for ours, theirs in _AFMOE_ATTN.items()}
+    layer["attn"].update(q_norm=_np(sd[p + "self_attn.q_norm.weight"]),
+                         k_norm=_np(sd[p + "self_attn.k_norm.weight"]))
+    if i < cfg.first_k_dense_replace:
+        layer["mlp"] = {ours: _np(sd[p + f"mlp.{theirs}.weight"]).T
+                        for ours, theirs in _AFMOE_MLP.items()}
+        return layer
+    first, count = held_experts(cfg)
+    layer["moe"] = {
+        "router": _np(sd[p + "mlp.router.gate.weight"]).T,
+        "router_bias": _np(sd[p + "mlp.expert_bias"]).astype(np.float32),
+        "shared": {ours: _np(sd[p + f"mlp.shared_experts.{theirs}.weight"]).T
+                   for ours, theirs in _AFMOE_MLP.items()},
+        **{ours: np.stack([_np(sd[p + f"mlp.experts.{e}.{theirs}.weight"]).T
+                           for e in range(first, first + count)])
+           for ours, theirs in _AFMOE_MLP.items()},
+    }
+    return layer
+
+
+def _afmoe_from_state_dict(sd, cfg: ModelConfig, cast) -> dict[str, Any]:
+    import jax
+
+    def keep(path, x):  # the bias for the choice stays float32, like the scores
+        return x if path[-1].key == "router_bias" else cast(x)
+
+    n_dense = cfg.first_k_dense_replace
+    out = {}
+    for kind, layers in (("dense", range(n_dense)), ("sparse", range(n_dense, cfg.num_layers))):
+        if len(layers):
+            out[kind] = jax.tree_util.tree_map_with_path(
+                lambda path, *leaves: keep(path, np.stack(leaves)),
+                *[_afmoe_layer_from_state_dict(sd, cfg, i) for i in layers])
+    return out
+
+
+def _afmoe_state_dict(layers, cfg: ModelConfig, host) -> dict[str, np.ndarray]:
+    from ditl_tpu.models.moe import held_experts
+
+    first, count = held_experts(cfg)
+    sd: dict[str, np.ndarray] = {}
+    for i in range(cfg.num_layers):
+        dense = i < cfg.first_k_dense_replace
+        stack = layers["dense" if dense else "sparse"]
+        n, p = (i if dense else i - cfg.first_k_dense_replace), f"model.layers.{i}."
+        for ours, theirs in _AFMOE_NORMS.items():
+            sd[p + f"{theirs}.weight"] = host(stack[ours]["scale"][n])
+        for ours, theirs in _AFMOE_ATTN.items():
+            sd[p + f"self_attn.{theirs}.weight"] = host(stack["attn"][ours][n]).T
+        sd[p + "self_attn.q_norm.weight"] = host(stack["attn"]["q_norm"][n])
+        sd[p + "self_attn.k_norm.weight"] = host(stack["attn"]["k_norm"][n])
+        if dense:
+            for ours, theirs in _AFMOE_MLP.items():
+                sd[p + f"mlp.{theirs}.weight"] = host(stack["mlp"][ours][n]).T
+            continue
+        moe = stack["moe"]
+        sd[p + "mlp.router.gate.weight"] = host(moe["router"][n]).T
+        sd[p + "mlp.expert_bias"] = host(moe["router_bias"][n])
+        for ours, theirs in _AFMOE_MLP.items():
+            sd[p + f"mlp.shared_experts.{theirs}.weight"] = host(moe["shared"][ours][n]).T
+            for e in range(count):
+                sd[p + f"mlp.experts.{first + e}.{theirs}.weight"] = host(moe[ours][n, e]).T
+    return sd
+
+
 def params_from_state_dict(
     sd: Mapping[str, Any], cfg: ModelConfig, dtype: str | None = None
 ) -> dict[str, Any]:
@@ -287,6 +372,13 @@ def params_from_state_dict(
     def cast(x: np.ndarray) -> np.ndarray:
         return x.astype(pd)
 
+    if cfg.window_layer:  # Trinity (afmoe): a leading dense stack and an expert stack
+        return {
+            "embed": {"embedding": cast(_np(sd["model.embed_tokens.weight"]))},
+            "layers": _afmoe_from_state_dict(sd, cfg, cast),
+            "final_norm": {"scale": cast(_np(sd["model.norm.weight"]))},
+            "lm_head": {"kernel": cast(_np(sd["lm_head.weight"]).T)},
+        }
     if cfg.layer_types:  # Granite-4.0-H: a subtree a position of the period
         tree = {
             "embed": {"embedding": cast(_np(sd["model.embed_tokens.weight"]))},
@@ -422,6 +514,10 @@ def state_dict_from_params(params: Mapping[str, Any], cfg: ModelConfig) -> dict[
         "model.embed_tokens.weight": host(params["embed"]["embedding"]),
         "model.norm.weight": host(params["final_norm"]["scale"]),
     }
+    if cfg.window_layer:
+        sd.update(_afmoe_state_dict(layers, cfg, host))
+        sd["lm_head.weight"] = host(params["lm_head"]["kernel"]).T
+        return sd
     if cfg.layer_types:
         sd.update(_hybrid_state_dict(layers, cfg, host))
         if not cfg.tie_embeddings:

@@ -94,6 +94,19 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
             "lm_head": {"kernel": dense(next(keys), (d, cfg.vocab_size), d)},
         }
 
+    if cfg.window_layer:
+        # Trinity (models/swa.py): a leading dense stack and an expert stack
+        # of window and full attention layers, drawn in ``param_dtype``
+        from ditl_tpu.models.swa import init_swa_params
+
+        return {
+            "embed": {"embedding": (
+                jax.random.normal(next(keys), (cfg.vocab_size, d)) * 0.02).astype(pd)},
+            "layers": init_swa_params(next(keys), cfg),
+            "final_norm": {"scale": jnp.ones((d,), pd)},
+            "lm_head": {"kernel": dense(next(keys), (d, cfg.vocab_size), d)},
+        }
+
     if cfg.layer_types:
         # Granite-4.0-H (models/ssm.py): a subtree a position of the period
         from ditl_tpu.models.ssm import init_hybrid_params
@@ -190,6 +203,15 @@ def param_logical_axes(cfg: ModelConfig) -> Params:
         return {
             "embed": {"embedding": ("vocab", "embed")},
             "layers": dsa_logical_axes(cfg),
+            "final_norm": {"scale": ("norm",)},
+            "lm_head": {"kernel": ("embed", "vocab")},
+        }
+    if cfg.window_layer:
+        from ditl_tpu.models.swa import swa_logical_axes
+
+        return {
+            "embed": {"embedding": ("vocab", "embed")},
+            "layers": swa_logical_axes(cfg),
             "final_norm": {"scale": ("norm",)},
             "lm_head": {"kernel": ("embed", "vocab")},
         }
@@ -797,7 +819,7 @@ def forward(
         # LongCat-Flash: the same scans carry its double layer (models/mla.py)
         from ditl_tpu.models.mla import double_layer as block
     rec = None  # a hybrid stack's recurrent state, carried beside the stream
-    if cfg.layer_types:
+    if cfg.layer_types and not cfg.window_layer:
         # Granite-4.0-H: one scan step a PERIOD of unlike layers (models/ssm.py)
         from ditl_tpu.models.ssm import hybrid_period as block
         from ditl_tpu.models.ssm import period_counts
@@ -826,6 +848,19 @@ def forward(
             token_mask=token_mask)
         if not with_moe_counts:  # the experts' counts, then the tokens selected
             moe_counts = []
+    elif cfg.window_layer:
+        # Trinity: window and full attention layers in two stacks, each a scan
+        # of its own (models/swa.py), cached or not
+        from ditl_tpu.models.swa import stack
+
+        if adapter_ids is not None:
+            raise ValueError("LoRA adapters are not implemented for a stack with "
+                             "window layers")
+        x, layer_aux, new_cache, counts = stack(
+            params["layers"], x, cfg=cfg, positions=positions, segment_ids=segment_ids,
+            mesh=mesh, rules=rules, cache=cache, cache_index=cache_index, paged=paged,
+            token_mask=token_mask)
+        moe_counts = [counts] if with_moe_counts else []
     elif cache is not None:
         layers, moe_stack = params["layers"], None
         if "moe" in layers:

@@ -253,6 +253,44 @@ PRESETS: dict[str, ModelConfig] = {
         experts_held_first=0,
         experts_held_count=256,
     ),
+    # Trinity-Mini (arcee-ai/Trinity-Mini, ``model_type: afmoe``; the cut that
+    # is served is benchmarks/configs/trinity-mini-cut1.json); models/swa.py.
+    # Eight periods of three window-2,048 layers (rope) and one full layer
+    # (no positional term), gated attention, q/k norm a head, four norms a
+    # layer, two leading dense layers, 128 experts of 1,024 (8 a token, sigmoid
+    # scores renormalised and scaled, a bias for the choice, one shared
+    # expert), the embedding times sqrt(hidden) (``mup_enabled``).
+    "trinity-mini": ModelConfig(
+        name="trinity-mini",
+        vocab_size=200192,
+        hidden_size=2048,
+        intermediate_size=6144,
+        expert_ffn_hidden_size=1024,
+        num_layers=32,
+        num_heads=32,
+        num_kv_heads=4,
+        head_dim=128,
+        max_seq_len=131072,
+        rope_theta=10000.0,
+        rms_norm_eps=1e-5,
+        qk_norm=True,
+        layer_types="wwwa" * 8,
+        sliding_window=2048,
+        position_embedding="rope_window",
+        attn_gate=True,
+        sandwich_norm=True,
+        embedding_multiplier=2048 ** 0.5,
+        num_experts=128,
+        num_experts_per_tok=8,
+        norm_topk_prob=True,
+        routed_scaling_factor=2.826,
+        router_bias=True,
+        scoring_func="sigmoid",
+        n_shared_experts=1,
+        first_k_dense_replace=2,
+        experts_held_first=0,
+        experts_held_count=128,
+    ),
 }
 
 

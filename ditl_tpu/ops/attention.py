@@ -39,8 +39,10 @@ def _xla_attention(
     mask: jax.Array | None = None,
     k_scale: jax.Array | None = None,
     v_scale: jax.Array | None = None,
+    window: int | None = None,
 ) -> jax.Array:
-    """``k_scale``/``v_scale`` (B, Skv, K) mark k/v as int8-quantized
+    """``window`` (with ``causal``): query i sees only the keys ``i - j <
+    window``. ``k_scale``/``v_scale`` (B, Skv, K) mark k/v as int8-quantized
     (infer/cache.py). The scales are factored OUT of the dots: the score
     matmul consumes raw int8 K (the int8->bf16 convert fuses into the dot's
     operand read, so HBM traffic stays int8-sized) and the per-slot scale
@@ -63,6 +65,8 @@ def _xla_attention(
         scores = scores * jnp.moveaxis(k_scale, 1, 2)[:, :, None, None, :]
     if causal:
         causal_mask = jnp.tril(jnp.ones((s_q, s_kv), dtype=bool))
+        if window is not None:
+            causal_mask = causal_mask & ~jnp.tril(jnp.ones((s_q, s_kv), dtype=bool), -window)
         scores = jnp.where(causal_mask[None, None, None], scores, NEG_INF)
     if segment_ids is not None:
         seg_mask = segment_ids[:, :, None] == segment_ids[:, None, :]  # (B,Sq,Skv)
@@ -184,8 +188,11 @@ def dot_product_attention(
     k_scale: jax.Array | None = None,
     v_scale: jax.Array | None = None,
     block_sizes: tuple[int, int, int, int] | None = None,
+    window: int | None = None,
 ) -> jax.Array:
-    """Grouped-query attention. ``segment_ids`` (B, S) int32 restricts
+    """Grouped-query attention. ``window`` (static; the xla and flash paths,
+    causal, no explicit mask, no mesh): a window attention layer, query i sees
+    key j iff ``0 <= i - j < window``. ``segment_ids`` (B, S) int32 restricts
     attention to tokens of the same segment (sequence packing / padding:
     give pad tokens a segment id of -1-ish sentinel distinct from real ones).
     ``mask`` is an explicit (B, Sq, Skv) boolean mask (True = attend), used by
@@ -201,6 +208,11 @@ def dot_product_attention(
         raise ValueError(f"q heads {q.shape[2]} not divisible by kv heads {k.shape[2]}")
     if k_scale is not None and mask is None:
         raise ValueError("quantized K/V (k_scale/v_scale) require the mask path")
+    if window is not None and (mask is not None or not causal or mesh is not None
+                               or impl not in ("xla", "flash")):
+        raise ValueError(
+            "a window is a clause of the causal mask on the xla and flash paths, on "
+            "one chip: with an explicit mask put it into the mask")
     if mask is not None:
         # Explicit-mask (decode) path: bandwidth-bound, XLA fuses it fine; the
         # flash/ring kernels are for long training chunks, not 1-token queries.
@@ -229,7 +241,8 @@ def dot_product_attention(
             k_scale=k_scale, v_scale=v_scale,
         )
     if impl == "xla":
-        return _xla_attention(q, k, v, causal=causal, segment_ids=segment_ids)
+        return _xla_attention(q, k, v, causal=causal, segment_ids=segment_ids,
+                              window=window)
     if impl == "ring":
         from ditl_tpu.ops.ring_attention import ring_attention
 
@@ -270,11 +283,13 @@ def dot_product_attention(
                 f"D={q.shape[3]} (block_q={bq}, block_kv={bkv}, "
                 f"bwd {bqb or bq}/{bkvb or bkv})",
             )
-            return _xla_attention(q, k, v, causal=causal, segment_ids=segment_ids)
+            return _xla_attention(q, k, v, causal=causal, segment_ids=segment_ids,
+                                  window=window)
         if mesh is None:
             return fa.flash_attention(
                 q, k, v, causal=causal, segment_ids=segment_ids,
                 block_q=bq, block_kv=bkv, block_q_bwd=bqb, block_kv_bwd=bkvb,
+                window=window,
             )
         # Pallas calls carry no GSPMD partitioning rules — under pjit they
         # must be explicitly mapped over the mesh. Batch splits over the

@@ -128,8 +128,10 @@ def _block_mask(
     causal: bool,
     q_seg: jax.Array | None,
     kv_seg: jax.Array | None,
+    window: int | None = None,
 ) -> jax.Array:
-    """Apply causal + segment masking to a (block_q, block_kv) score tile."""
+    """Apply causal + segment (+ window) masking to a (block_q, block_kv)
+    score tile."""
     mask = None
     if causal:
         rows = iq * block_q + jax.lax.broadcasted_iota(
@@ -139,6 +141,8 @@ def _block_mask(
             jnp.int32, s.shape, dimension=1
         )
         mask = rows >= cols
+        if window is not None:  # a window layer: the last ``window`` keys only
+            mask = jnp.logical_and(mask, rows - cols < window)
     if q_seg is not None:
         # q_seg: (block_q, 128) lane-replicated; kv_seg: (8, block_kv)
         # sublane-replicated. Tile q over lanes, slice kv's first sublane row
@@ -165,21 +169,28 @@ def _block_ranges(seg: jax.Array, block: int) -> tuple[jax.Array, jax.Array]:
     return tiles.min(axis=-1), tiles.max(axis=-1)
 
 
-def _reachable(n_q: int, n_kv: int, blocks: BlockSizes, causal: bool) -> jax.Array:
-    """``(n_q, n_kv)`` bool: the blocks causality leaves (all, if not causal)."""
+def _reachable(n_q: int, n_kv: int, blocks: BlockSizes, causal: bool,
+               window: int | None = None) -> jax.Array:
+    """``(n_q, n_kv)`` bool: the blocks causality leaves (all, if not causal);
+    with a ``window``, of those the blocks that are not wholly behind it (the
+    block's first query is nearer than ``window`` to the block's last key)."""
     iq = jnp.arange(n_q, dtype=jnp.int32)[:, None]
     ikv = jnp.arange(n_kv, dtype=jnp.int32)[None, :]
     reach = (iq + 1) * blocks.block_q - 1 >= ikv * blocks.block_kv
+    if window is not None:
+        reach = jnp.logical_and(
+            reach, iq * blocks.block_q - ((ikv + 1) * blocks.block_kv - 1) < window)
     return reach if causal else jnp.ones_like(reach)
 
 
-def _needed_blocks(q_rng, kv_rng, blocks: BlockSizes, causal: bool) -> jax.Array:
+def _needed_blocks(q_rng, kv_rng, blocks: BlockSizes, causal: bool,
+                   window: int | None = None) -> jax.Array:
     """``(B, n_q, n_kv)`` bool, the kernels' block predicate laid out whole:
     causally reachable, and the two blocks' id ranges meet."""
     (q_lo, q_hi), (kv_lo, kv_hi) = q_rng, kv_rng
     meet = jnp.logical_and(q_lo[:, :, None] <= kv_hi[:, None, :],
                            kv_lo[:, None, :] <= q_hi[:, :, None])
-    reach = _reachable(q_lo.shape[1], kv_lo.shape[1], blocks, causal)
+    reach = _reachable(q_lo.shape[1], kv_lo.shape[1], blocks, causal, window)
     return jnp.logical_and(meet, reach[None])
 
 
@@ -198,6 +209,7 @@ def block_counts(
     causal: bool = True,
     block_q: int = 512,
     block_kv: int = 512,
+    window: int | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """``(reachable, needed)``: how many blocks of the forward kernel's grid
     causality leaves for this batch, a head, and how many of those the
@@ -207,13 +219,14 @@ def block_counts(
     blocks = _pick_blocks(s, s, block_q, block_kv)
     needed = _needed_blocks(
         _block_ranges(segment_ids, blocks.block_q),
-        _block_ranges(segment_ids, blocks.block_kv), blocks, causal)
+        _block_ranges(segment_ids, blocks.block_kv), blocks, causal, window)
     reach = _reachable(*needed.shape[1:], blocks, causal)
     return (segment_ids.shape[0] * jnp.sum(reach, dtype=jnp.int32),
             jnp.sum(needed, dtype=jnp.int32))
 
 
-def _skip_operands(q_seg, kv_seg, blocks: BlockSizes, causal: bool):
+def _skip_operands(q_seg, kv_seg, blocks: BlockSizes, causal: bool,
+                   window: int | None = None):
     """The kernels' scalar-prefetch operands, seven int32 arrays: the id
     ranges of the query blocks ``(B, n_q)`` and of the key blocks
     ``(B, n_kv)``, the hull ``[first, last]`` of each query block's needed
@@ -224,7 +237,7 @@ def _skip_operands(q_seg, kv_seg, blocks: BlockSizes, causal: bool):
     walks query blocks."""
     q_rng = _block_ranges(q_seg, blocks.block_q)
     kv_rng = _block_ranges(kv_seg, blocks.block_kv)
-    needed = _needed_blocks(q_rng, kv_rng, blocks, causal)
+    needed = _needed_blocks(q_rng, kv_rng, blocks, causal, window)
 
     def operands(needed):
         first, last = _hull(needed)
@@ -261,13 +274,15 @@ def _unfold(inner, n: int, skip):
 
 
 def _block_needed(ib, iq, ikv, inside, *, causal: bool, block_q: int,
-                  block_kv: int, skip):
+                  block_kv: int, skip, window: int | None = None):
     """The grid step's predicate. With causal masking, blocks strictly above
     the diagonal contribute nothing; with ``skip`` (SMEM refs,
     ``_skip_operands``), neither do blocks whose id ranges do not meet, nor
     a step outside its hull (``_walk``). Compute and both matmuls are
     skipped."""
     needed = (iq + 1) * block_q - 1 >= ikv * block_kv if causal else True
+    if window is not None and causal:  # a block wholly behind the window
+        needed = jnp.logical_and(needed, iq * block_q - ((ikv + 1) * block_kv - 1) < window)
     if skip is None:
         return needed
     q_lo, q_hi, kv_lo, kv_hi = skip[:4]
@@ -321,6 +336,7 @@ def _fwd_kernel(
     block_kv: int,
     n_kv: int,
     skip=None,
+    window: int | None = None,
 ):
     ib = pl.program_id(0)
     iq = pl.program_id(2)
@@ -334,7 +350,8 @@ def _fwd_kernel(
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     needed = _block_needed(ib, iq, ikv, inside, causal=causal, block_q=block_q,
-                           block_kv=block_kv, skip=skip)
+                           block_kv=block_kv, skip=skip,
+                           window=window)
 
     @pl.when(needed)
     def _compute():
@@ -355,6 +372,7 @@ def _fwd_kernel(
             causal=causal,
             q_seg=q_seg_ref[0] if q_seg_ref is not None else None,
             kv_seg=kv_seg_ref[0] if kv_seg_ref is not None else None,
+            window=window,
         )
 
         m_prev = m_scr[...]  # (block_q, 128) lane-replicated
@@ -397,14 +415,20 @@ def _fwd(
     scale: float,
     blocks: BlockSizes,
     interpret: bool,
+    window: int | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     b, h, s_q, d = q.shape
     _, kv_heads, s_kv, _ = k.shape
     groups = h // kv_heads
     bq, bkv = blocks
     n_q, n_kv = s_q // bq, s_kv // bkv
+    if window is not None and q_seg is None:
+        # the hull of a query block's needed key blocks rides the skip
+        # operands: one document a row gives the window's hull alone
+        q_seg = jnp.ones((b, s_q), jnp.int32)
+        kv_seg = jnp.ones((b, s_kv), jnp.int32)
     skip = (None if q_seg is None
-            else _skip_operands(q_seg, kv_seg, blocks, causal)[0])
+            else _skip_operands(q_seg, kv_seg, blocks, causal, window)[0])
     grid = (b, h, n_q, n_kv if skip is None else skip[6][0])
 
     def q_map(ib, ih, iq, step, *skip):
@@ -448,6 +472,7 @@ def _fwd(
         block_q=bq,
         block_kv=bkv,
         n_kv=n_kv,
+        window=window,
     )
     out_shapes = (
         jax.ShapeDtypeStruct((b, h, s_q, d), q.dtype),
@@ -822,6 +847,28 @@ def _flash_bhsd_bwd(causal, scale, blocks, blocks_bwd, interpret, residuals, do)
 _flash_bhsd.defvjp(_flash_bhsd_fwd, _flash_bhsd_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _flash_window(q, k, v, q_seg, kv_seg, window, scale, blocks, interpret):
+    """The forward kernel with a window clause (a serving prefill, a forward
+    pass that nobody differentiates)."""
+    return _fwd(q, k, v, q_seg, kv_seg, causal=True, scale=scale, blocks=blocks,
+                interpret=interpret, window=window)[0]
+
+
+def _flash_window_fwd(q, k, v, q_seg, kv_seg, window, scale, blocks, interpret):
+    return _flash_window(q, k, v, q_seg, kv_seg, window, scale, blocks, interpret), None
+
+
+def _flash_window_bwd(window, scale, blocks, interpret, residuals, do):
+    raise NotImplementedError(
+        "flash attention's backward kernels have no window clause "
+        "(ops/flash_attention.py): train a stack with window attention layers "
+        "under attention_impl='xla'")
+
+
+_flash_window.defvjp(_flash_window_fwd, _flash_window_bwd)
+
+
 def flash_attention(
     q: jax.Array,  # (B, S, H, D)
     k: jax.Array,  # (B, S, K, D)
@@ -834,8 +881,15 @@ def flash_attention(
     block_q_bwd: int = 0,
     block_kv_bwd: int = 0,
     interpret: bool | None = None,
+    window: int | None = None,
 ) -> jax.Array:
     """FlashAttention with GQA + sequence-packing segment masks.
+
+    ``window`` (static, causal only): query i sees key j iff ``0 <= i - j <
+    window``. A clause of the block predicate (a block wholly behind the
+    window is not visited: the hull of a query block's walk is about ``window
+    / block_kv + 1`` blocks whatever the sequence's length) and of the
+    in-block mask. Forward only: differentiating it raises.
 
     Takes/returns the model's (B, S, H, D) layout. Raises ``ValueError`` on
     shapes the kernel cannot tile. ``block_*_bwd`` size the backward kernels'
@@ -867,6 +921,12 @@ def flash_attention(
     qt = jnp.transpose(q, (0, 2, 1, 3))
     kt = jnp.transpose(k, (0, 2, 1, 3))
     vt = jnp.transpose(v, (0, 2, 1, 3))
+    if window is not None:
+        if not causal:
+            raise ValueError("a window is a clause of the causal mask")
+        o = _flash_window(qt, kt, vt, segment_ids, segment_ids, window, d**-0.5, blocks,
+                          interpret)
+        return jnp.transpose(o, (0, 2, 1, 3))
     o = _flash_bhsd(
         qt, kt, vt, segment_ids, segment_ids,
         causal, d**-0.5, blocks, blocks_bwd, interpret,

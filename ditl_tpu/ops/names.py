@@ -70,6 +70,14 @@ and ``mla_kv``, the writes of both entries ``kv_write``. ``moe_shared``
 (``MOE_SHARED_SCOPES``), inside ``mlp``: the shared expert's dense SwiGLU FFN
 (``models/moe.py``).
 
+``SWA_SCOPES``, inside ``attn_core``, tell a WINDOW attention layer's
+attention from a FULL layer's in a stack that has both (``models/swa.py``):
+``attn_window`` and ``attn_full`` hold the layer's attention itself (in a
+decode step the kernel ``paged_attention`` over that kind's page pool, walking
+that kind's work list; in a prefill the masked scores over that kind's
+context row) and the output gate's product. A reader that knows only
+``SCOPES`` / ``KERNELS`` books both to ``attn_core`` / ``paged_attention``.
+
 ``attn_steps`` (``ATTN_SCOPES``), inside ``attn_core``: the work list of the
 paged decode kernels (``ops/paged_attention.py`` ``decode_steps``), built
 once a decode program in front of its scan; a reader that knows only
@@ -120,6 +128,8 @@ SSM_SCOPES = (
 )
 
 ATTN_SCOPES = ("attn_steps",)
+
+SWA_SCOPES = ("attn_window", "attn_full")
 
 # The decode step's state update (``ops/ssd.py``), inside ``ssm_scan``.
 SSM_KERNELS = ("ssd_step",)

@@ -92,8 +92,12 @@ def paged_attention_xla(
     starts: jax.Array | None = None,  # (B,) — tokens resident in pages
     k_scale: jax.Array | None = None,  # (P, K, 1, ps) — int8 pool scales
     v_scale: jax.Array | None = None,
+    window: int | None = None,
 ) -> jax.Array:
     """Gather-based reference: correctness oracle + CPU fallback.
+
+    ``window``: the query at position ``lengths - 1`` sees only the
+    positions ``>= lengths - window`` (a window attention layer).
 
     With a tail (the deferred-flush decode path), tokens [0, starts) live
     in pages and [starts, lengths) in the tail buffer at columns
@@ -139,6 +143,13 @@ def paged_attention_xla(
             < (lengths[:, None] + qi[None, :])[:, :, None]
         )  # (B, Q, T)
         valid = jnp.concatenate([valid, tail_valid], axis=2)
+    if window is not None:
+        at = jnp.arange(maxp * ps, dtype=jnp.int32)[None, :]
+        if tail_k is not None:
+            at = jnp.concatenate(
+                [jnp.broadcast_to(at, (b, maxp * ps)),
+                 starts[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]], axis=1)
+        valid = valid & (at >= (lengths - window)[:, None])[:, None, :]
     qg = q.reshape(b, nq, kv_heads, groups, d)
     scores = jnp.einsum(
         "bqkgd,bskd->bqkgs", qg, k, preferred_element_type=jnp.float32
@@ -154,7 +165,7 @@ def paged_attention_xla(
 
 def _accumulate_block(
     q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, *,
-    scale, base, width, limit, ks_ref=None, vs_ref=None, q_groups=None,
+    scale, base, width, limit, ks_ref=None, vs_ref=None, q_groups=None, low=None,
 ):
     """Online-softmax accumulation of one (all-kv-heads) KV block whose
     columns are global positions [base, base+width), masked to < limit.
@@ -179,6 +190,8 @@ def _accumulate_block(
     else:
         qi = jax.lax.broadcasted_iota(jnp.int32, (groups, width), 0) // q_groups
         col_mask = cols < (limit + qi)
+    if low is not None:  # a window layer: nothing below the window's first position
+        col_mask = col_mask & (cols >= low)
     for kh in range(kv_heads):
         q = q_ref[0, kh].astype(jnp.float32) * scale  # (G, D)
         k = k_ref[0, kh].astype(jnp.float32)  # (width, D)
@@ -267,17 +280,32 @@ def _paged_kernel(
         _finalize_out(o_ref, m_scr, l_scr, acc_scr)
 
 
+def window_first_page(starts, window: int, page_size: int):
+    """The first page a window layer's row still attends to when its program
+    starts at position ``starts``: the page of position ``starts - (window -
+    1)``."""
+    return jnp.maximum(starts - (window - 1), 0) // page_size
+
+
 def decode_steps(
     starts: jax.Array,  # (B,) int32 — tokens resident in pages
     alive: jax.Array,  # (B,) bool — rows that may attend during the program
     *,
     page_size: int,
     max_pages: int,
+    window: int | None = None,
 ) -> dict[str, jax.Array]:
     """The work list of the decode kernels (module docstring): every step
     that exists, row by row. A row with ``alive`` has ``ceil(starts /
     page_size)`` page steps ``k = 0, 1, ...`` and then its tail-and-finalize
     step ``k = ceil(starts / page_size)``; a row without has none.
+
+    With a ``window`` (a window attention layer's list) a row's page steps are
+    only the pages that meet ``[starts - (window - 1), starts)``: its first
+    query of the program, at position ``starts``, sees nothing in front of
+    them, and no later one does. Step ``k`` is then the row's page
+    ``window_first_page + k`` (the kernel and its index maps add it), the first
+    of them masked inside below each step's own ``lengths - window``.
 
     ``rows`` / ``ks``: (B * (max_pages + 1),) int32, step ``i``'s row and its
     ``k``; ``count`` (): the steps that exist (entries past it name row 0,
@@ -286,7 +314,10 @@ def decode_steps(
     rows and one comparison a (step, row): build it once where ``starts``
     and ``alive`` are constants (a decode program: in front of its scan)."""
     b = starts.shape[0]
-    per_row = jnp.where(alive, pl.cdiv(starts, page_size) + 1, 0).astype(jnp.int32)
+    n_pages = pl.cdiv(starts, page_size)
+    if window is not None:
+        n_pages = n_pages - window_first_page(starts, window, page_size)
+    per_row = jnp.where(alive, n_pages + 1, 0).astype(jnp.int32)
     ends = jnp.cumsum(per_row)  # (B,) one past each row's last step
     i = jnp.arange(b * (max_pages + 1), dtype=jnp.int32)
     # the rows whose steps all lie in front of step i: its row's index
@@ -315,6 +346,7 @@ def _paged_tail_kernel(
     page_size: int,
     quantized: bool,
     q_groups: int | None = None,
+    window: int | None = None,
 ):
     """Deferred-flush variant: grid (n_steps,), step ``i`` of the work list
     (``decode_steps``) is ``(b, p) = (rows[i], ks[i])``. Steps ``p <
@@ -347,13 +379,26 @@ def _paged_tail_kernel(
     n_pages = pl.cdiv(start, page_size)  # the row's page steps; then its tail
     page_limit = jnp.minimum(start, length)
     base = p * page_size
+    low = None
+    if window is not None:
+        # a window layer's list starts at the row's first page inside the
+        # window (``decode_steps``)
+        first = window_first_page(start, window, page_size)
+        n_pages = n_pages - first
+        base = base + first * page_size
+        low = length - window
+    wanted = (p < n_pages) & (base < page_limit)
+    if window is not None:
+        # a page that has fallen wholly behind this step's window (the
+        # program's later steps) is skipped
+        wanted = wanted & (base + page_size > low)
 
-    @pl.when((p < n_pages) & (base < page_limit))
+    @pl.when(wanted)
     def _pages():
         _accumulate_block(
             q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr,
             scale=scale, base=base, width=page_size, limit=page_limit,
-            ks_ref=kscale_ref, vs_ref=vscale_ref,
+            ks_ref=kscale_ref, vs_ref=vscale_ref, low=low,
         )
 
     @pl.when((p == n_pages) & (length > start))
@@ -361,7 +406,7 @@ def _paged_tail_kernel(
         _accumulate_block(
             q_ref, tk_ref, tv_ref, m_scr, l_scr, acc_scr,
             scale=scale, base=start, width=tk_ref.shape[2], limit=length,
-            q_groups=q_groups,
+            q_groups=q_groups, low=low,
         )
 
     @pl.when(p == n_pages)
@@ -369,7 +414,7 @@ def _paged_tail_kernel(
         _finalize_out(o_ref, m_scr, l_scr, acc_scr)
 
 
-def walk_maps(page_size: int, max_pages: int, trailing: int):
+def walk_maps(page_size: int, max_pages: int, trailing: int, window: int | None = None):
     """The block index maps of a walk over ``decode_steps``' list, for
     operands with ``trailing`` dims behind the leading one: ``slot_map``
     names step ``i``'s ROW (q, tail, output), ``page_map`` its page (pools,
@@ -386,7 +431,10 @@ def walk_maps(page_size: int, max_pages: int, trailing: int):
     def page_map(i, rows, ks, tab, lens, st):
         b = rows[i]
         last = jnp.maximum(pl.cdiv(st[b], page_size) - 1, 0)
-        col = jnp.minimum(jnp.minimum(ks[i], last), max_pages - 1)
+        k = ks[i]
+        if window is not None:  # step k of a window layer's list: page first + k
+            k = k + window_first_page(st[b], window, page_size)
+        col = jnp.minimum(jnp.minimum(k, last), max_pages - 1)
         live = col * page_size < jnp.minimum(st[b], lens[b])
         return (jnp.where(live, tab[b, col], 0), *zeros)
 
@@ -425,6 +473,7 @@ def paged_attention(
     interpret: bool | None = None,
     mesh=None,
     rules=None,
+    window: int | None = None,  # a window attention layer (static)
 ) -> jax.Array:
     """Pallas paged GQA decode attention (see module docstring).
 
@@ -454,6 +503,10 @@ def paged_attention(
     multi-host paged serving replicates the batch like the pod protocols
     do)."""
     multi_q = q.ndim == 4
+    if window is not None and (multi_q or mesh is not None or tail_k is None):
+        raise ValueError(
+            "paged_attention with a window walks the tail path's work list, one "
+            "query a row, on one chip: no speculative verify, no mesh")
     if mesh is not None:
         from ditl_tpu.ops.attention import _mesh_axes_size
         from ditl_tpu.parallel.sharding import DEFAULT_RULES, logical_to_spec
@@ -586,8 +639,9 @@ def paged_attention(
 
     if has_tail:
         if steps is None:
-            steps = decode_steps(starts, lengths > 0, page_size=ps, max_pages=maxp)
-        slot_map, page_map = walk_maps(ps, maxp, trailing=3)
+            steps = decode_steps(starts, lengths > 0, page_size=ps, max_pages=maxp,
+                                 window=window)
+        slot_map, page_map = walk_maps(ps, maxp, trailing=3, window=window)
         quantized = k_scale is not None
         in_specs = [
             pl.BlockSpec((1, kv_heads, qg_rows, d), slot_map),
@@ -611,6 +665,7 @@ def paged_attention(
             functools.partial(
                 _paged_tail_kernel, scale=d**-0.5, page_size=ps,
                 quantized=quantized, q_groups=groups if nq > 1 else None,
+                window=window,
             ),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=5,

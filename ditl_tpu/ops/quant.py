@@ -30,7 +30,9 @@ _QUANT_KEYS = ("wq", "wk", "wv", "w_qkv", "wo", "w_gate", "w_up", "w_gu", "w_dow
                # float: its halves are folded into the query and the output
                "w_qa", "w_qb", "w_kva",
                # a state-space mixer's projections (models/ssm.py)
-               "w_in", "w_dt", "w_out")
+               "w_in", "w_dt", "w_out",
+               # the attention output gate's projection (models/swa.py)
+               "wg")
 
 
 def is_quantized_leaf(w: Any) -> bool:

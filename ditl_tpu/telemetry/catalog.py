@@ -279,6 +279,9 @@ _ROWS: tuple = (
     ("ditl_serving_prefix_cache_hit_tokens_host_total", "counter", "", "prompt tokens reused via the host tier (pages swapped back in from the host-RAM tier)"),
     ("ditl_serving_prefix_cache_hit_tokens_total", "counter", "", "prompt tokens whose KV was reused from the prefix cache at slot admission (paged content-hash match or registered prefix)"),
     ("ditl_serving_prefix_cache_miss_tokens_total", "counter", "", "prompt tokens the engine prefilled because no cached KV covered them"),
+    ("ditl_serving_prefix_hits_refused", "gauge", "", "prefix matches of the full layers' page pool that were granted at NO length because the window layers' pool no longer covered a last window below any of it (a model with window attention layers: two page pools; lifetime count from /v1/stats)", True),
+    ("ditl_serving_prefix_hits_short", "gauge", "", "prefix matches granted at a SHORTER length than the full layers' pool could give: the longest whose last window the window layers' pool still covers (lifetime count from /v1/stats)", True),
+    ("ditl_serving_prefix_hits_whole", "gauge", "", "prefix matches granted at the whole matched length, the full layers' pages and the window layers' last window both present (lifetime count from /v1/stats)", True),
     ("ditl_serving_queue_by_class_batch", "gauge", "", "queued batch-class requests"),
     ("ditl_serving_queue_by_class_best_effort", "gauge", "", "queued best_effort-class requests"),
     ("ditl_serving_queue_by_class_interactive", "gauge", "", "queued interactive-class requests"),
@@ -321,6 +324,15 @@ _ROWS: tuple = (
     ("ditl_serving_tpot_interference_interactive_seconds", "histogram", "", "per-tick decode delay absorbed by interactive-class victims because the tick also ran another request's prefill"),
     ("ditl_serving_tpot_interference_seconds", "histogram", "", "per-tick decode delay a victim request absorbed because the tick also ran another request's prefill chunk(s) — the scheduler-interference signal behind chunked-prefill tuning (ISSUE 6)"),
     ("ditl_serving_up", "gauge", "", "1 when the replica server is scraping"),
+    ("ditl_serving_window_kv_bytes_per_token", "gauge", "", "bytes one token's keys and values take in the window layers' page pool (a model with window attention layers; the full layers' are kv_bytes_per_token)", True),
+    ("ditl_serving_window_pages_cached_evictable", "gauge", "", "pages of the window layers' pool that only the content cache holds: the last windows of published prefixes, reclaimable", True),
+    ("ditl_serving_window_pages_free", "gauge", "", "free pages of the window layers' pool", True),
+    ("ditl_serving_window_pages_freed_total", "gauge", "", "window pages that went back to the free list because the row that held them moved past them (lifetime count from /v1/stats)", True),
+    ("ditl_serving_window_pages_released_total", "gauge", "", "references to window pages that rows gave up behind their window, in chunked prefill and in decode (a shared page stays while another row or the cache holds it; lifetime count from /v1/stats)", True),
+    ("ditl_serving_window_pages_total", "gauge", "", "size of the window layers' page pool (--window-pages less the sentinel)", True),
+    ("ditl_serving_window_pages_walked_total", "gauge", "", "page steps the window layers' decode work list held, summed over the decode ticks' steps (every window layer walks the list once a step; full_pages_walked_total is the full layers')", True),
+    ("ditl_serving_full_pages_walked_total", "gauge", "", "page steps the full layers' decode work list held, summed over the decode ticks' steps, in a model that also has window attention layers", True),
+    ("ditl_serving_window_pool_evictions", "gauge", "", "companions the window layers' pool reclaimed from the content cache under pressure (the full page stays published; a later hit over it is shortened or refused)", True),
     ("ditl_slo_availability_alerting", "gauge", "", "1 when every window burns availability's budget faster than 1.0x"),
     ("ditl_slo_availability_burn_rate_w<window>", "gauge", "window seconds", "availability burn rate over 300s (error rate / error budget)"),
     ("ditl_slo_e2e_alerting", "gauge", "", "1 when every window burns e2e's budget faster than 1.0x"),

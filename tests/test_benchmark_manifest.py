@@ -119,7 +119,8 @@ def test_the_deepseek_cell_is_a_closed_loop_listed_under_what_it_can_report(mani
     m = manifest_mod.load()
     cell = "deepseek-v3.2-cut1.docs-32k-dsa"
     listed = {x["name"] for x in manifest_mod.metrics_for(m, "per_layer", cell)}
-    mine = {x["name"] for x in m["per_layer"] if x.get("workloads") == [cell]}
+    # (``moe_shared_time_share_chat`` reads Trinity-Mini's cell too since PR 48)
+    mine = {x["name"] for x in m["per_layer"] if x.get("workloads", [None])[0] == cell}
     assert mine == {"dsa_time_share_chat", "dsa_select_time_share_chat",
                     "dsa_index_roofline_decode", "dsa_attn_roofline_decode",
                     "dsa_selected_share_chat", "moe_shared_time_share_chat"}
@@ -158,5 +159,44 @@ def test_the_deepseek_cell_is_a_closed_loop_listed_under_what_it_can_report(mani
     pages = int(args[args.index("--pages") + 1])
     assert pages >= 12 * 128 + 32 * 4  # every document and every row's own pages
     assert int(args[args.index("--max-cache-len") + 1]) >= 32768 + 256 + 512
+    assert os.path.exists(os.path.join(ROOT, args[args.index("--tokenizer") + 1],
+                                       "tokenizer.json"))
+
+
+def test_the_trinity_cell_is_listed_under_what_it_can_report(manifest_mod):
+    """``trinity-mini-cut1.docs-32k-swa`` (PR 48): its own six readers, every
+    reader ``deepseek-v3.2-cut1.docs-32k-dsa`` is under that reads no latent
+    attention and no indexer, and the K/V decode kernel's two (this
+    configuration runs ``paged_attention`` and walks ``decode_steps``' lists).
+    Eight cells on eight configurations, one of them on four chips."""
+    m = manifest_mod.load()
+    cell = "trinity-mini-cut1.docs-32k-swa"
+    assert len(m["workloads"]) == len(m["configs"]) == 8
+    assert sum(w["chips"] == 4 for w in m["workloads"]) == 1
+    listed = {x["name"] for x in manifest_mod.metrics_for(m, "per_layer", cell)}
+    mine = {x["name"] for x in m["per_layer"] if x.get("workloads") == [cell]}
+    assert mine == {"window_attn_time_share_chat", "full_attn_time_share_chat",
+                    "window_attn_roofline_decode", "full_attn_roofline_decode",
+                    "window_pages_walked_share_chat", "window_pool_live_share_chat"}
+    for name, why in {
+        "mla_attn_time_share_chat": "no latent attention", "mla_proj_time_share_chat": "same",
+        "dsa_time_share_chat": "no indexer", "moe_zero_assign_share_chat": "no zero experts",
+        "moe_held_roofline_decode": "reads LongCat's keys",
+        "moe_experts_roofline_decode": "reads OLMoE's keys",
+        "ttft_p50_ms": "counts from due", "ttft_client_p95_ms": "counts from due",
+        "generator_late_p95_ms": "a schedule to be late on",
+        "ssm_time_share_chat": "no state-space mixer",
+    }.items():
+        assert name not in listed, (name, why)
+    assert {"paged_attn_time_share_chat", "attn_steps_walked_share_chat", "moe_time_share_chat",
+            "moe_shared_time_share_chat", "moe_held_assign_share_chat", "prefix_hit_share_chat",
+            "preemptions_chat", "compiles_in_window_chat", "tpot_p95_ms"} <= listed
+    assert len(listed) == len(mine) + 29
+    assert {x["name"] for x in manifest_mod.metrics_for(m, "end_to_end", cell)} == {
+        "setup_s", "tpot_p50_ms"}
+    with open(manifest_mod.traffic_path("docs-32k-swa")) as f:
+        traffic = json.load(f)
+    args = traffic["server_args"]
+    assert args[args.index("--window-pages") + 1] == "384"
     assert os.path.exists(os.path.join(ROOT, args[args.index("--tokenizer") + 1],
                                        "tokenizer.json"))
