@@ -110,12 +110,12 @@ def supports(s_q: int, s_kv: int, head_dim: int, block_q: int = 512,
 
 
 def _lane_tile(x: jax.Array, width: int) -> jax.Array:
-    """Tile a lane-replicated (rows, 128) array to (rows, width)."""
+    """Tile a lane-replicated (..., rows, 128) array to (..., rows, width)."""
     if width == NUM_LANES:
         return x
     if width < NUM_LANES:
-        return x[:, :width]
-    return jnp.tile(x, (1, width // NUM_LANES))
+        return x[..., :width]
+    return jnp.tile(x, (1,) * (x.ndim - 1) + (width // NUM_LANES,))
 
 
 def _block_mask(

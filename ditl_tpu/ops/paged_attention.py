@@ -18,7 +18,13 @@ Two implementations, equal by construction (tested against each other):
   scalar-prefetch channel so each grid step's *block index map* fetches the
   right physical page from HBM — no gathered copy is ever materialized.
   Online softmax over pages (same lane-replicated row-stat scheme as
-  ops/flash_attention.py); one grid step consumes one page for ALL kv heads.
+  ops/flash_attention.py); one grid step consumes one page for ALL kv heads,
+  in ONE pass (``_accumulate_block``): a batched score dot and a batched
+  value dot over the kv heads, their operands in the dtype the pool stores
+  and their sums in float32. A bfloat16 x bfloat16 product is exact in
+  float32, so the dots add the products a float32 copy of the page would
+  give and the page is never copied; float32 operands (the CPU tests') run
+  the float32 arithmetic they always ran.
 
 The decode path (the tail kernel, and ``ops/mla_attention.py``'s) walks a
 WORK LIST, not a rectangle. A grid of every slot by every page-table position
@@ -170,70 +176,86 @@ def _accumulate_block(
     """Online-softmax accumulation of one (all-kv-heads) KV block whose
     columns are global positions [base, base+width), masked to < limit.
 
+    ALL kv heads in one pass: one batched score dot ``(K, G, D) x (K, width,
+    D) -> (K, G, width)``, one mask, one ``max`` / ``exp`` / ``sum`` over the
+    whole ``(K, G, width)`` score block, one batched value dot, and the three
+    scratch arrays (``(K, G, .)``, a kv head's rows on the second axis) read
+    and written whole once a step. A dot a kv head with its slice of the
+    scratch between them is a chain the next head's waits behind: 1.09 us a
+    page of 4 kv heads on a v5e where this pass takes 0.82 and the page's DMA
+    0.64 (PERF.md section 6, PR 49). Both dots take their operands in the
+    dtype they are STORED in and accumulate in float32
+    (``preferred_element_type``): the product of two bfloat16 values is exact
+    in float32, so a bf16 pool gets the sums a float32 copy of its page would
+    give, and no copy is made; ``head_dim ** -0.5`` multiplies the float32
+    scores behind the dot, as ``paged_attention_xla`` writes it. The
+    probabilities enter the value dot in the values' dtype.
+
     ``ks_ref``/``vs_ref`` ((1, K, 1, width) f32) mark the block as int8:
     the scales factor OUT of the dots — the score matmul consumes raw int8
-    K (HBM reads stay int8-sized) and the per-position scale multiplies the
-    (G, width) score row afterwards; V's scale folds into the
-    probabilities before the pv matmul. Lane-aligned broadcasts both
-    times (same scheme as the contiguous int8 cache, ops/attention.py).
+    K (HBM reads stay int8-sized; cast to q's dtype, exact for |x| <= 127)
+    and the per-position scale multiplies the (G, width) score rows
+    afterwards; V's scale folds into the probabilities before the pv matmul.
+    Lane-aligned broadcasts both times (same scheme as the contiguous int8
+    cache, ops/attention.py).
 
     ``q_groups`` (multi-query / speculative verify): the q block's rows are
     Q consecutive query tokens x ``q_groups`` GQA group members (row
     r = qi * q_groups + g), and row r's column limit is ``limit + qi`` —
     causal masking WITHIN the verify chunk at zero extra block traffic."""
-    kv_heads, groups = q_ref.shape[1], q_ref.shape[2]
+    groups = q_ref.shape[2]
     d = acc_scr.shape[-1]
     tile = _lane_tile  # shared lane-replication helper (ops/flash_attention)
-    cols = base + jax.lax.broadcasted_iota(jnp.int32, (groups, width), 1)
+    cols = base + jax.lax.broadcasted_iota(jnp.int32, (1, groups, width), 2)
     if q_groups is None:
         col_mask = cols < limit
     else:
-        qi = jax.lax.broadcasted_iota(jnp.int32, (groups, width), 0) // q_groups
+        qi = jax.lax.broadcasted_iota(jnp.int32, (1, groups, width), 1) // q_groups
         col_mask = cols < (limit + qi)
     if low is not None:  # a window layer: nothing below the window's first position
         col_mask = col_mask & (cols >= low)
-    for kh in range(kv_heads):
-        q = q_ref[0, kh].astype(jnp.float32) * scale  # (G, D)
-        k = k_ref[0, kh].astype(jnp.float32)  # (width, D)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # (G, width)
-        if ks_ref is not None:
-            s = s * ks_ref[0, kh]  # (1, width) broadcast over G sublanes
-        s = jnp.where(col_mask, s, NEG_INF)
-        rows = slice(kh * groups, (kh + 1) * groups)
-        m_prev = m_scr[rows]  # (G, NUM_LANES) lane-replicated
-        l_prev = l_scr[rows]
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_next = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_next)
-        ptab = jnp.exp(s - tile(m_next, width))
-        l_scr[rows] = alpha * l_prev + jnp.sum(ptab, axis=1, keepdims=True)
-        m_scr[rows] = m_next
-        v = v_ref[0, kh]  # (width, D)
-        if vs_ref is not None:
-            pv = jax.lax.dot_general(
-                ptab * vs_ref[0, kh], v.astype(jnp.float32),
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )  # (G, D)
-        else:
-            pv = jax.lax.dot_general(
-                ptab.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )  # (G, D)
-        acc_scr[rows] = acc_scr[rows] * tile(alpha, d) + pv
+    q = q_ref[0]  # (K, G, D)
+    k = k_ref[0]  # (K, width, D)
+    if ks_ref is not None:
+        k = k.astype(q.dtype)
+    s = jax.lax.dot_general(
+        q, k, (((2,), (2,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32,
+    ) * scale  # (K, G, width)
+    if ks_ref is not None:
+        s = s * ks_ref[0]  # (K, 1, width) broadcast over G sublanes
+    s = jnp.where(col_mask, s, NEG_INF)
+    m_prev = m_scr[...]  # (K, G, NUM_LANES) lane-replicated
+    m_next = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+    alpha = jnp.exp(m_prev - m_next)
+    ptab = jnp.exp(s - tile(m_next, width))
+    l_scr[...] = alpha * l_scr[...] + jnp.sum(ptab, axis=2, keepdims=True)
+    m_scr[...] = m_next
+    v = v_ref[0]  # (K, width, D)
+    if vs_ref is not None:
+        ptab, v = ptab * vs_ref[0], v.astype(jnp.float32)
+    pv = jax.lax.dot_general(
+        ptab.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32,
+    )  # (K, G, D)
+    acc_scr[...] = acc_scr[...] * tile(alpha, d) + pv
 
 
 def _finalize_out(o_ref, m_scr, l_scr, acc_scr):
-    kv_heads, groups = o_ref.shape[1], o_ref.shape[2]
-    d = acc_scr.shape[-1]
-    for kh in range(kv_heads):
-        rows = slice(kh * groups, (kh + 1) * groups)
-        l = l_scr[rows]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, kh] = (acc_scr[rows] / _lane_tile(l_safe, d)).astype(o_ref.dtype)
+    l = l_scr[...]
+    l_safe = jnp.where(l == 0.0, 1.0, l)
+    o_ref[0] = (acc_scr[...] / _lane_tile(l_safe, acc_scr.shape[-1])).astype(o_ref.dtype)
+
+
+def _scratch(kv_heads: int, rows: int, d: int) -> list:
+    """The online softmax's state over a row's steps: running max, sum
+    (lane-replicated) and the unnormalised output, a kv head's ``rows`` query
+    rows on the second axis."""
+    return [
+        pltpu.VMEM((kv_heads, rows, NUM_LANES), jnp.float32),  # m
+        pltpu.VMEM((kv_heads, rows, NUM_LANES), jnp.float32),  # l
+        pltpu.VMEM((kv_heads, rows, d), jnp.float32),  # acc
+    ]
 
 
 def _paged_kernel(
@@ -243,19 +265,18 @@ def _paged_kernel(
     k_ref,  # (1, K, ps, D)
     v_ref,
     o_ref,  # (1, K, G, D)
-    m_scr,  # (K*G padded, NUM_LANES)
+    m_scr,  # (K, G, NUM_LANES)
     l_scr,
-    acc_scr,  # (K*G padded, D)
+    acc_scr,  # (K, G, D)
     *,
     scale: float,
     page_size: int,
     n_pages: int,
 ):
-    """Grid (B, maxp): each step consumes one PAGE for ALL kv heads — the
-    kv-head loop is unrolled inside the kernel (static K small dots) so the
-    grid stays small; per-(b, h, page) grids are latency-bound at ~2k tiny
-    steps on v5e. Row r = k*G + g of the stats/acc scratch belongs to
-    (kv head k, group member g)."""
+    """Grid (B, maxp): each step consumes one PAGE for ALL kv heads, batched
+    inside the step's two dots, so the grid stays small; per-(b, h, page)
+    grids are latency-bound at ~2k tiny steps on v5e. Row ``[k, g]`` of the
+    stats/acc scratch belongs to (kv head k, group member g)."""
     b = pl.program_id(0)
     p = pl.program_id(1)
 
@@ -623,13 +644,8 @@ def paged_attention(
         .reshape(b, kv_heads, nq * groups, d)
     )
     qg_rows = nq * groups
-    g_rows = max(kv_heads * qg_rows, 8)  # scratch sublane floor
     has_tail = tail_k is not None
-    scratch = [
-        pltpu.VMEM((g_rows, NUM_LANES), jnp.float32),  # m
-        pltpu.VMEM((g_rows, NUM_LANES), jnp.float32),  # l
-        pltpu.VMEM((g_rows, d), jnp.float32),  # acc
-    ]
+    scratch = _scratch(kv_heads, qg_rows, d)
     out_shape = jax.ShapeDtypeStruct((b, kv_heads, qg_rows, d), q.dtype)
 
     def out_4d(o):

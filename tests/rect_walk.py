@@ -78,7 +78,6 @@ def paged_attention_rect(q, k_pages, v_pages, page_table, lengths, *, tail_k, ta
     qg_rows = nq * groups
     qg = (q.reshape(b, nq, kv_heads, groups, d).transpose(0, 2, 1, 3, 4)
           .reshape(b, kv_heads, qg_rows, d))
-    g_rows = max(kv_heads * qg_rows, 8)
     slot_map, page_map = _maps(maxp, ps, 3)
     quantized = k_scale is not None
     page = pl.BlockSpec((1, kv_heads, ps, d), page_map)
@@ -94,9 +93,7 @@ def paged_attention_rect(q, k_pages, v_pages, page_table, lengths, *, tail_k, ta
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(b, maxp + 1), in_specs=in_specs,
             out_specs=pl.BlockSpec((1, kv_heads, qg_rows, d), slot_map),
-            scratch_shapes=[pltpu.VMEM((g_rows, NUM_LANES), jnp.float32),
-                            pltpu.VMEM((g_rows, NUM_LANES), jnp.float32),
-                            pltpu.VMEM((g_rows, d), jnp.float32)]),
+            scratch_shapes=pa._scratch(kv_heads, qg_rows, d)),
         out_shape=jax.ShapeDtypeStruct((b, kv_heads, qg_rows, d), q.dtype),
         interpret=True,
     )(*args, tail_k, tail_v)

@@ -21,6 +21,7 @@ from ditl_tpu.infer.engine import GenerateConfig, Generator
 from ditl_tpu.infer.paged_cache import PageAllocator, block_keys
 from ditl_tpu.models import llama
 from tests import rect_walk
+from tests.tpu_compile import _eqns
 
 pytestmark = pytest.mark.pallas
 
@@ -559,14 +560,7 @@ def test_paged_attention_multi_query_requires_tail():
 
 def _scan_eqns(jaxpr):
     """Every ``scan`` equation of a jaxpr, nested ones included."""
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "scan":
-            yield eqn
-        for val in eqn.params.values():
-            for sub in val if isinstance(val, (tuple, list)) else (val,):
-                sub = getattr(sub, "jaxpr", sub)  # ClosedJaxpr -> Jaxpr
-                if hasattr(sub, "eqns"):
-                    yield from _scan_eqns(sub)
+    return (eqn for eqn in _eqns(jaxpr) if eqn.primitive.name == "scan")
 
 
 @pytest.mark.parametrize("kind", ["plain", "int8", "speculative"])

@@ -16,36 +16,51 @@ import pytest
 
 from ditl_tpu.models.presets import get_preset
 from ditl_tpu.ops import names
-from tests.tpu_compile import _instructions, _steps, over_tails
+from tests.tpu_compile import _eqns, _instructions, _steps, over_tails
 
 @over_tails
-@pytest.mark.parametrize("h, kv, pages", [
-    (16, 16, 10 * 192),  # OLMoE: ONE query head a kv head
-    (28, 4, 12 * 720),  # Qwen2-7B: 7 a kv head
-    (32, 8, 4 * 512),  # Granite: 64-wide heads, stored on 128 lanes
-], ids=["olmoe-1b-7b-cut1", "qwen2-7b-cut1", "granite-4.0-h-micro"])
+@pytest.mark.parametrize("h, kv, pages, b, maxp, window", [
+    (16, 16, 10 * 192, 64, 16, None),  # OLMoE: ONE query head a kv head
+    (28, 4, 12 * 720, 64, 16, None),  # Qwen2-7B: 7 a kv head
+    (32, 8, 4 * 512, 64, 16, None),  # Granite: 64-wide heads, stored on 128 lanes
+    (32, 4, 4 * 2048, 32, 132, None),  # Trinity-Mini's full layers: 8 a kv head
+    (32, 4, 12 * 384, 32, 132, 2048),  # and its window layers' pool and list
+], ids=["olmoe-1b-7b-cut1", "qwen2-7b-cut1", "granite-4.0-h-micro", "trinity-mini-cut1-full",
+        "trinity-mini-cut1-window"])
 def test_paged_decode_kernel_compiles_on_its_work_list_at_the_cells_shapes(
-        one_chip, tpu_branch, tail, h, kv, pages):
-    """``paged_attention`` as the serving cells run it: 64 slots, pages of
-    256 in all layers' pools addressed as one, 16 pages a slot, either tail,
-    the work list's rows / steps on the scalar-prefetch channel and its count
-    the length of the one-axis grid. The instruction keeps the kernel's name:
-    the readers and ``_scopes.py`` find it by that."""
+        one_chip, tpu_branch, tail, h, kv, pages, b, maxp, window):
+    """``paged_attention`` as the serving cells run it: 64 slots and 16 pages
+    a slot (the closed loop over 32k documents: 32 and 132), pages of 256 in
+    all layers' pools addressed as one, either tail, the work list's rows /
+    steps on the scalar-prefetch channel and its count the length of the
+    one-axis grid. The instruction keeps the kernel's name: the readers and
+    ``_scopes.py`` find it by that. Inside the kernel every dot takes its
+    operands as the pool stores them, bfloat16, and gives float32: no page is
+    converted to float32 in front of the score dot (PR 49)."""
     from ditl_tpu.ops.paged_attention import paged_attention
 
-    b, hd, ps, maxp = 64, 128, 256, 16
+    hd, ps = 128, 256
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
     args = (s((b, h, hd), jnp.bfloat16), s((pages, kv, ps, hd), jnp.bfloat16),
             s((pages, kv, ps, hd), jnp.bfloat16), s((b, maxp), jnp.int32), s((b,), jnp.int32),
             s((b, kv, tail, hd), jnp.bfloat16), s((b, kv, tail, hd), jnp.bfloat16),
             s((b,), jnp.int32), s((b,), jnp.bool_))
-    compiled = jax.jit(
-        lambda q, kp, vp, tab, lens, tk, tv, st, alive: paged_attention(
-            q, kp, vp, tab, lens, tail_k=tk, tail_v=tv, starts=st,
-            steps=_steps(st, alive, ps, maxp), interpret=False)
-    ).lower(*args).compile()
+
+    def call(q, kp, vp, tab, lens, tk, tv, st, alive):
+        return paged_attention(
+            q, kp, vp, tab, lens, tail_k=tk, tail_v=tv, starts=st, window=window,
+            steps=_steps(st, alive, ps, maxp, window), interpret=False)
+
+    compiled = jax.jit(call).lower(*args).compile()
     assert names.KERNELS[3] == "paged_attention"
     assert "paged_attention" in _instructions(compiled.as_text())
+    kernel, = [e for e in _eqns(jax.make_jaxpr(call)(*args).jaxpr)
+               if e.primitive.name == "pallas_call"]
+    dots = [e for e in _eqns(kernel.params["jaxpr"]) if e.primitive.name == "dot_general"]
+    assert len(dots) == 4  # scores and values, of a page and of the tail
+    for dot in dots:
+        assert [v.aval.dtype for v in dot.invars] == [jnp.bfloat16] * 2
+        assert dot.outvars[0].aval.dtype == jnp.float32
 
 
 @over_tails

@@ -171,3 +171,57 @@ def test_a_window_is_refused_where_the_kernel_cannot_carry_it():
                         starts=starts, window=W)
     with pytest.raises(ValueError, match="no speculative verify, no mesh"):
         paged_attention(q4[:, 0], k_pages, v_pages, table, starts, window=W)
+
+
+# The four K/V serving cells' heads: kv heads, query heads a kv head, the lanes
+# a head is stored on and those it fills (Granite's 64-wide heads lie on 128).
+CELL_HEADS = {
+    "trinity-mini-cut1": (4, 8, 128, 128),
+    "qwen2-7b-cut1": (4, 7, 128, 128),
+    "olmoe-1b-7b-cut1": (16, 1, 128, 128),
+    "granite-4.0-h-micro": (8, 4, 128, 64),
+}
+
+
+@pytest.mark.parametrize("window", [None, 2048], ids=["full", "window-2048"])
+@pytest.mark.parametrize("cell", list(CELL_HEADS))
+def test_the_kernel_on_bfloat16_pools_at_the_cells_heads_is_the_gather_oracle(cell, window):
+    """What the serving cells hand the kernel: bfloat16 q, pools and tail, so
+    both dots take bfloat16 operands and accumulate in float32, against the
+    gather on the SAME bfloat16 operands. Rows: far across the window, inside
+    it, on a page edge, one that ended inside the program (listed, ``lengths``
+    0) and a dead one the list never names (both exactly zero).
+
+    Tolerance, from bfloat16's rounding of the OUTPUT and not from the
+    arithmetic in front of it: the products are exact in float32 on both
+    sides; the kernel rounds its probabilities to bfloat16 before the
+    division by their sum and the gather after it, so the two float32
+    results differ by a fraction of an output ulp and may round to
+    neighbouring bfloat16 values: an ulp is at most ``2 ** -7`` of the value.
+    The float32 cases above keep ``atol=2e-5``."""
+    kv, groups, d, real = CELL_HEADS[cell]
+    ps, maxp, tail, b = 128, 20, 8, 5
+    starts = jnp.asarray([2300, 300, 17 * ps, 1500, 900], jnp.int32)
+    listed = jnp.asarray([True, True, True, True, False])
+    lengths = jnp.asarray([2303, 301, 17 * ps + 5, 0, 0], jnp.int32)
+    table = jnp.asarray(np.random.default_rng(1).permutation(np.arange(1, 1 + b * maxp))
+                        .reshape(b, maxp), jnp.int32)
+    keys = jax.random.split(jax.random.key(49), 5)
+    lanes = (jnp.arange(d) < real).astype(jnp.bfloat16)  # zeros on the unfilled lanes
+    draw = lambda key, *shape: jax.random.normal(key, shape, jnp.bfloat16) * lanes  # noqa: E731
+    q = draw(keys[0], b, kv * groups, d)
+    k_pages, v_pages = draw(keys[1], 1 + b * maxp, kv, ps, d), draw(keys[2], 1 + b * maxp, kv, ps, d)
+    kw = dict(tail_k=draw(keys[3], b, kv, tail, d), tail_v=draw(keys[4], b, kv, tail, d),
+              starts=starts, window=window)
+    steps = decode_steps(starts, listed, page_size=ps, max_pages=maxp, window=window)
+    # 18 + 3 + 17 + 12 pages and four tails; a window of 2,048 drops the far rows' first page
+    assert int(steps["count"]) == (54 if window is None else 52)
+    want = np.asarray(paged_attention_xla(q, k_pages, v_pages, table, lengths, **kw)
+                      .astype(jnp.float32))
+    got = paged_attention(q, k_pages, v_pages, table, lengths, steps=steps, interpret=True, **kw)
+    assert got.dtype == jnp.bfloat16
+    got = np.asarray(got.astype(jnp.float32))
+    np.testing.assert_allclose(got, want, atol=2.0 ** -7 * np.abs(want).max(), rtol=0)
+    assert np.abs(want[:3]).max() > 0.1  # the live rows say something
+    assert not got[3:].any()  # ended inside the program; never listed
+    assert not got[..., real:].any()  # lanes no head fills stay zero

@@ -36,13 +36,25 @@ def _instructions(text: str) -> set[str]:
     return {m.split(".")[0] for m in re.findall(r"%([\w\-.]+) = [^\n]*custom-call", text)}
 
 
-def _steps(starts, alive, ps, maxp):
+def _steps(starts, alive, ps, maxp, window=None):
     """The kernels' work list, built in the compiled program as a decode
     program builds it (``decode_steps``: rows, ks and the traced count that
     is the grid's length)."""
     from ditl_tpu.ops.paged_attention import decode_steps
 
-    return decode_steps(starts, alive, page_size=ps, max_pages=maxp)
+    return decode_steps(starts, alive, page_size=ps, max_pages=maxp, window=window)
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, those of nested jaxprs included (a
+    ``pallas_call``'s kernel, the branches of a ``pl.when``)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for val in eqn.params.values():
+            for sub in val if isinstance(val, (tuple, list)) else (val,):
+                sub = getattr(sub, "jaxpr", sub)  # ClosedJaxpr -> Jaxpr
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
 
 
 def _total_bytes(compiled):
