@@ -373,7 +373,9 @@ class ModelConfig:
     proj_bwd_block_d: int = 0
     # Loss head: "naive" materializes (B, S, V) f32 logits; "fused" computes
     # the lm-head matmul + cross-entropy blockwise (ops/fused_ce.py) so peak
-    # logits memory is loss_block_tokens x V instead of B*S*V.
+    # logits memory is loss_block_tokens x V instead of B*S*V, and forms both
+    # gradients in the same pass over the blocks (a custom VJP: three head
+    # matmuls a block, one loop).
     loss_impl: str = "naive"
     loss_block_tokens: int = 1024
     # Pipeline parallelism (active when the mesh's "stage" axis > 1):

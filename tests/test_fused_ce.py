@@ -5,6 +5,7 @@ in value and in gradients — it is a memory-layout change, not a math change.
 """
 
 import dataclasses
+import functools
 
 import jax
 import jax.flatten_util
@@ -212,47 +213,52 @@ def test_loss_partition_follows_mesh_and_rules(devices8, axes, rules, want):
     assert loss_partition(None, None) is None
 
 
+@functools.partial(jax.jit, static_argnames=("block_tokens", "compute_dtype"))
+@jax.named_scope("loss")
+def fused_cross_entropy_before(x, head, targets, mask, *, block_tokens=1024,
+                               compute_dtype=jnp.bfloat16):
+    """The one-device op as it was before it had a VJP of its own, frozen here
+    as the yardstick: every block checkpointed, the gradient left to autodiff
+    (a forward loop and a backward loop, four head matmuls a block)."""
+    n, d = x.shape
+    block = min(block_tokens, n) if n > 0 else block_tokens
+    pad = (-n) % block
+    if pad:
+        x = jnp.pad(x, ((0, pad), (0, 0)))
+        targets = jnp.pad(targets, (0, pad))
+        mask = jnp.pad(mask, (0, pad))
+    nb = (n + pad) // block
+    xb = x.reshape(nb, block, d)
+    tb = targets.reshape(nb, block).astype(jnp.int32)
+    mb = mask.reshape(nb, block).astype(jnp.float32)
+
+    def block_nll(head, x_blk, t_blk, m_blk):
+        logits = jnp.einsum(
+            "td,dv->tv", x_blk.astype(compute_dtype), head.astype(compute_dtype),
+            preferred_element_type=jnp.float32,
+        )
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        target_logit = jnp.take_along_axis(logits, t_blk[:, None], axis=1)[:, 0]
+        return jnp.sum((lse - target_logit) * m_blk)
+
+    block_nll = jax.checkpoint(block_nll)
+
+    def scan_step(nll_sum, xs):
+        x_blk, t_blk, m_blk = xs
+        return nll_sum + block_nll(head, x_blk, t_blk, m_blk), None
+
+    nll_sum, _ = jax.lax.scan(
+        scan_step, jnp.zeros((), jnp.float32), (xb, tb, mb))
+    return nll_sum
+
+
 def test_k1_lowers_to_the_parents_jaxpr(devices8):
     """Where no mesh axis shards the head (no mesh, one device: train-2k) the
-    op is the program it was before it learned about meshes. ``before`` is
-    that function, frozen here as the reference."""
-    import functools
-
-    @functools.partial(jax.jit, static_argnames=("block_tokens", "compute_dtype"))
-    @jax.named_scope("loss")
-    def fused_cross_entropy_before(x, head, targets, mask, *, block_tokens=1024,
-                                   compute_dtype=jnp.bfloat16):
-        n, d = x.shape
-        block = min(block_tokens, n) if n > 0 else block_tokens
-        pad = (-n) % block
-        if pad:
-            x = jnp.pad(x, ((0, pad), (0, 0)))
-            targets = jnp.pad(targets, (0, pad))
-            mask = jnp.pad(mask, (0, pad))
-        nb = (n + pad) // block
-        xb = x.reshape(nb, block, d)
-        tb = targets.reshape(nb, block).astype(jnp.int32)
-        mb = mask.reshape(nb, block).astype(jnp.float32)
-
-        def block_nll(head, x_blk, t_blk, m_blk):
-            logits = jnp.einsum(
-                "td,dv->tv", x_blk.astype(compute_dtype), head.astype(compute_dtype),
-                preferred_element_type=jnp.float32,
-            )
-            lse = jax.nn.logsumexp(logits, axis=-1)
-            target_logit = jnp.take_along_axis(logits, t_blk[:, None], axis=1)[:, 0]
-            return jnp.sum((lse - target_logit) * m_blk)
-
-        block_nll = jax.checkpoint(block_nll)
-
-        def scan_step(nll_sum, xs):
-            x_blk, t_blk, m_blk = xs
-            return nll_sum + block_nll(head, x_blk, t_blk, m_blk), None
-
-        nll_sum, _ = jax.lax.scan(
-            scan_step, jnp.zeros((), jnp.float32), (xb, tb, mb))
-        return nll_sum
-
+    op knows nothing of meshes, its gradient is one loop of three head
+    matmuls a block where ``fused_cross_entropy_before`` takes two loops and
+    four, and called for its value it lowers to that function's program,
+    string for string (a jaxpr shows the VJP's wrapper, so the lowered module
+    is what is compared)."""
     from ditl_tpu.ops.fused_ce import loss_partition
 
     args = (jnp.zeros((48, 32), jnp.bfloat16), jnp.zeros((32, 256), jnp.float32),
@@ -263,12 +269,189 @@ def test_k1_lowers_to_the_parents_jaxpr(devices8):
                         argnums=(0, 1))
         return str(jax.make_jaxpr(grad)(*args[:2]))
 
-    before = jaxpr_of(fused_cross_entropy_before).replace(
-        "fused_cross_entropy_before", "fused_cross_entropy")
-    assert jaxpr_of(fused_cross_entropy) == before
+    def counts(jaxpr):
+        return jaxpr.count("dot_general["), jaxpr.count("scan[")
+
+    ours = jaxpr_of(fused_cross_entropy)
     assert jaxpr_of(fused_cross_entropy,
-                    partition=loss_partition(_mesh(data=1), None)) == before
-    assert "shard_map" not in before
+                    partition=loss_partition(_mesh(data=1), None)) == ours
+    assert "shard_map" not in ours
+    assert counts(ours) == (3, 1)
+    assert counts(jaxpr_of(fused_cross_entropy_before)) == (4, 2)
+
+    def lowered(fn):
+        return fn.lower(*args, block_tokens=32).as_text()
+
+    assert lowered(fused_cross_entropy) == lowered(
+        fused_cross_entropy_before).replace(
+            "fused_cross_entropy_before", "fused_cross_entropy")
+
+
+def _dense(x, head, targets, mask):
+    logits = x @ head
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    tl = jnp.take_along_axis(logits, targets[:, None], axis=1)[:, 0]
+    return jnp.sum((lse - tl) * mask)
+
+
+_COTANGENTS = {
+    "times-0.37": lambda nll, mask: nll * 0.37,
+    "over-n-tokens": lambda nll, mask: nll / jnp.maximum(mask.sum(), 1.0),
+}
+
+
+def _loss_inputs(seed, n, d, v, dead=None):
+    rng = np.random.default_rng(seed)
+    x = jnp.asarray(rng.normal(size=(n, d)), jnp.float32)
+    head = jnp.asarray(rng.normal(size=(d, v)) * 0.1, jnp.float32)
+    targets = jnp.asarray(rng.integers(0, v, size=(n,)), jnp.int32)
+    mask = (rng.random(n) > 0.25).astype(np.float32)
+    if dead is not None:
+        mask[dead] = 0.0
+    return x, head, targets, jnp.asarray(mask)
+
+
+@pytest.mark.parametrize("cotangent", list(_COTANGENTS))
+@pytest.mark.parametrize(
+    "n,dead", [(48, None), (96, slice(32, 64))], ids=["pads", "dead-block"])
+def test_gradients_under_a_cotangent_that_is_not_one(n, dead, cotangent):
+    """The backward rule only scales what the forward pass stored: value,
+    ``d_x`` and ``d_head`` against the dense formula and against the frozen
+    parent where the loss is scaled or divided by its token count, with an
+    ``N`` the 32-token block does not divide and with a block whose mask is
+    all zero. The value is the parent's to the bit, differentiated or not."""
+    x, head, targets, mask = _loss_inputs(5, n, 32, 256, dead)
+    scale = _COTANGENTS[cotangent]
+
+    def of(fn, **kw):
+        return jax.value_and_grad(
+            lambda x, h: scale(fn(x, h, targets, mask, **kw), mask),
+            argnums=(0, 1))(x, head)
+
+    kw = dict(block_tokens=32, compute_dtype=jnp.float32)
+    got = of(fused_cross_entropy, **kw)
+    before = of(fused_cross_entropy_before, **kw)
+    assert float(got[0]) == float(before[0])
+    assert float(got[0]) == float(
+        scale(fused_cross_entropy(x, head, targets, mask, **kw), mask))
+    if dead is not None:
+        assert not np.asarray(got[1][0])[dead].any()
+    for ref in (of(_dense), before):
+        for g, r in zip(jax.tree.leaves(got), jax.tree.leaves(ref)):
+            r = np.asarray(r)
+            np.testing.assert_allclose(
+                np.asarray(g), r, rtol=1e-5, atol=1e-5 * np.abs(r).max())
+
+
+def test_frozen_head_costs_no_more_dots_than_the_parent():
+    """A fine-tune that freezes the head differentiates ``x`` alone: the
+    gradient is the parent's, and the compiler drops the unused ``d_head``
+    from the loop, so the compiled program holds the parent's two dots a
+    block (the logits, ``d_x``) and no third."""
+    import re
+
+    x, head, targets, mask = _loss_inputs(6, 80, 32, 256)
+
+    def grad_of(fn):
+        return jax.jit(jax.grad(lambda x: fn(
+            x, head, targets, mask, block_tokens=32,
+            compute_dtype=jnp.float32) / 7.0))
+
+    def dots(fn):
+        return len(re.findall(r"= \S+ dot\(", fn.lower(x).compile().as_text()))
+
+    ours, before = grad_of(fused_cross_entropy), grad_of(fused_cross_entropy_before)
+    want = np.asarray(before(x))
+    np.testing.assert_allclose(np.asarray(ours(x)), want, rtol=1e-5,
+                               atol=1e-5 * np.abs(want).max())
+    assert dots(ours) == 2
+    assert dots(ours) <= dots(before)
+
+
+def test_micro_batches_through_multi_step_equal_one_step_over_their_union():
+    """The loss is a pure sum whose gradient the forward pass stores, under a
+    scan too: two micro-batches (``grad_accum_steps=2``) inside
+    ``make_multi_step``'s scan over steps give the loss, the gradient norm
+    and the parameters of plain steps over the whole batches."""
+    from ditl_tpu.config import MeshConfig, TrainConfig
+    from ditl_tpu.data.loader import make_global_batch
+    from ditl_tpu.runtime.mesh import build_mesh
+    from ditl_tpu.train.state import create_train_state
+    from ditl_tpu.train.step import make_multi_step, make_train_step
+
+    cfg = _cfg(loss_impl="fused", loss_block_tokens=32, num_layers=1)
+    mesh = build_mesh(MeshConfig(), devices=jax.devices()[:1])
+    rng = np.random.default_rng(7)
+    b, s, steps = 4, 33, 2
+
+    def host():
+        return {
+            "input_ids": rng.integers(3, 512, size=(b, s)).astype(np.int32),
+            # every micro-batch counts the same tokens, so the mean of the
+            # micro-batches' losses is the loss over their union
+            "loss_mask": np.ones((b, s), np.float32),
+            "segment_ids": np.ones((b, s), np.int32),
+            "positions": np.tile(np.arange(s, dtype=np.int32), (b, 1)),
+        }
+
+    batches = [make_global_batch(mesh, host()) for _ in range(steps)]
+    plain_cfg = TrainConfig(total_steps=4, warmup_steps=1)
+    state = create_train_state(jax.random.key(0), cfg, plain_cfg)
+    plain = make_train_step(cfg, plain_cfg, mesh, batches[0])
+    want = []
+    for gb in batches:
+        state, metrics = plain(state, gb)
+        want.append(metrics)
+
+    accum_cfg = TrainConfig(total_steps=4, warmup_steps=1, grad_accum_steps=2)
+    multi = make_multi_step(cfg, accum_cfg, mesh, batches[0], steps)
+    window = jax.tree.map(lambda *xs: jnp.stack(xs), *batches)
+    got_state, got = multi(
+        create_train_state(jax.random.key(0), cfg, accum_cfg), window)
+    for k in ("loss", "grad_norm"):
+        np.testing.assert_allclose(
+            np.asarray(got[k]), np.asarray([float(m[k]) for m in want]), rtol=2e-5)
+    for g, w in zip(jax.tree.leaves(got_state.params), jax.tree.leaves(state.params)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-4, atol=1e-6)
+
+
+@pytest.mark.parametrize("cotangent", list(_COTANGENTS))
+@pytest.mark.parametrize(
+    "axes", [dict(fsdp=4), dict(data=2, fsdp=2, tensor=2)],
+    ids=["fsdp4", "data2-fsdp2-tensor2"])
+def test_sharded_gradients_under_a_cotangent_that_is_not_one(devices8, axes,
+                                                             cotangent):
+    """The vocabulary-parallel forward pass stores ``d_x`` and ``d_head``
+    summed over the chips and laid out as ``x`` and the head arrived, so what
+    the cotangent is worth on a chip is no convention of a transpose: scaled
+    or divided by the token count, the sharded gradients are the dense
+    formula's (``fsdp=4``: tokens gathered, ``d_x`` reduce-scattered; with
+    ``data`` and ``tensor``: ``d_head`` summed over ``data``, ``d_x`` over
+    ``tensor``)."""
+    from jax.sharding import NamedSharding
+
+    from ditl_tpu.ops.fused_ce import loss_partition
+    from ditl_tpu.parallel.sharding import logical_to_spec
+
+    mesh = _mesh(**axes)
+    part = loss_partition(mesh, None)
+    x, head, targets, mask = _loss_inputs(8, 8 * 13, 32, 510)
+    scale = _COTANGENTS[cotangent]
+
+    def put(a, logical):
+        return jax.device_put(a, NamedSharding(mesh, logical_to_spec(logical)))
+
+    got = jax.jit(jax.value_and_grad(
+        lambda x, h: scale(fused_cross_entropy(
+            x, h, targets, mask, block_tokens=32, compute_dtype=jnp.float32,
+            partition=part), mask), argnums=(0, 1)))(
+        put(x, ("batch", None)), put(head, ("embed", "vocab")))
+    ref = jax.value_and_grad(
+        lambda x, h: scale(_dense(x, h, targets, mask), mask), argnums=(0, 1))(x, head)
+    for g, r in zip(jax.tree.leaves(got), jax.tree.leaves(ref)):
+        r = np.asarray(r)
+        np.testing.assert_allclose(
+            np.asarray(g), r, rtol=1e-5, atol=1e-5 * np.abs(r).max())
 
 
 def _collectives(hlo_text):
