@@ -149,7 +149,9 @@ def _flash_case(with_segments: bool, heads) -> dict:
 
 
 def _paged_case(form: str, heads) -> dict:
-    """Paged decode attention (``plain`` | ``tail`` | ``tail_int8``)
+    """Paged decode attention (``plain`` | ``tail`` | ``tail_int8`` |
+    ``tail_ragged``: rows of 1 to 8 pages, odd and even counts, so that a
+    walk of several pages a step meets whole and ragged last groups)
     against ``paged_attention_xla``."""
     import jax
     import jax.numpy as jnp
@@ -177,6 +179,8 @@ def _paged_case(form: str, heads) -> dict:
         # anywhere); [starts, lengths) sit in the tail. A dead slot, an
         # empty tail, a full tail, a tail that ends the context.
         starts = [0, 0, 5, 256, 300, 1000, 2000, 2032]
+        if form == "tail_ragged":
+            starts = [0, 256, 300, 700, 1024, 1100, 1700, 2032]
         lengths = [s + n for s, n in zip(starts, [0, 3, 16, 0, 16, 7, 16, 16])]
         tk = jax.random.normal(keys[1], (b, kv, t, d), f32).astype(bf16)
         tv = jax.random.normal(keys[2], (b, kv, t, d), f32).astype(bf16)
@@ -237,6 +241,9 @@ def kernel_check() -> int:
         ("flash fwd+grad segment_ids", _flash_case, (True, heads)),
         ("paged plain", _paged_case, ("plain", heads)),
         ("paged tail", _paged_case, ("tail", heads)),
+        # 4 kv heads of 128: two pages a step (``pages_a_step``), as the 7B and
+        # Trinity-Mini cells' pools give it
+        ("paged tail 4 kv heads, ragged groups", _paged_case, ("tail_ragged", (32, 4, 128))),
         ("paged tail int8", _paged_case, ("tail_int8", heads)),
     ]
     checks = []

@@ -547,6 +547,10 @@ class ContinuousEngine:
         self.page_format = page_format(
             model_cfg, n_pages=self.n_pages, page_size=page_size, n_slots=n_slots,
             decode_chunk=decode_chunk, mesh=mesh, rules=rules, window_pages=window_pages)
+        # the pages a step of the decode attention kernel's walk takes: read
+        # off the pools' shape, here for the list's builder as in the kernel
+        self.attn_pages_a_step = self.page_format.attn_pages_a_step(self.maxp)
+        self.attn_pages_listed = self.attn_page_steps = 0  # lifetime, plain paged ticks
         asked = {
             "contiguous": cache_mode != "paged",
             "speculative": speculative,
@@ -1612,7 +1616,7 @@ class ContinuousEngine:
 
         with jax.named_scope("attn_core"), jax.named_scope("attn_steps"):
             return decode_steps(starts, alive, page_size=self.page_size,
-                                max_pages=self.maxp)
+                                max_pages=self.maxp, group=self.attn_pages_a_step)
 
     def _build_paged_decode(self, sampled: bool, topp: bool):
         """Paged decode tick with DEFERRED page writes: the chunk's K/V
@@ -1734,8 +1738,13 @@ class ContinuousEngine:
             )
 
             out = fmt.flush(cache_const, tails, starts, pos, table)
-            # the attention kernels' list's count rides with the scan's
-            counters = {**acc, "attn_steps_walked": steps["count"]}
+            # the attention kernels' list's count rides with the scan's, and
+            # the pages its rows hold (from positions: what a step takes at
+            # once is the kernel's own business)
+            counters = {**acc, "attn_steps_walked": steps["count"],
+                        "attn_page_steps": steps["count"] - listed.sum(dtype=jnp.int32),
+                        "attn_pages_listed": jnp.where(
+                            listed, -(-starts // self.page_size), 0).sum(dtype=jnp.int32)}
             fs = (fst,) if guided else ()
             if n_lp:
                 toks, c, i, t = ys
@@ -4217,6 +4226,8 @@ class ContinuousEngine:
         attrs = {name: int(n) for name, n in tick.items() if np.ndim(n) == 0}
         for name in self.tick_totals:
             self.tick_totals[name] += attrs[name]
+        self.attn_pages_listed += attrs["attn_pages_listed"]
+        self.attn_page_steps += attrs["attn_page_steps"]
         if "moe_counts" in tick:
             counts = np.asarray(tick["moe_counts"], np.int64)
             self.moe_assignments += counts
@@ -4229,8 +4240,11 @@ class ContinuousEngine:
         attrs.update(self.page_format.span_attrs(attrs, self.decode_chunk))
         # a call of the decode attention kernel walked this many steps, of
         # the rectangle of every slot by every page-table position and the
-        # tail (what it walked before PR 42)
-        attrs["attn_steps_rect"] = self.n_slots * (self.maxp + 1)
+        # tail (what it walked before PR 42), both in the list's unit: a
+        # page step is ``attn_pages_a_step`` pages, and ``attn_pages_listed``
+        # over that many times ``attn_page_steps`` is how full the steps were
+        group = attrs["attn_pages_a_step"] = self.attn_pages_a_step
+        attrs["attn_steps_rect"] = self.n_slots * (-(-self.maxp // group) + 1)
         if "moe_counts" in tick:
             from ditl_tpu.models.moe import split_counts
 
@@ -4604,6 +4618,11 @@ class ContinuousEngine:
             "ticks_overlapped_total": self.ticks_overlapped,
             "dead_chunk_rows_total": self.dead_chunk_rows,
             "decode_chunk": self.decode_chunk,
+            # The decode attention kernel's walk: pages a step (derived from
+            # the pools' shape), and what the plain ticks' lists held.
+            "attn_pages_a_step": self.attn_pages_a_step,
+            "attn_pages_listed_total": self.attn_pages_listed,
+            "attn_page_steps_total": self.attn_page_steps,
             "max_context": self.smax,
             "token_budget": self.token_budget,
             "max_tick_prefill_tokens": self.max_tick_prefill_tokens,

@@ -206,6 +206,12 @@ class PageFormat:
         the table and the kernels' work list; built once a program."""
         return {}
 
+    def attn_pages_a_step(self, max_pages: int) -> int:
+        """The pages a step of the decode attention kernel's work list takes
+        (``ops/paged_attention.py`` ``pages_a_step``, read off the pools'
+        shape): one, where the kernel is not the K/V one."""
+        return 1
+
     def allocator(self, **kw):
         """The host's bookkeeping of this format's page ids."""
         from ditl_tpu.infer.paged_cache import PageAllocator
@@ -277,6 +283,11 @@ class KVPages(PageFormat):
             self.page_bytes = 2 * per_val + 2 * (per_val // self.head_dim) * 4
         else:
             self.page_bytes = 2 * per_val * self.dtype.itemsize
+
+    def attn_pages_a_step(self, max_pages: int) -> int:
+        from ditl_tpu.ops.paged_attention import pages_a_step
+
+        return pages_a_step(self.shape, jnp.int8 if self.quantized else self.dtype, max_pages)
 
     def fresh(self) -> dict[str, jax.Array]:
         if self.quantized:
@@ -622,6 +633,11 @@ class WindowKVPages(PageFormat):
         self.alloc = None
         self._seen = (0, 0)
 
+    def attn_pages_a_step(self, max_pages: int) -> int:
+        from ditl_tpu.ops.paged_attention import pages_a_step
+
+        return pages_a_step(self.shape, self.dtype, max_pages)  # both pools' pages are one size
+
     def allocator(self, **kw):
         from ditl_tpu.infer.paged_cache import WindowedAllocator
 
@@ -729,19 +745,21 @@ class WindowKVPages(PageFormat):
         return out
 
     def tick_meta(self, starts, listed, table) -> dict:
-        from ditl_tpu.ops.paged_attention import decode_steps
+        from ditl_tpu.ops.paged_attention import decode_steps, window_first_page
 
+        ps, max_pages = self.page_size, table.shape[-1]
         with jax.named_scope("attn_core"), jax.named_scope("attn_steps"):
-            wsteps = decode_steps(starts, listed, page_size=self.page_size,
-                                  max_pages=table.shape[-1], window=self.window)
-            rows = listed.sum(dtype=jnp.int32)
-            full = jnp.where(listed, -(-starts // self.page_size), 0).sum(dtype=jnp.int32)
-        # each list's PAGE steps (the tail step a listed row is neither's)
-        return {"table": table[0], "wtable": table[1], "wsteps": wsteps,
-                "page_steps": (wsteps["count"] - rows, full)}
+            wsteps = decode_steps(starts, listed, page_size=ps, max_pages=max_pages,
+                                  window=self.window, group=self.attn_pages_a_step(max_pages))
+            # each list's PAGES, from the rows' positions (a step of a list
+            # may take several, and the tail step is neither's)
+            full = -(-starts // ps)
+            win = full - window_first_page(starts, self.window, ps)
+            win, full = (jnp.where(listed, n, 0).sum(dtype=jnp.int32) for n in (win, full))
+        return {"table": table[0], "wtable": table[1], "wsteps": wsteps, "pages": (win, full)}
 
     def count(self, acc, *, alive, lengths, starts, meta, counted):
-        win, full = meta["page_steps"]  # a call of each kernel walked them
+        win, full = meta["pages"]  # a call of each kernel walked them
         return {**acc, "window_pages_walked": acc["window_pages_walked"] + win,
                 "full_pages_walked": acc["full_pages_walked"] + full}
 

@@ -17,7 +17,10 @@ floor.
 (``decode_steps`` handed ``page_size = G * ps`` and ``max_pages = ceil(maxp /
 G)``: a live row's groups, then one step that does nothing here, where the
 attention kernels read their tail): one page a step would pay a step's fixed
-part 4,000 times a call for 64 KiB each. The pool stays in HBM (``pl.ANY``);
+part 4,000 times a call for 64 KiB each. (The K/V attention kernel groups too
+where a page is under 1 MiB, ``paged_attention.pages_a_step``, PR 53: there
+through the block pipeline, a pool operand a page of the group, because its
+groups are 2 pages of 512 KiB and not 12 of 64.) The pool stays in HBM (``pl.ANY``);
 a step starts one DMA for each LIVE page of the NEXT step's group (the row's
 ``ceil(min(starts, lengths) / ps)`` pages and no other: a ragged last group,
 an ended row and the list's idle steps fetch nothing) into the other half of

@@ -172,7 +172,7 @@ def mla_paged_attention(
 
     if steps is None:
         steps = decode_steps(starts, lengths > 0, page_size=ps, max_pages=maxp)
-    slot_map, page_map = walk_maps(ps, maxp, trailing=2)
+    slot_map, (page_map,) = walk_maps(ps, maxp, trailing=2)  # one page a step
     out = pl.pallas_call(
         functools.partial(_mla_kernel, scale=scale, page_size=ps),
         grid_spec=pltpu.PrefetchScalarGridSpec(

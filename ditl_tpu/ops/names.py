@@ -81,7 +81,12 @@ context row) and the output gate's product. A reader that knows only
 ``attn_steps`` (``ATTN_SCOPES``), inside ``attn_core``: the work list of the
 paged decode kernels (``ops/paged_attention.py`` ``decode_steps``), built
 once a decode program in front of its scan; a reader that knows only
-``SCOPES`` books its few microseconds a tick to ``attn_core``."""
+``SCOPES`` books its few microseconds a tick to ``attn_core``. A step of the
+K/V kernel's list is a group of ``pages_a_step`` pages (two where a page's
+keys and values are half a MiB, one at 1 MiB and more); the kernel keeps the
+name ``paged_attention`` whatever a step takes, and the counts the readers
+take from the ``engine.tick`` spans (``window_pages_walked``,
+``full_pages_walked``) are PAGES, from the rows' positions."""
 
 SCOPES = (
     "embed",

@@ -18,13 +18,14 @@ Two implementations, equal by construction (tested against each other):
   scalar-prefetch channel so each grid step's *block index map* fetches the
   right physical page from HBM — no gathered copy is ever materialized.
   Online softmax over pages (same lane-replicated row-stat scheme as
-  ops/flash_attention.py); one grid step consumes one page for ALL kv heads,
-  in ONE pass (``_accumulate_block``): a batched score dot and a batched
-  value dot over the kv heads, their operands in the dtype the pool stores
-  and their sums in float32. A bfloat16 x bfloat16 product is exact in
-  float32, so the dots add the products a float32 copy of the page would
-  give and the page is never copied; float32 operands (the CPU tests') run
-  the float32 arithmetic they always ran.
+  ops/flash_attention.py); one grid step consumes a page, or a GROUP of
+  pages (below), for ALL kv heads, in ONE pass (``_accumulate_block``): a
+  batched score dot and a batched value dot over the kv heads, their
+  operands in the dtype the pool stores and their sums in float32. A
+  bfloat16 x bfloat16 product is exact in float32, so the dots add the
+  products a float32 copy of the page would give and the page is never
+  copied; float32 operands (the CPU tests') run the float32 arithmetic they
+  always ran.
 
 The decode path (the tail kernel, and ``ops/mla_attention.py``'s) walks a
 WORK LIST, not a rectangle. A grid of every slot by every page-table position
@@ -37,7 +38,34 @@ has ONE axis whose length is the traced count of the steps that exist:
 ceil(starts / page_size) - 1`` and then its tail-and-finalize step; the lists
 ride the scalar-prefetch channel beside the table and the index maps read
 step ``i``'s row and page from them. A row's scratch is initialised at its
-``k == 0`` and its output written at its tail step. The list is a function of
+``k == 0`` and its output written at its tail step.
+
+The list's unit is a GROUP of ``P`` pages (``pages_a_step``; this kernel
+only: ``ops/mla_attention.py`` keeps one page a step, ``ops/dsa_index.py``
+has a rule of its own). A step's dot -> max -> exp -> dot is a chain of
+~0.75 us on a v5e however few columns it has, and the pipeline hides it
+behind the fetch of ONE step's blocks: behind 1.28 or 2.56 us (Granite's 1
+MiB pages, OLMoE's 2 MiB) it disappears, behind the 0.64 us of 4 kv heads x
+256 x 128 bf16 it does not (0.816 us a page). So ``P`` is read off the pool's
+shape and dtype, never set: the fewest pages whose keys and values are
+``STEP_BYTES`` (1 MiB), at most ``MOST_PAGES`` and the table's width: 2 at 4
+kv heads (0.703 us a page on a row of 130 pages; 4 gives 0.707, and on a row
+of 9 pages it loses to 1: PERF.md section 6, PR 53), 1 at 8 and 16, where the
+call is the one it always was. The pages of a group are no neighbours in the
+pool, so the pool is handed to the call ``P`` times, each operand with the
+index map of its member of the group (``walk_maps``), and the body joins the
+``P`` blocks at their tile boundaries into ONE ``(K, P * ps, D)`` operand: one
+score dot, one mask / max / exp / sum, one value dot and one pass over the
+scratch a step, whatever ``P`` (a dot a page measures the same: the MXU
+takes 512 columns in four passes either way). The bytes fetched are the
+one-page walk's: a row's last group may be ragged, and the member it lacks
+names the block it named a group earlier, which is not fetched again; its
+columns lie at or past ``starts``, where the mask is, and hold pages of the
+row itself, never the sentinel and never memory nobody wrote, so nothing a
+live row's step multiplies by a zero probability can be a NaN. A window
+layer's groups start at the row's first page inside the window.
+
+The list is a function of
 ``starts`` and of which rows are alive, both constants inside a decode
 program (a row may END inside it: its steps stay, predicated off by
 ``lengths == 0`` as on the rectangle), so the engine builds it once a
@@ -84,7 +112,7 @@ from ditl_tpu.ops.attention import NEG_INF
 from ditl_tpu.ops.backend import interpret_default
 from ditl_tpu.ops.flash_attention import NUM_LANES, _lane_tile
 
-__all__ = ["decode_steps", "paged_attention", "paged_attention_xla"]
+__all__ = ["decode_steps", "paged_attention", "paged_attention_xla", "pages_a_step"]
 
 
 def paged_attention_xla(
@@ -169,12 +197,23 @@ def paged_attention_xla(
     return out[:, 0] if squeeze else out
 
 
+def _side_by_side(refs, axis: int):
+    """The blocks of a step's pages as one operand: ``refs`` (one block a
+    page, each with a leading 1) joined along ``axis``, at page boundaries
+    that are whole tiles, so the join moves nothing."""
+    blocks = [r[0] for r in refs]
+    return blocks[0] if len(blocks) == 1 else jnp.concatenate(blocks, axis=axis)
+
+
 def _accumulate_block(
-    q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, *,
-    scale, base, width, limit, ks_ref=None, vs_ref=None, q_groups=None, low=None,
+    q_ref, k_refs, v_refs, m_scr, l_scr, acc_scr, *,
+    scale, base, width, limit, ks_refs=None, vs_refs=None, q_groups=None, low=None,
 ):
     """Online-softmax accumulation of one (all-kv-heads) KV block whose
-    columns are global positions [base, base+width), masked to < limit.
+    columns are global positions [base, base+width), masked to < limit. The
+    block is a tuple of refs, a GROUP of pages side by side (``pages_a_step``;
+    one ref for the tail and for a pool of large pages): ``width`` columns in
+    all, taken in ONE pass whatever their number.
 
     ALL kv heads in one pass: one batched score dot ``(K, G, D) x (K, width,
     D) -> (K, G, width)``, one mask, one ``max`` / ``exp`` / ``sum`` over the
@@ -191,7 +230,7 @@ def _accumulate_block(
     scores behind the dot, as ``paged_attention_xla`` writes it. The
     probabilities enter the value dot in the values' dtype.
 
-    ``ks_ref``/``vs_ref`` ((1, K, 1, width) f32) mark the block as int8:
+    ``ks_refs``/``vs_refs`` ((1, K, 1, ps) f32 a page) mark the block as int8:
     the scales factor OUT of the dots — the score matmul consumes raw int8
     K (HBM reads stay int8-sized; cast to q's dtype, exact for |x| <= 127)
     and the per-position scale multiplies the (G, width) score rows
@@ -215,15 +254,15 @@ def _accumulate_block(
     if low is not None:  # a window layer: nothing below the window's first position
         col_mask = col_mask & (cols >= low)
     q = q_ref[0]  # (K, G, D)
-    k = k_ref[0]  # (K, width, D)
-    if ks_ref is not None:
+    k = _side_by_side(k_refs, 1)  # (K, width, D)
+    if ks_refs is not None:
         k = k.astype(q.dtype)
     s = jax.lax.dot_general(
         q, k, (((2,), (2,)), ((0,), (0,))),
         preferred_element_type=jnp.float32,
     ) * scale  # (K, G, width)
-    if ks_ref is not None:
-        s = s * ks_ref[0]  # (K, 1, width) broadcast over G sublanes
+    if ks_refs is not None:
+        s = s * _side_by_side(ks_refs, 2)  # (K, 1, width) broadcast over G sublanes
     s = jnp.where(col_mask, s, NEG_INF)
     m_prev = m_scr[...]  # (K, G, NUM_LANES) lane-replicated
     m_next = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
@@ -231,9 +270,9 @@ def _accumulate_block(
     ptab = jnp.exp(s - tile(m_next, width))
     l_scr[...] = alpha * l_scr[...] + jnp.sum(ptab, axis=2, keepdims=True)
     m_scr[...] = m_next
-    v = v_ref[0]  # (K, width, D)
-    if vs_ref is not None:
-        ptab, v = ptab * vs_ref[0], v.astype(jnp.float32)
+    v = _side_by_side(v_refs, 1)  # (K, width, D)
+    if vs_refs is not None:
+        ptab, v = ptab * _side_by_side(vs_refs, 2), v.astype(jnp.float32)
     pv = jax.lax.dot_general(
         ptab.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
         preferred_element_type=jnp.float32,
@@ -292,7 +331,7 @@ def _paged_kernel(
     @pl.when(base < length)
     def _compute():
         _accumulate_block(
-            q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr,
+            q_ref, (k_ref,), (v_ref,), m_scr, l_scr, acc_scr,
             scale=scale, base=base, width=page_size, limit=length,
         )
 
@@ -308,6 +347,29 @@ def window_first_page(starts, window: int, page_size: int):
     return jnp.maximum(starts - (window - 1), 0) // page_size
 
 
+# The bytes of keys and values a step of the walk should fetch at least, and
+# the most pages it may take to get there. A page step's dot -> max -> exp ->
+# dot takes ~0.75 us on a v5e however few columns it has, and the pipeline
+# hides it behind ONE step's fetch: behind 1.28 us (1 MiB) or 2.56 (2 MiB) it
+# disappears (Granite's and OLMoE's pages: 91.9% and 92.1% of the DMA's
+# floor), behind 0.64 us (Trinity-Mini's and Qwen2-7B's 4 kv heads x 256 x
+# 128 bf16, 0.5 MiB) it does not (78.5%). The sweep that set the threshold is
+# in PERF.md section 6, PR 53.
+STEP_BYTES = 1 << 20
+MOST_PAGES = 4
+
+
+def pages_a_step(pool_shape: tuple[int, ...], dtype, max_pages: int) -> int:
+    """``P``, the pages one step of the decode walk consumes: the fewest whose
+    keys and values are ``STEP_BYTES`` together, at most ``MOST_PAGES`` and the
+    page table's width. Derived from the pool's shape ``(..., K, ps, D)`` and
+    dtype, never set: 2 at 4 kv heads x 256 x 128 bf16, 1 where a page is 1 MiB
+    or more, whose walk is then the one-page walk it always was."""
+    kv_heads, ps, d = pool_shape[-3:]
+    page_bytes = 2 * kv_heads * ps * d * jnp.dtype(dtype).itemsize
+    return max(1, min(pl.cdiv(STEP_BYTES, page_bytes), MOST_PAGES, max_pages))
+
+
 def decode_steps(
     starts: jax.Array,  # (B,) int32 — tokens resident in pages
     alive: jax.Array,  # (B,) bool — rows that may attend during the program
@@ -315,32 +377,39 @@ def decode_steps(
     page_size: int,
     max_pages: int,
     window: int | None = None,
+    group: int = 1,
 ) -> dict[str, jax.Array]:
     """The work list of the decode kernels (module docstring): every step
     that exists, row by row. A row with ``alive`` has ``ceil(starts /
-    page_size)`` page steps ``k = 0, 1, ...`` and then its tail-and-finalize
-    step ``k = ceil(starts / page_size)``; a row without has none.
+    page_size)`` pages, so ``ceil(pages / group)`` page steps ``k = 0, 1,
+    ...`` (step ``k`` is the row's pages ``k * group ..``, the last group
+    ragged behind) and then its tail-and-finalize step; a row without has
+    none. ``group`` is the kernel's ``pages_a_step``.
 
-    With a ``window`` (a window attention layer's list) a row's page steps are
-    only the pages that meet ``[starts - (window - 1), starts)``: its first
+    With a ``window`` (a window attention layer's list) a row's pages are
+    only those that meet ``[starts - (window - 1), starts)``: its first
     query of the program, at position ``starts``, sees nothing in front of
-    them, and no later one does. Step ``k`` is then the row's page
-    ``window_first_page + k`` (the kernel and its index maps add it), the first
-    of them masked inside below each step's own ``lengths - window``.
+    them, and no later one does. Step ``k`` then starts at the row's page
+    ``window_first_page + k * group`` (the kernel and its index maps add it),
+    the first page masked inside below each step's own ``lengths - window``.
 
-    ``rows`` / ``ks``: (B * (max_pages + 1),) int32, step ``i``'s row and its
-    ``k``; ``count`` (): the steps that exist (entries past it name row 0,
-    ``k`` 0 and are never walked). ``starts <= max_pages * page_size``, which
-    is what a page table that wide can address. One cumulative sum over the
-    rows and one comparison a (step, row): build it once where ``starts``
-    and ``alive`` are constants (a decode program: in front of its scan)."""
+    ``rows`` / ``ks``: (B * (ceil(max_pages / group) + 1),) int32, step
+    ``i``'s row and its ``k``; ``count`` (): the steps that exist (entries past
+    it name row 0, ``k`` 0 and are never walked). ``starts <= max_pages *
+    page_size``, which is what a page table that wide can address. One
+    cumulative sum over the rows and one comparison a (step, row): build it
+    once where ``starts`` and ``alive`` are constants (a decode program: in
+    front of its scan)."""
     b = starts.shape[0]
-    n_pages = pl.cdiv(starts, page_size)
-    if window is not None:
-        n_pages = n_pages - window_first_page(starts, window, page_size)
-    per_row = jnp.where(alive, n_pages + 1, 0).astype(jnp.int32)
+    n_steps = pl.cdiv(starts, page_size)  # a row's page steps: its pages,
+    if window is not None:  # those inside the window,
+        n_steps = n_steps - window_first_page(starts, window, page_size)
+    most = max_pages
+    if group > 1:  # in groups
+        n_steps, most = pl.cdiv(n_steps, group), pl.cdiv(max_pages, group)
+    per_row = jnp.where(alive, n_steps + 1, 0).astype(jnp.int32)
     ends = jnp.cumsum(per_row)  # (B,) one past each row's last step
-    i = jnp.arange(b * (max_pages + 1), dtype=jnp.int32)
+    i = jnp.arange(b * (most + 1), dtype=jnp.int32)
     # the rows whose steps all lie in front of step i: its row's index
     rows = jnp.minimum(jnp.sum(i[:, None] >= ends[None, :], axis=1), b - 1)
     ks = i - (ends - per_row)[rows]
@@ -359,32 +428,34 @@ def _paged_tail_kernel(
     lengths_ref,  # scalar prefetch: (B,) int32
     starts_ref,  # scalar prefetch: (B,) int32 — tokens resident in pages
     q_ref,  # (1, K, G, D)
-    k_ref,  # (1, K, ps, D) — int8 when quantized
-    v_ref,
-    *rest,  # [ks_ref, vs_ref ((1, K, 1, ps) f32)], tk_ref, tv_ref, o_ref,
-            # m_scr, l_scr, acc_scr
+    *rest,  # ``group`` k blocks (1, K, ps, D; int8 when quantized), as many v
+            # blocks, [as many k and v scale blocks ((1, K, 1, ps) f32)],
+            # tk_ref, tv_ref, o_ref, m_scr, l_scr, acc_scr
     scale: float,
     page_size: int,
+    group: int,
     quantized: bool,
     q_groups: int | None = None,
     window: int | None = None,
 ):
     """Deferred-flush variant: grid (n_steps,), step ``i`` of the work list
     (``decode_steps``) is ``(b, p) = (rows[i], ks[i])``. Steps ``p <
-    ceil(starts[b] / page_size)`` consume row b's flushed pages (positions <
-    starts[b]) in order; the row's last step consumes the hot TAIL block —
+    ceil(pages / group)`` consume row b's flushed pages (positions <
+    starts[b]) in order, ``group`` pages a step as ONE block of ``group *
+    page_size`` columns; the row's last step consumes the hot TAIL block —
     the current decode chunk's KV, held in a small contiguous buffer until
     the per-tick flush (positions [starts, lengths)) — and writes the row's
-    output. With ``quantized``, the pools are int8 and their per-position
-    scales factor out of the dots; the tail stays float until the flush.
+    output. A ragged last group's missing pages are blocks of the row's own
+    earlier pages (``walk_maps``), masked as columns ``>= starts``. With
+    ``quantized``, the pools are int8 and their per-position scales factor
+    out of the dots; the tail stays float until the flush.
     ``q_groups`` (speculative verify): the q block packs Q query tokens;
     per-query causal limits apply to the TAIL only — every page column
     precedes ``starts``, which every query's limit already covers."""
-    if quantized:
-        kscale_ref, vscale_ref, tk_ref, tv_ref, o_ref, m_scr, l_scr, acc_scr = rest
-    else:
-        kscale_ref = vscale_ref = None
-        tk_ref, tv_ref, o_ref, m_scr, l_scr, acc_scr = rest
+    pools, (tk_ref, tv_ref, o_ref, m_scr, l_scr, acc_scr) = rest[:-6], rest[-6:]
+    k_refs, v_refs = pools[:group], pools[group:2 * group]
+    kscale_refs, vscale_refs = (
+        (pools[2 * group:3 * group], pools[3 * group:]) if quantized else (None, None))
     i = pl.program_id(0)
     b = rows_ref[i]
     p = ks_ref[i]
@@ -397,69 +468,87 @@ def _paged_tail_kernel(
 
     length = lengths_ref[b]
     start = starts_ref[b]
-    n_pages = pl.cdiv(start, page_size)  # the row's page steps; then its tail
+    width = group * page_size
+    n_steps = pl.cdiv(start, page_size)  # the row's page steps (as ``decode_steps``)
     page_limit = jnp.minimum(start, length)
-    base = p * page_size
+    base = p * width
     low = None
     if window is not None:
         # a window layer's list starts at the row's first page inside the
         # window (``decode_steps``)
         first = window_first_page(start, window, page_size)
-        n_pages = n_pages - first
+        n_steps = n_steps - first
         base = base + first * page_size
         low = length - window
-    wanted = (p < n_pages) & (base < page_limit)
+    if group > 1:
+        n_steps = pl.cdiv(n_steps, group)
+    wanted = (p < n_steps) & (base < page_limit)
     if window is not None:
-        # a page that has fallen wholly behind this step's window (the
-        # program's later steps) is skipped
-        wanted = wanted & (base + page_size > low)
+        # pages that have fallen wholly behind this step's window (the
+        # program's later steps) are skipped
+        wanted = wanted & (base + width > low)
 
     @pl.when(wanted)
     def _pages():
         _accumulate_block(
-            q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr,
-            scale=scale, base=base, width=page_size, limit=page_limit,
-            ks_ref=kscale_ref, vs_ref=vscale_ref, low=low,
+            q_ref, k_refs, v_refs, m_scr, l_scr, acc_scr,
+            scale=scale, base=base, width=width, limit=page_limit,
+            ks_refs=kscale_refs, vs_refs=vscale_refs, low=low,
         )
 
-    @pl.when((p == n_pages) & (length > start))
+    @pl.when((p == n_steps) & (length > start))
     def _tail():
         _accumulate_block(
-            q_ref, tk_ref, tv_ref, m_scr, l_scr, acc_scr,
+            q_ref, (tk_ref,), (tv_ref,), m_scr, l_scr, acc_scr,
             scale=scale, base=start, width=tk_ref.shape[2], limit=length,
             q_groups=q_groups, low=low,
         )
 
-    @pl.when(p == n_pages)
+    @pl.when(p == n_steps)
     def _finalize():
         _finalize_out(o_ref, m_scr, l_scr, acc_scr)
 
 
-def walk_maps(page_size: int, max_pages: int, trailing: int, window: int | None = None):
+def walk_maps(page_size: int, max_pages: int, trailing: int, window: int | None = None,
+              group: int = 1):
     """The block index maps of a walk over ``decode_steps``' list, for
     operands with ``trailing`` dims behind the leading one: ``slot_map``
-    names step ``i``'s ROW (q, tail, output), ``page_map`` its page (pools,
-    scales). A page step names the row's ``k``-th page while it holds
-    tokens the row still attends to (``< min(starts, lengths)``); a row that
-    ended inside the program names sentinel page 0 instead, and the tail
-    step names the row's LAST page again: consecutive identical block
-    indices are not fetched again, so neither costs a fetch."""
+    names step ``i``'s ROW (q, tail, output), ``page_maps[j]`` the ``j``-th
+    page of its group (pools, scales: a pool is handed to the call ``group``
+    times, a map each, since the pages of a group are no neighbours in it).
+    A page step names the row's page ``k * group + j`` while it holds tokens
+    the row still attends to (``< min(starts, lengths)``); a row that ended
+    inside the program names sentinel page 0 instead, and the tail step names
+    the row's LAST group again: consecutive identical block indices are not
+    fetched again, so neither costs a fetch. Nor does the page a ragged last
+    group lacks: member ``j`` names what it named a group earlier (a row of
+    fewer than ``j + 1`` pages its last page, the one fetch that is not a
+    page's first), so every block a live row's step computes on holds pages
+    of that row, and the columns of a repeated one lie at or past ``starts``,
+    where the mask is."""
     zeros = (0,) * trailing
 
     def slot_map(i, rows, ks, tab, lens, st):
         return (rows[i], *zeros)
 
-    def page_map(i, rows, ks, tab, lens, st):
+    def page_map(i, rows, ks, tab, lens, st, *, member):
         b = rows[i]
-        last = jnp.maximum(pl.cdiv(st[b], page_size) - 1, 0)
+        pages = pl.cdiv(st[b], page_size)
+        last = jnp.maximum(pages - 1, 0)
         k = ks[i]
-        if window is not None:  # step k of a window layer's list: page first + k
-            k = k + window_first_page(st[b], window, page_size)
+        first = 0 if window is None else window_first_page(st[b], window, page_size)
+        if group > 1:
+            listed = pages - first  # the pages of the row's steps
+            # the tail step names the last group again
+            k = jnp.minimum(k, jnp.maximum(pl.cdiv(listed, group) - 1, 0)) * group + member
+            k = jnp.where((k >= listed) & (k >= group), k - group, k)
+        if window is not None:  # a window layer's list starts at the row's page first
+            k = k + first
         col = jnp.minimum(jnp.minimum(k, last), max_pages - 1)
         live = col * page_size < jnp.minimum(st[b], lens[b])
         return (jnp.where(live, tab[b, col], 0), *zeros)
 
-    return slot_map, page_map
+    return slot_map, tuple(functools.partial(page_map, member=j) for j in range(group))
 
 
 def walk_length(steps: dict[str, jax.Array]) -> jax.Array:
@@ -506,7 +595,9 @@ def paged_attention(
     has to name every row with ``lengths > 0`` (built from these ``starts``
     and an ``alive`` that covers them; a decode program builds it once, in
     front of its scan); left out, it is built here from ``lengths > 0``. A
-    row with ``lengths == 0`` comes out as zeros, in the list or not.
+    step of the list is ``pages_a_step(k_pages.shape, k_pages.dtype, maxp)``
+    pages, which whoever builds ``steps`` hands ``decode_steps`` as ``group``.
+    A row with ``lengths == 0`` comes out as zeros, in the list or not.
 
     4-D ``q`` (requires the tail path) is the speculative K+1-token verify:
     Q queries per slot share every page fetch — the whole point of
@@ -528,6 +619,9 @@ def paged_attention(
         raise ValueError(
             "paged_attention with a window walks the tail path's work list, one "
             "query a row, on one chip: no speculative verify, no mesh")
+    # of the WHOLE pool's pages, which is what whoever built ``steps`` saw (a
+    # shard's pages under a mesh are smaller)
+    group = pages_a_step(k_pages.shape, k_pages.dtype, page_table.shape[1])
     if mesh is not None:
         from ditl_tpu.ops.attention import _mesh_axes_size
         from ditl_tpu.parallel.sharding import DEFAULT_RULES, logical_to_spec
@@ -590,10 +684,11 @@ def paged_attention(
                     steps_ = {"rows": rows_, "ks": steps_ks_, "count": count_}
                 if has_scale:
                     ks_, vs_ = rest
-                return paged_attention(
+                return _on_one_chip(
                     q_, kp_, vp_, tab_, lens_,
                     tail_k=tk_, tail_v=tv_, starts=st_,
                     k_scale=ks_, v_scale=vs_, steps=steps_, interpret=interpret,
+                    group=group,
                 )
 
             return jax.shard_map(
@@ -618,6 +713,16 @@ def paged_attention(
                 f"GSPMD — expect per-step pool resharding",
                 stacklevel=2,
             )
+    return _on_one_chip(
+        q, k_pages, v_pages, page_table, lengths, tail_k=tail_k, tail_v=tail_v,
+        starts=starts, k_scale=k_scale, v_scale=v_scale, steps=steps,
+        interpret=interpret, window=window, group=group)
+
+
+def _on_one_chip(q, k_pages, v_pages, page_table, lengths, *, tail_k, tail_v, starts,
+                 k_scale, v_scale, steps, interpret, group, window=None):
+    """``paged_attention`` on the arrays one chip holds: the ``pallas_call``."""
+    multi_q = q.ndim == 4
     if multi_q:
         b, nq, h, d = q.shape
     else:
@@ -656,22 +761,17 @@ def paged_attention(
     if has_tail:
         if steps is None:
             steps = decode_steps(starts, lengths > 0, page_size=ps, max_pages=maxp,
-                                 window=window)
-        slot_map, page_map = walk_maps(ps, maxp, trailing=3, window=window)
+                                 window=window, group=group)
+        slot_map, page_maps = walk_maps(ps, maxp, trailing=3, window=window, group=group)
         quantized = k_scale is not None
-        in_specs = [
-            pl.BlockSpec((1, kv_heads, qg_rows, d), slot_map),
-            pl.BlockSpec((1, kv_heads, ps, d), page_map),
-            pl.BlockSpec((1, kv_heads, ps, d), page_map),
-        ]
+        # a pool is an operand once a page of the group, each with its own map
+        pages = [pl.BlockSpec((1, kv_heads, ps, d), m) for m in page_maps]
+        in_specs = [pl.BlockSpec((1, kv_heads, qg_rows, d), slot_map), *pages, *pages]
         args = [steps["rows"], steps["ks"], page_table, lengths, starts,
-                qg, k_pages, v_pages]
+                qg, *[k_pages] * group, *[v_pages] * group]
         if quantized:
-            in_specs += [
-                pl.BlockSpec((1, kv_heads, 1, ps), page_map),
-                pl.BlockSpec((1, kv_heads, 1, ps), page_map),
-            ]
-            args += [k_scale, v_scale]
+            in_specs += 2 * [pl.BlockSpec((1, kv_heads, 1, ps), m) for m in page_maps]
+            args += [*[k_scale] * group, *[v_scale] * group]
         in_specs += [
             pl.BlockSpec((1, kv_heads, tail_k.shape[2], d), slot_map),
             pl.BlockSpec((1, kv_heads, tail_k.shape[2], d), slot_map),
@@ -679,7 +779,7 @@ def paged_attention(
         args += [tail_k, tail_v]
         out = pl.pallas_call(
             functools.partial(
-                _paged_tail_kernel, scale=d**-0.5, page_size=ps,
+                _paged_tail_kernel, scale=d**-0.5, page_size=ps, group=group,
                 quantized=quantized, q_groups=groups if nq > 1 else None,
                 window=window,
             ),

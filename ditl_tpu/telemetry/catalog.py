@@ -224,6 +224,9 @@ _ROWS: tuple = (
     ("ditl_serving_admission_degrade_windows_total", "counter", "", "tick windows that engaged the anti-thrash admission degrade"),
     ("ditl_serving_admission_degraded", "gauge", "", "1 while the optimistic-admission anti-thrash degrade is engaged"),
     ("ditl_serving_admission_degrades", "gauge", "", "lifetime anti-thrash degrade windows (stats mirror)"),
+    ("ditl_serving_attn_page_steps_total", "gauge", "", "page steps the plain paged decode ticks' attention work lists held (a tick's list is built once; every layer of every step walks it); attn_pages_listed_total over attn_pages_a_step times this is how full the steps were (lifetime count from /v1/stats)"),
+    ("ditl_serving_attn_pages_a_step", "gauge", "", "pages one step of the K/V decode attention kernel's walk takes, read off the page pool's shape (the fewest whose keys and values make 1 MiB, at most 4; 1 where a page is that large and for the latent kernels)"),
+    ("ditl_serving_attn_pages_listed_total", "gauge", "", "pages the rows of the plain paged decode ticks' attention work lists held, counted from the rows' positions (lifetime count from /v1/stats)"),
     ("ditl_serving_client_disconnects_total", "counter", "", "in-flight generations cancelled because the client vanished mid-stream"),
     ("ditl_serving_deadline_expired_total", "counter", "", "requests evicted from the queue/slots at their deadline (expired work stops consuming engine ticks)"),
     ("ditl_serving_dead_chunk_rows_total", "gauge", "", "rows of harvested decode ticks whose request had already finished or been cancelled: the one dead chunk a slot decodes before a double-buffered tick's lagged harvest frees it (lifetime count from /v1/stats)"),
@@ -330,8 +333,8 @@ _ROWS: tuple = (
     ("ditl_serving_window_pages_freed_total", "gauge", "", "window pages that went back to the free list because the row that held them moved past them (lifetime count from /v1/stats)", True),
     ("ditl_serving_window_pages_released_total", "gauge", "", "references to window pages that rows gave up behind their window, in chunked prefill and in decode (a shared page stays while another row or the cache holds it; lifetime count from /v1/stats)", True),
     ("ditl_serving_window_pages_total", "gauge", "", "size of the window layers' page pool (--window-pages less the sentinel)", True),
-    ("ditl_serving_window_pages_walked_total", "gauge", "", "page steps the window layers' decode work list held, summed over the decode ticks' steps (every window layer walks the list once a step; full_pages_walked_total is the full layers')", True),
-    ("ditl_serving_full_pages_walked_total", "gauge", "", "page steps the full layers' decode work list held, summed over the decode ticks' steps, in a model that also has window attention layers", True),
+    ("ditl_serving_window_pages_walked_total", "gauge", "", "pages the rows of the window layers' decode work list held, counted from their positions (a step of the list may take several) and summed over the decode ticks' steps (every window layer walks the list once a step; full_pages_walked_total is the full layers')", True),
+    ("ditl_serving_full_pages_walked_total", "gauge", "", "pages the rows of the full layers' decode work list held, counted from their positions and summed over the decode ticks' steps, in a model that also has window attention layers", True),
     ("ditl_serving_window_pool_evictions", "gauge", "", "companions the window layers' pool reclaimed from the content cache under pressure (the full page stays published; a later hit over it is shortened or refused)", True),
     ("ditl_slo_availability_alerting", "gauge", "", "1 when every window burns availability's budget faster than 1.0x"),
     ("ditl_slo_availability_burn_rate_w<window>", "gauge", "window seconds", "availability burn rate over 300s (error rate / error budget)"),

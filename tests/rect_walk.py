@@ -43,8 +43,9 @@ def _paged_kernel(table_ref, lengths_ref, starts_ref, q_ref, k_ref, v_ref, *rest
                   scale, page_size, n_pages, quantized, q_groups):
     if quantized:
         ks_ref, vs_ref, tk_ref, tv_ref, o_ref, m_scr, l_scr, acc_scr = rest
+        scales = {"ks_refs": (ks_ref,), "vs_refs": (vs_ref,)}
     else:
-        ks_ref = vs_ref = None
+        scales = {}
         tk_ref, tv_ref, o_ref, m_scr, l_scr, acc_scr = rest
     b, p = pl.program_id(0), pl.program_id(1)
     pl.when(p == 0)(lambda: _init(m_scr, l_scr, acc_scr))
@@ -55,13 +56,13 @@ def _paged_kernel(table_ref, lengths_ref, starts_ref, q_ref, k_ref, v_ref, *rest
     @pl.when((p < n_pages) & (base < page_limit))
     def _pages():
         pa._accumulate_block(
-            q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, scale=scale, base=base,
-            width=page_size, limit=page_limit, ks_ref=ks_ref, vs_ref=vs_ref)
+            q_ref, (k_ref,), (v_ref,), m_scr, l_scr, acc_scr, scale=scale, base=base,
+            width=page_size, limit=page_limit, **scales)
 
     @pl.when((p == n_pages) & (length > start))
     def _tail():
         pa._accumulate_block(
-            q_ref, tk_ref, tv_ref, m_scr, l_scr, acc_scr, scale=scale, base=start,
+            q_ref, (tk_ref,), (tv_ref,), m_scr, l_scr, acc_scr, scale=scale, base=start,
             width=tk_ref.shape[2], limit=length, q_groups=q_groups)
 
     pl.when(p == n_pages)(lambda: pa._finalize_out(o_ref, m_scr, l_scr, acc_scr))
@@ -145,6 +146,16 @@ def mla_paged_attention_rect(q, pool, page_table, lengths, *, tail, starts, valu
         out_shape=jax.ShapeDtypeStruct((b, heads, value_width), q.dtype),
         interpret=True,
     )(page_table, lengths, starts, q.astype(pool.dtype), pool, tail.astype(pool.dtype))
+
+
+def derive_pages_a_step(monkeypatch, pages: int, pool) -> None:
+    """Make ``pages_a_step`` derive ``pages`` for ``pool`` (an array or a
+    ``ShapeDtypeStruct`` of ``(..., K, ps, D)``; and every pool of its page's
+    bytes): the rule's threshold moved to that many of its pages, as it lies
+    at two of a 4-kv-head bf16 page of 256 on the chip."""
+    kv_heads, ps, d = pool.shape[-3:]
+    monkeypatch.setattr(
+        pa, "STEP_BYTES", pages * 2 * kv_heads * ps * d * jnp.dtype(pool.dtype).itemsize)
 
 
 # Rows by what the walk has to get right. ``starts`` are the tokens in pages

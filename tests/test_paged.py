@@ -730,25 +730,27 @@ def test_flush_writes_the_committed_rows_and_nothing_else(tail_len, pool_dtype):
 # -- the work list of the decode kernels (PR 42) ----------------------------------
 
 
-def _brute_force_steps(starts, alive, ps):
+def _brute_force_steps(starts, alive, ps, group=1):
     return [(row, k) for row in range(len(starts)) if alive[row]
-            for k in range(-(-int(starts[row]) // ps) + 1)]
+            for k in range(-(-(-(-int(starts[row]) // ps)) // group) + 1)]
 
 
+@pytest.mark.parametrize("group", [1, 2, 3, 4], ids="{}-pages-a-step".format)
 @pytest.mark.parametrize("name", list(rect_walk.SCENARIOS))
-def test_decode_steps_is_the_enumeration_of_the_listed_rows_steps(name):
-    """The list builder alone: every listed row's page steps in order, then
-    its tail step, rows in order; nothing of a row that is not listed; the
-    entries past the count name row 0's step 0."""
+def test_decode_steps_is_the_enumeration_of_the_listed_rows_steps(name, group):
+    """The list builder alone: every listed row's page steps in order (a
+    step a ``group`` of pages, the last one ragged), then its tail step,
+    rows in order; nothing of a row that is not listed; the entries past the
+    count name row 0's step 0."""
     from ditl_tpu.ops.paged_attention import decode_steps
 
     starts, _, listed = rect_walk.rows_of(name)
     ps, maxp = rect_walk.PAGE_SIZE, rect_walk.MAX_PAGES
-    steps = jax.jit(lambda s, a: decode_steps(s, a, page_size=ps, max_pages=maxp))(
-        starts, listed)
-    want = _brute_force_steps(np.asarray(starts), np.asarray(listed), ps)
+    steps = jax.jit(lambda s, a: decode_steps(s, a, page_size=ps, max_pages=maxp,
+                                              group=group))(starts, listed)
+    want = _brute_force_steps(np.asarray(starts), np.asarray(listed), ps, group)
     count = int(steps["count"])
-    assert steps["rows"].shape == steps["ks"].shape == (len(starts) * (maxp + 1),)
+    assert steps["rows"].shape == steps["ks"].shape == (len(starts) * (-(-maxp // group) + 1),)
     assert steps["rows"].dtype == steps["ks"].dtype == jnp.int32
     assert count == len(want)
     got = list(zip(np.asarray(steps["rows"]).tolist(), np.asarray(steps["ks"]).tolist()))
@@ -780,28 +782,40 @@ def _walk_case(variant, seed=11):
     return (q, kp, vp, table), kw
 
 
+over_pages_a_step = pytest.mark.parametrize("pages", [1, 2, 4], ids="{}-pages-a-step".format)
+
+
 @pytest.mark.pallas
+@over_pages_a_step
 @pytest.mark.parametrize("variant", ["plain", "int8", "multi-query"])
 @pytest.mark.parametrize("name", list(rect_walk.SCENARIOS))
-def test_the_walk_over_the_list_gives_the_rectangles_numbers(name, variant):
+def test_the_walk_over_the_list_gives_the_rectangles_numbers(name, variant, pages, monkeypatch):
     """The work-list kernel against the rectangular walk it replaced (the
     same step bodies on a grid of every slot by every page-table position:
     ``tests/rect_walk.py``) and against the XLA gather: a row with
-    ``lengths > 0`` bit-equal to the rectangle's, a row with ``lengths ==
-    0`` exactly zero whether the list names it or not."""
-    from ditl_tpu.ops.paged_attention import (decode_steps, paged_attention,
+    ``lengths > 0`` equal to the rectangle's, TO THE BIT where a step is one
+    page (the parent's walk) and to float32 rounding where a step takes a
+    group of them (two pages enter one running maximum); a row with
+    ``lengths == 0`` exactly zero whether the list names it or not. The rows
+    have 0, 1, 2, 3 and 4 pages, ``starts`` on page edges and inside."""
+    from ditl_tpu.ops.paged_attention import (decode_steps, pages_a_step, paged_attention,
                                               paged_attention_xla)
 
     starts, lengths, listed = rect_walk.rows_of(name)
     (q, kp, vp, table), kw = _walk_case(variant)
+    rect_walk.derive_pages_a_step(monkeypatch, pages, kp)
+    assert pages_a_step(kp.shape, kp.dtype, rect_walk.MAX_PAGES) == pages
     steps = decode_steps(starts, listed, page_size=rect_walk.PAGE_SIZE,
-                         max_pages=rect_walk.MAX_PAGES)
+                         max_pages=rect_walk.MAX_PAGES, group=pages)
     got = np.asarray(paged_attention(q, kp, vp, table, lengths, starts=starts, steps=steps,
                                      interpret=True, **kw))
     rect = np.asarray(rect_walk.paged_attention_rect(q, kp, vp, table, lengths,
                                                      starts=starts, **kw))
     live = np.asarray(lengths) > 0
-    np.testing.assert_array_equal(got[live], rect[live])
+    if pages == 1:
+        np.testing.assert_array_equal(got[live], rect[live])
+    else:
+        np.testing.assert_allclose(got[live], rect[live], atol=2e-6)
     assert not got[~live].any() and np.isfinite(got).all()
     ref = paged_attention_xla(q, kp, vp, table, lengths, starts=starts, **kw)
     np.testing.assert_allclose(got, np.asarray(ref), atol=1e-4 if variant == "int8" else 2e-5)
@@ -812,9 +826,52 @@ def test_the_walk_over_the_list_gives_the_rectangles_numbers(name, variant):
 
 
 @pytest.mark.pallas
+@over_pages_a_step
+@pytest.mark.parametrize("variant", ["plain", "int8"])
+def test_a_ragged_groups_missing_pages_are_never_read(variant, pages, monkeypatch):
+    """The hazard of a group of pages: the last group of a row with ``pages
+    + 1`` pages lacks pages, and what stands in their columns enters the
+    VALUE dot with probability zero, where ``0 x NaN`` is NaN. Every page no
+    live row still attends to is POISONED here (the sentinel page 0, the pages
+    a row's table names past its ``starts``, those of dead and ended rows):
+    the index maps name only pages of the row itself for a block a live row's
+    step computes on, so the output is the clean pool's to the bit."""
+    from ditl_tpu.ops.paged_attention import decode_steps, paged_attention
+
+    ps, maxp = rect_walk.PAGE_SIZE, rect_walk.MAX_PAGES
+    starts = jnp.asarray([ps * min(pages + 1, maxp) - 3, 5, ps, 40, 0, ps * maxp], jnp.int32)
+    # row 2 is dead, row 3 ended inside the program
+    lengths = jnp.where(jnp.asarray([True, True, False, False, True, True]),
+                        starts + jnp.asarray([1, 2, 0, 0, 3, 1], jnp.int32), 0)
+    listed = jnp.asarray([True, True, False, True, True, True])
+    (q, kp, vp, table), kw = _walk_case(variant)
+    rect_walk.derive_pages_a_step(monkeypatch, pages, kp)
+    table = np.array(table)
+    table[:] = np.arange(1, 1 + table.size).reshape(table.shape) % (kp.shape[0] - 1) + 1
+    used = np.zeros(kp.shape[0], bool)
+    for row in range(len(starts)):
+        if int(lengths[row]) > 0:
+            used[table[row, :-(-int(starts[row]) // ps)]] = True
+    table = jnp.asarray(table)
+    steps = decode_steps(starts, listed, page_size=ps, max_pages=maxp, group=pages)
+    clean = np.asarray(paged_attention(q, kp, vp, table, lengths, starts=starts, steps=steps,
+                                       interpret=True, **kw))
+    bad = jnp.asarray(~used)[:, None, None, None]
+    if variant == "int8":  # an int8 page has no NaN: its scales carry the poison
+        kw = {**kw, "k_scale": jnp.where(bad, jnp.nan, kw["k_scale"]),
+              "v_scale": jnp.where(bad, jnp.nan, kw["v_scale"])}
+    else:
+        kp, vp = jnp.where(bad, jnp.nan, kp), jnp.where(bad, jnp.nan, vp)
+    got = np.asarray(paged_attention(q, kp, vp, table, lengths, starts=starts, steps=steps,
+                                     interpret=True, **kw))
+    assert np.isfinite(got).all()
+    np.testing.assert_array_equal(got, clean)
+
+
+@pytest.mark.pallas
 @pytest.mark.parametrize("n_devices", [2, 4], ids=["tensor-2", "data-2-tensor-2"])
 @pytest.mark.parametrize("variant", ["plain", "int8", "multi-query"])
-def test_the_walk_on_a_tensor_mesh_equals_one_devices(variant, n_devices):
+def test_the_walk_on_a_tensor_mesh_equals_one_devices(variant, n_devices, monkeypatch):
     """The ``shard_map`` over kv heads, the list replicated like the table
     (and, where the batch is split too, each shard's list its own rows'):
     the same numbers as the unsharded call, dead rows zero."""
@@ -824,8 +881,10 @@ def test_the_walk_on_a_tensor_mesh_equals_one_devices(variant, n_devices):
 
     starts, lengths, listed = rect_walk.rows_of("ended-inside-the-tick")
     (q, kp, vp, table), kw = _walk_case(variant)
+    # the list is the whole pool's, two pages a step, under the mesh too
+    rect_walk.derive_pages_a_step(monkeypatch, 2, kp)
     steps = decode_steps(starts, listed, page_size=rect_walk.PAGE_SIZE,
-                         max_pages=rect_walk.MAX_PAGES)
+                         max_pages=rect_walk.MAX_PAGES, group=2)
     call = functools.partial(paged_attention, q, kp, vp, table, lengths, starts=starts,
                              steps=steps, interpret=True, **kw)
     one = np.asarray(call())
@@ -835,27 +894,47 @@ def test_the_walk_on_a_tensor_mesh_equals_one_devices(variant, n_devices):
     assert not sharded[np.asarray(lengths) == 0].any()
 
 
-def test_a_traced_engines_tick_span_counts_the_steps_walked(tiny_setup, tmp_path):
+@over_pages_a_step
+def test_a_traced_engines_tick_span_counts_the_steps_walked(tiny_setup, tmp_path, pages,
+                                                            monkeypatch):
     """The decode program returns its list's count with the tick's tokens;
     an armed tracer's ``engine.tick`` span carries it beside the rectangle
-    the kernels walked before: ``attn_steps_walked <= attn_steps_rect``,
-    and a tick's count is the steps of the rows that decoded in it."""
+    the kernels walked before: ``attn_steps_walked <= attn_steps_rect``, both
+    in the list's unit (a page step is ``attn_pages_a_step`` pages), and a
+    tick's count is the steps of the rows that decoded in it. The PAGES the
+    list's rows held ride beside it (``attn_pages_listed``, from positions:
+    the same whatever a step takes), so a journal says how full the steps
+    were; ``/v1/stats`` carries the lifetime sums."""
     from ditl_tpu.telemetry.journal import EventJournal, merge_journals
     from ditl_tpu.telemetry.tracing import Tracer
 
     cfg, params = tiny_setup
+    rect_walk.derive_pages_a_step(monkeypatch, pages, jax.ShapeDtypeStruct(
+        (cfg.num_kv_heads, 16, cfg.head_dim), cfg.dtype))
     journal = EventJournal(str(tmp_path / "events-engine.jsonl"), source="engine")
     eng = _paged_engine(params, cfg, gen=GenerateConfig(max_new_tokens=12),
                         tracer=Tracer(journal))
+    assert eng.attn_pages_a_step == pages
     eng.generate(["hello paged world", "abc"])
     journal.close()
     ticks = [r for r in merge_journals(str(tmp_path)) if r.get("name") == "engine.tick"]
     counted = [t for t in ticks if "attn_steps_walked" in t]
     assert counted
-    rect = eng.n_slots * (eng.maxp + 1)
+    rect = eng.n_slots * (-(-eng.maxp // pages) + 1)
     for t in counted:
-        assert t["attn_steps_rect"] == rect
+        assert t["attn_steps_rect"] == rect and t["attn_pages_a_step"] == pages
         assert 0 <= t["attn_steps_walked"] <= rect
-    # pages of 16: 18 tokens growing to 30 are two page steps and the tail
-    # step, 4 growing to 16 one page step and the tail step
-    assert max(t["attn_steps_walked"] for t in counted) == 5
+        assert t["attn_page_steps"] <= t["attn_pages_listed"] <= pages * t["attn_page_steps"]
+    # pages of 16: 18 tokens growing to 30 are two pages and the tail step, 4
+    # growing to 16 one page and the tail step
+    assert max(t["attn_pages_listed"] for t in counted) == 3
+    assert max(t["attn_steps_walked"] for t in counted) == (5 if pages == 1 else 4)
+    stats = eng.stats()
+    assert stats["attn_pages_a_step"] == pages
+    assert stats["attn_pages_listed_total"] == sum(t["attn_pages_listed"] for t in counted)
+    assert stats["attn_page_steps_total"] == sum(t["attn_page_steps"] for t in counted)
+    from ditl_tpu.telemetry.catalog import catalog_families
+
+    assert {f"ditl_serving_{name}" for name in (
+        "attn_pages_a_step", "attn_pages_listed_total", "attn_page_steps_total"
+    )} <= set(catalog_families())

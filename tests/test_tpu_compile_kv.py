@@ -19,16 +19,17 @@ from ditl_tpu.ops import names
 from tests.tpu_compile import _eqns, _instructions, _steps, over_tails
 
 @over_tails
-@pytest.mark.parametrize("h, kv, pages, b, maxp, window", [
-    (16, 16, 10 * 192, 64, 16, None),  # OLMoE: ONE query head a kv head
-    (28, 4, 12 * 720, 64, 16, None),  # Qwen2-7B: 7 a kv head
-    (32, 8, 4 * 512, 64, 16, None),  # Granite: 64-wide heads, stored on 128 lanes
-    (32, 4, 4 * 2048, 32, 132, None),  # Trinity-Mini's full layers: 8 a kv head
-    (32, 4, 12 * 384, 32, 132, 2048),  # and its window layers' pool and list
+@pytest.mark.parametrize("h, kv, pages, b, maxp, window, group", [
+    (16, 16, 10 * 192, 64, 16, None, 1),  # OLMoE: ONE query head a kv head
+    (28, 4, 12 * 720, 64, 16, None, 2),  # Qwen2-7B: 7 a kv head
+    (32, 8, 4 * 512, 64, 16, None, 1),  # Granite: 64-wide heads, stored on 128 lanes
+    (32, 4, 4 * 2048, 32, 132, None, 2),  # Trinity-Mini's full layers: 8 a kv head
+    (32, 4, 12 * 384, 32, 132, 2048, 2),  # and its window layers' pool and list
+    (28, 4, 12 * 384, 32, 132, 2048, 2),  # a window list at 7 query heads a kv head
 ], ids=["olmoe-1b-7b-cut1", "qwen2-7b-cut1", "granite-4.0-h-micro", "trinity-mini-cut1-full",
-        "trinity-mini-cut1-window"])
+        "trinity-mini-cut1-window", "seven-a-kv-head-window"])
 def test_paged_decode_kernel_compiles_on_its_work_list_at_the_cells_shapes(
-        one_chip, tpu_branch, tail, h, kv, pages, b, maxp, window):
+        one_chip, tpu_branch, tail, h, kv, pages, b, maxp, window, group):
     """``paged_attention`` as the serving cells run it: 64 slots and 16 pages
     a slot (the closed loop over 32k documents: 32 and 132), pages of 256 in
     all layers' pools addressed as one, either tail, the work list's rows /
@@ -36,8 +37,14 @@ def test_paged_decode_kernel_compiles_on_its_work_list_at_the_cells_shapes(
     one-axis grid. The instruction keeps the kernel's name: the readers and
     ``_scopes.py`` find it by that. Inside the kernel every dot takes its
     operands as the pool stores them, bfloat16, and gives float32: no page is
-    converted to float32 in front of the score dot (PR 49)."""
-    from ditl_tpu.ops.paged_attention import paged_attention
+    converted to float32 in front of the score dot (PR 49). A step takes
+    ``group`` pages, read off the pool's shape: two where a page's keys and
+    values are half a MiB (4 kv heads), each pool then an operand twice, and
+    still ONE score dot and ONE value dot a step (PR 53); one where they are
+    1 MiB or 2, the call it always was."""
+    from ditl_tpu.ops.paged_attention import paged_attention, pages_a_step
+
+    assert pages_a_step((pages, kv, 256, 128), jnp.bfloat16, maxp) == group
 
     hd, ps = 128, 256
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
@@ -49,7 +56,7 @@ def test_paged_decode_kernel_compiles_on_its_work_list_at_the_cells_shapes(
     def call(q, kp, vp, tab, lens, tk, tv, st, alive):
         return paged_attention(
             q, kp, vp, tab, lens, tail_k=tk, tail_v=tv, starts=st, window=window,
-            steps=_steps(st, alive, ps, maxp, window), interpret=False)
+            steps=_steps(st, alive, ps, maxp, window, group), interpret=False)
 
     compiled = jax.jit(call).lower(*args).compile()
     assert names.KERNELS[3] == "paged_attention"
@@ -57,7 +64,9 @@ def test_paged_decode_kernel_compiles_on_its_work_list_at_the_cells_shapes(
     kernel, = [e for e in _eqns(jax.make_jaxpr(call)(*args).jaxpr)
                if e.primitive.name == "pallas_call"]
     dots = [e for e in _eqns(kernel.params["jaxpr"]) if e.primitive.name == "dot_general"]
-    assert len(dots) == 4  # scores and values, of a page and of the tail
+    assert len(dots) == 4  # scores and values, of a step's pages and of the tail
+    # q, the pools once a page of the group, the two tails
+    assert len(kernel.params["grid_mapping"].block_mappings) - 1 == 3 + 2 * group
     for dot in dots:
         assert [v.aval.dtype for v in dot.invars] == [jnp.bfloat16] * 2
         assert dot.outvars[0].aval.dtype == jnp.float32
@@ -79,6 +88,7 @@ def test_paged_decode_layer_loop_copies_no_pool(one_chip, tpu_branch, preset, la
     instruction of one layer's pool shape, a flattening that is a bitcast,
     and temporaries far under one layer's pool."""
     from ditl_tpu.models import llama
+    from ditl_tpu.ops.paged_attention import pages_a_step
 
     cfg = get_preset(preset, num_layers=layers, param_dtype="bfloat16")
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
@@ -96,7 +106,8 @@ def test_paged_decode_layer_loop_copies_no_pool(one_chip, tpu_branch, preset, la
             params, cur[:, None], cfg, positions=pos[:, None],
             cache={"kp": kp, "vp": vp, "tk": tk, "tv": tv},
             paged={"table": table, "lengths": lengths, "starts": starts, "t": t,
-                   "steps": _steps(starts, lengths > 0, ps, maxp)},
+                   "steps": _steps(starts, lengths > 0, ps, maxp,
+                                   group=pages_a_step(pool.shape, pool.dtype, maxp))},
             return_hidden=True)
 
     compiled = jax.jit(step).lower(

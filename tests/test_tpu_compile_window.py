@@ -6,7 +6,10 @@ under the tenth-spare line.
 
 from __future__ import annotations
 
+import json
+import os
 import re
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -107,3 +110,33 @@ def test_trinity_prefill_buckets_compile_under_the_tenth_spare_line(
     # 10.57 / 11.00 / 11.32 / 11.71 GiB (temporaries 0.25 / 0.68 / 1.00 / 1.39)
     assert compiled.memory_analysis().temp_size_in_bytes < 1.6 * _GIB
     assert _total_bytes(compiled) < _TENTH_SPARE
+
+
+@pytest.mark.parametrize("config, want", [
+    ("qwen2-7b-cut1", 2), ("trinity-mini-cut1", 2), ("olmoe-1b-7b-cut1", 1),
+    ("granite-4.0-h-micro", 1), ("longcat-flash-cut1", 1), ("deepseek-v3.2-cut1", 1)])
+def test_the_pages_a_step_of_the_serving_configurations(config, want):
+    """``pages_a_step`` read off the pools each serving configuration's file
+    gives an engine with pages of 256 (no chip and no compile: shapes alone):
+    two where a page's keys and values are half a MiB (4 kv heads of 128
+    lanes: Qwen2-7B, and both of Trinity-Mini's pools), one at Granite's 1 MiB
+    (8 kv heads of 64 stored on 128 lanes) and OLMoE's 2 MiB, whose walk stays
+    the one-page walk, and one for the latent kernels, which are not this one."""
+    from ditl_tpu.infer.page_format import page_format
+    from ditl_tpu.ops.paged_attention import pages_a_step
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmarks")
+    sys.path.insert(0, bench)
+    try:
+        import reference_check
+        from harness import model_override_args
+    finally:
+        sys.path.remove(bench)
+    with open(os.path.join(bench, "configs", f"{config}.json")) as f:
+        cell = json.load(f)
+    cfg = reference_check.model_config(cell, model_override_args(cell, "serve"))
+    fmt = page_format(cfg, n_pages=8, page_size=256, n_slots=2, decode_chunk=4)
+    assert fmt.attn_pages_a_step(132) == want
+    for shape in (getattr(fmt, name) for name in ("shape", "win_shape") if hasattr(fmt, name)):
+        assert pages_a_step(shape, fmt.dtype, 132) == want, shape
+    assert fmt.attn_pages_a_step(1) == 1  # never more than the table is wide

@@ -260,8 +260,19 @@ def test_the_window_pool_evicts_a_cached_companion_and_the_hit_is_refused():
     assert sound(eng)
 
 
-def test_the_engine_counts_the_pages_each_kind_walked():
-    eng = engine(n_slots=4, max_cache_len=128, decode_chunk=8)
+@pytest.mark.parametrize("pages", [1, 2, 4], ids="{}-pages-a-step".format)
+def test_the_engine_counts_the_pages_each_kind_walked(pages, monkeypatch):
+    """PAGES, from the rows' positions, whatever a step of either list takes
+    (``pages_a_step``: the window's list in groups too, from the row's first
+    page inside the window), so the same work reads the same; and the same
+    tokens come out."""
+    from tests.rect_walk import derive_pages_a_step
+
+    cfg = tiny()
+    derive_pages_a_step(monkeypatch, pages, jax.ShapeDtypeStruct(
+        (cfg.num_kv_heads, PS, cfg.head_dim), cfg.dtype))
+    eng = engine(cfg, n_slots=4, max_cache_len=128, decode_chunk=8)
+    assert eng.attn_pages_a_step == eng.stats()["attn_pages_a_step"] == pages
     prompt = prompt_of(np.random.default_rng(7), 70)
     rid = eng.submit(prompt, max_new_tokens=16, temperature=0.0)
     steps = len(eng.run()[rid])
@@ -272,6 +283,11 @@ def test_the_engine_counts_the_pages_each_kind_walked():
     assert st["full_pages_walked_total"] == sum(-(-s // PS) for s in starts)
     assert st["window_pages_walked_total"] == sum(
         -(-s // PS) - (s - 31) // PS for s in starts)
+    # one row: the engine's own list is the full layers' (their pages, every
+    # tick once), in steps of ``pages``
+    ticks = starts[::8]
+    assert st["attn_pages_listed_total"] == sum(-(-s // PS) for s in ticks)
+    assert st["attn_page_steps_total"] == sum(-(-(-(-s // PS)) // pages) for s in ticks)
     assert st["window_kv_bytes_per_token"] == 6 * 2 * 2 * 16 * 4  # 6 window layers, float32
     assert st["kv_bytes_per_token"] == 2 * 2 * 2 * 16 * 4
     assert st["moe_assign_held"] + st["moe_assign_absent"] == st["moe_assignments_total"]

@@ -19,6 +19,7 @@ from ditl_tpu.ops.paged_attention import (
     paged_attention_xla,
     window_first_page,
 )
+from tests.rect_walk import derive_pages_a_step
 
 PS, MAXP, W = 16, 12, 40  # pages of 16 tokens, 12 a row, a window of 40
 
@@ -111,19 +112,41 @@ def test_decode_steps_with_a_window_lists_the_pages_that_meet_it():
     assert all(np.array_equal(np.asarray(wide[n]), np.asarray(plain[n])) for n in plain)
 
 
+@pytest.mark.parametrize("group", [2, 3, 4], ids="{}-pages-a-step".format)
+def test_decode_steps_with_a_window_groups_from_the_windows_first_page(group):
+    """A step a ``group`` of pages: a row's groups start at its first page
+    inside the window (pages 0, 0, 6, 1 here: an even, an odd one), the last
+    group ragged, and the list is as long as the widest table needs."""
+    starts, alive, _ = _rows()
+    steps = decode_steps(starts, alive, page_size=PS, max_pages=MAXP, window=W, group=group)
+    groups = [-(-pages // group) for pages in (2, 3, 4, 3)]
+    n = int(steps["count"])
+    assert n == sum(groups) + 4
+    assert steps["rows"].shape == (5 * (-(-MAXP // group) + 1),)
+    assert np.asarray(steps["rows"])[:n].tolist() == sum(
+        ([r] * (groups[r] + 1) for r in range(4)), [])
+    assert np.asarray(steps["ks"])[:n].tolist() == sum(
+        (list(range(groups[r] + 1)) for r in range(4)), [])
+
+
+@pytest.mark.parametrize("pages", [1, 2, 4], ids="{}-pages-a-step".format)
 @pytest.mark.parametrize("t", [0, 3, 7], ids=lambda t: f"step-{t}")
-def test_the_paged_kernel_with_a_window_is_the_gather_oracle(t):
+def test_the_paged_kernel_with_a_window_is_the_gather_oracle(t, pages, monkeypatch):
     """Step ``t`` of a program that began at ``starts``: positions [starts,
     starts + t] sit in the tail. The list is built once, for step 0; at later
-    steps the first listed page may have fallen wholly behind the window."""
+    steps the first listed page may have fallen wholly behind the window. A
+    step takes ``pages`` pages from the row's first page inside the window
+    (rows whose list starts at page 0, at 6 and at the odd page 1): the
+    window's first page masked in part, the last group ragged."""
     starts, alive, table = _rows()
     k_pages, v_pages = _pool(3, n_pages=1 + 5 * MAXP)
+    derive_pages_a_step(monkeypatch, pages, k_pages)
     h, kv, d, tail = 4, 2, 128, 8
     q = jax.random.normal(jax.random.key(7), (5, h, d), jnp.float32)
     tk = jax.random.normal(jax.random.key(8), (5, kv, tail, d), jnp.float32)
     tv = jax.random.normal(jax.random.key(9), (5, kv, tail, d), jnp.float32)
     lengths = jnp.where(alive, starts + t + 1, 0)
-    steps = decode_steps(starts, alive, page_size=PS, max_pages=MAXP, window=W)
+    steps = decode_steps(starts, alive, page_size=PS, max_pages=MAXP, window=W, group=pages)
     want = paged_attention_xla(q, k_pages, v_pages, table, lengths, tail_k=tk, tail_v=tv,
                                starts=starts, window=W)
     got = paged_attention(q, k_pages, v_pages, table, lengths, tail_k=tk, tail_v=tv,
@@ -181,11 +204,16 @@ CELL_HEADS = {
     "olmoe-1b-7b-cut1": (16, 1, 128, 128),
     "granite-4.0-h-micro": (8, 4, 128, 64),
 }
+# the pages a step of their walk takes (tests/test_tpu_compile_window.py reads
+# them off the configurations' files)
+CELL_PAGES_A_STEP = {"trinity-mini-cut1": 2, "qwen2-7b-cut1": 2, "olmoe-1b-7b-cut1": 1,
+                     "granite-4.0-h-micro": 1}
 
 
 @pytest.mark.parametrize("window", [None, 2048], ids=["full", "window-2048"])
 @pytest.mark.parametrize("cell", list(CELL_HEADS))
-def test_the_kernel_on_bfloat16_pools_at_the_cells_heads_is_the_gather_oracle(cell, window):
+def test_the_kernel_on_bfloat16_pools_at_the_cells_heads_is_the_gather_oracle(cell, window,
+                                                                              monkeypatch):
     """What the serving cells hand the kernel: bfloat16 q, pools and tail, so
     both dots take bfloat16 operands and accumulate in float32, against the
     gather on the SAME bfloat16 operands. Rows: far across the window, inside
@@ -213,9 +241,14 @@ def test_the_kernel_on_bfloat16_pools_at_the_cells_heads_is_the_gather_oracle(ce
     k_pages, v_pages = draw(keys[1], 1 + b * maxp, kv, ps, d), draw(keys[2], 1 + b * maxp, kv, ps, d)
     kw = dict(tail_k=draw(keys[3], b, kv, tail, d), tail_v=draw(keys[4], b, kv, tail, d),
               starts=starts, window=window)
-    steps = decode_steps(starts, listed, page_size=ps, max_pages=maxp, window=window)
-    # 18 + 3 + 17 + 12 pages and four tails; a window of 2,048 drops the far rows' first page
-    assert int(steps["count"]) == (54 if window is None else 52)
+    group = CELL_PAGES_A_STEP[cell]  # as the cell's pages of 256 give it
+    derive_pages_a_step(monkeypatch, group, k_pages)
+    steps = decode_steps(starts, listed, page_size=ps, max_pages=maxp, window=window,
+                         group=group)
+    # 18 + 3 + 17 + 12 pages and four tails; a window of 2,048 drops the far rows'
+    # first page; two pages a step: 9 + 2 + 9 + 6 steps, and 9 + 2 + 8 + 6
+    want_steps = {1: (54, 52), 2: (30, 29)}[group]
+    assert int(steps["count"]) == want_steps[window is not None]
     want = np.asarray(paged_attention_xla(q, k_pages, v_pages, table, lengths, **kw)
                       .astype(jnp.float32))
     got = paged_attention(q, k_pages, v_pages, table, lengths, steps=steps, interpret=True, **kw)

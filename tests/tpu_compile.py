@@ -36,13 +36,14 @@ def _instructions(text: str) -> set[str]:
     return {m.split(".")[0] for m in re.findall(r"%([\w\-.]+) = [^\n]*custom-call", text)}
 
 
-def _steps(starts, alive, ps, maxp, window=None):
+def _steps(starts, alive, ps, maxp, window=None, group=1):
     """The kernels' work list, built in the compiled program as a decode
     program builds it (``decode_steps``: rows, ks and the traced count that
-    is the grid's length)."""
+    is the grid's length; ``group``: the K/V kernel's ``pages_a_step``)."""
     from ditl_tpu.ops.paged_attention import decode_steps
 
-    return decode_steps(starts, alive, page_size=ps, max_pages=maxp, window=window)
+    return decode_steps(starts, alive, page_size=ps, max_pages=maxp, window=window,
+                        group=group)
 
 
 def _eqns(jaxpr):
