@@ -47,6 +47,7 @@ from ditl_tpu.telemetry.slo import BurnRateMonitor, serving_slo
 from ditl_tpu.telemetry.usage import sanitize_label, tenant_label
 from ditl_tpu.telemetry.tracing import (
     NULL_TRACER,
+    StartupRecorder,
     Tracer,
     parse_traceparent,
     resolve_request_id,
@@ -658,6 +659,12 @@ class _Handler(KeepAliveHandlerMixin, BaseHTTPRequestHandler):
             compiles = compile_counter().snapshot()
             stats["compile_count_cum"] = compiles["compile_count"]
             stats["compile_s_cum"] = compiles["compile_s"]
+            stats["compile_miss_count_cum"] = compiles["cache_miss_count"]
+            # Where this process's start went (serve()'s StartupRecorder):
+            # the legs sum to /health's cold_start_s.
+            startup = getattr(self.server, "startup", None)
+            if startup is not None:
+                stats["startup"] = startup.block()
             eng = self._engine_for_stats()
             if eng is not None:
                 stats.update(eng.stats())
@@ -2240,6 +2247,14 @@ def make_server(
     return server
 
 
+def _tree_bytes(tree) -> int:
+    """Bytes of a tree's arrays, by their shapes (no device access)."""
+    import jax
+
+    return sum(int(x.nbytes) for x in jax.tree.leaves(tree)
+               if hasattr(x, "nbytes"))
+
+
 def _place_params(params, cfg: ModelConfig, mesh):
     """Put the serving params on the mesh by the model's own rule table.
     Eager init leaves the whole tree on the first device; un-placed, every
@@ -2264,11 +2279,12 @@ def _place_params(params, cfg: ModelConfig, mesh):
 
 
 def serve(argv: list[str] | None = None) -> int:
-    # Cold-start clock (ISSUE 12): time-to-first-ready measured from here
+    # The start's one clock (ISSUE 12, leg by leg since ISSUE 54): from here
     # (before the jax import below — that import and the engine build ARE
     # the cold start; the persistent compile cache is what shrinks it on a
-    # warm start) to the moment the listening server is built.
-    t_serve_start = time.monotonic()
+    # warm start) to the moment the listening server is built, as six
+    # contiguous legs whose sum is /health's cold_start_s.
+    startup = StartupRecorder()
     import jax
 
     from ditl_tpu.data.tokenizer import get_tokenizer
@@ -2506,6 +2522,7 @@ def serve(argv: list[str] | None = None) -> int:
         "max_tenant_families=64 or conviction_share=0.5",
     )
     args = parser.parse_args(argv)
+    startup.mark("imports")
 
     # Persistent compile cache, before the first program compiles: a
     # restarted server, and a gateway's next replica, skip the compile.
@@ -2540,6 +2557,7 @@ def serve(argv: list[str] | None = None) -> int:
             max_bytes=telemetry_cfg.journal_max_bytes(),
         ))
         compile_counter().journal = tracer.journal  # jit.compile events
+        startup.attach(tracer)  # startup.* spans, the closed leg backdated
 
     # Per-tenant usage metering (ISSUE 15): the meter is on by default on
     # process 0 (bounded per-tenant state, terminal-path-only updates);
@@ -2703,8 +2721,11 @@ def serve(argv: list[str] | None = None) -> int:
         ).model
     if args.kv_quant == "int8":
         cfg = dataclasses.replace(cfg, kv_cache_dtype="int8")
+    startup.mark("runtime")
     tokenizer = get_tokenizer(args.tokenizer)
+    startup.mark("tokenizer")
     params = llama.init_params(jax.random.key(0), cfg)
+    params_restored = False
     if args.checkpoint_dir:
         from ditl_tpu.train.checkpoint import CheckpointManager
 
@@ -2712,6 +2733,7 @@ def serve(argv: list[str] | None = None) -> int:
         restored = ckpt.restore_latest_params(jax.eval_shape(lambda: params))
         if restored is not None:
             params = restored
+            params_restored = True
             logger.info("restored params from %s", args.checkpoint_dir)
         ckpt.close()
     adapter_names: dict[str, int] = {}
@@ -2789,6 +2811,13 @@ def serve(argv: list[str] | None = None) -> int:
         logger.info("quantized weights to int8 (weight-only)")
     if mesh is not None and not args.pod:
         params = _place_params(params, cfg, mesh)
+    if startup.armed:
+        # A leg is host wall time; only a journaled start pays the wait that
+        # puts the device's share of the draw in THIS leg (synced=1).
+        jax.block_until_ready(params)
+    startup.mark("params", synced=int(startup.armed),
+                 param_bytes=lambda: _tree_bytes(params),
+                 restored=params_restored)
     generator = Generator(params, cfg, tokenizer, mesh=mesh)
     draft_params = draft_cfg = None
     if args.draft_preset:
@@ -2863,11 +2892,13 @@ def serve(argv: list[str] | None = None) -> int:
         return 0
     pod = None
     threaded = None
+    engine = None
     if args.engine == "continuous":
+        engine = build_engine()
         if args.pod:
             from ditl_tpu.infer.podserve import PodContinuousDriver
 
-            threaded = pod = PodContinuousDriver(build_engine())
+            threaded = pod = PodContinuousDriver(engine)
 
             class _TokenizerOnly:
                 """All device work must ride the tick broadcast: direct
@@ -2882,7 +2913,7 @@ def serve(argv: list[str] | None = None) -> int:
         else:
             from ditl_tpu.infer.continuous import ThreadedEngine
 
-            threaded = ThreadedEngine(build_engine())
+            threaded = ThreadedEngine(engine)
     elif args.pod:
         from ditl_tpu.infer.podserve import PodGenerator
 
@@ -2902,6 +2933,9 @@ def serve(argv: list[str] | None = None) -> int:
             )
         else:
             spec = SpeculativeGenerator(params, cfg, tokenizer, mesh=mesh)
+    # the engine's cache tree: the page pools (0 without an engine)
+    startup.mark("engine",
+                 pool_bytes=lambda: _tree_bytes(getattr(engine, "cache", ())))
     server = make_server(
         generator, host=args.host, port=args.port, model_name=cfg.name,
         default_max_tokens=args.max_tokens, threaded_engine=threaded,
@@ -2909,11 +2943,16 @@ def serve(argv: list[str] | None = None) -> int:
         max_pending=args.max_pending or None,
         tracer=tracer, telemetry=telemetry_cfg, role=args.role,
         slo=slo, incidents=incidents, serving_metrics=serving_metrics,
-        cold_start_s=time.monotonic() - t_serve_start,
         kv_handoff=args.kv_handoff and threaded is not None and pod is None,
         usage=usage_meter,
         usage_ledger=usage_ledger,
     )
+    startup.mark("listen", port=server.server_address[1])
+    startup.close()
+    # Measured time-to-first-ready (ISSUE 12): /health echoes the legs' sum,
+    # /v1/stats the legs themselves.
+    server.cold_start_s = startup.total()
+    server.startup = startup
 
     # SIGTERM = graceful drain (the gateway/orchestrator rolling-restart
     # protocol): /health flips to draining so routers stop sending traffic,

@@ -29,6 +29,7 @@ import dataclasses
 import json
 import os
 import sys
+import time
 
 from ditl_tpu.config import Config, parse_overrides
 from ditl_tpu.models.presets import get_preset
@@ -106,7 +107,7 @@ def build_config(argv: list[str] | None = None) -> Config:
     return config
 
 
-def run_supervised(config: Config) -> dict:
+def run_supervised(config: Config, startup=None) -> dict:
     """Restart supervisor — the analog of torchrun's elastic ``--max_restarts``
     (which the reference launches through but never configures, ref
     ``scripts/run_node0.sh:10``, SURVEY.md §5 'failure detection'). On an
@@ -114,7 +115,9 @@ def run_supervised(config: Config) -> dict:
     ``train.max_restarts`` times; each retry resumes from the latest Orbax
     checkpoint (``init_runtime`` is idempotent, so re-entry is in-process).
     Recovery requires somewhere to recover FROM: without ``checkpoint_dir`` +
-    ``resume`` the exception propagates immediately."""
+    ``resume`` the exception propagates immediately. ``startup``: the
+    process's start-up clock (``main``'s), handed to the first ``train()``;
+    a restart in this process counts its own start."""
     import logging
 
     from ditl_tpu.train.trainer import train
@@ -122,7 +125,7 @@ def run_supervised(config: Config) -> dict:
     restarts = 0
     while True:
         try:
-            summary = train(config)
+            summary = train(config, startup)
             summary["restarts"] = restarts
             return summary
         except Exception:
@@ -139,6 +142,7 @@ def run_supervised(config: Config) -> dict:
                 # runtime/elastic.py) is the only sound restart.
                 raise
             restarts += 1
+            startup = None
             logging.getLogger(__name__).exception(
                 "training failed; restart %d/%d from latest checkpoint",
                 restarts,
@@ -309,6 +313,7 @@ def _pod_size(argv: list[str]) -> int:
 
 
 def main(argv: list[str] | None = None) -> int:
+    t_entry = time.time()
     if argv is None:
         argv = sys.argv[1:]
     if argv and argv[0] == "gateway":
@@ -323,9 +328,16 @@ def main(argv: list[str] | None = None) -> int:
         return gateway_main(argv[1:])
     if "--supervise" in argv:
         return run_process_supervised(argv, max(1, _pod_size(argv)))
+    # The training process's start-up clock (the supervisor's parent above
+    # records nothing): train() adds its legs and hands them to the first
+    # metrics_file row.
+    from ditl_tpu.telemetry.tracing import StartupRecorder
+
+    startup = StartupRecorder(t_entry)
     config = build_config(argv)
+    startup.mark("config")
     try:
-        summary = run_supervised(config)
+        summary = run_supervised(config, startup)
     except Exception:
         import logging
 

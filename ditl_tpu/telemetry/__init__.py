@@ -88,6 +88,7 @@ from ditl_tpu.telemetry.tracing import (
     NULL_TRACER,
     Span,
     SpanContext,
+    StartupRecorder,
     Tracer,
     format_traceparent,
     new_request_id,
@@ -123,6 +124,7 @@ __all__ = [
     "ServingMetrics",
     "Span",
     "SpanContext",
+    "StartupRecorder",
     "StepAnatomy",
     "TICK_RING",
     "TOKEN_LATENCY_BUCKETS_S",

@@ -414,6 +414,23 @@ def render_markdown() -> str:
         "`behind_tokens`, `fetch_wait_s` and the rest) are defined in "
         "`docs/design.md`, \"A request's first token\", with the recipe "
         "that reads them: `python benchmarks/layer_metrics/_ttft.py RUN_DIR`.",
+        "",
+        "A process's start is journaled the same way (`docs/design.md`, "
+        "\"A program's start\"): one span `startup` from the program's "
+        "entry, its legs `startup.<leg>` (the server's `imports`, `runtime`, "
+        "`tokenizer`, `params`, `engine`, `listen`; the trainer's `config`, "
+        "`runtime`, `data`, `state`, `restore`, `loop_prep`, `first_flush`) "
+        "with `synced`, `param_bytes`, `restored`, `pool_bytes`, `port`, "
+        "`examples`, `n_params`, `resumed`, `step`, `steps`, and on every "
+        "`jit.compile` event `cache` (`hit` with `retrieval_s`, `miss`, "
+        "`off`). No `/metrics` family carries them either: the legs' seconds "
+        "are `startup` (`entry_wall`, `legs`) in the server's `/v1/stats` "
+        "(their sum is `/health`'s `cold_start_s`) and in the FIRST "
+        "`train.metrics_file` row a process writes; the programs the "
+        "persistent cache did not hold are `compile_miss_count_cum`, beside "
+        "`compile_count_cum` / `compile_s_cum`, in `/v1/stats` and in every "
+        "row. The recipe that reads them: `python "
+        "benchmarks/layer_metrics/_setup.py RUN_DIR`.",
     ]
     return "\n".join(lines) + "\n"
 

@@ -22,6 +22,10 @@ Span model:
   so the merged trace nests across process boundaries.
 - **Instants** (``trace.instant`` records) are zero-duration points on a
   process's track.
+- A **process's start** is one more tree on the same journal
+  (``StartupRecorder``, ISSUE 54): a ``startup`` span from the program's
+  entry with one contiguous child ``startup.<leg>`` a leg, written backdated
+  where the tracer was armed after the leg closed.
 
 Cost discipline: a ``Tracer`` with no journal is **unarmed** — span writes
 are skipped entirely, but span/trace IDs are still generated so propagation
@@ -45,6 +49,7 @@ __all__ = [
     "NULL_TRACER",
     "Span",
     "SpanContext",
+    "StartupRecorder",
     "Tracer",
     "format_traceparent",
     "new_request_id",
@@ -258,3 +263,77 @@ class Tracer:
 
 
 NULL_TRACER = Tracer(None)
+
+
+class StartupRecorder:
+    """A program's start, leg by leg, on ONE wall clock (``time.time()``, the
+    journal's). Made at the program's entry with the entry's instant;
+    ``mark(name)`` closes the leg that was running and opens the next, so
+    the legs are contiguous and telescope to ``total()`` by construction.
+    Always on: two clock reads a leg, about a dozen legs a process, none
+    inside a tick or a step.
+
+    Handed an ARMED tracer (``attach``, at creation or later: the server's
+    tracer does not exist yet when its first legs run) each leg is a span
+    ``startup.<leg>`` with ``t0=`` backdated, under one parent ``startup``
+    that ``close()`` writes from the entry to the last leg's end. Unarmed it
+    writes nothing, and a leg's attributes are neither evaluated nor kept: an
+    attribute that costs something (a tree's bytes) is passed as a callable.
+    Legs closed before ``attach`` are written then, without attributes."""
+
+    def __init__(self, entry_wall: float | None = None,
+                 tracer: Tracer | None = None):
+        self.entry_wall = time.time() if entry_wall is None else float(entry_wall)
+        self._edge = self.entry_wall  # where the running leg began
+        self._legs: list[tuple[str, float, float]] = []  # name, start, seconds
+        self._tracer: Tracer | None = None
+        self._parent: Span | None = None
+        self.attach(tracer)
+
+    @property
+    def armed(self) -> bool:
+        return self._parent is not None
+
+    def attach(self, tracer: Tracer | None) -> None:
+        if tracer is None or not tracer.armed or self._parent is not None:
+            return
+        self._tracer = tracer
+        self._parent = tracer.start_span("startup", t0=self.entry_wall)
+        for name, t0, seconds in self._legs:
+            self._write(name, t0, t0 + seconds, {})
+
+    def mark(self, name: str, **attrs: Any) -> float:
+        """Close the running leg as ``name``; its seconds."""
+        now = time.time()
+        t0, self._edge = self._edge, now
+        self._legs.append((name, t0, now - t0))
+        if self._parent is not None:
+            self._write(name, t0, now, attrs)
+        return now - t0
+
+    def _write(self, name: str, t0: float, t1: float, attrs: dict) -> None:
+        self._tracer.start_span(
+            f"startup.{name}", parent=self._parent, t0=t0,
+            **{k: v() if callable(v) else v for k, v in attrs.items()},
+        ).end(t_end=t1)
+
+    def close(self) -> None:
+        """The start is over: write the parent span (idempotent)."""
+        if self._parent is not None:
+            self._parent.end(t_end=self._edge)
+
+    def total(self) -> float:
+        """Entry to the end of the last closed leg."""
+        return self._edge - self.entry_wall
+
+    def totals(self) -> dict[str, float]:
+        """Seconds by leg, in the order the legs closed."""
+        out: dict[str, float] = {}
+        for name, _, seconds in self._legs:
+            out[name] = out.get(name, 0.0) + seconds
+        return out
+
+    def block(self) -> dict:
+        """What ``/v1/stats`` and the first ``metrics_file`` row carry."""
+        return {"entry_wall": round(self.entry_wall, 6),
+                "legs": {k: round(v, 6) for k, v in self.totals().items()}}

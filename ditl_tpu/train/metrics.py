@@ -23,10 +23,16 @@ to the end of the next, over the steps flushed, is the one step time that
 holds however far the host runs ahead. It is ``flush_step_s`` on the flushing
 row, and what the ``step N:`` log line prints (the first flush's interval
 starts when the logger is made and holds the compile). Every row also carries
-``compile_count_cum`` / ``compile_s_cum`` (utils/profiling.compile_counter)
-as they stood at its flush, and every device metric the step reports under
-the step's own name (``train/step.py``: the experts' load ratio, the flash
-kernels' block counts).
+``compile_count_cum`` / ``compile_s_cum`` / ``compile_miss_count_cum``
+(utils/profiling.compile_counter; the last counts the programs the persistent
+cache did not hold) as they stood at its flush, and every device metric the
+step reports under the step's own name (``train/step.py``: the experts' load
+ratio, the flash kernels' block counts).
+
+The FIRST row a process writes carries ``startup`` (``entry_wall`` and the
+seconds of every leg of the process's start, telemetry/tracing.py
+``StartupRecorder``): the first flush's sync is where the start's last leg,
+``first_flush``, ends. A resumed process appends its own.
 """
 
 from __future__ import annotations
@@ -54,6 +60,7 @@ class MetricsLogger:
         metrics_file: str = "",
         anatomy=None,
         on_host_metrics=None,
+        startup=None,
     ):
         """``metrics_file``: optional coordinator-only JSONL scalar stream
         (one object per STEP WINDOW — every pending entry is written at each
@@ -75,11 +82,17 @@ class MetricsLogger:
         detectors ride the existing log_every sync and add ZERO blocking
         transfers (tier-1-pinned). A callback exception (the non-finite
         crash) propagates only after the pending queue is cleared, so the
-        close() flush never re-syncs."""
+        close() flush never re-syncs.
+
+        ``startup``: the process's ``StartupRecorder``
+        (telemetry/tracing.py). The first flush's sync closes its
+        ``first_flush`` leg and the recorder itself; the first row written
+        carries its ``block()`` as ``startup``."""
         import jax
 
         self.anatomy = anatomy
         self.on_host_metrics = on_host_metrics
+        self._startup = startup  # None once its block is written
         self.log_every = max(1, log_every)
         self.n_chips = n_chips if n_chips is not None else jax.device_count()
         self.step_times: list[float] = []
@@ -152,13 +165,19 @@ class MetricsLogger:
         with jax.profiler.TraceAnnotation("train.flush"):
             host_all = jax.device_get([m for _, m, _, _, _ in self._pending])
         now = time.perf_counter()
+        n_flushed = sum(n for _, _, n, _, _ in self._pending)
+        startup_block = None
+        if self._startup is not None:
+            self._startup.mark("first_flush", steps=n_flushed)
+            self._startup.close()
+            startup_block, self._startup = self._startup.block(), None
         sync_s = now - t0
         self.sync_s += sync_s
         # The flush-to-flush wall over the steps flushed: the only interval
         # that ends in a device sync at both ends.
         flush_wall_s = now - self._last_sync_t
         self._last_sync_t = now
-        flush_step_s = flush_wall_s / sum(n for _, _, n, _, _ in self._pending)
+        flush_step_s = flush_wall_s / n_flushed
         flush_tps_chip = (
             sum(float(h.get("n_tokens", 0.0)) for h in host_all)
             / flush_wall_s / self.n_chips
@@ -194,8 +213,11 @@ class MetricsLogger:
                     "dispatch_s": round(dt * n_steps, 6),
                     "compile_count_cum": compiles["compile_count"],
                     "compile_s_cum": compiles["compile_s"],
+                    "compile_miss_count_cum": compiles["cache_miss_count"],
                     **{k: round(v, 6) for k, v in host.items()},
                 }
+                if startup_block is not None:
+                    row["startup"], startup_block = startup_block, None
                 if i == last_i:
                     # The sync belongs to the flush, not any single step;
                     # carried on the row that triggered it.

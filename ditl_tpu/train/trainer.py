@@ -47,6 +47,7 @@ from ditl_tpu.telemetry import (
     GoodputTracker,
     IncidentManager,
     MemoryWatcher,
+    StartupRecorder,
     StepAnatomy,
     Tracer,
     TrainingDetector,
@@ -157,17 +158,25 @@ def _crossed(step: int, n_advanced: int, every: int) -> bool:
     return every > 0 and step > 0 and (step // every) > ((step - n_advanced) // every)
 
 
-def train(config: Config) -> dict[str, Any]:
-    """Run the full fine-tune. Returns summary metrics (also logged)."""
+def train(config: Config,
+          startup: StartupRecorder | None = None) -> dict[str, Any]:
+    """Run the full fine-tune. Returns summary metrics (also logged).
+
+    ``startup``: the process's start-up clock where the caller made one at
+    its own entry (``launch.main``, whose ``config`` leg it already holds);
+    else the start is counted from here. Seven contiguous legs up to the
+    first metrics flush's sync: the first ``metrics_file`` row carries them,
+    and a journaled run (``train.telemetry_dir``) writes them as
+    ``startup.*`` spans."""
     t_start = time.time()
+    if startup is None:
+        startup = StartupRecorder(t_start)
     # Always-on goodput accounting (telemetry/goodput.py): pure host wall
     # clocks, zero device syncs. Every second of this run lands in a bucket
     # (productive step / compile / data-wait / checkpoint / eval / profiler
     # / restart lost-work) or the measured "other" remainder.
     tracker = GoodputTracker()
     tracker.start()
-    t_setup0 = time.perf_counter()
-    setup_excl = 0.0  # setup time already attributed to a finer bucket
     init_runtime(config.runtime)
     setup_logging(config.runtime.log_level)
     journal: EventJournal | None = None
@@ -181,6 +190,8 @@ def train(config: Config) -> dict[str, Any]:
         )
         journal.event("worker.start")
         compile_counter().journal = journal  # one jit.compile event a program
+    tracer = Tracer(journal)
+    startup.attach(tracer)  # startup.* spans, the closed legs backdated
     # Chaos plane (ditl_tpu/chaos/, ISSUE 5): armed pod-wide from the
     # identical config (the fingerprint covers chaos.*); per-worker
     # targeting via rule `proc=N`. Injections journal into this worker's
@@ -194,6 +205,7 @@ def train(config: Config) -> dict[str, Any]:
         state_dir=config.chaos.journal_dir or config.train.telemetry_dir,
     )
     mesh = build_mesh(config.mesh)
+    startup.mark("runtime")
     model_cfg = config.model  # preset resolution happens in launch.build_config
     # Adapter publication (ISSUE 16): misconfiguration fails HERE, before
     # any compile — a publish cadence with nowhere to write (or no LoRA to
@@ -260,6 +272,7 @@ def train(config: Config) -> dict[str, Any]:
         pipeline.host_batch_size,
         config.data.batch_size,
     )
+    startup.mark("data", examples=len(dataset))
 
     # Sharded-from-birth state init: jit with out_shardings so every param is
     # created directly on its mesh shards (a 70B state never fits one chip).
@@ -278,6 +291,11 @@ def train(config: Config) -> dict[str, Any]:
         )
         state = init_fn(rng)
     n_params = llama.num_params(state.params)
+    if startup.armed:
+        # A leg is host wall time; only a journaled start pays the wait that
+        # puts the device's share of the draw in THIS leg (synced=1).
+        jax.block_until_ready(state)
+    startup.mark("state", synced=int(startup.armed), n_params=n_params)
     # Which way the fused loss is laid over this mesh (ops/fused_ce.py): fixed
     # per compiled step, so written once here, on the step's jit.compile
     # event and in the summary (None: the naive loss, GSPMD's to partition).
@@ -312,11 +330,7 @@ def train(config: Config) -> dict[str, Any]:
                 jax.eval_shape(lambda: state),
                 state_shardings,
             )
-            t_restore0 = time.perf_counter()
             restored = ckpt.restore_latest(abstract)
-            dt_restore = time.perf_counter() - t_restore0
-            tracker.add("checkpoint_restore", dt_restore)
-            setup_excl += dt_restore
             if restored is not None:
                 state, data_iter = restored
                 resumed = True
@@ -351,6 +365,14 @@ def train(config: Config) -> dict[str, Any]:
                 state_shardings.params,
             )
         )
+    # The checkpoint manager and a resume are one leg, and the goodput
+    # report's checkpoint_restore bucket where a resume was asked for.
+    restore_s = startup.mark("restore", resumed=resumed,
+                             step=data_iter.global_step)
+    if ckpt is not None and config.train.resume:
+        tracker.add("checkpoint_restore", restore_s)
+    else:
+        restore_s = 0.0
 
     val_batches = None
     if val_dataset is not None and config.train.val_every > 0:
@@ -488,6 +510,7 @@ def train(config: Config) -> dict[str, Any]:
         log_every=config.train.log_every,
         metrics_file=config.train.metrics_file,
         on_host_metrics=_on_host_metrics,
+        startup=startup,
     )
     profiler = StepProfiler(
         config.train.profile_dir,
@@ -496,7 +519,7 @@ def train(config: Config) -> dict[str, Any]:
         # ISSUE 6 satellite: a journaled run records the xprof capture
         # window as a `profiler.capture` span on the training-leg timeline
         # (not only as a goodput bucket).
-        tracer=Tracer(journal) if journal is not None else None,
+        tracer=tracer,
     )
     client = LLMClient(config.api)
     total_steps = config.train.total_steps
@@ -515,9 +538,12 @@ def train(config: Config) -> dict[str, Any]:
     last_saved = None
     epoch = data_iter.epoch
 
-    # Everything before the loop is startup (minus spans already attributed
-    # to finer buckets, e.g. checkpoint restore).
-    tracker.add("startup", time.perf_counter() - t_setup0 - setup_excl)
+    # Everything before the loop is startup, on the recorder's clock (less
+    # the leg already attributed to a finer bucket, the checkpoint restore,
+    # and what ran before this function: the tracker's own total starts here).
+    startup.mark("loop_prep")
+    tracker.add("startup",
+                startup.total() - restore_s - (t_start - startup.entry_wall))
     data_wait_acc = [0.0]  # host wall blocked in the data iterator, per window
 
     def _note_wait(dt: float) -> None:

@@ -18,7 +18,8 @@ its own clock. Outside a trace an annotation costs microseconds.
 
 ``compile_counter()`` counts compilations where they happen, through
 ``jax.monitoring``: the rows of ``metrics_file`` and the server's
-``/v1/stats`` carry its totals.
+``/v1/stats`` carry its totals, the programs the persistent cache did not
+hold among them.
 """
 
 from __future__ import annotations
@@ -46,13 +47,20 @@ __all__ = [
 # events would count it twice.
 _LOWERING_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 _BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# jax/_src/compiler.py ``compile_or_get_cached``: recorded on a hit alone,
+# INSIDE the backend-compile interval, so on the thread that builds the
+# program it arrives just before that program's backend-compile event.
+_CACHE_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
 
 
 class CompileCounter:
     """Programs built by this process (compiled, or loaded from the
-    persistent cache) and the seconds that took, cumulative. ``journal``
+    persistent cache) and the seconds that took, cumulative, and how many of
+    them the persistent cache did not hold. ``journal``
     (telemetry/journal.py), when set, gets one ``jit.compile`` event per
-    program with its name and seconds: which step recompiled. ``notes``
+    program with its name, its seconds and ``cache``: ``hit`` (with
+    ``retrieval_s``), ``miss`` or ``off`` (no persistent cache): which step
+    recompiled, and whether a slow start compiled or loaded. ``notes``
     maps a program's name to further fields of its event: facts fixed when
     the program was built (the trainer's ``loss_partition``)."""
 
@@ -60,28 +68,44 @@ class CompileCounter:
         self._lock = threading.Lock()
         self.compile_count = 0  # guarded-by: _lock
         self.compile_s = 0.0  # guarded-by: _lock
+        self.cache_miss_count = 0  # guarded-by: _lock
         self.journal = None
         self.notes: dict[str, dict] = {}
+        # A hit's retrieval seconds, until its thread's backend-compile event.
+        self._hit = threading.local()
 
     def on_duration(self, event: str, duration_s: float, **kw) -> None:
         if event == _LOWERING_EVENT:
             with self._lock:
                 self.compile_s += duration_s
+        elif event == _CACHE_RETRIEVAL_EVENT:
+            self._hit.retrieval_s = duration_s
         elif event == _BACKEND_COMPILE_EVENT:
             name = str(kw.get("fun_name", ""))
+            retrieval_s = getattr(self._hit, "retrieval_s", None)
+            self._hit.retrieval_s = None
+            if retrieval_s is not None:
+                cache = {"cache": "hit", "retrieval_s": round(retrieval_s, 6)}
+            elif (jax.config.jax_compilation_cache_dir
+                    and jax.config.jax_enable_compilation_cache):
+                cache = {"cache": "miss"}
+            else:
+                cache = {"cache": "off"}
             with self._lock:
                 self.compile_count += 1
                 self.compile_s += duration_s
+                self.cache_miss_count += cache["cache"] == "miss"
             journal = self.journal
             if journal is not None:
                 journal.event("jit.compile", program=name,
-                              compile_s=round(duration_s, 6),
+                              compile_s=round(duration_s, 6), **cache,
                               **self.notes.get(name, {}))
 
     def snapshot(self) -> dict:
         with self._lock:
             return {"compile_count": self.compile_count,
-                    "compile_s": round(self.compile_s, 6)}
+                    "compile_s": round(self.compile_s, 6),
+                    "cache_miss_count": self.cache_miss_count}
 
 
 _counter: CompileCounter | None = None

@@ -147,7 +147,7 @@ def test_the_deepseek_cell_is_a_closed_loop_listed_under_what_it_can_report(mani
         "attn_steps_walked_share_chat", "ttft_p50_ms", "ttft_client_p95_ms",
         "generator_late_p95_ms", "ssm_time_share_chat", "ssm_scan_time_share_chat",
         "ssm_state_roofline_decode"}
-    assert mine <= listed and len(listed) == len(mine) + 28
+    assert mine <= listed and len(listed) == len(mine) + 36
     assert {x["name"] for x in manifest_mod.metrics_for(m, "end_to_end", cell)} == {
         "setup_s", "tpot_p50_ms"}
     with open(manifest_mod.traffic_path("docs-32k-dsa")) as f:
@@ -191,7 +191,7 @@ def test_the_trinity_cell_is_listed_under_what_it_can_report(manifest_mod):
     assert {"paged_attn_time_share_chat", "attn_steps_walked_share_chat", "moe_time_share_chat",
             "moe_shared_time_share_chat", "moe_held_assign_share_chat", "prefix_hit_share_chat",
             "preemptions_chat", "compiles_in_window_chat", "tpot_p95_ms"} <= listed
-    assert len(listed) == len(mine) + 29
+    assert len(listed) == len(mine) + 37
     assert {x["name"] for x in manifest_mod.metrics_for(m, "end_to_end", cell)} == {
         "setup_s", "tpot_p50_ms"}
     with open(manifest_mod.traffic_path("docs-32k-swa")) as f:
