@@ -40,11 +40,18 @@ Cached forms (``hybrid_period``'s ``layer_cache``):
   ``{"k", "v"}`` and a paged decode's tails ``{"tk", "tv"}`` arrive with a
   leading axis of attention layers a period; the pools (whole, outside the
   scan) hold ``n_periods x`` that many layers;
-- ``rec`` (``{"ssm": (n_mixers, B, H, P, N) float32, "conv": (n_mixers,
-  K - 1, B, H P + 2 N)}``, ALL mixers of the stack; the window's taps
-  before the rows, so that the last two dimensions tile whole) rides the scan's carry and
+- ``rec`` (``{"ssm": (n_mixers, B, H / hp, N, hp P) float32, "conv":
+  (n_mixers, K - 1, B, H P + 2 N)}``, ALL mixers of the stack; the state in
+  ``ops/ssd.py``'s STORED layout, the state columns on the sublanes and ``hp``
+  heads' ``P`` side by side on the lanes (two at P = 64), which the decode
+  step's kernel reads and writes as it lies; the window's taps before the
+  rows, so that the last two dimensions tile whole) rides the scan's carry and
   each mixer reads and writes its own entry by index, in place: as a scanned
-  input and output the whole state would be copied every step.
+  input and output the whole state would be copied every step. A prefill
+  works on its one slot's rows and converts them at its boundary
+  (``ssd.from_stored`` in front of ``ssd_scan``, ``ssd.to_stored`` behind it:
+  2 MiB a mixer each way, once a chunk); whoever moves state between slots
+  (``infer/page_format.py``) does so by ``SLOT_AXIS`` alone.
 
 Scopes (``ops/names.py`` ``SSM_SCOPES``), each INSIDE the scope of
 ``SCOPES`` it refines: ``ssm_in`` (``W_in``, convolution, activation) inside
@@ -82,11 +89,14 @@ def conv_width(cfg: ModelConfig) -> int:
 
 
 def init_state(cfg: ModelConfig, rows: int) -> dict[str, jax.Array]:
-    """The recurrent state of ``rows`` sequences, all mixers of the stack."""
+    """The recurrent state of ``rows`` sequences, all mixers of the stack:
+    ``ssm`` (n_mixers, rows, H / hp, N, hp P) float32, a row's state as the
+    decode step's kernel keeps it (``ssd.stored_shape``: the same bytes as
+    ``(H, P, N)``); ``conv`` (n_mixers, K - 1, rows, H P + 2 N)."""
     n_per, m, _ = period_counts(cfg)
     return {
-        "ssm": jnp.zeros((n_per * m, rows, cfg.ssm_heads, cfg.ssm_head_dim,
-                          cfg.ssm_state), F32),
+        "ssm": jnp.zeros((n_per * m, rows, *ssd.stored_shape(
+            cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state)), F32),
         "conv": jnp.zeros((n_per * m, cfg.ssm_conv - 1, rows, conv_width(cfg)),
                           jnp.dtype(cfg.dtype)),
     }
@@ -200,8 +210,6 @@ def _mamba_mixer(m, h, *, cfg: ModelConfig, rec, at, valid, doc):
     state = conv = None
     if rec is not None:
         conv = jax.lax.dynamic_index_in_dim(rec["conv"], at, keepdims=False)
-        if not step:
-            state = jax.lax.dynamic_index_in_dim(rec["ssm"], at, keepdims=False)
     with jax.named_scope("attn_qkv"), jax.named_scope("ssm_in"):
         z, u = jnp.split(
             weight_einsum("bsd,df->bsf", h, m["w_in"], compute_dtype=cd, preferred=F32),
@@ -220,7 +228,6 @@ def _mamba_mixer(m, h, *, cfg: ModelConfig, rec, at, valid, doc):
             new_conv = jnp.swapaxes(new_conv, 0, 1)  # (K - 1, B, C), as stored
         xbc = jax.nn.silu(xbc)
         x, bmat, cmat = jnp.split(xbc, (inner, inner + n), axis=-1)
-        x = x.reshape(b, s, nh, p)
         delta = jax.nn.softplus(dt.astype(F32) + m["dt_bias"].astype(F32))
         if valid is not None:
             delta = delta * valid[..., None]
@@ -232,16 +239,21 @@ def _mamba_mixer(m, h, *, cfg: ModelConfig, rec, at, valid, doc):
                                          bmat[:, 0], cmat[:, 0], alive)
             y = y[:, None]
         else:
-            y, state = ssd.ssd_scan(x, delta, a, bmat, cmat, chunk=cfg.ssm_chunk,
-                                    state=state, doc=doc)
-            if rec is not None:
-                stack = jax.lax.dynamic_update_index_in_dim(rec["ssm"], state, at, 0)
+            if rec is not None:  # this chunk's rows alone, out of the stored layout
+                state = ssd.from_stored(
+                    jax.lax.dynamic_index_in_dim(rec["ssm"], at, keepdims=False), nh)
+            y, state = ssd.ssd_scan(x.reshape(b, s, nh, p), delta, a, bmat, cmat,
+                                    chunk=cfg.ssm_chunk, state=state, doc=doc)
+            y = y.reshape(b, s, inner)
+            if rec is not None:  # and back into it
+                stack = jax.lax.dynamic_update_index_in_dim(
+                    rec["ssm"], ssd.to_stored(state), at, 0)
         if rec is not None:
             rec = {"ssm": stack, "conv": jax.lax.dynamic_update_index_in_dim(
                 rec["conv"], new_conv.astype(rec["conv"].dtype), at, 0)}
-        y = y + m["D"].astype(F32)[:, None] * x.astype(F32)
+        y = y + jnp.repeat(m["D"].astype(F32), p) * x.astype(F32)
     with jax.named_scope("attn_out"), jax.named_scope("ssm_out"):
-        gated = y.reshape(b, s, inner) * jax.nn.silu(z.astype(F32))
+        gated = y * jax.nn.silu(z.astype(F32))
         out = weight_einsum(
             "bsf,fd->bsd", rms_norm(gated, m["norm"], cfg.rms_norm_eps).astype(cd),
             m["w_out"], compute_dtype=cd)

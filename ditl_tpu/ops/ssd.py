@@ -31,10 +31,29 @@ makes two fusions of it, the read-out ``S C`` and the write back, each
 reading all 64 slots' state whether a slot is live or not: two reads and a
 write of 128 MiB a mixer a step, 14.5 GB a step over 36 mixers beside 6.4 GB
 of weights (seen in the program compiled for a described v5e). The kernel
-(``ssd_step``, grid over the slots) reads a LIVE row's state once, updates
-it, reads it out and writes it once; a dead row's grid step is redirected to
-the block of the live row before it, and consecutive identical block indices
-move nothing (``ops/kv_flush.py`` has the same trick with a sentinel).
+(``ssd_step``) reads a LIVE row's state once, updates it, reads it out and
+writes it once.
+
+The STORED layout of a row's state, which only this module and
+``models/ssm.py`` know (``to_stored`` / ``from_stored``; ``ssd_scan`` and
+``ssd_step`` keep ``(b, H, P, N)``): ``(H / hp, N, hp P)``, the state columns
+``N`` on the sublanes and ``hp = 128 // P`` heads' ``P`` side by side on the
+lanes (``heads_a_tile``, read off the shapes: 2 at P = 64; 1 where ``P`` does
+not divide 128 or ``H`` is no multiple of it). In such a tile a head's ``dt
+x`` and decay are LANE rows that broadcast over the sublanes for nothing,
+``x`` goes in and ``y`` comes out as the row's flattened ``(H P)`` vector (no
+transpose on either side of the call), the group's ``B`` and ``C`` become a
+value a sublane ONCE a row, and the read-out ``S C`` is a sum over sublanes
+(vreg adds and one fold). With the state columns on the lanes instead, every
+head pays a lane broadcast and a lane reduction of its own and the step runs
+at half the pace of its bytes (PERF.md section 6, PR 55).
+
+The walk: the grid's ONE axis is as long as the traced count of live rows
+(one step where there is none, which writes back what it read), over
+``live_rows``' compacted list of their indices in scalar memory, as
+``ops/paged_attention.py``'s walk over ``decode_steps``. A dead row is not
+visited: its state is not moved and its ``y`` is memory nobody wrote, which
+the ``where`` behind the call defines as 0.
 """
 
 from __future__ import annotations
@@ -114,83 +133,139 @@ def ssd_step(state, x, dt, a, bvec, cvec):
     return jnp.einsum("bhpn,bn->bhp", new, cvec.astype(F32)), new
 
 
-def _ssd_step_kernel(layer, rows, alive, dec, dtx_ref, b_ref, c_ref, s_ref,
+def heads_a_tile(heads: int, head_dim: int) -> int:
+    """Heads whose ``P`` columns lie side by side on a stored tile's lanes:
+    as many as fill 128 lanes (2 at P = 64), read off the shapes alone; one
+    (a head a tile) where ``P`` does not divide 128 or ``heads`` is no
+    multiple of that many."""
+    hp = max(1, 128 // head_dim)
+    return hp if 128 % head_dim == 0 and heads % hp == 0 else 1
+
+
+def stored_shape(heads: int, head_dim: int, n: int) -> tuple[int, int, int]:
+    """A row's state as it is STORED (module docstring): ``(H / hp, N, hp P)``."""
+    hp = heads_a_tile(heads, head_dim)
+    return heads // hp, n, hp * head_dim
+
+
+def to_stored(state):
+    """(..., H, P, N) -> (..., H / hp, N, hp P): a tile's heads side by side
+    on the lanes, the state columns on the sublanes."""
+    *lead, h, p, n = state.shape
+    hp = heads_a_tile(h, p)
+    tiles = state.reshape(*lead, h // hp, hp, p, n)
+    return jnp.moveaxis(tiles, -1, -3).reshape(*lead, h // hp, n, hp * p)
+
+
+def from_stored(stored, heads: int):
+    """``to_stored``'s inverse; ``heads`` is H (the stored shape holds only
+    H / hp and hp P)."""
+    *lead, tiles, n, lanes = stored.shape
+    hp = heads // tiles
+    split = stored.reshape(*lead, tiles, n, hp, lanes // hp)
+    return jnp.moveaxis(split, -3, -1).reshape(*lead, heads, lanes // hp, n)
+
+
+def _ssd_step_kernel(layer, rows, count, dt, dec, x_ref, b_ref, c_ref, s_ref,
                      y_ref, o_ref):
-    """One slot a grid step. dec: (B, H) float32 in scalar memory, a head's
-    decay a scalar; dtx_ref: (1, P, H), the row's ``dt x`` with the heads on
-    the lanes, so that a head's column broadcasts over its (P, N) tile; b_ref
-    / c_ref: (1, 1, N); s_ref / o_ref: (1, 1, H, P, N), the same block of the
-    aliased stack; y_ref: (1, P, H)."""
-    del layer, rows  # the index maps' alone
-    i = pl.program_id(0)
-    heads = s_ref.shape[2]
+    """One LIVE row a grid step: row ``rows[i]``. dt, dec: (B, H) float32 in
+    scalar memory, a head's step size and decay; x_ref / y_ref: (1, H / hp,
+    hp P), the row's flattened ``(H P)`` vector a tile a sublane row; b_ref /
+    c_ref: (1, 1, N); s_ref / o_ref: (1, 1, H / hp, N, hp P), the same block of
+    the aliased stack. In a tile everything a head owns is a lane row that
+    broadcasts over the sublanes, the group's ``B`` and ``C`` are a value a
+    sublane (made once a row) and the read-out is a sum over sublanes."""
+    del layer  # the index maps' alone
+    r = rows[pl.program_id(0)]
+    _, _, tiles, n, lanes = s_ref.shape
+    hp = dt.shape[1] // tiles
+    p = lanes // hp
 
-    @pl.when(alive[i] != 0)
+    @pl.when(count[0] > 0)
     def _():
-        bvec, cvec = b_ref[0], c_ref[0]  # (1, N)
-        dtx = dtx_ref[0]  # (P, H)
-        lane = jax.lax.broadcasted_iota(jnp.int32, dtx.shape, 1)
-        y = jnp.zeros(dtx.shape, F32)
-        for h in range(heads):
-            new = s_ref[0, 0, h] * dec[i, h] + dtx[:, h:h + 1] * bvec
-            o_ref[0, 0, h] = new
-            y = jnp.where(lane == h, jnp.sum(new * cvec, axis=1, keepdims=True), y)
-        y_ref[0] = y
+        # (1, N) on the lanes -> (N, lanes), N on the sublanes
+        bb = jnp.broadcast_to(b_ref[0], (lanes, n)).T
+        cc = jnp.broadcast_to(c_ref[0], (lanes, n)).T
+        head = jax.lax.broadcasted_iota(jnp.int32, (1, lanes), 1) // p
 
-    # Grid step 0 of a dead row 0 is its blocks' first visit, and if no row
-    # is live at all their only one: what is written back has to be what was
-    # read. (Any later dead step sits on a block a live step has written.)
-    @pl.when((alive[i] == 0) & (i == 0))
+        def of_lane(scalars, j):  # the tile's hp scalars, each over its P lanes
+            row = jnp.full((1, lanes), scalars[r, j * hp], F32)
+            for k in range(1, hp):
+                row = jnp.where(head == k, scalars[r, j * hp + k], row)
+            return row
+
+        for j in range(tiles):
+            dtx = of_lane(dt, j) * x_ref[0, j:j + 1, :]
+            new = s_ref[0, 0, j] * of_lane(dec, j) + bb * dtx
+            o_ref[0, 0, j] = new
+            y_ref[0, j:j + 1, :] = jnp.sum(new * cc, axis=0, keepdims=True)
+
+    # No live row at all: the walk's one step writes back what it read.
+    @pl.when(count[0] == 0)
     def _():
         o_ref[...] = s_ref[...]
-        y_ref[...] = jnp.zeros_like(y_ref)
+
+
+def live_rows(alive):
+    """The walk's list: ``(rows (B,) int32, the live rows' indices in order,
+    then B - 1 repeated; count () int32)``. One cumulative sum and one
+    comparison a (step, row), as ``paged_attention.decode_steps``."""
+    b = alive.shape[0]
+    ends = jnp.cumsum(alive.astype(jnp.int32))  # live rows up to and with each row
+    i = jnp.arange(b, dtype=jnp.int32)
+    rows = jnp.minimum(jnp.sum(i[:, None] >= ends[None, :], axis=1), b - 1)
+    return rows.astype(jnp.int32), ends[-1]
 
 
 def ssd_step_rows(stack, at, x, dt, a, bvec, cvec, alive, *,
                   interpret: bool | None = None):
     """One token of every slot, on mixer ``at``'s entry of the stacked state
-    ``stack`` (n_mixers, B, H, P, N) float32, in place (donate it). x: (B, H,
-    P); dt: (B, H) float32; alive: (B,) bool, the rows whose state moves (a
-    dead row's is neither read nor written; its dt has to be 0); a: (H,);
-    bvec, cvec: (B, N). Returns ``(y (B, H, P) float32, 0 for a dead row; the
-    stack)``. Off the TPU the plain form runs."""
+    ``stack`` (n_mixers, B, H / hp, N, hp P) float32 in the STORED layout
+    (module docstring), in place (donate it). x: (B, H P), as it leaves the
+    convolution; dt: (B, H) float32; alive: (B,) bool, the rows whose state
+    moves (a dead row's is neither read nor written; its dt has to be 0); a:
+    (H,); bvec, cvec: (B, N). Returns ``(y (B, H P) float32, 0 for a dead
+    row; the stack)``. Off the TPU the plain form runs."""
+    n_b, h = dt.shape
+    _, _, tiles, n, lanes = stack.shape
     if interpret is None and interpret_default():
-        y, new = ssd_step(jax.lax.dynamic_index_in_dim(stack, at, keepdims=False),
-                          x, dt, a, bvec, cvec)
-        return y, jax.lax.dynamic_update_index_in_dim(stack, new, at, 0)
-    _, n_b, h, p, n = stack.shape
-    idx = jnp.arange(n_b, dtype=jnp.int32)
-    before = jax.lax.cummax(jnp.where(alive, idx, -1))  # the last live row so far
-    rows = jnp.where(before >= 0, before, jnp.argmax(alive).astype(jnp.int32))
-    dtx = jnp.swapaxes(dt[..., None] * x.astype(F32), 1, 2)  # (B, P, H)
+        y, new = ssd_step(
+            from_stored(jax.lax.dynamic_index_in_dim(stack, at, keepdims=False), h),
+            x.reshape(n_b, h, -1), dt, a, bvec, cvec)
+        return y.reshape(n_b, -1), jax.lax.dynamic_update_index_in_dim(
+            stack, to_stored(new), at, 0)
+    rows, count = live_rows(alive)
 
-    def row(i, layer, rows, alive, dec):
+    def row(i, layer, rows, *_):
         return (rows[i], 0, 0)
 
-    def entry(i, layer, rows, alive, dec):
+    def entry(i, layer, rows, *_):
         return (layer[0], rows[i], 0, 0, 0)
 
-    per_head = pl.BlockSpec((1, p, h), row)
+    per_head = pl.BlockSpec((1, tiles, lanes), row)
     group = pl.BlockSpec((1, 1, n), row)
-    state = pl.BlockSpec((1, 1, h, p, n), entry)
+    state = pl.BlockSpec((1, 1, tiles, n, lanes), entry)
     y, stack = pl.pallas_call(
         _ssd_step_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
-            grid=(n_b,),
+            num_scalar_prefetch=5,
+            # ONE axis whose length is the traced count of live rows
+            grid=(jnp.maximum(count, 1),),
             in_specs=[per_head, group, group, state],
             out_specs=[per_head, state],
         ),
-        out_shape=[jax.ShapeDtypeStruct((n_b, p, h), F32),
+        out_shape=[jax.ShapeDtypeStruct((n_b, tiles, lanes), F32),
                    jax.ShapeDtypeStruct(stack.shape, stack.dtype)],
-        # operands count the scalar-prefetch ones: the stack is the eighth
-        input_output_aliases={7: 1},
+        # operands count the scalar-prefetch ones: the stack is the ninth
+        input_output_aliases={8: 1},
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=bool(interpret),
         name="ssd_step",
-    )(jnp.reshape(at, (1,)).astype(jnp.int32), rows, alive.astype(jnp.int32),
-      jnp.exp(dt * a), dtx, bvec.astype(F32)[:, None], cvec.astype(F32)[:, None], stack)
-    return jnp.where(alive[:, None, None], jnp.swapaxes(y, 1, 2), 0.0), stack
+    )(jnp.reshape(at, (1,)).astype(jnp.int32), rows, jnp.reshape(count, (1,)),
+      dt, jnp.exp(dt * a), x.astype(F32).reshape(n_b, tiles, lanes),
+      bvec.astype(F32)[:, None], cvec.astype(F32)[:, None], stack)
+    # a row that is not walked was never written: this is what defines it
+    return jnp.where(alive[:, None], y.reshape(n_b, -1), 0.0), stack
 
 
 def causal_conv(u, w, bias, *, conv=None, doc=None, lengths=None):

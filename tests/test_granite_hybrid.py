@@ -136,29 +136,80 @@ def test_the_chunked_form_equals_the_token_by_token_form(s, chunk):
     assert rel(last_pad, last_real) < 1e-6
 
 
+def _step_operands(key, b, h, p, n, alive):
+    k = jax.random.split(key, 5)
+    x = jax.random.normal(k[0], (b, h, p))
+    a = -jnp.exp(jax.random.normal(k[1], (h,)))
+    bvec, cvec = jax.random.normal(k[2], (b, n)), jax.random.normal(k[3], (b, n))
+    dt = jax.nn.softplus(jax.random.normal(k[4], (b, h))) * jnp.asarray(alive, jnp.float32)[:, None]
+    return x, dt, a, bvec, cvec
+
+
 @pytest.mark.pallas
 @pytest.mark.parametrize("alive", [(1, 1, 1, 1, 1, 1), (0, 1, 0, 0, 1, 0), (0, 0, 0, 0, 0, 0),
-                                   (0, 0, 0, 0, 0, 1), (1, 0, 0, 0, 0, 0)],
-                         ids=["all", "dead-first-between-last", "none", "last", "first"])
+                                   (0, 0, 0, 0, 0, 1), (1, 0, 0, 0, 0, 0), (0, 1, 1, 1, 1, 1)],
+                         ids=["all", "dead-first-between-last", "none", "last", "first",
+                              "all-but-row-0"])
 def test_the_interpreted_step_kernel_equals_the_plain_step(alive):
-    """``ssd_step`` over the stacked state: live rows updated in place, a dead
-    row's state as it was (whichever live row's block its grid step sits on),
-    every other mixer's entry untouched."""
-    k = jax.random.split(jax.random.key(7), 6)
-    n_mix, b, h, p, n = 3, 6, 4, 16, 128
-    stack = jax.random.normal(k[0], (n_mix, b, h, p, n))
-    x = jax.random.normal(k[1], (b, h, p))
-    a = -jnp.exp(jax.random.normal(k[2], (h,)))
-    bvec, cvec = jax.random.normal(k[3], (b, n)), jax.random.normal(k[4], (b, n))
-    dt = jax.nn.softplus(jax.random.normal(k[5], (b, h))) * jnp.asarray(alive, jnp.float32)[:, None]
+    """``ssd_step`` over the stacked state in its STORED layout (two 64-wide
+    heads a tile): the walk visits the live rows alone and updates them in
+    place, a dead row's state is as it was, every other mixer's entry
+    untouched; against ``ssd_step`` on the ``(b, H, P, N)`` form."""
+    k = jax.random.split(jax.random.key(7), 2)
+    n_mix, b, h, p, n = 3, 6, 4, 64, 128
+    plain = jax.random.normal(k[0], (n_mix, b, h, p, n))
+    stack = ssd.to_stored(plain)
+    assert stack.shape == (n_mix, b, 2, n, 128)
+    x, dt, a, bvec, cvec = _step_operands(k[1], b, h, p, n, alive)
     live = jnp.asarray(alive, bool)
-    y_want, s_want = ssd.ssd_step_rows(stack, jnp.int32(1), x, dt, a, bvec, cvec, live)
-    y, s = jax.jit(lambda st, at: ssd.ssd_step_rows(st, at, x, dt, a, bvec, cvec, live,
-                                                    interpret=True))(stack, jnp.int32(1))
-    assert rel(y[live], y_want[live]) < 1e-5 if any(alive) else True
+    y_want, s_want = ssd.ssd_step(plain[1], x, dt, a, bvec, cvec)
+    y, s = jax.jit(lambda st, at: ssd.ssd_step_rows(
+        st, at, x.reshape(b, h * p), dt, a, bvec, cvec, live, interpret=True))(
+            stack, jnp.int32(1))
+    assert y.shape == (b, h * p)
+    assert rel(y[live], y_want.reshape(b, h * p)[live]) < 1e-5 if any(alive) else True
     assert not bool(jnp.any(y[~live]))
-    assert float(jnp.abs(s - s_want).max()) < 1e-5
-    assert bool(jnp.all(s[:, ~live] == stack[:, ~live])) and bool(jnp.all(s[0] == stack[0]))
+    assert float(jnp.abs(ssd.from_stored(s[1], h) - s_want).max()) < 1e-5
+    assert bool(jnp.all(s[:, ~live] == stack[:, ~live]))
+    assert bool(jnp.all(s[0] == stack[0])) and bool(jnp.all(s[2] == stack[2]))
+    # off the chip the plain form runs on the same stored stack
+    y_cpu, s_cpu = ssd.ssd_step_rows(stack, jnp.int32(1), x.reshape(b, h * p), dt, a, bvec,
+                                     cvec, live)
+    assert float(jnp.abs(y_cpu - y_want.reshape(b, h * p)).max()) < 1e-5
+    assert float(jnp.abs(s_cpu - s).max()) < 1e-5
+
+
+@pytest.mark.pallas
+@pytest.mark.parametrize("h, p, n, hp", [(4, 64, 128, 2), (8, 16, 8, 8), (4, 16, 8, 1),
+                                         (3, 64, 16, 1), (4, 48, 8, 1), (2, 128, 8, 1)],
+                         ids=["two-heads-a-tile", "eight-heads-a-tile", "too-few-heads",
+                              "odd-heads", "width-48", "width-128"])
+def test_the_stored_layout_round_trips_through_the_prefill_boundary(h, p, n, hp):
+    """A prefill's final state (``ssd_scan``, ``(b, H, P, N)``) seated in the
+    stored layout, then one kernel step on it, against ``ssd_step`` on the
+    plain form; ``hp`` heads a tile read off the shapes (one where ``P`` does
+    not divide 128 or ``H`` is no multiple of ``128 / P``)."""
+    assert ssd.heads_a_tile(h, p) == hp
+    assert ssd.stored_shape(h, p, n) == (h // hp, n, hp * p)
+    k = jax.random.split(jax.random.key(p + h), 6)
+    b, s = 3, 21
+    xs = jax.random.normal(k[0], (b, s, h, p))
+    dts = jax.nn.softplus(jax.random.normal(k[1], (b, s, h)) - 2.0)
+    bmat, cmat = jax.random.normal(k[2], (b, s, n)), jax.random.normal(k[3], (b, s, n))
+    alive = (1, 0, 1)
+    x, dt, a, bvec, cvec = _step_operands(k[4], b, h, p, n, alive)
+    _, last = ssd.ssd_scan(xs, dts, a, bmat, cmat, chunk=8)
+    seated = ssd.to_stored(last)
+    assert seated.shape == (b, h // hp, n, hp * p)
+    assert bool(jnp.all(ssd.from_stored(seated, h) == last))  # a permutation, exactly
+    y, stack = jax.jit(lambda st: ssd.ssd_step_rows(
+        st, jnp.int32(0), x.reshape(b, h * p), dt, a, bvec, cvec, jnp.asarray(alive, bool),
+        interpret=True))(seated[None])
+    y_want, s_want = ssd.ssd_step(last, x, dt, a, bvec, cvec)
+    live = jnp.asarray(alive, bool)
+    assert rel(y[live], y_want.reshape(b, h * p)[live]) < 1e-6
+    assert rel(ssd.from_stored(stack[0], h), s_want) < 1e-6
+    assert bool(jnp.all(stack[0, 1] == seated[1]))  # the dead row, bit for bit
 
 
 def test_the_preset_is_the_published_model():
@@ -169,6 +220,8 @@ def test_the_preset_is_the_published_model():
     # ISSUE 41's count: 36 mixers + 4 attention layers + the tied embedding
     assert llama.num_params(shapes) == 3_191_396_096
     assert ssm.state_bytes_per_slot(cfg) == 36 * (64 * 64 * 128 * 4 + 3 * 4352 * 2)
+    # two 64-wide heads a tile, the state columns on the sublanes (ops/ssd.py)
+    assert jax.eval_shape(lambda: ssm.init_state(cfg, 64))["ssm"].shape == (36, 64, 32, 128, 128)
     axes = llama.param_logical_axes(cfg)
     assert jax.tree.structure(shapes) == jax.tree.structure(
         axes, is_leaf=lambda x: isinstance(x, tuple))

@@ -67,7 +67,7 @@ WANT = {
                  "ks": (2, P, 2, 1, PS), "vs": (2, P, 2, 1, PS)},
                 2 * 2 * 2 * PS * 8 * 1 + 2 * 2 * 2 * PS * 4),
     "kv+state": ({"kp": (2, P, 2, PS, 128), "vp": (2, P, 2, PS, 128),
-                  "ssm": (4, SLOTS, 4, 16, 8), "conv": (4, 3, SLOTS, 80)},
+                  "ssm": (4, SLOTS, 4, 8, 16), "conv": (4, 3, SLOTS, 80)},
                  2 * 2 * 2 * PS * 128 * 4),
     "latent": ({"cp": (2 * 2, P, PS, 128)}, 4 * PS * 128 * 4),
     "latent+index": ({"cp": (3, P, PS, 128), "ip": (3, P, PS, 16)},

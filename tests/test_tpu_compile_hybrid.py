@@ -49,7 +49,10 @@ def test_granite_decode_program_compiles_in_place_under_the_tenth_spare_line(
     ticks): nine ``ssd_step`` kernels a period scan and the paged attention
     kernel over 128-lane pages, the 4.5 GiB state and the pools aliased to
     the outputs, no instruction that produces a second state, temporaries far
-    under one mixer's state, the whole under the tenth-spare line."""
+    under one mixer's state, the whole under the tenth-spare line. The kernel
+    takes ``x`` and gives ``y`` as the row's flattened ``(H P)`` vector: no
+    transpose or layout copy of a (slots, P, H) operand lies under
+    ``ssm_scan`` (18 a period while the state's columns lay on the lanes)."""
     eng, params, cache, s = _granite_cell(one_chip, chunk)
     slots = 64
     row_i, row_f = s((slots,), jnp.int32), s((slots,), jnp.float32)
@@ -62,9 +65,13 @@ def test_granite_decode_program_compiles_in_place_under_the_tenth_spare_line(
     calls = _instructions(text)
     assert names.SSM_KERNELS[0] in calls and "paged_attention" in calls
     assert names.CACHE_KERNELS[0] in calls
-    state_shape = re.escape("f32[36,64,64,64,128]")
+    state_shape = re.escape("f32[36,64,32,128,128]")  # two heads a tile (ops/ssd.py)
     producers = set(re.findall(r" = " + state_shape + r"\S* ([\w\-]+)\(", text))
     assert producers <= {"bitcast", "parameter", "get-tuple-element", "custom-call", "while"}
+    scan = [line for line in text.splitlines() if f"/{names.SSM_SCOPES[1]}/" in line]
+    assert len([line for line in scan if names.SSM_KERNELS[0] + "/pallas_call" in line]) >= 9
+    turned = re.compile(r" = f32\[64,64,64\]\S* (transpose|copy)\(")  # slots, P, H: all 64
+    assert not [line for line in scan if turned.search(line)]
     mem = compiled.memory_analysis()
     state_bytes = 36 * 64 * 64 * 64 * 128 * 4
     assert mem.alias_size_in_bytes >= state_bytes + 2 * 4 * 512 * 8 * 256 * 128 * 2
