@@ -261,6 +261,17 @@ class ModelConfig:
     ssm_state: int = 0
     ssm_conv: int = 4
     ssm_chunk: int = 256
+    # A stack of POWER-RETENTION layers (Brumby: models/retention.py), every
+    # letter of ``layer_types`` an ``r``: the dense block's projections,
+    # ``qk_norm`` a head and rotation, and in place of softmax attention the
+    # gated retention of degree ``ret_degree`` (2, the only even degree whose
+    # state fits a chip: ops/retention.py), a gate a kv head, the output
+    # divided by the sum of its weights plus ``ret_eps``; a call's tokens
+    # run ``ret_chunk`` queries at a time. Such a stack keeps NO keys and
+    # values: its cache entry is a state a slot and nothing else.
+    ret_degree: int = 2
+    ret_chunk: int = 128
+    ret_eps: float = 1e-5
     # "rope" rotates queries and keys; "nope" applies no positional term at
     # all (Granite-4.0-H: position comes from the recurrence alone).
     position_embedding: str = "rope"
@@ -402,6 +413,11 @@ class ModelConfig:
         return "w" in self.layer_types
 
     @property
+    def retention_layer(self) -> bool:
+        """Whether the stack is power-retention layers (models/retention.py)."""
+        return "r" in self.layer_types
+
+    @property
     def layer_period(self) -> str:
         """One period of ``layer_types``: its shortest prefix that, repeated,
         gives the whole string ("" for a stack of identical layers)."""
@@ -443,20 +459,48 @@ class ModelConfig:
                 raise ValueError(
                     f"a stack with window layers (models/swa.py) does not carry {what}")
 
+    def _check_retention_stack(self) -> None:
+        """What a stack of retention layers (models/retention.py) can run;
+        everything else is refused by name."""
+        if set(self.layer_types) != {"r"}:
+            raise ValueError(
+                f"layer_types {self.layer_types!r} mixes retention ('r') with other "
+                "layers: models/retention.py carries a stack of retention layers alone")
+        if self.ret_degree != 2 or self.ret_chunk <= 0 or self.ret_eps <= 0:
+            raise ValueError(
+                "a retention layer ('r' in layer_types) has ret_degree 2 (degree 4 of "
+                "a 128-wide head is a state of 11.7 M x 128 values a head), "
+                "ret_chunk > 0 and ret_eps > 0")
+        for what, on in (("experts (num_experts)", self.num_experts > 0),
+                         ("latent attention (kv_lora_rank)", self.kv_lora_rank > 0),
+                         ("LoRA (lora_rank)", self.lora_rank > 0),
+                         ("attention_bias", self.attention_bias),
+                         ("position_embedding other than rope",
+                          self.position_embedding != "rope"),
+                         ("attention_multiplier / residual_multiplier",
+                          bool(self.attention_multiplier) or self.residual_multiplier != 1.0),
+                         ("an unfused gate and up (fused_gate_up=False) or fused_qkv",
+                          not self.fused_gate_up or self.fused_qkv)):
+            if on:
+                raise ValueError(
+                    f"a stack of retention layers (models/retention.py) does not carry {what}")
+
     def __post_init__(self):
         if self.layer_types:
             if len(self.layer_types) != self.num_layers or set(self.layer_types) - {
-                    "m", "a", "w"}:
+                    "m", "a", "w", "r"}:
                 raise ValueError(
                     f"layer_types {self.layer_types!r} must be num_layers "
-                    f"({self.num_layers}) letters, each 'm', 'a' or 'w'")
+                    f"({self.num_layers}) letters, each 'm', 'a', 'w' or 'r'")
             if "m" in self.layer_types and not (
                     self.ssm_heads > 0 and self.ssm_head_dim > 0 and self.ssm_state > 0
                     and self.ssm_conv > 1 and self.ssm_chunk > 0):
                 raise ValueError(
                     "a state-space layer ('m' in layer_types) needs ssm_heads, "
                     "ssm_head_dim, ssm_state, ssm_conv and ssm_chunk set")
-            if self.window_layer:
+            if self.retention_layer:
+                self._check_retention_stack()
+            elif self.window_layer:
                 self._check_window_stack()
             elif self.num_experts > 0 or self.kv_lora_rank > 0 or self.lora_rank > 0 or (
                     not self.fused_gate_up or self.fused_qkv):

@@ -547,6 +547,12 @@ class ContinuousEngine:
         self.page_format = page_format(
             model_cfg, n_pages=self.n_pages, page_size=page_size, n_slots=n_slots,
             decode_chunk=decode_chunk, mesh=mesh, rules=rules, window_pages=window_pages)
+        if not self.page_format.pooled:
+            if n_pages:
+                raise ValueError(
+                    "n_pages sizes a page pool: this model keeps a state a slot and no "
+                    "keys and values (a request needs a slot and no page)")
+            self.n_pages = self.page_format.n_pages
         # the pages a step of the decode attention kernel's walk takes: read
         # off the pools' shape, here for the list's builder as in the kernel
         self.attn_pages_a_step = self.page_format.attn_pages_a_step(self.maxp)
@@ -1614,6 +1620,8 @@ class ContinuousEngine:
         ``alive`` at the program's start. Every layer of every step walks it."""
         from ditl_tpu.ops.paged_attention import decode_steps
 
+        if not self.page_format.pooled:
+            return {}  # no attention kernel, no list
         with jax.named_scope("attn_core"), jax.named_scope("attn_steps"):
             return decode_steps(starts, alive, page_size=self.page_size,
                                 max_pages=self.maxp, group=self.attn_pages_a_step)
@@ -1741,10 +1749,13 @@ class ContinuousEngine:
             # the attention kernels' list's count rides with the scan's, and
             # the pages its rows hold (from positions: what a step takes at
             # once is the kernel's own business)
-            counters = {**acc, "attn_steps_walked": steps["count"],
-                        "attn_page_steps": steps["count"] - listed.sum(dtype=jnp.int32),
-                        "attn_pages_listed": jnp.where(
-                            listed, -(-starts // self.page_size), 0).sum(dtype=jnp.int32)}
+            counters = dict(acc)
+            if steps:
+                counters.update({
+                    "attn_steps_walked": steps["count"],
+                    "attn_page_steps": steps["count"] - listed.sum(dtype=jnp.int32),
+                    "attn_pages_listed": jnp.where(
+                        listed, -(-starts // self.page_size), 0).sum(dtype=jnp.int32)})
             fs = (fst,) if guided else ()
             if n_lp:
                 toks, c, i, t = ys
@@ -2209,7 +2220,7 @@ class ContinuousEngine:
                 f"/ cache cap {self.smax}"
             )
         if self.cache_mode == "paged":
-            need = -(-(len(prompt) + max_new) // self.page_size)
+            need = self.page_format.pages_for(len(prompt) + max_new)
             if need > self.n_pages - 1:  # page 0 is the reserved sentinel
                 # Reject now: admission could never reserve this many pages,
                 # and a forever-unadmittable request would spin run()/the
@@ -2834,8 +2845,8 @@ class ContinuousEngine:
         """Gather-bucket (in pages) covering a context of ``d`` tokens."""
         if d <= 0:
             return 0
-        need = -(-d // self.page_size)
-        return min(_next_pow2(need, floor=1), self.maxp)
+        need = self.page_format.pages_for(d)
+        return min(_next_pow2(need, floor=1), self.maxp) if need else 0
 
     def _run_paged_prefill(self, tokens, d: int, s: int, s_bucket: int,
                            ctx_row, write_pids, temp: float, top_p: float,
@@ -2952,9 +2963,9 @@ class ContinuousEngine:
             for pid in matched:
                 self.allocator.release(pid)
             return False
-        worst = -(-(len(req.prompt) + req.max_new_tokens) // ps)
+        worst = self.page_format.pages_for(len(req.prompt) + req.max_new_tokens)
         if self.admission == "optimistic" and not self._degraded:
-            want = -(-(len(req.prompt) + self._tick_advance_bound()) // ps)
+            want = self.page_format.pages_for(len(req.prompt) + self._tick_advance_bound())
             n_total = min(max(want, len(matched)), worst)
         else:
             n_total = worst
@@ -3041,9 +3052,9 @@ class ContinuousEngine:
             for pid in matched:
                 self.allocator.release(pid)
             return False
-        worst = -(-cap // ps)
+        worst = self.page_format.pages_for(cap)
         if self.admission == "optimistic" and not self._degraded:
-            n_total = min(-(-(pos + self._tick_advance_bound()) // ps), worst)
+            n_total = min(self.page_format.pages_for(pos + self._tick_advance_bound()), worst)
         else:
             n_total = worst
         n_total = max(n_total, len(matched))
@@ -3249,7 +3260,7 @@ class ContinuousEngine:
             # is written every round but only accepted tokens advance), and
             # accumulation would degenerate to reserve-mode footprint.
             target = min(len(req.prompt) + len(req.tokens) + lag * adv, cap)
-            need = -(-target // ps)
+            need = self.page_format.pages_for(target)
             while True:
                 have = len(self._slot_pages[slot])
                 if need <= have:
@@ -4226,8 +4237,8 @@ class ContinuousEngine:
         attrs = {name: int(n) for name, n in tick.items() if np.ndim(n) == 0}
         for name in self.tick_totals:
             self.tick_totals[name] += attrs[name]
-        self.attn_pages_listed += attrs["attn_pages_listed"]
-        self.attn_page_steps += attrs["attn_page_steps"]
+        self.attn_pages_listed += attrs.get("attn_pages_listed", 0)
+        self.attn_page_steps += attrs.get("attn_page_steps", 0)
         if "moe_counts" in tick:
             counts = np.asarray(tick["moe_counts"], np.int64)
             self.moe_assignments += counts
@@ -4243,8 +4254,9 @@ class ContinuousEngine:
         # tail (what it walked before PR 42), both in the list's unit: a
         # page step is ``attn_pages_a_step`` pages, and ``attn_pages_listed``
         # over that many times ``attn_page_steps`` is how full the steps were
-        group = attrs["attn_pages_a_step"] = self.attn_pages_a_step
-        attrs["attn_steps_rect"] = self.n_slots * (-(-self.maxp // group) + 1)
+        if self.page_format.pooled:
+            group = attrs["attn_pages_a_step"] = self.attn_pages_a_step
+            attrs["attn_steps_rect"] = self.n_slots * (-(-self.maxp // group) + 1)
         if "moe_counts" in tick:
             from ditl_tpu.models.moe import split_counts
 

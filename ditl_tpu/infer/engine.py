@@ -115,13 +115,13 @@ class Generator:
     def _build(self, batch: int, prompt_len: int, gen: GenerateConfig):
         """Compile the full prefill+decode program for one shape bucket."""
         cfg, mesh, rules = self.cfg, self.mesh, self.rules
-        if "m" in cfg.layer_types:
+        if set(cfg.layer_types) & {"m", "r"}:
             # at its first program and not at construction: the server holds
             # a Generator beside every engine (tokenizer, lock-step fallbacks)
             raise ValueError(
                 "the lock-step engine (--engine lockstep) keeps keys and values "
-                "only: a state-space layer's recurrent state is carried by "
-                "--engine continuous --cache-mode paged")
+                "only: a state-space or retention layer's state a slot is carried "
+                "by --engine continuous --cache-mode paged")
         max_len = prompt_len + gen.max_new_tokens
         if max_len > cfg.max_seq_len:
             raise ValueError(

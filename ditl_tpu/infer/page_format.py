@@ -8,7 +8,9 @@ the format cannot carry (``refuse``), a prefill's row (``gather``, ``write``), a
 decode tick's tails and their one write into the pools (``tails0``, ``split``,
 ``flush``), and the tick's counters with what derives from them.
 
-Three page layouts and one component. ``WindowKVPages`` (a stack with window
+Three page layouts, one component and one format without a page.
+``StateSlots`` (a stack of retention layers, models/retention.py): STATE A
+SLOT and nothing else; no pool, no page, no tail. ``WindowKVPages`` (a stack with window
 attention layers, models/swa.py): TWO pools of keys and values, the full
 layers' and the window layers', page ids of their own; a row keeps every page
 of the first and, of the second, only those inside its window. ``KVPages``: keys and values a kv-head
@@ -19,7 +21,8 @@ donated tree. ``LatentPages``: one latent vector an attention sublayer
 pool under the same page table.
 
 The names the model code reads stay what they are: pools ``kp vp ks vs cp ip``,
-tails ``tk tv tc ti``, the state ``ssm conv``, a prefill row's ``k v c i``.
+tails ``tk tv tc ti``, the state ``ssm conv`` / ``ret retz``, a prefill row's
+``k v c i``.
 This module imports from ``models/`` and ``ops/``; nothing there imports from
 ``infer/``.
 """
@@ -37,8 +40,8 @@ from ditl_tpu.utils.logging import get_logger
 
 logger = get_logger(__name__)
 
-__all__ = ["KVPages", "LatentPages", "MODES", "PageFormat", "WindowKVPages", "page_format",
-           "tail_width"]
+__all__ = ["KVPages", "LatentPages", "MODES", "PageFormat", "StateSlots", "WindowKVPages",
+           "page_format", "tail_width"]
 
 
 def tail_width(decode_chunk: int) -> int:
@@ -140,6 +143,37 @@ _HANDOFF = ("the disaggregated KV handoff (export_kv / import_kv) cannot "
             "carry latent pages or a recurrent state yet")
 
 
+def _state_stats(per_slot: int, n_slots: int, totals: dict, slots_seated: int) -> dict:
+    """The ``/v1/stats`` keys of a format that keeps a state a slot."""
+    return {"ssm_state_bytes_per_slot": per_slot,
+            "ssm_state_bytes_resident": per_slot * n_slots,
+            "ssm_slots_seated": slots_seated,
+            "ssm_row_steps_total": totals["ssm_row_steps"]}
+
+
+def _slot_state(pools, axes: dict[str, int], slot, offset) -> dict:
+    """The slot's recurrent state as a prefill's transient row carries it: what
+    a chunk before this one left, or zeros at a sequence's start (whatever the
+    slot's last tenant left is never read). The bucket's padding leaves it at
+    the last real token's."""
+    row = {}
+    for k, axis in axes.items():
+        was = jax.lax.dynamic_slice_in_dim(pools[k], slot, 1, axis=axis)
+        row[k] = jnp.where(offset > 0, was, jnp.zeros_like(was))
+    return row
+
+
+def _seat_state(pools, row, axes: dict[str, int], slot) -> dict:
+    """The row's state seated at the slot, in place."""
+    return {k: jax.lax.dynamic_update_slice_in_dim(pools[k], row[k], slot, axis=axis)
+            for k, axis in axes.items()}
+
+
+def _count_live(acc: dict, alive) -> dict:
+    """The live rows: each read and wrote its state once a mixer."""
+    return {**acc, "ssm_row_steps": acc["ssm_row_steps"] + alive.sum(dtype=jnp.int32)}
+
+
 class PageFormat:
     """What both layouts share: the sizes an engine was built with, the
     refusal, the counters' protocol. A layout adds ``page_bytes``, ``fresh()``,
@@ -157,6 +191,9 @@ class PageFormat:
     masks_tokens = False
     # the content cache is fed and consulted (a page serves whoever matches it)
     publishes = True
+    # a token's entry lies in a page of a pool (a format without one asks for
+    # no page, lists no attention step and takes no ``n_pages``)
+    pooled = True
 
     def __init__(self, cfg: ModelConfig, *, n_pages: int, page_size: int, n_slots: int,
                  decode_chunk: int, mesh=None, rules=None, window_pages: int = 0):
@@ -173,6 +210,10 @@ class PageFormat:
     @property
     def carries(self) -> frozenset[str]:
         return frozenset(MODES) - frozenset(self.refused)
+
+    def pages_for(self, tokens: int) -> int:
+        """Pages a row of ``tokens`` tokens holds."""
+        return -(-tokens // self.page_size)
 
     def refuse(self, *asked: str, error: type[Exception] = ValueError) -> None:
         """Raise for the first of the modes ``asked`` this format cannot carry."""
@@ -354,11 +395,7 @@ class KVPages(PageFormat):
             return {}
         from ditl_tpu.models.ssm import state_bytes_per_slot
 
-        per_slot = state_bytes_per_slot(self.cfg)
-        return {"ssm_state_bytes_per_slot": per_slot,
-                "ssm_state_bytes_resident": per_slot * self.n_slots,
-                "ssm_slots_seated": slots_seated,
-                "ssm_row_steps_total": totals["ssm_row_steps"]}
+        return _state_stats(state_bytes_per_slot(self.cfg), self.n_slots, totals, slots_seated)
 
     def span_attrs(self, tick, decode_chunk):
         return {"ssm_steps": decode_chunk} if self.state_axes else {}
@@ -391,13 +428,8 @@ class KVPages(PageFormat):
             "k": jnp.concatenate([ctx_k, zeros], axis=2),
             "v": jnp.concatenate([ctx_v, zeros], axis=2),
         }
-        # The slot's recurrent state rides the transient row: what a chunk
-        # before this one left, or zeros at a sequence's start (whatever the
-        # slot's last tenant left is never read). The bucket's padding leaves
-        # it at the last real token's.
-        for k, axis in self.state_axes.items():
-            was = jax.lax.dynamic_slice_in_dim(pools[k], slot, 1, axis=axis)
-            row[k] = jnp.where(offset > 0, was, jnp.zeros_like(was))
+        # the slot's recurrent state rides the transient row
+        row.update(_slot_state(pools, self.state_axes, slot, offset))
         return row
 
     @jax.named_scope("kv_write")
@@ -420,9 +452,7 @@ class KVPages(PageFormat):
             for j in range(n_wp):
                 out[name] = jax.lax.dynamic_update_slice(
                     out[name], chunk[:, j:j + 1], (0, write_pids[j], 0, 0, 0))
-        for k, axis in self.state_axes.items():  # seat the slot's state
-            out[k] = jax.lax.dynamic_update_slice_in_dim(
-                pools[k], row[k], slot, axis=axis)
+        out.update(_seat_state(pools, row, self.state_axes, slot))
         return out
 
     def tails0(self, n_b: int, tail_len: int | None = None) -> dict[str, jax.Array]:
@@ -445,10 +475,86 @@ class KVPages(PageFormat):
         return out
 
     def count(self, acc, *, alive, lengths, starts, meta, counted):
-        if not self.state_axes:
-            return acc
-        # the live rows: each read and wrote its state once a mixer
-        return {**acc, "ssm_row_steps": acc["ssm_row_steps"] + alive.sum(dtype=jnp.int32)}
+        return _count_live(acc, alive) if self.state_axes else acc
+
+
+class StateSlots(PageFormat):
+    """A stack of retention layers (models/retention.py): a sequence's cache
+    entry is its STATE, of fixed size, seated at its slot, and nothing else.
+    The donated tree holds the state alone; a request asks for no page
+    (``pages_for`` is 0, so admission, holds and preemption go by slots), a
+    row's length is bounded by the engine's ``max_cache_len`` (the positions
+    of the rotation), a prefill's row is the slot's state (zeros at a
+    sequence's start: what the slot's last tenant left is never read), a
+    decode tick has no tails and nothing to flush. Nothing is published:
+    state snapshots at boundaries, for prefix reuse and for resume, are not
+    kept yet, so a preempted request runs again from its first token."""
+
+    counters = ("ssm_row_steps",)
+    masks_tokens = True
+    publishes = False
+    pooled = False
+    page_bytes = 0
+
+    def __init__(self, cfg: ModelConfig, **kw):
+        from ditl_tpu.models.retention import SLOT_AXIS
+
+        super().__init__(cfg, **kw)
+        self.n_pages = 2  # the host allocator's sentinel and one id, which no row asks for
+        self.state_axes = SLOT_AXIS
+        said = {**_OPTION, "mesh": "a mesh (mesh, and pod serving over it)",
+                "adapters": "LoRA adapters (lora_rank)", "pod": "pod serving",
+                "handoff": "the disaggregated KV handoff (export_kv / import_kv)",
+                "registered prefix": "register_prefix"}
+        self.refused = {
+            mode: f"a stack of retention layers (layer_types={cfg.layer_types[:4]!r}...) "
+                  f"keeps a state a slot and no keys and values, which {option} cannot "
+                  "carry yet: serve it with cache_mode='paged', plain ticks, no int8, "
+                  "no host tier, no mesh and no adapters, one process on one chip"
+            for mode, option in said.items()}
+
+    def pages_for(self, tokens: int) -> int:
+        return 0
+
+    def attn_pages_a_step(self, max_pages: int) -> int:
+        return 0  # no attention kernel walks anything
+
+    def fresh(self) -> dict[str, jax.Array]:
+        from ditl_tpu.models.retention import init_state
+
+        return init_state(self.cfg, self.n_slots)
+
+    def stats(self, totals, slots_seated):
+        from ditl_tpu.models.retention import state_bytes_per_slot
+
+        return {"pages_total": 0, "pages_free": 0,  # ids without a pool behind them
+                **_state_stats(state_bytes_per_slot(self.cfg), self.n_slots, totals,
+                               slots_seated)}
+
+    def span_attrs(self, tick, decode_chunk):
+        return {"ssm_steps": decode_chunk}
+
+    def slot_operand(self, slot):
+        return jnp.int32(slot)
+
+    def gather(self, pools, table_row, ctx_pages: int, s_bucket: int, *, offset, slot=None):
+        return _slot_state(pools, self.state_axes, slot, offset)
+
+    @jax.named_scope("kv_write")
+    def write(self, pools, row, offset, write_pids, *, slot=None):
+        return _seat_state(pools, row, self.state_axes, slot)
+
+    def tails0(self, n_b: int, tail_len: int | None = None) -> dict[str, jax.Array]:
+        return {}
+
+    def split(self, pools) -> tuple[dict, dict]:
+        return {}, dict(pools)
+
+    def flush(self, pools, carried, starts, pos, table) -> dict:
+        return {k: carried[k] for k in self.state_axes}
+
+    def count(self, acc, *, alive, lengths, starts, meta, counted):
+        return _count_live(acc, alive)
 
 
 class LatentPages(PageFormat):
@@ -789,7 +895,9 @@ class WindowKVPages(PageFormat):
 def page_format(cfg: ModelConfig, **kw) -> PageFormat:
     """The format of ``cfg``'s cache entries (derived, never a setting): latent
     pages where attention is latent, two K/V pools where the stack has window
-    layers, K/V pages otherwise."""
+    layers, state slots where it keeps no keys and values, K/V pages otherwise."""
     if cfg.kv_lora_rank > 0:
         return LatentPages(cfg, **kw)
+    if cfg.retention_layer:
+        return StateSlots(cfg, **kw)
     return (WindowKVPages if cfg.window_layer else KVPages)(cfg, **kw)

@@ -77,6 +77,11 @@ def config_from_hf(hf_config: Any, **overrides) -> ModelConfig:
         if getattr(hf_config, "clip_qkv", None) is not None:
             raise ValueError("OLMoE's clip_qkv is not implemented (the "
                              "published 1B-7B configs leave it null)")
+    elif getattr(hf_config, "model_type", "") == "brumby":
+        # every layer a retention layer; what the config has no key for (the
+        # degree, the chunk, eps) is ModelConfig's default (models/retention.py)
+        kwargs.update(layer_types="r" * hf_config.num_hidden_layers, qk_norm=True,
+                      fused_gate_up=True)
     scaling = getattr(hf_config, "rope_scaling", None)
     if scaling and scaling.get("rope_type", scaling.get("type")) == "llama3":
         kwargs["rope_scaling_factor"] = scaling["factor"]
@@ -212,7 +217,48 @@ _HYBRID_MIXER = {"dt_bias": "mamba.dt_bias", "A_log": "mamba.A_log", "D": "mamba
 _HYBRID_ATTN = {"wq": "q_proj", "wk": "k_proj", "wv": "v_proj", "wo": "o_proj"}
 
 
+# Brumby's retention layer (models/retention.py; ``model_type: brumby``): the
+# Qwen3 block's names (``self_attn`` with q/k/v/o, a ``q_norm`` / ``k_norm`` a
+# head; ``mlp`` with ``gate_proj`` / ``up_proj`` / ``down_proj``, fused here
+# into ``w_gu``) and the retention's gate a kv head, ``self_attn.gate_proj``
+# with its bias (the gate's name is this repository's: recalled from no
+# published checkpoint).
+_RET_MATRICES = {**_HYBRID_ATTN, "wg": "gate_proj"}
+_RET_VECTORS = {"q_norm": "q_norm.weight", "k_norm": "k_norm.weight", "bg": "gate_proj.bias"}
+
+
+def _retention_layer_from_state_dict(sd, i: int) -> dict[str, Any]:
+    p = f"model.layers.{i}."
+    return {
+        "attn_norm": {"scale": _np(sd[p + "input_layernorm.weight"])},
+        "mlp_norm": {"scale": _np(sd[p + "post_attention_layernorm.weight"])},
+        "mlp": {"w_gu": np.concatenate([_np(sd[p + "mlp.gate_proj.weight"]).T,
+                                        _np(sd[p + "mlp.up_proj.weight"]).T], axis=1),
+                "w_down": _np(sd[p + "mlp.down_proj.weight"]).T},
+        "ret": {**{ours: _np(sd[p + f"self_attn.{theirs}.weight"]).T
+                   for ours, theirs in _RET_MATRICES.items()},
+                **{ours: _np(sd[p + f"self_attn.{theirs}"])
+                   for ours, theirs in _RET_VECTORS.items()}},
+    }
+
+
+def _retention_layer_state_dict(sub, n: int, p: str, host) -> dict[str, np.ndarray]:
+    gate, up = np.split(host(sub["mlp"]["w_gu"][n]), 2, axis=1)
+    return {
+        p + "input_layernorm.weight": host(sub["attn_norm"]["scale"][n]),
+        p + "post_attention_layernorm.weight": host(sub["mlp_norm"]["scale"][n]),
+        p + "mlp.gate_proj.weight": gate.T, p + "mlp.up_proj.weight": up.T,
+        p + "mlp.down_proj.weight": host(sub["mlp"]["w_down"][n]).T,
+        **{p + f"self_attn.{theirs}.weight": host(sub["ret"][ours][n]).T
+           for ours, theirs in _RET_MATRICES.items()},
+        **{p + f"self_attn.{theirs}": host(sub["ret"][ours][n])
+           for ours, theirs in _RET_VECTORS.items()},
+    }
+
+
 def _hybrid_layer_from_state_dict(sd, cfg: ModelConfig, i: int) -> dict[str, Any]:
+    if cfg.layer_types[i] == "r":
+        return _retention_layer_from_state_dict(sd, i)
     p = f"model.layers.{i}."
     inner = cfg.ssm_heads * cfg.ssm_head_dim
     layer: dict[str, Any] = {
@@ -254,6 +300,9 @@ def _hybrid_state_dict(layers, cfg: ModelConfig, host) -> dict[str, np.ndarray]:
     sd: dict[str, np.ndarray] = {}
     for i in range(cfg.num_layers):
         sub, n, p = layers[f"sub{i % period}"], i // period, f"model.layers.{i}."
+        if "ret" in sub:
+            sd.update(_retention_layer_state_dict(sub, n, p, host))
+            continue
         sd[p + "input_layernorm.weight"] = host(sub["attn_norm"]["scale"][n])
         sd[p + "post_attention_layernorm.weight"] = host(sub["mlp_norm"]["scale"][n])
         sd[p + "shared_mlp.input_linear.weight"] = host(sub["mlp"]["w_gu"][n]).T

@@ -822,16 +822,16 @@ def forward(
     if cfg.layer_types and not cfg.window_layer:
         # Granite-4.0-H: one scan step a PERIOD of unlike layers (models/ssm.py)
         from ditl_tpu.models.ssm import hybrid_period as block
-        from ditl_tpu.models.ssm import period_counts
+        from ditl_tpu.models.ssm import period_counts, state_axes
 
         n_scan, _, attn_per = period_counts(cfg)
         if cache is not None:
-            if "ssm" not in cache:
+            if set(state_axes(cfg)) - set(cache):
                 raise ValueError(
                     "a cached forward of a hybrid stack needs the sequences' "
-                    "recurrent state beside the keys and values (cache['ssm'], "
-                    "cache['conv']: models/ssm.py init_state)")
-            rec = {k: cache[k] for k in ("ssm", "conv")}
+                    f"recurrent state beside the keys and values ({list(state_axes(cfg))} "
+                    "in cache: models/ssm.py init_state)")
+            rec = {k: cache[k] for k in state_axes(cfg)}
             cache = {k: v for k, v in cache.items() if k not in rec}
 
     if cfg.dsa_layer:

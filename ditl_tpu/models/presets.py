@@ -291,6 +291,32 @@ PRESETS: dict[str, ModelConfig] = {
         experts_held_first=0,
         experts_held_count=128,
     ),
+    # Brumby-14B-Base (manifestai, ``model_type: brumby``; the cut that is
+    # served is benchmarks/configs/brumby-14b-cut1.json): the Qwen3-14B block
+    # (40 layers, 40 query heads in 8 groups of 128-wide heads, q/k norm a
+    # head, rotation, a SwiGLU FFN of 17,408, an untied head of 151,936) with
+    # gated power retention of degree 2 in place of softmax attention: no
+    # keys and values, a float32 state of 8 x 128 x 9,216 a layer a sequence
+    # (36 MiB); models/retention.py.
+    "brumby-14b": ModelConfig(
+        name="brumby-14b",
+        vocab_size=151936,
+        hidden_size=5120,
+        intermediate_size=17408,
+        num_layers=40,
+        num_heads=40,
+        num_kv_heads=8,
+        head_dim=128,
+        max_seq_len=32768,
+        rope_theta=1000000.0,
+        rms_norm_eps=1e-6,
+        qk_norm=True,
+        fused_gate_up=True,
+        layer_types="r" * 40,
+        ret_degree=2,
+        ret_chunk=128,
+        ret_eps=1e-5,
+    ),
 }
 
 

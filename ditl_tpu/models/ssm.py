@@ -72,7 +72,7 @@ from ditl_tpu.config import ModelConfig
 from ditl_tpu.ops import ssd
 
 __all__ = ["init_hybrid_params", "hybrid_logical_axes", "hybrid_period", "period_counts",
-           "conv_width", "init_state", "state_bytes_per_slot", "SLOT_AXIS"]
+           "conv_width", "init_state", "state_bytes_per_slot", "SLOT_AXIS", "state_axes"]
 
 F32 = jnp.float32
 
@@ -104,6 +104,17 @@ def init_state(cfg: ModelConfig, rows: int) -> dict[str, jax.Array]:
 
 # Which axis of each leaf of ``init_state`` counts the sequences (slots).
 SLOT_AXIS = {"ssm": 1, "conv": 2}
+
+
+def state_axes(cfg: ModelConfig) -> dict[str, int]:
+    """The leaves of the stack's state a slot (``init_state``'s, or
+    ``retention.init_state``'s) and the axis of each that counts the slots:
+    what a cached forward pass carries beside the stream."""
+    if cfg.retention_layer:
+        from ditl_tpu.models.retention import SLOT_AXIS as axes
+
+        return axes
+    return SLOT_AXIS
 
 
 def state_bytes_per_slot(cfg: ModelConfig) -> int:
@@ -156,10 +167,17 @@ def init_hybrid_params(rng: jax.Array, cfg: ModelConfig) -> dict[str, Any]:
             "wv": dense((d, nkv * hd), d), "wo": dense((nh * hd, d), nh * hd),
         }
 
+    def mixer_of(kind):
+        if kind == "r":
+            from ditl_tpu.models.retention import init_retention
+
+            return {"ret": init_retention(dense, uniform, cfg, n_per)}
+        return {"ssm": mixer()} if kind == "m" else {"attn": attention()}
+
     return {
         f"sub{j}": {
             "attn_norm": {"scale": jnp.ones((n_per, d), pd)},
-            **({"ssm": mixer()} if kind == "m" else {"attn": attention()}),
+            **mixer_of(kind),
             "mlp_norm": {"scale": jnp.ones((n_per, d), pd)},
             "mlp": {"w_gu": dense((d, 2 * f), d), "w_down": dense((f, d), f)},
         }
@@ -179,10 +197,13 @@ def hybrid_logical_axes(cfg: ModelConfig) -> dict[str, Any]:
         "wq": ("layers", "embed", "heads"), "wk": ("layers", "embed", "kv_heads"),
         "wv": ("layers", "embed", "kv_heads"), "wo": ("layers", "heads", "embed"),
     }
+    from ditl_tpu.models.retention import retention_axes
+
+    kinds = {"m": {"ssm": mixer}, "a": {"attn": attention}, "r": {"ret": retention_axes()}}
     return {
         f"sub{j}": {
             "attn_norm": {"scale": ("layers", "norm")},
-            **({"ssm": dict(mixer)} if kind == "m" else {"attn": dict(attention)}),
+            **{name: dict(axes) for name, axes in kinds[kind].items()},
             "mlp_norm": {"scale": ("layers", "norm")},
             "mlp": {"w_gu": ("layers", "embed", "mlp"), "w_down": ("layers", "mlp", "embed")},
         }
@@ -293,7 +314,7 @@ def hybrid_period(
 
     if adapter_ids is not None or with_moe_counts or moe_stack is not None:
         raise ValueError("a hybrid stack has no LoRA adapters and no experts")
-    _, n_m, _ = period_counts(cfg)
+    n_m = len(cfg.layer_period) - cfg.layer_period.count("a")  # mixers a period
     cd = jnp.dtype(cfg.dtype)
     cached = layer_cache is not None
     doc = None
@@ -322,10 +343,17 @@ def hybrid_period(
             continue
         with jax.named_scope("attn_qkv"):
             h = rms_norm(x, sub["attn_norm"]["scale"], cfg.rms_norm_eps).astype(cd)
-        out, rec = _mamba_mixer(
-            sub["ssm"], h, cfg=cfg, rec=rec,
-            at=None if rec is None else layer_index * n_m + i_m,
-            valid=token_mask if cached else None, doc=doc)
+        at = None if rec is None else layer_index * n_m + i_m
+        if kind == "r":
+            from ditl_tpu.models.retention import retention_mixer
+
+            if doc is not None:
+                raise ValueError("a retention layer does not carry packed documents")
+            out, rec = retention_mixer(sub["ret"], h, cfg=cfg, positions=positions, rec=rec,
+                                       at=at, valid=token_mask if cached else None)
+        else:
+            out, rec = _mamba_mixer(sub["ssm"], h, cfg=cfg, rec=rec, at=at,
+                                    valid=token_mask if cached else None, doc=doc)
         with jax.named_scope("attn_out"):
             x = _constrain(x + res * out, ("batch", "seq", "act_embed"), mesh, rules)
         with jax.named_scope("mlp"):
@@ -334,6 +362,6 @@ def hybrid_period(
             x = _constrain(x, ("batch", "seq", "act_embed"), mesh, rules)
         i_m += 1
     out = (x, jnp.zeros((), F32))
-    if cached:
-        out += ({k: jnp.stack([kv[k] for kv in new_kv]) for k in new_kv[0]}, rec)
+    if cached:  # a period without an attention layer returns no keys and values
+        out += ({k: jnp.stack([kv[k] for kv in new_kv]) for k in (new_kv or [{}])[0]}, rec)
     return out

@@ -51,6 +51,17 @@ projection) inside ``attn_out``. The seat of a slot's state after a prefill
 is ``kv_write``. ``ssd_step`` (``SSM_KERNELS``), inside ``ssm_scan``: the
 decode step's update of the live rows' states in place (``ops/ssd.py``).
 
+``RET_SCOPES`` are the parts of a power-retention mixer (``models/
+retention.py``), each INSIDE the scope of ``SCOPES`` it refines: ``ret_in``
+(the projections, the head norms, the rotation, the gate) inside
+``attn_qkv``; ``ret_state`` (a prefill call's scan, or a decode step's
+feature maps, the sum of keys, the read, update, read-out and write back of
+the live rows' states, and the division) inside ``attn_core``; ``ret_out``
+(the output projection) inside ``attn_out``. The seat of a slot's state after
+a prefill is ``kv_write``. ``ret_step`` (``RET_KERNELS``), inside
+``ret_state``: the decode step's update of the live rows' states in place
+(``ops/retention.py``).
+
 ``DSA_SCOPES`` are the parts of DeepSeek-V3.2's sparse attention (``models/
 dsa.py``), each INSIDE a scope of ``SCOPES``: ``dsa_index`` is the lightning
 indexer, inside ``attn_qkv`` its projections (the index queries from the
@@ -132,12 +143,21 @@ SSM_SCOPES = (
     "ssm_out",
 )
 
+RET_SCOPES = (
+    "ret_in",
+    "ret_state",
+    "ret_out",
+)
+
 ATTN_SCOPES = ("attn_steps",)
 
 SWA_SCOPES = ("attn_window", "attn_full")
 
 # The decode step's state update (``ops/ssd.py``), inside ``ssm_scan``.
 SSM_KERNELS = ("ssd_step",)
+
+# The decode step's state update (``ops/retention.py``), inside ``ret_state``.
+RET_KERNELS = ("ret_step",)
 
 # Decode attention over a latent page pool (``ops/mla_attention.py``), inside
 # ``mla_attn``.

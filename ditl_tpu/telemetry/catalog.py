@@ -314,10 +314,10 @@ _ROWS: tuple = (
     ("ditl_serving_speculative_spec_ticks", "gauge", "", "ticks that ran speculatively"),
     ("ditl_serving_speculative_threshold", "gauge", "", "predicted-acceptance threshold for speculating"),
     ("ditl_serving_speculative_ticks", "gauge", "", "ticks counted by the speculation decision path"),
-    ("ditl_serving_ssm_row_steps_total", "gauge", "", "live rows summed over the decode ticks' steps of a model with state-space layers: each read and rewrote its recurrent state once a mixer (lifetime count from /v1/stats)", True),
-    ("ditl_serving_ssm_slots_seated", "gauge", "", "slots whose recurrent state belongs to a request in flight (a model with state-space layers; a freed slot's state is overwritten by the next seat, never cleared)", True),
-    ("ditl_serving_ssm_state_bytes_per_slot", "gauge", "", "recurrent state one slot holds over all state-space mixers: the float32 state and the convolution window", True),
-    ("ditl_serving_ssm_state_bytes_resident", "gauge", "", "recurrent state resident on the device: bytes a slot times the slots, allocated once beside the page pool", True),
+    ("ditl_serving_ssm_row_steps_total", "gauge", "", "live rows summed over the decode ticks' steps of a model with state-space or retention layers: each read and rewrote its recurrent state once a mixer (lifetime count from /v1/stats)", True),
+    ("ditl_serving_ssm_slots_seated", "gauge", "", "slots whose recurrent state belongs to a request in flight (a model with state-space or retention layers; a freed slot's state is overwritten by the next seat, never cleared)", True),
+    ("ditl_serving_ssm_state_bytes_per_slot", "gauge", "", "recurrent state one slot holds over all state-space mixers (the float32 state and the convolution window) or retention layers (the float32 state and sum of keys)", True),
+    ("ditl_serving_ssm_state_bytes_resident", "gauge", "", "recurrent state resident on the device: bytes a slot times the slots, allocated once beside the page pool (a stack of retention layers has no pool beside it)", True),
     ("ditl_serving_staged", "gauge", "", "requests staged for the next pod tick broadcast", True),
     ("ditl_serving_ticks_overlapped_total", "gauge", "", "scheduler steps that fetched and harvested one decode tick while the next tick's program was already enqueued on the device (double-buffered ticks; lifetime count from /v1/stats)"),
     ("ditl_serving_token_budget", "gauge", "", "per-tick token budget (0 = unbudgeted)"),

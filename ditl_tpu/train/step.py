@@ -49,6 +49,12 @@ def loss_fn(
     lm-head+CE (ops/fused_ce.py) — same value, no (B, S, V) logits tensor."""
     if cfg.loss_impl not in ("naive", "fused"):
         raise ValueError(f"unknown loss_impl {cfg.loss_impl!r} (naive|fused)")
+    if cfg.retention_layer:
+        raise ValueError(
+            "a stack of retention layers (layer_types 'r', models/retention.py) is served, "
+            "not trained: its prefill scan carries no packed documents and has no "
+            "backward pass of its own yet (infer/server.py --engine continuous "
+            "--cache-mode paged serves it)")
     targets = batch["input_ids"][:, 1:]
     mask = batch["loss_mask"][:, 1:].astype(jnp.float32)
     n_tokens = jnp.maximum(mask.sum(), 1.0)

@@ -94,11 +94,11 @@ def tpu_branch(monkeypatch):
     """The code asks ``jax.default_backend()``, which is the CPU here: take
     the branch the chip takes."""
     from ditl_tpu.models import moe as moe_mod
-    from ditl_tpu.ops import backend, kv_flush, paged_attention, ssd
+    from ditl_tpu.ops import backend, kv_flush, paged_attention, retention, ssd
 
     monkeypatch.setattr(moe_mod, "_use_gmm", lambda rows, mesh: rows % 128 == 0)
     # every module that bound the name at its import, whichever came first
-    for module in (moe_mod, backend, kv_flush, paged_attention, ssd):
+    for module in (moe_mod, backend, kv_flush, paged_attention, retention, ssd):
         monkeypatch.setattr(module, "interpret_default", lambda: False)
 
 

@@ -228,7 +228,7 @@ def test_the_preset_is_the_published_model():
 
 
 @pytest.mark.parametrize("kw, said", [
-    (dict(layer_types="mmx"), "each 'm', 'a' or 'w'"),
+    (dict(layer_types="mmx"), "each 'm', 'a', 'w' or 'r'"),
     (dict(layer_types="mm"), "num_layers"),
     (dict(ssm_state=0), "ssm_heads"),
     (dict(fused_gate_up=False), "fused_gate_up"),

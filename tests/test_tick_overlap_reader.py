@@ -92,7 +92,8 @@ def test_the_readers_constants_are_the_manifests(reader):
     assert (entry["layer"], entry["unit"], entry["moves"], entry["source"]) == (
         reader.LAYER, reader.UNIT, reader.MOVES, reader.SOURCE)
     # every serving cell: three until PR 41 added the fourth, PR 44 the fifth
-    # (a closed loop over documents: its traffic is no "chat")
+    # (a closed loop over documents: its traffic is no "chat"), PR 56 the
+    # seventh (a closed loop of streams)
     serving = [w["name"] for w in manifest["workloads"]
-               if "chat" in w["traffic"] or "docs" in w["traffic"]]
+               if any(kind in w["traffic"] for kind in ("chat", "docs", "streams"))]
     assert entry["better"] == "higher" and entry["workloads"] == serving and len(serving) >= 3

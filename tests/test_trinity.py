@@ -161,7 +161,7 @@ REFUSED = [
     (dict(residual_dtype="float32"), "does not carry residual_dtype"),
     (dict(attention_impl="ring"), "attention_impl other than xla or flash"),
     (dict(tie_embeddings=True), "does not carry tie_embeddings"),
-    (dict(layer_types="wwwawwwx"), "each 'm', 'a' or 'w'"),
+    (dict(layer_types="wwwawwwx"), "each 'm', 'a', 'w' or 'r'"),
     (dict(position_embedding="alibi"), "rope|nope|rope_window"),
 ]
 
