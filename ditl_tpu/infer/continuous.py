@@ -1700,7 +1700,7 @@ class ContinuousEngine:
                 )
                 # what the forward pass counted, named where it returns it
                 counted = dict(zip(("moe_counts", "dsa_selected"), moe_counts))
-                acc = fmt.count(acc, alive=step_alive, lengths=lengths, starts=starts,
+                acc = fmt.count(acc, t=t, alive=step_alive, lengths=lengths, starts=starts,
                                 meta=format_meta, counted=counted)
                 if moe:
                     # the live rows' assignments; the experts they touched

@@ -170,7 +170,8 @@ def _seat_state(pools, row, axes: dict[str, int], slot) -> dict:
 
 
 def _count_live(acc: dict, alive) -> dict:
-    """The live rows: each read and wrote its state once a mixer."""
+    """The live rows: each read its state once a mixer (and, a state-space
+    mixer's, wrote it)."""
     return {**acc, "ssm_row_steps": acc["ssm_row_steps"] + alive.sum(dtype=jnp.int32)}
 
 
@@ -203,7 +204,7 @@ class PageFormat:
                 "no window attention layer")
         self.cfg = cfg
         self.n_pages, self.page_size, self.n_slots = n_pages, page_size, n_slots
-        self.tail_len = tail_width(decode_chunk)
+        self.decode_chunk, self.tail_len = decode_chunk, tail_width(decode_chunk)
         self.mesh, self.rules = mesh, rules
         self.dtype = jnp.dtype(cfg.dtype)
 
@@ -236,8 +237,8 @@ class PageFormat:
         """A prefill call's ``slot`` operand: None (no operand) where no state is."""
         return None
 
-    def count(self, acc: dict, *, alive, lengths, starts, meta, counted) -> dict:
-        """``acc`` (``counters``, by name) after one decode step: ``alive`` its
+    def count(self, acc: dict, *, t, alive, lengths, starts, meta, counted) -> dict:
+        """``acc`` (``counters``, by name) after decode step ``t`` of a tick: ``alive`` its
         live rows, ``lengths`` their contexts (0 for a dead row), ``meta`` what
         ``tick_meta`` gave, ``counted`` the forward pass's counts, by name."""
         return acc
@@ -474,7 +475,7 @@ class KVPages(PageFormat):
         out.update({k: carried[k] for k in self.state_axes})
         return out
 
-    def count(self, acc, *, alive, lengths, starts, meta, counted):
+    def count(self, acc, *, t, alive, lengths, starts, meta, counted):
         return _count_live(acc, alive) if self.state_axes else acc
 
 
@@ -485,12 +486,20 @@ class StateSlots(PageFormat):
     (``pages_for`` is 0, so admission, holds and preemption go by slots), a
     row's length is bounded by the engine's ``max_cache_len`` (the positions
     of the rotation), a prefill's row is the slot's state (zeros at a
-    sequence's start: what the slot's last tenant left is never read), a
-    decode tick has no tails and nothing to flush. Nothing is published:
-    state snapshots at boundaries, for prefix reuse and for resume, are not
-    kept yet, so a preempted request runs again from its first token."""
+    sequence's start: what the slot's last tenant left is never read).
 
-    counters = ("ssm_row_steps",)
+    A decode tick's tails are its HELD TOKENS (``retention.held_tokens``: each
+    step's ``k``, ``v`` and ``log g`` a layer a row a kv head, zeros at the
+    tick's start): a step reads a live row's state and the tick's LAST step
+    alone writes it, the held tokens folded in (``ops/retention.py``), so
+    there is nothing left to flush and ``flush`` drops them. A tick of one
+    step holds nothing. ``ret_row_folds`` counts the rows whose state a tick
+    wrote (those live at its last step) beside ``ssm_row_steps``, the rows
+    whose state a step read. Nothing is published: state snapshots at
+    boundaries, for prefix reuse and for resume, are not kept yet, so a
+    preempted request runs again from its first token."""
+
+    counters = ("ssm_row_steps", "ret_row_folds")
     masks_tokens = True
     publishes = False
     pooled = False
@@ -529,7 +538,8 @@ class StateSlots(PageFormat):
 
         return {"pages_total": 0, "pages_free": 0,  # ids without a pool behind them
                 **_state_stats(state_bytes_per_slot(self.cfg), self.n_slots, totals,
-                               slots_seated)}
+                               slots_seated),
+                "ret_row_folds_total": totals["ret_row_folds"]}
 
     def span_attrs(self, tick, decode_chunk):
         return {"ssm_steps": decode_chunk}
@@ -545,16 +555,21 @@ class StateSlots(PageFormat):
         return _seat_state(pools, row, self.state_axes, slot)
 
     def tails0(self, n_b: int, tail_len: int | None = None) -> dict[str, jax.Array]:
-        return {}
+        from ditl_tpu.models.retention import held_tokens
+
+        return held_tokens(self.cfg, n_b, self.decode_chunk) if self.decode_chunk > 1 else {}
 
     def split(self, pools) -> tuple[dict, dict]:
         return {}, dict(pools)
 
     def flush(self, pools, carried, starts, pos, table) -> dict:
+        # the held tokens were folded in by the tick's last step
         return {k: carried[k] for k in self.state_axes}
 
-    def count(self, acc, *, alive, lengths, starts, meta, counted):
-        return _count_live(acc, alive)
+    def count(self, acc, *, t, alive, lengths, starts, meta, counted):
+        acc = _count_live(acc, alive)
+        folds = jnp.where(t == self.decode_chunk - 1, alive.sum(dtype=jnp.int32), 0)
+        return {**acc, "ret_row_folds": acc["ret_row_folds"] + folds}
 
 
 class LatentPages(PageFormat):
@@ -670,7 +685,7 @@ class LatentPages(PageFormat):
             return {"index_steps": index_steps(starts, listed, page_size=self.page_size,
                                                max_pages=max_pages)}
 
-    def count(self, acc, *, alive, lengths, starts, meta, counted):
+    def count(self, acc, *, t, alive, lengths, starts, meta, counted):
         # the context tokens this step's rows had (what the latent kernel had
         # to read, a sublayer)
         out = {**acc, "decode_ctx_tokens": acc["decode_ctx_tokens"] + lengths.sum()}
@@ -864,7 +879,7 @@ class WindowKVPages(PageFormat):
             win, full = (jnp.where(listed, n, 0).sum(dtype=jnp.int32) for n in (win, full))
         return {"table": table[0], "wtable": table[1], "wsteps": wsteps, "pages": (win, full)}
 
-    def count(self, acc, *, alive, lengths, starts, meta, counted):
+    def count(self, acc, *, t, alive, lengths, starts, meta, counted):
         win, full = meta["pages"]  # a call of each kernel walked them
         return {**acc, "window_pages_walked": acc["window_pages_walked"] + win,
                 "full_pages_walked": acc["full_pages_walked"] + full}

@@ -822,7 +822,7 @@ def forward(
     if cfg.layer_types and not cfg.window_layer:
         # Granite-4.0-H: one scan step a PERIOD of unlike layers (models/ssm.py)
         from ditl_tpu.models.ssm import hybrid_period as block
-        from ditl_tpu.models.ssm import period_counts, state_axes
+        from ditl_tpu.models.ssm import period_counts, state_axes, tick_leaves
 
         n_scan, _, attn_per = period_counts(cfg)
         if cache is not None:
@@ -831,7 +831,9 @@ def forward(
                     "a cached forward of a hybrid stack needs the sequences' "
                     f"recurrent state beside the keys and values ({list(state_axes(cfg))} "
                     "in cache: models/ssm.py init_state)")
-            rec = {k: cache[k] for k in state_axes(cfg)}
+            # the state, and what a decode tick holds beside it (retention's
+            # held tokens: ``page_format.StateSlots.tails0``)
+            rec = {k: cache[k] for k in (*state_axes(cfg), *tick_leaves(cfg)) if k in cache}
             cache = {k: v for k, v in cache.items() if k not in rec}
 
     if cfg.dsa_layer:

@@ -21,6 +21,12 @@ heads in ``K`` groups, one key/value head a group::
         z_t = g_t z_(t-1) + phi(k_t)                    (D, float32)
         y_t = S_t phi(q_t) / (phi(q_t) . z_t + eps)     (each of the group's query heads)
 
+    a decode step (``ops/retention.py`` ``ret_step_rows``): the recurrent form
+        through the state as the TICK's start left it, the attention form over
+        the tick's own tokens, held beside the state; the tick's last step
+        folds them into ``S`` (read once a step, written once a tick; ``z``
+        is rewritten every step)
+
     a prefill call (``ops/retention.py`` ``ret_scan``): the attention form
         against the call's own keys, ``ret_chunk`` queries at a time; what the
         sequence carried in, through phi(q_t) and the state decayed from the
@@ -30,7 +36,10 @@ A sequence's cache entry is STATE and nothing else: ``rec = {"ret": (layers,
 B, K, d, D), "retz": (layers, B, K, D)}``, float32, in the layout every form
 of ``ops/retention.py`` computes in (the head's values on the sublanes, the
 features on the lanes), riding the layer scan's carry and addressed in place
-by the layer's index. No keys, no values, no page.
+by the layer's index. No keys, no values, no page. Inside a decode tick, and
+never in the donated tree, ``rec`` also carries the tick's held tokens
+(``held_tokens``: ``hk``, ``hv`` (layers, steps, B, K, d) and ``hl`` (layers,
+steps, B, K), float32), zeros at the tick's start and dropped at its end.
 
 ``q`` and ``k`` leave their bfloat16 matmuls as float32 sums and stay float32
 through norm, rotation and the power: squared in bfloat16 the weights ``a_ts``
@@ -39,7 +48,8 @@ carry 2^-7 each and no two of the three forms agree.
 Scopes (``ops/names.py`` ``RET_SCOPES``), each INSIDE the scope of ``SCOPES``
 it refines: ``ret_in`` (projections, norms, rotation, gate) inside
 ``attn_qkv``; ``ret_state`` (a prefill call's scan, or a decode step's ``phi``,
-``z``, the kernel ``ret_step`` over the live rows' states, and the division)
+``z``, the held tokens' sums, the kernel over the live rows' states,
+``ret_step_read`` or at a tick's last step ``ret_step``, and the division)
 inside ``attn_core``; ``ret_out`` (``W_o``) inside ``attn_out``.
 """
 
@@ -53,7 +63,7 @@ import jax.numpy as jnp
 from ditl_tpu.config import ModelConfig
 from ditl_tpu.ops import retention as ret
 
-__all__ = ["init_retention", "retention_axes", "retention_mixer", "init_state",
+__all__ = ["init_retention", "retention_axes", "retention_mixer", "init_state", "held_tokens",
            "state_bytes_per_slot", "SLOT_AXIS", "GATE_RANGE"]
 
 F32 = jnp.float32
@@ -108,14 +118,25 @@ def retention_axes() -> dict:
     }
 
 
-def retention_mixer(m, h, *, cfg: ModelConfig, positions, rec, at, valid):
+def held_tokens(cfg: ModelConfig, rows: int, steps: int) -> dict[str, jax.Array]:
+    """Room for a decode tick's tokens beside the state of ``rows`` sequences,
+    every layer's (``ops/retention.py`` ``HELD``): ``hk`` and ``hv`` (layers,
+    steps, rows, K, d) and ``hl`` (layers, steps, rows, K), float32 zeros (a
+    step still to come weighs nothing)."""
+    lead = (cfg.num_layers, steps, rows, cfg.num_kv_heads)
+    return {"hk": jnp.zeros((*lead, cfg.head_dim), F32),
+            "hv": jnp.zeros((*lead, cfg.head_dim), F32), "hl": jnp.zeros(lead, F32)}
+
+
+def retention_mixer(m, h, *, cfg: ModelConfig, positions, rec, at, valid, t=None):
     """The mixer on the normed input ``h`` (B, S, D): ``(out (B, S, D) before
     the residual, rec)``. ``rec``: every layer's state (module docstring), of
     which entry ``at`` is this layer's: what the sequences carried in,
     updated in place; or None (a sequence's start, nothing kept). ``valid``
     (B, S) bool or None: positions that are real tokens; the others leave the
     state as the last real token left it. S == 1 with ``rec`` is one cached
-    step."""
+    step; where ``rec`` also carries a tick's held tokens (``held_tokens``),
+    step ``t`` of that tick."""
     from ditl_tpu.models.llama import apply_rope, rms_norm
     from ditl_tpu.ops.quant import weight_einsum
 
@@ -138,12 +159,14 @@ def retention_mixer(m, h, *, cfg: ModelConfig, positions, rec, at, valid):
         if valid is not None:  # padding and dead rows: no decay, no weight
             k = jnp.where(valid[..., None, None], k, 0.0)
             log_g = jnp.where(valid[..., None], log_g, 0.0)
+    held = {}  # a decode tick's held tokens, where ``rec`` carries them
     with jax.named_scope("attn_core"), jax.named_scope("ret_state"):
         if step:  # the stack in place, live rows only (ops/retention.py)
             alive = jnp.ones((b,), bool) if valid is None else valid[:, 0]
-            y, big, z = ret.ret_step_rows(
-                rec["ret"], rec["retz"], at, q[:, 0], k[:, 0], v[:, 0],
-                jnp.exp(log_g[:, 0]), alive, eps=cfg.ret_eps)
+            held = {name: rec[name] for name in ret.HELD if name in rec}
+            y, big, z, held = ret.ret_step_rows(
+                rec["ret"], rec["retz"], at, q[:, 0], k[:, 0], v[:, 0], log_g[:, 0], alive,
+                eps=cfg.ret_eps, held=held, t=t)
             y = y[:, None]
         else:
             state = None if rec is None else tuple(
@@ -155,7 +178,7 @@ def retention_mixer(m, h, *, cfg: ModelConfig, positions, rec, at, valid):
                 big, z = (jax.lax.dynamic_update_index_in_dim(rec[name], new, at, 0)
                           for name, new in zip(("ret", "retz"), state))
         if rec is not None:
-            rec = {"ret": big, "retz": z}
+            rec = {"ret": big, "retz": z, **held}
     with jax.named_scope("attn_out"), jax.named_scope("ret_out"):
         out = weight_einsum("bsf,fd->bsd", y.reshape(b, s, nh * hd).astype(cd), m["wo"],
                             compute_dtype=cd)

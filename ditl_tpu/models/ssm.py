@@ -72,7 +72,8 @@ from ditl_tpu.config import ModelConfig
 from ditl_tpu.ops import ssd
 
 __all__ = ["init_hybrid_params", "hybrid_logical_axes", "hybrid_period", "period_counts",
-           "conv_width", "init_state", "state_bytes_per_slot", "SLOT_AXIS", "state_axes"]
+           "conv_width", "init_state", "state_bytes_per_slot", "SLOT_AXIS", "state_axes",
+           "tick_leaves"]
 
 F32 = jnp.float32
 
@@ -115,6 +116,16 @@ def state_axes(cfg: ModelConfig) -> dict[str, int]:
 
         return axes
     return SLOT_AXIS
+
+
+def tick_leaves(cfg: ModelConfig) -> tuple[str, ...]:
+    """The leaves a decode tick may carry beside ``state_axes``' and drops at
+    its end: a retention layer's held tokens (``retention.held_tokens``)."""
+    if cfg.retention_layer:
+        from ditl_tpu.ops.retention import HELD
+
+        return HELD
+    return ()
 
 
 def state_bytes_per_slot(cfg: ModelConfig) -> int:
@@ -350,7 +361,8 @@ def hybrid_period(
             if doc is not None:
                 raise ValueError("a retention layer does not carry packed documents")
             out, rec = retention_mixer(sub["ret"], h, cfg=cfg, positions=positions, rec=rec,
-                                       at=at, valid=token_mask if cached else None)
+                                       at=at, valid=token_mask if cached else None,
+                                       t=(paged or {}).get("t"))
         else:
             out, rec = _mamba_mixer(sub["ssm"], h, cfg=cfg, rec=rec, at=at,
                                     valid=token_mask if cached else None, doc=doc)

@@ -55,12 +55,14 @@ decode step's update of the live rows' states in place (``ops/ssd.py``).
 retention.py``), each INSIDE the scope of ``SCOPES`` it refines: ``ret_in``
 (the projections, the head norms, the rotation, the gate) inside
 ``attn_qkv``; ``ret_state`` (a prefill call's scan, or a decode step's
-feature maps, the sum of keys, the read, update, read-out and write back of
-the live rows' states, and the division) inside ``attn_core``; ``ret_out``
-(the output projection) inside ``attn_out``. The seat of a slot's state after
-a prefill is ``kv_write``. ``ret_step`` (``RET_KERNELS``), inside
-``ret_state``: the decode step's update of the live rows' states in place
-(``ops/retention.py``).
+feature maps, the sum of keys, the read of the live rows' states, the tick's
+held tokens' own sums, at a tick's last step their fold into the states and
+the write back, and the division) inside ``attn_core``; ``ret_out`` (the
+output projection) inside ``attn_out``. The seat of a slot's state after a
+prefill is ``kv_write``. ``RET_KERNELS``, inside ``ret_state``: ``ret_step``,
+a tick's last step (the held tokens folded into the live rows' states in
+place, and the read-out), and ``ret_step_read``, every other step (the
+read-out alone, nothing written back) (``ops/retention.py``).
 
 ``DSA_SCOPES`` are the parts of DeepSeek-V3.2's sparse attention (``models/
 dsa.py``), each INSIDE a scope of ``SCOPES``: ``dsa_index`` is the lightning
@@ -156,8 +158,10 @@ SWA_SCOPES = ("attn_window", "attn_full")
 # The decode step's state update (``ops/ssd.py``), inside ``ssm_scan``.
 SSM_KERNELS = ("ssd_step",)
 
-# The decode step's state update (``ops/retention.py``), inside ``ret_state``.
-RET_KERNELS = ("ret_step",)
+# The decode step over the live rows' states (``ops/retention.py``), inside
+# ``ret_state``: a tick's last step, which folds the tick's held tokens into
+# the state in place and reads it out; and every other step, which only reads.
+RET_KERNELS = ("ret_step", "ret_step_read")
 
 # Decode attention over a latent page pool (``ops/mla_attention.py``), inside
 # ``mla_attn``.

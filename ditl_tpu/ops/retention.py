@@ -1,5 +1,6 @@
 """Power retention of degree 2 with a gate ("Scaling Context Requires
-Rethinking Attention", arXiv:2507.04239), three forms of one equation.
+Rethinking Attention", arXiv:2507.04239), three forms of one equation, and
+a decode step that mixes two of them.
 
 For a kv head with its group's ``G`` query heads, head width ``d``, gate
 ``g_t`` in (0, 1) and ``G_t = sum_{s<=t} log g_s``::
@@ -24,10 +25,11 @@ products alone are 8,256, which is no whole number of lanes; the full square,
 call the attention form (no ``phi``), across calls the state, read through
 ``phi(q)`` and grown through ``phi(k)``; it takes an initial state and
 returns the final one.
-``ret_step`` is one token of the recurrent form. State, gates, decays, powers
-and every sum are float32 and every matmul of them runs at the highest
-precision: a state is rewritten every token, and a product of two rounded
-products is no feature map of anything.
+``ret_step`` is one token of the recurrent form and ``ret_fold`` several
+tokens into the state at once. State, gates, decays, powers and every sum
+are float32 and every matmul of them runs at the highest precision: a state
+lives for a sequence's whole length, and a product of two rounded products
+is no feature map of anything.
 
 A position with ``log g == 0`` and ``k == 0`` leaves the state EXACTLY as it
 was and weighs nothing in any later sum: a bucket's padding, a chunk's tail
@@ -43,12 +45,32 @@ the sublanes each of a row's six ``phi`` vectors would have to be turned into
 a column first, 72 tile transposes each.
 
 ``ret_step_rows`` is the decode step over the STACKED state of every layer
-and slot, in place, a Pallas kernel on the chip (``ret_step``): the grid
-walks ``ssd.live_rows``' list (live rows only, a traced count), a kv head
-and a block of ``VALUES_A_BLOCK`` value rows a step; a step reads its block
-of ``S`` once, decays and grows it, reads it out for the group's query heads
-and writes it once. ``z`` and the denominators are a 130th of the bytes and
-stay plain ``jax.numpy`` in front of the call.
+and slot, in place. ``y_t`` needs all of ``S_(t-1)``, but an exact step need
+not STORE ``S_t``: a decode tick of ``T`` steps (the serving loop's
+``decode_chunk``) holds its tokens' ``k_j``, ``v_j`` and ``log g_j`` beside
+the state (``HELD``: float32, zeros at the tick's start) and, with ``S0`` the
+state as stored (as of the tick's start) and ``L_t`` the sum of the tick's
+``log g`` up to and including step ``t``::
+
+    numerator_t = exp(L_t) (S0 phi(q_t)) + sum_{j<=t} exp(L_t - L_j) (q_t . k_j)^2 v_j
+    at t == T - 1, and there alone:
+        S <- exp(L_t) S0 + sum_{j<=t} exp(L_t - L_j) v_j (outer) phi(k_j)
+
+the recurrent form through the state the tick began with and the attention
+form over the tick's own tokens: the same equation in another order of
+float32 sums. A live row's state is READ once a step and WRITTEN once a tick.
+Two Pallas kernels on the chip, chosen by a conditional on ``t``, whose grid
+walks ``ssd.live_rows``' list (live rows only, a traced count), a kv head and
+a block of ``VALUES_A_BLOCK`` value rows a step: ``ret_step_read`` reads its
+block of ``S0`` once, reads it out for the group's query heads and writes
+nothing back; ``ret_step``, the tick's last step, decays the block, grows it
+by every held token's ``v (outer) phi(k)``, reads it out and writes it once.
+The rows folded are those live at the LAST step: a row only ever ends inside
+a tick, and the state of one that ended is never read again (a slot is
+reseated from zeros). A step with no held tokens (``held=None``, a tick of
+one step) is the last step of its own tick. ``z``, the denominators and the
+held tokens' own sums are a 130th of the bytes and stay plain ``jax.numpy``
+around the call.
 """
 
 from __future__ import annotations
@@ -62,13 +84,19 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ditl_tpu.ops.backend import interpret_default
+from ditl_tpu.ops.names import RET_KERNELS
 from ditl_tpu.ops.ssd import live_rows
 
-__all__ = ["features", "phi", "ret_scan", "ret_step", "ret_step_rows"]
+__all__ = ["features", "phi", "ret_scan", "ret_fold", "ret_step", "ret_step_rows", "HELD"]
 
 F32 = jnp.float32
 HIGHEST = jax.lax.Precision.HIGHEST
 BLOCKS = 8
+# a tick's held tokens, a layer a row a kv head: ``k`` (after norm, rotation
+# and ``d ** -0.25``), ``v`` and ``log g``, float32
+HELD = ("hk", "hv", "hl")
+# the kernels of ``ret_step_rows``: a tick's last step, and every other
+FOLD_KERNEL, READ_KERNEL = RET_KERNELS
 # value rows of a head's state a grid step of the kernel takes: 32 rows x
 # 9,216 features x 4 B = 1.1 MiB a block, and its G running (32, 128)
 # products stay in vector registers
@@ -178,47 +206,103 @@ def ret_scan(q, k, v, log_g, *, chunk: int, eps: float, state=None):
     return y[:, :s], state
 
 
+def ret_fold(big, pks, vc, decay):
+    """Tokens folded into the state together. big: (b, K, P, D) float32, the
+    state before the first of them; pks: (T, b, K, D), each token's
+    ``phi(k)``; vc: (T, b, K, P), each token's ``v`` times its decay from its
+    own position to the last one's; decay: (b, K), the state's over all of
+    them. Returns the state after the last one."""
+    return big * decay[..., None, None] + jnp.einsum(
+        "tbkp,tbkd->bkpd", vc.astype(F32), pks, precision=HIGHEST)
+
+
 def ret_step(big, z, pq, pk, v, g, *, eps: float):
     """One token. big: (b, K, P, D) float32; z: (b, K, D); pq: (b, K, G, D)
     ``phi(q)``; pk: (b, K, D) ``phi(k)`` (0 = this row adds nothing); v: (b,
     K, P); g: (b, K) float32 (1 = this row's state stays). Returns ``(y (b,
     K, G, P) float32, new S, new z)``."""
-    big = big * g[..., None, None] + v.astype(F32)[..., :, None] * pk[..., None, :]
+    big = ret_fold(big, pk[None], v[None], g)
     z = z * g[..., None] + pk
     num = jnp.einsum("bkgd,bkpd->bkgp", pq, big, precision=HIGHEST)
     den = jnp.einsum("bkgd,bkd->bkg", pq, z, precision=HIGHEST)
     return num / (den + eps)[..., None], big, z
 
 
+# The kernels' blocks leave their leading 1s out (``None`` in a BlockSpec),
+# every access in their loops is slices alone, and the loop over the lane
+# tiles is a ``fori_loop`` of ``TILES_A_TURN`` tiles a turn, not 72 copies of
+# its body: what a process pays to TRACE and lower the decode program it
+# pays at every start, whatever the compile cache holds, and inside the
+# server that was 15-20 s for two kernels written out tile by tile with an
+# integer index (each made an array where it is written: a host-to-device
+# transfer of a scalar) in every access (PERF.md section 6, PR 57).
+TILES_A_TURN = 8
+
+
+def _read_out(tile_at, n_f, pq_ref, y_ref):
+    """``tile_at(at)``: the (VB, lanes) tile of the state at lane slice
+    ``at``. The features go by in tiles of ``lanes``: a tile's products with
+    each query head's ``phi(q)`` (``pq_ref`` (G, D)) join that head's running
+    tile; ONE sum over lanes a head ends the block, head ``h``'s numerators
+    in lane ``h`` of ``y_ref`` (VB, lanes)."""
+    n_g = pq_ref.shape[0]
+    lt = y_ref.shape[-1]  # a lane tile: 128, or all the features where they are no multiple
+
+    tiles = n_f // lt
+    a_turn = TILES_A_TURN if tiles % TILES_A_TURN == 0 else tiles
+
+    def turn(i, acc):
+        for u in range(a_turn):  # (Mosaic's own unrolling is all of a loop or none)
+            at = pl.ds(pl.multiple_of((i * a_turn + u) * lt, lt), lt)
+            tile = tile_at(at)
+            acc = tuple(a + tile * pq_ref[h:h + 1, at] for h, a in enumerate(acc))
+        return acc
+
+    acc = jax.lax.fori_loop(0, tiles // a_turn, turn, (jnp.zeros(y_ref.shape, F32),) * n_g)
+    lane = jax.lax.broadcasted_iota(jnp.int32, y_ref.shape, 1)
+    y = jnp.zeros(y_ref.shape, F32)
+    for h in range(n_g):
+        y = jnp.where(lane == h, jnp.sum(acc[h], axis=1, keepdims=True), y)
+    y_ref[...] = y
+
+
+def _ret_read_kernel(layer, rows, count, pq_ref, s_ref, y_ref):
+    """A step that is not its tick's last: one LIVE row, one kv head, ``VB``
+    value rows a grid step, READ and nothing else. pq_ref: (G, D); s_ref:
+    (VB, D), the block of the stack as the tick's start left it; y_ref: (VB,
+    lanes), ``S0 phi(q)``. The stack is no output: no decay, no grow, no
+    store. (No live row at all: the walk's one step reads a block and writes
+    a ``y`` that nobody keeps.)"""
+    del layer, rows, count  # the index maps' alone
+    _read_out(lambda at: s_ref[:, at], s_ref.shape[-1], pq_ref, y_ref)
+
+
 def _ret_step_kernel(layer, rows, count, g, pq_ref, pk_ref, vb_ref, s_ref, y_ref, o_ref):
-    """One LIVE row, one kv head, ``VB`` value rows a grid step. g: (B, K)
-    float32 in scalar memory; pq_ref: (1, 1, G, D); pk_ref: (1, 1, 1, D);
-    vb_ref: (1, 1, VB, lanes), each value over a tile's lanes; s_ref / o_ref: (1,
-    1, 1, VB, D), the same block of the aliased stack; y_ref: (1, 1, VB, lanes),
-    query head ``h``'s numerators in lane ``h``. The features go by in tiles
-    of 128 lanes: a tile of the state is decayed, grown by ``v phi(k)`` and
-    written, and its products with each query head's ``phi(q)`` join that
-    head's running tile; ONE sum over lanes a head ends the block."""
+    """A tick's last step: one LIVE row, one kv head, ``VB`` value rows a grid
+    step, the tick's ``T`` held tokens folded in and the result read out in
+    ONE pass. g: (B, K) float32 in scalar memory, the state's decay over the
+    tick; pq_ref: (G, D); pk_ref: (T, D), each held token's ``phi(k)``;
+    vb_ref: (T, VB, lanes), each held token's values times their decay to the
+    tick's end, over a tile's lanes; s_ref / o_ref: (VB, D), the same block
+    of the aliased stack; y_ref: (VB, lanes), query head ``h``'s numerators
+    in lane ``h``. A tile of the state is decayed, grown by every ``v
+    phi(k)`` and written, and read out (``_read_out``)."""
     del layer  # the index maps' alone
-    n_g, n_f = pq_ref.shape[2:]
-    lt = vb_ref.shape[-1]  # a lane tile: 128, or all the features where they are no multiple
+    n_t = pk_ref.shape[0]
     decay = g[rows[pl.program_id(0)], pl.program_id(1)]
 
     @pl.when(count[0] > 0)
     def _():
-        vb = vb_ref[0, 0]
-        acc = [jnp.zeros(vb.shape, F32) for _ in range(n_g)]
-        for t in range(n_f // lt):
-            at = slice(t * lt, (t + 1) * lt)
-            new = s_ref[0, 0, 0, :, at] * decay + vb * pk_ref[0, 0, :, at]
-            o_ref[0, 0, 0, :, at] = new
-            for h in range(n_g):
-                acc[h] = acc[h] + new * pq_ref[0, 0, h:h + 1, at]
-        lane = jax.lax.broadcasted_iota(jnp.int32, y_ref.shape[2:], 1)
-        y = jnp.zeros(y_ref.shape[2:], F32)
-        for h in range(n_g):
-            y = jnp.where(lane == h, jnp.sum(acc[h], axis=1, keepdims=True), y)
-        y_ref[0, 0] = y
+        vbs = [vb_ref[j] for j in range(n_t)]
+
+        def tile_at(at):
+            new = s_ref[:, at] * decay
+            for j in range(n_t):
+                new = new + vbs[j] * pk_ref[j:j + 1, at]
+            o_ref[:, at] = new
+            return new
+
+        _read_out(tile_at, s_ref.shape[-1], pq_ref, y_ref)
 
     # No live row at all: the walk's one step writes back what it read.
     @pl.when(count[0] == 0)
@@ -226,30 +310,15 @@ def _ret_step_kernel(layer, rows, count, g, pq_ref, pk_ref, vb_ref, s_ref, y_ref
         o_ref[...] = s_ref[...]
 
 
-def ret_step_rows(stack, zstack, at, q, k, v, g, alive, *, eps: float,
-                  interpret: bool | None = None):
-    """One token of every slot, on layer ``at``'s entries of the stacked
-    state ``stack`` (layers, B, K, P, D) and ``zstack`` (layers, B, K, D),
-    float32, in place (donate them). q: (B, K, G, d), k: (B, K, d), float32,
-    both times ``d ** -0.25``; v: (B, K, P); g: (B, K) float32; alive: (B,)
-    bool, the rows whose state moves (a dead row's is neither read nor
-    written). Returns ``(y (B, K, G, P) float32, 0 for a dead row; stack;
-    zstack)``. Off the TPU the plain form runs."""
-    n_b, n_kv, n_g, _ = q.shape
-    _, _, _, p, n_f = stack.shape
-    live = alive[:, None]
-    pq = phi(q)
-    pk = jnp.where(live[..., None], phi(k), 0.0)
-    g = jnp.where(live, g, 1.0)
-    z = jax.lax.dynamic_index_in_dim(zstack, at, keepdims=False)
-    if interpret is None and interpret_default():
-        y, new, z = ret_step(jax.lax.dynamic_index_in_dim(stack, at, keepdims=False),
-                             z, pq, pk, v, g, eps=eps)
-        return (jnp.where(live[..., None, None], y, 0.0),
-                jax.lax.dynamic_update_index_in_dim(stack, new, at, 0),
-                jax.lax.dynamic_update_index_in_dim(zstack, z, at, 0))
-    z = z * g[..., None] + pk
-    den = jnp.einsum("bkgd,bkd->bkg", pq, z, precision=HIGHEST)
+def _walk(stack, at, alive, pq, fold, *, interpret: bool):
+    """A kernel over ``ssd.live_rows``' list of ``alive``, a kv head and
+    ``VALUES_A_BLOCK`` value rows of layer ``at``'s entry of ``stack`` a grid
+    step. ``fold`` None: ``ret_step_read``, which writes no state. Else
+    ``(decay (B, K), pks (T, B, K, D), vc (T, B, K, P))`` as ``ret_fold``
+    takes them: ``ret_step``, the stack aliased in place. Returns ``(S phi(q)
+    (B, K, G, P), stack)``."""
+    n_b, n_kv, n_g, n_f = pq.shape
+    p = stack.shape[3]
     rows, count = live_rows(alive)
     vb = min(VALUES_A_BLOCK, p)
     lanes = 128 if n_f % 128 == 0 else n_f
@@ -260,33 +329,109 @@ def ret_step_rows(stack, zstack, at, q, k, v, g, alive, *, eps: float,
     def values(i, h, j, layer, rows, *_):
         return (rows[i], h, j, 0)
 
+    def held_values(i, h, j, layer, rows, *_):
+        return (rows[i], h, 0, j, 0)
+
     def entry(i, h, j, layer, rows, *_):
         return (layer[0], rows[i], h, j, 0)
 
-    state = pl.BlockSpec((1, 1, 1, vb, n_f), entry)
-    num, stack = pl.pallas_call(
-        _ret_step_kernel,
+    state = pl.BlockSpec((None, None, None, vb, n_f), entry)
+    y_spec = pl.BlockSpec((None, None, vb, lanes), values)
+    y_shape = jax.ShapeDtypeStruct((n_b, n_kv, p, lanes), F32)
+    scalars = [jnp.reshape(at, (1,)).astype(jnp.int32), rows, jnp.reshape(count, (1,))]
+    operands, in_specs = [pq], [pl.BlockSpec((None, None, n_g, n_f), head)]
+    if fold is not None:
+        decay, pks, vc = fold
+        n_t = len(pks)
+        scalars.append(decay)
+        # a row's and a kv head's tokens together; each value over a tile's lanes
+        operands += [jnp.moveaxis(pks, 0, 2), jnp.broadcast_to(
+            jnp.moveaxis(vc, 0, 2)[..., None], (n_b, n_kv, n_t, p, lanes))]
+        in_specs += [pl.BlockSpec((None, None, n_t, n_f), head),
+                     pl.BlockSpec((None, None, n_t, vb, lanes), held_values)]
+    out = pl.pallas_call(
+        _ret_read_kernel if fold is None else _ret_step_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=len(scalars),
             # the first axis is as long as the traced count of live rows
             grid=(jnp.maximum(count, 1), n_kv, p // vb),
-            in_specs=[pl.BlockSpec((1, 1, n_g, n_f), head),
-                      pl.BlockSpec((1, 1, 1, n_f), head),
-                      pl.BlockSpec((1, 1, vb, lanes), values), state],
-            out_specs=[pl.BlockSpec((1, 1, vb, lanes), values), state],
+            in_specs=[*in_specs, state],
+            out_specs=y_spec if fold is None else [y_spec, state],
         ),
-        out_shape=[jax.ShapeDtypeStruct((n_b, n_kv, p, lanes), F32),
-                   jax.ShapeDtypeStruct(stack.shape, stack.dtype)],
-        # operands count the scalar-prefetch ones: the stack is the eighth
-        input_output_aliases={7: 1},
+        out_shape=y_shape if fold is None else [
+            y_shape, jax.ShapeDtypeStruct(stack.shape, stack.dtype)],
+        # operands count the scalar-prefetch ones: the stack is the last
+        input_output_aliases={} if fold is None else {len(scalars) + len(operands): 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
-        interpret=bool(interpret),
-        name="ret_step",
-    )(jnp.reshape(at, (1,)).astype(jnp.int32), rows, jnp.reshape(count, (1,)), g,
-      pq, pk[:, :, None], jnp.broadcast_to(v.astype(F32)[..., None], (n_b, n_kv, p, lanes)),
-      stack)
-    y = jnp.swapaxes(num[..., :n_g], 2, 3) / (den + eps)[..., None]
+        interpret=interpret,
+        name=READ_KERNEL if fold is None else FOLD_KERNEL,
+    )(*scalars, *operands, stack)
+    num, stack = (out, stack) if fold is None else out
+    return jnp.swapaxes(num[..., :n_g], 2, 3), stack
+
+
+def ret_step_rows(stack, zstack, at, q, k, v, log_g, alive, *, eps: float, held=None,
+                  t=None, interpret: bool | None = None):
+    """One token of every slot, on layer ``at``'s entries of the stacked
+    state ``stack`` (layers, B, K, P, D) and ``zstack`` (layers, B, K, D),
+    float32, in place (donate them). q: (B, K, G, d), k: (B, K, d), float32,
+    both times ``d ** -0.25``; v: (B, K, P); log_g: (B, K) float32, <= 0;
+    alive: (B,) bool, the rows whose state moves (a dead row's is neither
+    read nor written). ``held``: the tick's tokens beside the state (module
+    docstring), ``{"hk": (layers, T, B, K, d), "hv": (layers, T, B, K, P),
+    "hl": (layers, T, B, K)}`` float32, zeros at the tick's start, and ``t``
+    this step's index in its tick: the token is held, the state is read, and
+    at ``t == T - 1`` the rows live THEN are folded and written. None or
+    empty: a tick of one step, every step folds. Returns ``(y (B, K, G, P) float32, 0
+    for a dead row; stack; zstack; held)``. Off the TPU the plain forms run."""
+    live = alive[:, None]
+    q = q.astype(F32)
+    k = jnp.where(live[..., None], k.astype(F32), 0.0)
+    log_g = jnp.where(live, log_g, 0.0)
+    pq, pk = phi(q), phi(k)
+    z = (jax.lax.dynamic_index_in_dim(zstack, at, keepdims=False)
+         * jnp.exp(log_g)[..., None] + pk)
+    den = jnp.einsum("bkgd,bkd->bkg", pq, z, precision=HIGHEST)
+    now = {"hk": k, "hv": v.astype(F32), "hl": log_g}
+    if not held:
+        ks, vs, lgs = (now[name][None] for name in HELD)
+    else:
+        held = {name: jax.lax.dynamic_update_slice(
+            held[name], now[name][None, None], (at, t) + (0,) * now[name].ndim)
+            for name in HELD}
+        ks, vs, lgs = (jax.lax.dynamic_index_in_dim(held[name], at, keepdims=False)
+                       for name in HELD)
+    # L_j of the held tokens; a step still to come holds zeros: L_j = L_t there
+    upto = jnp.cumsum(lgs, axis=0)
+    total = upto[-1]  # (B, K): L_t
+    since = jnp.exp(total - upto)  # (T, B, K): exp(L_t - L_j)
+    plain = interpret is None and interpret_default()
+
+    def read(stack):  # S0 through phi(q), the held tokens in the attention form
+        if plain:
+            was = jnp.einsum("bkgd,bkpd->bkgp", pq, jax.lax.dynamic_index_in_dim(
+                stack, at, keepdims=False), precision=HIGHEST)
+        else:
+            was, _ = _walk(stack, at, alive, pq, None, interpret=bool(interpret))
+        dots = jnp.einsum("bkgd,tbkd->bkgt", q, ks, precision=HIGHEST)
+        a = dots * dots * jnp.moveaxis(since, 0, -1)[:, :, None]
+        return (was * jnp.exp(total)[..., None, None]
+                + jnp.einsum("bkgt,tbkp->bkgp", a, vs, precision=HIGHEST)), stack
+
+    def fold(stack):  # the held tokens into S, and S through phi(q), in one pass
+        pks, vc = phi(ks), vs * since[..., None]
+        if plain:
+            was = jax.lax.dynamic_index_in_dim(stack, at, keepdims=False)
+            new = jnp.where(live[..., None, None], ret_fold(was, pks, vc, jnp.exp(total)), was)
+            return (jnp.einsum("bkgd,bkpd->bkgp", pq, new, precision=HIGHEST),
+                    jax.lax.dynamic_update_index_in_dim(stack, new, at, 0))
+        return _walk(stack, at, alive, pq, (jnp.exp(total), pks, vc), interpret=bool(interpret))
+
+    if len(ks) == 1:
+        num, stack = fold(stack)
+    else:
+        num, stack = jax.lax.cond(t == len(ks) - 1, fold, read, stack)
     # a row that is not walked was never written: this is what defines it
-    return (jnp.where(live[..., None, None], y, 0.0), stack,
-            jax.lax.dynamic_update_index_in_dim(zstack, z, at, 0))
+    return (jnp.where(live[..., None, None], num / (den + eps)[..., None], 0.0), stack,
+            jax.lax.dynamic_update_index_in_dim(zstack, z, at, 0), held)

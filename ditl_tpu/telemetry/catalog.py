@@ -301,6 +301,7 @@ _ROWS: tuple = (
     ("ditl_serving_requests_admitted_total", "counter", "", "requests admitted into a slot"),
     ("ditl_serving_requests_completed_total", "counter", "", "requests finished"),
     ("ditl_serving_requests_total", "counter", "", "requests accepted by submit"),
+    ("ditl_serving_ret_row_folds_total", "gauge", "", "live rows whose state a decode tick of a stack of retention layers WROTE: a tick's last step folds the tick's held tokens into the state of each row live at that step, once a layer; beside ditl_serving_ssm_row_steps_total, the rows whose state a step read, the ratio is one over the tick's steps but for rows that end inside a tick (lifetime count from /v1/stats)", True),
     ("ditl_serving_resume_prefill_tokens", "gauge", "", "tokens re-prefilled resuming preempted requests"),
     ("ditl_serving_slots_busy", "gauge", "", "occupied slots"),
     ("ditl_serving_slots_prefilling", "gauge", "", "slots running chunked prefill"),
@@ -314,7 +315,7 @@ _ROWS: tuple = (
     ("ditl_serving_speculative_spec_ticks", "gauge", "", "ticks that ran speculatively"),
     ("ditl_serving_speculative_threshold", "gauge", "", "predicted-acceptance threshold for speculating"),
     ("ditl_serving_speculative_ticks", "gauge", "", "ticks counted by the speculation decision path"),
-    ("ditl_serving_ssm_row_steps_total", "gauge", "", "live rows summed over the decode ticks' steps of a model with state-space or retention layers: each read and rewrote its recurrent state once a mixer (lifetime count from /v1/stats)", True),
+    ("ditl_serving_ssm_row_steps_total", "gauge", "", "live rows summed over the decode ticks' steps of a model with state-space or retention layers: each read its recurrent state once a mixer, and a state-space mixer's rewrote it (lifetime count from /v1/stats)", True),
     ("ditl_serving_ssm_slots_seated", "gauge", "", "slots whose recurrent state belongs to a request in flight (a model with state-space or retention layers; a freed slot's state is overwritten by the next seat, never cleared)", True),
     ("ditl_serving_ssm_state_bytes_per_slot", "gauge", "", "recurrent state one slot holds over all state-space mixers (the float32 state and the convolution window) or retention layers (the float32 state and sum of keys)", True),
     ("ditl_serving_ssm_state_bytes_resident", "gauge", "", "recurrent state resident on the device: bytes a slot times the slots, allocated once beside the page pool (a stack of retention layers has no pool beside it)", True),

@@ -119,22 +119,50 @@ def test_the_recurrent_form_equals_the_attention_form_token_by_token():
     assert rel(big, big_scan) < 2e-6 and rel(z, z_scan) < 2e-6
 
 
+def _held(layers, tick, rows, n_kv=2, d=16):
+    lead = (layers, tick, rows, n_kv)
+    return {"hk": jnp.zeros((*lead, d)), "hv": jnp.zeros((*lead, d)), "hl": jnp.zeros(lead)}
+
+
+# (steps a tick, this step's index in it): a tick of one step (no held
+# tokens: the kernel of every step before ISSUE 57), a step that only reads,
+# and a tick's last step folding 2, 3 and 4 held tokens
+@pytest.mark.parametrize("tick, t", [(1, 0), (4, 1), (2, 1), (3, 2), (4, 3)],
+                         ids=["tick-1", "read-only", "fold-2", "fold-3", "fold-4"])
 @pytest.mark.parametrize("alive", [(1, 0, 1, 1), (0, 0, 0, 0), (1, 1, 1, 1), (0, 0, 1, 0)])
-def test_the_interpreted_step_kernel_equals_the_plain_step(alive):
-    """The kernel on one layer's entry of the stacked state, in place: live
-    rows as the plain recurrence, a dead row's state bit for bit and its
-    output zero, the other layers untouched."""
+def test_the_interpreted_step_kernel_equals_the_plain_step(alive, tick, t):
+    """Both kernels on one layer's entry of the stacked state, ``t`` tokens
+    of the tick already held beside it: live rows as the plain recurrence
+    token by token, a dead row's state bit for bit and its output zero, the
+    other layers untouched; the read-only kernel returns the WHOLE stack bit
+    for bit, the folding one writes the live rows' state after all ``t + 1``
+    tokens."""
     rng = np.random.default_rng(4)
     f = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)  # noqa: E731
     n_f = ret.features(16)
-    stack, zstack = f(3, 4, 2, 16, n_f), f(3, 4, 2, n_f)
-    q, k, v = f(4, 2, 2, 16), f(4, 2, 16), f(4, 2, 16)
-    g = jnp.asarray(rng.uniform(0.5, 1.0, (4, 2)), jnp.float32)
+    stack, z0 = f(3, 4, 2, 16, n_f), f(4, 2, n_f)
+    q = f(4, 2, 2, 16)
+    k, v = f(t + 1, 4, 2, 16), f(t + 1, 4, 2, 16)
+    g = jnp.asarray(rng.uniform(0.5, 1.0, (t + 1, 4, 2)), jnp.float32)
     live = jnp.asarray(alive, bool)
-    y, new, znew = jax.jit(lambda st, zs: ret.ret_step_rows(
-        st, zs, jnp.int32(1), q, k, v, g, live, eps=EPS, interpret=True))(stack, zstack)
-    y_want, s_want, z_want = ret.ret_step(stack[1], zstack[1], ret.phi(q), ret.phi(k), v, g,
-                                          eps=EPS)
+    # the plain recurrence over the tick's tokens, from the tick's start
+    s_want, z_want = stack[1], z0
+    for j in range(t + 1):
+        z_in = z_want
+        y_want, s_want, z_want = ret.ret_step(s_want, z_want, ret.phi(q), ret.phi(k[j]), v[j],
+                                              g[j], eps=EPS)
+    zstack = f(3, 4, 2, n_f).at[1].set(z_in)  # the sum of keys is rewritten every step
+    held = None
+    if tick > 1:
+        held = _held(3, tick, 4)
+        held = {"hk": held["hk"].at[1, :t].set(k[:t]), "hv": held["hv"].at[1, :t].set(v[:t]),
+                "hl": held["hl"].at[1, :t].set(jnp.log(g[:t]))}
+    y, new, znew, kept = jax.jit(lambda st, zs, at: ret.ret_step_rows(
+        st, zs, jnp.int32(1), q, k[t], v[t], jnp.log(g[t]), live, eps=EPS, held=held, t=at,
+        interpret=True))(stack, zstack, jnp.int32(t))
+    if t < tick - 1:
+        assert bool(jnp.all(new == stack))
+        s_want = stack[1]
     if live.any():
         # (random states of either sign: a denominator near 0 magnifies a reordered sum)
         assert rel(y[live], y_want[live]) < 1e-4 and rel(new[1][live], s_want[live]) < 1e-6
@@ -143,6 +171,94 @@ def test_the_interpreted_step_kernel_equals_the_plain_step(alive):
     assert bool(jnp.all(new[1][~live] == stack[1][~live]))
     assert bool(jnp.all(znew[1][~live] == zstack[1][~live]))
     assert bool(jnp.all(new[0] == stack[0])) and bool(jnp.all(new[2] == stack[2]))
+    if tick > 1:  # this step's token held where the next step finds it
+        assert bool(jnp.all(kept["hv"][1, t] == v[t])) and bool(jnp.all(kept["hv"][0] == 0))
+        assert bool(jnp.all(kept["hk"][1, t][live] == k[t][live]))
+        assert bool(jnp.all(kept["hk"][1, t][~live] == 0))
+
+
+def test_the_step_kernels_trace_small(monkeypatch):
+    """What a process pays to trace the decode program it pays at EVERY
+    start, whatever the compile cache holds (ISSUE 57's first chip runs:
+    `setup_s` +14 s with the cache warm). Two costs are held down here. An
+    integer index into a kernel's ref is made an ARRAY where it is written
+    (``jax._src.state.indexing``: ``jnp.asarray`` on the default device, a
+    host-to-device transfer on the chip): inside loops written out over 72
+    lane tiles that was ~2,700 transfers a trace. And the loops themselves:
+    3,300 equations of kernel where a ``fori_loop`` of 8 tiles a turn has a
+    ninth of them."""
+    from jax._src.state import primitives as state_primitives
+
+    made = []
+    broadcast_to = state_primitives.broadcast_to
+    monkeypatch.setattr(state_primitives, "broadcast_to",
+                        lambda a, shape: (made.append(a), broadcast_to(a, shape))[1])
+    rng = np.random.default_rng(9)
+    f = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)  # noqa: E731
+    n_f, tick = ret.features(128), 4
+    stack, zstack = f(1, 2, 1, 128, n_f), f(1, 2, 1, n_f)
+    q, k, v, log_g = f(2, 1, 5, 128), f(2, 1, 128), f(2, 1, 128), -jnp.abs(f(2, 1))
+    # both branches of the conditional are traced: the read-only kernel and the folding one
+    jaxpr = jax.make_jaxpr(lambda st, zs: ret.ret_step_rows(
+        st, zs, jnp.int32(0), q, k, v, log_g, jnp.ones((2,), bool), eps=EPS,
+        held=_held(1, tick, 2, 1, 128), t=jnp.int32(1), interpret=False))(stack, zstack)
+    # the integers left are the folding kernel's ``vb_ref[j]`` and the scalars' ``count[0]``
+    assert len([a for a in made if isinstance(a, int)]) < 20
+    from tests.tpu_compile import _eqns
+
+    assert len(list(_eqns(jaxpr.jaxpr))) < 1200  # 3,900 with the loops written out
+
+
+# which of three rows are live at each of 64 steps
+ALIVE = {"all-live": lambda step: (True, True, True),
+         "dead-from-the-start": lambda step: (True, False, True),
+         "ends-at-step-1": lambda step: (True, step < 1, True)}
+
+
+@pytest.mark.parametrize("pattern", list(ALIVE))
+@pytest.mark.parametrize("tick", [1, 2, 4])
+def test_the_held_token_form_equals_the_recurrent_form_step_by_step(tick, pattern):
+    """64 steps in ticks of ``tick`` from a non-zero state: every step's ``y``
+    and the state after every tick against ``ret_step`` token by token. A row
+    that ended inside a tick is not folded (its state is never read again)
+    and stays, bit for bit, where its last whole tick left it."""
+    rng = np.random.default_rng(7)
+    f = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)  # noqa: E731
+    n_f, n_b, steps = ret.features(16), 3, 64
+    # a state of outer products with positive weights, as a sequence leaves one
+    k0 = f(5, n_b, 2, 16) * 16 ** -0.25
+    stack = jnp.zeros((2, n_b, 2, 16, n_f)).at[1].set(
+        jnp.einsum("tbkp,tbkd->bkpd", f(5, n_b, 2, 16), ret.phi(k0)))
+    zstack = jnp.zeros((2, n_b, 2, n_f)).at[1].set(ret.phi(k0).sum(0))
+    q, k = f(steps, n_b, 2, 2, 16) * 16 ** -0.25, f(steps, n_b, 2, 16) * 16 ** -0.25
+    v = f(steps, n_b, 2, 16)
+    log_g = jnp.log(jnp.asarray(rng.uniform(0.8, 0.999, (steps, n_b, 2)), jnp.float32))
+
+    @jax.jit
+    def step(stack, zstack, held, t, q, k, v, log_g, live):
+        return ret.ret_step_rows(stack, zstack, jnp.int32(1), q, k, v, log_g, live, eps=EPS,
+                                 held=held, t=t)
+
+    @jax.jit
+    def plain(big, z, q, k, v, log_g, live):
+        on = live[:, None]
+        return ret.ret_step(big, z, ret.phi(q), jnp.where(on[..., None], ret.phi(k), 0.0), v,
+                            jnp.where(on, jnp.exp(log_g), 1.0), eps=EPS)
+
+    big, z = stack[1], zstack[1]
+    for first in range(0, steps, tick):
+        held, before = _held(2, tick, n_b) if tick > 1 else None, stack
+        for t in range(tick):
+            at = first + t
+            live = jnp.asarray(ALIVE[pattern](at))
+            y, stack, zstack, held = step(stack, zstack, held, jnp.int32(t), q[at], k[at], v[at],
+                                          log_g[at], live)
+            y_want, big, z = plain(big, z, q[at], k[at], v[at], log_g[at], live)
+            assert rel(y[live], y_want[live]) < 1e-5, (at, pattern)
+            assert bool(jnp.all(y[~live] == 0.0))
+        assert rel(stack[1][live], big[live]) < 1e-5 and rel(zstack[1][live], z[live]) < 1e-5
+        assert bool(jnp.all(stack[1][~live] == before[1][~live]))
+        assert bool(jnp.all(stack[0] == 0.0))
 
 
 @pytest.mark.parametrize("tokens, chunk", [(70, 16), (40, 64), (33, 8)])

@@ -46,7 +46,7 @@ def reader(name):
 
 def test_the_name_tables_equal_the_programs():
     assert _ret.RET_SCOPES == names.RET_SCOPES == ("ret_in", "ret_state", "ret_out")
-    assert names.RET_KERNELS == ("ret_step",)
+    assert names.RET_KERNELS == ("ret_step", "ret_step_read")
     assert not set(names.RET_SCOPES) & set(
         names.SCOPES + names.MOE_SCOPES + names.MLA_SCOPES + names.SSM_SCOPES)
     assert not set(names.RET_KERNELS) & set(

@@ -101,14 +101,23 @@ def test_prefill_in_a_padded_bucket_then_200_cached_steps_match_one_full_pass():
 
 
 def test_a_bfloat16_state_where_float32_is_stated_is_refused(monkeypatch):
-    step = ret.ret_step
-
-    def rounded(*a, **k):
-        y, big, z = step(*a, **k)
-        return y, big.astype(jnp.bfloat16).astype(jnp.float32), z
-
-    monkeypatch.setattr(ret, "ret_step", rounded)
+    fold = ret.ret_fold
+    monkeypatch.setattr(ret, "ret_fold", lambda *a: fold(*a).astype(jnp.bfloat16).astype(
+        jnp.float32))
     assert check()["logprob_err_over_logit_rms"] > 3 * TOL
+
+
+def test_a_tick_whose_held_tokens_are_dropped_instead_of_folded_is_refused(monkeypatch):
+    """The deferred fold broken on purpose (ISSUE 57): a tick's last step
+    decays the state and writes it, and the ``v (outer) phi(k)`` of the
+    tick's tokens never joins it. Every step INSIDE a tick still reads its
+    own tick's tokens from beside the state, so only a comparison that runs
+    across ticks sees it: the 200 steps do."""
+    fold = ret.ret_fold
+    monkeypatch.setattr(ret, "ret_fold", lambda big, pks, vc, decay: fold(
+        big, pks, jnp.zeros_like(vc), decay))
+    verdict = check()
+    assert not verdict["ok"] and verdict["logprob_err_over_logit_rms"] > 100 * TOL
 
 
 def test_a_state_that_is_not_carried_between_ticks_is_refused(monkeypatch):
@@ -199,17 +208,55 @@ def test_requests_that_share_a_prefix_answer_as_their_uncached_runs_do(model):
     assert eng.stats()["prefix_cache"]["hit_tokens"] == 0
 
 
-def test_the_counters_say_what_ran(model):
-    eng = engine(model, n_slots=3)
+def test_the_counters_say_what_ran(model, tmp_path):
+    from ditl_tpu.telemetry.journal import EventJournal, merge_journals
+    from ditl_tpu.telemetry.tracing import Tracer
+
+    journal = EventJournal(str(tmp_path / "events-engine.jsonl"), source="engine")
+    eng = engine(model, n_slots=3, tracer=Tracer(journal))
     outs = serve(eng, [prompt(9, 11), prompt(20, 12)], max_new_tokens=13)
     st = eng.stats()
     # the step that emits a token computes the next one, the last one's too
     assert st["ssm_row_steps_total"] == sum(len(t) for t, _ in outs) == 26
+    # a row's state is READ every step and WRITTEN once a tick of 4, by the
+    # tick's last step if the row is live there: 13 tokens are three whole
+    # ticks and a step, and the row that ends at that step is not folded
+    assert st["decode_chunk"] == 4 and st["ret_row_folds_total"] == 2 * 3
+    journal.close()
+    ticks = [e for e in merge_journals(str(tmp_path)) if e.get("name") == "engine.tick"
+             and "ssm_row_steps" in e]
+    assert ticks and all("ret_row_folds" in e for e in ticks)
+    assert sum(e["ssm_row_steps"] for e in ticks) == 26
+    assert sum(e["ret_row_folds"] for e in ticks) == 6
     assert st["ssm_state_bytes_per_slot"] == 3 * 2 * 144 * (16 + 1) * 4
     assert st["ssm_state_bytes_resident"] == 3 * st["ssm_state_bytes_per_slot"]
     assert st["ssm_slots_seated"] == 0
     assert st["attn_pages_a_step"] == 0 and st["attn_pages_listed_total"] == 0
     assert eng.cache["ret"].shape[:3] == (3, 3, 2) and eng.cache["ret"].dtype == jnp.float32
+
+
+@pytest.mark.parametrize("chunk", [1, 4, 16])
+def test_a_ticks_tails_are_its_held_tokens_and_its_flush_drops_them(chunk):
+    """The format alone (ISSUE 57): room for a tick's ``k``, ``v`` and
+    ``log g`` a layer a step a row a kv head, float32 zeros, never part of the
+    donated tree; a tick of one step holds nothing; every step's live rows
+    are counted as read, the last step's as written."""
+    from ditl_tpu.infer.page_format import StateSlots
+
+    fmt = StateSlots(tiny(), n_pages=0, page_size=16, n_slots=3, decode_chunk=chunk)
+    tails = fmt.tails0(3)
+    lead = (3, chunk, 3, 2)
+    assert {k: v.shape for k, v in tails.items()} == (
+        {} if chunk == 1 else {"hk": (*lead, 16), "hv": (*lead, 16), "hl": lead})
+    assert all(v.dtype == jnp.float32 and not v.any() for v in tails.values())
+    const, carried = fmt.split(fmt.fresh())
+    assert not const and set(carried) == {"ret", "retz"}
+    assert set(fmt.flush(const, {**tails, **carried}, None, None, None)) == {"ret", "retz"}
+    acc = dict.fromkeys(fmt.counters, jnp.int32(0))
+    for t in range(chunk):
+        acc = fmt.count(acc, t=jnp.int32(t), alive=jnp.asarray([True, False, True]),
+                        lengths=None, starts=None, meta={}, counted={})
+    assert (int(acc["ssm_row_steps"]), int(acc["ret_row_folds"])) == (2 * chunk, 2)
 
 
 @pytest.mark.parametrize("mode, kw", [
