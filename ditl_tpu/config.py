@@ -199,11 +199,16 @@ class ModelConfig:
     # head's query/key is ``qk_nope_head_dim + qk_rope_head_dim`` wide, its
     # value ``v_head_dim``. ``mla_scale_*``: the normed latents times
     # sqrt(hidden_size / rank). Rotary pairs are neighbouring elements (2i,
-    # 2i+1), not the two halves. It is implemented inside LongCat-Flash's
-    # shortcut-connected double layer only (models/mla.py: two attention
-    # sublayers and two dense FFNs a layer, and one expert block that reads
-    # the first post-attention norm and joins at the layer's end), so
-    # ``kv_lora_rank > 0`` also selects that block (``double_layer`` below).
+    # 2i+1), not the two halves. Two blocks carry it, told apart by ONE
+    # stated fact, ``first_k_dense_replace``: 0 is LongCat-Flash's
+    # shortcut-connected double layer (models/mla.py: two attention sublayers
+    # and two dense FFNs a layer, and one expert block that reads the first
+    # post-attention norm and joins at the layer's end; always a query
+    # latent); > 0 is the single pre-norm block behind leading dense layers
+    # (models/dsa.py; DeepSeek-V3's family), whose query latent
+    # (``q_lora_rank``, 0 = one ``wq``), YaRN (``rope_yarn_factor``) and
+    # lightning indexer (``index_topk``) are each optional (``double_layer``,
+    # ``dsa_layer``, ``indexed`` below).
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
@@ -211,11 +216,11 @@ class ModelConfig:
     v_head_dim: int = 0
     mla_scale_q_lora: bool = False
     mla_scale_kv_lora: bool = False
-    # DeepSeek-V3.2 (models/dsa.py), selected by ``index_topk > 0``: latent
-    # attention in a SINGLE pre-norm block whose every query attends to the
-    # ``index_topk`` cached tokens a lightning indexer scores highest
-    # (``index_n_heads`` heads of ``index_head_dim``, fed by the query latent;
-    # one index key a token, cached beside the latent entry).
+    # DeepSeek-V3.2's lightning indexer (models/dsa.py), ``index_topk > 0``:
+    # every query of the single pre-norm block attends to the ``index_topk``
+    # cached tokens the indexer scores highest (``index_n_heads`` heads of
+    # ``index_head_dim``, fed by the query latent, so it needs one; one index
+    # key a token, cached beside the latent entry). 0: attention is dense.
     index_n_heads: int = 0
     index_head_dim: int = 0
     index_topk: int = 0
@@ -233,8 +238,9 @@ class ModelConfig:
     rope_yarn_mscale_all_dim: float = 0.0
     # Its expert layer (models/moe.py ``_shared_moe_block``): ``scoring_func``
     # "sigmoid" scores each expert on its own (float32) where "softmax"
-    # scores them against each other; with ``n_group > 0`` the experts lie
-    # in ``n_group`` equal groups, a group's score is the sum of its two
+    # scores them against each other; with ``n_group > 1`` the experts lie
+    # in ``n_group`` equal groups (1, like 0, is no group limiting: one group
+    # that always stays), a group's score is the sum of its two
     # largest biased scores, and a token chooses inside its ``topk_group``
     # best groups only; ``n_shared_experts`` SwiGLU experts of the routed
     # experts' width (side by side: one FFN of n x width) see every token.
@@ -396,15 +402,22 @@ class ModelConfig:
     @property
     def double_layer(self) -> bool:
         """Whether the layers are LongCat-Flash's double layer (models/mla.py):
-        the only block latent attention is implemented in, so derived and not
-        a field: the second latent-attention family (``dsa_layer``) is told
-        apart by its indexer."""
-        return self.kv_lora_rank > 0 and self.index_topk == 0
+        latent attention with no leading dense layers. Derived, not a field:
+        the single pre-norm block (``dsa_layer``) is told apart by its leading
+        dense layers."""
+        return self.kv_lora_rank > 0 and self.first_k_dense_replace == 0
 
     @property
     def dsa_layer(self) -> bool:
-        """Whether the layers are DeepSeek-V3.2's (models/dsa.py): latent
-        attention in a single pre-norm block, behind a lightning indexer."""
+        """Whether the layers are the single pre-norm latent block
+        (models/dsa.py): latent attention behind ``first_k_dense_replace``
+        leading dense layers, with or without a query latent, YaRN and a
+        lightning indexer (``indexed``)."""
+        return self.kv_lora_rank > 0 and self.first_k_dense_replace > 0
+
+    @property
+    def indexed(self) -> bool:
+        """Whether that block selects by DeepSeek-V3.2's lightning indexer."""
         return self.index_topk > 0
 
     @property
@@ -555,44 +568,65 @@ class ModelConfig:
                 "every expert would ignore them"
             )
         if self.kv_lora_rank > 0 and not (
-            self.num_experts > 0 and self.q_lora_rank > 0 and self.qk_nope_head_dim > 0
+            self.num_experts > 0 and self.qk_nope_head_dim > 0
             and self.qk_rope_head_dim > 0 and self.v_head_dim > 0
         ):
             raise ValueError(
                 "latent attention (kv_lora_rank > 0) is implemented inside "
-                "LongCat-Flash's double layer and DeepSeek-V3.2's block only: "
-                "it needs an expert layer and q_lora_rank, qk_nope_head_dim, "
-                "qk_rope_head_dim and v_head_dim set"
+                "LongCat-Flash's double layer and the single pre-norm block "
+                "(models/dsa.py) only: it needs an expert layer and "
+                "qk_nope_head_dim, qk_rope_head_dim and v_head_dim set"
+            )
+        if self.double_layer and self.q_lora_rank <= 0:
+            raise ValueError(
+                "the double layer (latent attention without leading dense layers, "
+                "models/mla.py) always has a query latent: it needs q_lora_rank > 0; "
+                "one wq is the single pre-norm block's (first_k_dense_replace > 0)"
             )
         if self.index_topk > 0 and not (
-            self.kv_lora_rank > 0 and self.index_n_heads > 0
+            self.kv_lora_rank > 0 and self.index_n_heads > 0 and self.q_lora_rank > 0
             and 0 < self.qk_rope_head_dim <= self.index_head_dim
             and 0 < self.first_k_dense_replace < self.num_layers
+        ):
+            raise ValueError(
+                "a lightning indexer (index_topk > 0) selects inside "
+                "the single pre-norm latent block (models/dsa.py): it needs latent "
+                "attention (kv_lora_rank) with a query latent (q_lora_rank), "
+                "index_n_heads, an index_head_dim of at least qk_rope_head_dim, "
+                "and leading dense layers (0 < first_k_dense_replace < num_layers)"
+            )
+        if self.dsa_layer and not (
+            self.first_k_dense_replace < self.num_layers
+            and self.experts_held_count > 0
             and not (self.mla_scale_q_lora or self.mla_scale_kv_lora
                      or self.zero_expert_num)
         ):
             raise ValueError(
-                "a lightning indexer (index_topk > 0) selects inside "
-                "DeepSeek-V3.2's block (models/dsa.py): it needs latent "
-                "attention (kv_lora_rank), index_n_heads, an index_head_dim "
-                "of at least qk_rope_head_dim, leading dense layers "
-                "(0 < first_k_dense_replace < num_layers), and none of the "
-                "double layer's mla_scale_* and zero_expert_num"
+                "the single pre-norm latent block (kv_lora_rank > 0 behind "
+                "first_k_dense_replace leading dense layers, models/dsa.py) needs "
+                "an expert layer with a held share behind them "
+                "(first_k_dense_replace < num_layers, experts_held_count), and "
+                "none of the double layer's mla_scale_* and zero_expert_num"
             )
-        if self.index_topk == 0 and (
-            self.index_n_heads or self.index_head_dim or self.rope_yarn_factor
+        if self.index_topk == 0 and (self.index_n_heads or self.index_head_dim):
+            raise ValueError(
+                "index_n_heads and index_head_dim belong to the lightning indexer "
+                "(index_topk > 0): every other block would ignore them"
+            )
+        if not self.dsa_layer and (
+            self.rope_yarn_factor
             or (self.first_k_dense_replace and not self.window_layer)
         ):
             raise ValueError(
-                "index_n_heads, index_head_dim and rope_yarn_* belong to "
-                "DeepSeek-V3.2's block (index_topk > 0), first_k_dense_replace to "
-                "it and to a stack with window layers: every other block would "
-                "ignore them"
+                "rope_yarn_* belongs to the single pre-norm latent block "
+                "(kv_lora_rank > 0, models/dsa.py), first_k_dense_replace to it and "
+                "to a stack with window layers: every other block would ignore them"
             )
         if self.scoring_func not in ("softmax", "sigmoid"):
             raise ValueError(
                 f"unknown scoring_func {self.scoring_func!r} (softmax|sigmoid)")
-        grouped = self.n_group > 0 or self.topk_group > 0
+        # one group that always stays is no group limiting (n_group 1)
+        grouped = self.n_group > 1 or self.topk_group > 1
         if (self.scoring_func != "softmax" or grouped or self.n_shared_experts) and not (
             self.experts_held_count
         ):
@@ -664,6 +698,12 @@ class DataConfig:
     prefetch: int = 2  # device prefetch depth (double buffering)
     synthetic: bool = False  # True => generated data, no HF hub (hermetic tests)
     synthetic_examples: int = 256
+    # The synthetic corpus's documents at FIXED token lengths (bos and eos
+    # counted, byte tokenizer), comma-separated, repeated in this order:
+    # "4096,2048,1024,512,256,128,64,64" with ``shuffle=false`` packs every
+    # row of 8,192 with the same eight documents, whatever ``seed`` draws as
+    # their content. "" = lengths drawn with the content (16-96 words).
+    synthetic_doc_tokens: str = ""
     # Max seconds the consumer may block waiting for the prefetch producer
     # before raising a diagnosable DataStallError (data/loader.py) instead
     # of hanging the step loop forever behind a wedged pipeline (hub stall,
@@ -691,6 +731,15 @@ class TrainConfig:
     # Optimizer steps per compiled call (lax.scan window; train/step.py
     # make_multi_step). >1 removes host dispatch overhead between steps.
     steps_per_call: int = 1
+    # Seed of the initial parameters where it is not ``seed`` (-1): a job whose
+    # every run starts from ONE draw (a published checkpoint's stand-in) while
+    # ``seed`` and ``data.seed`` still vary the run.
+    init_seed: int = -1
+    # Leaves that do not train, by the name of a key on their path, comma-
+    # separated ("router": a fine-tune that leaves the routers as the
+    # checkpoint has them, as expert-specialised fine-tuning does): no
+    # optimizer update, no weight decay, no optimizer state. "" = all train.
+    frozen: str = ""
     log_every: int = 10
     metrics_file: str = ""  # "" => no JSONL scalar stream (metrics.py)
     eval_every: int = 0  # 0 => no API eval loop

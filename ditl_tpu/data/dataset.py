@@ -49,10 +49,25 @@ class TextDataset:
         return {"text": self.texts[idx], "label": self.labels[idx]}
 
 
-def synthetic_dataset(n_examples: int = 256, seed: int = 0) -> TextDataset:
-    """Deterministic IMDB-shaped sentiment corpus (text + binary label)."""
+def synthetic_dataset(n_examples: int = 256, seed: int = 0,
+                      doc_tokens: Sequence[int] = ()) -> TextDataset:
+    """Deterministic IMDB-shaped sentiment corpus (text + binary label).
+    ``doc_tokens``: document ``i`` is exactly ``doc_tokens[i % len]`` byte
+    tokens long with its bos and eos (ASCII words cut to ``n - 2`` characters),
+    so the seed draws the content and nothing of the packing."""
     rng = np.random.default_rng(seed)
     texts, labels = [], []
+    if doc_tokens:
+        if min(doc_tokens) < 3:
+            raise ValueError(f"synthetic_doc_tokens {list(doc_tokens)}: a document is "
+                             "its bos, its eos and at least one byte")
+        for i in range(n_examples):
+            n = doc_tokens[i % len(doc_tokens)] - 2
+            # every word and its space is at least two characters
+            words = rng.choice(_WORDS, size=n // 2 + 1)
+            texts.append(" ".join(words.tolist())[:n])
+            labels.append(int(rng.integers(0, 2)))
+        return TextDataset(texts, labels)
     for _ in range(n_examples):
         label = int(rng.integers(0, 2))
         n_words = int(rng.integers(16, 96))
@@ -66,7 +81,9 @@ def synthetic_dataset(n_examples: int = 256, seed: int = 0) -> TextDataset:
 def load_text_dataset(config: DataConfig) -> TextDataset:
     """HF-hub ingestion with a hermetic fallback."""
     if config.synthetic:
-        return synthetic_dataset(config.synthetic_examples, config.seed)
+        return synthetic_dataset(
+            config.synthetic_examples, config.seed,
+            tuple(int(n) for n in config.synthetic_doc_tokens.split(",") if n))
     try:
         from datasets import load_dataset
 

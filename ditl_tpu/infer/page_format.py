@@ -911,6 +911,12 @@ def page_format(cfg: ModelConfig, **kw) -> PageFormat:
     """The format of ``cfg``'s cache entries (derived, never a setting): latent
     pages where attention is latent, two K/V pools where the stack has window
     layers, state slots where it keeps no keys and values, K/V pages otherwise."""
+    if cfg.dsa_layer and not cfg.indexed:
+        raise ValueError(
+            "the single pre-norm latent block without an indexer (kv_lora_rank > 0, "
+            "first_k_dense_replace > 0, index_topk 0: models/dsa.py) is trained, not "
+            "served: its cached forward (the absorbed form over a latent pool with no "
+            "index-key pool beside it) is not built; the trainer and an evaluation run it")
     if cfg.kv_lora_rank > 0:
         return LatentPages(cfg, **kw)
     if cfg.retention_layer:

@@ -1,8 +1,13 @@
-"""DeepSeek-V3.2's block: multi-head latent attention in a SINGLE pre-norm
-block, sparse by a lightning indexer (DeepSeek Sparse Attention), and an FFN
-that is dense in the first ``first_k_dense_replace`` layers and an expert
-layer with a shared expert in the rest. ``llama.forward`` hands its layers to
-``stack`` when ``cfg.dsa_layer``.
+"""The single pre-norm latent block (DeepSeek-V3's family): multi-head latent
+attention, and an FFN that is dense in the first ``first_k_dense_replace``
+layers and an expert layer with a shared expert in the rest. ``llama.forward``
+hands its layers to ``stack`` when ``cfg.dsa_layer``. Three extras are each
+optional (``ModelConfig``): a QUERY LATENT (``q_lora_rank > 0``; without one
+``q = z Wq``, one matrix), YaRN on the rotary frequencies (``rope_yarn_factor
+> 0``) and DeepSeek-V3.2's lightning INDEXER (``index_topk > 0``: DeepSeek
+Sparse Attention; without one the ``index`` subtree, the index keys and their
+pool do not exist and attention is dense). DeepSeek-V3.2 has all three,
+Kanana-2 none.
 
 With ``n`` an RMSNorm with its own scale, ``t`` a query position and
 ``s <= t`` a key position::
@@ -29,7 +34,15 @@ A token's cache entry is the latent ``[c | rope(kr)]`` (``mla.latent_width``:
 576 values stored at 640) AND its index key ``rope(kI)`` (``index_head_dim``
 values), in two page pools under ONE page table.
 
-Attention is always the ABSORBED form (``Wkvb``'s key half folded into the
+Without an indexer a forward WITHOUT cache (the trainer, a reference check,
+an evaluation) runs the DECOMPRESSED form (``_decompressed_attend``): ``k =
+[k_nope | rope(kr) broadcast over the heads]`` (``nope + rope`` wide), ``v``
+(``v_head_dim`` wide) for every position, through ``ops/attention.py`` and so
+the flash kernels at two widths, under ``segment_ids``. A row of 8,192 tokens
+in the absorbed form does 3 x the score and 4 x the value operations (a query
+576 wide against ``c``, a value 512 wide) and has no kernel.
+
+Everything else is the ABSORBED form (``Wkvb``'s key half folded into the
 query, its value half behind the attention), because a query reads the few
 entries it selected and not a decompressed copy of the context:
 
@@ -65,8 +78,9 @@ and ``dsa_gather`` inside ``attn_core``; ``mla_q`` / ``mla_kv`` / ``mla_attn``
 as the double layer has them.
 
 Serving only as far as the cache goes, like the double layer: paged pools,
-plain ticks; the engine refuses the rest (infer/page_format.py). The
-multi-token-prediction module is not implemented.
+plain ticks; the engine refuses the rest, and the block without an indexer
+whole (infer/page_format.py). The multi-token-prediction module is not
+implemented.
 """
 
 from __future__ import annotations
@@ -170,25 +184,29 @@ def init_dsa_params(rng: jax.Array, cfg: ModelConfig) -> dict[str, Any]:
         def dense(shape, fan_in):
             return lean_dense(next(keys), (n,) + shape, fan_in, pd)
 
+        if qr:
+            query = {"w_qa": dense((d, qr), d), "q_norm": jnp.ones((n, qr), pd),
+                     "w_qb": dense((qr, nh * (nope + rope)), qr)}
+        else:  # no query latent: one matrix
+            query = {"wq": dense((d, nh * (nope + rope)), d)}
         out = {
             "attn_norm": {"scale": jnp.ones((n, d), pd)},
             "attn": {
-                "w_qa": dense((d, qr), d),
-                "q_norm": jnp.ones((n, qr), pd),
-                "w_qb": dense((qr, nh * (nope + rope)), qr),
+                **query,
                 "w_kva": dense((d, kr + rope), d),
                 "kv_norm": jnp.ones((n, kr), pd),
                 "w_kvb": dense((kr, nh * (nope + vd)), kr),
                 "wo": dense((nh * vd, d), nh * vd),
             },
-            "index": {
+            "mlp_norm": {"scale": jnp.ones((n, d), pd)},
+        }
+        if cfg.indexed:
+            out["index"] = {
                 "wq_b": dense((qr, hi * di), qr),
                 "wk": dense((d, di), d),
                 "k_norm": {"scale": jnp.ones((n, di), pd), "bias": jnp.zeros((n, di), pd)},
                 "w_proj": dense((d, hi), d),
-            },
-            "mlp_norm": {"scale": jnp.ones((n, d), pd)},
-        }
+            }
         if dense_ffn:
             out["mlp"] = {"w_gate": dense((d, f), d), "w_up": dense((d, f), d),
                           "w_down": dense((f, d), f)}
@@ -206,25 +224,29 @@ def dsa_logical_axes(cfg: ModelConfig) -> dict[str, Any]:
     from ditl_tpu.models.moe import moe_logical_axes
 
     def one(dense_ffn):
+        if cfg.q_lora_rank:
+            query = {"w_qa": ("layers", "embed", None), "q_norm": ("layers", "norm"),
+                     "w_qb": ("layers", None, "heads")}
+        else:
+            query = {"wq": ("layers", "embed", "heads")}
         out = {
             "attn_norm": {"scale": ("layers", "norm")},
             "attn": {
-                "w_qa": ("layers", "embed", None),
-                "q_norm": ("layers", "norm"),
-                "w_qb": ("layers", None, "heads"),
+                **query,
                 "w_kva": ("layers", "embed", None),
                 "kv_norm": ("layers", "norm"),
                 "w_kvb": ("layers", None, "heads"),
                 "wo": ("layers", "heads", "embed"),
             },
-            "index": {
+            "mlp_norm": {"scale": ("layers", "norm")},
+        }
+        if cfg.indexed:
+            out["index"] = {
                 "wq_b": ("layers", None, "heads"),
                 "wk": ("layers", "embed", None),
                 "k_norm": {"scale": ("layers", "norm"), "bias": ("layers", "norm")},
                 "w_proj": ("layers", "embed", None),
-            },
-            "mlp_norm": {"scale": ("layers", "norm")},
-        }
+            }
         if dense_ffn:
             out["mlp"] = {"w_gate": ("layers", "embed", "mlp"),
                           "w_up": ("layers", "embed", "mlp"),
@@ -511,16 +533,46 @@ def _decode_attend(q_full, qi, w, *, cfg: ModelConfig, pools, tails, paged):
     return lat, ok.sum(), (idx, ok)
 
 
+def _decompressed_attend(q, c, kr, w_kvb, *, cfg: ModelConfig, segment_ids, mesh, rules):
+    """Dense attention of a forward without cache, keys and values decompressed
+    for every position: q (B, S, H, nope + rope) rotated, c (B, S, r) normed,
+    kr (B, S, rope) rotated, w_kvb (r, H, nope + vd) -> (B, S, H, vd). Through
+    ``ops/attention.py``: the flash kernels at two widths where
+    ``attention_impl`` says so, causal inside ``segment_ids``."""
+    from ditl_tpu.ops.attention import dot_product_attention
+
+    b, s, nh, _ = q.shape
+    nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    with jax.named_scope("mla_kv"):
+        # two products, so that no [k_nope | v] of every position is held
+        # beside the k and the v cut out of it (512 MB a row of 4 x 8,192)
+        k_nope = jnp.einsum("bsr,rhn->bshn", c, w_kvb[..., :nope])
+        v = jnp.einsum("bsr,rhv->bshv", c, w_kvb[..., nope:])
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(kr[:, :, None, :], (b, s, nh, rope))], axis=-1)
+    ratio = softmax_scale(cfg) / (nope + rope) ** -0.5  # YaRN's m ** 2, else 1
+    if ratio != 1.0:  # the attention paths scale by the query's width alone
+        q = (q * ratio).astype(q.dtype)
+    with jax.named_scope("mla_attn"):
+        return dot_product_attention(
+            q, k, v, causal=True, segment_ids=segment_ids,
+            impl=cfg.attention_impl, mesh=mesh, rules=rules,
+            block_sizes=(cfg.flash_block_q, cfg.flash_block_kv,
+                         cfg.flash_block_q_bwd, cfg.flash_block_kv_bwd))
+
+
 def _attention(a, ix, h, *, cfg: ModelConfig, positions, allowed, cache, cache_index,
-               paged, pools, cd, layer, token_mask):
+               paged, pools, cd, layer, token_mask, segment_ids=None, mesh=None, rules=None):
     """The attention sublayer of layer ``layer`` on the normed input ``h`` (B,
     S, D): ``(out (B, S, D) before the residual, new cache or None, selected
-    tokens)``. ``cache``: None; a prefill's row ``{"c": (B, Smax, Dl),
-    "i": (B, Smax, Di)}`` (written at ``cache_index``, attended under
-    ``allowed`` (B, S, Smax)); or, with ``pools``, this layer's tails
-    ``{"tc": (B, T, Dl), "ti": (B, T, Di)}`` of a paged decode step."""
+    tokens)``. ``ix``: the indexer's parameters, None without one. ``cache``:
+    None; a prefill's row ``{"c": (B, Smax, Dl), "i": (B, Smax, Di)}`` (written
+    at ``cache_index``, attended under ``allowed`` (B, S, Smax)); or, with
+    ``pools``, this layer's tails ``{"tc": (B, T, Dl), "ti": (B, T, Di)}`` of a
+    paged decode step. ``allowed`` None (no cache, no indexer): the
+    decompressed form under ``segment_ids``."""
     from ditl_tpu.models.llama import rms_norm
-    from ditl_tpu.ops.quant import weight_einsum
+    from ditl_tpu.ops.quant import is_quantized_leaf, weight_einsum
 
     b, s, _ = h.shape
     nh, eps = cfg.num_heads, cfg.rms_norm_eps
@@ -529,33 +581,64 @@ def _attention(a, ix, h, *, cfg: ModelConfig, positions, allowed, cache, cache_i
     w_kvb = a["w_kvb"].astype(cd).reshape(r, nh, nope + vd)
     inv_freq = jnp.asarray(yarn_inv_freq(cfg))
     pad = latent_width(cfg) - r - rope
+    decompressed = cache is None and ix is None
 
     with jax.named_scope("attn_qkv"):
         with jax.named_scope("mla_q"):
-            cq = rms_norm(weight_einsum("bsd,dr->bsr", h, a["w_qa"], compute_dtype=cd),
-                          a["q_norm"], eps)
-            q = weight_einsum("bsr,rf->bsf", cq, a["w_qb"], compute_dtype=cd)
-            q = q.reshape(b, s, nh, nope + rope)
+            if "wq" not in a:
+                cq = rms_norm(weight_einsum("bsd,dr->bsr", h, a["w_qa"], compute_dtype=cd),
+                              a["q_norm"], eps)
+                q = weight_einsum("bsr,rf->bsf", cq, a["w_qb"], compute_dtype=cd)
+                q = q.reshape(b, s, nh, nope + rope)
+            elif is_quantized_leaf(a["wq"]):  # no query latent; a weight-only int8 leaf
+                cq = None
+                q = weight_einsum("bsd,df->bsf", h, a["wq"], compute_dtype=cd)
+                q = q.reshape(b, s, nh, nope + rope)
+            else:
+                # the heads split in the WEIGHT: a (B, S, H x 192) product cut
+                # into heads of 192 is a copy into 256-lane tiles, forward and back
+                cq = None
+                q = jnp.einsum("bsd,dhf->bshf", h,
+                               a["wq"].astype(cd).reshape(-1, nh, nope + rope))
             q_rope = rope_interleaved(q[..., nope:], positions, 0.0, inv_freq=inv_freq)
-            # Wkvb's key half folded into the query: kv_lora_rank wide, against c
-            q_lat = jnp.einsum("bshn,rhn->bshr", q[..., :nope], w_kvb[..., :nope])
-            q_full = jnp.concatenate(
-                [q_lat, q_rope, jnp.zeros((b, s, nh, pad), q_lat.dtype)], axis=-1)
+            if decompressed:
+                q_full = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
+            else:
+                # Wkvb's key half folded into the query: kv_lora_rank wide, against c
+                q_lat = jnp.einsum("bshn,rhn->bshr", q[..., :nope], w_kvb[..., :nope])
+                q_full = jnp.concatenate(
+                    [q_lat, q_rope, jnp.zeros((b, s, nh, pad), q_lat.dtype)], axis=-1)
         with jax.named_scope("mla_kv"):
             ckr = weight_einsum("bsd,df->bsf", h, a["w_kva"], compute_dtype=cd)
             c = rms_norm(ckr[..., :r], a["kv_norm"], eps)
             kr = rope_interleaved(ckr[..., None, r:], positions, 0.0,
                                   inv_freq=inv_freq)[:, :, 0]
-            entry = jnp.concatenate(
-                [c, kr, jnp.zeros((b, s, pad), c.dtype)], axis=-1)  # (B, S, Dl)
-        with jax.named_scope("dsa_index"):
-            qi = weight_einsum("bsr,rf->bsf", cq, ix["wq_b"], compute_dtype=cd)
-            qi = _rope_front(qi.reshape(b, s, hi, di), positions, inv_freq, rope)
-            ki = _layer_norm(weight_einsum("bsd,df->bsf", h, ix["wk"], compute_dtype=cd),
-                             ix["k_norm"], eps)
-            ki = _rope_front(ki[:, :, None], positions, inv_freq, rope)[:, :, 0]
-            w = weight_einsum("bsd,dh->bsh", h, ix["w_proj"], compute_dtype=cd,
-                              preferred=jnp.float32) * (hi ** -0.5 * di ** -0.5)
+            if not decompressed:
+                entry = jnp.concatenate(
+                    [c, kr, jnp.zeros((b, s, pad), c.dtype)], axis=-1)  # (B, S, Dl)
+        qi = ki = w = None
+        if ix is not None:
+            with jax.named_scope("dsa_index"):
+                qi = weight_einsum("bsr,rf->bsf", cq, ix["wq_b"], compute_dtype=cd)
+                qi = _rope_front(qi.reshape(b, s, hi, di), positions, inv_freq, rope)
+                ki = _layer_norm(weight_einsum("bsd,df->bsf", h, ix["wk"], compute_dtype=cd),
+                                 ix["k_norm"], eps)
+                ki = _rope_front(ki[:, :, None], positions, inv_freq, rope)[:, :, 0]
+                w = weight_einsum("bsd,dh->bsh", h, ix["w_proj"], compute_dtype=cd,
+                                  preferred=jnp.float32) * (hi ** -0.5 * di ** -0.5)
+
+    if decompressed:
+        attn = _decompressed_attend(q_full, c, kr, w_kvb, cfg=cfg, segment_ids=segment_ids,
+                                    mesh=mesh, rules=rules)
+        with jax.named_scope("attn_out"):
+            out = weight_einsum("bsf,fd->bsd", attn.reshape(b, s, nh * vd), a["wo"],
+                                compute_dtype=cd)
+        return out, None, jnp.zeros((), jnp.int32)  # nothing is selected: dense
+    if ix is None:
+        raise ValueError(
+            "the latent block without an indexer (index_topk 0, models/dsa.py) has no "
+            "cached forward: it is trained and evaluated, not served "
+            "(infer/page_format.py refuses it)")
 
     with jax.named_scope("attn_core"):
         if pools is not None:
@@ -596,7 +679,8 @@ def _attention(a, ix, h, *, cfg: ModelConfig, positions, allowed, cache, cache_i
 
 
 def _block(lp, x, *, cfg: ModelConfig, positions, allowed, mesh, rules, layer_cache,
-           cache_index, paged, pools, token_mask, moe_stack, layer_index, layer):
+           cache_index, paged, pools, token_mask, moe_stack, layer_index, layer,
+           segment_ids=None):
     """Layer ``layer`` of the model, ``layer_index`` of its stack: ``(x, aux,
     new cache or None, expert counts (count_width,) or None (a dense layer),
     selected tokens ())``.
@@ -613,9 +697,9 @@ def _block(lp, x, *, cfg: ModelConfig, positions, allowed, mesh, rules, layer_ca
     with jax.named_scope("attn_qkv"):
         h = rms_norm(x, lp["attn_norm"]["scale"], eps)
     out, new_cache, n_sel = _attention(
-        lp["attn"], lp["index"], h, cfg=cfg, positions=positions, allowed=allowed,
+        lp["attn"], lp.get("index"), h, cfg=cfg, positions=positions, allowed=allowed,
         cache=cache, cache_index=cache_index, paged=paged, pools=pools, cd=cd, layer=layer,
-        token_mask=token_mask)
+        token_mask=token_mask, segment_ids=segment_ids, mesh=mesh, rules=rules)
     with jax.named_scope("attn_out"):
         x = _constrain(x + out, ("batch", "seq", "act_embed"), mesh, rules)
     with jax.named_scope("mlp"):
@@ -624,9 +708,14 @@ def _block(lp, x, *, cfg: ModelConfig, positions, allowed, mesh, rules, layer_ca
             y = dense_mlp(lp["mlp"], u, cfg=cfg, mesh=mesh, rules=rules)
             aux, counts = jnp.zeros((), jnp.float32), None
         else:
+            # a forward without cache of the block without an indexer may be
+            # differentiated: a static number of buffers of held pairs, not a
+            # loop whose trip count is data (an indexed block is served only,
+            # and its passes keep the loop they had)
             y, aux, counts = moe_block(
                 {**lp["moe"], **(moe_stack or {})}, u, cfg, token_mask=token_mask,
-                mesh=mesh, layer=layer_index if moe_stack else None)
+                mesh=mesh, layer=layer_index if moe_stack else None,
+                static_buffers=layer_cache is None and not cfg.indexed)
         x = _constrain(x + y, ("batch", "seq", "act_embed"), mesh, rules)
     if new_cache is not None:
         new_cache = {k: v[None] for k, v in new_cache.items()}
@@ -659,8 +748,8 @@ def stack(layers, x, *, cfg: ModelConfig, positions, segment_ids, mesh, rules,
         n_pages = cache["cp"].shape[1]
         pools = {k: cache[k].reshape(-1, *cache[k].shape[2:]) for k in ("cp", "ip")}
         cache = {k: cache[k] for k in ("tc", "ti")}
-    if pools is not None:
-        allowed = None
+    if pools is not None or (cache is None and not cfg.indexed):
+        allowed = None  # a decode step; the decompressed form, under segment_ids
     elif cache is not None and not prefill_causal:
         allowed = attn_mask  # (B, S, Smax), the engine's
     else:
@@ -696,7 +785,8 @@ def stack(layers, x, *, cfg: ModelConfig, positions, segment_ids, mesh, rules,
                 lp, carry, cfg=cfg, positions=positions, allowed=allowed, mesh=mesh,
                 rules=rules, layer_cache=layer_cache, cache_index=cache_index,
                 paged=layer_paged, pools=pools, token_mask=token_mask,
-                moe_stack=moe_stack, layer_index=i, layer=first + i)
+                moe_stack=moe_stack, layer_index=i, layer=first + i,
+                segment_ids=segment_ids)
             return y, tuple(ys)
 
         if cache is None:

@@ -67,6 +67,7 @@ SHARED expert, a dense SwiGLU FFN every token passes through, joins the sum
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any
 
@@ -280,6 +281,7 @@ def moe_block(
     token_mask: jax.Array | None = None,
     mesh=None,
     layer=None,
+    static_buffers: bool = False,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """(B, S, D) -> ((B, S, D), aux, counts) through top-k routed experts.
 
@@ -290,10 +292,12 @@ def moe_block(
     None). ``mesh``: the mesh the step is partitioned over, if any (it
     decides which grouped matmul runs, see ``_use_gmm``). ``layer``: the
     index of this layer where ``moe``'s expert weights are the whole stack
-    (``experts_in_place``; the router is this layer's own)."""
+    (``experts_in_place``; the router is this layer's own).
+    ``static_buffers``: a share walks a STATIC number of buffers of held pairs
+    (``_shared_moe_block``), so that the pass can be differentiated."""
     if shares_experts(cfg):
         return _shared_moe_block(moe, h, cfg, token_mask=token_mask, mesh=mesh,
-                                 layer=layer)
+                                 layer=layer, static_buffers=static_buffers)
     b, s, d = h.shape
     e, k = cfg.num_experts, cfg.num_experts_per_tok
     cd = h.dtype
@@ -336,9 +340,18 @@ def moe_block(
     return out.reshape(b, s, d), aux, counts.astype(jnp.int32)
 
 
-def _shared_moe_block(moe, h, cfg: ModelConfig, *, token_mask, mesh, layer):
+def _shared_moe_block(moe, h, cfg: ModelConfig, *, token_mask, mesh, layer,
+                      static_buffers: bool = False):
     """``moe_block`` for a share of a wider expert layer (module docstring):
-    (B, S, D) -> ((B, S, D), aux, counts (n_held + 2,))."""
+    (B, S, D) -> ((B, S, D), aux, counts (n_held + 2,)).
+
+    The buffers of held pairs are walked by a loop whose trip count is data
+    (a serving pass: as many buffers as the held pairs fill) or, with
+    ``static_buffers`` (a pass that may be differentiated: reverse mode does
+    not cross a data trip count), by ``ceil(T k / m)`` buffers unrolled, all
+    the pairs there can be at any skew. Each is a ``jax.lax.cond`` on whether
+    it holds a pair, so an empty one runs no grouped matmul, forward or
+    backward, and passes the sum through."""
     b, s, d = h.shape
     e, k = cfg.num_experts, cfg.num_experts_per_tok
     first, n_held = held_experts(cfg)
@@ -355,10 +368,17 @@ def _shared_moe_block(moe, h, cfg: ModelConfig, *, token_mask, mesh, layer):
                  else jax.nn.softmax(logits, axis=-1))  # (T, E + Z) f32
 
     with jax.named_scope("moe_dispatch"):
-        choose = gates + moe["router_bias"] if "router_bias" in moe else gates
-        if cfg.n_group:
+        # a buffer, not a parameter: it only chooses, and no gradient reaches it
+        choose = (gates + jax.lax.stop_gradient(moe["router_bias"])
+                  if "router_bias" in moe else gates)
+        if cfg.n_group > 1:  # one group that always stays limits nothing
             choose = group_limited(choose, cfg.n_group, cfg.topk_group)
         _, top_idx = jax.lax.top_k(choose, k)  # the bias chooses, and only chooses
+        if "choice" in moe:
+            # a check that holds the choice still (benchmarks/train_grad_check.py
+            # gives the plain reference's, so that a rounding's flipped sixth
+            # choice is told from an error): (T, k) expert ids in place of ours
+            top_idx = moe["choice"].reshape(t, k).astype(top_idx.dtype)
         top_w = jnp.take_along_axis(gates, top_idx, axis=-1)
         if cfg.norm_topk_prob:
             top_w = top_w / jnp.maximum(top_w.sum(axis=-1, keepdims=True), 1e-9)
@@ -383,35 +403,63 @@ def _shared_moe_block(moe, h, cfg: ModelConfig, *, token_mask, mesh, layer):
         n_rows = ends[-1]
         pair_w = top_w.reshape(t * k)
 
-    def one_buffer(i, acc):
-        # rows [i * m, (i + 1) * m) of the sorted held pairs
+    def buffer_rows(i, x, experts, pair_w):
+        """Rows [i * m, (i + 1) * m) of the sorted held pairs through the
+        experts: (their tokens (m,), their weighted outputs (m, D) float32,
+        zero where the buffer ends before the row)."""
         with jax.named_scope("moe_dispatch"):
             at = i * m + jnp.arange(m, dtype=jnp.int32)
             valid = at < n_rows
             pair = jax.lax.dynamic_slice(order, (i * m,), (m,))
             tok = pair // k
             xs = x[tok]
+            if static_buffers:
+                # the kernel's transposed products leave the rows of no group
+                # as they were, and the gather's backward would add them to
+                # their tokens: a select, so that no cotangent reaches them
+                xs = jnp.where(valid[:, None], xs, 0)
             part = jnp.clip(jnp.minimum(ends, (i + 1) * m)
                             - jnp.maximum(ends - sizes, i * m), 0, m)
             row_expert = jnp.minimum(key[pair], n_held - 1)
         with jax.named_scope("moe_experts"):
-            gate = _grouped(xs, moe["w_gate"], part, row_expert, cd, mesh, layer)
-            up = _grouped(xs, moe["w_up"], part, row_expert, cd, mesh, layer)
-            ys = _grouped(jax.nn.silu(gate) * up, moe["w_down"], part, row_expert, cd,
+            gate = _grouped(xs, experts["w_gate"], part, row_expert, cd, mesh, layer)
+            up = _grouped(xs, experts["w_up"], part, row_expert, cd, mesh, layer)
+            ys = _grouped(jax.nn.silu(gate) * up, experts["w_down"], part, row_expert, cd,
                           mesh, layer)
         with jax.named_scope("moe_combine"):
             # rows past the held pairs belong to no group: the kernel leaves
             # them as they were, so they are masked, not weighted by zero
             rows = jnp.where(valid[:, None], ys.astype(jnp.float32), 0.0)
-            return acc.at[tok].add(rows * pair_w[pair][:, None])
+            return tok, rows * pair_w[pair][:, None]
 
-    out = jax.lax.fori_loop(0, (n_rows + m - 1) // m, one_buffer,
-                            jnp.zeros((t, d), jnp.float32))
+    experts = {n: moe[n] for n in ("w_gate", "w_up", "w_down")}
+    # a differentiated buffer keeps its inputs alone and runs its rows again in
+    # the backward pass: the layer's own remat no longer needs them, so the
+    # grouped matmuls run as often as without, and eight buffers' rows
+    # (0.4 GiB each at 24,576 pairs of 2,048) are never resident together
+    rows_of = jax.checkpoint(buffer_rows, static_argnums=0) if static_buffers else buffer_rows
+
+    def one_buffer(i, acc):
+        tok, rows = rows_of(i, x, experts, pair_w)
+        with jax.named_scope("moe_combine"):
+            return acc.at[tok].add(rows)
+
+    out = jnp.zeros((t, d), jnp.float32)
+    if static_buffers:
+        for i in range(-(-(t * k) // m)):
+            out = jax.lax.cond(i * m < n_rows, functools.partial(one_buffer, i),
+                               lambda acc: acc, out)
+    else:
+        out = jax.lax.fori_loop(0, (n_rows + m - 1) // m, one_buffer, out)
     with jax.named_scope("moe_combine"):
-        with jax.named_scope("moe_zero"):
-            # the identities: the token's own row, once, times their weights
-            w_zero = (top_w * (top_idx >= e)).sum(axis=-1)
-            out = out + x.astype(jnp.float32) * w_zero[:, None]
+        if cfg.zero_expert_num or not static_buffers:
+            with jax.named_scope("moe_zero"):
+                # the identities: the token's own row, once, times their weights
+                # (a differentiated pass without zero-compute experts leaves the
+                # term out: a float32 copy of the stream times zero, kept for
+                # the backward pass)
+                w_zero = (top_w * (top_idx >= e)).sum(axis=-1)
+                out = out + x.astype(jnp.float32) * w_zero[:, None]
         out = out.astype(cd)
     if "shared" in moe:
         from ditl_tpu.ops.quant import weight_einsum

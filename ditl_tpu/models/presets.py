@@ -253,6 +253,45 @@ PRESETS: dict[str, ModelConfig] = {
         experts_held_first=0,
         experts_held_count=256,
     ),
+    # Kanana-2-30B-A3B (kakaocorp/kanana-2-30b-a3b-instruct-2601, ``model_type:
+    # deepseek_v3``; the cut that is TRAINED is benchmarks/configs/
+    # kanana-2-30b-a3b-cut1.json); models/dsa.py without its three extras: no
+    # query latent (``q_lora_rank`` null: one ``wq``), no YaRN, no indexer.
+    # 48 layers, one leading dense FFN of 6,144, 128 experts of 768 (6 a token,
+    # sigmoid scores renormalised x 2.448, a bias for the choice, no group
+    # limiting, two shared experts: one FFN of 1,536), an untied head. The loss
+    # has no auxiliary term (``topk_method: noaux_tc``).
+    "kanana-2-30b-a3b": ModelConfig(
+        name="kanana-2-30b-a3b",
+        vocab_size=128256,
+        hidden_size=2048,
+        intermediate_size=6144,
+        expert_ffn_hidden_size=768,
+        num_layers=48,
+        num_heads=32,
+        num_kv_heads=32,
+        head_dim=192,  # a query's / key's: 128 without position + 64 rotary
+        max_seq_len=32768,
+        rope_theta=1000000.0,
+        rms_norm_eps=1e-6,
+        kv_lora_rank=512,
+        qk_nope_head_dim=128,
+        qk_rope_head_dim=64,
+        v_head_dim=128,
+        num_experts=128,
+        num_experts_per_tok=6,
+        norm_topk_prob=True,
+        routed_scaling_factor=2.448,
+        router_bias=True,
+        router_aux_coef=0.0,
+        scoring_func="sigmoid",
+        n_group=1,
+        topk_group=1,
+        n_shared_experts=2,
+        first_k_dense_replace=1,
+        experts_held_first=0,
+        experts_held_count=128,
+    ),
     # Trinity-Mini (arcee-ai/Trinity-Mini, ``model_type: afmoe``; the cut that
     # is served is benchmarks/configs/trinity-mini-cut1.json); models/swa.py.
     # Eight periods of three window-2,048 layers (rope) and one full layer

@@ -14,7 +14,9 @@ with four interchangeable implementations selected by
                re-sharded instead of KV rotated (ops/ulysses.py).
 
 All take GQA-layout tensors: q ``(B, S, H, D)``, k/v ``(B, S, K, D)`` with
-``H % K == 0``; softmax is computed in float32 regardless of input dtype.
+``H % K == 0``; softmax is computed in float32 regardless of input dtype. On
+the xla and flash paths v (and so the output) may have a width of its own
+(latent attention decompressed: 192 / 128).
 """
 
 from __future__ import annotations
@@ -78,7 +80,7 @@ def _xla_attention(
         probs = probs * jnp.moveaxis(v_scale, 1, 2)[:, :, None, None, :]
     probs = probs.astype(v.dtype)
     out = jnp.einsum("bkgqs,bskd->bqkgd", probs, v)
-    return out.reshape(b, s_q, h, d)
+    return out.reshape(b, s_q, h, v.shape[-1])  # the values' width, not q's
 
 
 def _mesh_axes_size(mesh, axes) -> int:
@@ -272,15 +274,15 @@ def dot_product_attention(
             )
         bq, bkv, bqb, bkvb = block_sizes or (0, 0, 0, 0)
         bq, bkv = bq or 512, bkv or 512
-        if not (fa.supports(q.shape[1], k.shape[1], q.shape[3], bq, bkv)
+        if not (fa.supports(q.shape[1], k.shape[1], q.shape[3], bq, bkv, v.shape[3])
                 and fa.supports(q.shape[1], k.shape[1], q.shape[3],
-                                bqb or bq, bkvb or bkv)):
+                                bqb or bq, bkvb or bkv, v.shape[3])):
             # Shapes the kernel can't tile: an error on the TPU; in
             # interpret mode (tiny tests, odd seq lens) XLA.
             refuse_on_tpu(
                 "attention_impl='flash'",
                 f"cannot tile Sq={q.shape[1]} Skv={k.shape[1]} "
-                f"D={q.shape[3]} (block_q={bq}, block_kv={bkv}, "
+                f"D={q.shape[3]} Dv={v.shape[3]} (block_q={bq}, block_kv={bkv}, "
                 f"bwd {bqb or bq}/{bkvb or bkv})",
             )
             return _xla_attention(q, k, v, causal=causal, segment_ids=segment_ids,

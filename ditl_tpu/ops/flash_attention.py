@@ -57,6 +57,10 @@ Design (TPU-first):
   query and key ids under which some query row shares a segment with NO key:
   there the unskipped forward returns a mean of V and this one 0. The public
   ``flash_attention`` cannot reach it.
+- **Two widths**: queries and keys are ``D`` wide, values and the output
+  ``Dv`` (one width everywhere but latent attention decompressed: 192 / 128).
+  ``D`` only ever meets itself in a contraction (``q.k^T``, ``ds.k``,
+  ``ds^T.q``); the row statistics are tiled to ``Dv``. Nothing is padded.
 - **Custom VJP**: backward runs two Pallas kernels — one accumulating dq over
   KV blocks, one accumulating dk/dv over (group × query) blocks — both
   recomputing p from the saved log-sum-exp (FlashAttention-2 style).
@@ -95,17 +99,23 @@ def _pick_blocks(s_q: int, s_kv: int, block_q: int, block_kv: int) -> BlockSizes
 
 
 def supports(s_q: int, s_kv: int, head_dim: int, block_q: int = 512,
-             block_kv: int = 512) -> bool:
+             block_kv: int = 512, v_dim: int | None = None) -> bool:
     """True if the kernel can handle these shapes (off the TPU,
-    ``ops.attention`` gives way to XLA otherwise; on it, it raises)."""
+    ``ops.attention`` gives way to XLA otherwise; on it, it raises).
+    ``head_dim`` is the queries' and keys' width, ``v_dim`` the values' and
+    the output's where it differs (latent attention decompressed: 192 / 128)."""
     bq, bkv = _pick_blocks(s_q, s_kv, block_q, block_kv)
+    v_dim = head_dim if v_dim is None else v_dim
     return (
         s_q % bq == 0
         and s_kv % bkv == 0
         and bkv % NUM_LANES == 0
         and bq % NUM_SUBLANES == 0
-        # _lane_tile can slice (64) or tile whole lanes (128k), nothing else.
-        and (head_dim == 64 or head_dim % NUM_LANES == 0)
+        # _lane_tile can slice (64) or tile whole lanes (128k), nothing else:
+        # the width the row statistics are tiled to is the values'.
+        and (v_dim == 64 or v_dim % NUM_LANES == 0)
+        # q and k only meet in a contraction: whole or half lanes of 128
+        and (head_dim == v_dim or head_dim % (NUM_LANES // 2) == 0)
     )
 
 
@@ -419,6 +429,7 @@ def _fwd(
 ) -> tuple[jax.Array, jax.Array]:
     b, h, s_q, d = q.shape
     _, kv_heads, s_kv, _ = k.shape
+    dv = v.shape[-1]  # the values' and the output's width; d is q's and k's
     groups = h // kv_heads
     bq, bkv = blocks
     n_q, n_kv = s_q // bq, s_kv // bkv
@@ -440,7 +451,7 @@ def _fwd(
     in_specs = [
         pl.BlockSpec((1, 1, bq, d), q_map),
         pl.BlockSpec((1, 1, bkv, d), kv_map),
-        pl.BlockSpec((1, 1, bkv, d), kv_map),
+        pl.BlockSpec((1, 1, bkv, dv), kv_map),
     ]
     args = [q, k, v]
     if q_seg is not None:
@@ -475,11 +486,11 @@ def _fwd(
         window=window,
     )
     out_shapes = (
-        jax.ShapeDtypeStruct((b, h, s_q, d), q.dtype),
+        jax.ShapeDtypeStruct((b, h, s_q, dv), q.dtype),
         jax.ShapeDtypeStruct((b, h, s_q, NUM_LANES), jnp.float32),
     )
     out_specs = (
-        pl.BlockSpec((1, 1, bq, d), q_map),
+        pl.BlockSpec((1, 1, bq, dv), q_map),
         pl.BlockSpec((1, 1, bq, NUM_LANES), q_map),
     )
     o, lse = _pallas(
@@ -492,7 +503,7 @@ def _fwd(
         scratch_shapes=[
             pltpu.VMEM((bq, NUM_LANES), jnp.float32),  # m
             pltpu.VMEM((bq, NUM_LANES), jnp.float32),  # l
-            pltpu.VMEM((bq, d), jnp.float32),  # acc
+            pltpu.VMEM((bq, dv), jnp.float32),  # acc
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
@@ -665,6 +676,7 @@ def _bwd_impl(
 ):
     b, h, s_q, d = q.shape
     _, kv_heads, s_kv, _ = k.shape
+    dv = v.shape[-1]  # v, o, do and dv; d is q, k, dq and dk
     groups = h // kv_heads
     bq, bkv = blocks
     n_q, n_kv = s_q // bq, s_kv // bkv
@@ -700,8 +712,8 @@ def _bwd_impl(
     dq_in_specs = [
         pl.BlockSpec((1, 1, bq, d), q_map),
         pl.BlockSpec((1, 1, bkv, d), kv_map),
-        pl.BlockSpec((1, 1, bkv, d), kv_map),
-        pl.BlockSpec((1, 1, bq, d), q_map),
+        pl.BlockSpec((1, 1, bkv, dv), kv_map),
+        pl.BlockSpec((1, 1, bq, dv), q_map),
         pl.BlockSpec((1, 1, bq, NUM_LANES), q_map),
         pl.BlockSpec((1, 1, bq, NUM_LANES), q_map),
     ]
@@ -756,8 +768,8 @@ def _bwd_impl(
     dkv_in_specs = [
         pl.BlockSpec((1, 1, bq, d), q_map2),
         pl.BlockSpec((1, 1, bkv, d), kv_map2),
-        pl.BlockSpec((1, 1, bkv, d), kv_map2),
-        pl.BlockSpec((1, 1, bq, d), q_map2),
+        pl.BlockSpec((1, 1, bkv, dv), kv_map2),
+        pl.BlockSpec((1, 1, bq, dv), q_map2),
         pl.BlockSpec((1, 1, bq, NUM_LANES), q_map2),
         pl.BlockSpec((1, 1, bq, NUM_LANES), q_map2),
     ]
@@ -795,15 +807,15 @@ def _bwd_impl(
         in_specs=dkv_in_specs,
         out_specs=(
             pl.BlockSpec((1, 1, bkv, d), kv_map2),
-            pl.BlockSpec((1, 1, bkv, d), kv_map2),
+            pl.BlockSpec((1, 1, bkv, dv), kv_map2),
         ),
         out_shape=(
             jax.ShapeDtypeStruct((b, kv_heads, s_kv, d), k.dtype),
-            jax.ShapeDtypeStruct((b, kv_heads, s_kv, d), v.dtype),
+            jax.ShapeDtypeStruct((b, kv_heads, s_kv, dv), v.dtype),
         ),
         scratch_shapes=[
             pltpu.VMEM((bkv, d), jnp.float32),
-            pltpu.VMEM((bkv, d), jnp.float32),
+            pltpu.VMEM((bkv, dv), jnp.float32),
         ],
         interpret=interpret,
         name="flash_bwd_dkv",
@@ -872,7 +884,7 @@ _flash_window.defvjp(_flash_window_fwd, _flash_window_bwd)
 def flash_attention(
     q: jax.Array,  # (B, S, H, D)
     k: jax.Array,  # (B, S, K, D)
-    v: jax.Array,  # (B, S, K, D)
+    v: jax.Array,  # (B, S, K, Dv); Dv may differ from D (192 / 128)
     *,
     causal: bool = True,
     segment_ids: jax.Array | None = None,  # (B, S) int32
@@ -906,10 +918,11 @@ def flash_attention(
     block_kv_bwd = block_kv_bwd or block_kv
     if h % kv_heads:
         raise ValueError(f"q heads {h} not divisible by kv heads {kv_heads}")
-    if not (supports(s_q, s_kv, d, block_q, block_kv)
-            and supports(s_q, s_kv, d, block_q_bwd, block_kv_bwd)):
+    dv = v.shape[-1]
+    if not (supports(s_q, s_kv, d, block_q, block_kv, dv)
+            and supports(s_q, s_kv, d, block_q_bwd, block_kv_bwd, dv)):
         raise ValueError(
-            f"flash_attention cannot tile Sq={s_q} Skv={s_kv} D={d} "
+            f"flash_attention cannot tile Sq={s_q} Skv={s_kv} D={d} Dv={dv} "
             f"(block_q={block_q}, block_kv={block_kv}, "
             f"bwd {block_q_bwd}/{block_kv_bwd})"
         )

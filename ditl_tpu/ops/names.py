@@ -37,7 +37,12 @@ norm, scale and rotation; in prefill its decompression through ``Wkvb``)
 inside ``attn_qkv``, ``mla_kv`` again inside ``attn_out`` for the value half
 of the absorption; ``mla_attn`` inside ``attn_core``: a decode step's
 attention over the latent pool (the kernel ``mla_paged_attention`` and what
-surrounds it). The latent's write into the tick's tail and the tail's flush
+surrounds it). The single latent block's TRAINING form (``models/dsa.py``
+without an indexer, a forward without cache) keeps the three names:
+``mla_q`` the query's one matrix and rotation, ``mla_kv`` the latent's
+projection, norm and rotation AND the keys' and values' decompression
+through ``Wkvb`` with the rotary key's broadcast over the heads, ``mla_attn``
+inside ``attn_core`` the flash kernels at two widths (``KERNELS``). The latent's write into the tick's tail and the tail's flush
 are ``kv_write``. ``moe_zero`` (``MOE_ZERO_SCOPES``), inside ``moe_combine``:
 the zero-compute experts' weighted identity.
 

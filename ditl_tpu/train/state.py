@@ -28,18 +28,24 @@ class TrainState:
     opt_state: Any
 
 
-def lora_mask(params: Any) -> Any:
+BUFFERS = ("router_bias",)  # leaves that are state, not parameters
+
+
+def lora_mask(params: Any, frozen: tuple[str, ...] = ()) -> Any:
     """True for trainable leaves. With LoRA enabled, only adapter params train
-    (base weights frozen) — optimizer state for frozen leaves is zero-sized."""
+    (base weights frozen) — optimizer state for frozen leaves is zero-sized.
+    A buffer (``BUFFERS``: a router's selection bias, which only chooses) never
+    trains: no AdamW update and no weight decay, which a zero gradient alone
+    would not spare it. Nor does a leaf ``frozen`` names (``train.frozen``)."""
     flat = jax.tree_util.tree_flatten_with_path(params)[0]
 
-    def trainable(path) -> bool:
-        return any(getattr(k, "key", None) == "lora" for k in path)
+    def names(path):
+        return [getattr(k, "key", None) for k in path]
 
-    has_lora = any(trainable(path) for path, _ in flat)
-    if not has_lora:
-        return jax.tree.map(lambda _: True, params)
-    return jax.tree_util.tree_map_with_path(lambda path, _: trainable(path), params)
+    has_lora = any("lora" in names(path) for path, _ in flat)
+    return jax.tree_util.tree_map_with_path(
+        lambda path, _: ("lora" in names(path) if has_lora
+                         else not {*BUFFERS, *frozen} & set(names(path))), params)
 
 
 def make_optimizer(cfg: TrainConfig, params: Any) -> optax.GradientTransformation:
@@ -81,7 +87,13 @@ def make_optimizer(cfg: TrainConfig, params: Any) -> optax.GradientTransformatio
             f"unknown optimizer {cfg.optimizer!r} (adamw|adafactor|lion|sgd)"
         )
     tx = optax.chain(optax.clip_by_global_norm(cfg.grad_clip_norm), opt)
-    mask = lora_mask(params)
+    frozen = tuple(n for n in cfg.frozen.split(",") if n)
+    mask = lora_mask(params, frozen)
+    named = {getattr(k, "key", None) for path, _ in
+             jax.tree_util.tree_flatten_with_path(params)[0] for k in path}
+    if set(frozen) - named:
+        raise ValueError(f"train.frozen names no leaf of this model: "
+                         f"{sorted(set(frozen) - named)}")
     if not all(jax.tree.leaves(mask)):
         # Freeze non-LoRA leaves: their updates are hard zeros (optax.masked
         # would pass raw gradients through for unmasked leaves, which is the

@@ -27,6 +27,7 @@ from ditl_tpu.train.state import TrainState, make_optimizer, state_logical_axes
 
 __all__ = [
     "loss_fn",
+    "compute_params",
     "make_train_step",
     "make_multi_step",
     "make_eval_step",
@@ -104,10 +105,40 @@ def loss_fn(
     # E * sum_e f_e P_e per layer, averaged over the layers (1 when balanced)
     metrics["router_aux_loss"] = aux / cfg.num_layers
     if counted:
-        counts = moe_counts[0].astype(jnp.float32)  # (L, E)
+        from ditl_tpu.models.moe import shares_experts, split_counts
+
+        # (expert layers, E), or a share's held experts beside the zero-compute
+        # and absent totals; the load ratio is over the experts held here
+        held, n_zero, n_absent = split_counts(moe_counts[0].astype(jnp.float32), cfg)
         metrics["moe_load_max_over_mean"] = jnp.mean(
-            counts.max(axis=-1) / jnp.maximum(counts.mean(axis=-1), 1e-9))
+            held.max(axis=-1) / jnp.maximum(held.mean(axis=-1), 1e-9))
+        if shares_experts(cfg):  # held pairs over T x k, live tokens
+            metrics["moe_held_assign_share"] = held.sum() / jnp.maximum(
+                held.sum() + n_zero.sum() + n_absent.sum(), 1.0)
+    if not cfg.router_aux_coef:  # no auxiliary term (noaux_tc): the reading stays
+        return ce, metrics
     return ce + cfg.router_aux_coef * metrics["router_aux_loss"], metrics
+
+
+def compute_params(params: Any, cfg: ModelConfig) -> Any:
+    """The float32 master parameters cast to the compute dtype ONCE a step:
+    per-use casts inside the layers re-read the 4-byte masters at every matmul
+    (forward and backward). Gradients flow back through the cast (bfloat16
+    cotangents cast to float32), which is the precision the bfloat16 matmuls
+    produced anyway. Norm scales stay float32: the model contract computes
+    norms in float32 (``llama.rms_norm``) and they never pass through a
+    matmul, so rounding them would be a pure precision loss and would make
+    train numerics diverge from eval's."""
+    cd = jnp.dtype(cfg.dtype)
+    if cd == jnp.float32:
+        return params
+
+    def cast(path, p):
+        if any(getattr(k, "key", None) and "norm" in k.key for k in path):
+            return p
+        return p.astype(cd) if p.dtype == jnp.float32 else p
+
+    return jax.tree_util.tree_map_with_path(cast, params)
 
 
 def moe_metric_names(cfg: ModelConfig, mesh) -> tuple[str, ...]:
@@ -118,7 +149,10 @@ def moe_metric_names(cfg: ModelConfig, mesh) -> tuple[str, ...]:
         return ()
     if mesh is not None and mesh.shape.get("stage", 1) > 1:
         return ("router_aux_loss",)
-    return ("router_aux_loss", "moe_load_max_over_mean")
+    from ditl_tpu.models.moe import shares_experts
+
+    return ("router_aux_loss", "moe_load_max_over_mean",
+            *(("moe_held_assign_share",) if shares_experts(cfg) else ()))
 
 
 def flash_metric_names(cfg: ModelConfig, mesh, rules: dict, batch) -> tuple[str, ...]:
@@ -138,7 +172,7 @@ def flash_metric_names(cfg: ModelConfig, mesh, rules: dict, batch) -> tuple[str,
         return ()
     s = seg.shape[-1]
     if not fa.supports(s, s, cfg.head_dim, cfg.flash_block_q or 512,
-                       cfg.flash_block_kv or 512):
+                       cfg.flash_block_kv or 512, cfg.v_head_dim or None):
         return ()
     return ("flash_blocks_reachable", "flash_blocks_needed")
 
@@ -166,24 +200,8 @@ def _build_step_fn(
     moe_names = moe_metric_names(model_cfg, mesh)
 
     def single_loss(params, batch):
-        # Cast float32 master params to the compute dtype ONCE per step:
-        # per-use casts inside the layers re-read the 4-byte masters at
-        # every matmul (fwd and bwd). Gradients flow back through the cast
-        # (bf16 cotangents cast to f32), which is the precision the bf16
-        # matmuls produced anyway.
-        cd = jnp.dtype(model_cfg.dtype)
-        if cd != jnp.float32:
-            def cast(path, p):
-                # Norm scales stay f32: the model contract computes norms in
-                # float32 (llama.rms_norm) and they never pass through a
-                # matmul, so rounding them would be a pure precision loss —
-                # and would make train numerics diverge from eval's.
-                if any(getattr(k, "key", None) and "norm" in k.key for k in path):
-                    return p
-                return p.astype(cd) if p.dtype == jnp.float32 else p
-
-            params = jax.tree_util.tree_map_with_path(cast, params)
-        return loss_fn(params, batch, model_cfg, mesh=mesh, rules=rules)
+        return loss_fn(compute_params(params, model_cfg), batch, model_cfg,
+                       mesh=mesh, rules=rules)
 
     def train_step(state: TrainState, batch: dict) -> tuple[TrainState, dict]:
         tx = get_tx(state.params)

@@ -283,7 +283,8 @@ def train(config: Config,
     state_shardings = named_sharding_tree(
         mesh, state_logical_axes(model_cfg, config.train), rules
     )
-    rng = jax.random.key(config.train.seed)
+    rng = jax.random.key(
+        config.train.seed if config.train.init_seed < 0 else config.train.init_seed)
     with mesh:
         init_fn = jax.jit(
             lambda r: create_train_state(r, model_cfg, config.train),

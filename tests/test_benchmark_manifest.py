@@ -168,10 +168,10 @@ def test_the_trinity_cell_is_listed_under_what_it_can_report(manifest_mod):
     reader ``deepseek-v3.2-cut1.docs-32k-dsa`` is under that reads no latent
     attention and no indexer, and the K/V decode kernel's two (this
     configuration runs ``paged_attention`` and walks ``decode_steps``' lists).
-    Nine cells on nine configurations (PR 56), one of them on four chips."""
+    Ten cells on ten configurations (PR 58), one of them on four chips."""
     m = manifest_mod.load()
     cell = "trinity-mini-cut1.docs-32k-swa"
-    assert len(m["workloads"]) == len(m["configs"]) == 9
+    assert len(m["workloads"]) == len(m["configs"]) == 10
     assert sum(w["chips"] == 4 for w in m["workloads"]) == 1
     listed = {x["name"] for x in manifest_mod.metrics_for(m, "per_layer", cell)}
     mine = {x["name"] for x in m["per_layer"] if x.get("workloads") == [cell]}

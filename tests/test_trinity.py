@@ -185,9 +185,11 @@ def test_a_window_without_window_layers_is_refused(kw):
 
 
 def test_the_windows_backward_is_refused_by_name_in_the_flash_kernel():
-    """A held share has no backward pass (ROADMAP.md Reach 1), so no trainer
-    reaches it; asked directly, the flash kernel refuses the window's backward
-    by name and the XLA mask differentiates as every mask does."""
+    """A window stack's held share keeps the loop whose trip count is data (the
+    static buffers with a backward pass are the single latent block's:
+    models/dsa.py, PR 58), so no trainer reaches it; asked directly, the flash
+    kernel refuses the window's backward by name and the XLA mask
+    differentiates as every mask does."""
     from ditl_tpu.ops.attention import dot_product_attention
 
     q = jax.random.normal(jax.random.key(0), (1, 256, 4, 64))
