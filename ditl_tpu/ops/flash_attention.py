@@ -18,35 +18,49 @@ Design (TPU-first):
   The log-sum-exp residual is stored lane-replicated the same way.
 - **GQA-native**: H query heads share H//K KV heads; the KV block index map
   divides the head index, so KV tiles are fetched once per group.
-- **Block skipping**: a grid step whose block is fully masked is predicated
-  off with ``pl.when`` (the grid still visits it; compute and both matmuls are
-  skipped). Without segment ids that is causality's test alone. With them the
-  wrappers reduce the ids, once a call and outside the kernels, to a
-  ``(min, max)`` range for every query block and every key block (forward and
-  backward tiles apart) and hand the kernels those as scalar-prefetch operands
-  (SMEM): a block is needed when it is causally reachable AND the two ranges
-  meet (``q_min <= kv_max and kv_min <= q_max``), a few scalar reads a step.
-  On packed rows as the loader makes them (ids 1-based and non-decreasing
-  along a row) two blocks share a document exactly when their ranges meet, so
-  the test is exact; for any other layout it is conservative, and a block it
-  lets through is still masked element by element by ``_block_mask``. Rows of
-  documents a tenth as long as the row keep 71% of the causal 512 x 512
-  blocks at 2,048 tokens and 43% at 4,096.
-- **The inner axis walks the hull**: three more prefetch operands a kernel
-  are the hull ``[first, last]`` of every outer block's needed inner blocks
-  (key blocks for ``flash_fwd`` / ``flash_bwd_dq``; query blocks for
-  ``flash_bwd_dkv``) and the widest hull of the call. The inner grid axis
-  takes that many steps and no more (a dynamic grid bound: 2-3 on the
-  trainer's rows where the axis has 4 or 8 blocks), and the index maps lay
-  every walk so that it ENDS on its last needed block; the steps in front of
-  a narrower hull name its first block, which is then already in VMEM when
-  it is needed, so a skipped step copies nothing: neither K/V nor, in
-  ``flash_bwd_dkv``, q, dO, lse and delta. On the v5e a skipped step's cost
-  was its copies and the copies it left exposed: the predicate alone takes
-  7% off a layer's two forwards and backward at the 2,048-token cell's
-  shapes, copying nothing for skipped steps 25%, ending each walk on a
-  needed block (the next walk's first copy then runs under compute) 35%,
-  and visiting only the widest hull 40-46% (PERF.md section 6, PR 40).
+- **Block skipping**: without segment ids the grid is the rectangle of
+  (query block, key block) pairs, and a step whose block lies above the
+  diagonal is predicated off with ``pl.when`` (the grid still visits it;
+  compute and both matmuls are skipped). With them the wrappers reduce the
+  ids, once a call and outside the kernels, to a ``(min, max)`` range for
+  every query block and every key block (forward and backward tiles apart):
+  a block is needed when it is causally reachable AND the two ranges meet
+  (``q_min <= kv_max and kv_min <= q_max``). On packed rows as the loader
+  makes them (ids 1-based and non-decreasing along a row) two blocks share a
+  document exactly when their ranges meet, so the test is exact; for any
+  other layout it is conservative, and a block it lets through is still
+  masked element by element by ``_block_mask``. Rows of documents a tenth as
+  long as the row keep 71% of the causal 512 x 512 blocks at 2,048 tokens and
+  43% at 4,096; a row of 4,096 / 2,048 / 1,024 / 512 / 256 / 128 / 64 / 64
+  tokens keeps 51 of 136 at 8,192.
+- **The grid walks a work list**: with segment ids the needed blocks of the
+  WHOLE call are laid out as one list (``_work_list``), built once a call
+  outside the kernels and handed over as four scalar-prefetch operands
+  (SMEM): an entry's row, its outer block, its inner block and three flags.
+  The grid is ``(heads, entries)``, the second a dynamic bound, and every
+  step computes: ``flash_fwd`` and ``flash_bwd_dq`` walk the needed (query
+  block, key block) pairs row by row in row-major order, all of a query
+  block's key blocks together and ascending; ``flash_bwd_dkv`` walks the
+  transposed list, a key block's needed query blocks once for every query
+  head of its GQA group. The index maps read a step's blocks from the list;
+  a kernel clears its scratch on an entry flagged the FIRST of its outer
+  block and writes its output on the LAST, so the output's block changes
+  only between runs and is written once. An outer block that needs nothing
+  (separate query and key ids only, below) keeps one entry that computes
+  nothing, so its output is still written. One list over the batch has no
+  padding: rows that need different counts cost what they need. The lists
+  are a few hundred entries (``B x`` the causally reachable blocks at most,
+  ``x`` the group for ``flash_bwd_dkv``: 4 x 136 = 544 at four rows of 8,192
+  tokens, 16 x 10 x 7 = 1,120 at sixteen of 2,048 in groups of 7; 4 arrays
+  of int32: 8.5 and 17.5 KiB of SMEM).
+  History (PERF.md section 6, PR 40 and PR 59): the predicate alone took 7%
+  off a layer's two forwards and backward at the 2,048-token cell's shapes;
+  what a skipped step cost on the v5e was its copies and the copy it left
+  exposed in front of the next walk. PR 40 walked the HULL of every outer
+  block's needed blocks on an inner axis as long as the call's widest hull
+  (-40 to -46%), the steps in front of a narrower hull visited and skipped
+  at ~0.3 us each; one long document in a row of short ones made three steps
+  in five such steps, which is what the list removes.
 - **The same numbers**: a skipped block contributed exactly nothing. In both
   backward kernels ``p = exp(NEG_INF - lse)`` is 0; in the forward whatever a
   fully masked block adds to ``m``, ``l`` and ``acc`` is wiped by ``alpha =
@@ -77,6 +91,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -180,23 +195,23 @@ def _block_ranges(seg: jax.Array, block: int) -> tuple[jax.Array, jax.Array]:
 
 
 def _reachable(n_q: int, n_kv: int, blocks: BlockSizes, causal: bool,
-               window: int | None = None) -> jax.Array:
-    """``(n_q, n_kv)`` bool: the blocks causality leaves (all, if not causal);
-    with a ``window``, of those the blocks that are not wholly behind it (the
-    block's first query is nearer than ``window`` to the block's last key)."""
-    iq = jnp.arange(n_q, dtype=jnp.int32)[:, None]
-    ikv = jnp.arange(n_kv, dtype=jnp.int32)[None, :]
+               window: int | None = None) -> np.ndarray:
+    """``(n_q, n_kv)`` bool, a constant of the shapes: the blocks causality
+    leaves (all, if not causal); with a ``window``, of those the blocks that
+    are not wholly behind it (the block's first query is nearer than
+    ``window`` to the block's last key)."""
+    iq = np.arange(n_q)[:, None]
+    ikv = np.arange(n_kv)[None, :]
     reach = (iq + 1) * blocks.block_q - 1 >= ikv * blocks.block_kv
     if window is not None:
-        reach = jnp.logical_and(
-            reach, iq * blocks.block_q - ((ikv + 1) * blocks.block_kv - 1) < window)
-    return reach if causal else jnp.ones_like(reach)
+        reach &= iq * blocks.block_q - ((ikv + 1) * blocks.block_kv - 1) < window
+    return reach if causal else np.ones_like(reach)
 
 
 def _needed_blocks(q_rng, kv_rng, blocks: BlockSizes, causal: bool,
                    window: int | None = None) -> jax.Array:
-    """``(B, n_q, n_kv)`` bool, the kernels' block predicate laid out whole:
-    causally reachable, and the two blocks' id ranges meet."""
+    """``(B, n_q, n_kv)`` bool, the block predicate laid out whole: causally
+    reachable, and the two blocks' id ranges meet."""
     (q_lo, q_hi), (kv_lo, kv_hi) = q_rng, kv_rng
     meet = jnp.logical_and(q_lo[:, :, None] <= kv_hi[:, None, :],
                            kv_lo[:, None, :] <= q_hi[:, :, None])
@@ -204,13 +219,11 @@ def _needed_blocks(q_rng, kv_rng, blocks: BlockSizes, causal: bool,
     return jnp.logical_and(meet, reach[None])
 
 
-def _hull(needed: jax.Array) -> tuple[jax.Array, jax.Array]:
-    """First and last needed index along the last axis (int32). A row that
-    needs nothing keeps the whole axis: its steps are predicated off anyway."""
-    n = needed.shape[-1]
-    first = jnp.argmax(needed, axis=-1).astype(jnp.int32)
-    last = (n - 1 - jnp.argmax(needed[..., ::-1], axis=-1)).astype(jnp.int32)
-    return first, last
+def _steps_walked(needed: jax.Array) -> jax.Array:
+    """How many entries the work list of ``needed`` ``(B, outer, inner)`` has
+    (int32 scalar): an outer block's needed inner blocks, and one for an
+    outer block that needs none."""
+    return jnp.sum(jnp.maximum(jnp.sum(needed, axis=-1, dtype=jnp.int32), 1))
 
 
 def block_counts(
@@ -220,107 +233,180 @@ def block_counts(
     block_q: int = 512,
     block_kv: int = 512,
     window: int | None = None,
-) -> tuple[jax.Array, jax.Array]:
-    """``(reachable, needed)``: how many blocks of the forward kernel's grid
-    causality leaves for this batch, a head, and how many of those the
-    predicate keeps (int32 scalars). The arithmetic the kernels' operands are
-    made with, for the trainer's counter."""
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """``(reachable, needed, walked)``: how many blocks of the (query block,
+    key block) rectangle causality leaves for this batch, a head; how many
+    of those the predicate keeps; and how many grid steps the forward kernel
+    takes over the batch, a head, which is the length of its work list (int32
+    scalars). The arithmetic the kernels' operands are made with, for the
+    trainer's counters: ``walked == needed`` wherever every query block needs
+    a block, and under one id array for queries and keys each needs its own."""
     s = segment_ids.shape[1]
     blocks = _pick_blocks(s, s, block_q, block_kv)
     needed = _needed_blocks(
         _block_ranges(segment_ids, blocks.block_q),
         _block_ranges(segment_ids, blocks.block_kv), blocks, causal, window)
     reach = _reachable(*needed.shape[1:], blocks, causal)
-    return (segment_ids.shape[0] * jnp.sum(reach, dtype=jnp.int32),
-            jnp.sum(needed, dtype=jnp.int32))
+    return (jnp.int32(segment_ids.shape[0] * int(reach.sum())),
+            jnp.sum(needed, dtype=jnp.int32), _steps_walked(needed))
 
 
-def _skip_operands(q_seg, kv_seg, blocks: BlockSizes, causal: bool,
-                   window: int | None = None):
-    """The kernels' scalar-prefetch operands, seven int32 arrays: the id
-    ranges of the query blocks ``(B, n_q)`` and of the key blocks
-    ``(B, n_kv)``, the hull ``[first, last]`` of each query block's needed
-    key blocks, and the widest hull of the call ``(1,)``, which is how many
-    steps the inner grid axis takes. Returned twice: for the kernels whose
-    inner axis walks key blocks, and with the hulls of each KEY block's
-    needed query blocks ``(B, n_kv)`` for the dk/dv kernel, whose inner axis
-    walks query blocks."""
-    q_rng = _block_ranges(q_seg, blocks.block_q)
-    kv_rng = _block_ranges(kv_seg, blocks.block_kv)
-    needed = _needed_blocks(q_rng, kv_rng, blocks, causal, window)
-
-    def operands(needed):
-        first, last = _hull(needed)
-        return (*q_rng, *kv_rng, first, last,
-                jnp.max(last - first + 1).reshape(1))
-
-    return operands(needed), operands(jnp.swapaxes(needed, 1, 2))
+# What an entry of a work list says of itself (``flags``): it is the first or
+# the last of its outer block's run, and its block is needed.
+FIRST, LAST, NEEDED = 1, 2, 4
 
 
-def _walk(step, ib, outer, n: int, skip):
-    """Where a step of the inner grid axis stands: ``(block, inside, last
-    step)``. Without ``skip`` operands the axis is the ``n`` blocks. With
-    them it is as long as the call's widest hull and ENDS on the outer
-    block's last needed block: the steps in front of a narrower hull are
-    outside it, and name its first block, so nothing is copied for them.
-    Ending a walk on a needed block matters: the pipeline starts the copy of
-    the next walk's first blocks one step ahead, and under a skipped step
-    that copy has no compute to hide behind."""
-    if not skip:
-        return step, True, n - 1
-    first, last, width = skip[4:]
-    at = last[ib, outer] - (width[0] - 1 - step)
-    return jnp.maximum(at, first[ib, outer]), at >= first[ib, outer], width[0] - 1
+def _work_list(needed: jax.Array, size: int, folds: int = 1):
+    """``(B, outer, inner)`` bool -> the work list of a walk over it and how
+    many entries it has. The list is four int32 arrays ``(size,)``, an
+    entry's ``rows``, ``outer`` and ``inner`` index and its ``flags``: the
+    needed blocks in row-major order, so that an outer block's entries are
+    adjacent and ascend. With ``folds`` an outer block's run is walked that
+    many times over, fold ``f`` naming inner index ``f * n_inner + block``. An
+    outer block that needs nothing keeps one entry a fold (inner block 0)
+    without ``NEEDED``, so that its output is written. ``size`` is a static
+    bound on the count; entries past the count are never walked.
+
+    Dense arithmetic over small arrays, no sort, gather or scatter: a
+    cumulative sum over the outer blocks' run lengths places every entry in
+    its run (one comparison an entry and outer block), a cumulative sum
+    along each outer block's inner axis finds the entry's block in it."""
+    b, n_outer, n_inner = needed.shape
+    blocks = needed.reshape(b * n_outer, n_inner)
+    some = blocks.any(axis=1, keepdims=True)
+    listed = jnp.concatenate([blocks[:, :1] | ~some, blocks[:, 1:]], axis=1)
+    nth = jnp.cumsum(listed, axis=1, dtype=jnp.int32)
+    ends = jnp.cumsum(folds * nth[:, -1])  # one past each run's last entry
+    count = ends[-1]
+    t = jnp.arange(size, dtype=jnp.int32)
+    run = jnp.sum(ends[None, :] <= t[:, None], axis=1, dtype=jnp.int32)  # runs in front of t
+    # the entry's run's own numbers: where it starts, whether it needs
+    # anything, its inner blocks' ranks (the last is a fold's length)
+    table = jnp.concatenate([(ends - folds * nth[:, -1])[:, None], some, nth], axis=1)
+    mine = run[:, None] == jnp.arange(b * n_outer, dtype=jnp.int32)[None, :]
+    start, some, nth = jnp.split(
+        jnp.sum(jnp.where(mine[:, :, None], table[None], 0), axis=1), [1, 2], axis=1)
+    length = nth[:, -1:]
+    at = t[:, None] - start  # the entry's place in its run, and in its fold
+    fold = jax.lax.div(at, jnp.maximum(length, 1))
+    block = jnp.sum(nth <= at - fold * length, axis=1, dtype=jnp.int32)
+    flags = FIRST * (at == 0) + LAST * (at == folds * length - 1) + NEEDED * some
+    walked = t < count
+    return tuple(jnp.where(walked, x, 0) for x in (
+        jax.lax.div(run, n_outer), jax.lax.rem(run, n_outer),
+        fold[:, 0] * n_inner + block, flags[:, 0])), count
 
 
-def _unfold(inner, n: int, skip):
-    """``(group, step)`` of the dk/dv kernel's inner axis, which folds the
-    GQA group loop into the walk over query blocks (``n`` steps a group, or
-    the widest hull's with ``skip``)."""
-    if not skip:
-        return inner // n, inner % n
-    width = skip[6][0]
-    return jax.lax.div(inner, width), jax.lax.rem(inner, width)
+def _work_lists(q_seg, kv_seg, blocks: BlockSizes, causal: bool,
+                window: int | None = None, groups: int | None = None):
+    """The kernels' scalar-prefetch operands and grid bounds: the list of
+    the needed (query block, key block) pairs for the kernels that
+    accumulate over key blocks (``flash_fwd``, ``flash_bwd_dq``) and, with
+    ``groups``, the transposed list for ``flash_bwd_dkv`` behind it, whose
+    outer block is a key block and whose inner index is ``group * n_q +
+    iq``: a key block's needed query blocks once for each of the ``groups``
+    query heads that share its kv head, the plain grid's folded axis with
+    the unneeded steps left out."""
+    needed = _needed_blocks(_block_ranges(q_seg, blocks.block_q),
+                            _block_ranges(kv_seg, blocks.block_kv), blocks, causal, window)
+    b, n_q, n_kv = needed.shape
+    reach = _reachable(n_q, n_kv, blocks, causal, window)
+    # no outer block lists more than it can reach, nor fewer than one
+    over_kv, over_q = (b * int(np.maximum(reach.sum(axis), 1).sum()) for axis in (1, 0))
+    lists = (_work_list(needed, over_kv),)
+    if groups is not None:
+        lists += (_work_list(jnp.swapaxes(needed, 1, 2), groups * over_q, groups),)
+    return lists
 
 
-def _block_needed(ib, iq, ikv, inside, *, causal: bool, block_q: int,
-                  block_kv: int, skip, window: int | None = None):
-    """The grid step's predicate. With causal masking, blocks strictly above
-    the diagonal contribute nothing; with ``skip`` (SMEM refs,
-    ``_skip_operands``), neither do blocks whose id ranges do not meet, nor
-    a step outside its hull (``_walk``). Compute and both matmuls are
-    skipped."""
-    needed = (iq + 1) * block_q - 1 >= ikv * block_kv if causal else True
-    if window is not None and causal:  # a block wholly behind the window
-        needed = jnp.logical_and(needed, iq * block_q - ((ikv + 1) * block_kv - 1) < window)
-    if skip is None:
-        return needed
-    q_lo, q_hi, kv_lo, kv_hi = skip[:4]
-    meet = jnp.logical_and(q_lo[ib, iq] <= kv_hi[ib, ikv],
-                           kv_lo[ib, ikv] <= q_hi[ib, iq])
-    meet = jnp.logical_and(inside, meet)
-    return meet if needed is True else jnp.logical_and(needed, meet)
+def _grid_step(work, n_inner: int, *, causal: bool, block_q: int, block_kv: int,
+               fold: int | None = None):
+    """Where a grid step stands: ``(iq, ikv, first, last, needed)``, the
+    blocks it names, whether it opens or closes its outer block's run, and
+    its predicate (compute and both matmuls are skipped without it). Without
+    a ``work`` list the grid is ``(B, H, outer, n_inner)``, every block of
+    the rectangle is visited and, with causal masking, those strictly above
+    the diagonal are not needed; with one it is ``(H, entries)``, the list
+    says, and it holds needed blocks only (but the one entry of an outer
+    block that needs none). ``fold`` as in ``_index_maps``."""
+    if work is None:
+        outer, inner = pl.program_id(2), pl.program_id(3)
+        first, last, needed = inner == 0, inner == n_inner - 1, None
+    else:
+        _, outers, inners, flags = work
+        t = pl.program_id(1)
+        outer, inner, flag = outers[t], inners[t], flags[t]
+        first, last, needed = flag & FIRST != 0, flag & LAST != 0, flag & NEEDED != 0
+    iq, ikv = (outer, inner) if fold is None else (jax.lax.rem(inner, fold), outer)
+    if needed is None:
+        needed = (iq + 1) * block_q - 1 >= ikv * block_kv if causal else True
+    return iq, ikv, first, last, needed
 
 
-def _pallas(kernel, skip, *, grid, in_specs, out_specs, scratch_shapes, **kw):
-    """``pl.pallas_call``; with ``skip`` operands, through a scalar-prefetch
-    grid spec: they reach the index maps as trailing arguments and the kernel
-    as leading refs."""
-    if skip is None:
+def _index_maps(work, groups: int, fold: int | None = None):
+    """The index maps of a kernel's blocks: ``(q, kv, q ids, kv ids)``, for
+    arrays laid out as q ``(B, H, S, .)``, k ``(B, K, S, .)`` and the
+    broadcast ids. The grid's coordinates are ``(ib, head, outer, inner)``
+    or, with a ``work`` list, ``(head, entry)`` and the list's refs behind
+    them. Without ``fold`` the head is a query head, the outer block a query
+    block and the inner a key block; with it (``flash_bwd_dkv``: the number
+    of query blocks) the head is a kv head, the outer block a key block and
+    the inner index ``group * fold + iq``."""
+
+    def entry(*ids):
+        if work is None:
+            return ids
+        head, t, rows, outers, inners, _ = ids
+        return rows[t], head, outers[t], inners[t]
+
+    def q_at(ib, head, outer, inner):
+        if fold is None:
+            return ib, head, outer
+        return ib, head * groups + jax.lax.div(inner, fold), jax.lax.rem(inner, fold)
+
+    def kv_at(ib, head, outer, inner):
+        if fold is None:
+            return ib, jax.lax.div(head, groups), inner
+        return ib, head, outer
+
+    def q_map(*ids):
+        ib, ih, iq = q_at(*entry(*ids))
+        return ib, ih, iq, 0
+
+    def kv_map(*ids):
+        ib, ikh, ikv = kv_at(*entry(*ids))
+        return ib, ikh, ikv, 0
+
+    def q_seg_map(*ids):
+        ib, _, iq = q_at(*entry(*ids))
+        return ib, iq, 0
+
+    def kv_seg_map(*ids):
+        ib, _, ikv = kv_at(*entry(*ids))
+        return ib, 0, ikv
+
+    return q_map, kv_map, q_seg_map, kv_seg_map
+
+
+def _pallas(kernel, work, *, grid, in_specs, out_specs, scratch_shapes, **kw):
+    """``pl.pallas_call``; with a ``work`` list, through a scalar-prefetch
+    grid spec: its arrays reach the index maps as trailing arguments and the
+    kernel as leading refs."""
+    if work is None:
         return pl.pallas_call(
             kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
             scratch_shapes=scratch_shapes, **kw)
 
-    def with_skip(*refs):
-        kernel(*refs[len(skip):], skip=refs[:len(skip)])
+    def with_work(*refs):
+        kernel(*refs[len(work):], work=refs[:len(work)])
 
     call = pl.pallas_call(
-        with_skip,
+        with_work,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=len(skip), grid=grid, in_specs=in_specs,
+            num_scalar_prefetch=len(work), grid=grid, in_specs=in_specs,
             out_specs=out_specs, scratch_shapes=scratch_shapes),
         **kw)
-    return functools.partial(call, *skip)
+    return functools.partial(call, *work)
 
 
 # ---------------------------------------------------------------------------
@@ -345,23 +431,17 @@ def _fwd_kernel(
     block_q: int,
     block_kv: int,
     n_kv: int,
-    skip=None,
+    work=None,
     window: int | None = None,
 ):
-    ib = pl.program_id(0)
-    iq = pl.program_id(2)
-    step = pl.program_id(3)
-    ikv, inside, last_step = _walk(step, ib, iq, n_kv, skip)
+    iq, ikv, first, last, needed = _grid_step(
+        work, n_kv, causal=causal, block_q=block_q, block_kv=block_kv)
 
-    @pl.when(step == 0)
+    @pl.when(first)
     def _init():
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    needed = _block_needed(ib, iq, ikv, inside, causal=causal, block_q=block_q,
-                           block_kv=block_kv, skip=skip,
-                           window=window)
 
     @pl.when(needed)
     def _compute():
@@ -403,7 +483,7 @@ def _fwd_kernel(
         )  # (block_q, D)
         acc_scr[...] = acc_scr[...] * _lane_tile(alpha, acc_scr.shape[-1]) + pv
 
-    @pl.when(step == last_step)
+    @pl.when(last)
     def _finalize():
         l = l_scr[...]
         # Fully-masked rows have l == 0; emit 0 there instead of NaN.
@@ -412,6 +492,28 @@ def _fwd_kernel(
             acc_scr[...] / _lane_tile(l_safe, acc_scr.shape[-1])
         ).astype(o_ref.dtype)
         lse_ref[0, 0] = m_scr[...] + jnp.log(l_safe)
+
+
+def _seg_operands(q_seg, kv_seg, blocks: BlockSizes, q_seg_map, kv_seg_map):
+    """The ids as the kernels' masks read them, ``(specs, arrays)``: the
+    queries' lane-replicated, the keys' sublane-replicated; a pair of Nones
+    without ids."""
+    if q_seg is None:
+        return [None, None], [None, None]
+    (b, s_q), s_kv = q_seg.shape, kv_seg.shape[1]
+    return (
+        [pl.BlockSpec((1, blocks.block_q, NUM_LANES), q_seg_map),
+         pl.BlockSpec((1, NUM_SUBLANES, blocks.block_kv), kv_seg_map)],
+        [jax.lax.broadcast_in_dim(q_seg, (b, s_q, NUM_LANES), (0, 1)),
+         jax.lax.broadcast_in_dim(kv_seg, (b, NUM_SUBLANES, s_kv), (0, 2))],
+    )
+
+
+def _semantics(work):
+    """Every axis parallel but the last, along which a kernel accumulates."""
+    axes = 4 if work is None else 2
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel",) * (axes - 1) + ("arbitrary",))
 
 
 def _fwd(
@@ -434,47 +536,16 @@ def _fwd(
     bq, bkv = blocks
     n_q, n_kv = s_q // bq, s_kv // bkv
     if window is not None and q_seg is None:
-        # the hull of a query block's needed key blocks rides the skip
-        # operands: one document a row gives the window's hull alone
+        # the window's clause rides the work list: one document a row lists
+        # the blocks that meet the window and no others
         q_seg = jnp.ones((b, s_q), jnp.int32)
         kv_seg = jnp.ones((b, s_kv), jnp.int32)
-    skip = (None if q_seg is None
-            else _skip_operands(q_seg, kv_seg, blocks, causal, window)[0])
-    grid = (b, h, n_q, n_kv if skip is None else skip[6][0])
-
-    def q_map(ib, ih, iq, step, *skip):
-        return (ib, ih, iq, 0)
-
-    def kv_map(ib, ih, iq, step, *skip):
-        return (ib, ih // groups, _walk(step, ib, iq, n_kv, skip)[0], 0)
-
-    in_specs = [
-        pl.BlockSpec((1, 1, bq, d), q_map),
-        pl.BlockSpec((1, 1, bkv, d), kv_map),
-        pl.BlockSpec((1, 1, bkv, dv), kv_map),
-    ]
-    args = [q, k, v]
+    work, grid = None, (b, h, n_q, n_kv)
     if q_seg is not None:
-        in_specs.append(
-            pl.BlockSpec((1, bq, NUM_LANES),
-                         lambda ib, ih, iq, ikv, *skip: (ib, iq, 0))
-        )
-        in_specs.append(
-            pl.BlockSpec(
-                (1, NUM_SUBLANES, bkv),
-                lambda ib, ih, iq, step, *skip: (
-                    ib, 0, _walk(step, ib, iq, n_kv, skip)[0]),
-            )
-        )
-        args.append(
-            jax.lax.broadcast_in_dim(q_seg, (b, s_q, NUM_LANES), (0, 1))
-        )
-        args.append(
-            jax.lax.broadcast_in_dim(kv_seg, (b, NUM_SUBLANES, s_kv), (0, 2))
-        )
-    else:
-        in_specs += [None, None]
-        args += [None, None]
+        ((work, count),) = _work_lists(q_seg, kv_seg, blocks, causal, window)
+        grid = (h, count)
+    q_map, kv_map, q_seg_map, kv_seg_map = _index_maps(work, groups)
+    seg_specs, seg_args = _seg_operands(q_seg, kv_seg, blocks, q_seg_map, kv_seg_map)
 
     kernel = functools.partial(
         _fwd_kernel,
@@ -485,32 +556,33 @@ def _fwd(
         n_kv=n_kv,
         window=window,
     )
-    out_shapes = (
-        jax.ShapeDtypeStruct((b, h, s_q, dv), q.dtype),
-        jax.ShapeDtypeStruct((b, h, s_q, NUM_LANES), jnp.float32),
-    )
-    out_specs = (
-        pl.BlockSpec((1, 1, bq, dv), q_map),
-        pl.BlockSpec((1, 1, bq, NUM_LANES), q_map),
-    )
     o, lse = _pallas(
         kernel,
-        skip,
+        work,
         grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shapes,
+        in_specs=[
+            pl.BlockSpec((1, 1, bq, d), q_map),
+            pl.BlockSpec((1, 1, bkv, d), kv_map),
+            pl.BlockSpec((1, 1, bkv, dv), kv_map),
+            *seg_specs,
+        ],
+        out_specs=(
+            pl.BlockSpec((1, 1, bq, dv), q_map),
+            pl.BlockSpec((1, 1, bq, NUM_LANES), q_map),
+        ),
+        out_shape=(
+            jax.ShapeDtypeStruct((b, h, s_q, dv), q.dtype),
+            jax.ShapeDtypeStruct((b, h, s_q, NUM_LANES), jnp.float32),
+        ),
         scratch_shapes=[
             pltpu.VMEM((bq, NUM_LANES), jnp.float32),  # m
             pltpu.VMEM((bq, NUM_LANES), jnp.float32),  # l
             pltpu.VMEM((bq, dv), jnp.float32),  # acc
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
-        ),
+        compiler_params=_semantics(work),
         interpret=interpret,
         name="flash_fwd",
-    )(*args)
+    )(q, k, v, *seg_args)
     return o, lse
 
 
@@ -536,19 +608,14 @@ def _dq_kernel(
     block_q: int,
     block_kv: int,
     n_kv: int,
-    skip=None,
+    work=None,
 ):
-    ib = pl.program_id(0)
-    iq = pl.program_id(2)
-    step = pl.program_id(3)
-    ikv, inside, last_step = _walk(step, ib, iq, n_kv, skip)
+    iq, ikv, first, last, needed = _grid_step(
+        work, n_kv, causal=causal, block_q=block_q, block_kv=block_kv)
 
-    @pl.when(step == 0)
+    @pl.when(first)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
-
-    needed = _block_needed(ib, iq, ikv, inside, causal=causal, block_q=block_q,
-                           block_kv=block_kv, skip=skip)
 
     @pl.when(needed)
     def _compute():
@@ -578,7 +645,7 @@ def _dq_kernel(
             ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
 
-    @pl.when(step == last_step)
+    @pl.when(last)
     def _finalize():
         dq_ref[0, 0] = dq_scr[...].astype(dq_ref.dtype)
 
@@ -603,22 +670,18 @@ def _dkv_kernel(
     block_kv: int,
     n_q: int,
     n_inner: int,
-    skip=None,
+    work=None,
 ):
-    """Grid (B, K, n_kv, groups * n_q): the innermost (sequential) dim folds
+    """Grid (B, K, n_kv, groups * n_q), or (K, entries) of a work list whose
+    inner index is the first grid's: the innermost (sequential) dim folds
     the GQA group loop into the q loop so dk/dv accumulation is race-free."""
-    ib = pl.program_id(0)
-    ikv = pl.program_id(2)
-    inner = pl.program_id(3)
-    iq, inside, _ = _walk(_unfold(inner, n_q, skip)[1], ib, ikv, n_q, skip)
+    iq, ikv, first, last, needed = _grid_step(
+        work, n_inner, causal=causal, block_q=block_q, block_kv=block_kv, fold=n_q)
 
-    @pl.when(inner == 0)
+    @pl.when(first)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
-
-    needed = _block_needed(ib, iq, ikv, inside, causal=causal, block_q=block_q,
-                           block_kv=block_kv, skip=skip)
 
     @pl.when(needed)
     def _compute():
@@ -653,7 +716,7 @@ def _dkv_kernel(
             ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
 
-    @pl.when(inner == (n_inner if skip is None else pl.num_programs(3)) - 1)
+    @pl.when(last)
     def _finalize():
         dk_ref[0, 0] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_scr[...].astype(dv_ref.dtype)
@@ -680,6 +743,7 @@ def _bwd_impl(
     groups = h // kv_heads
     bq, bkv = blocks
     n_q, n_kv = s_q // bq, s_kv // bkv
+    n_inner = groups * n_q  # the dk/dv grid's folded axis
 
     # delta_i = rowsum(do ⊙ o): cheap elementwise+reduce, XLA fuses it.
     delta = jnp.sum(
@@ -689,49 +753,34 @@ def _bwd_impl(
         delta, (b, h, s_q, NUM_LANES), (0, 1, 2)
     )
 
-    seg_args = [None, None]
-    skip = skip_dkv = None
+    work = work_dkv = None
+    grid, grid_dkv = (b, h, n_q, n_kv), (b, kv_heads, n_kv, n_inner)
     if q_seg is not None:
-        q_seg_b = jax.lax.broadcast_in_dim(q_seg, (b, s_q, NUM_LANES), (0, 1))
-        kv_seg_b = jax.lax.broadcast_in_dim(
-            kv_seg, (b, NUM_SUBLANES, s_kv), (0, 2)
-        )
-        seg_args = [q_seg_b, kv_seg_b]
-        skip, skip_dkv = _skip_operands(q_seg, kv_seg, blocks, causal)
+        (work, count), (work_dkv, count_dkv) = _work_lists(
+            q_seg, kv_seg, blocks, causal, groups=groups)
+        grid, grid_dkv = (h, count), (kv_heads, count_dkv)
 
     # dk = scale·dsᵀq_unscaled = dsᵀ(scale·q): pre-scaling q once inside the
     # kernels folds the scale into both s and dk, so no post-multiply needed.
 
-    # ---- dq: grid (B, H, n_q, n_kv), accumulate over kv blocks ----
-    def q_map(ib, ih, iq, step, *skip):
-        return (ib, ih, iq, 0)
+    def operands(work, fold=None):
+        """Both kernels read the same eight arrays; q, dO, lse and delta in
+        query blocks, k and v in key blocks."""
+        q_map, kv_map, q_seg_map, kv_seg_map = _index_maps(work, groups, fold)
+        seg_specs, seg_args = _seg_operands(q_seg, kv_seg, blocks, q_seg_map, kv_seg_map)
+        specs = [
+            pl.BlockSpec((1, 1, bq, d), q_map),
+            pl.BlockSpec((1, 1, bkv, d), kv_map),
+            pl.BlockSpec((1, 1, bkv, dv), kv_map),
+            pl.BlockSpec((1, 1, bq, dv), q_map),
+            pl.BlockSpec((1, 1, bq, NUM_LANES), q_map),
+            pl.BlockSpec((1, 1, bq, NUM_LANES), q_map),
+            *seg_specs,
+        ]
+        return specs, (q, k, v, do, lse, delta, *seg_args), q_map, kv_map
 
-    def kv_map(ib, ih, iq, step, *skip):
-        return (ib, ih // groups, _walk(step, ib, iq, n_kv, skip)[0], 0)
-
-    dq_in_specs = [
-        pl.BlockSpec((1, 1, bq, d), q_map),
-        pl.BlockSpec((1, 1, bkv, d), kv_map),
-        pl.BlockSpec((1, 1, bkv, dv), kv_map),
-        pl.BlockSpec((1, 1, bq, dv), q_map),
-        pl.BlockSpec((1, 1, bq, NUM_LANES), q_map),
-        pl.BlockSpec((1, 1, bq, NUM_LANES), q_map),
-    ]
-    if q_seg is not None:
-        dq_in_specs.append(
-            pl.BlockSpec((1, bq, NUM_LANES),
-                         lambda ib, ih, iq, ikv, *skip: (ib, iq, 0))
-        )
-        dq_in_specs.append(
-            pl.BlockSpec(
-                (1, NUM_SUBLANES, bkv),
-                lambda ib, ih, iq, step, *skip: (
-                    ib, 0, _walk(step, ib, iq, n_kv, skip)[0]),
-            )
-        )
-    else:
-        dq_in_specs += [None, None]
-
+    # ---- dq: accumulate over kv blocks ----
+    specs, args, q_map, _ = operands(work)
     dq = _pallas(
         functools.partial(
             _dq_kernel,
@@ -741,56 +790,19 @@ def _bwd_impl(
             block_kv=bkv,
             n_kv=n_kv,
         ),
-        skip,
-        grid=(b, h, n_q, n_kv if skip is None else skip[6][0]),
-        in_specs=dq_in_specs,
+        work,
+        grid=grid,
+        in_specs=specs,
         out_specs=pl.BlockSpec((1, 1, bq, d), q_map),
         out_shape=jax.ShapeDtypeStruct((b, h, s_q, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
-        ),
+        compiler_params=_semantics(work),
         interpret=interpret,
         name="flash_bwd_dq",
-    )(q, k, v, do, lse, delta, *seg_args)
+    )(*args)
 
-    # ---- dk/dv: grid (B, K, n_kv, groups·n_q), accumulate over (g, q) ----
-    n_inner = groups * n_q
-
-    def q_map2(ib, ikh, ikv, inner, *skip):
-        group, step = _unfold(inner, n_q, skip)
-        return (ib, ikh * groups + group,
-                _walk(step, ib, ikv, n_q, skip)[0], 0)
-
-    def kv_map2(ib, ikh, ikv, inner, *skip):
-        return (ib, ikh, ikv, 0)
-
-    dkv_in_specs = [
-        pl.BlockSpec((1, 1, bq, d), q_map2),
-        pl.BlockSpec((1, 1, bkv, d), kv_map2),
-        pl.BlockSpec((1, 1, bkv, dv), kv_map2),
-        pl.BlockSpec((1, 1, bq, dv), q_map2),
-        pl.BlockSpec((1, 1, bq, NUM_LANES), q_map2),
-        pl.BlockSpec((1, 1, bq, NUM_LANES), q_map2),
-    ]
-    if q_seg is not None:
-        dkv_in_specs.append(
-            pl.BlockSpec(
-                (1, bq, NUM_LANES),
-                lambda ib, ikh, ikv, inner, *skip: (
-                    ib, _walk(_unfold(inner, n_q, skip)[1], ib, ikv, n_q,
-                              skip)[0], 0),
-            )
-        )
-        dkv_in_specs.append(
-            pl.BlockSpec(
-                (1, NUM_SUBLANES, bkv),
-                lambda ib, ikh, ikv, inner, *skip: (ib, 0, ikv),
-            )
-        )
-    else:
-        dkv_in_specs += [None, None]
-
+    # ---- dk/dv: accumulate over (group, q block) ----
+    specs, args, _, kv_map = operands(work_dkv, fold=n_q)
     dk, dv = _pallas(
         functools.partial(
             _dkv_kernel,
@@ -801,13 +813,12 @@ def _bwd_impl(
             n_q=n_q,
             n_inner=n_inner,
         ),
-        skip_dkv,
-        grid=(b, kv_heads, n_kv,
-              n_inner if skip_dkv is None else groups * skip_dkv[6][0]),
-        in_specs=dkv_in_specs,
+        work_dkv,
+        grid=grid_dkv,
+        in_specs=specs,
         out_specs=(
-            pl.BlockSpec((1, 1, bkv, d), kv_map2),
-            pl.BlockSpec((1, 1, bkv, dv), kv_map2),
+            pl.BlockSpec((1, 1, bkv, d), kv_map),
+            pl.BlockSpec((1, 1, bkv, dv), kv_map),
         ),
         out_shape=(
             jax.ShapeDtypeStruct((b, kv_heads, s_kv, d), k.dtype),
@@ -819,7 +830,7 @@ def _bwd_impl(
         ],
         interpret=interpret,
         name="flash_bwd_dkv",
-    )(q, k, v, do, lse, delta, *seg_args)
+    )(*args)
     return dq, dk, dv
 
 

@@ -157,9 +157,12 @@ def moe_metric_names(cfg: ModelConfig, mesh) -> tuple[str, ...]:
 
 def flash_metric_names(cfg: ModelConfig, mesh, rules: dict, batch) -> tuple[str, ...]:
     """What a step adds to its metrics where its attention is the flash
-    kernel over packed rows: the causally reachable blocks of the forward
-    kernel's grid over the step's batch, and those its predicate keeps
-    (``ops/flash_attention.block_counts``; once a step, not once a layer).
+    kernel over packed rows: the causally reachable blocks of the (query
+    block, key block) rectangle over the step's batch, those the kernels'
+    predicate keeps, and the grid steps the forward kernel takes, a head: the
+    length of its work list, equal to the kept blocks since the grid visits
+    nothing else (``ops/flash_attention.block_counts``; once a step, not once
+    a layer).
     Nothing where another path runs: no segment ids, a sharded sequence (ring
     attention's own loop), a length the kernel cannot tile."""
     from ditl_tpu.ops import flash_attention as fa
@@ -174,7 +177,7 @@ def flash_metric_names(cfg: ModelConfig, mesh, rules: dict, batch) -> tuple[str,
     if not fa.supports(s, s, cfg.head_dim, cfg.flash_block_q or 512,
                        cfg.flash_block_kv or 512, cfg.v_head_dim or None):
         return ()
-    return ("flash_blocks_reachable", "flash_blocks_needed")
+    return ("flash_blocks_reachable", "flash_blocks_needed", "flash_steps_walked")
 
 
 def batch_logical_axes(example_batch: dict[str, Any]) -> dict[str, tuple]:

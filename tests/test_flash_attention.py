@@ -155,6 +155,9 @@ LAYOUTS = {
     "trailing-zero-padding": _ids((1, 300), (0, 212)),
     # ids that come back: the range test may only be conservative here
     "non-monotone": _ids((2, 100), (1, 156), (3, 128), (1, 128)),
+    # rows that need different counts: one document (10 of 10 causal blocks
+    # at 128 x 128) beside sixteen (4)
+    "rows-differ": np.stack([np.ones(S, np.int32), np.repeat(np.arange(1, 17, dtype=np.int32), 32)]),
 }
 # (block_q, block_kv, block_q_bwd, block_kv_bwd): the second's backward tiles
 # differ from its forward's
@@ -203,8 +206,10 @@ def test_packed_rows_match_xla_and_the_kernels_that_skip_nothing(
 
 
 @pytest.mark.parametrize("heads", [(2, 2), (4, 2), (8, 2)], ids=lambda h: f"{h[0]}q-{h[1]}kv")
-def test_packed_rows_with_gqa_groups_match_the_kernels_that_skip_nothing(monkeypatch, heads):
-    seg = jnp.asarray(LAYOUTS["three-inside-blocks"])
+@pytest.mark.parametrize("layout", ["three-inside-blocks", "rows-differ"])
+def test_packed_rows_with_gqa_groups_match_the_kernels_that_skip_nothing(
+        monkeypatch, layout, heads):
+    seg = jnp.asarray(LAYOUTS[layout])
     q, k, v = _make_qkv(jax.random.key(12), 2, S, *heads, 64, dtype=jnp.bfloat16)
 
     def flash(q, k, v):
@@ -228,7 +233,7 @@ def _pallas_calls(jaxpr):
             yield from _pallas_calls(sub)
 
 
-@pytest.mark.parametrize("with_segments,operands", [(False, 0), (True, 7)])
+@pytest.mark.parametrize("with_segments,operands", [(False, 0), (True, 4)])
 def test_only_a_call_with_segment_ids_carries_prefetch_operands(with_segments, operands):
     q, k, v = _make_qkv(jax.random.key(13), 1, 256, 2, 1, 64)
     seg = jnp.ones((1, 256), jnp.int32) if with_segments else None
@@ -239,6 +244,33 @@ def test_only_a_call_with_segment_ids_carries_prefetch_operands(with_segments, o
     calls = list(_pallas_calls(jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(q, k, v).jaxpr))
     assert len(calls) == 3  # flash_fwd, flash_bwd_dq, flash_bwd_dkv
     assert [c.params["grid_mapping"].num_index_operands for c in calls] == [operands] * 3
+
+
+def _equations_outside_kernels(jaxpr):
+    n = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name != "pallas_call":
+            n += 1 + sum(_equations_outside_kernels(sub)
+                         for sub in jax.core.jaxprs_in_params(eqn.params))
+    return n
+
+
+def test_building_the_work_lists_traces_to_a_few_hundred_equations():
+    """The lists are dense array arithmetic, traced once a call whatever the
+    batch and the row's length: no loop over rows, blocks or entries is
+    written out (forward and backward together: three lists)."""
+    def count(b, s):
+        q, k, v = (jax.ShapeDtypeStruct((b, s, h, 64), jnp.bfloat16) for h in (4, 2, 2))
+        seg = jax.ShapeDtypeStruct((b, s), jnp.int32)
+
+        def loss(q, k, v, seg):
+            return jnp.sum(flash_attention(q, k, v, segment_ids=seg, block_q=128,
+                                           block_kv=128).astype(jnp.float32))
+
+        return _equations_outside_kernels(
+            jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(q, k, v, seg).jaxpr)
+
+    assert count(1, 256) == count(16, 2048) < 400
 
 
 def _brute_counts(seg, bq, bkv, causal):
@@ -264,10 +296,11 @@ def test_block_counts_against_a_brute_force_count(layout, causal, blocks):
     from ditl_tpu.ops.flash_attention import block_counts
 
     seg = LAYOUTS[layout]
-    reachable, needed = map(int, block_counts(
+    reachable, needed, walked = map(int, block_counts(
         jnp.asarray(seg), causal=causal, block_q=blocks[0], block_kv=blocks[1]))
     want_reachable, want_needed = _brute_counts(seg, *blocks, causal)
     assert reachable == want_reachable
+    assert walked == needed  # every grid step computes: one id array, so no block needs nothing
     if layout == "non-monotone":  # conservative: never fewer than there are
         assert want_needed <= needed <= reachable
     else:  # exact for the loader's rows: ids that never come back
@@ -280,29 +313,92 @@ def test_block_counts_fixed_points():
     one = jnp.ones((3, S), jnp.int32)
     own = jnp.asarray(np.repeat(np.arange(1, 5, dtype=np.int32), 128)[None])
     # one document a row: every causal block is needed, 100%
-    assert tuple(map(int, block_counts(one, block_q=128, block_kv=128))) == (30, 30)
+    assert tuple(map(int, block_counts(one, block_q=128, block_kv=128))) == (30, 30, 30)
     # every block its own document: the diagonal only
-    assert tuple(map(int, block_counts(own, block_q=128, block_kv=128))) == (10, 4)
-    assert tuple(map(int, block_counts(own, causal=False, block_q=128, block_kv=128))) == (16, 4)
+    assert tuple(map(int, block_counts(own, block_q=128, block_kv=128))) == (10, 4, 4)
+    assert tuple(map(int, block_counts(own, causal=False, block_q=128, block_kv=128))) == (16, 4, 4)
     # the defaults are the kernels': 512 x 512, so S is one block
-    assert tuple(map(int, block_counts(own))) == (1, 1)
+    assert tuple(map(int, block_counts(own))) == (1, 1, 1)
 
 
-@pytest.mark.parametrize("layout,width", [
-    ("one-document", 4),  # the last query block reaches every key block
-    ("three-on-block-edges", 2),  # the 256-token document is two blocks
-    ("three-inside-blocks", 3),  # block 2 holds the ends of documents 2 and 3
-    ("many", 1),  # every block its own documents: the diagonal
-    ("trailing-zero-padding", 3),  # the prompt's three blocks; the padding's two
-], ids=lambda v: str(v))
-def test_the_inner_axis_takes_the_widest_hulls_steps(layout, width):
-    """The kernels' last prefetch operand, which is their inner grid bound:
-    the widest run of needed blocks over the call's outer blocks, for the
-    walk over key blocks and for the dk/dv kernel's walk over query blocks."""
-    from ditl_tpu.ops.flash_attention import BlockSizes, _skip_operands
+# The claimed cell's row (benchmarks/traffic/train-ep8-8k.json): eight
+# documents in 8,192 tokens, 51 of the 136 causal 512 x 512 blocks.
+KANANA_ROW = np.repeat(np.arange(1, 9, dtype=np.int32), [4096, 2048, 1024, 512, 256, 128, 64, 64])
+# name -> (ids, (block_q, block_kv), GQA group, entries a row)
+WALKS = {
+    **{name: (LAYOUTS[name], (128, 128), group, a_row) for name, group, a_row in [
+        ("one-document", 1, (10, 10)),  # the causal triangle
+        ("three-on-block-edges", 2, (5, 5)),  # 1 + (1 + 2) + 1: the 256-token document is two blocks
+        ("three-inside-blocks", 7, (8, 8)),  # 1 + 2 + 3 + 2: block 2 holds the ends of documents 2 and 3
+        ("many", 1, (4, 4)),  # every block its own documents: the diagonal
+        ("trailing-zero-padding", 2, (8, 8)),  # the prompt's three blocks (6); the padding's two
+        ("rows-differ", 2, (10, 4)),
+    ]},
+    "kanana-row": (np.stack([KANANA_ROW] * 4), (512, 512), 1, (51,) * 4),
+    "kanana-row-tiles-differ": (np.stack([KANANA_ROW] * 2), (256, 512), 4, None),
+}
 
-    seg = jnp.asarray(LAYOUTS[layout])
-    over_kv, over_q = _skip_operands(seg, seg, BlockSizes(128, 128), True)
-    for first, last, widest in (over_kv[4:], over_q[4:]):
-        assert widest.shape == (1,) and int(widest[0]) == width
-        assert int(jnp.max(last - first + 1)) == width and bool(jnp.all(first <= last))
+
+@pytest.mark.parametrize("name", WALKS)
+def test_the_work_lists_hold_every_needed_block_once_and_nothing_else(name):
+    """The kernels' prefetch operands and grid bounds (``_work_lists``): the
+    list the forward and dq kernels walk, query block by query block over
+    key blocks, and the dk/dv kernel's, key block by key block over (query
+    head of the group, query block)."""
+    from ditl_tpu.ops import flash_attention as fa
+
+    ids, tiles, group, a_row = WALKS[name]
+    seg, blocks = jnp.asarray(ids), fa.BlockSizes(*tiles)
+    needed = np.asarray(fa._needed_blocks(
+        fa._block_ranges(seg, blocks.block_q), fa._block_ranges(seg, blocks.block_kv),
+        blocks, True))
+    b, n_q, n_kv = needed.shape
+    _, want, walked = map(int, fa.block_counts(seg, block_q=tiles[0], block_kv=tiles[1]))
+    assert want == walked == needed.sum()
+    if a_row is not None:
+        assert tuple(needed.sum(axis=(1, 2))) == a_row
+
+    over_kv, over_q = fa._work_lists(seg, seg, blocks, True, groups=group)
+    for (work, count), folds, wanted in (
+            (over_kv, 1, needed), (over_q, group, np.swapaxes(needed, 1, 2))):
+        rows, outer, inner, flags = (np.asarray(x) for x in work)
+        count = int(count)
+        assert count == folds * want <= len(rows) == len(outer) == len(inner) == len(flags)
+        rows, outer, inner, flags = rows[:count], outer[:count], inner[:count], flags[:count]
+        fold, block = np.divmod(inner, wanted.shape[2])  # the group loop folded in
+        entries = list(zip(rows, outer, fold, block))
+        # every entry is a needed block, and every needed block appears once a fold
+        assert wanted[rows, outer, block].all() and (flags & fa.NEEDED).all()
+        assert len(set(entries)) == count and (fold < folds).all()
+        # row-major: rows in turn, a row's outer blocks in turn and each in ONE
+        # run, a run's entries ascending (the order the kernels accumulate in)
+        assert entries == sorted(entries)
+        # every outer block has a run, flagged where it opens and where it closes
+        runs = list(zip(rows, outer))
+        assert set(runs) == {(r, o) for r in range(b) for o in range(wanted.shape[1])}
+        opens = [i == 0 or runs[i] != runs[i - 1] for i in range(count)]
+        closes = [i == count - 1 or runs[i] != runs[i + 1] for i in range(count)]
+        assert ((flags & fa.FIRST) != 0).tolist() == opens
+        assert ((flags & fa.LAST) != 0).tolist() == closes
+
+
+def test_an_outer_block_that_needs_nothing_keeps_one_step_that_computes_nothing():
+    """Separate ids for queries and keys, which the public call cannot send:
+    a query block that shares no document with any key block still writes its
+    output (zeros, as under the hull: the module docstring's one exception)."""
+    from ditl_tpu.ops import flash_attention as fa
+
+    blocks = fa.BlockSizes(128, 128)
+    q_seg = jnp.asarray(np.repeat([[1, 1, 9, 2]], 128, axis=1).astype(np.int32))
+    kv_seg = jnp.asarray(np.repeat([[1, 1, 2, 2]], 128, axis=1).astype(np.int32))
+    (work, count), (_, count_t) = fa._work_lists(q_seg, kv_seg, blocks, True, groups=1)
+    rows, outer, inner, flags = (np.asarray(x)[:int(count)] for x in work)
+    # query block 2 needs nothing: one entry, first and last of its run, not needed
+    assert outer.tolist() == [0, 1, 1, 2, 3, 3] and inner.tolist() == [0, 0, 1, 0, 2, 3]
+    assert flags.tolist() == [7, 5, 6, 3, 5, 6]
+    assert int(count_t) == 5  # every key block has a query block: the five needed
+    q, k, v = _make_qkv(jax.random.key(14), 1, S, 2, 2, 64)
+    o, lse = fa._fwd(*(jnp.swapaxes(x, 1, 2) for x in (q, k, v)), q_seg, kv_seg,
+                     causal=True, scale=0.125, blocks=blocks, interpret=True)
+    assert not np.asarray(o[:, :, 256:384]).any() and np.asarray(o[:, :, 384:]).any()
+    assert (np.asarray(lse[:, :, 256:384]) < -1e30).all()

@@ -168,15 +168,20 @@ def test_paged_flush_copies_no_pool(one_chip, tpu_branch, layers, pages, kv, tai
 
 @pytest.mark.parametrize("kernels", [("flash_fwd",), ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")],
                          ids=["forward", "backward"])
-@pytest.mark.parametrize("b,h,kv,s,d", [(16, 14, 2, 2048, 64), (2, 28, 4, 4096, 128)],
-                         ids=["train-2k", "train-fsdp4-4k-a-chip"])
-def test_flash_kernels_compile_with_their_prefetch_operands(one_chip, kernels, b, h, kv, s, d):
+@pytest.mark.parametrize("b,h,kv,s,d,dv", [
+    (16, 14, 2, 2048, 64, 64), (2, 28, 4, 4096, 128, 128), (4, 32, 32, 8192, 192, 128)],
+    ids=["train-2k", "train-fsdp4-4k-a-chip", "train-ep8-8k"])
+def test_flash_kernels_compile_with_their_prefetch_operands(one_chip, kernels, b, h, kv, s, d, dv):
     """The three flash kernels as the trainer cells run them, on packed rows:
-    each takes the blocks' id ranges and the hull of its needed blocks as
-    scalar-prefetch operands and walks an inner grid axis whose bound is the
-    widest hull, a value of the call (``ops/flash_attention.py``): what
-    Mosaic makes of that, interpret mode cannot say. ``qwen2-0.5b.train-2k``'s batch of 16 rows, 14/2
-    heads of 64; ``qwen2-7b-cut4.train-fsdp4-4k``'s 2 rows a chip, 28/4 of 128."""
+    each takes the work list of its needed blocks as four scalar-prefetch
+    operands (an entry's row, outer block, inner block and flags) and walks a
+    grid ``(heads, entries)`` whose second bound is the list's count, a value
+    of the call (``ops/flash_attention.py``): what Mosaic makes of that,
+    interpret mode cannot say. ``qwen2-0.5b.train-2k``'s batch of 16 rows,
+    14/2 heads of 64 (the dk/dv list folds a group of 7: 1,120 entries at
+    most); ``qwen2-7b-cut4.train-fsdp4-4k``'s 2 rows a chip, 28/4 of 128;
+    ``kanana-2-30b-a3b-cut1.train-ep8-8k``'s 4 rows of 8,192, 32/32 heads at
+    192 / 128 (544 entries at most)."""
     from ditl_tpu.ops.flash_attention import flash_attention
 
     sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
@@ -190,7 +195,7 @@ def test_flash_kernels_compile_with_their_prefetch_operands(one_chip, kernels, b
     fn = attend if len(kernels) == 1 else jax.grad(loss, argnums=(0, 1, 2))
     compiled = jax.jit(fn).lower(
         sd((b, s, h, d), jnp.bfloat16), sd((b, s, kv, d), jnp.bfloat16),
-        sd((b, s, kv, d), jnp.bfloat16), sd((b, s), jnp.int32)).compile()
+        sd((b, s, kv, dv), jnp.bfloat16), sd((b, s), jnp.int32)).compile()
     # outside the trainer's scopes an instruction is named for its transform
     # too (``jvp_flash_fwd_``, ``transpose_jvp_flash_bwd_dq__``)
     calls = _instructions(compiled.as_text())

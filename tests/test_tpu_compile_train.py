@@ -1,7 +1,8 @@
 """Compiled for a described v5e, with no chip attached (tests/tpu_compile.py):
 the first trainer cell that runs experts, ``kanana-2-30b-a3b-cut1.
 train-ep8-8k``. Its whole optimizer step at the published widths: the three
-flash kernels at 192 / 128 (Mosaic takes a 192-lane operand), ``gmm`` in the
+flash kernels at 192 / 128 (Mosaic takes a 192-lane operand) on the grid of
+their work lists, a bound the call computes, ``gmm`` in the
 forward and ``gmm`` and ``tgmm`` (the grouped matmul's transposed product, held
 by a test in a training step for the first time) in the backward pass of a held
 share, the fused loss, AdamW on float32 masters; and the step's bytes.
@@ -75,7 +76,9 @@ def test_the_kanana_cells_step_compiles_with_gmm_tgmm_and_flash_at_two_widths(
     # masters and two moments (none for the 4 x 128 router biases, buffers, nor
     # for the four frozen routers of 2,048 x 128), three counters
     assert state_bytes == 575_955_968 * 12 - 2 * 4 * (128 + 2048 * 128) * 4 + 12
-    # The compiler's own peak: 14.10 GiB at this tree (15,144,678,912), under
+    # The compiler's own peak: 14.10 GiB at this tree (15,144,680,448; the flash
+    # kernels' work lists are SMEM operands of 8.5 KiB a call and cost 1,536
+    # bytes on the 15,144,678,912 of the tree before them), under
     # the tenth-spare line (0.9 x 15.75 = 14.17 GiB) the issue sets for ONE
     # micro-batch of 32,768 tokens; a buffer of held pairs keeps its inputs
     # alone for the backward pass (models/moe.py), without which the eight

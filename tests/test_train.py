@@ -328,17 +328,19 @@ def test_a_packed_flash_step_counts_its_blocks_into_the_metrics_rows(tiny_model_
     # holds nothing of
     assert int(metrics["flash_blocks_reachable"]) == 24
     assert int(metrics["flash_blocks_needed"]) == 20
+    assert int(metrics["flash_steps_walked"]) == 20  # every grid step computes
     path = tmp_path / "rows.jsonl"
     logger = MetricsLogger(log_every=1, n_chips=1, metrics_file=str(path))
     logger.start_step()
     logger.end_step(0, metrics)
     logger.close()
     (row,) = [json.loads(ln) for ln in path.read_text().splitlines()]
-    assert (row["flash_blocks_reachable"], row["flash_blocks_needed"]) == (24.0, 20.0)
+    assert (row["flash_blocks_reachable"], row["flash_blocks_needed"],
+            row["flash_steps_walked"]) == (24.0, 20.0, 20.0)
 
 
 @pytest.mark.parametrize("change,names", [
-    ({}, ("flash_blocks_reachable", "flash_blocks_needed")),
+    ({}, ("flash_blocks_reachable", "flash_blocks_needed", "flash_steps_walked")),
     ({"attention_impl": "xla"}, ()),
     ({"head_dim": 16}, ()),  # a head the kernel cannot tile: XLA runs
     ({"no_segment_ids": True}, ()),

@@ -61,20 +61,22 @@ def test_flash_forward_with_a_window_is_the_xla_mask(packed):
 
 def test_flash_visits_the_blocks_that_meet_the_window_and_no_more():
     """8,192 tokens in blocks of 512 with a window of 2,048: a query block's
-    walk is at most 2,048 / 512 + 1 = 5 key blocks where causality alone
-    leaves up to 16; the hull that the kernel's inner axis walks is as wide."""
+    run is at most 2,048 / 512 + 1 = 5 key blocks where causality alone
+    leaves up to 16; the work list the forward kernel walks holds those."""
     seg = jnp.ones((1, 8192), jnp.int32)
-    reach, needed = fa.block_counts(seg, block_q=512, block_kv=512, window=2048)
+    reach, needed, walked = fa.block_counts(seg, block_q=512, block_kv=512, window=2048)
     assert int(reach) == 16 * 17 // 2
-    assert int(needed) == sum(min(i + 1, 5) for i in range(16)) == 70
+    assert int(needed) == int(walked) == sum(min(i + 1, 5) for i in range(16)) == 70
     assert int(fa.block_counts(seg, block_q=512, block_kv=512)[1]) == 136
     blocks = fa.BlockSizes(512, 512)
-    skip, _ = fa._skip_operands(seg, seg, blocks, True, 2048)
-    first, last, width = (np.asarray(x) for x in skip[4:])
-    assert int(width[0]) == 5
-    assert (last[0] == np.arange(16)).all() and (first[0] == np.maximum(np.arange(16) - 4, 0)).all()
+    (_, outer, inner, _), count = fa._work_lists(seg, seg, blocks, True, window=2048)[0]
+    assert int(count) == 70 == len(outer)  # the list's static length knows the window too
+    outer, inner = np.asarray(outer), np.asarray(inner)
+    assert [inner[outer == i].tolist() for i in range(16)] == [
+        list(range(max(i - 4, 0), i + 1)) for i in range(16)]
     # a window that is a whole number of blocks less one token: 4 blocks
-    assert int(fa._skip_operands(seg, seg, blocks, True, 1537)[0][6][0]) == 4
+    assert int(fa._work_lists(seg, seg, blocks, True, window=1537)[0][1]) == sum(
+        min(i + 1, 4) for i in range(16))
 
 
 def _pool(seed, n_pages=40, kv=2, d=128):
