@@ -12,27 +12,21 @@ import dataclasses
 import json
 import math
 import os
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH = os.path.join(ROOT, "benchmarks")
-for _p in (ROOT, BENCH):
-    if _p not in sys.path:
-        sys.path.insert(0, _p)
+from ditl_tpu.config import ModelConfig
+from ditl_tpu.models import dsa, llama
+from ditl_tpu.models import moe as moe_mod
+from ditl_tpu.models.presets import get_preset
+from tests import family
+from tests.family import rel
 
-from harness import load_module  # noqa: E402
-
-from ditl_tpu.config import ModelConfig  # noqa: E402
-from ditl_tpu.models import dsa, llama  # noqa: E402
-from ditl_tpu.models import moe as moe_mod  # noqa: E402
-from ditl_tpu.models.presets import get_preset  # noqa: E402
-
-ref = load_module(os.path.join(BENCH, "reference", "deepseek_v32.py"))
+ref = family.reference("deepseek_v32")
+PRESET = "deepseek-v3.2"
 
 # Both sides compute in float32 on the same weights; they differ in the order
 # of their sums (absorbed against decompressed attention, a gather of the
@@ -49,19 +43,7 @@ TINY = dict(num_layers=3, first_k_dense_replace=1, vocab_size=512, hidden_size=6
             index_topk=16, num_experts=32, num_experts_per_tok=4, n_group=4, topk_group=2,
             experts_held_first=0, experts_held_count=8, max_seq_len=512,
             rope_yarn_original_max_len=64, dtype="float32", param_dtype="float32")
-
-
-def tiny(**kw):
-    return dataclasses.replace(get_preset("deepseek-v3.2"), **{**TINY, **kw})
-
-
-def rel(got, want):
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    return float(np.sqrt(np.mean((got - want) ** 2)) / np.sqrt(np.mean(want ** 2)))
-
-
-def seeded(cfg, seed=0):
-    return ref.perturb(llama.init_params(jax.random.key(seed), cfg), cfg, seed)
+CFG = family.tiny(PRESET, TINY)
 
 
 def sample(cfg, shape=(2, 96), seed=1):
@@ -70,8 +52,8 @@ def sample(cfg, shape=(2, 96), seed=1):
 
 @pytest.mark.parametrize("packed", [False, True], ids=["plain", "packed"])
 def test_forward_matches_the_reference_where_the_selection_does_real_work(packed):
-    cfg = tiny()
-    params, ids = seeded(cfg), sample(cfg)
+    cfg = CFG
+    params, ids = family.seeded(ref, cfg), sample(cfg)
     kw = {}
     if packed:  # two documents a row: a query selects inside its own
         seg = jnp.concatenate([jnp.ones((2, 60), jnp.int32), 2 * jnp.ones((2, 36), jnp.int32)], 1)
@@ -85,8 +67,8 @@ def test_forward_matches_the_reference_where_the_selection_does_real_work(packed
 
 
 def test_the_programs_selected_sets_are_the_references():
-    cfg = tiny()
-    params, ids = seeded(cfg), sample(cfg)
+    cfg = CFG
+    params, ids = family.seeded(ref, cfg), sample(cfg)
     import dsa_check
 
     dsa.TAP = tap = dsa_check.Tapped(cfg.num_layers, 96, rows=2, by_row=True)
@@ -110,8 +92,9 @@ def test_the_programs_selected_sets_are_the_references():
 def test_a_context_of_at_most_index_topk_is_dense_latent_attention():
     """Everything is selected: the reference is bit for bit its own dense
     pass, the program (which then skips the indexer) within tolerance."""
-    cfg, dense = tiny(index_topk=96), tiny(index_topk=4096)
-    params, ids = seeded(cfg), sample(cfg)
+    cfg = family.tiny(PRESET, TINY, index_topk=96)
+    dense = family.tiny(PRESET, TINY, index_topk=4096)
+    params, ids = family.seeded(ref, cfg), sample(cfg)
     a = ref.forward(params, ids, ref.sizes(cfg, {}))
     b = ref.forward(params, ids, ref.sizes(dense, {}))
     assert np.array_equal(np.asarray(a["logits"]), np.asarray(b["logits"]))
@@ -119,7 +102,7 @@ def test_a_context_of_at_most_index_topk_is_dense_latent_attention():
     got = llama.forward(params, ids, cfg)
     assert rel(got, a["logits"]) < TOL
     # and a selection that bites changes the answer: the mechanism is not idle
-    sparse = llama.forward(params, ids, tiny())
+    sparse = llama.forward(params, ids, CFG)
     assert rel(sparse, a["logits"]) > 0.05
 
 
@@ -132,8 +115,8 @@ def _moe_of(cfg, full, first, count):
 
 
 def test_the_shares_routed_parts_and_the_shared_expert_once_add_up_to_the_uncut_layer():
-    whole = tiny(experts_held_first=0, experts_held_count=32)
-    params = seeded(whole)
+    whole = family.tiny(PRESET, TINY, experts_held_first=0, experts_held_count=32)
+    params = family.seeded(ref, whole)
     full = jax.tree.map(lambda w: w[0], params["layers"]["sparse"]["moe"])  # one layer's
     u = jax.random.normal(jax.random.key(5), (2, 24, whole.hidden_size), jnp.float32)
     want, _, counts = moe_mod.moe_block(full, u, whole)
@@ -141,17 +124,17 @@ def test_the_shares_routed_parts_and_the_shared_expert_once_add_up_to_the_uncut_
     shared_only = {k: v for k, v in full.items() if k != "shared"}
     total = jnp.zeros_like(want)
     for first in range(0, 32, 2):  # 16 shares of 2 experts, the shared expert left out
-        cfg = tiny(experts_held_first=first, experts_held_count=2)
+        cfg = family.tiny(PRESET, TINY, experts_held_first=first, experts_held_count=2)
         part, _, c = moe_mod.moe_block(_moe_of(cfg, shared_only, first, 2), u, cfg)
         total = total + part
         assert int(c.sum()) == 2 * 24 * 4  # held + absent: every choice is counted
-    one = tiny(experts_held_first=0, experts_held_count=2)
+    one = family.tiny(PRESET, TINY, experts_held_first=0, experts_held_count=2)
     with_shared, _, _ = moe_mod.moe_block(_moe_of(one, full, 0, 2), u, one)
     without, _, _ = moe_mod.moe_block(_moe_of(one, shared_only, 0, 2), u, one)
     total = total + (with_shared - without)  # the shared expert, once
     assert rel(total, want) < TOL
     # and the reference's block agrees with the program's on a share
-    cfg = tiny(experts_held_first=8, experts_held_count=8)
+    cfg = family.tiny(PRESET, TINY, experts_held_first=8, experts_held_count=8)
     stacked = jax.tree.map(lambda w: w[None], _moe_of(cfg, full, 8, 8))
     theirs, _ = ref._experts(stacked, 0, u, ref.sizes(cfg, {}))
     mine, _, _ = moe_mod.moe_block(_moe_of(cfg, full, 8, 8), u, cfg)
@@ -170,8 +153,9 @@ def test_group_limited_choice_on_a_hand_made_case():
     assert np.asarray(ref.choose(scores, sizes))[0].tolist() == [1, 0, 1, 0, 0, 0, 0, 0]
     assert sorted(np.asarray(jax.lax.top_k(limited, 2)[1])[0].tolist()) == [0, 2]
     # the bias enters the choice and not the weights
-    cfg = tiny(num_experts=8, n_group=4, topk_group=2, num_experts_per_tok=2,
-               experts_held_first=0, experts_held_count=8, n_shared_experts=0)
+    cfg = family.tiny(PRESET, TINY, num_experts=8, n_group=4, topk_group=2,
+                      num_experts_per_tok=2, experts_held_first=0, experts_held_count=8,
+                      n_shared_experts=0)
     d = cfg.hidden_size
     moe = moe_mod.init_moe_params(jax.random.key(0), cfg, n_layers=1)
     moe = jax.tree.map(lambda w: w[0], moe)
@@ -203,7 +187,8 @@ def test_yarn_frequencies_against_the_closed_form():
         "rope_scaling"])), rtol=1e-6)
     m = 0.1 * math.log(40.0) + 1.0
     assert dsa.softmax_scale(cfg) == pytest.approx(192 ** -0.5 * m * m)
-    assert dsa.softmax_scale(tiny(rope_yarn_factor=0.0)) == pytest.approx(24 ** -0.5)
+    plain = family.tiny(PRESET, TINY, rope_yarn_factor=0.0)
+    assert dsa.softmax_scale(plain) == pytest.approx(24 ** -0.5)
 
 
 @pytest.mark.parametrize("kw, said", [
@@ -218,7 +203,7 @@ def test_yarn_frequencies_against_the_closed_form():
 ])
 def test_a_setting_nothing_would_read_is_refused(kw, said):
     with pytest.raises(ValueError, match=said):
-        tiny(**kw)
+        family.tiny(PRESET, TINY, **kw)
 
 
 def test_an_indexer_without_its_block_is_refused():
@@ -229,13 +214,14 @@ def test_an_indexer_without_its_block_is_refused():
 
 
 def test_the_preset_and_its_cut_count_their_parameters_and_hold_the_configuration_file():
-    with open(os.path.join(BENCH, "configs", "deepseek-v3.2-cut1.json")) as f:
+    with open(os.path.join(family.BENCH, "configs", "deepseek-v3.2-cut1.json")) as f:
         config = json.load(f)
     import reference_check
     from harness import model_override_args
 
     cfg = reference_check.model_config(config, model_override_args(config, "serve"))
     assert ref.check_sizes(cfg, config) == []
+    # shapes alone: this size is never drawn
     shapes = jax.eval_shape(lambda: llama.init_params(jax.random.key(0), cfg))
     n = sum(math.prod(a.shape) for a in jax.tree.leaves(shapes))
     assert n == config["cut"]["parameters"] == 4_635_518_208
@@ -259,7 +245,7 @@ def test_the_preset_and_its_cut_count_their_parameters_and_hold_the_configuratio
 
 
 def test_the_references_flops_count_the_selected_entries_not_the_context():
-    with open(os.path.join(BENCH, "configs", "deepseek-v3.2-cut1.json")) as f:
+    with open(os.path.join(family.BENCH, "configs", "deepseek-v3.2-cut1.json")) as f:
         config = json.load(f)
     short, long = (ref.forward_flops_per_token(config, c) for c in (2048.0, 33000.0))
     # past index_topk only the indexer's scores grow: 5 layers x 64 x 128 x 2 a key
@@ -267,8 +253,8 @@ def test_the_references_flops_count_the_selected_entries_not_the_context():
 
 
 def test_loss_is_the_mean_next_token_cross_entropy():
-    cfg = tiny()
-    params, ids = seeded(cfg), sample(cfg, (2, 20))
+    cfg = CFG
+    params, ids = family.seeded(ref, cfg), sample(cfg, (2, 20))
     out = ref.forward(params, ids, ref.sizes(cfg, {}))
     mask = jnp.ones(ids.shape, jnp.float32)
     logp = jax.nn.log_softmax(out["logits"][:, :-1], -1)

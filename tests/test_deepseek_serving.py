@@ -9,28 +9,17 @@ its own so that the test runner can give it a worker of its own
 
 from __future__ import annotations
 
-import dataclasses
-import os
-import sys
-
 import jax
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH = os.path.join(ROOT, "benchmarks")
-for _p in (ROOT, BENCH):
-    if _p not in sys.path:
-        sys.path.insert(0, _p)
+from ditl_tpu.data.tokenizer import ByteTokenizer
+from ditl_tpu.models import llama
+from tests import family
+from tests.family import ask, prompt_of
 
-from harness import load_module  # noqa: E402
-
-from ditl_tpu.data.tokenizer import ByteTokenizer  # noqa: E402
-from ditl_tpu.infer.continuous import ContinuousEngine  # noqa: E402
-from ditl_tpu.models import llama  # noqa: E402
-from ditl_tpu.models.presets import get_preset  # noqa: E402
-
-ref = load_module(os.path.join(BENCH, "reference", "deepseek_v32.py"))
+ref = family.reference("deepseek_v32")
+PRESET = "deepseek-v3.2"
 
 # float32 on both sides, sums in another order (tests/test_deepseek.py): 1e-6
 # is what that leaves, 1e-4 a hundred times of room and a hundred times under
@@ -48,17 +37,7 @@ OVERRIDES = [f"{k}={v}" for k, v in TINY.items()]
 CONFIG = {"preset": "deepseek-v3.2", "reference": "deepseek_v32"}
 
 
-def tiny(**kw):
-    return dataclasses.replace(get_preset("deepseek-v3.2"), **{**TINY, **kw})
-
-
-def seeded(cfg, seed=0):
-    return ref.perturb(llama.init_params(jax.random.key(seed), cfg), cfg, seed)
-
-
-def engine(cfg, **kw):
-    kw = {"n_slots": 2, "cache_mode": "paged", "page_size": 16, "max_cache_len": 128, **kw}
-    return ContinuousEngine(seeded(cfg), cfg, ByteTokenizer(), **kw)
+CFG = family.tiny(PRESET, TINY)
 
 
 def test_paged_prefill_then_decode_through_both_pools_matches_the_reference():
@@ -101,26 +80,21 @@ def test_the_tap_is_off_in_serving_and_traces_nothing():
     from ditl_tpu.models import dsa
 
     assert dsa.TAP is None
-    cfg = tiny()
     ids = np.zeros((1, 40), np.int32)
-    text = jax.jit(lambda p: llama.forward(p, ids, cfg)).lower(seeded(cfg)).as_text()
+    text = jax.jit(lambda p: llama.forward(p, ids, CFG)).lower(
+        family.seeded(ref, CFG)).as_text()
     assert "callback" not in text
 
 
-def test_chunked_and_whole_prefill_and_a_prefix_hit_give_one_answer():
-    cfg = tiny()
-    tok = ByteTokenizer()
-    rng = np.random.default_rng(0)
-    prompt = [tok.bos_id] + [int(t) for t in rng.integers(3, cfg.vocab_size, 70)]
+def test_chunked_and_whole_prefill_and_a_prefix_hit_give_one_answer(engines):
+    prompt = prompt_of(np.random.default_rng(0), 71)
     outs = []
     for chunk in (0, 32):
-        eng = engine(cfg, prefill_chunk=chunk)
-        answers = []
-        for _ in range(2):  # the second finds the first one's published pages
-            rid = eng.submit(prompt, max_new_tokens=6, temperature=0.0)
-            answers.append(eng.run()[rid])
-        outs.append(answers)
-        assert eng.stats()["prefix_cache"]["hit_tokens"] >= 64
+        eng = engines(family.model(ref, CFG), prefill_chunk=chunk)
+        hit_was = eng.stats()["prefix_cache"]["hit_tokens"]
+        # the second finds the first one's published pages
+        outs.append([ask(eng, prompt, 6) for _ in range(2)])
+        assert eng.stats()["prefix_cache"]["hit_tokens"] - hit_was >= 64
     assert outs[0][0] == outs[0][1] == outs[1][0] == outs[1][1]
 
 
@@ -128,16 +102,15 @@ def test_the_index_pool_lives_under_the_latent_pools_page_table():
     """One page id names a latent page and its index-key page: what is
     published is found again with both, what is evicted loses both, and the
     page manager's bytes count both."""
-    cfg = tiny()
-    eng = engine(cfg, n_pages=10)  # 9 usable pages of 16 tokens
+    cfg = CFG
+    # an engine of its own: the pool's size (9 usable pages of 16 tokens) is what is under test
+    eng = family.engine(family.model(ref, cfg), n_pages=10)
     assert set(eng.cache) == {"cp", "ip"}
     assert eng.cache["cp"].shape == (3, 10, 16, 128) and eng.cache["ip"].shape == (3, 10, 16, 16)
     assert eng.page_bytes == 3 * 16 * (128 + 16) * 4  # float32 here
     assert eng.index_pool_bytes == 3 * 10 * 16 * 16 * 4
-    tok = ByteTokenizer()
     rng = np.random.default_rng(1)
-    docs = [[tok.bos_id] + [int(t) for t in rng.integers(3, cfg.vocab_size, 48)]
-            for _ in range(3)]
+    docs = [prompt_of(rng, 49) for _ in range(3)]
     first = {}
     for i, doc in enumerate(docs[:2]):
         rid = eng.submit(doc, max_new_tokens=4, temperature=0.0)
@@ -161,21 +134,16 @@ def test_the_index_pool_lives_under_the_latent_pools_page_table():
     assert eng.run()[rid] == first[0]
 
 
-def test_a_preempted_row_comes_back_with_both_its_pages():
+def test_a_preempted_row_comes_back_with_both_its_pages(engines):
     """Two long answers in a pool too small for both: one row is preempted,
     its pages given up, and recomputed later; both answers equal what each
     gets alone."""
-    cfg = tiny()
-    tok = ByteTokenizer()
     rng = np.random.default_rng(2)
-    prompts = [[tok.bos_id] + [int(t) for t in rng.integers(3, cfg.vocab_size, 30)]
-               for _ in range(2)]
-    alone = []
-    for p in prompts:
-        eng = engine(cfg)
-        rid = eng.submit(p, max_new_tokens=40, temperature=0.0)
-        alone.append(eng.run()[rid])
-    eng = engine(cfg, n_pages=8, admission="optimistic")
+    prompts = [prompt_of(rng, 31) for _ in range(2)]
+    roomy = engines(family.model(ref, CFG), prefill_chunk=0)
+    alone = [ask(roomy, p, 40) for p in prompts]
+    # an engine of its own: the pool's size is what is under test
+    eng = family.engine(family.model(ref, CFG), n_pages=8, admission="optimistic")
     ids = [eng.submit(p, max_new_tokens=40, temperature=0.0) for p in prompts]
     out = eng.run()
     assert eng.stats()["preemptions"] >= 1
@@ -183,8 +151,9 @@ def test_a_preempted_row_comes_back_with_both_its_pages():
 
 
 def test_the_engine_counts_the_context_it_scored_and_the_entries_it_selected():
-    cfg = tiny()
-    eng = engine(cfg, n_slots=4, max_cache_len=64, decode_chunk=8)
+    cfg = CFG
+    # an engine of its own: its counters are read whole, and nothing else asks for these options
+    eng = family.engine(family.model(ref, cfg), n_slots=4, max_cache_len=64, decode_chunk=8)
     tok = ByteTokenizer()
     prompt = [tok.bos_id] + list(range(7, 27))  # 21 tokens: over index_topk at once
     rid = eng.submit(prompt, max_new_tokens=12, temperature=0.0)
@@ -209,9 +178,10 @@ def test_a_traced_engines_tick_span_counts_the_index_pages_walked(tmp_path):
     from ditl_tpu.telemetry.journal import EventJournal, merge_journals
     from ditl_tpu.telemetry.tracing import Tracer
 
-    cfg = tiny()
+    cfg = CFG
     journal = EventJournal(str(tmp_path / "events-engine.jsonl"), source="engine")
-    eng = engine(cfg, n_slots=2, max_cache_len=96, tracer=Tracer(journal))
+    # an engine of its own: the tracer and its journal are the case's
+    eng = family.engine(family.model(ref, cfg), max_cache_len=96, tracer=Tracer(journal))
     tok = ByteTokenizer()
     prompts = [[tok.bos_id] + list(range(7, 7 + n)) for n in (20, 36)]  # 21 and 37 tokens
     ids = [eng.submit(p, max_new_tokens=12, temperature=0.0) for p in prompts]
@@ -232,26 +202,27 @@ def test_a_traced_engines_tick_span_counts_the_index_pages_walked(tmp_path):
 
 @pytest.mark.parametrize("mode, kw", [
     ("contiguous cache", dict(cache_mode="contiguous")),
-    ("speculative ticks", dict(cache_mode="paged", speculative=True)),
-    ("host tier", dict(cache_mode="paged", host_tier_mb=1)),
-    ("a mesh", dict(cache_mode="paged", mesh="one")),
-    ("int8 page pools", dict(cache_mode="paged", kv="int8")),
+    ("speculative ticks", dict(speculative=True)),
+    ("host tier", dict(host_tier_mb=1)),
+    ("a mesh", dict(mesh="one")),
+    ("int8 page pools", dict(kv="int8")),
 ])
 def test_modes_that_cannot_carry_a_latent_page_refuse_the_index_pool_in_the_same_words(mode, kw):
     kw = dict(kw)
-    cfg = tiny(kv_cache_dtype=kw.pop("kv", ""))
+    cfg = family.tiny(PRESET, TINY, kv_cache_dtype=kw.pop("kv", ""))
     if kw.get("mesh"):
         kw["mesh"] = jax.sharding.Mesh(np.array(jax.devices()[:1]), ("tensor",))
+    # shapes alone: the engine refuses before it reads a weight
     params = jax.eval_shape(lambda: llama.init_params(jax.random.key(0), cfg))
     with pytest.raises(ValueError, match=mode) as e:
-        ContinuousEngine(params, cfg, ByteTokenizer(), n_slots=2, max_cache_len=64, **kw)
+        family.engine((params, cfg), max_cache_len=64, **kw)
     assert "latent page pool" in str(e.value)
 
 
-def test_handoff_and_pod_serving_refuse_both_pools():
+def test_handoff_and_pod_serving_refuse_both_pools(engines):
     from ditl_tpu.infer.podserve import PodContinuousDriver
 
-    eng = engine(tiny(), max_cache_len=64)
+    eng = engines(family.model(ref, CFG), prefill_chunk=0)
     with pytest.raises(ValueError, match="handoff"):
         eng.export_kv(list(range(3, 40)))
     with pytest.raises(ValueError, match="handoff"):

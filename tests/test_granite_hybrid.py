@@ -6,28 +6,19 @@ tests/test_ssm_serving.py."""
 
 from __future__ import annotations
 
-import dataclasses
-import os
-import sys
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH = os.path.join(ROOT, "benchmarks")
-for _p in (ROOT, BENCH):
-    if _p not in sys.path:
-        sys.path.insert(0, _p)
+from ditl_tpu.models import llama, ssm
+from ditl_tpu.models.presets import get_preset
+from ditl_tpu.ops import ssd
+from tests import family
+from tests.family import rel
 
-from harness import load_module  # noqa: E402
-
-from ditl_tpu.models import llama, ssm  # noqa: E402
-from ditl_tpu.models.presets import get_preset  # noqa: E402
-from ditl_tpu.ops import ssd  # noqa: E402
-
-ref = load_module(os.path.join(BENCH, "reference", "granite_hybrid.py"))
+ref = family.reference("granite_hybrid")
+PRESET = "granite-4.0-h-micro"
 
 # Both sides compute in float32 on the same weights and differ in the order
 # of their sums (chunks of 16 with masked matmuls against a scan over tokens;
@@ -41,19 +32,10 @@ TINY = dict(vocab_size=512, hidden_size=32, intermediate_size=64, num_heads=4, n
             attention_multiplier=1 / 16, max_seq_len=1024, dtype="float32", remat="none")
 
 
-def tiny(period="mma", periods=1, **kw):
-    return dataclasses.replace(
-        get_preset("granite-4.0-h-micro"),
-        **{"num_layers": len(period) * periods, "layer_types": period * periods, **TINY, **kw})
-
-
-def rel(got, want):
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    return float(np.sqrt(np.mean((got - want) ** 2)) / np.sqrt(np.mean(want ** 2)))
-
-
-def seeded(cfg, seed=0):
-    return ref.perturb(llama.init_params(jax.random.key(seed), cfg), cfg, seed)
+def stack(period="mma", periods=1, **kw):
+    """``periods`` periods of ``period``'s layers."""
+    kw = {"num_layers": len(period) * periods, "layer_types": period * periods, **kw}
+    return family.tiny(PRESET, TINY, **kw)
 
 
 def packed(rows, s, cuts):
@@ -71,8 +53,8 @@ def packed(rows, s, cuts):
 def test_forward_matches_the_reference(period, periods, segments):
     """37 tokens a row: two chunks of 16 and a tail of 5. Packed rows: three
     documents, one cut inside a chunk and one on a chunk's edge."""
-    cfg = tiny(period, periods)
-    params = seeded(cfg)
+    cfg = stack(period, periods)
+    params = family.seeded(ref, cfg)
     ids = jax.random.randint(jax.random.key(1), (2, 37), 3, cfg.vocab_size)
     kw = {"segment_ids": packed(2, 37, [(10, 32), (16, 30)])} if segments else {}
     got = llama.forward(params, ids, cfg, **kw)
@@ -83,8 +65,8 @@ def test_forward_matches_the_reference(period, periods, segments):
 def test_loss_and_every_gradient_match_the_reference_through_the_trainers_loss_fn():
     from ditl_tpu.train.step import loss_fn
 
-    cfg = tiny("mma", 2)
-    params = seeded(cfg)
+    cfg = stack("mma", 2)
+    params = family.seeded(ref, cfg)
     ids = jax.random.randint(jax.random.key(2), (2, 40), 3, cfg.vocab_size)
     seg = packed(2, 40, [(13,), (16, 29)])
     pos = jnp.broadcast_to(jnp.arange(40, dtype=jnp.int32), ids.shape)
@@ -216,6 +198,7 @@ def test_the_preset_is_the_published_model():
     cfg = get_preset("granite-4.0-h-micro")
     assert cfg.layer_period == "mmmmmammmm" and ssm.period_counts(cfg) == (4, 9, 1)
     assert [i for i, t in enumerate(cfg.layer_types) if t == "a"] == [5, 15, 25, 35]
+    # shapes alone: this size is never drawn
     shapes = jax.eval_shape(lambda: llama.init_params(jax.random.key(0), cfg))
     # ISSUE 41's count: 36 mixers + 4 attention layers + the tied embedding
     assert llama.num_params(shapes) == 3_191_396_096
@@ -236,14 +219,14 @@ def test_the_preset_is_the_published_model():
 ])
 def test_a_setting_the_stack_cannot_run_is_refused(kw, said):
     with pytest.raises(ValueError, match=said):
-        tiny("mma", 1, **kw)
+        stack("mma", 1, **kw)
 
 
 def test_the_converter_round_trips_the_hybrid_tree():
     from ditl_tpu.models.convert import params_from_state_dict, state_dict_from_params
 
-    cfg = tiny("mma", 2)
-    params = seeded(cfg)
+    cfg = stack("mma", 2)
+    params = family.seeded(ref, cfg)
     hf = state_dict_from_params(params, cfg)
     assert "model.layers.0.mamba.in_proj.weight" in hf
     assert hf["model.layers.0.mamba.in_proj.weight"].shape == (

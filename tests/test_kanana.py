@@ -12,52 +12,38 @@ import dataclasses
 import json
 import math
 import os
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH = os.path.join(ROOT, "benchmarks")
-for _p in (ROOT, BENCH):
-    if _p not in sys.path:
-        sys.path.insert(0, _p)
+from ditl_tpu.config import DataConfig, ModelConfig, TrainConfig
+from ditl_tpu.models import llama
+from ditl_tpu.models import moe as moe_mod
+from ditl_tpu.models.presets import get_preset
+from ditl_tpu.ops.attention import _xla_attention
+from ditl_tpu.ops.flash_attention import flash_attention, supports
+from ditl_tpu.train.step import loss_fn, moe_metric_names
+from tests import family
 
-from harness import load_module  # noqa: E402
-
-from ditl_tpu.config import DataConfig, ModelConfig, TrainConfig  # noqa: E402
-from ditl_tpu.models import llama  # noqa: E402
-from ditl_tpu.models import moe as moe_mod  # noqa: E402
-from ditl_tpu.models.presets import get_preset  # noqa: E402
-from ditl_tpu.ops.attention import _xla_attention  # noqa: E402
-from ditl_tpu.ops.flash_attention import flash_attention, supports  # noqa: E402
-from ditl_tpu.train.step import loss_fn, moe_metric_names  # noqa: E402
-
-ref = load_module(os.path.join(BENCH, "reference", "kanana2.py"))
+ref = family.reference("kanana2")
+PRESET = "kanana-2-30b-a3b"
 
 TINY = dict(num_layers=2, first_k_dense_replace=1, vocab_size=512, hidden_size=64,
             intermediate_size=128, expert_ffn_hidden_size=32, num_heads=4, num_kv_heads=4,
             head_dim=24, kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
             v_head_dim=16, num_experts=32, num_experts_per_tok=4, experts_held_first=8,
             experts_held_count=8, max_seq_len=512, dtype="float32", param_dtype="float32")
+CFG = family.tiny(PRESET, TINY)
 # heads the flash kernels tile on the CPU: 128 wide in q and k (64 + 64 rotary), 64 in v
 FLASH = dict(num_heads=2, num_kv_heads=2, head_dim=128, qk_nope_head_dim=64,
              qk_rope_head_dim=64, v_head_dim=64, attention_impl="flash", loss_impl="fused")
 
 
-def tiny(**kw):
-    return dataclasses.replace(get_preset("kanana-2-30b-a3b"), **{**TINY, **kw})
-
-
 def rel(got, want):
     got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
     return float(np.sqrt(np.mean((got - want) ** 2)) / (np.sqrt(np.mean(want ** 2)) + 1e-30))
-
-
-def seeded(cfg, seed=0):
-    return ref.perturb(llama.init_params(jax.random.key(seed), cfg), cfg, seed)
 
 
 def packed_batch(cfg, rows=2, s=128, seed=1):
@@ -97,8 +83,8 @@ CASES = {
 @pytest.mark.parametrize("case", CASES)
 def test_the_program_matches_the_reference_on_logits_loss_and_every_gradient_leaf(case):
     kw, logits_tol, loss_tol, grad_tol = CASES[case]
-    cfg = tiny(**kw)
-    params, batch = seeded(cfg), packed_batch(cfg)
+    cfg = family.tiny(PRESET, TINY, **kw)
+    params, batch = family.seeded(ref, cfg), packed_batch(cfg)
     sizes = ref.sizes(cfg, {})
     ids, pos, seg = batch["input_ids"], batch["positions"], batch["segment_ids"]
     got = jax.jit(lambda p: llama.forward(p, ids, cfg, positions=pos, segment_ids=seg))(params)
@@ -184,7 +170,7 @@ def test_the_gate_takes_a_width_for_the_values_and_one_width_as_before():
 
 
 def _moe_inputs(cfg, bias):
-    params = seeded(cfg)["layers"]["sparse"]["moe"]
+    params = family.seeded(ref, cfg)["layers"]["sparse"]["moe"]
     m = jax.tree.map(lambda w: w[0], params)
     m["router_bias"] = jnp.asarray(bias, jnp.float32)
     u = jax.random.normal(jax.random.key(9), (2, 64, cfg.hidden_size), jnp.float32)
@@ -211,7 +197,7 @@ SKEWS = {"even": 0.0, "every-pair-held": 5.0, "none-held": -5.0}
 
 @pytest.mark.parametrize("skew", SKEWS)
 def test_the_held_shares_backward_matches_a_loop_over_experts_at_any_skew(skew):
-    cfg = tiny()
+    cfg = CFG
     bias = np.zeros(cfg.num_experts, np.float32)
     bias[cfg.experts_held_first:cfg.experts_held_first + cfg.experts_held_count] = SKEWS[skew]
     m, u = _moe_inputs(cfg, bias)
@@ -243,7 +229,7 @@ def test_the_held_shares_backward_matches_a_loop_over_experts_at_any_skew(skew):
 
 
 def test_the_static_buffers_give_the_loops_output_and_an_empty_one_runs_no_matmul():
-    cfg = tiny()
+    cfg = CFG
     m, u = _moe_inputs(cfg, np.zeros(cfg.num_experts, np.float32))
     a = moe_mod.moe_block(m, u, cfg, static_buffers=True)
     b = moe_mod.moe_block(m, u, cfg)
@@ -264,13 +250,13 @@ def test_the_eight_shares_add_up_to_the_uncut_layer_forward_and_gradient():
     """Every share's routed part, and the shared expert ONCE: the layer that
     holds all 32 experts, in its output and in the gradient of the parameters
     every chip holds (the shared expert, the router) and of the stream."""
-    cfg = tiny(experts_held_first=0, experts_held_count=32)
+    cfg = family.tiny(PRESET, TINY, experts_held_first=0, experts_held_count=32)
     m, u = _moe_inputs(cfg, np.random.default_rng(0).normal(0, 0.02, 32))
     shared_only = lambda m, u: (jax.nn.silu(u @ m["shared"]["w_gate"])  # noqa: E731
                                 * (u @ m["shared"]["w_up"])) @ m["shared"]["w_down"]
 
     def share(s):
-        c = tiny(experts_held_first=4 * s, experts_held_count=4)
+        c = family.tiny(PRESET, TINY, experts_held_first=4 * s, experts_held_count=4)
         return lambda m, u: moe_mod.moe_block(
             {**m, **{k: m[k][4 * s:4 * s + 4] for k in ("w_gate", "w_up", "w_down")}}, u, c,
             static_buffers=True)[0]
@@ -297,7 +283,7 @@ def test_the_router_bias_is_a_buffer_no_update_and_no_decay():
     from ditl_tpu.train.step import _build_step_fn
     from ditl_tpu.parallel.sharding import DEFAULT_RULES
 
-    cfg = tiny()
+    cfg = CFG
     tc = TrainConfig(weight_decay=0.1, learning_rate=1e-2, warmup_steps=0, total_steps=10)
     state = create_train_state(jax.random.key(0), cfg, tc)
     bias = jax.random.normal(jax.random.key(1), (1, 32)) * 0.02
@@ -319,7 +305,7 @@ def test_a_frozen_router_is_left_as_the_checkpoint_has_it_and_keeps_no_moments()
     from ditl_tpu.train.step import _build_step_fn
     from ditl_tpu.parallel.sharding import DEFAULT_RULES
 
-    cfg = tiny()
+    cfg = CFG
     tc = TrainConfig(weight_decay=0.1, learning_rate=1e-2, warmup_steps=0, total_steps=10,
                      frozen="router")
     state = create_train_state(jax.random.key(0), cfg, tc)
@@ -340,7 +326,7 @@ def test_a_frozen_router_is_left_as_the_checkpoint_has_it_and_keeps_no_moments()
 
 
 def test_a_given_choice_takes_the_place_of_the_routers_own():
-    cfg = tiny()
+    cfg = CFG
     m, u = _moe_inputs(cfg, np.zeros(cfg.num_experts, np.float32))
     gates = jax.nn.sigmoid(u.reshape(-1, u.shape[-1]).astype(jnp.float32)
                            @ m["router"].astype(jnp.float32))
@@ -365,7 +351,7 @@ def test_an_indexed_block_keeps_the_loop_its_passes_had():
         seen.append(static_buffers)
         return real(*a, static_buffers=static_buffers, **kw)
 
-    for cfg in (tiny(), dataclasses.replace(
+    for cfg in (CFG, dataclasses.replace(
             get_preset("deepseek-v3.2"), num_layers=2, first_k_dense_replace=1, vocab_size=512,
             hidden_size=64, intermediate_size=128, expert_ffn_hidden_size=32, num_heads=4,
             num_kv_heads=4, head_dim=24, q_lora_rank=32, kv_lora_rank=32, qk_nope_head_dim=16,
@@ -375,14 +361,15 @@ def test_an_indexed_block_keeps_the_loop_its_passes_had():
         ids = jnp.zeros((1, 32), jnp.int32)
         with pytest.MonkeyPatch.context() as mp:
             mp.setattr(moe_mod, "moe_block", spy)
+            # shapes alone: the pass is traced, no weight is drawn
             jax.eval_shape(lambda p: llama.forward(p, ids, cfg),  # noqa: B023
                            jax.eval_shape(lambda: llama.init_params(jax.random.key(0), cfg)))  # noqa: B023
     assert seen == [True, False]  # the trained block walks static buffers, the indexed one loops
 
 
 def test_the_loss_has_no_auxiliary_term_and_keeps_the_reading():
-    cfg = tiny()
-    params, batch = seeded(cfg), packed_batch(cfg)
+    cfg = CFG
+    params, batch = family.seeded(ref, cfg), packed_batch(cfg)
     loss, metrics = jax.jit(lambda p: loss_fn(p, batch, cfg))(params)
     assert float(loss) == float(metrics["loss"]) and float(metrics["router_aux_loss"]) > 0
     with_term, m2 = jax.jit(lambda p: loss_fn(
@@ -431,7 +418,7 @@ def test_the_corpus_at_fixed_lengths_packs_every_row_alike_whatever_the_seed():
 ])
 def test_a_combination_no_block_carries_is_refused_by_name(kw, said):
     with pytest.raises(ValueError, match=said):
-        tiny(**kw)
+        family.tiny(PRESET, TINY, **kw)
 
 
 def test_the_three_latent_families_are_told_apart_by_one_fact_each():
@@ -443,20 +430,22 @@ def test_the_three_latent_families_are_told_apart_by_one_fact_each():
     with pytest.raises(ValueError, match="rope_yarn"):
         ModelConfig(rope_yarn_factor=2.0)
     # one group that always stays is no group limiting
-    a, b = tiny(), tiny(n_group=0, topk_group=0)
+    a, b = CFG, family.tiny(PRESET, TINY, n_group=0, topk_group=0)
     m, u = _moe_inputs(a, np.zeros(32, np.float32))
     np.testing.assert_array_equal(moe_mod.moe_block(m, u, a)[0], moe_mod.moe_block(m, u, b)[0])
-    tree = jax.eval_shape(lambda: llama.init_params(jax.random.key(0), tiny()))
+    # shapes alone: this size is never drawn
+    tree = jax.eval_shape(lambda: llama.init_params(jax.random.key(0), CFG))
     assert "index" not in tree["layers"]["sparse"] and "wq" in tree["layers"]["sparse"]["attn"]
     assert set(tree["layers"]["sparse"]["attn"]) == {"wq", "w_kva", "kv_norm", "w_kvb", "wo"}
 
 
 def test_the_preset_and_its_cut_count_their_parameters_and_hold_the_configuration_file():
-    with open(os.path.join(BENCH, "configs", "kanana-2-30b-a3b-cut1.json")) as f:
+    with open(os.path.join(family.BENCH, "configs", "kanana-2-30b-a3b-cut1.json")) as f:
         config = json.load(f)
     cfg = dataclasses.replace(get_preset("kanana-2-30b-a3b"), **config["model_overrides"],
                               **config["train_overrides"])
     assert ref.check_sizes(cfg, config) == []
+    # shapes alone: this size is never drawn
     shapes = jax.eval_shape(lambda: llama.init_params(jax.random.key(0), cfg))
     n = sum(math.prod(x.shape) for x in jax.tree.leaves(shapes))
     assert n == config["cut"]["parameters"] == 575_955_968
@@ -479,10 +468,10 @@ def test_the_preset_and_its_cut_count_their_parameters_and_hold_the_configuratio
 def test_serving_it_is_refused_by_name_and_a_cached_forward_too():
     from ditl_tpu.infer.page_format import page_format
 
-    cfg = tiny()
+    cfg = CFG
     with pytest.raises(ValueError, match="trained, not served"):
         page_format(cfg, n_pages=8, page_size=16, n_slots=2, decode_chunk=1)
-    params = seeded(cfg)
+    params = family.seeded(ref, cfg)
     cache = {"c": jnp.zeros((2, 1, 1, 32, 128)), "i": jnp.zeros((2, 1, 1, 32, 16))}
     with pytest.raises(ValueError, match="no.*cached forward"):
         llama.forward(params, jnp.ones((1, 8), jnp.int32), cfg, cache=cache, cache_index=0,

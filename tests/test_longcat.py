@@ -9,29 +9,21 @@ sees and the device counters (tests/test_longcat_serving.py has the engine)."""
 from __future__ import annotations
 
 import dataclasses
-import os
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH = os.path.join(ROOT, "benchmarks")
-for _p in (ROOT, BENCH):
-    if _p not in sys.path:
-        sys.path.insert(0, _p)
+from ditl_tpu.models import llama, mla
+from ditl_tpu.models import moe as moe_mod
+from ditl_tpu.models.presets import get_preset
+from ditl_tpu.ops import mla_attention
+from tests import family, rect_walk
+from tests.family import rel
 
-from harness import load_module  # noqa: E402
-
-from ditl_tpu.models import llama, mla  # noqa: E402
-from ditl_tpu.models import moe as moe_mod  # noqa: E402
-from ditl_tpu.models.presets import get_preset  # noqa: E402
-from ditl_tpu.ops import mla_attention  # noqa: E402
-from tests import rect_walk  # noqa: E402
-
-ref = load_module(os.path.join(BENCH, "reference", "longcat_flash.py"))
+ref = family.reference("longcat_flash")
+PRESET = "longcat-flash"
 
 # Both sides compute in float32 on the same weights; they differ in the order
 # of their sums (a grouped matmul and a scatter-add against a masked loop,
@@ -45,25 +37,13 @@ TINY = dict(vocab_size=512, hidden_size=64, intermediate_size=128, expert_ffn_hi
             kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
             num_experts=32, zero_expert_num=16, num_experts_per_tok=4, max_seq_len=256,
             dtype="float32")
+CFG = family.tiny(PRESET, TINY)
 OVERRIDES = [f"{k}={v}" for k, v in TINY.items()]
 
 
-def tiny(**kw):
-    return dataclasses.replace(get_preset("longcat-flash"), **{**TINY, **kw})
-
-
-def rel(got, want):
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    return float(np.sqrt(np.mean((got - want) ** 2)) / np.sqrt(np.mean(want ** 2)))
-
-
-def seeded(cfg, seed=0):
-    return ref.perturb(llama.init_params(jax.random.key(seed), cfg), cfg, seed)
-
-
 def test_forward_matches_the_reference():
-    cfg = tiny(experts_held_first=8, experts_held_count=8)
-    params = seeded(cfg)
+    cfg = family.tiny(PRESET, TINY, experts_held_first=8, experts_held_count=8)
+    params = family.seeded(ref, cfg)
     ids = jax.random.randint(jax.random.key(1), (2, 40), 3, cfg.vocab_size)
     seg = jnp.concatenate([jnp.ones((2, 25), jnp.int32), 2 * jnp.ones((2, 15), jnp.int32)], 1)
     pos = jnp.concatenate([jnp.arange(25), jnp.arange(15)])[None].repeat(2, 0)
@@ -85,8 +65,8 @@ def _latents(cfg, a, h, pos):
 
 
 def test_absorbed_decode_equals_decompressed_attention():
-    cfg = tiny()
-    a = jax.tree.map(lambda w: w[0], seeded(cfg)["layers"]["attn"]["sub1"])
+    cfg = CFG
+    a = jax.tree.map(lambda w: w[0], family.seeded(ref, cfg)["layers"]["attn"]["sub1"])
     b, s, ps = 3, 37, 16
     h = jax.random.normal(jax.random.key(2), (b, s, cfg.hidden_size))
     pos = jnp.broadcast_to(jnp.arange(s), (b, s))
@@ -166,8 +146,8 @@ def test_the_shares_add_up_to_the_uncut_expert_block():
     """32 routed + 16 zero experts, top-4, four shares of 8: the shares'
     routed parts plus the zero experts' part counted once are the uncut
     reference's MoE(u)."""
-    cfg = tiny(num_layers=1)
-    full = seeded(cfg)["layers"]["moe"]
+    cfg = family.tiny(PRESET, TINY, num_layers=1)
+    full = family.seeded(ref, cfg)["layers"]["moe"]
     u = jax.random.normal(jax.random.key(4), (2, 9, cfg.hidden_size))
     sizes = ref.sizes(cfg, {})
     want, _ = ref._experts(full, 0, u, sizes)
@@ -188,8 +168,8 @@ def test_rows_whose_choices_are_all_of_one_kind(kind):
     """A selection bias that sends every choice of every row to zero-compute
     experts, to experts held elsewhere (the grouped matmul sees no row: the
     loop over buffers runs zero times), or to experts held here."""
-    cfg = tiny(num_layers=1, experts_held_first=8, experts_held_count=8)
-    full = seeded(cfg)["layers"]["moe"]
+    cfg = family.tiny(PRESET, TINY, num_layers=1, experts_held_first=8, experts_held_count=8)
+    full = family.seeded(ref, cfg)["layers"]["moe"]
     where = {"zero": slice(32, 48), "absent": slice(16, 32), "held": slice(8, 16)}[kind]
     full = {**full, "router_bias": full["router_bias"].at[:, where].add(1.0)}
     u = jax.random.normal(jax.random.key(5), (1, 6, cfg.hidden_size))
@@ -205,8 +185,8 @@ def test_rows_whose_choices_are_all_of_one_kind(kind):
 
 
 def test_the_device_counters_equal_a_host_recount_of_the_live_rows():
-    cfg = tiny(experts_held_first=8, experts_held_count=8)
-    params = seeded(cfg)
+    cfg = family.tiny(PRESET, TINY, experts_held_first=8, experts_held_count=8)
+    params = family.seeded(ref, cfg)
     ids = jax.random.randint(jax.random.key(6), (3, 11), 3, cfg.vocab_size)
     live = jnp.arange(11)[None, :] < jnp.array([11, 4, 0])[:, None]  # a dead row among them
     _, counts = jax.jit(lambda p: llama.forward(
@@ -233,9 +213,9 @@ def test_a_setting_nothing_would_read_is_refused(kw, said):
     layer only. The block kind is derived (``kv_lora_rank > 0``), and the
     rotary pairing is what ``mla.py`` does, so neither is a field."""
     with pytest.raises(ValueError, match=said):
-        tiny(**kw)
-    assert tiny().double_layer and not get_preset("olmoe-1b-7b").double_layer
-    fields = {f.name for f in dataclasses.fields(tiny())}
+        family.tiny(PRESET, TINY, **kw)
+    assert CFG.double_layer and not get_preset("olmoe-1b-7b").double_layer
+    fields = {f.name for f in dataclasses.fields(CFG)}
     assert not fields & {"double_layer", "rope_interleaved"}
 
 
@@ -256,8 +236,8 @@ def test_the_converter_round_trips_the_double_layer_and_a_share_of_it():
     experts by their PUBLISHED indices and loads only those from the whole."""
     from ditl_tpu.models import convert
 
-    cfg = tiny(num_layers=1, param_dtype="float32")
-    params = seeded(cfg)
+    cfg = family.tiny(PRESET, TINY, num_layers=1, param_dtype="float32")
+    params = family.seeded(ref, cfg)
     sd = convert.state_dict_from_params(params, cfg)
     assert sd["model.layers.0.self_attn.1.kv_b_proj.weight"].shape == (4 * 32, 32)
     assert sd["model.layers.0.mlp.router.classifier.weight"].shape == (48, 64)

@@ -6,29 +6,17 @@ runner can give it a worker of its own (tests/test_longcat.py has the model)."""
 
 from __future__ import annotations
 
-import dataclasses
-import os
-import sys
-
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH = os.path.join(ROOT, "benchmarks")
-for _p in (ROOT, BENCH):
-    if _p not in sys.path:
-        sys.path.insert(0, _p)
+from ditl_tpu.data.tokenizer import ByteTokenizer
+from ditl_tpu.models import llama
+from tests import family
+from tests.family import ask, prompt_of
 
-from harness import load_module  # noqa: E402
-
-from ditl_tpu.data.tokenizer import ByteTokenizer  # noqa: E402
-from ditl_tpu.infer.continuous import ContinuousEngine  # noqa: E402
-from ditl_tpu.models import llama  # noqa: E402
-from ditl_tpu.models.presets import get_preset  # noqa: E402
-
-ref = load_module(os.path.join(BENCH, "reference", "longcat_flash.py"))
+ref = family.reference("longcat_flash")
+PRESET = "longcat-flash"
 
 # Both sides compute in float32 on the same weights; they differ in the order
 # of their sums (a grouped matmul and a scatter-add against a masked loop,
@@ -45,17 +33,8 @@ TINY = dict(vocab_size=512, hidden_size=64, intermediate_size=128, expert_ffn_hi
 OVERRIDES = [f"{k}={v}" for k, v in TINY.items()]
 
 
-def tiny(**kw):
-    return dataclasses.replace(get_preset("longcat-flash"), **{**TINY, **kw})
-
-
-def rel(got, want):
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    return float(np.sqrt(np.mean((got - want) ** 2)) / np.sqrt(np.mean(want ** 2)))
-
-
-def seeded(cfg, seed=0):
-    return ref.perturb(llama.init_params(jax.random.key(seed), cfg), cfg, seed)
+# the share of the expert layer that the serving cell holds: experts 8 to 15
+CFG = family.tiny(PRESET, TINY, experts_held_first=8, experts_held_count=8)
 
 
 def test_paged_prefill_then_decode_matches_the_references_full_forward():
@@ -72,33 +51,26 @@ def test_paged_prefill_then_decode_matches_the_references_full_forward():
     assert verdict["logprob_err_over_logit_rms"] < TOL, verdict
 
 
-def test_chunked_prefill_gathers_latent_pages_as_context():
+def test_chunked_prefill_gathers_latent_pages_as_context(engines):
     """A prompt longer than the prefill chunk: later chunks gather the earlier
     ones' latent pages and decompress them; a second request with the same
     prompt finds its pages by their hashes (a page's hash never looks inside)."""
-    cfg = tiny(experts_held_first=8, experts_held_count=8)
-    params = seeded(cfg)
-    tok = ByteTokenizer()
-    rng = np.random.default_rng(0)
-    prompt = [tok.bos_id] + [int(t) for t in rng.integers(3, cfg.vocab_size, 70)]
+    prompt = prompt_of(np.random.default_rng(0), 71)
     outs = []
     for chunk in (0, 32):
-        eng = ContinuousEngine(params, cfg, tok, n_slots=2, cache_mode="paged", page_size=16,
-                               max_cache_len=128, prefill_chunk=chunk)
-        answers = []
-        for _ in range(2):  # the second finds the first one's published pages
-            rid = eng.submit(prompt, max_new_tokens=6, temperature=0.0)
-            answers.append(eng.run()[rid])
-        outs.append(answers)
-        assert eng.stats()["prefix_cache"]["hit_tokens"] >= 64
+        eng = engines(family.model(ref, CFG), prefill_chunk=chunk)
+        hit_was = eng.stats()["prefix_cache"]["hit_tokens"]
+        # the second finds the first one's published pages
+        outs.append([ask(eng, prompt, 6) for _ in range(2)])
+        assert eng.stats()["prefix_cache"]["hit_tokens"] - hit_was >= 64
     assert outs[0][0] == outs[0][1] == outs[1][0] == outs[1][1]
 
 
 def test_the_engine_counts_assignments_by_kind_and_the_context_it_read():
-    cfg = tiny(experts_held_first=8, experts_held_count=8)
+    cfg = CFG
     tok = ByteTokenizer()
-    eng = ContinuousEngine(seeded(cfg), cfg, tok, n_slots=4, cache_mode="paged", page_size=16,
-                           max_cache_len=64, decode_chunk=8)
+    # an engine of its own: its counters are read whole
+    eng = family.engine(family.model(ref, cfg), n_slots=4, max_cache_len=64, decode_chunk=8)
     prompt = [tok.bos_id, 7, 8, 9, 10]
     rid = eng.submit(prompt, max_new_tokens=12, temperature=0.0)
     n_out = len(eng.run()[rid])
@@ -114,32 +86,29 @@ def test_the_engine_counts_assignments_by_kind_and_the_context_it_read():
 
 @pytest.mark.parametrize("mode, kw", [
     ("contiguous cache", dict(cache_mode="contiguous")),
-    ("speculative ticks", dict(cache_mode="paged", speculative=True)),
-    ("host tier", dict(cache_mode="paged", host_tier_mb=1)),
-    ("a mesh", dict(cache_mode="paged", mesh="one")),
-    ("int8 page pools", dict(cache_mode="paged", kv="int8")),
+    ("speculative ticks", dict(speculative=True)),
+    ("host tier", dict(host_tier_mb=1)),
+    ("a mesh", dict(mesh="one")),
+    ("int8 page pools", dict(kv="int8")),
 ])
 def test_modes_that_cannot_carry_a_latent_page_refuse_by_name(mode, kw):
     kw = dict(kw)
-    cfg = tiny(kv_cache_dtype=kw.pop("kv", ""))
+    cfg = family.tiny(PRESET, TINY, kv_cache_dtype=kw.pop("kv", ""))
     if kw.get("mesh"):
         kw["mesh"] = jax.sharding.Mesh(np.array(jax.devices()[:1]), ("tensor",))
+    # shapes alone: the engine refuses before it reads a weight
     params = jax.eval_shape(lambda: llama.init_params(jax.random.key(0), cfg))
     with pytest.raises(ValueError, match=mode):
-        ContinuousEngine(params, cfg, ByteTokenizer(), n_slots=2, max_cache_len=64, **kw)
+        family.engine((params, cfg), max_cache_len=64, **kw)
 
 
-def test_handoff_and_pod_serving_refuse_a_latent_pool():
+def test_handoff_and_pod_serving_refuse_a_latent_pool(engines):
     from ditl_tpu.infer.podserve import PodContinuousDriver
 
-    cfg = tiny()
-    eng = ContinuousEngine(seeded(cfg), cfg, ByteTokenizer(), n_slots=2, cache_mode="paged",
-                           page_size=16, max_cache_len=64)
+    eng = engines(family.model(ref, CFG), prefill_chunk=0)
     with pytest.raises(ValueError, match="handoff"):
         eng.export_kv(list(range(3, 40)))
     with pytest.raises(ValueError, match="handoff"):
         eng.import_kv(b"")
     with pytest.raises(ValueError, match="pod serving"):
         PodContinuousDriver(eng)
-
-

@@ -16,8 +16,6 @@ renormalising the gates by ~3e-1).
 from __future__ import annotations
 
 import dataclasses
-import importlib.util
-import os
 
 import jax
 import jax.numpy as jnp
@@ -25,14 +23,13 @@ import numpy as np
 import pytest
 
 from ditl_tpu.config import ModelConfig
-from ditl_tpu.data.tokenizer import ByteTokenizer
-from ditl_tpu.infer.continuous import ContinuousEngine
 from ditl_tpu.models import llama
 from ditl_tpu.models.moe import moe_block
 from ditl_tpu.models.presets import get_preset
 from ditl_tpu.train.step import loss_fn
+from tests import family
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ref = family.reference("olmoe")
 TOL = 1e-4
 
 # OLMoE's shape in small: as many kv heads as query heads, q/k normalisation,
@@ -46,20 +43,7 @@ CFG = ModelConfig(
 )
 
 
-@pytest.fixture(scope="module")
-def ref():
-    path = os.path.join(ROOT, "benchmarks", "reference", "olmoe.py")
-    spec = importlib.util.spec_from_file_location("reference_olmoe", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def _params(ref, cfg, seed=0):
-    return ref.perturb(llama.init_params(jax.random.key(seed), cfg), cfg, seed)
-
-
-def _sizes(ref, cfg):
+def _sizes(cfg):
     return ref.sizes(cfg, {})
 
 
@@ -97,6 +81,7 @@ def test_the_preset_is_the_published_model():
     assert (cfg.num_experts, cfg.num_experts_per_tok, cfg.intermediate_size) == (64, 8, 1024)
     assert cfg.qk_norm and not cfg.norm_topk_prob and not cfg.attention_bias
     assert not cfg.tie_embeddings and cfg.vocab_size == 50304 and cfg.max_seq_len == 4096
+    # shapes alone: the published size is never drawn
     shapes = jax.eval_shape(lambda: llama.init_params(jax.random.key(0), cfg))
     assert sum(x.size for x in jax.tree.leaves(shapes)) == 6_919_161_856
     # Mixtral's gates still sum to 1
@@ -104,13 +89,13 @@ def test_the_preset_is_the_published_model():
 
 
 @pytest.mark.parametrize("renormalise", [False, True])
-def test_logits_match_the_reference_on_packed_rows(ref, renormalise):
+def test_logits_match_the_reference_on_packed_rows(renormalise):
     cfg = dataclasses.replace(CFG, norm_topk_prob=renormalise)
-    params = _params(ref, cfg)
+    params = family.seeded(ref, cfg)
     b = _packed_batch()
     kw = {"positions": b["positions"], "segment_ids": b["segment_ids"]}
     got = llama.forward(params, b["input_ids"], cfg, **kw)
-    want = ref.forward(params, b["input_ids"], _sizes(ref, cfg), **kw)["logits"]
+    want = ref.forward(params, b["input_ids"], _sizes(cfg), **kw)["logits"]
     _close(got, want, "logits")
     # the two settings are different models (or the flag would be dead)
     other = dataclasses.replace(cfg, norm_topk_prob=not renormalise)
@@ -118,10 +103,10 @@ def test_logits_match_the_reference_on_packed_rows(ref, renormalise):
     assert moved > 100 * TOL * np.abs(np.asarray(want)).max()
 
 
-def test_a_dropped_qk_scale_would_show(ref):
-    params = _params(ref, CFG)
+def test_a_dropped_qk_scale_would_show():
+    params = family.seeded(ref, CFG)
     b = _packed_batch()
-    want = ref.forward(params, b["input_ids"], _sizes(ref, CFG))["logits"]
+    want = ref.forward(params, b["input_ids"], _sizes(CFG))["logits"]
     ones = jax.tree.map(lambda x: x, params)
     for name in ("q_norm", "k_norm"):
         ones["layers"]["attn"][name] = jnp.ones_like(params["layers"]["attn"][name])
@@ -129,10 +114,10 @@ def test_a_dropped_qk_scale_would_show(ref):
     assert np.abs(np.asarray(got - want)).max() > 100 * TOL * np.abs(np.asarray(want)).max()
 
 
-def test_loss_and_every_gradient_match_the_reference(ref):
-    params = _params(ref, CFG)
+def test_loss_and_every_gradient_match_the_reference():
+    params = family.seeded(ref, CFG)
     b = _packed_batch(seed=1)
-    sizes = _sizes(ref, CFG)
+    sizes = _sizes(CFG)
     kw = {"positions": b["positions"], "segment_ids": b["segment_ids"]}
 
     def want_fn(p):
@@ -160,8 +145,7 @@ def test_a_token_is_the_same_alone_and_among_tokens_that_choose_its_experts():
     """Token-exactness. Sixty-four copies of one token all choose the same
     four experts of sixteen: a capacity of ceil(k T / E x 1.25) = 20 rows an
     expert would drop two thirds of them."""
-    moe = jax.tree.map(lambda w: w[0], llama.init_params(
-        jax.random.key(3), CFG)["layers"]["moe"])
+    moe = jax.tree.map(lambda w: w[0], family.seeded(None, CFG, 3)["layers"]["moe"])
     token = jax.random.normal(jax.random.key(4), (1, 1, CFG.hidden_size), jnp.float32)
     alone, _, counts = moe_block(moe, token, CFG)
     crowd, _, crowd_counts = moe_block(moe, jnp.tile(token, (1, 64, 1)), CFG)
@@ -178,8 +162,7 @@ def test_a_token_is_the_same_alone_and_among_tokens_that_choose_its_experts():
 
 
 def test_counts_see_live_tokens_only_and_the_output_does_not_change():
-    moe = jax.tree.map(lambda w: w[0], llama.init_params(
-        jax.random.key(3), CFG)["layers"]["moe"])
+    moe = jax.tree.map(lambda w: w[0], family.seeded(None, CFG, 3)["layers"]["moe"])
     h = jax.random.normal(jax.random.key(6), (2, 10, CFG.hidden_size), jnp.float32)
     mask = jnp.asarray(np.arange(20).reshape(2, 10) % 3 != 0)
     out_all, aux_all, counts_all = moe_block(moe, h, CFG)
@@ -202,8 +185,7 @@ def test_the_tpu_kernel_and_xlas_grouped_matmul_agree(monkeypatch):
     from ditl_tpu.models import moe as moe_mod
 
     cfg = dataclasses.replace(CFG, hidden_size=128, intermediate_size=128)
-    moe = jax.tree.map(lambda w: w[0], llama.init_params(
-        jax.random.key(8), cfg)["layers"]["moe"])
+    moe = jax.tree.map(lambda w: w[0], family.seeded(None, cfg, 8)["layers"]["moe"])
     h = jax.random.normal(jax.random.key(9), (2, 16, cfg.hidden_size), jnp.float32)
 
     def run():
@@ -231,7 +213,7 @@ def test_a_cached_forward_addresses_the_experts_inside_the_stack(monkeypatch):
     from ditl_tpu.models import moe as moe_mod
 
     cfg = dataclasses.replace(CFG, hidden_size=128, intermediate_size=128, head_dim=32)
-    params = llama.init_params(jax.random.key(12), cfg)
+    params = family.seeded(None, cfg, 12)
     ids = jnp.asarray(np.random.default_rng(12).integers(3, 500, (2, 16)), jnp.int32)
     mask = jnp.tril(jnp.ones((16, 32), bool), k=0)[None].repeat(2, 0)
 
@@ -264,17 +246,13 @@ def test_a_cached_forward_addresses_the_experts_inside_the_stack(monkeypatch):
 
 
 @pytest.fixture(scope="module")
-def served(ref):
+def served():
     """Three prompts of uneven length through a paged engine of four slots
     (one stays dead), greedy, with the returned log-probabilities."""
-    params = _params(ref, CFG, seed=7)
-    tok = ByteTokenizer()
-    eng = ContinuousEngine(
-        params, CFG, tok, n_slots=4, decode_chunk=4, max_cache_len=128,
-        cache_mode="paged", page_size=16, logprobs_k=5,
-    )
+    params = family.seeded(ref, CFG, 7)
+    eng = family.engine((params, CFG), n_slots=4, decode_chunk=4, logprobs_k=5)
     rng = np.random.default_rng(11)
-    prompts = [[tok.bos_id] + [int(t) for t in rng.integers(3, 250, n)] for n in (5, 21, 38)]
+    prompts = [family.prompt_of(rng, n + 1, vocab=250) for n in (5, 21, 38)]
     new = (9, 6, 12)
     ids = [eng.submit(p, max_new_tokens=n, temperature=0.0, logprobs=5)
            for p, n in zip(prompts, new)]
@@ -284,9 +262,9 @@ def served(ref):
     return params, eng, [(p, done[i]) for p, i in zip(prompts, ids)]
 
 
-def test_prefill_then_paged_decode_matches_the_references_full_forward(ref, served):
+def test_prefill_then_paged_decode_matches_the_references_full_forward(served):
     params, _eng, pairs = served
-    sizes = _sizes(ref, CFG)
+    sizes = _sizes(CFG)
     for prompt, req in pairs:
         n = len(req.tokens)
         assert n >= 2 and len(req.lp_token) == n

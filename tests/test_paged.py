@@ -20,7 +20,7 @@ from ditl_tpu.infer.continuous import ContinuousEngine
 from ditl_tpu.infer.engine import GenerateConfig, Generator
 from ditl_tpu.infer.paged_cache import PageAllocator, block_keys
 from ditl_tpu.models import llama
-from tests import rect_walk
+from tests import family, rect_walk
 from tests.tpu_compile import _eqns
 
 pytestmark = pytest.mark.pallas
@@ -578,12 +578,12 @@ def test_paged_decode_scans_no_pool(tiny_setup, kind):
     eng = _paged_engine(params, cfg, **kw)
     alive = jnp.ones((eng.n_slots,), bool)
     if kind == "speculative":
-        program = eng._build_spec_paged_decode(False)
+        program = family.build_program(eng, "spec_paged_decode", False)
         args = (eng.params, eng.cache, eng.cur, eng.pos, alive,
                 eng._table_device(), eng.limits, eng.hist, eng.temps,
                 eng.top_ps, eng.keys, eng.adapters)
     else:
-        program = eng._build_paged_decode(False, False)
+        program = family.build_program(eng, "paged_decode", False, False)
         args = (eng.params, eng.cache, eng.cur, eng.pos, alive, eng.temps,
                 eng.top_ps, eng.keys, eng._table_device(), eng.limits,
                 eng.hist, eng.adapters)

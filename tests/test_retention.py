@@ -6,40 +6,25 @@ refusals and the converter (tests/test_retention_serving.py has the engine)."""
 from __future__ import annotations
 
 import dataclasses
-import os
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH = os.path.join(ROOT, "benchmarks")
-for _p in (ROOT, BENCH):
-    if _p not in sys.path:
-        sys.path.insert(0, _p)
+from ditl_tpu.models import llama, retention
+from ditl_tpu.models.presets import get_preset
+from ditl_tpu.ops import retention as ret
+from tests import family
 
-from harness import load_module  # noqa: E402
-
-from ditl_tpu.models import llama, retention  # noqa: E402
-from ditl_tpu.models.presets import get_preset  # noqa: E402
-from ditl_tpu.ops import retention as ret  # noqa: E402
-
-ref = load_module(os.path.join(BENCH, "reference", "brumby.py"))
+ref = family.reference("brumby")
+PRESET = "brumby-14b"
 
 TINY = dict(vocab_size=512, hidden_size=64, intermediate_size=128, num_layers=3,
             layer_types="rrr", num_heads=4, num_kv_heads=2, head_dim=16, ret_chunk=16,
             max_seq_len=1024, dtype="float32")
+CFG = family.tiny(PRESET, TINY)
 EPS = 1e-5
-
-
-def tiny(**kw):
-    return dataclasses.replace(get_preset("brumby-14b"), **{**TINY, **kw})
-
-
-def seeded(cfg, seed=0):
-    return ref.perturb(llama.init_params(jax.random.key(seed), cfg), cfg, seed)
 
 
 def rel(a, b):
@@ -265,8 +250,8 @@ def test_the_held_token_form_equals_the_recurrent_form_step_by_step(tick, patter
 def test_forward_matches_the_reference(tokens, chunk):
     """The system's uncached pass (``ret_scan`` inside the layer scan)
     against the reference's attention form, float32 on both sides."""
-    cfg = tiny(ret_chunk=chunk)
-    params = seeded(cfg)
+    cfg = family.tiny(PRESET, TINY, ret_chunk=chunk)
+    params = family.seeded(ref, cfg)
     ids = jnp.asarray(np.random.default_rng(0).integers(3, 512, (2, tokens)), jnp.int32)
     with jax.default_matmul_precision("highest"):
         got = jax.jit(lambda p: llama.forward(p, ids, cfg))(params)
@@ -294,6 +279,7 @@ def test_a_power_taken_in_bfloat16_is_refused_by_the_reference(monkeypatch):
 def test_the_preset_is_the_published_model():
     cfg = get_preset("brumby-14b")
     assert cfg.layer_period == "r" and cfg.retention_layer and not cfg.window_layer
+    # shapes alone: this size is never drawn
     shapes = jax.eval_shape(lambda: llama.init_params(jax.random.key(0), cfg))
     layer = 26_214_400 * 2 + 5_242_880 * 2 + 267_386_880 + 40_968 + 10_496
     assert layer == 330_352_904
@@ -311,8 +297,8 @@ def test_the_preset_is_the_published_model():
 
 
 def test_seeded_gates_remember_for_tens_to_thousands_of_tokens():
-    cfg = tiny()
-    m = llama.init_params(jax.random.key(3), cfg)["layers"]["sub0"]["ret"]
+    cfg = CFG
+    m = family.seeded(None, cfg, 3)["layers"]["sub0"]["ret"]
     centre = jax.nn.sigmoid(m["bg"].astype(jnp.float32))
     lo, hi = retention.GATE_RANGE
     assert bool(jnp.all((centre >= lo - 1e-6) & (centre <= hi + 1e-6)))
@@ -335,27 +321,27 @@ def test_seeded_gates_remember_for_tens_to_thousands_of_tokens():
 ])
 def test_a_setting_the_stack_cannot_run_is_refused(kw, said):
     with pytest.raises(ValueError, match=said):
-        tiny(**kw)
+        family.tiny(PRESET, TINY, **kw)
 
 
 def test_the_trainer_refuses_a_retention_stack_by_name():
     from ditl_tpu.train.step import loss_fn
 
-    cfg = tiny()
+    cfg = CFG
     ids = jnp.zeros((1, 8), jnp.int32)
     batch = {"input_ids": ids, "loss_mask": jnp.ones_like(ids), "segment_ids": jnp.ones_like(ids)}
     with pytest.raises(ValueError, match="served, not trained"):
         loss_fn({}, batch, cfg)
     with pytest.raises(ValueError, match="packed documents"):
-        llama.forward(seeded(cfg), ids, cfg, segment_ids=jnp.ones_like(ids))
+        llama.forward(family.seeded(ref, cfg), ids, cfg, segment_ids=jnp.ones_like(ids))
 
 
 def test_the_converter_round_trips_the_retention_tree():
     from ditl_tpu.models.convert import (config_from_hf, params_from_state_dict,
                                          state_dict_from_params)
 
-    cfg = tiny()
-    params = seeded(cfg)
+    cfg = CFG
+    params = family.seeded(ref, cfg)
     hf = state_dict_from_params(params, cfg)
     assert hf["model.layers.2.self_attn.gate_proj.weight"].shape == (2, cfg.hidden_size)
     assert hf["model.layers.0.self_attn.q_norm.weight"].shape == (16,)

@@ -8,31 +8,19 @@ its own so that the test runner can give it a worker of its own
 
 from __future__ import annotations
 
-import dataclasses
-import os
-import sys
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH = os.path.join(ROOT, "benchmarks")
-for _p in (ROOT, BENCH):
-    if _p not in sys.path:
-        sys.path.insert(0, _p)
+from ditl_tpu.data.tokenizer import ByteTokenizer
+from ditl_tpu.infer.engine import GenerateConfig
+from ditl_tpu.models import llama
+from ditl_tpu.ops import ssd
+from tests import family
 
-from harness import load_module  # noqa: E402
-
-from ditl_tpu.data.tokenizer import ByteTokenizer  # noqa: E402
-from ditl_tpu.infer.continuous import ContinuousEngine  # noqa: E402
-from ditl_tpu.infer.engine import GenerateConfig  # noqa: E402
-from ditl_tpu.models import llama  # noqa: E402
-from ditl_tpu.models.presets import get_preset  # noqa: E402
-from ditl_tpu.ops import ssd  # noqa: E402
-
-ref = load_module(os.path.join(BENCH, "reference", "granite_hybrid.py"))
+ref = family.reference("granite_hybrid")
+PRESET = "granite-4.0-h-micro"
 
 # Float32 on both sides, the same weights: a prefill in chunks of 16 and then
 # one cached step a token against a scan over all tokens. The measured error
@@ -50,30 +38,15 @@ OVERRIDES = [f"{k}={v}" for k, v in TINY.items()]
 CONFIG = {"preset": "granite-4.0-h-micro", "reference": "granite_hybrid"}
 
 
-def tiny(**kw):
-    return dataclasses.replace(get_preset("granite-4.0-h-micro"), **{**TINY, **kw})
-
-
-def seeded(cfg, seed=0):
-    return ref.perturb(llama.init_params(jax.random.key(seed), cfg), cfg, seed)
-
-
-@pytest.fixture(scope="module")
-def model():
-    cfg = tiny()
-    return seeded(cfg), cfg, ByteTokenizer()
-
-
-def engine(model, **kw):
-    params, cfg, tok = model
-    kw = {"n_slots": 4, "cache_mode": "paged", "page_size": 16, "max_cache_len": 512,
-          "gen": GenerateConfig(max_new_tokens=16), **kw}
-    return ContinuousEngine(params, cfg, tok, **kw)
+CFG = family.tiny(PRESET, TINY)
+# four rows of up to 512 tokens, 16 new tokens unless a request says otherwise
+ROWS = dict(n_slots=4, max_cache_len=512, gen=GenerateConfig(max_new_tokens=16))
+# the same with the two likeliest tokens' log-probabilities beside the chosen one's
+LOGPROBS = dict(ROWS, logprobs_k=2)
 
 
 def prompt(n, seed):
-    rng = np.random.default_rng(seed)
-    return [ByteTokenizer().bos_id] + [int(t) for t in rng.integers(3, 512, n - 1)]
+    return family.prompt_of(np.random.default_rng(seed), n)
 
 
 def serve(eng, prompts, **kw):
@@ -116,19 +89,20 @@ def test_a_bfloat16_state_where_float32_is_stated_is_refused(monkeypatch):
 def test_a_state_that_is_not_carried_between_ticks_is_refused(monkeypatch):
     """The timed path broken on purpose: every tick starts from the state the
     prefill seated (the tick's own updates are dropped)."""
-    build = ContinuousEngine._build_paged_decode
+    def broken(build):
+        def builder(self, *key):
+            program = build(self, *key)
 
-    def broken(self, *key):
-        program = build(self, *key)
+            def run(params, pools, *rest):
+                kept = {k: jnp.copy(pools[k]) for k in ("ssm", "conv")}
+                out, *others = program(params, pools, *rest)
+                return ({**out, **kept}, *others)
 
-        def run(params, pools, *rest):
-            kept = {k: jnp.copy(pools[k]) for k in ("ssm", "conv")}
-            out, *others = program(params, pools, *rest)
-            return ({**out, **kept}, *others)
+            return run
 
-        return run
+        return builder
 
-    monkeypatch.setattr(ContinuousEngine, "_build_paged_decode", broken)
+    family.patch_builder(monkeypatch, "paged_decode", broken)
     verdict = check(new_tokens=48)
     assert not verdict["ok"] and verdict["logprob_err_over_logit_rms"] > 100 * TOL
 
@@ -140,56 +114,63 @@ def close(a, b):
     return ta == tb and np.allclose(la, lb, atol=2e-5)
 
 
-def test_chunked_prefill_carries_the_state_between_chunks(model):
+def test_chunked_prefill_carries_the_state_between_chunks(engines):
     """A 70-token prompt in chunks of 32 (two whole chunks and a tail of 6
     in a padded bucket), decode ticks of other slots in between."""
     prompts = [prompt(70, 1), prompt(9, 2), prompt(45, 3)]
-    whole = serve(engine(model, logprobs_k=2), prompts, max_new_tokens=24, logprobs=2)
-    chunked = serve(engine(model, logprobs_k=2, prefill_chunk=32), prompts,
+    model = family.model(ref, CFG)
+    whole = serve(engines(model, **LOGPROBS), prompts, max_new_tokens=24, logprobs=2)
+    chunked = serve(engines(model, **LOGPROBS, prefill_chunk=32), prompts,
                     max_new_tokens=24, logprobs=2)
     assert all(close(a, b) for a, b in zip(whole, chunked))
 
 
-def test_a_slot_reused_after_release_carries_nothing_over(model):
-    """One slot: the second request sits where the first one's state was."""
+def test_a_slot_reused_after_release_carries_nothing_over():
+    """One slot: the second request sits where the first one's state was, and
+    answers as it did alone in a slot nothing had sat in."""
     a, b = prompt(30, 4), prompt(12, 5)
-    eng = engine(model, n_slots=1, logprobs_k=2)
+    # an engine of its own: ``alone`` is held to a slot that no request has used
+    eng = family.engine(family.model(ref, CFG), **dict(LOGPROBS, n_slots=1))
+    alone = serve(eng, [b], max_new_tokens=20, logprobs=2)
     both = serve(eng, [a, b], max_new_tokens=20, logprobs=2)
-    alone = serve(engine(model, n_slots=1, logprobs_k=2), [b], max_new_tokens=20, logprobs=2)
     assert close(both[1], alone[0])
 
 
-def test_preempt_and_resume_equals_an_uninterrupted_run(model):
+def test_preempt_and_resume_equals_an_uninterrupted_run(engines):
     """Optimistic admission on a pool too small for both: the younger
     request is preempted, its state dropped, and its resume prefills prompt
     and answer so far again from token 0."""
     a, b = prompt(17, 6), prompt(17, 7)
     kw = dict(max_new_tokens=96, logprobs=2)
-    solo = serve(engine(model, n_pages=20, logprobs_k=2), [a, b], **kw)
-    eng = engine(model, n_pages=10, admission="optimistic", logprobs_k=2)
+    model = family.model(ref, CFG)
+    solo = serve(engines(model, **LOGPROBS), [a, b], **kw)  # a pool with room for both
+    # an engine of its own: the pool's size is what is under test
+    eng = family.engine(model, **LOGPROBS, n_pages=10, admission="optimistic")
     res = serve(eng, [a, b], **kw)
     assert eng.preemptions >= 1 and eng.resume_prefill_tokens > 17
     assert all(close(x, y) for x, y in zip(res, solo))
 
 
-def test_requests_that_share_a_prefix_answer_as_their_uncached_runs_do(model):
+def test_requests_that_share_a_prefix_answer_as_their_uncached_runs_do(engines):
     """48 shared tokens (three whole pages), then each its own tail: no page
     is published or matched, every prompt is prefilled from its first token."""
     shared = prompt(48, 8)
     prompts = [shared + prompt(10, 9)[1:], shared + prompt(20, 10)[1:]]
-    eng = engine(model, logprobs_k=2)
+    model = family.model(ref, CFG)
+    eng = engines(model, **LOGPROBS)
     first = serve(eng, prompts[:1], max_new_tokens=20, logprobs=2)
     second = serve(eng, prompts[1:], max_new_tokens=20, logprobs=2)  # after the first
-    fresh = [serve(engine(model, logprobs_k=2), [p], max_new_tokens=20, logprobs=2)[0]
+    # engines of their own: "uncached" is an engine that has never seen the shared tokens
+    fresh = [serve(family.engine(model, **LOGPROBS), [p], max_new_tokens=20, logprobs=2)[0]
              for p in prompts]
     assert close(first[0], fresh[0]) and close(second[0], fresh[1])
     assert eng.stats()["prefix_cache"]["hit_tokens"] == 0
     assert eng.stats()["pages_cached_evictable"] == 0
 
 
-def test_the_counters_say_what_ran(model):
-    params, cfg, tok = model
-    eng = engine(model, n_slots=3)
+def test_the_counters_say_what_ran():
+    # an engine of its own: its counters are read whole and its slot count is in them
+    eng = family.engine(family.model(ref, CFG), **dict(ROWS, n_slots=3))
     outs = serve(eng, [prompt(9, 11), prompt(20, 12)], max_new_tokens=13)
     st = eng.stats()
     # the step that emits a token computes the next one, the last one's too
@@ -203,30 +184,32 @@ def test_the_counters_say_what_ran(model):
 
 @pytest.mark.parametrize("mode, kw", [
     ("contiguous cache", dict(cache_mode="contiguous")),
-    ("speculative ticks", dict(cache_mode="paged", speculative=True)),
-    ("host tier", dict(cache_mode="paged", host_tier_mb=1)),
-    ("a mesh", dict(cache_mode="paged", mesh="one")),
-    ("int8 page pools", dict(cache_mode="paged", kv="int8")),
-    ("LoRA adapters", dict(cache_mode="paged", lora=True)),
+    ("speculative ticks", dict(speculative=True)),
+    ("host tier", dict(host_tier_mb=1)),
+    ("a mesh", dict(mesh="one")),
+    ("int8 page pools", dict(kv="int8")),
+    ("LoRA adapters", dict(lora=True)),
 ])
 def test_options_that_cannot_carry_a_recurrent_state_refuse_by_name(mode, kw):
     kw = dict(kw)
-    cfg = tiny(kv_cache_dtype=kw.pop("kv", ""))
+    cfg = family.tiny(PRESET, TINY, kv_cache_dtype=kw.pop("kv", ""))
     if kw.get("mesh"):
         kw["mesh"] = jax.sharding.Mesh(np.array(jax.devices()[:1]), ("tensor",))
+    # shapes alone: the engine refuses before it reads a weight
     params = jax.eval_shape(lambda: llama.init_params(jax.random.key(0), cfg))
     if kw.pop("lora", False):
         params = {**params, "layers": {**params["layers"], "lora": {}}}
     with pytest.raises(ValueError, match=mode):
-        ContinuousEngine(params, cfg, ByteTokenizer(), n_slots=2, max_cache_len=64, **kw)
+        family.engine((params, cfg), max_cache_len=64, **kw)
 
 
-def test_handoff_prefix_registration_pod_serving_and_the_lock_step_engine_refuse(model):
+def test_handoff_prefix_registration_pod_serving_and_the_lock_step_engine_refuse(engines):
     from ditl_tpu.infer.engine import Generator
     from ditl_tpu.infer.podserve import PodContinuousDriver
 
-    params, cfg, tok = model
-    eng = engine(model, n_slots=2, max_cache_len=64)
+    params, cfg = family.model(ref, CFG)
+    tok = ByteTokenizer()
+    eng = engines((params, cfg), **LOGPROBS)
     with pytest.raises(ValueError, match="handoff"):
         eng.export_kv(list(range(3, 40)))
     with pytest.raises(ValueError, match="handoff"):

@@ -15,6 +15,7 @@ import pytest
 from ditl_tpu.infer.continuous import ContinuousEngine
 from ditl_tpu.models.presets import get_preset
 from ditl_tpu.ops import names
+from tests import family
 from tests.tpu_compile import _GIB, _TENTH_SPARE, _instructions, _total_bytes
 
 # deepseek-v3.2-cut1.docs-32k-dsa (ISSUE 44): one chip's share of 16, 32 slots,
@@ -99,7 +100,7 @@ def test_deepseek_decode_program_compiles_in_place_under_the_tenth_spare_line(
     row_i, row_f = s((slots,), jnp.int32), s((slots,), jnp.float32)
     keys = jax.eval_shape(lambda: jax.vmap(jax.random.key)(jnp.arange(slots, dtype=jnp.uint32)))
     keys = jax.ShapeDtypeStruct(keys.shape, keys.dtype, sharding=one_chip)
-    compiled = eng._build_paged_decode(False, False).lower(
+    compiled = family.build_program(eng, "paged_decode", False, False).lower(
         params, cache, row_i, row_i, s((slots,), jnp.bool_), row_f, row_f, keys,
         s((slots, 132), jnp.int32), row_i, s((slots, 1), jnp.int32), row_i).compile()
     text = compiled.as_text()
@@ -131,7 +132,7 @@ def test_deepseek_prefill_buckets_compile_under_the_tenth_spare_line(
     eng, params, cache, s = _deepseek_cell(one_chip)
     key = jax.eval_shape(lambda: jax.random.key(0))
     scalar_i, scalar_f = s((), jnp.int32), s((), jnp.float32)
-    compiled = eng._build_paged_prefill(bucket, ctx).lower(
+    compiled = family.build_program(eng, "paged_prefill", bucket, ctx).lower(
         params, cache, s((max(ctx, 1),), jnp.int32), s((1, bucket), jnp.int32), scalar_i,
         scalar_i, scalar_f, scalar_f,
         jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=one_chip),

@@ -16,6 +16,7 @@ import pytest
 from ditl_tpu.infer.continuous import ContinuousEngine
 from ditl_tpu.models.presets import get_preset
 from ditl_tpu.ops import names
+from tests import family
 from tests.tpu_compile import (
     _GIB,
     _TENTH_SPARE,
@@ -102,7 +103,7 @@ def test_longcat_prefill_reads_its_context_page_by_page(one_chip, tpu_branch, bu
     cache = {k: s((v.shape[0], 1280, *v.shape[2:]), v.dtype) for k, v in eng.cache.items()}
     key = jax.eval_shape(lambda: jax.random.key(0))
     scalar_i, scalar_f = s((), jnp.int32), s((), jnp.float32)
-    compiled = eng._build_paged_prefill(bucket, ctx).lower(
+    compiled = family.build_program(eng, "paged_prefill", bucket, ctx).lower(
         params, cache, s((max(ctx, 1),), jnp.int32), s((1, bucket), jnp.int32), scalar_i,
         scalar_i, scalar_f, scalar_f,
         jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=one_chip),

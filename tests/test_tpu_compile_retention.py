@@ -14,6 +14,7 @@ import pytest
 from ditl_tpu.infer.continuous import ContinuousEngine
 from ditl_tpu.models.presets import get_preset
 from ditl_tpu.ops import names
+from tests import family
 from tests.tpu_compile import _CHUNK, _TENTH_SPARE, _instructions, _total_bytes
 
 LAYERS, SLOTS = 8, 16
@@ -57,7 +58,7 @@ def test_brumby_decode_program_compiles_in_place_under_the_tenth_spare_line(
     row_i, row_f = s((SLOTS,), jnp.int32), s((SLOTS,), jnp.float32)
     keys = jax.eval_shape(lambda: jax.vmap(jax.random.key)(jnp.arange(SLOTS, dtype=jnp.uint32)))
     keys = jax.ShapeDtypeStruct(keys.shape, keys.dtype, sharding=one_chip)
-    compiled = eng._build_paged_decode(False, False).lower(
+    compiled = family.build_program(eng, "paged_decode", False, False).lower(
         params, cache, row_i, row_i, s((SLOTS,), jnp.bool_), row_f, row_f, keys,
         s((SLOTS, 16), jnp.int32), row_i, s((SLOTS, 1), jnp.int32), row_i).compile()
     text = compiled.as_text()
@@ -85,7 +86,7 @@ def test_brumby_prefill_buckets_compile_under_the_tenth_spare_line(one_chip, tpu
     eng, params, cache, s = _brumby_cell(one_chip, _CHUNK)
     key = jax.eval_shape(lambda: jax.random.key(0))
     scalar_i, scalar_f = s((), jnp.int32), s((), jnp.float32)
-    compiled = eng._build_paged_prefill(bucket, 0).lower(
+    compiled = family.build_program(eng, "paged_prefill", bucket, 0).lower(
         params, cache, s((1,), jnp.int32), s((1, bucket), jnp.int32), scalar_i, scalar_i,
         scalar_f, scalar_f, jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=one_chip),
         s((bucket // 256,), jnp.int32), s((1,), jnp.int32), scalar_i).compile()

@@ -18,6 +18,7 @@ import pytest
 from ditl_tpu.infer.continuous import ContinuousEngine
 from ditl_tpu.models.presets import get_preset
 from ditl_tpu.ops import names
+from tests import family
 from tests.tpu_compile import _GIB, _TENTH_SPARE, _instructions, _total_bytes
 
 PAGES, WINDOW_PAGES, SLOTS, MAXP = 2048, 384, 32, 132
@@ -71,7 +72,7 @@ def test_trinity_decode_program_compiles_in_place_under_the_tenth_spare_line(
     row_i, row_f = s((SLOTS,), jnp.int32), s((SLOTS,), jnp.float32)
     keys = jax.eval_shape(lambda: jax.vmap(jax.random.key)(jnp.arange(SLOTS, dtype=jnp.uint32)))
     keys = jax.ShapeDtypeStruct(keys.shape, keys.dtype, sharding=one_chip)
-    compiled = eng._build_paged_decode(False, False).lower(
+    compiled = family.build_program(eng, "paged_decode", False, False).lower(
         params, cache, row_i, row_i, s((SLOTS,), jnp.bool_), row_f, row_f, keys,
         s((2, SLOTS, MAXP), jnp.int32), row_i, s((SLOTS, 1), jnp.int32), row_i).compile()
     text = compiled.as_text()
@@ -101,7 +102,7 @@ def test_trinity_prefill_buckets_compile_under_the_tenth_spare_line(
     key = jax.eval_shape(lambda: jax.random.key(0))
     scalar_i, scalar_f = s((), jnp.int32), s((), jnp.float32)
     wctx = min(ctx, 8)
-    compiled = eng._build_paged_prefill(bucket, ctx).lower(
+    compiled = family.build_program(eng, "paged_prefill", bucket, ctx).lower(
         params, cache, (s((max(ctx, 1),), jnp.int32), s((max(wctx, 1),), jnp.int32)),
         s((1, bucket), jnp.int32), scalar_i, scalar_i, scalar_f, scalar_f,
         jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=one_chip),

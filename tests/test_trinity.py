@@ -11,45 +11,26 @@ import dataclasses
 import json
 import math
 import os
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH = os.path.join(ROOT, "benchmarks")
-for _p in (ROOT, BENCH):
-    if _p not in sys.path:
-        sys.path.insert(0, _p)
+from ditl_tpu.config import ModelConfig
+from ditl_tpu.models import llama
+from ditl_tpu.models.presets import get_preset
+from tests import family
 
-from harness import load_module  # noqa: E402
-
-from ditl_tpu.config import ModelConfig  # noqa: E402
-from ditl_tpu.models import llama  # noqa: E402
-from ditl_tpu.models.presets import get_preset  # noqa: E402
-
-ref = load_module(os.path.join(BENCH, "reference", "trinity_mini.py"))
+ref = family.reference("trinity_mini")
+PRESET = "trinity-mini"
 
 TINY = dict(num_layers=8, layer_types="wwwa" * 2, first_k_dense_replace=1, vocab_size=512,
             hidden_size=64, intermediate_size=128, expert_ffn_hidden_size=32, num_heads=4,
             num_kv_heads=2, head_dim=16, num_experts=16, num_experts_per_tok=4,
             experts_held_first=0, experts_held_count=8, sliding_window=24,
             embedding_multiplier=8.0, max_seq_len=512, dtype="float32", param_dtype="float32")
-
-
-def tiny(**kw):
-    return dataclasses.replace(get_preset("trinity-mini"), **{**TINY, **kw})
-
-
-def seeded(cfg, seed=0):
-    return ref.perturb(llama.init_params(jax.random.key(seed), cfg), cfg, seed)
-
-
-def rel_rms(got, want):
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    return float(np.sqrt(np.mean((got - want) ** 2)) / np.sqrt(np.mean(want ** 2)))
+CFG = family.tiny(PRESET, TINY)
 
 
 def sample(seq, packed, seed=1):
@@ -72,46 +53,48 @@ def test_the_program_is_the_reference_in_float32(packed, impl):
     gate, norm or share moves the error to order 1; float32 sums in another
     order leave 1e-6."""
     # the kernel tiles key blocks of whole lanes and heads of 64: so sized
-    cfg = tiny(attention_impl=impl, flash_block_q=32, flash_block_kv=128, head_dim=64,
-               num_heads=2, num_kv_heads=1)
-    params = seeded(cfg)
+    cfg = family.tiny(PRESET, TINY, attention_impl=impl, flash_block_q=32, flash_block_kv=128,
+                      head_dim=64, num_heads=2, num_kv_heads=1)
+    params = family.seeded(ref, cfg)
     ids, kw = sample(256, packed)
     got = jax.jit(lambda p: llama.forward(p, ids, cfg, **kw))(params)
     want = ref.forward(params, ids, ref.sizes(cfg, {}), **kw)["logits"]
-    assert rel_rms(got, want) < 2e-5
+    assert family.rel(got, want) < 2e-5
     no_window = ref.forward(params, ids, ref.sizes(cfg, {}), window=False, **kw)["logits"]
-    assert rel_rms(got, no_window) > 0.1  # the comparison sees the window
+    assert family.rel(got, no_window) > 0.1  # the comparison sees the window
 
 
 def test_the_program_in_bfloat16_stays_under_the_reference_checks_tolerance():
     """bfloat16 against the float32 reference on the weights every check uses
     (``perturb``): 3% is ``reference_check``'s, which the published widths read
     2.35% against on the chip. At mid widths here, with the held share."""
-    cfg = tiny(hidden_size=256, intermediate_size=512, expert_ffn_hidden_size=128, head_dim=64,
-               num_experts=64, num_experts_per_tok=8, experts_held_count=8, sliding_window=48,
-               embedding_multiplier=16.0, dtype="bfloat16", param_dtype="bfloat16")
-    params = seeded(cfg)
+    cfg = family.tiny(PRESET, TINY, hidden_size=256, intermediate_size=512,
+                      expert_ffn_hidden_size=128, head_dim=64, num_experts=64,
+                      num_experts_per_tok=8, experts_held_count=8, sliding_window=48,
+                      embedding_multiplier=16.0, dtype="bfloat16", param_dtype="bfloat16")
+    params = family.seeded(ref, cfg)
     ids, _ = sample(160, False)
     got = jax.jit(lambda p: llama.forward(p, ids, cfg))(params)
     want = ref.forward(params, ids, ref.sizes(cfg, {}))["logits"]
-    assert rel_rms(got, want) < 3e-2
+    assert family.rel(got, want) < 3e-2
 
 
 def test_a_stack_of_window_layers_alone_ignores_a_token_beyond_its_receptive_field():
     """Four window layers of 8: position i reads nothing in front of i - 4 x 7.
     Changing token 0 moves no logit from position 29 on, and does move one
     inside the field (the full layers of the real stack would see it)."""
-    cfg = tiny(num_layers=4, layer_types="wwww", sliding_window=8)
-    params = seeded(cfg)
+    cfg = family.tiny(PRESET, TINY, num_layers=4, layer_types="wwww", sliding_window=8)
+    params = family.seeded(ref, cfg)
     ids, _ = sample(64, False)
     other = ids.at[:, 0].set((ids[:, 0] + 7) % 500 + 3)
     fwd = jax.jit(lambda i: llama.forward(params, i, cfg))
     a, b = np.asarray(fwd(ids)), np.asarray(fwd(other))
     assert np.array_equal(a[:, 29:], b[:, 29:])
     assert not np.allclose(a[:, 1:29], b[:, 1:29])
-    mixed = tiny(num_layers=4, layer_types="wwwa", sliding_window=8)
-    a, b = (np.asarray(jax.jit(lambda i: llama.forward(seeded(mixed), i, mixed))(x))
-            for x in (ids, other))
+    mixed = family.tiny(PRESET, TINY, num_layers=4, layer_types="wwwa", sliding_window=8)
+    mixed_params = family.seeded(ref, mixed)
+    mixed_fwd = jax.jit(lambda i: llama.forward(mixed_params, i, mixed))
+    a, b = np.asarray(mixed_fwd(ids)), np.asarray(mixed_fwd(other))
     assert not np.allclose(a[:, 40:], b[:, 40:])
 
 
@@ -121,8 +104,8 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
     program (``moe_block``) and in the reference (``experts``) alike."""
     from ditl_tpu.models.moe import moe_block
 
-    whole = tiny(experts_held_count=16)
-    params = seeded(whole)
+    whole = family.tiny(PRESET, TINY, experts_held_count=16)
+    params = family.seeded(ref, whole)
     moe = jax.tree.map(lambda w: w[2], params["layers"]["sparse"]["moe"])  # one layer's
     u = jax.random.normal(jax.random.key(3), (2, 12, 64), jnp.float32)
     full, _, counts = moe_block(moe, u, whole, token_mask=None)
@@ -132,7 +115,7 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
     total, ref_total = shared, None
     stack = params["layers"]["sparse"]["moe"]
     for rank in range(8):
-        cfg = tiny(experts_held_first=2 * rank, experts_held_count=2)
+        cfg = family.tiny(PRESET, TINY, experts_held_first=2 * rank, experts_held_count=2)
         held = {**moe, **{n: moe[n][2 * rank:2 * rank + 2] for n in ("w_gate", "w_up", "w_down")}}
         part, _, c = moe_block(held, u, cfg, token_mask=None)
         total = total + (part - shared)
@@ -142,10 +125,10 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
                             for n in ("w_gate", "w_up", "w_down")}}
         y, _ = ref.experts(mine, 2, u, sizes, shared=False)
         ref_total = y if ref_total is None else ref_total + y
-    assert rel_rms(total, full) < 1e-5
+    assert family.rel(total, full) < 1e-5
     uncut, _ = ref.experts(stack, 2, u, ref.sizes(whole, {}))
-    assert rel_rms(ref_total + shared, uncut) < 1e-5
-    assert rel_rms(full, uncut) < 1e-5
+    assert family.rel(ref_total + shared, uncut) < 1e-5
+    assert family.rel(full, uncut) < 1e-5
 
 
 REFUSED = [
@@ -169,7 +152,7 @@ REFUSED = [
 @pytest.mark.parametrize("kw, match", REFUSED, ids=[m for _, m in REFUSED])
 def test_what_a_window_stack_cannot_run_is_refused_by_name(kw, match):
     with pytest.raises(ValueError, match=match.replace("|", r"\|")):
-        tiny(**kw)
+        family.tiny(PRESET, TINY, **kw)
 
 
 @pytest.mark.parametrize("kw", [dict(sliding_window=64), dict(attn_gate=True),
@@ -202,11 +185,12 @@ def test_the_windows_backward_is_refused_by_name_in_the_flash_kernel():
 
 
 def test_the_presets_parameter_count_is_the_configuration_files():
-    with open(os.path.join(BENCH, "configs", "trinity-mini-cut1.json")) as f:
+    with open(os.path.join(family.BENCH, "configs", "trinity-mini-cut1.json")) as f:
         config = json.load(f)
     cfg = dataclasses.replace(get_preset("trinity-mini"), **config["model_overrides"],
                               **config["serve_overrides"])
     assert ref.check_sizes(cfg, config) == []
+    # shapes alone: this size is never drawn
     shapes = jax.eval_shape(lambda: llama.init_params(jax.random.key(0), cfg))
     n = sum(math.prod(x.shape) for x in jax.tree.leaves(shapes))
     assert n == config["cut"]["parameters"] == 2_184_847_232
@@ -215,6 +199,7 @@ def test_the_presets_parameter_count_is_the_configuration_files():
     assert jax.tree.structure(llama.param_logical_axes(cfg), is_leaf=lambda x: isinstance(
         x, tuple)) == jax.tree.structure(shapes)
     # the whole published model, were it on one chip: 26.1 B
+    # shapes alone: this size is never drawn
     whole = jax.eval_shape(lambda: llama.init_params(jax.random.key(0), get_preset("trinity-mini")))
     assert round(sum(math.prod(x.shape) for x in jax.tree.leaves(whole)) / 1e9, 1) == 26.1
 
@@ -226,8 +211,8 @@ def test_the_afmoe_checkpoints_tensor_names_round_trip():
     recalled)."""
     from ditl_tpu.models.convert import params_from_state_dict, state_dict_from_params
 
-    cfg = tiny(experts_held_first=4, experts_held_count=8)
-    params = seeded(cfg)
+    cfg = family.tiny(PRESET, TINY, experts_held_first=4, experts_held_count=8)
+    params = family.seeded(ref, cfg)
     sd = state_dict_from_params(params, cfg)
     assert sd["model.layers.0.mlp.gate_proj.weight"].shape == (128, 64)  # the dense layer
     assert sd["model.layers.3.self_attn.gate_proj.weight"].shape == (64, 64)

@@ -10,29 +10,17 @@ give it a worker of its own (tests/test_trinity.py has the model)."""
 
 from __future__ import annotations
 
-import dataclasses
-import os
-import sys
-
 import jax
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH = os.path.join(ROOT, "benchmarks")
-for _p in (ROOT, BENCH):
-    if _p not in sys.path:
-        sys.path.insert(0, _p)
+from ditl_tpu.infer.page_format import MODES, page_format
+from ditl_tpu.models.presets import get_preset
+from tests import family
+from tests.family import ask, prompt_of
 
-from harness import load_module  # noqa: E402
-
-from ditl_tpu.data.tokenizer import ByteTokenizer  # noqa: E402
-from ditl_tpu.infer.continuous import ContinuousEngine  # noqa: E402
-from ditl_tpu.infer.page_format import MODES, page_format  # noqa: E402
-from ditl_tpu.models import llama  # noqa: E402
-from ditl_tpu.models.presets import get_preset  # noqa: E402
-
-ref = load_module(os.path.join(BENCH, "reference", "trinity_mini.py"))
+ref = family.reference("trinity_mini")
+PRESET = "trinity-mini"
 
 # float32 on both sides, sums in another order: 1e-6 is what that leaves,
 # 1e-4 a hundred times of room and a hundred times under a wrong page,
@@ -49,27 +37,11 @@ CONFIG = {"preset": "trinity-mini", "reference": "trinity_mini"}
 PS, REACH = 16, 2  # pages of 16 tokens: a window of 32 reaches 2 pages back
 
 
-def tiny(**kw):
-    return dataclasses.replace(get_preset("trinity-mini"), **{**TINY, **kw})
-
-
-def seeded(cfg, seed=0):
-    return ref.perturb(llama.init_params(jax.random.key(seed), cfg), cfg, seed)
-
-
-def engine(cfg=None, **kw):
-    cfg = cfg or tiny()
-    kw = {"n_slots": 2, "cache_mode": "paged", "page_size": PS, "max_cache_len": 160, **kw}
-    return ContinuousEngine(seeded(cfg), cfg, ByteTokenizer(), **kw)
-
-
-def prompt_of(rng, n, cfg=None):
-    return [ByteTokenizer().bos_id] + [int(t) for t in rng.integers(3, 512, n - 1)]
-
-
-def ask(eng, prompt, n=3):
-    rid = eng.submit(prompt, max_new_tokens=n, temperature=0.0)
-    return eng.run()[rid]
+CFG = family.tiny(PRESET, TINY)
+# the options most cases ask for: two rows of up to 160 tokens
+ROWS = dict(page_size=PS, max_cache_len=160)
+# one row of up to 256 tokens, prefilled in chunks longer than the window
+LONG = dict(page_size=PS, prefill_chunk=32, n_slots=1, max_cache_len=256)
 
 
 def sound(eng):
@@ -125,28 +97,29 @@ def test_a_document_in_chunks_a_hit_in_both_pools_and_a_row_decoding_across_the_
     assert verdict["window_pages_released"]["long_answer"] >= 2
 
 
-def test_chunked_and_whole_prefill_and_a_prefix_hit_give_one_answer():
-    cfg = tiny()
+def test_chunked_and_whole_prefill_and_a_prefix_hit_give_one_answer(engines):
     prompt = prompt_of(np.random.default_rng(0), 71)
     outs = []
     for chunk in (0, 32):
-        eng = engine(cfg, prefill_chunk=chunk)
+        eng = engines(family.model(ref, CFG), **ROWS, prefill_chunk=chunk)
+        before = eng.stats()
         answers = []
         for _ in range(2):  # the second finds the first one's published pages
-            rid = eng.submit(prompt, max_new_tokens=6, temperature=0.0)
-            answers.append(eng.run()[rid])
+            answers.append(ask(eng, prompt, 6))
             assert sound(eng)
         outs.append(answers)
-        assert eng.stats()["prefix_cache"]["hit_tokens"] == 64
-        assert eng.stats()["prefix_hits_whole"] == 1
+        st = eng.stats()
+        assert st["prefix_cache"]["hit_tokens"] - before["prefix_cache"]["hit_tokens"] == 64
+        assert st["prefix_hits_whole"] - before["prefix_hits_whole"] == 1
     assert outs[0][0] == outs[0][1] == outs[1][0] == outs[1][1]
 
 
-def test_a_live_row_holds_its_window_and_the_tick_in_flight_and_no_more():
+def test_a_live_row_holds_its_window_and_the_tick_in_flight_and_no_more(engines):
     """Guarantee (a): at every tick a row holds at most ``ceil(window / ps) +
     1`` window pages of context plus those of the chunk or tick in flight, in
     chunked prefill and in decode; what it lets go is counted."""
-    eng = engine(prefill_chunk=32, max_cache_len=256, n_slots=1)
+    eng = engines(family.model(ref, CFG), **LONG)
+    before = eng.stats()
     rid = eng.submit(prompt_of(np.random.default_rng(1), 120), max_new_tokens=100,
                      temperature=0.0)
     widest = 0
@@ -160,73 +133,80 @@ def test_a_live_row_holds_its_window_and_the_tick_in_flight_and_no_more():
     assert widest <= REACH + 1 + 2
     st = eng.stats()
     # 220 tokens are 14 pages; the last window's pages stay with the cache
-    assert st["window_pages_released_total"] >= 14 - (REACH + 1)
-    assert st["window_pages_freed_total"] >= 8  # some stay with the cache
-    # the row is gone: what is still in the pool is the cache's alone
-    assert (st["window_pages_total"] - st["window_pages_free"]
-            == st["window_pages_cached_evictable"] <= 2 * (REACH + 1))
+    assert (st["window_pages_released_total"] - before["window_pages_released_total"]
+            >= 14 - (REACH + 1))
+    # some stay with the cache
+    assert st["window_pages_freed_total"] - before["window_pages_freed_total"] >= 8
+    # the row is gone: what is still in the pool is the cache's alone, and
+    # this row added no more to it than two windows' pages
+    assert st["window_pages_total"] - st["window_pages_free"] == st["window_pages_cached_evictable"]
+    assert (st["window_pages_cached_evictable"] - before["window_pages_cached_evictable"]
+            <= 2 * (REACH + 1))
 
 
-def test_a_hit_at_the_whole_length_at_a_shorter_one_and_none():
+def test_a_hit_at_the_whole_length_at_a_shorter_one_and_none(engines):
     """Guarantee (b): a hit is granted at length P only where the full pool
     has [0, P) and the window pool covers the last window below P; else at the
     longest shorter P for which both hold; else not at all."""
-    cfg = tiny()
     rng = np.random.default_rng(2)
     doc = prompt_of(rng, 96)  # 6 whole pages
-    eng = engine(cfg, prefill_chunk=32, n_slots=1, max_cache_len=256)
-    first = ask(eng, doc + [7, 8, 9])
+    eng = engines(family.model(ref, CFG), **LONG)
     al = eng.allocator
-    assert (al.hits_whole, al.hits_short, al.hits_refused) == (0, 0, 0)
+    was = (al.hits_whole, al.hits_short, al.hits_refused)
+    hit_was = eng.stats()["prefix_cache"]["hit_tokens"]
+
+    def hits():  # (whole, short, refused) since this case began
+        return tuple(n - w for n, w in zip((al.hits_whole, al.hits_short, al.hits_refused), was))
+
+    first = ask(eng, doc + [7, 8, 9])
+    assert hits() == (0, 0, 0)
     # whole: the document's six pages, its last two in the window pool too
     assert ask(eng, doc + [7, 8, 9]) == first
-    assert (al.hits_whole, al.hits_short, al.hits_refused) == (1, 0, 0)
+    assert hits() == (1, 0, 0)
     hit = eng.stats()["prefix_cache"]["hit_tokens"]
-    assert hit == 96
+    assert hit - hit_was == 96
     # shorter: a prompt that shares only the first five pages finds all five in
     # the full pool, but the window pool kept the document's LAST window
     # (pages 4 and 5): at five pages page 3 is missing, and at no shorter
     # length do both hold, so the hit is refused and counted
     branch = doc[:80] + prompt_of(rng, 20)[1:]
     ask(eng, branch)
-    assert (al.hits_whole, al.hits_short, al.hits_refused) == (1, 0, 1)
+    assert hits() == (1, 0, 1)
     assert eng.stats()["prefix_cache"]["hit_tokens"] == hit
     assert sound(eng)
-    # the branch published its own pages: its second page-aligned tip is found
-    # again whole, and a prompt that runs PAST the document's end is granted
-    # the document's length, shorter than what the full pool could give
-    eng2 = engine(cfg, prefill_chunk=32, n_slots=1, max_cache_len=256)
-    answer = ask(eng2, doc, 20)  # publishes document + answer: 7 pages, the tip's window kept
-    al2 = eng2.allocator
+    # a prompt that runs PAST a document's end is granted the document's
+    # length, shorter than what the full pool could give (another document, so
+    # that what the engine holds of it is this part's alone)
+    doc = prompt_of(rng, 96)
+    answer = ask(eng, doc, 20)  # publishes document + answer: 7 pages, the tip's window kept
     longer = doc + answer + prompt_of(rng, 30)[1:]
     # evict the window companions of the answer's page only: the full pool still
     # matches 7 pages, the window pool covers the last window below page 6
-    tip = al2.match_prefix(longer, PS)
+    tip = al.match_prefix(longer, PS)
     assert len(tip) == 7
     for pid in tip:
-        al2.release(pid)
-    al2.hits_whole = 0
+        al.release(pid)
+    was = (al.hits_whole, al.hits_short, al.hits_refused)
     victim = tip[-1]
-    del al2._wcached[victim]
-    al2._drop(victim, by_row=False)
-    assert ask(eng2, longer, 20)[:1]  # served
-    assert (al2.hits_whole, al2.hits_short) == (0, 1)
-    assert sound(eng2)
+    del al._wcached[victim]
+    al._drop(victim, by_row=False)
+    assert ask(eng, longer, 20)[:1]  # served
+    assert hits()[:2] == (0, 1)
+    assert sound(eng)
 
 
-def test_eviction_preemption_and_resume_keep_both_pools_counts_sound():
+def test_eviction_preemption_and_resume_keep_both_pools_counts_sound(engines):
     """Guarantee (c): two long answers in pools too small for both rows and a
     cache; a row is preempted (its pages published, its holds given up) and
     comes back, re-prefilling what neither pool holds; both answers equal
     what each gets alone, and both pools' counts add up at every tick."""
-    cfg = tiny()
     rng = np.random.default_rng(5)
     prompts = [prompt_of(rng, 40) for _ in range(2)]
-    alone = []
-    for p in prompts:
-        eng = engine(cfg)
-        alone.append(ask(eng, p, 60))
-    eng = engine(cfg, n_pages=10, window_pages=7, admission="optimistic")
+    roomy = engines(family.model(ref, CFG), **ROWS, prefill_chunk=0)
+    alone = [ask(roomy, p, 60) for p in prompts]
+    # an engine of its own: the pools' sizes are what is under test
+    eng = family.engine(family.model(ref, CFG), **ROWS, n_pages=10, window_pages=7,
+                        admission="optimistic")
     ids = [eng.submit(p, max_new_tokens=60, temperature=0.0) for p in prompts]
     while eng.pending:
         eng.step()
@@ -246,9 +226,10 @@ def test_eviction_preemption_and_resume_keep_both_pools_counts_sound():
 
 
 def test_the_window_pool_evicts_a_cached_companion_and_the_hit_is_refused():
-    cfg = tiny()
     rng = np.random.default_rng(6)
-    eng = engine(cfg, n_slots=1, n_pages=40, window_pages=6, max_cache_len=128)  # 5 window pages
+    # an engine of its own: the window pool's size (5 usable pages) is what is under test
+    eng = family.engine(family.model(ref, CFG), page_size=PS, n_slots=1, n_pages=40,
+                        window_pages=6)
     docs = [prompt_of(rng, 64) for _ in range(3)]
     answers = [ask(eng, d) for d in docs]
     st = eng.stats()
@@ -268,10 +249,11 @@ def test_the_engine_counts_the_pages_each_kind_walked(pages, monkeypatch):
     tokens come out."""
     from tests.rect_walk import derive_pages_a_step
 
-    cfg = tiny()
+    cfg = CFG
     derive_pages_a_step(monkeypatch, pages, jax.ShapeDtypeStruct(
         (cfg.num_kv_heads, PS, cfg.head_dim), cfg.dtype))
-    eng = engine(cfg, n_slots=4, max_cache_len=128, decode_chunk=8)
+    # an engine of its own: it is built under the patch, which a shared program would keep
+    eng = family.engine(family.model(ref, cfg), page_size=PS, n_slots=4, decode_chunk=8)
     assert eng.attn_pages_a_step == eng.stats()["attn_pages_a_step"] == pages
     prompt = prompt_of(np.random.default_rng(7), 70)
     rid = eng.submit(prompt, max_new_tokens=16, temperature=0.0)
@@ -299,7 +281,8 @@ def test_a_traced_engines_tick_span_carries_the_windows_counters(tmp_path):
     from ditl_tpu.telemetry.tracing import Tracer
 
     journal = EventJournal(str(tmp_path / "events-engine.jsonl"), source="engine")
-    eng = engine(n_slots=2, max_cache_len=160, tracer=Tracer(journal))
+    # an engine of its own: the tracer and its journal are the case's
+    eng = family.engine(family.model(ref, CFG), **ROWS, tracer=Tracer(journal))
     rng = np.random.default_rng(8)
     ids = [eng.submit(prompt_of(rng, n), max_new_tokens=80, temperature=0.0) for n in (50, 70)]
     eng.run()
@@ -317,7 +300,7 @@ def test_a_traced_engines_tick_span_carries_the_windows_counters(tmp_path):
 
 @pytest.mark.parametrize("mode", MODES)
 def test_every_mode_the_two_pools_cannot_carry_is_refused_by_name(mode):
-    fmt = page_format(tiny(), n_pages=8, page_size=PS, n_slots=2, decode_chunk=4)
+    fmt = page_format(CFG, n_pages=8, page_size=PS, n_slots=2, decode_chunk=4)
     assert fmt.carries == frozenset()
     with pytest.raises(ValueError, match="two page pools"):
         fmt.refuse(mode)
@@ -330,14 +313,13 @@ def test_every_mode_the_two_pools_cannot_carry_is_refused_by_name(mode):
 ])
 def test_the_engine_refuses_at_construction(kw, match):
     with pytest.raises(ValueError, match=match):
-        engine(**kw)
+        family.engine(family.model(ref, CFG), **{**ROWS, **kw})
 
 
-def test_window_pages_belong_to_a_model_with_window_layers():
+def test_window_pages_belong_to_a_model_with_window_layers(engines):
     cfg = get_preset("tiny-llama")
     with pytest.raises(ValueError, match="no window attention layer"):
-        ContinuousEngine(llama.init_params(jax.random.key(0), cfg), cfg, ByteTokenizer(),
-                         cache_mode="paged", page_size=16, max_cache_len=64, window_pages=8)
-    eng = engine()
+        family.engine(family.model(None, cfg), max_cache_len=64, window_pages=8)
+    eng = engines(family.model(ref, CFG), **ROWS, prefill_chunk=0)
     with pytest.raises(ValueError, match="two page pools"):
         eng.register_prefix([1, 2, 3])
