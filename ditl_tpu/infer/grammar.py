@@ -1262,10 +1262,8 @@ def token_strings(tokenizer) -> list[bytes]:
             out[i] = bytes([i - off])
         return out
     specials = {tokenizer.pad_id, tokenizer.bos_id, tokenizer.eos_id}
-    inner = getattr(tokenizer, "_tok", None)
-    if inner is not None:
-        specials |= set(getattr(inner, "all_special_ids", ()) or ())
-    to_tokens = getattr(inner, "convert_ids_to_tokens", None)
+    specials |= set(getattr(tokenizer, "all_special_ids", ()))
+    to_tokens = getattr(tokenizer, "id_to_token", None)
     u2b = _gpt2_unicode_to_byte()
     strings = [
         to_tokens(i) if to_tokens is not None else None for i in range(v)

@@ -282,17 +282,20 @@ class _StopTracker:
         return out
 
 
+def _tokenizer_loader(tokenizer) -> str:
+    """What built the tokenizer: ``tokenizers`` or ``transformers`` for a
+    Hugging Face one (``HFTokenizer.loader``), ``byte`` for the built-in."""
+    return getattr(tokenizer, "loader", "byte")
+
+
 def _chat_prompt(messages: list[dict], tokenizer=None) -> str:
     """Render chat messages to a prompt string. HF tokenizers that carry a
     chat template (Llama-3.1 etc.) use it — real special-token turns, the
     same rendering the model was trained with; the byte/debug tokenizer
     falls back to plain-text role turns."""
-    inner = getattr(tokenizer, "_tok", None)
-    if inner is not None and getattr(inner, "chat_template", None):
+    if getattr(tokenizer, "chat_template", None):
         try:
-            return inner.apply_chat_template(
-                messages, tokenize=False, add_generation_prompt=True
-            )
+            return tokenizer.apply_chat_template(messages)
         except Exception:
             logger.exception("chat template failed; using plain-text turns")
     parts = [f"{m.get('role', 'user')}: {m.get('content', '')}" for m in messages]
@@ -664,7 +667,10 @@ class _Handler(KeepAliveHandlerMixin, BaseHTTPRequestHandler):
             # the legs sum to /health's cold_start_s.
             startup = getattr(self.server, "startup", None)
             if startup is not None:
-                stats["startup"] = startup.block()
+                stats["startup"] = {
+                    **startup.block(),
+                    "tokenizer_loader": _tokenizer_loader(self.generator.tokenizer),
+                }
             eng = self._engine_for_stats()
             if eng is not None:
                 stats.update(eng.stats())
@@ -2723,7 +2729,9 @@ def serve(argv: list[str] | None = None) -> int:
         cfg = dataclasses.replace(cfg, kv_cache_dtype="int8")
     startup.mark("runtime")
     tokenizer = get_tokenizer(args.tokenizer)
-    startup.mark("tokenizer")
+    loader = _tokenizer_loader(tokenizer)
+    logger.info("tokenizer %s loaded through %s", args.tokenizer, loader)
+    startup.mark("tokenizer", loader=loader)
     params = llama.init_params(jax.random.key(0), cfg)
     params_restored = False
     if args.checkpoint_dir:

@@ -421,11 +421,11 @@ def render_markdown() -> str:
         "entry, its legs `startup.<leg>` (the server's `imports`, `runtime`, "
         "`tokenizer`, `params`, `engine`, `listen`; the trainer's `config`, "
         "`runtime`, `data`, `state`, `restore`, `loop_prep`, `first_flush`) "
-        "with `synced`, `param_bytes`, `restored`, `pool_bytes`, `port`, "
+        "with `loader`, `synced`, `param_bytes`, `restored`, `pool_bytes`, `port`, "
         "`examples`, `n_params`, `resumed`, `step`, `steps`, and on every "
         "`jit.compile` event `cache` (`hit` with `retrieval_s`, `miss`, "
         "`off`). No `/metrics` family carries them either: the legs' seconds "
-        "are `startup` (`entry_wall`, `legs`) in the server's `/v1/stats` "
+        "are `startup` (`entry_wall`, `legs`, `tokenizer_loader`) in the server's `/v1/stats` "
         "(their sum is `/health`'s `cold_start_s`) and in the FIRST "
         "`train.metrics_file` row a process writes; the programs the "
         "persistent cache did not hold are `compile_miss_count_cum`, beside "

@@ -412,21 +412,18 @@ def test_token_strings_byte_level_with_plain_ascii_added_token():
     so no added token can break it."""
     b2u = {b: u for u, b in G._gpt2_unicode_to_byte().items()}
 
-    class FakeInner:
+    class FakeTok:
+        vocab_size = 8
+        pad_id, bos_id, eos_id = 0, 1, 2
         all_special_ids = [0]
 
-        def convert_ids_to_tokens(self, i):
+        def id_to_token(self, i):
             return {
                 3: b2u[0xC3], 4: b2u[0xA9],  # partial-UTF-8 byte tokens
                 5: "\n\n",  # plain-text added token
                 6: b2u[0x20] + "the",  # Ġthe: the positive signal
                 7: "你好",  # non-ASCII added token (outside the alphabet)
             }.get(i)
-
-    class FakeTok:
-        vocab_size = 8
-        pad_id, bos_id, eos_id = 0, 1, 2
-        _tok = FakeInner()
 
         def decode(self, ids):
             raise AssertionError("byte-level vocab must not decode()")
@@ -443,16 +440,13 @@ def test_token_strings_sp_vocab_with_latin_extended_not_byte_level():
     letters (ā, č, ł …): a multilingual SentencePiece vocab ('▁český')
     must NOT flip onto the byte-level path — the ▁ marker vetoes."""
 
-    class FakeInner:
-        all_special_ids = [0]
-
-        def convert_ids_to_tokens(self, i):
-            return {3: "▁český", 4: "▁the", 5: "ně"}.get(i)
-
     class FakeTok:
         vocab_size = 6
         pad_id, bos_id, eos_id = 0, 1, 2
-        _tok = FakeInner()
+        all_special_ids = [0]
+
+        def id_to_token(self, i):
+            return {3: "▁český", 4: "▁the", 5: "ně"}.get(i)
 
         def decode(self, ids):
             return {5: "ně"}[ids[0]]
@@ -492,21 +486,18 @@ def test_token_strings_byte_level_bpe_partial_utf8():
     including tokens that are partial UTF-8 sequences."""
     b2u = {b: u for u, b in G._gpt2_unicode_to_byte().items()}
 
-    class FakeInner:
+    class FakeTok:
+        vocab_size = 10
+        pad_id, bos_id, eos_id = 0, 1, 2
         all_special_ids = [0, 1, 2, 9]
 
-        def convert_ids_to_tokens(self, i):
+        def id_to_token(self, i):
             # token 3: the lone byte 0xC3 (first half of 'é') — decode()
             # would mangle this to U+FFFD. Token 6 carries the Ġ (space
             # remap) every real byte-level vocab has — the positive
             # byte-level detection signal.
             return {3: b2u[0xC3], 4: b2u[0xA9], 5: "".join(b2u[b] for b in b"hi"),
                     6: b2u[0x20] + "a", 9: "<unk>"}.get(i)
-
-    class FakeTok:
-        vocab_size = 10
-        pad_id, bos_id, eos_id = 0, 1, 2
-        _tok = FakeInner()
 
         def decode(self, ids):
             return "�"
@@ -530,16 +521,13 @@ def test_token_strings_byte_level_bpe_partial_utf8():
 
 
 def test_token_strings_sentencepiece_marker():
-    class FakeInner:
-        all_special_ids = [0]
-
-        def convert_ids_to_tokens(self, i):
-            return {3: "▁hello", 4: "world"}.get(i)
-
     class FakeTok:
         vocab_size = 5
         pad_id, bos_id, eos_id = 0, 1, 2
-        _tok = FakeInner()
+        all_special_ids = [0]
+
+        def id_to_token(self, i):
+            return {3: "▁hello", 4: "world"}.get(i)
 
         def decode(self, ids):
             raise AssertionError("should not fall back")
@@ -556,18 +544,15 @@ def test_token_strings_sentencepiece_not_byte_level(  # ADVICE r3
     the byte table per token: 'é' is UTF-8 C3 A9, not byte 0xE9. And SP
     byte-fallback tokens like <0x0A> are ONE raw byte, not literal text."""
 
-    class FakeInner:
-        all_special_ids = [0]
-
-        def convert_ids_to_tokens(self, i):
-            # '▁the' marks this vocab as NOT byte-level (▁ is outside the
-            # GPT-2 alphabet), as in any real SP vocab.
-            return {3: "é", 4: "<0x0A>", 5: "▁the", 6: "café"}.get(i)
-
     class FakeTok:
         vocab_size = 7
         pad_id, bos_id, eos_id = 0, 1, 2
-        _tok = FakeInner()
+        all_special_ids = [0]
+
+        def id_to_token(self, i):
+            # '▁the' marks this vocab as NOT byte-level (▁ is outside the
+            # GPT-2 alphabet), as in any real SP vocab.
+            return {3: "é", 4: "<0x0A>", 5: "▁the", 6: "café"}.get(i)
 
         def decode(self, ids):
             return {3: "é", 6: "café"}[ids[0]]

@@ -450,15 +450,11 @@ def test_profile_endpoint_returns_collapsed_stacks(setup):
 def test_chat_template_used_when_tokenizer_has_one(setup):
     from ditl_tpu.infer.server import _chat_prompt
 
-    class FakeInner:
+    class FakeTok:
         chat_template = "{{messages}}"
 
-        def apply_chat_template(self, messages, tokenize, add_generation_prompt):
-            assert not tokenize and add_generation_prompt
+        def apply_chat_template(self, messages):
             return "<|templated|>" + messages[0]["content"]
-
-    class FakeTok:
-        _tok = FakeInner()
 
     msgs = [{"role": "user", "content": "hi"}]
     assert _chat_prompt(msgs, FakeTok()) == "<|templated|>hi"

@@ -237,6 +237,7 @@ def served(tmp_path_factory):
         [sys.executable, "-m", "ditl_tpu.infer.server", "--preset", "tiny-llama",
          "--engine", "continuous", "--cache-mode", "paged", "--host", "127.0.0.1",
          "--port", str(port), "--max-cache-len", "128", "--slots", "2",
+         "--tokenizer", os.path.join(REPO, "tests", "fixtures", "llama3_tokenizer"),
          "--trace-dir", str(tmp / "spans")],
         env=_single_device_env(JAX_COMPILATION_CACHE_DIR=str(tmp / "cache")),
         cwd=REPO, stdout=subprocess.DEVNULL, stderr=subprocess.PIPE, text=True)
@@ -277,6 +278,14 @@ def test_serve_writes_its_six_legs_in_order_under_one_parent(served):
     assert by_name["startup.params"]["param_bytes"] > 0
     assert by_name["startup.engine"]["pool_bytes"] > 0
     assert by_name["startup.listen"]["port"] > 0
+
+
+def test_the_tokenizer_leg_names_its_loader(served):
+    """A directory with a tokenizer.json is read without `transformers`: the
+    span and the stats block say which loader ran."""
+    _, stats, records = served
+    (leg,) = [r for r in records if r.get("name") == "startup.tokenizer"]
+    assert leg["loader"] == stats["startup"]["tokenizer_loader"] == "tokenizers"
 
 
 def test_cold_start_s_is_the_legs_sum_in_health_stats_and_journal(served):
